@@ -156,11 +156,6 @@ register(
     "on the oldest fetch.",
     "engine")
 register(
-    "CLIENT_TPU_PLATFORM", "", "str",
-    "Force the JAX platform for the embedded engine (e.g. `cpu` for "
-    "hermetic runs on machines without a TPU).",
-    "engine")
-register(
     "CLIENT_TPU_SEQ_PIPELINE", "2", "int",
     "Sequence-batcher dispatch-ahead depth (waves in flight before the "
     "worker blocks on the oldest fetch).",

@@ -186,17 +186,18 @@ class ArenaAllocator:
 def device_hbm_budget(fraction: float, fallback_bytes: int = 0) -> int:
     """The arena budget for device 0: ``bytes_limit`` (the
     ``tpu_hbm_limit_bytes`` gauge source) scaled by ``fraction``. CPU
-    backends report no limit (``memory_stats`` absent or 0) — fall back to
-    ``fallback_bytes`` so the planner still works in tests/CI."""
-    limit = 0
-    try:
-        import jax
+    backends report no limit (``memory_stats`` is None) — fall back to
+    ``fallback_bytes`` so the planner still works in tests/CI. A TPU that
+    reports none is an error: planning a 16 GB chip against the CPU
+    fallback would be wrong by an order of magnitude, silently."""
+    from client_tpu.engine.backend_init import ensure_backend
 
-        dev = jax.local_devices()[0]
-        stats = getattr(dev, "memory_stats", lambda: None)() or {}
-        limit = int(stats.get("bytes_limit", 0) or 0)
-    except Exception:
-        limit = 0
-    if limit <= 0:
-        return int(fallback_bytes)
-    return int(limit * fraction)
+    dev = ensure_backend()[0]
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0) or 0)
+    if limit > 0:
+        return int(limit * fraction)
+    if dev.platform == "tpu":
+        raise RuntimeError(
+            f"TPU device {dev} reports no memory_stats()['bytes_limit']; "
+            "refusing to plan HBM against the CPU fallback budget")
+    return int(fallback_bytes)

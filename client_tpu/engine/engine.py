@@ -54,8 +54,11 @@ class TpuEngine:
             # a slow TPU attach is indistinguishable from a hang (round-1
             # failure mode: first device_put on a daemon thread → opaque 504).
             from client_tpu.engine.backend_init import ensure_backend
+            from client_tpu.observability.roofline import (
+                require_device_peaks,
+            )
 
-            ensure_backend()
+            require_device_peaks(ensure_backend())
         self.repository = repository or ModelRepository(jit=jit)
         self._schedulers: dict[str, Scheduler] = {}
         self._stats: dict[str, ModelStats] = {}
@@ -118,8 +121,9 @@ class TpuEngine:
         self.profiler = _profiler()
         self.profiler.bind_metrics(self.metrics.registry)
         # Roofline attribution config: resolved here purely so a
-        # malformed CLIENT_TPU_ROOFLINE fails the boot loudly — the
-        # capture/join paths re-read it and degrade instead of raising.
+        # malformed CLIENT_TPU_ROOFLINE fails the boot loudly (as does a
+        # TPU kind with no peaks row, above) — the capture/join paths
+        # re-read it and degrade instead of raising.
         from client_tpu.observability import roofline as _roofline
 
         _roofline.roofline_config()
@@ -220,6 +224,10 @@ class TpuEngine:
             autotune=self.autotuner is not None,
             selfdrive=self.selfdrive is not None,
             blackbox=self.blackbox is not None)
+        # name -> reason for every model load_all could not bring up
+        # (build, placement or warmup compile).  The launcher turns an
+        # entry for a model named on its command line into a failed start.
+        self.load_errors: dict[str, str] = {}
         if load_all:
             for name in self.repository.names():
                 try:
@@ -228,6 +236,7 @@ class TpuEngine:
                     # Also visible in the repository index state, but a
                     # model silently absent at startup is the kind of
                     # failure operators grep the journal for.
+                    self.load_errors[name] = f"{type(exc).__name__}: {exc}"
                     self.events.emit(
                         "lifecycle", "model_load_failed",
                         severity="ERROR", model=name, error=str(exc))

@@ -11,7 +11,7 @@ server's `simple*` family — e.g. add/sub INT32[16] checks in
 - ``simple_repeat``     — decoupled/streaming repeat
 - ``simple_dyna_sequence`` — sequence + additive correlation-id semantics
 
-Flagship models (BASELINE.md configs): ``resnet50``, ``densenet_onnx``
+Flagship models (BASELINE.json configs): ``resnet50``, ``densenet_onnx``
 (DenseNet-121), ``bert_base``, ``ssd_mobilenet_v2_coco_quantized``, plus the
 ``ensemble_bert`` preprocess→BERT→postprocess pipeline.
 
@@ -59,15 +59,17 @@ def build_repository(names: list[str] | None = None,
 
 
 def _import_all() -> None:
-    from client_tpu.models import simple  # noqa: F401
-
-    for mod in ("vision", "bert", "ssd", "ensembles", "generate", "dlrm"):
-        try:
-            __import__(f"client_tpu.models.{mod}")
-        except ImportError:
-            pass
+    """Import every zoo module so its ``register_model`` calls run.  An
+    import error propagates: a module that fails to import (a renamed
+    Pallas symbol, say) must not quietly vanish from the zoo."""
+    from client_tpu.models import (  # noqa: F401
+        bert,
+        dlrm,
+        ensembles,
+        generate,
+        simple,
+        ssd,
+        vision,
+    )
     # Multi-chip serving models live with the parallelism code.
-    try:
-        __import__("client_tpu.parallel.serving")
-    except ImportError:
-        pass
+    from client_tpu.parallel import serving  # noqa: F401

@@ -1,6 +1,6 @@
 """BERT-base flagship model (`bert_base`).
 
-Serving-side counterpart of BASELINE.md config 5 (ensemble
+Serving-side counterpart of BASELINE.json config 5 (ensemble
 preprocess→BERT-base→postprocess); the reference carries no model code, so
 this is a TPU-first encoder design:
 
@@ -136,13 +136,15 @@ class BertBackend(ModelBackend):
             import jax.numpy as jnp
 
             if attention_impl == "flash":
+                from client_tpu.engine.backend_init import pallas_interpret
                 from client_tpu.ops.flash_attention import flash_attention
 
                 # Bigger tiles amortize the per-grid-step overhead at long
                 # sequence (512/1024 measured fastest at s=2048 on v5e);
                 # clamp to divisors of the actual sequence length so any
-                # seq_len works. interpret=True off-TPU keeps the hermetic
-                # CPU suite on the same kernel code path the chip compiles.
+                # seq_len works. Off-TPU the kernel runs interpreted, which
+                # keeps the hermetic CPU suite on the same kernel code path
+                # the chip compiles.
                 def pick_block(s_len, cap):
                     # Largest divisor of s_len that is <= cap AND a legal
                     # TPU tile height (multiple of 8); fall back to the
@@ -158,7 +160,7 @@ class BertBackend(ModelBackend):
                     q, k, v, bias2d,
                     block_q=pick_block(s_len, 512),
                     block_k=pick_block(s_len, 1024),
-                    interpret=jax.default_backend() != "tpu")
+                    interpret=pallas_interpret())
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
             scores = (scores / np.sqrt(head_dim)
                       + bias2d[:, None, None, :].astype(jnp.float32))

@@ -255,7 +255,9 @@ class DlrmBackend(ModelBackend):
         combine = self.combine
         # The Pallas ring combine needs interpret mode off-TPU (the psum
         # combine is a plain XLA collective and runs anywhere).
-        interpret = jax.default_backend() != "tpu"
+        from client_tpu.engine.backend_init import pallas_interpret
+
+        interpret = pallas_interpret()
 
         params = {
             "bottom": [(jax.device_put(w), jax.device_put(b))

@@ -1,6 +1,6 @@
 """Ensemble pipelines and their composing pre/post-process models.
 
-BASELINE.md config 5 names the flagship pipeline: preprocess → BERT-base →
+BASELINE.json config 5 names the flagship pipeline: preprocess → BERT-base →
 postprocess with string I/O, served like the reference serves ensembles
 (composing steps declared via input_map/output_map, executed by the engine's
 EnsembleScheduler with per-composing-model statistics — the reference's perf

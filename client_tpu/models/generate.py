@@ -238,6 +238,7 @@ class TinyGptBackend(ModelBackend):
 
         def attend(q, k, v):
             if use_flash:
+                from client_tpu.engine.backend_init import pallas_interpret
                 from client_tpu.ops.flash_attention import flash_attention
 
                 def pick_block(s_len, cap):
@@ -252,7 +253,7 @@ class TinyGptBackend(ModelBackend):
                     q[None], k[None], v[None], causal=True,
                     block_q=pick_block(n, cap_q),
                     block_k=pick_block(n, cap_k),
-                    interpret=jax.default_backend() != "tpu")[0]
+                    interpret=pallas_interpret())[0]
             s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(d_)
             if mask is not None:
                 s = jnp.where(mask[None], s, -1e30)
@@ -482,7 +483,9 @@ class TinyGptBackend(ModelBackend):
         import jax.numpy as jnp
 
         h_, d_ = self.n_heads, self.head_dim
-        interpret = jax.default_backend() != "tpu"
+        from client_tpu.engine.backend_init import pallas_interpret
+
+        interpret = pallas_interpret()
         block_s = self.decode_block_s
 
         if self.kv_shards > 1:

@@ -15,7 +15,7 @@ reference's CPU TFLite_Detection_PostProcess op. "quantized" parity: the
 wire input stays UINT8 (dequantized on device); matmul precision is bf16.
 
 A batched variant ``ssd_mobilenet_v2_tpu`` (max_batch_size 16, dynamic
-batching) is also registered — that's the BASELINE.md north-star bench
+batching) is also registered — that's the BASELINE.json north-star bench
 target, where batch>1 keeps the MXU fed.
 """
 
@@ -292,7 +292,7 @@ class SsdMobileNetV2Backend(ModelBackend):
 
 
 class SsdMobileNetV2TpuBackend(SsdMobileNetV2Backend):
-    """Batched TPU-throughput variant — BASELINE.md north-star bench model."""
+    """Batched TPU-throughput variant — BASELINE.json north-star bench model."""
 
     def __init__(self):
         super().__init__(name="ssd_mobilenet_v2_tpu", max_batch_size=16)

@@ -2,7 +2,7 @@
 
 These are the serving-side counterparts of the models the reference's image
 clients drive (/root/reference/src/c++/examples/image_client.cc:26-120
-preprocesses for "resnet"-style models; BASELINE.md configs 3-4 name
+preprocesses for "resnet"-style models; BASELINE.json configs 3-4 name
 `resnet50` and `densenet_onnx`). The reference repo carries no model code —
 models live behind the server boundary — so these are TPU-first designs, not
 translations:

@@ -111,16 +111,8 @@ def sharded_bag_sum(mesh, table, rows, seg_ids, num_segments: int, *,
             return ring_all_reduce(pooled, "emb", n, interpret=interpret)
         return jax.lax.psum(pooled, "emb")
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
-    kwargs = dict(mesh=mesh,
-                  in_specs=(P("emb", None), P(), P()),
-                  out_specs=P())
-    try:
-        fn = shard_map(body, check_vma=False, **kwargs)
-    except TypeError:  # pre-0.8 jax spells it check_rep
-        fn = shard_map(body, check_rep=False, **kwargs)
+    fn = shard_map(body, mesh=mesh, in_specs=(P("emb", None), P(), P()),
+                   out_specs=P(), check_vma=False)
     return fn(table, rows, seg_ids)

@@ -21,9 +21,10 @@ The combine is the cross-chip data plane and comes in two flavors:
 ``psum`` (XLA's collective) and the default ``ring`` — a Pallas kernel
 moving the partial outputs neighbor-to-neighbor with
 ``make_async_remote_copy`` remote DMA (SNIPPETS.md [3] / pallas_guide.md),
-double-buffered with per-slot DMA semaphores.  Both run under
-``interpret=True`` on CPU, which is how the tier-1 suite exercises ≥2
-shards on 8 virtual devices (tests/conftest.py).
+double-buffered with per-slot DMA semaphores, a neighbor barrier and a
+per-hop slot handshake.  Both run under ``interpret=True`` on CPU, which
+is how the tier-1 suite exercises ≥2 shards on 8 virtual devices
+(tests/conftest.py).
 """
 
 from __future__ import annotations
@@ -81,56 +82,107 @@ def shard_arena(arena: dict, mesh):
 # -- ring all-reduce over remote DMA ------------------------------------------
 
 
-def _ring_kernel(x_ref, o_ref, buf_ref, send_sem, recv_sem,
+def _ring_kernel(x_ref, o_ref, buf_ref, send_sem, recv_sem, free_sem,
                  *, n_dev: int, axis_name: str):
     """All-reduce-sum by rotating the chunk around the ring n-1 times:
     each step remote-copies the current buffer slot to the right
     neighbor's other slot and accumulates what arrived from the left.
-    Double-buffered so a step never sends the slot it is receiving into;
-    start()+wait() per hop keeps the schedule a simple barrier ring."""
+    Double-buffered so a step never sends the slot it is receiving into.
+
+    Two things keep the ring safe on real chips, where neighbors run at
+    their own pace: a barrier with both neighbors before the first remote
+    write (the target must have entered the kernel, or the write lands in
+    VMEM that is not this kernel's yet), and a per-hop handshake — the
+    slot written at step ``s+1`` is the one the right neighbor *sent from*
+    at step ``s``, so it says when that send has drained (``free_sem``)
+    before the slot is overwritten."""
     from jax.experimental.pallas import tpu as pltpu
 
+    logical = pltpu.DeviceIdType.LOGICAL
     my = jax.lax.axis_index(axis_name)
     right = jax.lax.rem(my + 1, n_dev)
+    left = jax.lax.rem(my + n_dev - 1, n_dev)
+
+    barrier = pltpu.get_barrier_semaphore()
+    for neighbor in (left, right):
+        pltpu.semaphore_signal(barrier, inc=1, device_id=neighbor,
+                               device_id_type=logical)
+    pltpu.semaphore_wait(barrier, 2)
+
     o_ref[...] = x_ref[...]
     buf_ref[0] = x_ref[...]
     for step in range(n_dev - 1):
         src, dst = step % 2, (step + 1) % 2
+        if step >= 1:
+            pltpu.semaphore_wait(free_sem, 1)
         copy = pltpu.make_async_remote_copy(
             src_ref=buf_ref.at[src],
             dst_ref=buf_ref.at[dst],
             send_sem=send_sem.at[src],
             recv_sem=recv_sem.at[dst],
             device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+            device_id_type=logical)
         copy.start()
         copy.wait()
         o_ref[...] += buf_ref[dst]
+        if step < n_dev - 2:
+            # My send from slot `src` has drained: the left neighbor may
+            # overwrite it on its next hop.  Signals and waits pair up
+            # (steps 0..n-3 signal, steps 1..n-2 wait), so every
+            # semaphore is back at zero when the kernel exits.
+            pltpu.semaphore_signal(free_sem, inc=1, device_id=left,
+                                   device_id_type=logical)
 
 
-def ring_all_reduce(x, axis_name: str, n_dev: int, *,
-                    interpret: bool = False):
+_LANES = 128
+
+
+def ring_all_reduce(x, axis_name: str, n_dev: int, *, interpret=False):
     """Sum ``x`` across ``axis_name`` (size ``n_dev``, static) with a
     Pallas remote-DMA ring.  Call under ``shard_map``; the result is
     replicated.  ``n_dev`` must be passed statically — Pallas needs the
-    hop count at trace time."""
+    hop count at trace time.
+
+    The payload travels as a lane-dense ``[rows, 128]`` slab (flattened
+    and zero-padded): Mosaic refuses to slice a VMEM buffer whose minor
+    dim is narrower than the 128-lane tile, which is what a ``[B, H, 64]``
+    attention output or a 16-wide embedding row would be.
+
+    ``interpret=True`` runs under Pallas' TPU interpreter
+    (``pltpu.InterpretParams``), the one that models remote DMA and
+    cross-device semaphores; pass an ``InterpretParams`` to set its
+    options (the tests turn on ``detect_races``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if n_dev == 1:
         return x
+    if interpret is True:
+        interpret = pltpu.InterpretParams()
+    flat = x.reshape(-1)
+    pad = -flat.size % _LANES
+    slab = jnp.pad(flat, (0, pad)).reshape(-1, _LANES)
     kernel = functools.partial(_ring_kernel, n_dev=n_dev,
                                axis_name=axis_name)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=jax.ShapeDtypeStruct(slab.shape, slab.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((2,) + x.shape, x.dtype),
+            pltpu.VMEM((2,) + slab.shape, slab.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.REGULAR,
         ],
+        # collective_id names the barrier semaphore this kernel's devices
+        # share; has_side_effects keeps the remote writes from being
+        # elided.
+        compiler_params=pltpu.CompilerParams(collective_id=0,
+                                             has_side_effects=True),
         interpret=interpret,
-    )(x)
+    )(slab)
+    return out.reshape(-1)[:flat.size].reshape(x.shape)
 
 
 # -- sharded fused decode ------------------------------------------------------
@@ -172,19 +224,13 @@ def sharded_decode_attention(mesh, k_arena, v_arena, q, k_new, v_new,
             o = jax.lax.psum(o, "kv")
         return k_sh, v_sh, o
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     arena_spec = P(None, "kv")
     rep = P()
-    kwargs = dict(mesh=mesh,
-                  in_specs=(arena_spec, arena_spec, rep, rep, rep, rep,
-                            rep),
-                  out_specs=(arena_spec, arena_spec, rep))
-    try:
-        fn = shard_map(body, check_vma=False, **kwargs)
-    except TypeError:  # pre-0.8 jax spells it check_rep
-        fn = shard_map(body, check_rep=False, **kwargs)
+    fn = shard_map(body, mesh=mesh,
+                   in_specs=(arena_spec, arena_spec, rep, rep, rep, rep,
+                             rep),
+                   out_specs=(arena_spec, arena_spec, rep),
+                   check_vma=False)
     return fn(k_arena, v_arena, q, k_new, v_new, rows, lens)

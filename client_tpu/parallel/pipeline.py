@@ -119,19 +119,13 @@ def pipeline_apply(mesh, stacked, x_mb, n_heads, mask):
         # broadcast the last stage's collected outputs to every pp member
         return lax.all_gather(outputs, "pp")[n_stages - 1]
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     block_spec = jax.tree.map(lambda _: P("pp"), stacked)
-    kwargs = dict(mesh=mesh,
-                  in_specs=(block_spec, P(None, "dp", None, None)),
-                  out_specs=P(None, "dp", None, None))
-    try:
-        mapped = shard_map(run, check_vma=False, **kwargs)
-    except TypeError:  # pre-0.8 jax spells it check_rep
-        mapped = shard_map(run, check_rep=False, **kwargs)
+    mapped = shard_map(run, mesh=mesh,
+                       in_specs=(block_spec, P(None, "dp", None, None)),
+                       out_specs=P(None, "dp", None, None),
+                       check_vma=False)
     return mapped(stacked, x_mb)
 
 

@@ -95,20 +95,13 @@ def sequence_parallel_attention(mesh, q, k, v, bias, axis_name: str = "sp"):
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax spells it experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     batch = "dp" if "dp" in mesh.shape else None
     qkv_spec = P(batch, axis_name, None, None)
     bias_spec = P(batch, axis_name)
     fn = functools.partial(ring_attention, axis_name=axis_name)
-    kwargs = dict(mesh=mesh,
-                  in_specs=(qkv_spec, qkv_spec, qkv_spec, bias_spec),
-                  out_specs=qkv_spec)
-    try:
-        mapped = shard_map(fn, check_vma=False, **kwargs)
-    except TypeError:  # pre-0.8 jax spells it check_rep
-        mapped = shard_map(fn, check_rep=False, **kwargs)
+    mapped = shard_map(fn, mesh=mesh,
+                       in_specs=(qkv_spec, qkv_spec, qkv_spec, bias_spec),
+                       out_specs=qkv_spec, check_vma=False)
     return mapped(q, k, v, bias)

@@ -77,6 +77,19 @@ def main(argv: list[str] | None = None) -> int:
         if entry.get("reason"):
             line += f" ({entry['reason']})"
         print(line, file=sys.stderr, flush=True)
+    failed = {n: why for n, why in engine.load_errors.items()
+              if n in (zoo_names or ())}
+    if failed:
+        # tritonserver's --exit-on-error default: a model named on the
+        # command line that did not load (build, placement or warmup
+        # compile) is a failed start, not a server that serves on without
+        # it.  A directory repository keeps "load the rest" — its index
+        # above says which are missing.
+        for name, why in failed.items():
+            print(f"model {name} failed to load: {why}", file=sys.stderr,
+                  flush=True)
+        engine.shutdown()
+        return 1
 
     servers = []
     http_servers = []
@@ -96,19 +109,21 @@ def main(argv: list[str] | None = None) -> int:
                                        port=args.grpc_port).start()
         grpc_servers.append(grpc_srv)
         servers.append(("grpc", grpc_srv.url))
-    for kind, url in servers:
-        print(f"serving {kind} at {url}", file=sys.stderr, flush=True)
     if not servers:
         print("nothing to serve (--no-http and --no-grpc)", file=sys.stderr)
         return 2
     # Graceful drain on SIGTERM (the orchestrator's stop signal): flip
     # readiness, refuse new work, let in-flight requests finish inside
-    # --drain-deadline, then exit 0.
+    # --drain-deadline, then exit 0.  Installed BEFORE the "serving" lines:
+    # whoever reads those as "up" may send the stop signal the next moment,
+    # and the default handler would kill the process undrained.
     from client_tpu.admission.drain import install_sigterm_handler
 
     drained = install_sigterm_handler(
         engine, http_servers=http_servers, grpc_servers=grpc_servers,
         deadline_s=args.drain_deadline)
+    for kind, url in servers:
+        print(f"serving {kind} at {url}", file=sys.stderr, flush=True)
     try:
         while not drained.wait(timeout=3600):
             pass

@@ -39,6 +39,8 @@ setup(
         "protobuf>=3.20",
     ],
     extras_require={
-        "engine": ["jax>=0.4"],
+        # The version the tree is tested with (jaxlib 0.9.0, libtpu
+        # 0.0.34 on the chip); there are no shims for others.
+        "engine": ["jax==0.9.0"],
     },
 )

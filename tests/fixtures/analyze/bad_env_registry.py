@@ -3,5 +3,5 @@
 import os
 
 
-def platform():
-    return os.environ.get("CLIENT_TPU_PLATFORM", "")
+def loglevel():
+    return os.environ.get("CLIENT_TPU_LOGLEVEL", "")

@@ -3,5 +3,5 @@
 from client_tpu import config as envcfg
 
 
-def platform():
-    return envcfg.env_str("CLIENT_TPU_PLATFORM")
+def loglevel():
+    return envcfg.env_str("CLIENT_TPU_LOGLEVEL")

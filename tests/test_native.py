@@ -321,7 +321,7 @@ def test_perf_analyzer_capi_inprocess(native_build, tmp_path):
     (reference triton_c_api kind, SURVEY.md §2.3/§3.5). CPU platform for
     hermetic runs."""
     csv = tmp_path / "capi.csv"
-    env = dict(os.environ, CLIENT_TPU_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [os.path.join(native_build, "tpu_perf_analyzer"),
          "-m", "simple", "--service-kind", "tpu_capi",
@@ -342,7 +342,7 @@ def test_perf_analyzer_capi_inprocess(native_build, tmp_path):
 @pytest.mark.parametrize("shm_mode", ["system", "tpu"])
 def test_perf_analyzer_shm_modes(native_build, server, tmp_path, shm_mode):
     """--shared-memory system|tpu over HTTP: the north-star data planes
-    (BASELINE.md config 2, reference cudashm path load_manager.cc:287-446)
+    (BASELINE.json config 2, reference cudashm path load_manager.cc:287-446)
     driven by the native harness against the live server."""
     csv = tmp_path / f"shm_{shm_mode}.csv"
     proc = subprocess.run(
@@ -362,7 +362,7 @@ def test_perf_analyzer_capi_tpushm(native_build, tmp_path):
     zero network anywhere (reference has no counterpart — its C-API kind
     cannot do shm, main.cc:1227-1248)."""
     csv = tmp_path / "capi_tpushm.csv"
-    env = dict(os.environ, CLIENT_TPU_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [os.path.join(native_build, "tpu_perf_analyzer"),
          "-m", "simple", "--service-kind", "tpu_capi",
@@ -765,7 +765,7 @@ def test_perf_analyzer_ensemble_composing_csv(native_build, tmp_path):
     server-side phase breakdown (reference main.cc:1503-1668 writes
     `<path>.<model>` files)."""
     csv = tmp_path / "ens.csv"
-    env = dict(os.environ, CLIENT_TPU_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                CLIENT_TPU_WARMUP="1")
     proc = subprocess.run(
         [os.path.join(native_build, "tpu_perf_analyzer"),

@@ -256,26 +256,32 @@ class TestShardedKvArena:
             arena_row_layout(5, 2)
 
     def test_ring_all_reduce_sums(self):
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = kv_mesh(4)
         x = jnp.arange(4 * 8, dtype=jnp.float32).reshape(4, 8)
 
-        def body(x_sh):
-            return ring_all_reduce(x_sh[0], "kv", 4, interpret=True)[None]
+        # The TPU interpreter's vector-clock race detector checks the
+        # ring's synchronisation as well as its sum: without the per-hop
+        # handshake it reports the neighbor overwriting a slot that is
+        # still being sent from.
+        from jax._src.pallas.mosaic.interpret import (
+            interpret_pallas_call as tpu_interpret,
+        )
+        from jax.experimental.pallas import tpu as pltpu
 
-        kwargs = dict(mesh=mesh, in_specs=(P("kv"),), out_specs=P("kv"))
-        try:
-            fn = shard_map(body, check_vma=False, **kwargs)
-        except TypeError:
-            fn = shard_map(body, check_rep=False, **kwargs)
+        def body(x_sh):
+            return ring_all_reduce(
+                x_sh[0], "kv", 4,
+                interpret=pltpu.InterpretParams(detect_races=True))[None]
+
+        fn = shard_map(body, mesh=mesh, in_specs=(P("kv"),),
+                       out_specs=P("kv"), check_vma=False)
         out = np.asarray(fn(x))
         want = np.tile(np.asarray(x).sum(0), (4, 1))
         np.testing.assert_allclose(out, want, rtol=1e-6)
+        assert not tpu_interpret.races.races_found
 
     @pytest.mark.parametrize("combine", ["ring", "psum"])
     def test_sharded_matches_single_chip(self, combine):

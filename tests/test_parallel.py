@@ -91,8 +91,6 @@ class TestMultihost:
         import sys
 
         code = (
-            "import jax\n"
-            "jax.config.update('jax_platforms', 'cpu')\n"
             "from client_tpu.parallel import multihost\n"
             "pid = multihost.initialize('127.0.0.1:19765', 1, 0)\n"
             "assert pid == 0, pid\n"
@@ -101,7 +99,7 @@ class TestMultihost:
             "assert pid2 == 0  # idempotent\n"
             "print('MULTIHOST-OK')\n"
         )
-        env = dict(os.environ, PYTHONPATH=REPO_ROOT)
+        env = dict(os.environ, PYTHONPATH=REPO_ROOT, JAX_PLATFORMS="cpu")
         proc = subprocess.run([sys.executable, "-c", code],
                               capture_output=True, text=True, timeout=120,
                               env=env)

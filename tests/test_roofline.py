@@ -43,7 +43,7 @@ from client_tpu.observability.roofline import (
     capture_cost_model,
     capture_memory_analysis,
     classify_bound,
-    peak_flops_for_gen,
+    require_device_peaks,
 )
 from client_tpu.observability.timeseries import MODEL_SIGNALS
 from client_tpu.server import GrpcInferenceServer, HttpInferenceServer
@@ -199,13 +199,19 @@ class TestPeakRegistry:
         spec = cfg.resolve_peaks("TPU v5e")
         assert spec.flops_per_s == 7.0 and spec.source == "env"
 
-    def test_gen_shorthand(self):
-        assert peak_flops_for_gen("v5e") == PEAK_SPECS["tpu v5e"].flops_per_s
-        assert peak_flops_for_gen("v5litepod") == \
-            PEAK_SPECS["tpu v5e"].flops_per_s
-        assert peak_flops_for_gen("V4") == PEAK_SPECS["tpu v4"].flops_per_s
-        assert peak_flops_for_gen("v99") is None
-        assert peak_flops_for_gen("") is None
+    def test_unlisted_tpu_kind_is_a_startup_error(self):
+        from types import SimpleNamespace as Dev
+
+        require_device_peaks([Dev(platform="tpu", device_kind="TPU v5 lite")])
+        require_device_peaks([Dev(platform="cpu", device_kind="cpu")])
+        with pytest.raises(RuntimeError, match="TPU v99"):
+            require_device_peaks([Dev(platform="tpu", device_kind="TPU v99")])
+        # An env row is a row: the operator named the chip.
+        require_device_peaks(
+            [Dev(platform="tpu", device_kind="TPU v99")],
+            environ={"CLIENT_TPU_ROOFLINE": json.dumps({"device_kinds": {
+                "tpu v99": {"peak_flops": 1e12,
+                            "peak_bytes_per_s": 1e11}}})})
 
     def test_ridge(self):
         assert PEAKS.ridge() == pytest.approx(10.0)

@@ -3,7 +3,7 @@
 
 The round-5 history schema appends one record per PROBE as it completes
 (plus a run-status record), grouped by ``run_ts`` — this prints each run's
-probes on one screen so BASELINE.md reconciliation is mechanical.
+probes on one screen so reconciling them with the prose is mechanical.
 
 ``--check`` turns the tool into a regression gate: the newest run's
 per-probe p99 latency is compared against the median of the prior runs
@@ -25,7 +25,7 @@ def _probe_runs(hist: list) -> dict:
     """{run_ts: {probe: record}} for probe records (run-status excluded).
 
     Records with ``status: "unavailable"`` (the pre-r06 placeholder for
-    backend-init outages — see BENCH_r05.json) or
+    backend-init outages) or
     ``status: "backend_init_error"`` (the r06+ fail-fast diagnostic) are
     dropped: an outage run carries no performance signal, and letting
     its zeros into the p99/ips medians would mask real regressions."""

@@ -91,6 +91,9 @@ set -u -o pipefail
 
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
+# CI runs must not depend on executables a previous run left on disk (and
+# XLA:CPU logs two multi-KB loader lines per persistent-cache hit).
+export JAX_ENABLE_COMPILATION_CACHE="${JAX_ENABLE_COMPILATION_CACHE:-false}"
 FAST=0
 [ "${1:-}" = "--fast" ] && FAST=1
 rc=0
