@@ -132,6 +132,15 @@ def ensure_backend(hard_timeout_s: float = 300.0) -> list:
             done.set()
         _init_seconds = time.monotonic() - t0
         _devices = devices
+        # The program's one compile listener hears every jit from here on;
+        # the init just paid is the first set-up span of /v2/profile.
+        from client_tpu.observability import spans
+        from client_tpu.observability.profiler import (
+            install_compile_listener, profiler)
+
+        install_compile_listener()
+        profiler().record_startup(spans.STARTUP_BACKEND_INIT,
+                                  int(t0 * 1e9), time.monotonic_ns())
         log.info("JAX backend ready in %.1fs: platform=%s device_kind=%s "
                  "devices=%d compile_cache=%s",
                  _init_seconds, devices[0].platform, devices[0].device_kind,

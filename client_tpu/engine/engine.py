@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from client_tpu import config as envcfg
 import threading
+import time
 from client_tpu.utils import lockdep
 from typing import Callable
 
@@ -116,10 +117,16 @@ class TpuEngine:
         # tpu_batch_fill_ratio / tpu_padded_rows_total /
         # tpu_xla_compilations_total / tpu_xla_compile_seconds /
         # tpu_device_seconds_total / tpu_device_duty_cycle here.
-        from client_tpu.observability.profiler import profiler as _profiler
+        from client_tpu.observability.profiler import (
+            install_compile_listener,
+            profiler as _profiler,
+        )
 
         self.profiler = _profiler()
         self.profiler.bind_metrics(self.metrics.registry)
+        # The one feeder of the compile counter (ensure_backend installs it
+        # too; an engine built with eager_init=False still counts).
+        install_compile_listener()
         # Roofline attribution config: resolved here purely so a
         # malformed CLIENT_TPU_ROOFLINE fails the boot loudly (as does a
         # TPU kind with no peaks row, above) — the capture/join paths
@@ -366,6 +373,9 @@ class TpuEngine:
         (Triton load semantics): schedulers are created for newly served
         versions, retired for versions no longer selected, kept untouched
         for unchanged ones, and the bare-name latest alias is refreshed."""
+        from client_tpu.observability import spans
+
+        t_load = time.monotonic_ns()
         self.repository.load(name)
         versions = self.repository.loaded_versions(name)
         retired: list[Scheduler] = []
@@ -439,11 +449,18 @@ class TpuEngine:
                 self.autotuner.on_version_retired(name, v)
             for model, sched in zip(new_models, new_scheds):
                 self.autotuner.on_model_loaded(model, sched)
+        # Set-up spans of /v2/profile: build + placement + arena, then the
+        # precompile (the launcher's --warmup).
+        t_loaded = time.monotonic_ns()
+        self.profiler.record_startup(spans.STARTUP_MODEL_LOAD + name,
+                                     t_load, t_loaded)
         if self._warmup:
             for model in new_models:
                 model.warmup()
             for sched in new_scheds:
                 sched.warmup()
+            self.profiler.record_startup(spans.STARTUP_WARMUP + name,
+                                         t_loaded, time.monotonic_ns())
 
     def unload_model(self, name: str, unload_dependents: bool = False) -> None:
         dependents: list[str] = []

@@ -33,6 +33,12 @@ generation streams. Design, TPU-first:
   wave (iteration-level batching), they never wait for a running stream to
   finish (request-level batching would).
 
+The worker's own clock is inside the program: every phase of a loop iteration
+is a ``gen.*`` span and every dispatch, drain and first token bumps a counter
+(:mod:`client_tpu.observability.spans`), served per model under
+``GET /v2/profile`` ``generative`` and, while a device trace is active, written
+into it as ``TraceAnnotation``s of the same names.
+
 Tokens stream out through the ordinary decoupled response protocol
 (``triton_final_response`` terminates), so the gRPC stream frontend and the
 C API serve generative models without modification.
@@ -64,7 +70,9 @@ from client_tpu.engine.types import (
     InferResponse,
     now_ns,
 )
+from client_tpu.observability import spans as _sp
 from client_tpu.observability.costs import ledger
+from client_tpu.observability.profiler import profiler
 
 _log = logging.getLogger("client_tpu")
 
@@ -95,15 +103,19 @@ class _Stream:
 class _Inflight:
     """One dispatched execution whose token fetch is pending."""
 
-    __slots__ = ("kind", "streams", "tokens", "waves", "t_disp", "bucket")
+    __slots__ = ("kind", "streams", "tokens", "waves", "t_disp", "bucket",
+                 "depth", "positions")
 
-    def __init__(self, kind, streams, tokens, waves=1, t_disp=0, bucket=0):
+    def __init__(self, kind, streams, tokens, waves=1, t_disp=0, bucket=0,
+                 depth=0, positions=0):
         self.kind = kind          # 'prefill' | 'wave' | 'chunk'
         self.streams = streams    # lane order, real lanes only
         self.tokens = tokens      # jax.Array future (copy_to_host_async'd)
         self.waves = waves        # logical waves this dispatch advances
         self.t_disp = t_disp      # monotonic ns at dispatch (wave timing)
         self.bucket = bucket      # wave bucket (0 for prefill)
+        self.depth = depth        # waves in flight at a prefill's dispatch
+        self.positions = positions  # valid context positions it reads
 
 
 class _WarmupReq:
@@ -226,10 +238,15 @@ class GenerativeScheduler(Scheduler):
             model.config.name, "kv_arena", self, _census_arena)
         # `sample` is static: all-greedy calls get an executable with no
         # sampling pipeline in it (prefill arg 9, decode arg 8).
-        self._prefill = jax.jit(backend.prefill_fn(), donate_argnums=(1,),
-                                static_argnums=(9,))
-        self._decode = jax.jit(backend.decode_fn(), donate_argnums=(1,),
-                               static_argnums=(8,))
+        # The XLA modules are named here (jit_prefill, jit_decode,
+        # jit_decode_chunk), not by what a backend calls its functions:
+        # the device-trace reduction finds the steps by these names.
+        self._prefill = jax.jit(
+            _sp.named_step(backend.prefill_fn(), _sp.STEP_PREFILL),
+            donate_argnums=(1,), static_argnums=(9,))
+        self._decode = jax.jit(
+            _sp.named_step(backend.decode_fn(), _sp.STEP_DECODE),
+            donate_argnums=(1,), static_argnums=(8,))
         # Chunked decode (CLIENT_TPU_GEN_CHUNK > 1): K waves fused into one
         # scanned execution — one dispatch advances every stream K tokens,
         # dividing per-wave Python + transport-command overhead by K.
@@ -241,8 +258,9 @@ class GenerativeScheduler(Scheduler):
         self._decode_chunk = None
         if self._chunk > 1:
             self._decode_chunk = jax.jit(
-                backend.decode_chunk_fn(), donate_argnums=(1,),
-                static_argnums=(8, 9))
+                _sp.named_step(backend.decode_chunk_fn(),
+                               _sp.STEP_DECODE_CHUNK),
+                donate_argnums=(1,), static_argnums=(8, 9))
         self._prompt_buckets = power_buckets(self._max_seq)
         self._wave_buckets = power_buckets(self._cap)
         # ONE admit lane bucket: every prefill chunk pads to this, so there
@@ -275,6 +293,11 @@ class GenerativeScheduler(Scheduler):
         # Per-row arena bytes for the cost ledger's HBM-byte-second
         # charges, cached on first use (one pytree walk, static shapes).
         self._row_bytes = 0.0
+        # Loop-phase spans and lane counters of this worker; an iteration
+        # commits into whichever profiler is the global one when it ends.
+        name, version = model.config.name, model.config.version
+        self._rec = _sp.GenRecorder(
+            lambda rec: profiler().commit_generative(name, version, rec))
         super().__init__(model, stats)
 
     def arena_shards(self) -> int:
@@ -323,13 +346,15 @@ class GenerativeScheduler(Scheduler):
         z_f = np.zeros(lane, np.float32)
         ones_f = np.ones(lane, np.float32)
         for pb in self._prompt_buckets:
-            self.model._set_state(f"warmup: prefill prompt bucket={pb}")
+            self.model._set_state(f"warmup: prefill prompt bucket={pb}",
+                                  _sp.STEP_PREFILL, pb)
             self._arena, tokens = self._prefill(
                 self.model._params, self._arena, dummy,
                 np.zeros((lane, pb), np.int32), np.ones(lane, np.int32),
                 z_i, z_f, z_i, ones_f, False)
         for wb in self._wave_buckets:
-            self.model._set_state(f"warmup: decode wave bucket={wb}")
+            self.model._set_state(f"warmup: decode wave bucket={wb}",
+                                  _sp.STEP_DECODE, wb)
             rows = np.full(wb, self._dummy, np.int32)
             self._arena, tokens = self._decode(
                 self.model._params, self._arena, rows,
@@ -338,7 +363,8 @@ class GenerativeScheduler(Scheduler):
                 np.ones(wb, np.float32), False)
             if self._decode_chunk is not None:
                 self.model._set_state(
-                    f"warmup: chunked decode bucket={wb} k={self._chunk}")
+                    f"warmup: chunked decode bucket={wb} k={self._chunk}",
+                    _sp.STEP_DECODE_CHUNK, wb)
                 self._arena, tokens = self._decode_chunk(
                     self.model._params, self._arena, rows,
                     np.zeros(wb, np.int32), np.zeros(wb, np.int32),
@@ -351,117 +377,143 @@ class GenerativeScheduler(Scheduler):
     # -- worker ---------------------------------------------------------------
 
     def _worker_loop(self) -> None:
+        rec = self._rec
         while True:
-            pending = []
-            shutdown = False
-            # Blocking admit only when fully idle; otherwise opportunistic —
-            # a new request joins the *next* wave, never waits for a stream
-            # to finish.
-            if not self._streams and not self._inflight:
-                item = self.queue.get()
-                if item is _SHUTDOWN:
+            rec.begin_loop()
+            try:
+                if self._loop_once(rec):
                     return
-                if isinstance(item, _WarmupReq):
-                    self._run_warmup(item)
-                    continue
-                pending.append(item)
-            while len(self._free) > len(pending):
-                try:
-                    item = self.queue.get(timeout=0)
-                except _queue.Empty:
-                    break
-                if item is _SHUTDOWN:
-                    shutdown = True
-                    break
-                if isinstance(item, _WarmupReq):
-                    self._run_warmup(item)
-                    continue
-                pending.append(item)
-            if pending:
+            finally:
+                rec.end_loop()
+
+    def _loop_once(self, rec) -> bool:
+        """One scheduler iteration (one ``gen.loop`` span); True stops the
+        worker."""
+        span = rec.span
+        pending = []
+        shutdown = False
+        # Blocking admit only when fully idle; otherwise opportunistic —
+        # a new request joins the *next* wave, never waits for a stream
+        # to finish.
+        if not self._streams and not self._inflight:
+            with span[_sp.S_IDLE]:
+                item = self.queue.get()
+            if item is _SHUTDOWN:
+                return True
+            if isinstance(item, _WarmupReq):
+                self._run_warmup(item)
+                return False
+            pending.append(item)
+        while len(self._free) > len(pending):
+            try:
+                item = self.queue.get(timeout=0)
+            except _queue.Empty:
+                break
+            if item is _SHUTDOWN:
+                shutdown = True
+                break
+            if isinstance(item, _WarmupReq):
+                self._run_warmup(item)
+                continue
+            pending.append(item)
+        if pending:
+            with span[_sp.S_ADMIT]:
                 try:
                     self._admit_batch(pending)
                 except Exception as exc:  # noqa: BLE001 — sole worker:
                     # an escape here would kill the scheduler thread and
                     # hang the model permanently.
                     self._reset_arena(exc)
-            if shutdown:
-                self._abort_streams("server shutting down")
-                return
-            # Client-abandoned streams stop consuming decode slots at the
-            # next wave boundary (frontends set `cancelled` on disconnect).
-            for s in list(self._streams):
-                if s.req.cancelled:
-                    self._drop(s)
-                    self._fail(s.req, EngineError("request cancelled", 499))
-            # Transport flow control: streams whose frontend reports a
-            # backlogged response path sit out this wave (production is
-            # writer-paced) instead of flooding the stream queue until the
-            # slow-consumer shed kills them.  They stay live and rejoin
-            # the moment the writer drains — but a stream CONTINUOUSLY
-            # throttled past the timeout is holding an arena slot for a
-            # consumer that stopped reading; drop it (bounds slot
-            # occupancy the way the shed bounds queue memory).
-            live = []
-            now_mono = time.monotonic()
-            for s in list(self._streams):
-                if not self._has_budget(s):
-                    continue
-                if _backpressured(s.req):
-                    if s.throttled_since is None:
-                        s.throttled_since = now_mono
-                    elif (now_mono - s.throttled_since
-                          > self.BACKPRESSURE_TIMEOUT_S):
-                        self._drop(s)
-                        self._fail(s.req, EngineError(
-                            "request cancelled (stream backpressured "
-                            f"beyond {self.BACKPRESSURE_TIMEOUT_S:.0f}s)",
-                            499))
-                    continue
-                s.throttled_since = None
-                live.append(s)
-            if live:
-                try:
-                    self._dispatch_wave(live)
-                except Exception as exc:  # noqa: BLE001
-                    self._reset_arena(exc)
-            # Consume fetches: non-blocking while results are ready or the
-            # pipeline is over depth; forced (blocking on the oldest) when
-            # nothing was dispatched — every budget-exhausted stream has
-            # its final wave in flight, so this always makes progress.
-            self._drain_fetches(force_one=not live and not pending)
-            if (not live and not pending and not self._inflight
-                    and self._streams):
-                # Every stream is throttled by transport backpressure:
-                # nothing to dispatch, nothing to fetch.  Park briefly so
-                # the writer can drain (it advances ~10 rows/ms) — via a
-                # timed queue poll, not a bare sleep: _SHUTDOWN must not
-                # be starved for the whole backpressure timeout
-                # (engine.shutdown joins this thread), and a warmup
-                # sentinel must not rot behind throttled streams.
-                try:
+        if shutdown:
+            self._abort_streams("server shutting down")
+            return True
+        with span[_sp.S_SWEEP]:
+            live = self._sweep()
+        if live:
+            try:
+                self._dispatch_wave(live)
+            except Exception as exc:  # noqa: BLE001
+                self._reset_arena(exc)
+        # Consume fetches: non-blocking while results are ready or the
+        # pipeline is over depth; forced (blocking on the oldest) when
+        # nothing was dispatched — every budget-exhausted stream has
+        # its final wave in flight, so this always makes progress.
+        self._drain_fetches(force_one=not live and not pending)
+        if (not live and not pending and not self._inflight
+                and self._streams):
+            # Every stream is throttled by transport backpressure:
+            # nothing to dispatch, nothing to fetch.  Park briefly so
+            # the writer can drain (it advances ~10 rows/ms) — via a
+            # timed queue poll, not a bare sleep: _SHUTDOWN must not
+            # be starved for the whole backpressure timeout
+            # (engine.shutdown joins this thread), and a warmup
+            # sentinel must not rot behind throttled streams.
+            try:
+                with span[_sp.S_IDLE]:
                     item = self.queue.get(timeout=0.001)
-                except _queue.Empty:
-                    continue
-                if item is _SHUTDOWN:
-                    self._abort_streams("server shutting down")
-                    return
-                if isinstance(item, _WarmupReq):
-                    self._run_warmup(item)
-                else:
-                    # A new admit while the arena is throttle-parked: put
-                    # it back at the FRONT (no reordering) and yield the
-                    # core — the loop-top opportunistic admit takes it the
-                    # moment a slot frees.
-                    self.queue.put_front(item)
+            except _queue.Empty:
+                return False
+            if item is _SHUTDOWN:
+                self._abort_streams("server shutting down")
+                return True
+            if isinstance(item, _WarmupReq):
+                self._run_warmup(item)
+            else:
+                # A new admit while the arena is throttle-parked: put
+                # it back at the FRONT (no reordering) and yield the
+                # core — the loop-top opportunistic admit takes it the
+                # moment a slot frees.
+                self.queue.put_front(item)
+                with span[_sp.S_IDLE]:
                     time.sleep(0.001)
+        return False
+
+    def _sweep(self) -> list:
+        """Drop cancelled streams and return the lanes of the next wave."""
+        # Client-abandoned streams stop consuming decode slots at the
+        # next wave boundary (frontends set `cancelled` on disconnect).
+        for s in list(self._streams):
+            if s.req.cancelled:
+                self._drop(s)
+                self._fail(s.req, EngineError("request cancelled", 499))
+        # Transport flow control: streams whose frontend reports a
+        # backlogged response path sit out this wave (production is
+        # writer-paced) instead of flooding the stream queue until the
+        # slow-consumer shed kills them.  They stay live and rejoin
+        # the moment the writer drains — but a stream CONTINUOUSLY
+        # throttled past the timeout is holding an arena slot for a
+        # consumer that stopped reading; drop it (bounds slot
+        # occupancy the way the shed bounds queue memory).
+        live = []
+        now_mono = time.monotonic()
+        for s in list(self._streams):
+            if not self._has_budget(s):
+                continue
+            if _backpressured(s.req):
+                if s.throttled_since is None:
+                    s.throttled_since = now_mono
+                elif (now_mono - s.throttled_since
+                      > self.BACKPRESSURE_TIMEOUT_S):
+                    self._drop(s)
+                    self._fail(s.req, EngineError(
+                        "request cancelled (stream backpressured "
+                        f"beyond {self.BACKPRESSURE_TIMEOUT_S:.0f}s)",
+                        499))
+                continue
+            s.throttled_since = None
+            live.append(s)
+        return live
 
     def _run_warmup(self, req: _WarmupReq) -> None:
+        t0 = time.monotonic_ns()
         try:
             self._precompile()
         except Exception as exc:  # noqa: BLE001 — surface to the caller
             req.error = exc
         finally:
             req.done.set()
+            # Set-up, not serving: out of this iteration's gen.loop.
+            self._rec.exclude(time.monotonic_ns() - t0)
 
     def _has_budget(self, s: _Stream) -> bool:
         return (not s.dead and s.disp_tokens < s.max_new
@@ -525,7 +577,8 @@ class GenerativeScheduler(Scheduler):
                        for i in range(0, len(entries), cap)]
         for ci, (bucket, chunk) in enumerate(chunks):
             try:
-                self._prefill_chunk(bucket, chunk)
+                with self._rec.span[_sp.S_PREFILL_DISPATCH]:
+                    self._prefill_chunk(bucket, chunk)
             except EngineError as exc:
                 for req, *_ in chunk:
                     self._fail(req, exc)
@@ -569,7 +622,7 @@ class GenerativeScheduler(Scheduler):
                 rows + [self._dummy] * pad, np.int32)  # dummy row pads
             self.model._set_state(
                 f"generative prefill ({n} streams, prompt "
-                f"bucket={prompt_bucket})")
+                f"bucket={prompt_bucket})", _sp.STEP_PREFILL, prompt_bucket)
             try:
                 self._arena, tokens = self._prefill(
                     self.model._params, self._arena, rows_arr, ids_mat,
@@ -594,7 +647,8 @@ class GenerativeScheduler(Scheduler):
         # fetch, and everything discarded by an arena reset.
         self.stats.record_execution(n)
         self._inflight.append(_Inflight("prefill", streams, tokens,
-                                        t_disp=time.monotonic_ns()))
+                                        t_disp=time.monotonic_ns(),
+                                        depth=self._inflight_waves))
         self._inflight_waves += 1
 
     def _dispatch_wave(self, live: list) -> None:
@@ -612,6 +666,11 @@ class GenerativeScheduler(Scheduler):
     def _dispatch_one_wave(self, live: list) -> None:
         """Dispatch one decode wave; input tokens come from the arena's
         device-side slots, so no host value is needed."""
+        with self._rec.span[_sp.S_WAVE_STAGE]:
+            self._stage_and_dispatch(live)
+
+    def _stage_and_dispatch(self, live: list) -> None:
+        rec = self._rec
         bucket = next(b for b in self._wave_buckets if b >= len(live))
         pad = bucket - len(live)
         rows = np.asarray([s.row for s in live] + [self._dummy] * pad,
@@ -635,20 +694,29 @@ class GenerativeScheduler(Scheduler):
             k = 1
         self.model._set_state(
             f"generative decode wave ({len(live)} streams, bucket={bucket}"
-            + (f", chunk={k}" if k > 1 else "") + ")")
+            + (f", chunk={k}" if k > 1 else "") + ")",
+            _sp.STEP_DECODE_CHUNK if k > 1 else _sp.STEP_DECODE, bucket)
         try:
             sample = bool((temps > 0.0).any())
-            if k > 1:
-                self._arena, nxt = self._decode_chunk(
-                    self.model._params, self._arena, rows, lens,
-                    seeds, temps, top_ks, top_ps, sample, k)
-            else:
-                self._arena, nxt = self._decode(
-                    self.model._params, self._arena, rows, lens,
-                    seeds, temps, top_ks, top_ps, sample)
+            # The enqueue alone: where a full runtime queue blocks.
+            with rec.span[_sp.S_WAVE_DISPATCH]:
+                if k > 1:
+                    self._arena, nxt = self._decode_chunk(
+                        self.model._params, self._arena, rows, lens,
+                        seeds, temps, top_ks, top_ps, sample, k)
+                else:
+                    self._arena, nxt = self._decode(
+                        self.model._params, self._arena, rows, lens,
+                        seeds, temps, top_ks, top_ps, sample)
             nxt.copy_to_host_async()
         finally:
             self.model._clear_state()
+        # How deep the pipeline already was, and the valid context the
+        # live lanes read (each its length before this wave, one more for
+        # each scanned step): counted when the wave's tokens arrive.
+        rec.c[_sp.C_DISPATCHES] += 1
+        rec.c[_sp.C_INFLIGHT_WAVES] += self._inflight_waves
+        positions = k * int(lens.sum()) + len(live) * (k * (k - 1) // 2)
         for s in live:
             s.disp_len += k
             s.disp_tokens += k
@@ -659,7 +727,8 @@ class GenerativeScheduler(Scheduler):
         self._inflight.append(_Inflight("chunk" if k > 1 else "wave",
                                         live, nxt, waves=k,
                                         t_disp=time.monotonic_ns(),
-                                        bucket=bucket))
+                                        bucket=bucket,
+                                        positions=positions))
         self._inflight_waves += k
         if (bucket, k) not in self._wave_cost_captured:
             # Once per wave shape: static roofline numerator for this
@@ -669,7 +738,6 @@ class GenerativeScheduler(Scheduler):
             # the live post-dispatch arena with identical avals.
             self._wave_cost_captured.add((bucket, k))
             from client_tpu.observability import roofline
-            from client_tpu.observability.profiler import profiler
 
             args = (self.model._params, self._arena, rows, lens,
                     seeds, temps, top_ks, top_ps, sample)
@@ -684,19 +752,26 @@ class GenerativeScheduler(Scheduler):
         """Consume completed token fetches in dispatch order; emission,
         stop-token checks, and retirement happen here (a few waves behind
         dispatch)."""
+        rec = self._rec
+        c = rec.c
+        drained = False
+        decode_fetches = 0
         while self._inflight:
             head = self._inflight[0]
-            if not (force_one or self._inflight_waves > self._depth
-                    or head.tokens.is_ready()):
-                return
+            if not head.tokens.is_ready():
+                if not (force_one or self._inflight_waves > self._depth):
+                    break
+                c[_sp.C_FETCHES_FORCED] += 1  # taken to wait, not ready
             force_one = False
             self._inflight.popleft()
             self._inflight_waves -= head.waves
             try:
-                toks = np.asarray(head.tokens)
+                with rec.span[_sp.S_FETCH_WAIT]:
+                    toks = np.asarray(head.tokens)
             except Exception as exc:  # noqa: BLE001 — execution failed
                 self._reset_arena(exc)
-                return
+                break
+            drained = True
             # Wave timing: the device ran this dispatch from
             # max(its dispatch, the previous fetch) until now — pipelined
             # waves complete back to back, so the deltas between
@@ -705,10 +780,18 @@ class GenerativeScheduler(Scheduler):
             # staging; steady-state waves dominate the histogram).
             t_done = time.monotonic_ns()
             if head.kind != "prefill" and head.bucket:
-                from client_tpu.observability.profiler import profiler
-
+                decode_fetches += 1
                 busy_ns = max(
                     0, t_done - max(head.t_disp, self._last_fetch_ns))
+                # The device has run the wave: its lanes, padding and
+                # valid context are what decode_waves and the clients'
+                # token gaps of this moment are about.
+                lanes = len(head.streams) * head.waves
+                c[_sp.C_FETCHED_WAVES] += head.waves
+                c[_sp.C_FETCHED_LANES_LIVE] += lanes
+                c[_sp.C_FETCHED_LANES_PADDED] += \
+                    head.bucket * head.waves - lanes
+                c[_sp.C_FETCHED_POSITIONS_VALID] += head.positions
                 profiler().record_wave(
                     self.model.config.name, self.model.config.version,
                     bucket=head.bucket, chunk=head.waves,
@@ -732,27 +815,45 @@ class GenerativeScheduler(Scheduler):
                         padded=max(0, head.bucket - len(live)),
                         component="wave")
             self._last_fetch_ns = t_done
-            # A chunked fetch is K stacked waves [K, B]; emit them in wave
-            # order so stop/budget retirement lands mid-chunk exactly
-            # where a per-wave dispatch would have retired (surplus lanes
-            # past a retirement are junk and are discarded like any dead
-            # lane).
-            waves = toks if head.kind == "chunk" else toks[None]
-            for kk in range(waves.shape[0]):
-                for i, s in enumerate(head.streams):
-                    if s.dead:
-                        continue  # retired/cancelled lanes: discard junk
-                    tok = int(waves[kk, i])
-                    if head.kind != "prefill":
-                        s.f_len += 1
-                    if tok in s.stop:
-                        # Stop tokens terminate without being emitted.
-                        self._retire(s)
-                        continue
-                    self._emit_token(s, tok)
-                    if (s.emitted >= s.max_new
-                            or s.f_len + 1 >= self._max_seq):
-                        self._retire(s)
+            with rec.span[_sp.S_EMIT]:
+                self._emit_fetched(head, toks)
+        if drained:
+            c[_sp.C_DRAINS] += 1
+            if decode_fetches >= 2:
+                # Two waves' tokens leave back to back: the pairs a
+                # client sees as one long gap and one of nothing.
+                c[_sp.C_DRAINS_MULTI] += 1
+
+    def _emit_fetched(self, head: _Inflight, toks) -> None:
+        """Emit one fetch's tokens.  A chunked fetch is K stacked waves
+        [K, B]; emit them in wave order so stop/budget retirement lands
+        mid-chunk exactly where a per-wave dispatch would have retired
+        (surplus lanes past a retirement are junk and are discarded like
+        any dead lane)."""
+        c = self._rec.c
+        prefill = head.kind == "prefill"
+        waves = toks if head.kind == "chunk" else toks[None]
+        for kk in range(waves.shape[0]):
+            for i, s in enumerate(head.streams):
+                if s.dead:
+                    continue  # retired/cancelled lanes: discard junk
+                tok = int(waves[kk, i])
+                if prefill:
+                    # TTFT from inside: prefill dispatch to token 0.
+                    t = s.req.times.first_token = now_ns()
+                    c[_sp.C_FIRST_TOKENS] += 1
+                    c[_sp.C_FIRST_TOKEN_WAIT_NS] += t - head.t_disp
+                    c[_sp.C_FIRST_TOKEN_INFLIGHT_WAVES] += head.depth
+                else:
+                    s.f_len += 1
+                if tok in s.stop:
+                    # Stop tokens terminate without being emitted.
+                    self._retire(s)
+                    continue
+                self._emit_token(s, tok)
+                if (s.emitted >= s.max_new
+                        or s.f_len + 1 >= self._max_seq):
+                    self._retire(s)
 
     # -- stream lifecycle ------------------------------------------------------
 

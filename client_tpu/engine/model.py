@@ -31,7 +31,12 @@ from client_tpu.engine.backend_init import log as _log
 from client_tpu.engine.config import ModelConfig
 from client_tpu.engine.types import DeadlineExpired, EngineError, now_ns
 from client_tpu.observability import roofline as _roofline
-from client_tpu.observability.profiler import profiler as _profiler
+from client_tpu.observability import spans as _spans
+from client_tpu.observability.profiler import (
+    clear_compile_scope as _clear_compile_scope,
+    profiler as _profiler,
+    set_compile_scope as _set_compile_scope,
+)
 from client_tpu.protocol.dtypes import wire_to_np_dtype
 
 
@@ -169,7 +174,12 @@ class Model:
                 apply_fn = backend.make_apply()
             jittable = getattr(backend, "jittable", True)
             self._jitted = jit and jittable
-            self._apply = jax.jit(apply_fn) if self._jitted else apply_fn
+            # The XLA module is jit_apply whatever the backend called its
+            # function (a lambda would be jit__lambda_): the device-trace
+            # reduction finds the batcher's step by that name.
+            self._apply = (jax.jit(_spans.named_step(apply_fn,
+                                                     _spans.STEP_APPLY))
+                           if self._jitted else apply_fn)
         self._jax = jax
         # Live execution states for timeout diagnostics ("compiling" vs
         # "dead"), keyed by executing thread so concurrent instances don't
@@ -195,11 +205,19 @@ class Model:
         active = list(self._states.values())
         return "; ".join(active) if active else "idle"
 
-    def _set_state(self, s: str) -> None:
+    def _set_state(self, s: str, step: str = _spans.STEP_APPLY,
+                   bucket=0) -> None:
+        """Brackets (with :meth:`_clear_state`) every jit call site of this
+        model: ``s`` for timeout diagnostics, ``(step, bucket)`` for the
+        compile listener — a compilation inside the bracket is booked on
+        this model's scope."""
         self._states[threading.get_ident()] = s
+        _set_compile_scope(self.config.name, self.config.version, step,
+                           bucket or 0)
 
     def _clear_state(self) -> None:
         self._states.pop(threading.get_ident(), None)
+        _clear_compile_scope()
 
     # -- shape/validation helpers -------------------------------------------
 
@@ -325,8 +343,12 @@ class Model:
                 and batch_size is not None:
             pad_to = self.pick_bucket(batch_size)
 
+        # The three phases are exec.* annotations while a device trace is
+        # active (their aggregate times are the profiler's host_s/device_s).
+        ann = _spans.begin(_spans.EXEC_STAGE)
         try:
-            self._set_state(f"staging inputs (bucket={pad_to})")
+            self._set_state(f"staging inputs (bucket={pad_to})",
+                            bucket=pad_to)
             # Ragged backends own their padding: the generic row-pad below
             # would stretch every tensor's leading dim to the *lookup*
             # bucket, which is only right for the indices tensor. The hook
@@ -372,7 +394,9 @@ class Model:
             self._set_state(
                 f"compiling bucket={pad_to} (first call, XLA compile can "
                 "take 20-40s on TPU)" if first
-                else f"executing (bucket={pad_to})")
+                else f"executing (bucket={pad_to})", bucket=pad_to)
+            _spans.end(ann)
+            ann = _spans.begin(_spans.EXEC_RUN)
             outputs = (self._apply(self._params, staged)
                        if self._takes_params else self._apply(staged))
             if not isinstance(outputs, dict):
@@ -414,7 +438,9 @@ class Model:
                     cfg.name, cfg.version, pad_to, cost,
                     axis=cfg.padding_axis)
             phases.infer_end = now_ns()
-            self._set_state("fetching outputs")
+            self._set_state("fetching outputs", bucket=pad_to)
+            _spans.end(ann)
+            ann = _spans.begin(_spans.EXEC_FETCH)
             host: dict[str, np.ndarray] = {}
             for name, val in outputs.items():
                 if not fetch_outputs and isinstance(val, self._jax.Array):
@@ -452,6 +478,7 @@ class Model:
             # Always clear: a raise mid-compile must not leave a stale
             # "compiling" state to misdirect later timeout diagnostics.
             self._clear_state()
+            _spans.end(ann)
 
     def _fetch_host(self, val) -> np.ndarray:
         """Device→host fetch that works under multihost: an output sharded

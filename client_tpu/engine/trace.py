@@ -13,10 +13,19 @@ Settings vocabulary (mirrors Triton's trace_setting fields where they make
 sense): ``trace_level`` — ``["OFF"]`` or ``["TIMESTAMPS"]`` (device events);
 ``log_dir`` — where the trace is written (``trace_file`` accepted as an
 alias on update).
+
+The trace runs with the Python call tracer off (``python_tracer_level=0``):
+with it on every Python call of the host path is instrumented, a 2 s trace
+takes 22 s to stop and the traced seconds no longer resemble the untraced
+ones (PERF.md).  What the host was doing is in the trace all the same: while
+it is active the program's own ``gen.*``/``exec.*`` spans
+(:mod:`client_tpu.observability.spans`) are ``TraceAnnotation``s on the same
+clock as the device operations.
 """
 
 from __future__ import annotations
 
+from client_tpu.observability import spans as _spans
 from client_tpu.utils import lockdep
 
 from client_tpu.engine.types import EngineError
@@ -57,6 +66,7 @@ class TraceManager:
             if want_active is False and self._active:
                 import jax
 
+                _spans.set_trace_active(False)
                 try:
                     jax.profiler.stop_trace()
                 # tpulint: allow[swallowed-exception] already stopped
@@ -75,7 +85,10 @@ class TraceManager:
                 import jax
 
                 try:
-                    jax.profiler.start_trace(self._log_dir)
+                    options = jax.profiler.ProfileOptions()
+                    options.python_tracer_level = 0
+                    jax.profiler.start_trace(self._log_dir,
+                                             profiler_options=options)
                 except Exception as exc:
                     # A failed start must not leave _active=True (the
                     # next OFF would then call stop_trace on a profiler
@@ -90,6 +103,7 @@ class TraceManager:
                     raise EngineError(
                         f"failed to start device trace: {exc}", 500)
                 self._active = True
+                _spans.set_trace_active(True)
         return self.setting()
 
     def shutdown(self) -> None:
@@ -97,6 +111,7 @@ class TraceManager:
             if self._active:
                 import jax
 
+                _spans.set_trace_active(False)
                 try:
                     jax.profiler.stop_trace()
                 # tpulint: allow[swallowed-exception] best-effort on teardown

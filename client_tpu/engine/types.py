@@ -54,6 +54,11 @@ class RequestTimes:
     # the response cold (Server-Timing `compile` entry / server_compile_us
     # parameter) so clients can tell compile-hit outliers from queueing.
     compile_ns: int = 0
+    # Generative streams only: token 0 reached the worker (prefill fetched).
+    # TTFT inside the server = queue + (first_token - compute_start); the
+    # four phases above keep their meaning (compute_infer is the whole
+    # stream).
+    first_token: int = 0
 
     @property
     def queue_ns(self) -> int:

@@ -15,11 +15,24 @@ of a few dict operations per *batch* (not per request):
   ``tpu_padded_rows_total`` counter; the padding-waste estimate in
   :meth:`EfficiencyProfiler.snapshot` is ``device_s * padded/(real+padded)``
   — the device seconds spent multiplying zeros.
-- **Compile telemetry** — every first-call XLA trace of a bucket counts on
-  ``tpu_xla_compilations_total{model,version,bucket}``, observes
-  ``tpu_xla_compile_seconds``, and emits a ``compile.finished`` event into
-  the PR-4 journal. Cold executions are excluded from device-time
-  accumulation so one 30 s compile doesn't masquerade as load.
+- **Compile telemetry** — one ``jax.monitoring`` listener per process
+  (:func:`install_compile_listener`) hears every backend compilation of
+  every ``jax.jit`` (the batcher's apply, the generative scheduler's
+  prefill/decode, a backend's own helpers; a persistent-cache hit included)
+  and is the one feeder of ``tpu_xla_compilations_total{model,version,
+  bucket}``, ``tpu_xla_compile_seconds`` and the snapshot's ``compiles``
+  object; the labels come from the thread-local scope ``Model._set_state``
+  brackets each jit call site with (:func:`set_compile_scope`).
+  :meth:`EfficiencyProfiler.record_compile` (``Model.execute_timed``'s
+  first-call heuristic) keeps the per-bucket ``compile_s`` of the cost
+  table and the ``compile.finished`` journal event. Cold executions are
+  excluded from device-time accumulation so one 30 s compile doesn't
+  masquerade as load.
+- **The generative scheduler's clock** — loop-phase spans and lane counters
+  of every ``GenerativeScheduler`` worker (vocabulary and recorder in
+  :mod:`client_tpu.observability.spans`), committed per loop iteration and
+  served as each model's ``generative`` object; the launcher's set-up
+  phases as the snapshot's ``startup`` list.
 - **Device duty-cycle** — a sliding window (default 60 s,
   ``CLIENT_TPU_PROFILE_WINDOW_S``) of executable-busy intervals, sampled
   at scrape time into the ``tpu_device_duty_cycle`` gauge (busy device
@@ -42,7 +55,9 @@ from __future__ import annotations
 import os
 from client_tpu import config as envcfg
 from client_tpu.observability import roofline as _roofline
+from client_tpu.observability import spans as _spans
 from client_tpu.utils import lockdep
+import threading
 import time
 import weakref
 from collections import deque
@@ -166,6 +181,43 @@ class _WaveCost:
     cost_model: dict | None = None
 
 
+class _GenTotals:
+    """Committed spans and counters of one generative (model, version):
+    lists indexed like ``spans.GEN_SPANS`` / ``spans.GEN_COUNTERS``."""
+
+    __slots__ = ("ns", "n", "max", "c")
+
+    def __init__(self):
+        self.ns = [0] * len(_spans.GEN_SPANS)
+        self.n = [0] * len(_spans.GEN_SPANS)
+        self.max = [0] * len(_spans.GEN_SPANS)
+        self.c = [0] * len(_spans.GEN_COUNTERS)
+
+    def add(self, rec) -> None:
+        """Move one finished loop iteration out of ``rec`` (zeroing it)."""
+        ns, n, mx = rec.ns, rec.n, rec.max
+        for i, count in enumerate(n):
+            if count:
+                self.n[i] += count
+                self.ns[i] += ns[i]
+                if mx[i] > self.max[i]:
+                    self.max[i] = mx[i]
+                n[i] = ns[i] = mx[i] = 0
+        c = rec.c
+        for i, v in enumerate(c):
+            if v:
+                self.c[i] += v
+                c[i] = 0
+
+    def as_dict(self) -> dict:
+        return {
+            "spans": {name: {"count": self.n[i], "total_ns": self.ns[i],
+                             "max_ns": self.max[i]}
+                      for i, name in enumerate(_spans.GEN_SPANS)},
+            "counters": dict(zip(_spans.GEN_COUNTERS, self.c)),
+        }
+
+
 class _Bound:
     """One engine registry's instrument handles (see bind_metrics)."""
 
@@ -186,11 +238,13 @@ class _Bound:
             ("model", "version", "bucket"))
         self.compilations = registry.counter(
             "tpu_xla_compilations_total",
-            "XLA compilations (first call per model/bucket signature)",
+            "XLA backend compilations of every jax.jit, a persistent-cache "
+            "hit included (labels: the jit call site's scope, empty "
+            "outside any)",
             ("model", "version", "bucket"))
         self.compile_seconds = registry.histogram(
             "tpu_xla_compile_seconds",
-            "XLA compile duration per first-call bucket trace (seconds)",
+            "Duration of each XLA backend compilation (seconds)",
             ("model", "version"), buckets=COMPILE_SECONDS_BUCKETS)
         self.device_seconds = registry.counter(
             "tpu_device_seconds_total",
@@ -242,6 +296,18 @@ class EfficiencyProfiler:
         # (end_mono_ns, device_ns) of warm executions inside the window.
         self._busy: deque[tuple[int, int]] = deque()
         self._bound: dict[int, _Bound] = {}
+        # (model, version) -> committed generative spans and counters
+        # (lists indexed like spans.GEN_SPANS / spans.GEN_COUNTERS).
+        self._gen: dict[tuple[str, str], _GenTotals] = {}
+        # Every backend compilation the jax.monitoring listener heard.
+        self._compile_count = 0
+        self._compile_s = 0.0
+        self._cache_misses = 0
+        self._compile_scopes: dict[str, list] = {}  # scope -> [n, seconds]
+        # Set-up phases: (name, start mono ns, end mono ns), oldest first;
+        # relative to the launcher's entry once that is marked.
+        self._startup: list[tuple[str, int, int]] = []
+        self._startup_t0: int | None = None
 
     # -- metric binding ------------------------------------------------------
 
@@ -326,8 +392,12 @@ class EfficiencyProfiler:
     def record_compile(self, model: str, version, bucket: int | None,
                        compile_ns: int, trace_id: str | None = None,
                        axis: str = "rows") -> None:
-        """A first-call XLA trace finished: count it, observe its
-        duration, and journal ``compile.finished``. ``axis`` tags the
+        """A first-call XLA trace finished (``Model.execute_timed``'s
+        heuristic: trace + compile + first run of a new input signature):
+        book it on the bucket's ``compilations``/``compile_s`` and journal
+        ``compile.finished``.  The process-wide compile counter is not fed
+        here but by :meth:`record_backend_compile`, which hears every jit.
+        ``axis`` tags the
         bucket's padded unit up front — warmup/tuner compiles are
         synthetic (no ``record_execution`` follows), so without it a
         warm-compiled lookup bucket would sit mislabelled "rows" until
@@ -340,11 +410,6 @@ class EfficiencyProfiler:
             c.axis = axis
             c.compile_count += 1
             c.compile_ns += max(0, compile_ns)
-        for b in self._bindings():
-            b.compilations.inc(model=key[0], version=key[1],
-                               bucket=str(key[2]))
-            b.compile_seconds.observe(compile_ns / 1e9,
-                                      model=key[0], version=key[1])
         # Lazy import: observability.metrics users must not pull in the
         # journal (and its env wiring) just by importing this module.
         from client_tpu.observability.events import journal
@@ -352,6 +417,63 @@ class EfficiencyProfiler:
         journal().emit("compile", "finished", model=key[0],
                        version=key[1], trace_id=trace_id,
                        bucket=key[2], compile_s=round(compile_ns / 1e9, 3))
+
+    def record_backend_compile(self, seconds: float,
+                               scope: tuple | None = None) -> None:
+        """One XLA backend compilation, as ``jax.monitoring`` reported it
+        (:func:`install_compile_listener`); ``scope`` is the compiling
+        thread's ``(model, version, step, bucket)`` or None outside any jit
+        call site the program brackets."""
+        model, version, step, bucket = scope or ("", "", "", "")
+        key = f"{model}:{version}:{step}:{bucket}" if scope else ""
+        with self._lock:
+            self._compile_count += 1
+            self._compile_s += seconds
+            row = self._compile_scopes.setdefault(key, [0, 0.0])
+            row[0] += 1
+            row[1] += seconds
+        for b in self._bindings():
+            b.compilations.inc(model=str(model), version=str(version),
+                               bucket=str(bucket))
+            b.compile_seconds.observe(seconds, model=str(model),
+                                      version=str(version))
+
+    def record_cache_miss(self) -> None:
+        with self._lock:
+            self._cache_misses += 1
+
+    def compile_totals(self) -> dict:
+        """The snapshot's ``compiles`` object: every backend compilation
+        since process start, and by the scope it happened in."""
+        with self._lock:
+            return {
+                "count": self._compile_count,
+                "seconds": self._compile_s,
+                "cache_misses": self._cache_misses,
+                "by_scope": {k: {"count": n, "seconds": sec} for k, (n, sec)
+                             in sorted(self._compile_scopes.items())},
+            }
+
+    def commit_generative(self, model: str, version, rec) -> None:
+        """One finished loop iteration of a generative worker
+        (``spans.GenRecorder.end_loop``): moved into the (model, version)
+        totals, which outlive the worker."""
+        key = (str(model), str(version))
+        with self._lock:
+            tot = self._gen.get(key)
+            if tot is None:
+                tot = self._gen[key] = _GenTotals()
+            tot.add(rec)
+
+    def startup_entry(self) -> None:
+        """The launcher's entry: set-up spans are reported relative to it."""
+        self._startup_t0 = self._now()
+
+    def record_startup(self, name: str, start_ns: int, end_ns: int) -> None:
+        """One set-up phase (``spans.STARTUP_*``), monotonic ns."""
+        with self._lock:
+            if len(self._startup) < 256:  # reloads must not grow it forever
+                self._startup.append((name, int(start_ns), int(end_ns)))
 
     def record_cost_model(self, model: str, version, bucket: int | None,
                           cost: dict | None, axis: str = "rows") -> None:
@@ -536,6 +658,8 @@ class EfficiencyProfiler:
                 (k, (w.waves, w.device_ns, w.wave_ns_ewma,
                      sorted(w.recent), w.dispatches, w.cost_model))
                 for k, w in self._waves.items())
+            gen_items = sorted((k, g.as_dict()) for k, g in self._gen.items())
+            startup = list(self._startup)
         models: dict[str, dict] = {}
         # Per-model roofline accumulators: [flops, bytes, wasted_flops,
         # covered_device_s] summed over buckets+waves with cost models.
@@ -635,6 +759,10 @@ class EfficiencyProfiler:
                 "wave_ms_p99": round(pct(0.99) / 1e6, 3),
                 "roofline": rl,
             })
+        for (mname, version), gen in gen_items:
+            if model and mname != model:
+                continue
+            model_entry(mname, version)["generative"] = gen
         for mkey, entry in models.items():
             entry["device_s"] = round(entry["device_s"], 6)
             entry["host_s"] = round(entry["host_s"], 6)
@@ -650,6 +778,8 @@ class EfficiencyProfiler:
             "window_s": self.window_s,
             "duty_cycle": round(self.duty_cycle(), 6),
             "roofline": ctx,
+            "compiles": self.compile_totals(),
+            "startup": _startup_spans(startup, self._startup_t0),
             "models": models,
         }
 
@@ -658,6 +788,7 @@ class EfficiencyProfiler:
         with self._lock:
             self._costs.clear()
             self._waves.clear()
+            self._gen.clear()
             self._busy.clear()
             self._t0 = self._now()
 
@@ -697,6 +828,17 @@ def _model_roofline(agg: list[float], device_s: float, peaks) -> dict:
     if peaks and peaks.bytes_per_s:
         out["mbu"] = round(achieved_b / peaks.bytes_per_s, 6)
     return out
+
+
+def _startup_spans(spans: list, t0: int | None) -> list[dict]:
+    """``(name, start_s, end_s)`` relative to the launcher's entry (to the
+    first span's start where no launcher marked one: an embedded engine).
+    A phase that ran before the entry (a wrapper that initialised the
+    backend first) starts below zero."""
+    if t0 is None:
+        t0 = min((s for _, s, _ in spans), default=0)
+    return [{"name": name, "start_s": (s - t0) / 1e9, "end_s": (e - t0) / 1e9}
+            for name, s, e in spans]
 
 
 def _suggest_bucket_tweak(buckets: list[dict]) -> dict | None:
@@ -795,3 +937,49 @@ def reset_profiler() -> None:
     global _default
     with _default_lock:
         _default = None
+
+
+# -- the compile listener -------------------------------------------------------
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+_scope = threading.local()
+_listener_installed = False
+
+
+def set_compile_scope(model: str, version, step: str, bucket) -> None:
+    """What the calling thread is about to run through a ``jax.jit``: read
+    by the compile listener if that call compiles.  ``Model._set_state``
+    sets it at every jit call site, ``_clear_state`` clears it."""
+    _scope.value = (model, version, step, bucket)
+
+
+def clear_compile_scope() -> None:
+    _scope.value = None
+
+
+def _on_compile_duration(event: str, duration_secs: float, **_) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        profiler().record_backend_compile(
+            duration_secs, getattr(_scope, "value", None))
+
+
+def _on_compile_event(event: str, **_) -> None:
+    if event == CACHE_MISS_EVENT:
+        profiler().record_cache_miss()
+
+
+def install_compile_listener() -> None:
+    """Register the process's one ``jax.monitoring`` listener (idempotent).
+    It feeds whichever profiler is the global one when a compile happens,
+    so ``reset_profiler()`` needs no re-registration."""
+    global _listener_installed
+    with _default_lock:
+        if _listener_installed:
+            return
+        _listener_installed = True
+    import jax.monitoring as monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    monitoring.register_event_listener(_on_compile_event)

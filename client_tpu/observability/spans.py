@@ -1,0 +1,215 @@
+"""The program's own span vocabulary, and the recorder a worker thread
+writes it with.
+
+Three families of names, constants here and nowhere else:
+
+- ``gen.*`` — the phases of one ``GenerativeScheduler._worker_loop``
+  iteration.  Always on: every span is (count, total ns, max ns) per model,
+  taken with ``time.monotonic_ns()`` on the worker thread, committed into the
+  :class:`~client_tpu.observability.profiler.EfficiencyProfiler` when the
+  iteration ends and served under each generative model's entry of
+  ``GET /v2/profile`` (``"generative": {"spans", "counters"}``).  ``gen.loop``
+  is the whole iteration (inclusive); every other span is exclusive of the
+  spans opened inside it (``gen.admit`` is ``_admit_batch`` less its
+  ``gen.prefill_dispatch``, ``gen.wave_stage`` is ``_dispatch_one_wave`` less
+  its ``gen.wave_dispatch``), so the children partition the iteration and
+  ``gen.loop`` less their sum is the loop's own bookkeeping.
+- ``exec.*`` — the batcher's three phases inside ``Model.execute_timed``.
+  Their aggregate times already live in the profiler's bucket table
+  (``host_s``/``device_s``); the names exist for the device trace.
+- ``startup.*`` — the launcher's set-up phases (``/v2/profile`` ``startup``).
+
+While a device trace is active (``TraceManager`` flips :func:`set_trace_active`)
+each ``gen.*``/``exec.*`` span is also a ``jax.profiler.TraceAnnotation`` of
+the same name, so the ``.xplane.pb`` holds host spans and device operations on
+one clock.  Off the trace no annotation object is built: the helper reads one
+module-level boolean.
+
+The jitted steps' names are here too: ``jax.jit`` names an XLA module
+``jit_<fn.__name__>``, and the benchmark's trace reduction keys on
+``jit_decode``/``jit_prefill``/``jit_apply`` — :func:`named_step` makes that a
+contract instead of an accident of what a backend calls its inner function.
+"""
+
+from __future__ import annotations
+
+import time
+
+# -- the generative worker loop ------------------------------------------------
+
+GEN_LOOP = "gen.loop"
+GEN_IDLE = "gen.idle"
+GEN_ADMIT = "gen.admit"
+GEN_PREFILL_DISPATCH = "gen.prefill_dispatch"
+GEN_SWEEP = "gen.sweep"
+GEN_WAVE_STAGE = "gen.wave_stage"
+GEN_WAVE_DISPATCH = "gen.wave_dispatch"
+GEN_FETCH_WAIT = "gen.fetch_wait"
+GEN_EMIT = "gen.emit"
+
+GEN_SPANS = (GEN_LOOP, GEN_IDLE, GEN_ADMIT, GEN_PREFILL_DISPATCH, GEN_SWEEP,
+             GEN_WAVE_STAGE, GEN_WAVE_DISPATCH, GEN_FETCH_WAIT, GEN_EMIT)
+(S_LOOP, S_IDLE, S_ADMIT, S_PREFILL_DISPATCH, S_SWEEP, S_WAVE_STAGE,
+ S_WAVE_DISPATCH, S_FETCH_WAIT, S_EMIT) = range(len(GEN_SPANS))
+
+# Cumulative, monotone: two snapshots difference exactly.  Every counter has
+# a reader (docs/OBSERVABILITY.md, the inventory): a per-layer metric of
+# BENCHMARK.json or a documented operator's use.  Dispatch runs up to a
+# pipeline's depth ahead of the device, so lanes and positions are counted
+# when a wave's tokens arrive (``fetched_*``): the moment ``decode_waves`` and
+# the clients' token gaps are about, not what the device will run many waves
+# later.  What a span's count already says has no counter (prefill dispatches
+# = ``gen.prefill_dispatch`` count, fetches = ``gen.fetch_wait`` count).
+GEN_COUNTERS = (
+    # per decode dispatch: how many, and the waves already in flight at each
+    "dispatches", "inflight_waves",
+    # per decode fetch (a K-chunk fetch counts K waves)
+    "fetched_waves", "fetched_lanes_live", "fetched_lanes_padded",
+    "fetched_positions_valid",
+    # per drain (a ``_drain_fetches`` call that took at least one fetch)
+    "drains", "drains_multi", "fetches_forced",
+    # per first token: prefill dispatch to the emit of token 0, and the
+    # waves in flight when that prefill was dispatched
+    "first_tokens", "first_token_wait_ns", "first_token_inflight_waves",
+)
+(C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
+ C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
+ C_FETCHES_FORCED, C_FIRST_TOKENS, C_FIRST_TOKEN_WAIT_NS,
+ C_FIRST_TOKEN_INFLIGHT_WAVES) = range(len(GEN_COUNTERS))
+
+# -- Model.execute_timed (trace annotations only) --------------------------------
+
+EXEC_STAGE = "exec.stage"
+EXEC_RUN = "exec.run"
+EXEC_FETCH = "exec.fetch"
+
+# -- set-up phases ------------------------------------------------------------
+
+STARTUP_BACKEND_INIT = "startup.backend_init"
+STARTUP_MODEL_LOAD = "startup.model_load:"   # + model name
+STARTUP_WARMUP = "startup.warmup:"           # + model name
+STARTUP_FRONTENDS = "startup.frontends"
+
+# -- jitted steps ---------------------------------------------------------------
+
+STEP_PREFILL = "prefill"
+STEP_DECODE = "decode"
+STEP_DECODE_CHUNK = "decode_chunk"
+STEP_APPLY = "apply"
+
+
+def named_step(fn, name: str):
+    """``fn`` under the stable name ``name``: what ``jax.jit`` sees, so the
+    XLA module is ``jit_<name>`` whatever the backend called its function."""
+
+    def step(*args):
+        return fn(*args)
+
+    step.__name__ = step.__qualname__ = name
+    step.__doc__ = getattr(fn, "__doc__", None)
+    return step
+
+
+# -- the device trace's clock ----------------------------------------------------
+
+_trace_active = False
+
+
+def set_trace_active(on: bool) -> None:
+    """Flipped by ``TraceManager`` around ``jax.profiler`` start/stop."""
+    global _trace_active
+    _trace_active = bool(on)
+
+
+def trace_active() -> bool:
+    return _trace_active
+
+
+def _annotate(name: str):
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+def begin(name: str):
+    """Open a trace-only span: the entered annotation while a device trace
+    is active, ``None`` (nothing built) otherwise.  Close with :func:`end`."""
+    return _annotate(name) if _trace_active else None
+
+
+def end(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
+class _Span:
+    """One name of a recorder, reusable as a context manager (a worker
+    thread opens a given name at most once at a time)."""
+
+    __slots__ = ("rec", "idx", "name", "inclusive", "t0", "child", "parent",
+                 "ann")
+
+    def __init__(self, rec, idx: int, name: str, inclusive: bool):
+        self.rec, self.idx, self.name = rec, idx, name
+        self.inclusive = inclusive
+        self.t0 = self.child = 0
+        self.parent = self.ann = None
+
+    def __enter__(self):
+        rec = self.rec
+        self.parent, rec.open = rec.open, self
+        self.child = 0
+        if _trace_active:
+            self.ann = _annotate(self.name)
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.monotonic_ns() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
+        rec, i = self.rec, self.idx
+        own = dt if self.inclusive else dt - self.child
+        rec.ns[i] += own
+        rec.n[i] += 1
+        if own > rec.max[i]:
+            rec.max[i] = own
+        parent = rec.open = self.parent
+        if parent is not None:
+            parent.child += dt
+        return False
+
+
+class GenRecorder:
+    """One generative worker's spans and counters for the iteration in
+    progress.  Single writer (the worker thread), no lock on the hot path;
+    :meth:`end_loop` hands the iteration to ``commit`` (the profiler adds it
+    to the model's totals under its lock and this buffer is zeroed), so a
+    snapshot only ever sees whole iterations: the children's totals never
+    exceed ``gen.loop``'s, at any instant."""
+
+    def __init__(self, commit):
+        self._commit = commit
+        self.ns = [0] * len(GEN_SPANS)
+        self.n = [0] * len(GEN_SPANS)
+        self.max = [0] * len(GEN_SPANS)
+        self.c = [0] * len(GEN_COUNTERS)   # rec.c[C_DISPATCHES] += 1
+        self.open = None
+        self.span = tuple(_Span(self, i, name, inclusive=(i == S_LOOP))
+                          for i, name in enumerate(GEN_SPANS))
+
+    def begin_loop(self) -> None:
+        self.open = None
+        self.span[S_LOOP].__enter__()
+
+    def exclude(self, ns: int) -> None:
+        """Take ``ns`` just spent (a warm-up run on the worker thread) out
+        of the iteration: it is set-up, not serving."""
+        self.span[S_LOOP].t0 += ns
+
+    def end_loop(self) -> None:
+        self.span[S_LOOP].__exit__(None, None, None)
+        self._commit(self)

@@ -126,6 +126,12 @@ def build_request_trace(ctx: TraceContext, model_name: str, request_id: str,
     for name, s, e in bounds:
         if s and e >= s:
             spans.append(Span(name, s, e))
+    # A generative stream's wait for its first token, beside (not among)
+    # the four PHASES: queue + prefill = the server's share of TTFT.
+    first_token = getattr(times, "first_token", 0)
+    if first_token and times.compute_start \
+            and first_token >= times.compute_start:
+        spans.append(Span("prefill", times.compute_start, first_token))
     return RequestTrace(
         trace_id=ctx.trace_id, span_id=ctx.span_id,
         parent_span_id=ctx.parent_span_id, model_name=model_name,

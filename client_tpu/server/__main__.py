@@ -41,6 +41,13 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
 
+    # Set-up phases (backend init, model load, warm-up, frontends) are
+    # spans of /v2/profile "startup", relative to this moment.
+    from client_tpu.observability import spans
+    from client_tpu.observability.profiler import profiler
+
+    profiler().startup_entry()
+
     # Opt-in structured logging (CLIENT_TPU_LOG=json): JSON lines on
     # stderr, with the event journal mirrored alongside normal log records.
     from client_tpu.observability.events import configure_logging
@@ -91,6 +98,7 @@ def main(argv: list[str] | None = None) -> int:
         engine.shutdown()
         return 1
 
+    t_frontends = time.monotonic_ns()
     servers = []
     http_servers = []
     grpc_servers = []
@@ -122,6 +130,8 @@ def main(argv: list[str] | None = None) -> int:
     drained = install_sigterm_handler(
         engine, http_servers=http_servers, grpc_servers=grpc_servers,
         deadline_s=args.drain_deadline)
+    profiler().record_startup(spans.STARTUP_FRONTENDS, t_frontends,
+                              time.monotonic_ns())
     for kind, url in servers:
         print(f"serving {kind} at {url}", file=sys.stderr, flush=True)
     try:

@@ -1,0 +1,260 @@
+"""The benchmark's readers of the program's own clock
+(``benchmark/progspans.py`` and one reader per metric under
+``benchmark/metrics/``) on hand-made ``/v2/profile`` snapshots, and
+``benchmark/hostgaps.py`` on a hand-made trace and on the piece of a v5e trace
+recorded by PR 24's traced run."""
+
+import importlib.util
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+sys.path.insert(0, BENCH)
+
+import hostgaps  # noqa: E402
+import progspans  # noqa: E402
+
+NEW_METRICS = [
+    "sched_host_ms_per_wave.itl", "sched_device_wait_share.itl",
+    "sched_dispatch_ms_mean.itl", "drain_multi_wave_share.obs",
+    "wave_live_lanes_mean.itl", "wave_padded_lane_share.itl",
+    "arena_live_share.itl", "first_token_wait_ms_mean.obs",
+    "xla_compiles_in_window.itl", "startup_backend_init_s.setup",
+    "startup_model_load_s.setup", "startup_compile_s.setup",
+]
+
+
+def reader(name):
+    """The way ``run.py`` finds a reader: the file named by the metric,
+    or by the metric less its suffix."""
+    stem = name if os.path.exists(
+        os.path.join(BENCH, "metrics", name + ".py")) \
+        else name.rsplit(".", 1)[0]
+    path = os.path.join(BENCH, "metrics", stem + ".py")
+    spec = importlib.util.spec_from_file_location("metric_" + stem, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def snap(spans_ms, counters, compiles=(0, 0.0), startup=()):
+    """A harness snapshot whose one generative model has these span totals
+    (name -> (count, ms)) and counters."""
+    return {"t": 0.0, "stats": {}, "profile": {
+        "compiles": {"count": compiles[0], "seconds": compiles[1],
+                     "cache_misses": 0, "by_scope": {}},
+        "startup": [{"name": n, "start_s": a, "end_s": b}
+                    for n, a, b in startup],
+        "models": {"gpt:1": {"model": "gpt", "version": "1", "generative": {
+            "spans": {k: {"count": n, "total_ns": int(ms * 1e6),
+                          "max_ns": int(ms * 1e6)}
+                      for k, (n, ms) in spans_ms.items()},
+            "counters": dict(counters)}}}}}
+
+
+BEFORE = snap(
+    {"gen.loop": (100, 50_000), "gen.idle": (1, 1000), "gen.admit": (5, 10),
+     "gen.prefill_dispatch": (5, 20), "gen.sweep": (100, 5),
+     "gen.wave_stage": (100, 30), "gen.wave_dispatch": (100, 100),
+     "gen.fetch_wait": (105, 48_000), "gen.emit": (105, 200)},
+    {"dispatches": 100, "fetched_waves": 70, "fetched_lanes_live": 3000,
+     "fetched_lanes_padded": 1000, "fetched_positions_valid": 2_000_000,
+     "drains": 90,
+     "drains_multi": 10, "first_tokens": 30, "first_token_wait_ns": 3e11},
+    compiles=(14, 31.5),
+    startup=[("startup.backend_init", -9.0, -0.5),
+             ("startup.model_load:gpt", 0.25, 4.25),
+             ("startup.model_load:other", 4.25, 5.25),
+             ("startup.warmup:gpt", 5.25, 40.0),
+             ("startup.frontends", 40.0, 40.5)])
+AFTER = snap(
+    {"gen.loop": (122, 70_000), "gen.idle": (1, 1000), "gen.admit": (8, 16),
+     "gen.prefill_dispatch": (8, 36), "gen.sweep": (122, 6),
+     "gen.wave_stage": (122, 41), "gen.wave_dispatch": (122, 1100),
+     "gen.fetch_wait": (130, 66_500), "gen.emit": (130, 260)},
+    {"dispatches": 122, "fetched_waves": 92, "fetched_lanes_live": 3858,
+     "fetched_lanes_padded": 1150, "fetched_positions_valid": 2_700_000,
+     "drains": 110,
+     "drains_multi": 12, "first_tokens": 34, "first_token_wait_ns": 3.6e11},
+    compiles=(14, 31.5))
+CTX = {"snap_before": BEFORE, "snap_after": AFTER,
+       "cfg": {"serve": {"kwargs": {"max_streams": 48}}},
+       "traffic": {"max_model_len": 1024}}
+
+# Worked out by hand from BEFORE/AFTER above.
+EXPECTED = {
+    # (20000 - 18500 - 0 - 1000 - 16) ms over 22 dispatches
+    "sched_host_ms_per_wave.itl": 484 / 22,
+    # fetch 18500 + dispatch 1000 + prefill dispatch 16 of 20000 ms
+    "sched_device_wait_share.itl": 100 * 19_516 / 20_000,
+    "sched_dispatch_ms_mean.itl": 1000 / 22,
+    "drain_multi_wave_share.obs": 100 * 2 / 20,
+    "wave_live_lanes_mean.itl": 858 / 22,
+    "wave_padded_lane_share.itl": 100 * 150 / (858 + 150),
+    "arena_live_share.itl": 100 * 700_000 / (22 * 48 * 1024),
+    "first_token_wait_ms_mean.obs": 0.6e11 / 4 / 1e6,
+    "xla_compiles_in_window.itl": 0.0,
+    "startup_backend_init_s.setup": 8.5,
+    "startup_model_load_s.setup": 5.0,
+    "startup_compile_s.setup": 31.5,
+}
+
+
+class TestReaders:
+    @pytest.mark.parametrize("name", NEW_METRICS)
+    def test_value_on_a_hand_made_window(self, name):
+        assert reader(name)(CTX) == pytest.approx(EXPECTED[name])
+
+    @pytest.mark.parametrize("name", NEW_METRICS)
+    def test_nothing_to_read_on_the_parent(self, name):
+        """A program without the spans (the parent commit) serves a
+        profile with none of the three objects: the reader returns nothing
+        and does not raise."""
+        old = {"t": 0.0, "stats": {}, "profile": {"models": {"gpt:1": {
+            "decode_waves": []}}}}
+        ctx = dict(CTX, snap_before=old, snap_after=old)
+        assert reader(name)(ctx) is None
+        assert reader(name)(dict(ctx, snap_before=None,
+                                 snap_after=None)) is None
+
+    def test_every_new_metric_is_in_the_manifest_with_a_reader(self):
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+        by = {m["name"]: m for m in manifest["per_layer"]}
+        for name in NEW_METRICS:
+            m = by[name]
+            assert m["workloads"] == ["gpt2_small.chat"]
+            assert m["moves"] == ("setup_s" if name.endswith(".setup")
+                                  else "itl_mean_ms")
+            assert callable(reader(name))
+        # appended, nothing before them moved
+        assert [m["name"] for m in manifest["per_layer"]][-len(NEW_METRICS):] \
+            == NEW_METRICS
+
+    def test_every_counter_a_reader_asks_for_is_in_the_vocabulary(self):
+        """The readers ask the snapshot for counters by name: each name is
+        one the program serves (a misspelt one would read as 0)."""
+        import re
+
+        from client_tpu.observability import spans
+
+        asked = set()
+        for name in NEW_METRICS:
+            stem = name.rsplit(".", 1)[0]
+            with open(os.path.join(BENCH, "metrics", stem + ".py")) as f:
+                src = f.read()
+            asked |= set(re.findall(r'"((?:fetched_|first_|drains|dispatches)'
+                                    r'[a-z_]*)"', src))
+            for span in re.findall(r'"(gen\.[a-z_]+)"', src):
+                assert span in spans.GEN_SPANS, (name, span)
+        assert asked and asked <= set(spans.GEN_COUNTERS), asked
+
+    def test_window_sums_models_and_keeps_the_run_max(self):
+        two = json.loads(json.dumps(AFTER))
+        two["profile"]["models"]["b:1"] = json.loads(json.dumps(
+            AFTER["profile"]["models"]["gpt:1"]))
+        g = progspans.generative(two)
+        assert g["counters"]["dispatches"] == 244
+        assert g["spans"]["gen.loop"]["count"] == 244
+        w = progspans.window({"snap_before": BEFORE, "snap_after": AFTER})
+        assert w["counters"]["dispatches"] == 22
+        assert w["spans"]["gen.fetch_wait"]["total_ns"] == 18_500_000_000
+        assert w["spans"]["gen.fetch_wait"]["max_ns"] == 66_500_000_000
+
+    def test_zero_denominators_read_as_nothing(self):
+        ctx = dict(CTX, snap_after=BEFORE)  # an empty window
+        for name in NEW_METRICS[:8]:
+            assert reader(name)(ctx) is None
+
+
+# Device: two programs with a gap of 3 us between them, then a gap of 2 us
+# to a third.  Host thread: gen.loop over everything; inside it a
+# gen.fetch_wait covering the first gap's first 2 us and a gen.wave_stage
+# with a gen.wave_dispatch nested in it over part of the second gap; an
+# exec.run on another thread that no gap touches.
+HAND_MADE = """
+planes {
+  id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Modules" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 4000000 }
+    events { metadata_id: 1 offset_ps: 7000000 duration_ps: 2000000 }
+    events { metadata_id: 2 offset_ps: 11000000 duration_ps: 1000000 } }
+  lines { id: 2 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 3 offset_ps: 0 duration_ps: 4000000 }
+    events { metadata_id: 3 offset_ps: 7000000 duration_ps: 2000000 }
+    events { metadata_id: 3 offset_ps: 11000000 duration_ps: 1000000 } }
+  event_metadata { key: 1 value { id: 1 name: "jit_decode(123)" } }
+  event_metadata { key: 2 value { id: 2 name: "jit_prefill(77)" } }
+  event_metadata { key: 3 value { id: 3 name: "fusion.1" } }
+}
+planes { id: 2 name: "/host:CPU"
+  lines { id: 1 name: "worker" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 12000000 }
+    events { metadata_id: 2 offset_ps: 3500000 duration_ps: 2500000 }
+    events { metadata_id: 3 offset_ps: 9200000 duration_ps: 1500000 }
+    events { metadata_id: 4 offset_ps: 9500000 duration_ps: 1000000 }
+    events { metadata_id: 4 offset_ps: 500000 duration_ps: 200000 } }
+  lines { id: 2 name: "batcher" timestamp_ns: 1000
+    events { metadata_id: 5 offset_ps: 0 duration_ps: 1000000 }
+    events { metadata_id: 6 offset_ps: 100000 duration_ps: 100000 } }
+  event_metadata { key: 1 value { id: 1 name: "gen.loop" } }
+  event_metadata { key: 2 value { id: 2 name: "gen.fetch_wait" } }
+  event_metadata { key: 3 value { id: 3 name: "gen.wave_stage" } }
+  event_metadata { key: 4 value { id: 4 name: "gen.wave_dispatch" } }
+  event_metadata { key: 5 value { id: 5 name: "exec.run" } }
+  event_metadata { key: 6 value { id: 6 name: "$python call" } } }
+"""
+
+
+def _load_text(text):
+    from jax.profiler import ProfileData
+
+    return ProfileData.from_serialized_xspace(
+        ProfileData.text_proto_to_serialized_xspace(text))
+
+
+class TestHostGaps:
+    def test_hand_made_trace(self):
+        out = hostgaps.reduce_gaps(_load_text(HAND_MADE))
+        assert out["gaps"] == 2
+        assert out["idle_s"] == pytest.approx(5e-6)
+        by = {name: (seconds, n) for name, seconds, n in out["by_span"]}
+        # gap 1 = [5000, 8000) ns: fetch_wait covers to 7000, then the loop
+        # gap 2 = [10000, 12000): loop to 10200, stage to 10500, dispatch
+        # to 11500, stage again to 11700, loop to 12000
+        assert by["gen.fetch_wait"] == (pytest.approx(2.0e-6), 1)
+        assert by["gen.wave_dispatch"] == (pytest.approx(1.0e-6), 1)
+        assert by["gen.wave_stage"] == (pytest.approx(0.5e-6), 0)
+        assert by["gen.loop"] == (pytest.approx(1.5e-6), 0)
+        assert "exec.run" not in by and hostgaps.NO_SPAN not in by
+        assert sum(s for s, _ in by.values()) == pytest.approx(5e-6)
+        assert out["host_spans"] == {
+            "gen.loop": 1, "gen.fetch_wait": 1, "gen.wave_stage": 1,
+            "gen.wave_dispatch": 2, "exec.run": 1}
+
+    def test_trace_without_annotations_is_all_no_span(self):
+        device_only = HAND_MADE[:HAND_MADE.index('planes { id: 2')]
+        out = hostgaps.reduce_gaps(_load_text(device_only))
+        assert out["by_span"] == [[hostgaps.NO_SPAN,
+                                   pytest.approx(5e-6), 2]]
+
+    def test_trace_without_a_device_plane_has_no_gaps(self):
+        host_only = HAND_MADE[HAND_MADE.index('planes { id: 2'):]
+        out = hostgaps.reduce_gaps(_load_text(host_only))
+        assert out["gaps"] == 0 and out["by_span"] == []
+
+    def test_recorded_v5e_trace(self):
+        """The piece of PR 24's traced chip run kept under
+        ``benchmark/testdata/``: figures recomputed by brute force."""
+        sys.path.insert(0, os.path.join(BENCH, "testdata"))
+        import check_hostgaps
+
+        assert check_hostgaps.main() == 0
+
+    def test_idle_gaps_ignore_overlapping_programs(self):
+        mods = [("a", 0, 10), ("b", 5, 8), ("c", 12, 20), ("d", 20, 25)]
+        assert hostgaps.idle_gaps(mods) == [(10, 12)]

@@ -552,8 +552,11 @@ class TestEventsEndpointE2e:
         eng = stack["engine"]
         orig = eng.admission
         eng.admission = AdmissionController(
+            # 0.01/s cannot refill the one-token burst inside the test: at
+            # 5/s a second request more than 200 ms after the first (six
+            # xdist workers) was admitted and no 429 came.
             AdmissionConfig.from_dict({"models": {"simple": {
-                "tokens_per_s": 5.0, "burst": 1.0}}}), metrics=eng.metrics)
+                "tokens_per_s": 0.01, "burst": 1.0}}}), metrics=eng.metrics)
         cursor = journal().export()["next_seq"]
         c = httpclient.InferenceServerClient(stack["http"].url)
         try:

@@ -1,0 +1,648 @@
+"""The generative scheduler's clock inside the program: loop-phase spans and
+lane counters in ``/v2/profile``, the same spans on the device trace's clock,
+the compile counter that hears every jit, set-up spans, stable step names.
+
+Everything runs the tiny generative model on the CPU; the numbers checked are
+counts and identities, never times (a time comes only from a chip run)."""
+
+import http.client
+import json
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from client_tpu.engine import InferRequest, TpuEngine
+from client_tpu.engine.repository import ModelRepository
+from client_tpu.engine.trace import TraceManager
+from client_tpu.models.generate import TinyGptBackend
+from client_tpu.observability import spans
+from client_tpu.observability.profiler import (
+    BACKEND_COMPILE_EVENT,
+    EfficiencyProfiler,
+)
+
+MODEL = "spans_gpt"
+CHILDREN = [s for s in spans.GEN_SPANS if s != spans.GEN_LOOP]
+
+
+def _engine(name=MODEL, **kw):
+    kw = {"max_streams": 8, "n_layers": 2, "max_seq_len": 64, **kw}
+    repo = ModelRepository()
+    repo.register_backend(TinyGptBackend(name=name, **kw))
+    return TpuEngine(repo)
+
+
+def _stream(engine, prompt, max_tokens, model=MODEL, **params):
+    """Start one stream; returns join() -> emitted tokens."""
+    tokens, err, done = [], [], threading.Event()
+
+    def cb(resp):
+        if resp.error is not None:
+            err.append(resp.error)
+            done.set()
+        elif resp.final:
+            done.set()
+        else:
+            tokens.append(int(resp.outputs["TOKEN"][0]))
+
+    engine.async_infer(InferRequest(
+        model_name=model, inputs={"INPUT_IDS": np.asarray(prompt, np.int32)},
+        parameters={"max_tokens": max_tokens, **params}), cb)
+
+    def join():
+        assert done.wait(120), "stream did not finish"
+        assert not err, err
+        return tokens
+
+    return join
+
+
+def _gen(engine, model=MODEL):
+    """The model's committed ``generative`` object, once the worker has
+    gone back to its blocking wait (every iteration committed)."""
+    sched = engine._schedulers[model]
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        if not sched._streams and not sched._inflight \
+                and sched._rec.open is sched._rec.span[spans.S_IDLE]:
+            break
+        time.sleep(0.005)
+    snap = engine.profile_snapshot(model=model)
+    return snap["models"][f"{model}:1"]["generative"]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = _engine()
+    yield eng
+    eng.shutdown()
+
+
+@pytest.fixture(scope="module")
+def ran(engine):
+    """Known traffic, drained: (before, after, streams) where streams is
+    [(prompt length, max_tokens, tokens emitted)], all ended by budget."""
+    before = _gen_or_zero(engine)
+    plan = [([1, 2, 3], 6), ([4, 5, 6, 7, 8], 4), ([9], 7),
+            ([3, 1, 4, 1, 5, 9, 2, 6], 5), ([2, 7], 1)]
+    joins = [_stream(engine, p, n) for p, n in plan]
+    out = [(len(p), n, j()) for (p, n), j in zip(plan, joins)]
+    return before, _gen(engine), out
+
+
+def _gen_or_zero(engine):
+    snap = engine.profile_snapshot(model=MODEL)
+    g = snap["models"].get(f"{MODEL}:1", {}).get("generative")
+    if g is None:
+        g = {"spans": {s: {"count": 0, "total_ns": 0, "max_ns": 0}
+                       for s in spans.GEN_SPANS},
+             "counters": dict.fromkeys(spans.GEN_COUNTERS, 0)}
+    return g
+
+
+def _delta(before, after):
+    return {k: after["counters"][k] - before["counters"][k]
+            for k in spans.GEN_COUNTERS}
+
+
+class TestLoopSpans:
+    def test_vocabulary_is_what_the_profile_serves(self, ran):
+        _, after, _ = ran
+        assert list(after["spans"]) == list(spans.GEN_SPANS)
+        assert list(after["counters"]) == list(spans.GEN_COUNTERS)
+        assert len(set(spans.GEN_SPANS)) == 9
+
+    def test_children_partition_the_iteration(self, ran):
+        _, after, _ = ran
+        loop = after["spans"][spans.GEN_LOOP]["total_ns"]
+        kids = sum(after["spans"][s]["total_ns"] for s in CHILDREN)
+        assert loop > 0
+        # Exclusive children never exceed the inclusive parent, and what
+        # is left (the loop's own bookkeeping) is a few percent.
+        assert kids <= loop
+        assert (loop - kids) / loop < 0.10
+
+    @pytest.mark.parametrize("span", [
+        spans.GEN_ADMIT, spans.GEN_PREFILL_DISPATCH, spans.GEN_SWEEP,
+        spans.GEN_WAVE_STAGE, spans.GEN_WAVE_DISPATCH, spans.GEN_FETCH_WAIT,
+        spans.GEN_EMIT, spans.GEN_IDLE])
+    def test_every_phase_was_seen(self, ran, span):
+        _, after, _ = ran
+        s = after["spans"][span]
+        assert s["count"] > 0
+        assert 0 < s["max_ns"] <= s["total_ns"]
+
+    def test_span_counts_follow_the_counters(self, ran):
+        """A span's count is the count of its call site: no counter repeats
+        it (decode dispatches have one, as the metrics' denominator)."""
+        before, after, streams = ran
+        c, s = after["counters"], after["spans"]
+        assert s[spans.GEN_WAVE_DISPATCH]["count"] == c["dispatches"]
+        assert s[spans.GEN_WAVE_STAGE]["count"] == c["dispatches"]
+        assert s[spans.GEN_FETCH_WAIT]["count"] == s[spans.GEN_EMIT]["count"] \
+            == c["dispatches"] + s[spans.GEN_PREFILL_DISPATCH]["count"]
+        assert c["drains"] <= s[spans.GEN_FETCH_WAIT]["count"]
+
+    def test_warmup_is_not_serving_time(self):
+        eng = _engine(name="spans_warm", max_streams=2, max_seq_len=16)
+        try:
+            t0 = time.monotonic_ns()
+            eng._schedulers["spans_warm"].warmup()
+            warm_ns = time.monotonic_ns() - t0
+            _stream(eng, [1, 2], 2, model="spans_warm")()
+            g = _gen(eng, "spans_warm")
+            loop = g["spans"][spans.GEN_LOOP]["total_ns"]
+            idle = g["spans"][spans.GEN_IDLE]["total_ns"]
+            # The precompile ran on the worker thread inside an iteration
+            # and is excluded from it.
+            assert warm_ns > 50e6
+            assert loop - idle < warm_ns / 2
+        finally:
+            eng.shutdown()
+
+
+class TestLaneCounters:
+    def test_every_lane_is_accounted(self, ran):
+        """Budget-ended streams, drained: a client's tokens are its first
+        token and one for each decode lane the device ran for it."""
+        before, after, streams = ran
+        d = _delta(before, after)
+        assert all(len(t) == n for _, n, t in streams)
+        assert d["first_tokens"] + d["fetched_lanes_live"] \
+            == sum(len(t) for _, _, t in streams)
+
+    def test_positions_valid_from_the_known_prompts(self, ran):
+        before, after, streams = ran
+        d = _delta(before, after)
+        # A stream of prompt p and budget n gets n-1 decode waves; wave j
+        # reads p+j valid positions.
+        want = sum(p + j for p, n, _ in streams for j in range(n - 1))
+        assert d["fetched_positions_valid"] == want
+        assert d["fetched_lanes_live"] == sum(n - 1 for _, n, _ in streams)
+
+    def test_every_stream_has_one_first_token(self, ran):
+        before, after, streams = ran
+        d = _delta(before, after)
+        assert d["first_tokens"] == len(streams)
+        assert d["first_token_wait_ns"] > 0
+
+    def test_lanes_match_the_decode_wave_table(self, engine, ran):
+        """``fetched_lanes_live + fetched_lanes_padded`` is the sum of
+        bucket x waves of the profile's waves-by-bucket table (what
+        wave_host_ms_mean reads)."""
+        _, after, _ = ran
+        snap = engine.profile_snapshot(model=MODEL)
+        table = snap["models"][f"{MODEL}:1"]["decode_waves"]
+        c = after["counters"]
+        assert sum(w["bucket"] * w["waves"] for w in table) \
+            == c["fetched_lanes_live"] + c["fetched_lanes_padded"]
+        assert sum(w["waves"] for w in table) == c["fetched_waves"]
+        assert c["fetched_waves"] == c["dispatches"]  # no chunking, drained
+        assert c["fetched_lanes_padded"] >= 0
+
+    def test_counters_are_monotone(self, engine, ran):
+        _, first, _ = ran
+        _stream(engine, [5, 5, 5], 3)()
+        second = _gen(engine)
+        for k in spans.GEN_COUNTERS:
+            assert second["counters"][k] >= first["counters"][k], k
+        for s in spans.GEN_SPANS:
+            for field in ("count", "total_ns", "max_ns"):
+                assert second["spans"][s][field] >= first["spans"][s][field]
+        assert second["counters"]["first_tokens"] \
+            == first["counters"]["first_tokens"] + 1
+
+    def test_pipeline_depth_at_dispatch_is_remembered(self, engine):
+        """A lone stream's prefill goes into an empty pipeline and its first
+        token remembers that depth; its waves queue behind each other."""
+        before = _gen(engine)
+        _stream(engine, [1, 2, 3], 6)()
+        d = _delta(before, _gen(engine))
+        assert d["first_tokens"] == 1
+        assert d["first_token_inflight_waves"] == 0
+        assert d["inflight_waves"] > 0
+        assert d["fetches_forced"] <= d["dispatches"] + 1
+
+    def test_stop_token_ends_a_stream_mid_pipeline(self, engine):
+        """Lanes the device ran for a stream that had already stopped are
+        counted (the device ran them) though no client got their tokens."""
+        toks = _stream(engine, [1, 2, 3], 6)()
+        stop = toks[2]
+        cut = toks.index(stop)
+        before = _gen(engine)
+        got = _stream(engine, [1, 2, 3], 6, stop_token_ids=stop)()
+        d = _delta(before, _gen(engine))
+        assert got == toks[:cut]
+        assert d["first_tokens"] == 1
+        assert d["first_tokens"] + d["fetched_lanes_live"] > len(got)
+
+
+class TestRecorder:
+    def test_snapshot_sees_whole_iterations_only(self):
+        p = EfficiencyProfiler()
+        rec = spans.GenRecorder(lambda r: p.commit_generative("m", 1, r))
+        rec.begin_loop()
+        with rec.span[spans.S_FETCH_WAIT]:
+            pass
+        rec.c[spans.C_DISPATCHES] += 3
+        assert "m:1" not in p.snapshot()["models"]  # nothing committed yet
+        rec.end_loop()
+        g = p.snapshot()["models"]["m:1"]["generative"]
+        assert g["counters"]["dispatches"] == 3
+        assert g["spans"][spans.GEN_LOOP]["count"] == 1
+        assert g["spans"][spans.GEN_FETCH_WAIT]["count"] == 1
+        assert rec.c[spans.C_DISPATCHES] == 0  # handed over
+
+    def test_children_are_exclusive_and_the_loop_inclusive(self):
+        p = EfficiencyProfiler()
+        rec = spans.GenRecorder(lambda r: p.commit_generative("m", 1, r))
+        rec.begin_loop()
+        with rec.span[spans.S_ADMIT]:
+            with rec.span[spans.S_PREFILL_DISPATCH]:
+                time.sleep(0.02)
+        rec.end_loop()
+        s = p.snapshot()["models"]["m:1"]["generative"]["spans"]
+        assert s[spans.GEN_PREFILL_DISPATCH]["total_ns"] >= 20e6
+        assert s[spans.GEN_ADMIT]["total_ns"] < 10e6   # less its child
+        assert s[spans.GEN_LOOP]["total_ns"] >= \
+            s[spans.GEN_PREFILL_DISPATCH]["total_ns"] \
+            + s[spans.GEN_ADMIT]["total_ns"]
+
+    def test_reset_drops_generative_totals(self):
+        p = EfficiencyProfiler()
+        rec = spans.GenRecorder(lambda r: p.commit_generative("m", 1, r))
+        rec.begin_loop()
+        rec.end_loop()
+        p.reset()
+        assert p.snapshot()["models"] == {}
+
+
+class TestPrefillSpanOfARequest:
+    def test_trace_requests_shows_queue_plus_prefill(self, engine):
+        from client_tpu.server import HttpInferenceServer
+
+        srv = HttpInferenceServer(engine, port=0).start()
+        trace_id = "ab" * 16
+        try:
+            host, port = srv.url.split(":")
+            conn = http.client.HTTPConnection(host, int(port), timeout=120)
+            body = json.dumps({
+                "inputs": [{"name": "INPUT_IDS", "datatype": "INT32",
+                            "shape": [3], "data": [1, 2, 3]}],
+                "parameters": {"max_tokens": 4}}).encode()
+            conn.request("POST", f"/v2/models/{MODEL}/generate", body=body,
+                         headers={"traceparent":
+                                  f"00-{trace_id}-{'cd' * 8}-01"})
+            resp = conn.getresponse()
+            assert resp.status == 200 and len(
+                json.loads(resp.read())["responses"]) == 4
+            conn.request("GET", f"/v2/trace/requests?trace_id={trace_id}")
+            events = json.loads(conn.getresponse().read())["traceEvents"]
+            conn.close()
+        finally:
+            srv.stop()
+        by = {e["name"]: e for e in events if e["ph"] == "X"}
+        assert {"queue", "prefill", "compute_infer"} <= set(by)
+        q, pre, infer = by["queue"], by["prefill"], by["compute_infer"]
+        # TTFT inside the server = queue + prefill, additively: prefill
+        # starts where queue ends and ends inside the stream.
+        assert pre["ts"] == pytest.approx(q["ts"] + q["dur"], abs=1e-3)
+        assert 0 < pre["dur"] <= infer["dur"]
+        chunks = sorted(e["ts"] for e in events if e["name"] == "chunk")
+        assert len(chunks) == 4
+        assert pre["ts"] + pre["dur"] <= chunks[0] + 1e-3
+
+    def test_no_prefill_span_without_a_first_token(self):
+        from client_tpu.engine.types import RequestTimes
+        from client_tpu.observability.tracing import (
+            PHASES, TraceContext, build_request_trace)
+
+        t = RequestTimes(received=1, queue_start=1, compute_start=5,
+                         compute_input_end=6, compute_infer_end=9,
+                         compute_output_end=10)
+        ctx = TraceContext.new() if hasattr(TraceContext, "new") \
+            else TraceContext("a" * 32, "b" * 16, "")
+        names = [s.name for s in build_request_trace(
+            ctx, "m", "r", t, ok=True).spans]
+        assert "prefill" not in names
+        assert names == ["request", *PHASES]
+        t.first_token = 7
+        spans_ = {s.name: s for s in build_request_trace(
+            ctx, "m", "r", t, ok=True).spans}
+        assert (spans_["prefill"].start_ns, spans_["prefill"].end_ns) == (5, 7)
+
+
+class TestCompileCounter:
+    def test_equals_an_independent_listener_then_adds_nothing(self):
+        import jax.monitoring as monitoring
+
+        heard = []
+
+        def mine(event, duration_secs, **_):
+            if event == BACKEND_COMPILE_EVENT:
+                heard.append(duration_secs)
+
+        monitoring.register_event_duration_secs_listener(mine)
+        # Shapes no other test of this process compiles.
+        eng = _engine(name="spans_compile", max_streams=3, max_seq_len=24,
+                      n_layers=1)
+        try:
+            c0 = eng.profile_snapshot()["compiles"]
+            n0 = len(heard)
+            _stream(eng, [1, 2, 3], 3, model="spans_compile")()
+            c1 = eng.profile_snapshot()["compiles"]
+            n1 = len(heard)
+            assert n1 - n0 >= 2          # prefill and decode, at least
+            assert c1["count"] - c0["count"] == n1 - n0
+            assert c1["seconds"] - c0["seconds"] == pytest.approx(
+                sum(heard[n0:n1]))
+            scopes = {k for k, v in c1["by_scope"].items()
+                      if k.startswith("spans_compile:1:")
+                      and v["count"] > c0["by_scope"].get(
+                          k, {"count": 0})["count"]}
+            assert "spans_compile:1:prefill:4" in scopes
+            assert any(k.startswith("spans_compile:1:decode:")
+                       for k in scopes)
+            # The same shapes again compile nothing.
+            _stream(eng, [3, 2, 1], 3, model="spans_compile")()
+            c2 = eng.profile_snapshot()["compiles"]
+            assert c2["count"] == c1["count"] and len(heard) == n1
+            text = eng.prometheus_metrics()
+            assert 'tpu_xla_compilations_total{model="spans_compile",' \
+                   'version="1",bucket="4"} 1' in text
+        finally:
+            eng.shutdown()
+            monitoring.unregister_event_duration_listener(mine)
+
+    def test_record_compile_no_longer_counts(self):
+        from client_tpu.observability.metrics import MetricRegistry
+
+        p = EfficiencyProfiler()
+        reg = MetricRegistry()
+        p.bind_metrics(reg)
+        p.record_compile("m", 1, 8, compile_ns=1_000_000_000)
+        assert p.snapshot()["compiles"]["count"] == 0
+        assert p.snapshot()["models"]["m:1"]["compilations"] == 1
+        assert 'tpu_xla_compilations_total{' not in reg.render()
+        p.record_backend_compile(0.5, ("m", 1, "apply", 8))
+        p.record_backend_compile(0.25, None)
+        c = p.snapshot()["compiles"]
+        assert c["count"] == 2 and c["seconds"] == pytest.approx(0.75)
+        assert c["by_scope"] == {
+            "": {"count": 1, "seconds": 0.25},
+            "m:1:apply:8": {"count": 1, "seconds": 0.5}}
+        text = reg.render()
+        assert 'tpu_xla_compilations_total{model="m",version="1",' \
+               'bucket="8"} 1' in text
+        assert "tpu_xla_compile_seconds_count" in text
+
+    def test_scope_is_per_thread_and_cleared(self, engine):
+        from client_tpu.observability.profiler import _scope
+
+        model = engine._schedulers[MODEL].model
+        model._set_state("x", spans.STEP_DECODE, 4)
+        assert _scope.value == (MODEL, 1, "decode", 4)
+        seen = []
+        t = threading.Thread(
+            target=lambda: seen.append(getattr(_scope, "value", None)))
+        t.start()
+        t.join()
+        assert seen == [None]
+        model._clear_state()
+        assert _scope.value is None
+
+
+class TestStartupSpans:
+    def test_engine_records_backend_init_and_model_load(self, engine):
+        names = [s["name"] for s in engine.profile_snapshot()["startup"]]
+        assert spans.STARTUP_MODEL_LOAD + MODEL in names
+        for s in engine.profile_snapshot()["startup"]:
+            assert s["end_s"] >= s["start_s"]
+
+    def test_relative_to_the_launcher_entry(self):
+        clock = [1_000_000_000]
+        p = EfficiencyProfiler(now=lambda: clock[0])
+        p.record_startup(spans.STARTUP_BACKEND_INIT, 400_000_000,
+                         900_000_000)  # a wrapper initialised it first
+        p.startup_entry()
+        p.record_startup(spans.STARTUP_MODEL_LOAD + "m", 1_500_000_000,
+                         2_000_000_000)
+        p.record_startup(spans.STARTUP_WARMUP + "m", 2_000_000_000,
+                         4_000_000_000)
+        assert p.snapshot()["startup"] == [
+            {"name": "startup.backend_init", "start_s": -0.6,
+             "end_s": -0.1},
+            {"name": "startup.model_load:m", "start_s": 0.5, "end_s": 1.0},
+            {"name": "startup.warmup:m", "start_s": 1.0, "end_s": 3.0}]
+
+    def test_launcher_marks_entry_warmup_and_frontends(self):
+        import os
+        import re
+        import signal
+        import subprocess
+        import sys
+        import urllib.request
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, PYTHONPATH=root, JAX_PLATFORMS="cpu",
+                   JAX_ENABLE_COMPILATION_CACHE="false")
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "client_tpu.server", "--zoo", "simple",
+             "--warmup", "--http-port", "0", "--no-grpc",
+             "--host", "127.0.0.1"],
+            env=env, cwd=root, stderr=subprocess.PIPE, text=True)
+        try:
+            url = None
+            for line in proc.stderr:
+                m = re.match(r"serving http at (\S+)", line)
+                if m:
+                    url = m.group(1)
+                    break
+            assert url, "launcher did not come up"
+            snap = json.load(urllib.request.urlopen(
+                f"http://{url}/v2/profile", timeout=30))
+        finally:
+            proc.send_signal(signal.SIGTERM)
+            proc.wait(timeout=60)
+        by = {s["name"]: s for s in snap["startup"]}
+        assert list(by) == ["startup.backend_init",
+                            "startup.model_load:simple",
+                            "startup.warmup:simple", "startup.frontends"]
+        assert by["startup.backend_init"]["start_s"] >= 0  # after the entry
+        ends = [s["end_s"] for s in snap["startup"]]
+        assert ends == sorted(ends)
+        # --warmup compiled every bucket of `simple`, and the program's
+        # counter saw it without a request having been served.
+        assert snap["compiles"]["count"] >= 1
+        assert any(k.startswith("simple:1:apply:")
+                   for k in snap["compiles"]["by_scope"])
+
+
+class TestStepNames:
+    """``jit_<name>`` is what the device-trace reduction and
+    ``benchmark/traffic/chat.json`` (``step_module``) key on."""
+
+    def test_generative_modules(self, engine):
+        sched = engine._schedulers[MODEL]
+        m = sched.model
+        lane, wb = 8, 1
+        z = np.zeros(lane, np.int32)
+        prefill = sched._prefill.lower(
+            m._params, sched._arena, z, np.zeros((lane, 4), np.int32),
+            np.ones(lane, np.int32), z, np.zeros(lane, np.float32), z,
+            np.ones(lane, np.float32), False).as_text()
+        assert "module @jit_prefill" in prefill
+        z1 = np.zeros(wb, np.int32)
+        decode = sched._decode.lower(
+            m._params, sched._arena, z1, z1, z1, np.zeros(wb, np.float32),
+            z1, np.ones(wb, np.float32), False).as_text()
+        assert "module @jit_decode " in decode \
+            or "module @jit_decode\n" in decode \
+            or "module @jit_decode attributes" in decode
+
+    def test_chunked_decode_module(self, monkeypatch):
+        monkeypatch.setenv("CLIENT_TPU_GEN_CHUNK", "2")
+        eng = _engine(name="spans_chunk", max_streams=2, max_seq_len=16,
+                      n_layers=1)
+        try:
+            sched = eng._schedulers["spans_chunk"]
+            z1 = np.zeros(1, np.int32)
+            text = sched._decode_chunk.lower(
+                sched.model._params, sched._arena, z1, z1, z1,
+                np.zeros(1, np.float32), z1, np.ones(1, np.float32),
+                False, 2).as_text()
+            assert "module @jit_decode_chunk" in text
+            toks = _stream(eng, [1, 2], 6, model="spans_chunk")()
+            assert len(toks) == 6
+            c = _gen(eng, "spans_chunk")["counters"]
+            # K waves in one dispatch; a lane for each wave, used or not.
+            assert c["fetched_waves"] > c["dispatches"]
+            assert c["fetched_lanes_live"] == c["fetched_waves"]
+            assert c["first_tokens"] + c["fetched_lanes_live"] >= len(toks)
+        finally:
+            eng.shutdown()
+
+    def test_batcher_module_is_jit_apply(self):
+        from client_tpu.models import build_repository
+
+        eng = TpuEngine(build_repository(["simple"]))
+        try:
+            model = eng._schedulers["simple"].model
+            a = np.zeros((1, 16), np.int32)
+            args = ({"INPUT0": a, "INPUT1": a},)
+            if model._takes_params:
+                args = (model._params,) + args
+            assert "module @jit_apply" in model._apply.lower(*args).as_text()
+        finally:
+            eng.shutdown()
+
+    def test_named_step_keeps_the_function(self):
+        def whatever(a, b):
+            return a - b
+
+        step = spans.named_step(whatever, "decode")
+        assert step.__name__ == step.__qualname__ == "decode"
+        assert step(5, 3) == 2
+
+
+class _FakeProfiler:
+    def __init__(self):
+        self.options = None
+        self.active_at_start = None
+
+    def start_trace(self, log_dir, profiler_options=None):
+        self.options = profiler_options
+        self.active_at_start = spans.trace_active()
+
+    def stop_trace(self):
+        self.active_at_stop = spans.trace_active()
+
+
+class TestTraceClock:
+    def test_trace_manager_turns_the_python_tracer_off(self, monkeypatch,
+                                                       tmp_path):
+        import jax
+
+        fake = _FakeProfiler()
+        monkeypatch.setattr(jax.profiler, "start_trace", fake.start_trace)
+        monkeypatch.setattr(jax.profiler, "stop_trace", fake.stop_trace)
+        tm = TraceManager()
+        assert not spans.trace_active()
+        tm.update({"trace_level": ["TIMESTAMPS"], "log_dir": str(tmp_path)})
+        assert fake.options is not None
+        assert fake.options.python_tracer_level == 0
+        assert fake.active_at_start is False and spans.trace_active()
+        tm.update({"trace_level": ["OFF"]})
+        assert fake.active_at_stop is False and not spans.trace_active()
+
+    def test_no_annotation_is_built_off_the_trace(self, engine, monkeypatch):
+        built = []
+        monkeypatch.setattr(spans, "_annotate",
+                            lambda name: built.append(name))
+        assert not spans.trace_active()
+        _stream(engine, [1, 2, 3], 3)()
+        _gen(engine)
+        assert built == []
+        assert spans.begin(spans.EXEC_RUN) is None
+        spans.end(None)
+
+    def test_cpu_trace_holds_the_host_spans(self, engine, tmp_path):
+        from jax.profiler import ProfileData
+
+        engine.trace.update({"trace_level": ["TIMESTAMPS"],
+                             "log_dir": str(tmp_path)})
+        try:
+            assert spans.trace_active()
+            joins = [_stream(engine, [i, i + 1], 4) for i in range(1, 4)]
+            for j in joins:
+                j()
+            _gen(engine)
+        finally:
+            engine.trace.update({"trace_level": ["OFF"]})
+        assert not spans.trace_active()
+        files = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+        assert len(files) == 1
+        pd = ProfileData.from_file(str(files[0]))
+        seen = {}
+        for plane in pd.planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("gen."):
+                        seen[e.name] = seen.get(e.name, 0) + 1
+        assert seen.get(spans.GEN_WAVE_DISPATCH, 0) >= 3
+        assert seen.get(spans.GEN_FETCH_WAIT, 0) >= 3
+        assert seen.get(spans.GEN_LOOP, 0) >= 3
+        assert set(seen) <= set(spans.GEN_SPANS)
+
+    def test_batcher_phases_are_exec_annotations(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        from client_tpu.models import build_repository
+
+        eng = TpuEngine(build_repository(["simple"]))
+        try:
+            a = np.arange(16, dtype=np.int32).reshape(1, 16)
+            req = lambda: InferRequest(  # noqa: E731
+                model_name="simple", inputs={"INPUT0": a, "INPUT1": a})
+            eng.infer(req())  # compile outside the trace
+            eng.trace.update({"trace_level": ["TIMESTAMPS"],
+                              "log_dir": str(tmp_path)})
+            try:
+                for _ in range(3):
+                    eng.infer(req())
+            finally:
+                eng.trace.update({"trace_level": ["OFF"]})
+        finally:
+            eng.shutdown()
+        pd = ProfileData.from_file(str(next(
+            tmp_path.glob("plugins/profile/*/*.xplane.pb"))))
+        seen = {}
+        for plane in pd.planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("exec."):
+                        seen[e.name] = seen.get(e.name, 0) + 1
+        assert seen == {spans.EXEC_STAGE: 3, spans.EXEC_RUN: 3,
+                        spans.EXEC_FETCH: 3}

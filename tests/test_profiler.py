@@ -274,8 +274,9 @@ class _FakeJaxProfiler:
         self.starts = 0
         self.stops = 0
 
-    def start_trace(self, log_dir):
+    def start_trace(self, log_dir, profiler_options=None):
         self.starts += 1
+        self.options = profiler_options
         if self.fail_start:
             raise RuntimeError("profiler already running")
 
