@@ -28,7 +28,9 @@ Phases (run in this order; any failure → non-zero exit, no result line):
                cache gains nothing the second time; cold/warm compile seconds
   A  server    the six-model server; every request over the wire; steady
                state compiles nothing; SIGTERM drains to exit 0
-  B  fused     ``CLIENT_TPU_ATTN_IMPL=fused`` tiny_gpt: tokens equal phase A's
+  B  oracle    ``CLIENT_TPU_ATTN_IMPL=reference`` tiny_gpt (the XLA scatter/
+               gather step): tokens equal phase A's, which the served step
+               produced (on a TPU, unset: the Pallas decode-wave kernel)
   E  four_chip ``bert_base_mc`` on >= 4 devices, else "not run (N device)"
 
 The last stdout line of a passing run is one JSON object:
@@ -806,24 +808,32 @@ def _serve_and_check(ctx, srv: Server, http, grpc, hc, gc) -> None:
     ctx["gen_tokens"] = first["gen"]["batched"]
 
 
-def phase_fused(ctx: dict) -> None:
-    """Phase B: the fused Pallas decode path gives phase A's tokens."""
+def phase_oracle(ctx: dict) -> None:
+    """Phase B: two implementations of the decode step, token for token.
+    Phase A's server ran with no setting, so on the chip its tokens came
+    from the served step, the Pallas decode-wave kernel; this server is
+    told to serve the oracle, the XLA scatter/gather/dense-softmax step.
+    (A rehearsal on the CPU serves the XLA step in phase A too, and this
+    phase then compares it with itself: it says so.)"""
     import client_tpu.grpc as grpc
 
     mode: Mode = ctx["mode"]
     if "gen_tokens" not in ctx:
         raise SmokeFailure("not run: needs phase A's tokens")
-    srv = Server(mode, "phaseB_fused", ["tiny_gpt"],
-                 CLIENT_TPU_ATTN_IMPL="fused")
+    srv = Server(mode, "phaseB_oracle", ["tiny_gpt"],
+                 CLIENT_TPU_ATTN_IMPL="reference")
     try:
         srv.wait_ready()
         got = check_streams(grpc, srv.urls, "tiny_gpt")["batched"]
-        for i, (fused, ref) in enumerate(zip(got, ctx["gen_tokens"])):
-            if fused != ref:
+        for i, (oracle, served) in enumerate(zip(got, ctx["gen_tokens"])):
+            if oracle != served:
                 raise SmokeFailure(
-                    f"fused decode != reference decode for prompt {i}\n"
-                    f"  fused     {fused}\n  reference {ref}")
-        say("    fused decode tokens == reference decode tokens "
+                    f"served decode != oracle decode for prompt {i}\n"
+                    f"  served (kernel) {served}\n  oracle (XLA)    {oracle}")
+        served_by = ("XLA step, the same implementation twice"
+                     if mode.rehearsal else "Pallas kernel")
+        say(f"    served decode tokens ({served_by}, phase A) == oracle "
+            f"decode tokens (XLA scatter/gather, this phase) "
             f"({len(got)} prompts x {GEN_TOKENS})")
         srv.check_log_clean()
         srv.stop()
@@ -877,7 +887,7 @@ PHASES = [
     ("C kernels", phase_kernels),
     ("D cache", phase_cache),
     ("A server", phase_server),
-    ("B fused", phase_fused),
+    ("B oracle", phase_oracle),
     ("E four_chip", phase_four_chip),
 ]
 
@@ -1009,11 +1019,11 @@ def kernels_child(rehearsal: bool) -> int:
     flash_case("flash_attention(causal)", (1, s_long, 4, 64), jnp.float32,
                True, False, FLASH_F32_ATOL)
 
-    def decode_case(name, layers, rows, seq, bsz, lens_list):
-        h, d = 4, 64   # tiny_gpt: d_model 256 / 4 heads
+    def decode_case(name, layers, rows, seq, bsz, lens_list, h=4):
+        d = 64         # tiny_gpt: d_model 256 / 4 heads
         ks = jax.random.split(jax.random.PRNGKey(11), 5)
-        k_a = jax.random.normal(ks[0], (layers, rows, seq, h, d))
-        v_a = jax.random.normal(ks[1], (layers, rows, seq, h, d))
+        k_a = jax.random.normal(ks[0], (layers, rows, seq, h * d))
+        v_a = jax.random.normal(ks[1], (layers, rows, seq, h * d))
         q, kn, vn = (jax.random.normal(kk, (bsz, h, d)) for kk in ks[2:])
         # Distinct real rows, plus one padded lane parked on the dummy row.
         rows_ix = np.arange(bsz, dtype=np.int32) * 2 % (rows - 1)
@@ -1043,7 +1053,7 @@ def kernels_child(rehearsal: bool) -> int:
             and bool(np.all(np.isfinite(fo[live])))
         if not rehearsal and not compiled:
             ok = False
-        report(name, ok, f"arena [{layers},{rows},{seq},{h},{d}] wave {bsz}: "
+        report(name, ok, f"arena [{layers},{rows},{seq},{h * d}] wave {bsz}: "
                f"max |diff| {err:.2e} (<= {DECODE_ATOL:g}), arena == oracle "
                f"bitwise {bitwise}, untouched rows preserved {preserved}, "
                f"mosaic custom call {'present' if compiled else 'ABSENT'}")
@@ -1055,6 +1065,10 @@ def kernels_child(rehearsal: bool) -> int:
         # tiny_gpt_long: 16 streams + dummy row, 2048 positions.
         decode_case("decode_wave_attention(tiny_gpt_long)", 4, 17, 2048, 4,
                     [300, 2047, 1024, 0])
+        # GPT-2's heads (12 x 64 on a 768-lane row), 1024 positions: the
+        # benchmark's geometry; lengths around the 512-position block edge.
+        decode_case("decode_wave_attention(gpt2)", 2, 17, 1024, 8,
+                    [700, 0, 1023, 511, 512, 513, 1, 0], h=12)
 
     if len(devices) >= 4:
         from client_tpu.parallel.kv_shard import (
@@ -1069,8 +1083,8 @@ def kernels_child(rehearsal: bool) -> int:
         h, d, seq, bsz = 4, 64, 128, 4
         ks = jax.random.split(jax.random.PRNGKey(13), 5)
         arena = shard_arena(
-            {"k": jax.random.normal(ks[0], (2, total, seq, h, d)),
-             "v": jax.random.normal(ks[1], (2, total, seq, h, d)),
+            {"k": jax.random.normal(ks[0], (2, total, seq, h * d)),
+             "v": jax.random.normal(ks[1], (2, total, seq, h * d)),
              "tok": jnp.zeros(total, jnp.int32)}, mesh)
         q, kn, vn = (jax.random.normal(kk, (bsz, h, d)) for kk in ks[2:])
         rows_ix = jnp.asarray([free[0], free[3], free[5], free[7]],

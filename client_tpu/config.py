@@ -128,9 +128,12 @@ def env_flag(name: str, environ=None) -> bool:
 
 # -- engine ------------------------------------------------------------------
 register(
-    "CLIENT_TPU_ATTN_IMPL", "reference", "str",
-    "Generative attention implementation: `reference` (XLA) or `fused` "
-    "(Pallas decode-wave kernel); streams are token-identical either way.",
+    "CLIENT_TPU_ATTN_IMPL", "", "str",
+    "Generative decode step: `fused` (Pallas decode-wave kernel: the arena "
+    "written in place, each live row read once) or `reference` (the XLA "
+    "scatter/gather oracle the parity checks serve); streams are "
+    "token-identical either way. Unset, a TPU serves `fused` and a "
+    "platform that would only interpret Pallas serves `reference`.",
     "engine")
 register(
     "CLIENT_TPU_AUTOTUNE", "", "json",

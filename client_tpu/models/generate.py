@@ -8,12 +8,13 @@ served token-by-token through the decoupled response protocol, with
 share each decode step via a KV-cache arena in HBM
 (client_tpu/engine/generative.py).
 
-TPU-first shapes: the KV cache is one pytree with leading dims
-``[n_layers, capacity+1, max_seq_len, heads, head_dim]`` (the +1 row absorbs
-padded decode lanes); prefill writes a whole row, each decode wave scatters
-one position per active stream and computes masked attention over the static
-``max_seq_len`` axis — no dynamic shapes anywhere, so XLA compiles one
-executable per (prompt bucket | wave bucket).
+TPU-first shapes: the KV cache is one pytree whose k/v leaves are
+``[n_layers, capacity+1, max_seq_len, heads*head_dim]`` (the +1 row absorbs
+padded decode lanes; the heads' features lie side by side on the minor axis
+so the chip's (8, 128) tile holds a leaf without padding); prefill writes a
+whole row, each decode wave writes one position per active stream in place
+and reads each live row once (ops/decode_kernel.py) — no dynamic shapes
+anywhere, so XLA compiles one executable per (prompt bucket | wave bucket).
 
 Weights are random (seeded) — generation is deterministic nonsense, which is
 exactly what the correctness tests need: batched decode must produce
@@ -104,14 +105,19 @@ class TinyGptBackend(ModelBackend):
         # s=2048 on v5e (bert.py's sweep); tests shrink them to drive the
         # multi-block grid at short sequence.
         self.flash_blocks = (512, 1024)
-        # Decode-wave implementation: "reference" is the stacked-XLA path
-        # above; "fused" runs the one-pass Pallas kernel
-        # (ops/decode_kernel.py) — same math, same `_sample_token`
-        # sequence, so streams are token-identical either way. The env
-        # flips the fleet without touching model registration.
+        # Decode-wave implementation: "fused" runs the Pallas kernel
+        # (ops/decode_kernel.py): one row written in place, each live row
+        # read once.  "reference" is the stacked-XLA oracle (scatter,
+        # gather, dense masked softmax) on the same arena — same math,
+        # same `_sample_token` sequence, so streams are token-identical
+        # either way; the parity tests and chip_smoke's phase B serve it,
+        # and so do the GSPMD-sharded families (parallel/serving.py), whose
+        # programs XLA has to partition.  Unset ("") the platform decides:
+        # the kernel wherever Mosaic compiles it (a TPU), the XLA step
+        # where Pallas would only be interpreted.
         if attn_impl is None:
             attn_impl = envcfg.env_str("CLIENT_TPU_ATTN_IMPL")
-        if attn_impl not in ("reference", "fused"):
+        if attn_impl not in ("", "reference", "fused"):
             raise ValueError(
                 f"attn_impl must be 'reference' or 'fused', got "
                 f"{attn_impl!r}")
@@ -124,10 +130,11 @@ class TinyGptBackend(ModelBackend):
         if self.kv_shards < 1:
             raise ValueError(f"kv_shards must be >= 1, got {kv_shards}")
         if self.kv_shards > 1:
-            if self.attn_impl != "fused":
+            if self.attn_impl == "reference":
                 raise ValueError(
                     "kv_shards > 1 requires attn_impl='fused' (the "
                     "sharded arena is served by the shard_map'd kernel)")
+            self.attn_impl = "fused"
             if max_streams % self.kv_shards:
                 raise ValueError(
                     f"max_streams ({max_streams}) must be divisible by "
@@ -295,10 +302,13 @@ class TinyGptBackend(ModelBackend):
         return self._kv_mesh
 
     def init_arena(self, capacity: int):
-        """KV arena pytree: k/v of shape [L, R, S, H, D] plus ``tok`` [R] —
+        """KV arena pytree: k/v of shape [L, R, S, H*D] plus ``tok`` [R] —
         each row's latest token, kept ON DEVICE so decode waves chain
         without a host round trip per step (the scheduler pipelines waves
-        and fetches emitted tokens asynchronously).  Unsharded, R is
+        and fetches emitted tokens asynchronously).  A position's row is
+        what ``h @ wk`` produced, heads side by side: lane-dense, so the
+        device stores a leaf unpadded in row-major order and a decode wave
+        can address one position of one row.  Unsharded, R is
         ``capacity + 1`` (the +1 dummy row absorbs padded decode lanes);
         with ``kv_shards > 1`` the rows carry a junk row per shard and the
         k/v leaves are placed row-sharded over the "kv" mesh
@@ -309,8 +319,7 @@ class TinyGptBackend(ModelBackend):
                                                   shard_arena)
 
         total, _free, _dummy = arena_row_layout(capacity, self.kv_shards)
-        shape = (self.n_layers, total, self.max_seq_len,
-                 self.n_heads, self.head_dim)
+        shape = (self.n_layers, total, self.max_seq_len, self.d_model)
         arena = {"k": jnp.zeros(shape, jnp.float32),
                  "v": jnp.zeros(shape, jnp.float32),
                  "tok": jnp.zeros(total, jnp.int32)}
@@ -341,10 +350,11 @@ class TinyGptBackend(ModelBackend):
                 ks, vs = [], []
                 x = self._stack(p, x, causal=True,
                                 on_kv=lambda li, k, v:
-                                (ks.append(k), vs.append(v)))
+                                (ks.append(k.reshape(n, -1)),
+                                 vs.append(v.reshape(n, -1))))
                 import jax.numpy as jnp
 
-                return x, jnp.stack(ks), jnp.stack(vs)  # [S,d],[L,S,H,D]x2
+                return x, jnp.stack(ks), jnp.stack(vs)  # [S,d],[L,S,H*D]x2
 
             xB, kB, vB = jax.vmap(one)(ids)              # [B,...]
             import jax.numpy as jnp
@@ -360,15 +370,15 @@ class TinyGptBackend(ModelBackend):
                     logits, seeds, lens, temps, top_ks, top_ps)
             else:
                 tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            # Scatter whole prompt rows: [B,L,S,H,D] -> arena [L,rows,:n];
+            # Scatter whole prompt rows: [B,L,S,H*D] -> arena [L,rows,:n];
             # the first token lands in the device-side token slot so the
             # first decode wave can start without the host fetch.
             arena = {
                 **arena,
                 "k": arena["k"].at[:, rows, :n].set(
-                    kB.transpose(1, 0, 2, 3, 4)),
+                    kB.transpose(1, 0, 2, 3)),
                 "v": arena["v"].at[:, rows, :n].set(
-                    vB.transpose(1, 0, 2, 3, 4)),
+                    vB.transpose(1, 0, 2, 3)),
                 "tok": arena["tok"].at[rows].set(tokens),
             }
             return arena, tokens
@@ -415,15 +425,18 @@ class TinyGptBackend(ModelBackend):
         the arena's device-side token slots (written by prefill / the
         previous wave), so consecutive waves chain on device with no host
         round trip between them — the scheduler dispatches waves ahead and
-        fetches emitted tokens asynchronously. Scatter each stream's new
-        K/V at its current position, masked attention over the static
-        max_seq_len axis, per-stream sampled (or greedy) next token.
+        fetches emitted tokens asynchronously. Write each stream's new
+        K/V at its current position, attend over its valid prefix,
+        per-stream sampled (or greedy) next token.
 
-        ``attn_impl="fused"`` swaps the per-layer scatter/gather/attend
-        stack for the one-pass Pallas kernel (``_fused_decode_fn``); this
-        body stays as the reference path and the parity oracle.
+        The served step is ``_fused_decode_fn`` (the Pallas kernel);
+        ``attn_impl="reference"`` selects the body below, the per-layer
+        scatter/gather/dense-softmax stack kept as the parity oracle.
         """
-        if self.attn_impl == "fused":
+        from client_tpu.engine.backend_init import pallas_interpret
+
+        if self.attn_impl == "fused" or (
+                not self.attn_impl and not pallas_interpret()):
             return self._fused_decode_fn()
         import jax
         import jax.numpy as jnp
@@ -442,13 +455,16 @@ class TinyGptBackend(ModelBackend):
                 v = (h @ lp["wv"]).reshape(b, h_, d_)
                 arena = {
                     **arena,
-                    "k": arena["k"].at[li, rows, lens].set(k),
-                    "v": arena["v"].at[li, rows, lens].set(v),
+                    "k": arena["k"].at[li, rows, lens].set(
+                        k.reshape(b, self.d_model)),
+                    "v": arena["v"].at[li, rows, lens].set(
+                        v.reshape(b, self.d_model)),
                 }
-                ck = arena["k"][li, rows]                    # [B, S, H, D]
-                cv = arena["v"][li, rows]
+                seq = self.max_seq_len
+                ck = arena["k"][li, rows].reshape(b, seq, h_, d_)
+                cv = arena["v"][li, rows].reshape(b, seq, h_, d_)
                 s = jnp.einsum("bhd,bshd->bhs", q, ck) / math.sqrt(d_)
-                mask = jnp.arange(self.max_seq_len)[None, :] <= lens[:, None]
+                mask = jnp.arange(seq)[None, :] <= lens[:, None]
                 s = jnp.where(mask[:, None, :], s, -1e30)
                 o = jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(s), cv)
                 x = x + o.reshape(b, self.d_model) @ lp["wo"]
@@ -471,12 +487,12 @@ class TinyGptBackend(ModelBackend):
         return decode
 
     def _fused_decode_fn(self):
-        """The ``attn_impl="fused"`` decode step: same signature, same
-        sampling sequence, but each layer's scatter + masked attention is
-        ONE Pallas grid (ops/decode_kernel.py) — the arena row streams
-        through VMEM once instead of materializing a [B, S, H, D] gather
-        per layer.  With ``kv_shards > 1`` the per-layer call is the
-        shard_map-wrapped variant over the row-sharded arena
+        """The served decode step: each layer's K/V write + masked
+        attention is ONE Pallas grid (ops/decode_kernel.py) over the
+        donated arena — one row group written in place per lane, each
+        live row streamed through VMEM once, no [B, S, ...] gather and no
+        pass over an arena leaf.  With ``kv_shards > 1`` the per-layer call
+        is the shard_map-wrapped variant over the row-sharded arena
         (parallel/kv_shard.py).  ``decode_chunk_fn`` scans this body
         unchanged, so chunked decode inherits the kernel for free."""
         import jax

@@ -1,30 +1,43 @@
-"""Fused decode-wave kernel: KV scatter + masked single-query attention.
+"""Decode-wave kernel: one in-place K/V row write + single-query attention.
 
 The generative engine's hot loop is the decode wave
 (engine/generative.py): for every live stream, write the new token's K/V
 row into the KV arena at ``(row, len)`` and attend the stream's query over
-its valid prefix.  The reference path (models/generate.py ``decode_fn``)
-does this as stacked XLA ops — ``arena.at[li, rows, lens].set`` followed by
-``arena[li, rows]``, which materializes a fresh ``[B, S, H, D]`` gather of
-every lane's row in HBM per layer per wave, then runs a dense masked
-softmax over the static ``max_seq_len`` axis.
+its valid prefix.  The arena is **lane-dense**: each leaf is
+``[L, R, S, H*D]`` — positions on the second-minor axis, the heads' features
+side by side on the minor axis (GPT-2: 768 = 6 x 128 lanes), so the chip's
+``(8, 128)`` float32 tile holds it without padding and a row of the arena is
+what ``h @ wk`` produced, untransposed.  (The earlier ``[L, R, S, H, D]``
+leaves had ``[12, 64]`` minor dimensions; the compiler stored them with S
+minor-most and re-laid out the whole leaf around every scatter and gather:
+PERF.md section 6, PR 25.)
 
-This kernel fuses the scatter and the attention into one Pallas grid so
-the arena row is streamed through VMEM exactly once (arXiv 2308.15152's
-shared-memory-footprint discipline): grid ``(B, S // block_s)`` with the
-key-block index innermost, the lane's ``(row, len)`` pair arriving via
-scalar prefetch (``PrefetchScalarGridSpec``) so the BlockSpec index maps
-gather each lane's row directly out of the arena — no ``[B, S, H, D]``
-intermediate exists anywhere.  The arena update is in place via
-``input_output_aliases``: each visited block is copied through VMEM
-unchanged except the scatter block, where the new K/V row is inserted at
-``len % block_s`` with an iota mask (TPU vector stores want static
-offsets).  Attention follows ``_fa_kernel``'s online-softmax carry
-(ops/flash_attention.py:31) with a *strict* ``pos < len`` mask over the
-old arena content; the new token's contribution (position ``len``, whose
-value is exactly the k/v being scattered) is folded in at the finalize
-step from registers — so the kernel never depends on reading back its own
-scatter, and block write-back order cannot matter.
+One Pallas grid per layer, ``(B, S // block_s)`` with the key-block index
+innermost; the lane's ``(row, len)`` pair arrives by scalar prefetch and the
+BlockSpec index maps pick each lane's blocks straight out of the arena, so no
+``[B, S, ...]`` gather exists anywhere.  What a wave does to the arena:
+
+- **Reads each live row once.**  Blocks that hold no valid position are not
+  fetched: their index map repeats the last valid block, which the pipeline
+  sees as unchanged and does not copy again, and their compute is skipped.
+- **Writes one row per lane and leaf.**  The arena operand is aliased to the
+  output (``input_output_aliases``) and the output stays in HBM
+  (``memory_space=ANY``): the kernel copies the aligned 8-row group that
+  holds position ``len`` into VMEM, inserts the new row with an iota mask
+  and copies the group back (HBM is tiled by 8 rows, so one row alone is not
+  a DMA the chip accepts).  Nothing else of the arena is written.
+- **Scores on the MXU.**  The query becomes a block-diagonal ``[Hp, H*D]``
+  matrix (row h holds head h's 64 features at their lanes, zero elsewhere),
+  so ``scores[h, s] = Qbd @ K_blk^T`` and ``acc[h, :] += p @ V_blk`` are two
+  plain matmuls over lane-dense blocks, in full float32 precision; the
+  output row is read off the block diagonal of ``acc``.
+
+Attention follows ``_fa_kernel``'s online-softmax carry
+(ops/flash_attention.py) with a *strict* ``pos < len`` mask over the old
+arena content; the new token's term (position ``len``, whose value is the
+k/v being written) is folded in at the finalize step from registers, so the
+kernel never reads back its own write and the order of the group's
+write-back against the pipeline's block reads cannot matter.
 
 ``interpret=True`` runs the same kernel on CPU; the tier-1 suite and
 ci_check drive it that way (tests/test_ops.py parity suite).  The sharded
@@ -35,19 +48,24 @@ client_tpu/parallel/kv_shard.py.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 _NEG_INF = -1e30
+# HBM arrays are tiled (8, 128): a DMA's slice of the second-minor axis
+# starts and ends on a multiple of 8 rows.
+_ROW_GROUP = 8
 
 
-def pick_block_s(seq_len: int, cap: int = 128) -> int:
+def pick_block_s(seq_len: int, cap: int = 512) -> int:
     """Largest multiple-of-8 divisor of ``seq_len`` up to ``cap`` (falls
-    back to ``seq_len`` itself when no aligned divisor exists) — the same
-    rule the flash prefill path uses to keep TPU tiles (8, 128)-friendly
-    while still exercising a multi-block grid at test sizes."""
+    back to ``seq_len`` itself when no aligned divisor exists).  A block is
+    ``block_s x H*D`` floats of K and of V, double-buffered: 512 x 768 is
+    1.5 MB each, long enough a DMA to run near HBM bandwidth and short
+    enough that a row's tail beyond ``len`` is mostly skipped."""
     best = None
     for cand in range(8, min(cap, seq_len) + 1, 8):
         if seq_len % cand == 0:
@@ -55,79 +73,99 @@ def pick_block_s(seq_len: int, cap: int = 128) -> int:
     return best if best is not None else seq_len
 
 
-def _decode_kernel(rows_ref, lens_ref,           # scalar prefetch
+def _decode_kernel(rows_ref, lens_ref,                    # scalar prefetch
                    k_ref, v_ref, q_ref, kn_ref, vn_ref,   # inputs
                    ko_ref, vo_ref, o_ref,                 # outputs
-                   m_ref, l_ref, acc_ref,                 # VMEM scratch
-                   *, block_s: int, sm_scale: float):
+                   m_ref, l_ref, acc_ref, kbuf, vbuf, sem,    # scratch
+                   *, layer: int, block_s: int, head_dim: int,
+                   sm_scale: float):
     """One (lane, key-block) grid step; key blocks iterate innermost so the
     scratch carries the online-softmax state across one lane's row."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
     ik = pl.program_id(1)
     nk = pl.num_programs(1)
+    row = rows_ref[b]
     length = lens_ref[b]                 # valid prefix length (strict)
+    hp, hd = acc_ref.shape
+    group = kbuf.shape[0]
+    g0 = pl.multiple_of((length // group) * group, group)
+
+    def group_copies(read: bool):
+        """The aligned row group around position ``length``, K and V:
+        arena -> VMEM (``read``) or back."""
+        out = []
+        for i, (arena, buf) in enumerate(((ko_ref, kbuf), (vo_ref, vbuf))):
+            hbm = arena.at[layer, row, pl.ds(g0, group)]
+            out.append(pltpu.make_async_copy(hbm, buf, sem.at[i]) if read
+                       else pltpu.make_async_copy(buf, hbm, sem.at[2 + i]))
+        return out
 
     @pl.when(ik == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        for copy in group_copies(read=True):
+            copy.start()
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    k_blk = k_ref[0, 0]                  # [block_s, H, D] (old content)
-    v_blk = v_ref[0, 0]
-    q = q_ref[0]                         # [H, D]
-    kn = kn_ref[0]                       # [H, D]
-    vn = vn_ref[0]
+    # Block-diagonal query: row h keeps head h's lanes of the scaled q.
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+    own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
+    qbd = jnp.where(own, q_ref[0] * sm_scale, 0.0)           # [Hp, H*D]
+    highest = jax.lax.Precision.HIGHEST
 
-    # Copy-through scatter: every block writes back what it read, except
-    # the scatter block inserts the new K/V row at position `length`.
-    # Writing every block (out index map == in index map) keeps the
-    # aliased arena well-defined under any block write-back schedule; a
-    # write-once-at-the-scatter-block design would depend on unwritten
-    # output windows preserving their aliased input, which Pallas does not
-    # promise.
-    off = length - (length // block_s) * block_s
-    ins = (ik == length // block_s) & (jax.lax.broadcasted_iota(
-        jnp.int32, (block_s, 1, 1), 0) == off)
-    ko_ref[0, 0] = jnp.where(ins, kn[None], k_blk)
-    vo_ref[0, 0] = jnp.where(ins, vn[None], v_blk)
-
-    # Masked single-query scores over the OLD prefix content: strictly
-    # pos < length (position `length` is the new token, folded below).
-    s = jnp.sum(q[None] * k_blk, axis=-1) * sm_scale      # [block_s, H]
-    pos = ik * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, (block_s, 1), 0)
-    s = jnp.where(pos < length, s, _NEG_INF)
-
-    m_prev = m_ref[:]                                     # [1, H]
-    m_cur = jnp.max(s, axis=0, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    safe_m = jnp.where(m_new <= _NEG_INF, 0.0, m_new)
-    p = jnp.exp(jnp.where(s <= _NEG_INF, -jnp.inf, s) - safe_m)
-    corr = jnp.where(m_prev <= _NEG_INF, 0.0, jnp.exp(m_prev - safe_m))
-    m_ref[:] = m_new
-    l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=0, keepdims=True)
-    h = acc_ref.shape[0]
-    acc_ref[:] = (acc_ref[:] * corr.reshape(h, 1)
-                  + jnp.sum(p[:, :, None] * v_blk, axis=0))  # [H, D]
+    @pl.when(ik * block_s < length)
+    def _block():
+        # Scores over the OLD prefix content: strictly pos < length
+        # (position `length` is the new token, folded in below).
+        s = jax.lax.dot_general(
+            qbd, k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=highest)
+        pos = ik * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = pos < length                                 # [Hp, block_s]
+        s = jnp.where(valid, s, _NEG_INF)
+        m_prev = m_ref[...]                                  # [Hp, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # Block 0 always holds position 0 < length, so m_new is a real
+        # score whenever this body runs.
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p, v_ref[...], preferred_element_type=jnp.float32,
+            precision=highest)                               # [Hp, H*D]
 
     @pl.when(ik == nk - 1)
     def _finalize():
+        kn, vn = kn_ref[0], vn_ref[0]                        # [1, H*D]
         # Fold in the new token (position `length`, value kn/vn) from
         # registers — it is always valid, so the denominator is > 0 and
-        # fully-masked-prefix lanes (length == 0, i.e. padded lanes on the
+        # lanes with an empty prefix (length == 0, i.e. padded lanes on the
         # dummy row) come out as exactly vn instead of NaN.
-        s_new = jnp.sum(q * kn, axis=-1)[None] * sm_scale  # [1, H]
-        m_fin = jnp.maximum(m_ref[:], s_new)
+        s_new = jnp.sum(qbd * kn, axis=1, keepdims=True)     # [Hp, 1]
+        m_fin = jnp.maximum(m_ref[...], s_new)
         p_new = jnp.exp(s_new - m_fin)
-        corr_f = jnp.where(m_ref[:] <= _NEG_INF, 0.0,
-                           jnp.exp(m_ref[:] - m_fin))
-        l_fin = l_ref[:] * corr_f + p_new
-        acc_f = (acc_ref[:] * corr_f.reshape(h, 1)
-                 + p_new.reshape(h, 1) * vn)
-        o_ref[0] = (acc_f / l_fin.reshape(h, 1)).astype(o_ref.dtype)
+        corr = jnp.exp(m_ref[...] - m_fin)
+        l_fin = l_ref[...] * corr + p_new
+        acc = (acc_ref[...] * corr + p_new * vn) / l_fin
+        o_ref[0] = jnp.sum(jnp.where(own, acc, 0.0), axis=0,
+                           keepdims=True).astype(o_ref.dtype)
+        # The one write into the arena: the new row, inside its row group.
+        for copy in group_copies(read=True):
+            copy.wait()
+        ins = jax.lax.broadcasted_iota(
+            jnp.int32, kbuf.shape, 0) == length - g0
+        kbuf[...] = jnp.where(ins, kn, kbuf[...])
+        vbuf[...] = jnp.where(ins, vn, vbuf[...])
+        for copy in group_copies(read=False):
+            copy.start()
+        for copy in group_copies(read=False):
+            copy.wait()
 
 
 @functools.partial(jax.jit, static_argnames=("layer", "block_s",
@@ -135,84 +173,92 @@ def _decode_kernel(rows_ref, lens_ref,           # scalar prefetch
 def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
                           layer: int, block_s: int | None = None,
                           interpret: bool = False):
-    """One layer's fused decode wave over the KV arena.
+    """One layer's decode wave over the KV arena.
 
-    k_arena/v_arena: ``[L, R, S, H, D]``; q/k_new/v_new: ``[B, H, D]``;
+    k_arena/v_arena: ``[L, R, S, H*D]``; q/k_new/v_new: ``[B, H, D]``;
     rows/lens: ``[B]`` int32 (lane → arena row, valid prefix length).
-    Returns ``(k_arena, v_arena, o)`` with the new K/V scattered at
-    ``(layer, rows[b], lens[b])`` in place (donation-friendly: the arena
-    operands are aliased to the outputs) and ``o: [B, H, D]`` the
-    attention read over positions ``0 .. lens[b]`` inclusive.
+    Returns ``(k_arena, v_arena, o)`` with the new K/V written at
+    ``(layer, rows[b], lens[b])`` in place (the arena operands are aliased
+    to the outputs, so a donated arena is never copied) and ``o: [B, H, D]``
+    the attention read over positions ``0 .. lens[b]`` inclusive.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    _, _, s, h, d = k_arena.shape
-    bsz = q.shape[0]
+    _, _, s, hd = k_arena.shape
+    bsz, h, d = q.shape
+    if h * d != hd:
+        raise ValueError(f"arena rows hold {hd} features, q has {h} x {d}")
     if block_s is None:
         block_s = pick_block_s(s)
     if s % block_s:
         raise ValueError(f"block_s ({block_s}) must divide max_seq_len "
                          f"({s})")
-    sm_scale = 1.0 / np.sqrt(d)
-    grid = (bsz, s // block_s)
+    hp = -(-h // 8) * 8                  # heads padded to whole sublanes
+    group = math.gcd(s, _ROW_GROUP)
 
     def arena_map(b, ik, rows, lens):
-        return (layer, rows[b], ik, 0, 0)
+        # Blocks beyond the last valid position repeat that block's index:
+        # the pipeline does not fetch an unchanged block again.
+        last = jnp.maximum(lens[b] - 1, 0) // block_s
+        return (layer, rows[b], jnp.minimum(ik, last), 0)
 
     def lane_map(b, ik, rows, lens):
         return (b, 0, 0)
 
+    block = pl.BlockSpec((None, None, block_s, hd), arena_map)
+    vec = pl.BlockSpec((1, 1, hd), lane_map)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_s, h, d), arena_map),   # k arena
-            pl.BlockSpec((1, 1, block_s, h, d), arena_map),   # v arena
-            pl.BlockSpec((1, h, d), lane_map),                # q
-            pl.BlockSpec((1, h, d), lane_map),                # k_new
-            pl.BlockSpec((1, h, d), lane_map),                # v_new
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_s, h, d), arena_map),   # k arena out
-            pl.BlockSpec((1, 1, block_s, h, d), arena_map),   # v arena out
-            pl.BlockSpec((1, h, d), lane_map),                # o
-        ],
+        grid=(bsz, s // block_s),
+        in_specs=[block, block, vec, vec, vec],    # k, v arena; q, kn, vn
+        out_specs=[in_hbm, in_hbm, vec],           # k, v arena; o
         scratch_shapes=[
-            pltpu.VMEM((1, h), jnp.float32),    # running max
-            pltpu.VMEM((1, h), jnp.float32),    # running denominator
-            pltpu.VMEM((h, d), jnp.float32),    # weighted accumulator
+            pltpu.VMEM((hp, 1), jnp.float32),      # running max
+            pltpu.VMEM((hp, 1), jnp.float32),      # running denominator
+            pltpu.VMEM((hp, hd), jnp.float32),     # weighted accumulator
+            pltpu.VMEM((group, hd), k_arena.dtype),    # K row group
+            pltpu.VMEM((group, hd), v_arena.dtype),    # V row group
+            pltpu.SemaphoreType.DMA((4,)),
         ],
     )
-    kernel = functools.partial(_decode_kernel, block_s=block_s,
-                               sm_scale=sm_scale)
-    return pl.pallas_call(
+    kernel = functools.partial(_decode_kernel, layer=layer, block_s=block_s,
+                               head_dim=d, sm_scale=1.0 / np.sqrt(d))
+    block_bytes = block_s * hd * k_arena.dtype.itemsize
+    k_out, v_out, o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(k_arena.shape, k_arena.dtype),
             jax.ShapeDtypeStruct(v_arena.shape, v_arena.dtype),
-            jax.ShapeDtypeStruct((bsz, h, d), q.dtype),
+            jax.ShapeDtypeStruct((bsz, 1, hd), q.dtype),
         ],
         # Operand indices count the scalar-prefetch args: rows=0, lens=1,
         # k_arena=2, v_arena=3.
         input_output_aliases={2: 0, 3: 1},
+        # K and V blocks double-buffered, plus the matmuls' operand copies.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(100 << 20, 10 * block_bytes + (16 << 20))),
         interpret=interpret,
-    )(rows, lens, k_arena, v_arena, q, k_new, v_new)
+    )(rows, lens, k_arena, v_arena, q.reshape(bsz, 1, hd),
+      k_new.reshape(bsz, 1, hd), v_new.reshape(bsz, 1, hd))
+    return k_out, v_out, o.reshape(bsz, h, d)
 
 
 def reference_decode_attention(k_arena, v_arena, q, k_new, v_new, rows,
                                lens, *, layer: int):
     """XLA oracle with the reference path's exact semantics (scatter the
     new K/V, gather the rows, dense masked softmax over ``pos <= len``) —
-    the parity target for the fused kernel, kept next to it like
-    ``reference_attention`` is for flash."""
-    d = q.shape[-1]
+    the parity target for the kernel, kept next to it like
+    ``reference_attention`` is for flash.  Same arena layout, same
+    signature."""
+    bsz, h, d = q.shape
     s = k_arena.shape[2]
-    k_arena = k_arena.at[layer, rows, lens].set(k_new)
-    v_arena = v_arena.at[layer, rows, lens].set(v_new)
-    ck = k_arena[layer, rows]                       # [B, S, H, D]
-    cv = v_arena[layer, rows]
+    k_arena = k_arena.at[layer, rows, lens].set(k_new.reshape(bsz, h * d))
+    v_arena = v_arena.at[layer, rows, lens].set(v_new.reshape(bsz, h * d))
+    ck = k_arena[layer, rows].reshape(bsz, s, h, d)
+    cv = v_arena[layer, rows].reshape(bsz, s, h, d)
     scores = jnp.einsum("bhd,bshd->bhs", q, ck) / np.sqrt(d)
     mask = jnp.arange(s)[None, :] <= lens[:, None]
     scores = jnp.where(mask[:, None, :], scores, _NEG_INF)

@@ -290,6 +290,10 @@ class ShardedTinyGptBackend(TinyGptBackend):
         if mesh is None:
             mesh = make_mesh(axes=("tp",))
         self.mesh = mesh
+        # GSPMD partitions the XLA decode step over the mesh; a Mosaic
+        # custom call has no partitioning rule, so the tp families serve
+        # the scatter/gather step, not the single-chip kernel.
+        kw.setdefault("attn_impl", "reference")
         super().__init__(name=name, n_heads=n_heads, **kw)
         tp = int(mesh.shape["tp"])
         if self.n_heads % tp:
@@ -327,17 +331,17 @@ class ShardedTinyGptBackend(TinyGptBackend):
 
 def _place_arena_heads_sharded(mesh, arena):
     """KV-arena placement shared by the sharded generative families:
-    k/v [L, cap+1, S, H, D] shard their heads axis with the tp weight
-    splits (dropped when the mesh has no tp); the per-row token slots and
-    any other small plane replicate (tiny, read by every shard)."""
+    k/v [L, cap+1, S, H*D] shard their feature axis with the tp weight
+    splits, whole heads per shard (dropped when the mesh has no tp); the
+    per-row token slots and any other small plane replicate (tiny, read by
+    every shard)."""
     import jax
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    kv = NamedSharding(mesh, P(None, None, None, drop_absent(mesh, "tp"),
-                               None))
+    kv = NamedSharding(mesh, P(None, None, None, drop_absent(mesh, "tp")))
     rep = NamedSharding(mesh, P())
-    return {name: jax.device_put(a, kv if a.ndim == 5 else rep)
+    return {name: jax.device_put(a, kv if a.ndim == 4 else rep)
             for name, a in arena.items()}
 
 
@@ -398,6 +402,8 @@ class MoeGptBackend(TinyGptBackend):
                 f"n_heads ({n_heads}) must divide by tp ({tp})")
         if d_ff % tp:
             raise ValueError(f"d_ff ({d_ff}) must divide by tp ({tp})")
+        # As ShardedTinyGptBackend: GSPMD partitions the XLA decode step.
+        kw.setdefault("attn_impl", "reference")
         super().__init__(name=name, n_layers=n_layers, d_model=d_model,
                          n_heads=n_heads, d_ff=d_ff, vocab=vocab,
                          max_seq_len=max_seq_len, max_streams=max_streams,

@@ -992,9 +992,10 @@ class TestFlashPrefill:
 
 
 class TestFusedDecode:
-    """attn_impl='fused' (ops/decode_kernel.py) and the row-sharded arena
-    (parallel/kv_shard.py) must be invisible: token streams bit-identical
-    to the reference decode path, greedy and sampled, solo and batched."""
+    """The served decode step (attn_impl='fused', ops/decode_kernel.py)
+    and the row-sharded arena (parallel/kv_shard.py) must be invisible:
+    token streams bit-identical to the oracle step (attn_impl='reference'),
+    greedy and sampled, solo and batched, chunked and not."""
 
     KW = dict(n_layers=2, d_model=64, n_heads=2, d_ff=128, vocab=128,
               max_seq_len=32, max_streams=4)
@@ -1039,36 +1040,42 @@ class TestFusedDecode:
                              top_k=24, top_p=0.9))
         return out
 
-    def test_fused_matches_reference_token_for_token(self):
-        ref_eng = self._engine(attn_impl="reference")
+    def _oracle_suite(self, **kw):
+        ref_eng = self._engine(attn_impl="reference", **kw)
         try:
-            want = self._stream_suite(ref_eng)
+            return self._stream_suite(ref_eng)
         finally:
             ref_eng.shutdown()
-        fus_eng = self._engine(attn_impl="fused")
-        try:
-            assert self._stream_suite(fus_eng) == want
-        finally:
-            fus_eng.shutdown()
 
-    def test_sharded_arena_matches_and_serves_two_shards(self):
-        ref_eng = self._engine(attn_impl="reference")
+    @pytest.mark.parametrize("kv_shards", [1, 2, 4])
+    def test_kernel_matches_oracle_token_for_token(self, kv_shards):
+        """One chip, and the arena's rows over 2 and 4 mesh shards."""
+        want = self._oracle_suite()
+        eng = self._engine(attn_impl="fused", kv_shards=kv_shards)
         try:
-            want = self._stream_suite(ref_eng)
+            sched = eng._schedulers["tg"]
+            assert sched.arena_shards() == kv_shards
+            if kv_shards > 1:
+                assert sched.model.backend._mesh().shape["kv"] == kv_shards
+            assert self._stream_suite(eng) == want
         finally:
-            ref_eng.shutdown()
-        shd_eng = self._engine(attn_impl="fused", kv_shards=2)
-        try:
-            sched = shd_eng._schedulers["tg"]
-            assert sched.arena_shards() == 2
-            mesh = sched.model.backend._mesh()
-            assert mesh.shape["kv"] == 2
-            assert self._stream_suite(shd_eng) == want
-        finally:
-            shd_eng.shutdown()
+            eng.shutdown()
 
-    def test_sharded_batched_streams_match_solo(self):
-        eng = self._engine(attn_impl="fused", kv_shards=2)
+    def test_kernel_matches_oracle_at_gpt2_head_geometry(self):
+        """12 heads x 64 on a 768-lane arena row, through the engine."""
+        kw = dict(d_model=768, n_heads=12, d_ff=256)
+        want = self._oracle_suite(**kw)
+        eng = self._engine(attn_impl="fused", **kw)
+        try:
+            arena = eng._schedulers["tg"]._arena
+            assert arena["k"].shape == (2, 5, 32, 768)
+            assert self._stream_suite(eng) == want
+        finally:
+            eng.shutdown()
+
+    @pytest.mark.parametrize("kv_shards", [1, 2, 4])
+    def test_batched_streams_match_solo(self, kv_shards):
+        eng = self._engine(attn_impl="fused", kv_shards=kv_shards)
         try:
             prompts = [[i + 1, i + 2] for i in range(6)]
             solo = [self._gen(eng, p, 6) for p in prompts]
@@ -1092,14 +1099,16 @@ class TestFusedDecode:
         finally:
             eng.shutdown()
 
-    def test_chunked_decode_identical_on_fused_path(self, monkeypatch):
-        want_eng = self._engine(attn_impl="fused")
+    @pytest.mark.parametrize("kv_shards", [1, 2])
+    def test_chunked_decode_identical_to_unchunked(self, monkeypatch,
+                                                   kv_shards):
+        want_eng = self._engine(attn_impl="fused", kv_shards=kv_shards)
         try:
             want = self._gen(want_eng, [7, 8], 13)
         finally:
             want_eng.shutdown()
         monkeypatch.setenv("CLIENT_TPU_GEN_CHUNK", "4")
-        chunk_eng = self._engine(attn_impl="fused")
+        chunk_eng = self._engine(attn_impl="fused", kv_shards=kv_shards)
         try:
             assert self._gen(chunk_eng, [7, 8], 13) == want
         finally:
@@ -1108,14 +1117,31 @@ class TestFusedDecode:
     def test_env_var_selects_impl(self, monkeypatch):
         from client_tpu.models.generate import TinyGptBackend
 
-        monkeypatch.setenv("CLIENT_TPU_ATTN_IMPL", "fused")
-        assert TinyGptBackend(name="e1", **self.KW).attn_impl == "fused"
-        monkeypatch.delenv("CLIENT_TPU_ATTN_IMPL")
-        assert TinyGptBackend(name="e2", **self.KW).attn_impl == "reference"
-        # Explicit ctor arg wins over the env default.
         monkeypatch.setenv("CLIENT_TPU_ATTN_IMPL", "reference")
-        assert TinyGptBackend(name="e3", attn_impl="fused",
+        assert TinyGptBackend(name="e1", **self.KW).attn_impl == "reference"
+        # Explicit ctor arg wins over the env.
+        assert TinyGptBackend(name="e2", attn_impl="fused",
                               **self.KW).attn_impl == "fused"
+        monkeypatch.delenv("CLIENT_TPU_ATTN_IMPL")
+        assert TinyGptBackend(name="e3", **self.KW).attn_impl == ""
+        # A sharded arena is the kernel's whatever the platform.
+        assert TinyGptBackend(name="e4", kv_shards=2,
+                              **self.KW).attn_impl == "fused"
+
+    @pytest.mark.parametrize("interpreted,step", [
+        (False, "_fused_decode_fn"), (True, "decode")])
+    def test_unset_the_platform_decides(self, monkeypatch, interpreted,
+                                        step):
+        """No setting: the kernel wherever Mosaic compiles it, the XLA step
+        where Pallas would only be interpreted (this suite's CPU)."""
+        from client_tpu.engine import backend_init
+        from client_tpu.models.generate import TinyGptBackend
+
+        monkeypatch.delenv("CLIENT_TPU_ATTN_IMPL", raising=False)
+        monkeypatch.setattr(backend_init, "pallas_interpret",
+                            lambda: interpreted)
+        backend = TinyGptBackend(name="p", **self.KW)
+        assert step in backend.decode_fn().__qualname__
 
     def test_invalid_configs_rejected(self):
         from client_tpu.models.generate import TinyGptBackend

@@ -160,8 +160,8 @@ def _decode_case(layers=2, rows=5, s=32, h=2, d=16, bsz=4, seed=0):
     """A populated arena + one wave of lane inputs. Lane 3 is a padded
     lane parked on the dummy row (len 0) like the scheduler pads waves."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    k_arena = jax.random.normal(ks[0], (layers, rows, s, h, d))
-    v_arena = jax.random.normal(ks[1], (layers, rows, s, h, d))
+    k_arena = jax.random.normal(ks[0], (layers, rows, s, h * d))
+    v_arena = jax.random.normal(ks[1], (layers, rows, s, h * d))
     q = jax.random.normal(ks[2], (bsz, h, d))
     kn = jax.random.normal(ks[3], (bsz, h, d))
     vn = jax.random.normal(ks[4], (bsz, h, d))
@@ -234,7 +234,8 @@ class TestFusedDecodeKernel:
 
     def test_pick_block_s(self):
         assert pick_block_s(32) == 32
-        assert pick_block_s(256) == 128
+        assert pick_block_s(256) == 256
+        assert pick_block_s(1024) == 512
         assert pick_block_s(256, cap=64) == 64
         assert pick_block_s(24) == 24
         assert pick_block_s(7) == 7  # no aligned divisor: whole row
@@ -244,6 +245,85 @@ class TestFusedDecodeKernel:
         with pytest.raises(ValueError, match="divide"):
             decode_wave_attention(k_a, v_a, q, kn, vn, rows, lens,
                                   layer=0, block_s=24, interpret=True)
+
+
+class TestDecodeKernelGpt2Geometry:
+    """The arena access at GPT-2's head geometry (12 heads x 64 on a
+    768-lane row): the kernel against the XLA oracle where the block does
+    not divide every length, at the row's edges, with padded lanes parked
+    on the dummy row, and with every row and position a wave does not
+    touch bitwise unchanged."""
+
+    H, D, S, ROWS = 12, 64, 48, 7
+
+    def _case(self, lens, seed=5):
+        bsz = len(lens)
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+        shape = (2, self.ROWS, self.S, self.H * self.D)
+        k_a = jax.random.normal(ks[0], shape)
+        v_a = jax.random.normal(ks[1], shape)
+        q, kn, vn = (jax.random.normal(k, (bsz, self.H, self.D))
+                     for k in ks[2:])
+        # Real lanes take rows 0..; a length of None is a padded lane:
+        # the dummy row (the last), length 0.
+        rows = [self.ROWS - 1 if n is None else i
+                for i, n in enumerate(lens)]
+        return (k_a, v_a, q, kn, vn, jnp.asarray(rows, jnp.int32),
+                jnp.asarray([n or 0 for n in lens], jnp.int32))
+
+    @pytest.mark.parametrize("block_s", [8, 16, 24, 48])
+    def test_matches_oracle_where_block_splits_lengths(self, block_s):
+        lens = [0, 1, 7, 8, 23, 47]          # 47 = S - 1, the row's end
+        k_a, v_a, q, kn, vn, rows, lens_a = self._case(lens)
+        fk, fv, fo = decode_wave_attention(
+            k_a, v_a, q, kn, vn, rows, lens_a, layer=1, block_s=block_s,
+            interpret=True)
+        rk, rv, ro = reference_decode_attention(
+            k_a, v_a, q, kn, vn, rows, lens_a, layer=1)
+        assert float(jnp.max(jnp.abs(fo - ro))) < 2e-5
+        np.testing.assert_array_equal(np.asarray(fk), np.asarray(rk))
+        np.testing.assert_array_equal(np.asarray(fv), np.asarray(rv))
+
+    def test_padded_lanes_on_the_dummy_row(self):
+        lens = [5, None, 30, None, None]
+        k_a, v_a, q, kn, vn, rows, lens_a = self._case(lens)
+        fk, fv, fo = decode_wave_attention(
+            k_a, v_a, q, kn, vn, rows, lens_a, layer=0, block_s=16,
+            interpret=True)
+        _, _, ro = reference_decode_attention(
+            k_a, v_a, q, kn, vn, rows, lens_a, layer=0)
+        live = np.asarray([n is not None for n in lens])
+        assert float(jnp.max(jnp.abs(fo[live] - ro[live]))) < 2e-5
+        # A padded lane attends to its own token alone: exactly v_new.
+        np.testing.assert_allclose(np.asarray(fo[~live]),
+                                   np.asarray(vn[~live]), rtol=1e-6)
+        # The dummy row took the junk; nothing beyond its position 0 did.
+        dummy = self.ROWS - 1
+        np.testing.assert_array_equal(np.asarray(fk[0, dummy, 1:]),
+                                      np.asarray(k_a[0, dummy, 1:]))
+
+    def test_a_wave_writes_one_position_per_lane_and_nothing_else(self):
+        lens = [3, 47, None, 16]
+        k_a, v_a, q, kn, vn, rows, lens_a = self._case(lens)
+        layer = 1
+        fk, fv, _ = decode_wave_attention(
+            k_a, v_a, q, kn, vn, rows, lens_a, layer=layer, block_s=24,
+            interpret=True)
+        touched = np.zeros(k_a.shape[:3], bool)
+        touched[layer, np.asarray(rows), np.asarray(lens_a)] = True
+        for got, before, new in ((fk, k_a, kn), (fv, v_a, vn)):
+            got, before = np.asarray(got), np.asarray(before)
+            np.testing.assert_array_equal(got[~touched], before[~touched])
+            for b in (0, 1, 3):
+                np.testing.assert_array_equal(
+                    got[layer, int(rows[b]), int(lens_a[b])],
+                    np.asarray(new[b]).reshape(-1))
+
+    def test_feature_width_must_match_the_arena(self):
+        k_a, v_a, q, kn, vn, rows, lens_a = self._case([1, 2])
+        with pytest.raises(ValueError, match="features"):
+            decode_wave_attention(k_a, v_a, q[:, :6], kn[:, :6], vn[:, :6],
+                                  rows, lens_a, layer=0, interpret=True)
 
 
 class TestShardedKvArena:
@@ -283,20 +363,22 @@ class TestShardedKvArena:
         np.testing.assert_allclose(out, want, rtol=1e-6)
         assert not tpu_interpret.races.races_found
 
-    @pytest.mark.parametrize("combine", ["ring", "psum"])
-    def test_sharded_matches_single_chip(self, combine):
-        """2 mesh shards over the row-sharded arena == the single-chip
-        fused kernel on the free rows, and == the XLA reference."""
-        cap, n = 4, 2
+    @pytest.mark.parametrize("n,combine", [(2, "ring"), (2, "psum"),
+                                           (4, "ring"), (4, "psum")])
+    def test_sharded_matches_single_chip(self, n, combine):
+        """2 and 4 mesh shards over the row-sharded arena == the
+        single-chip kernel on the free rows, and == the XLA reference."""
+        cap = 4
         total, free, _dummy = arena_row_layout(cap, n)
         layers, s, h, d, bsz = 2, 16, 2, 8, 3
         ks = jax.random.split(jax.random.PRNGKey(3), 5)
-        k_a = jax.random.normal(ks[0], (layers, total, s, h, d))
-        v_a = jax.random.normal(ks[1], (layers, total, s, h, d))
+        k_a = jax.random.normal(ks[0], (layers, total, s, h * d))
+        v_a = jax.random.normal(ks[1], (layers, total, s, h * d))
         q = jax.random.normal(ks[2], (bsz, h, d))
         kn = jax.random.normal(ks[3], (bsz, h, d))
         vn = jax.random.normal(ks[4], (bsz, h, d))
-        # Lanes on both shards: global rows 0 (shard 0), 3 and 4 (shard 1).
+        # Lanes on more than one shard: the first, third and last free
+        # rows (2 shards: rows 0 | 3, 4; 4 shards: rows 0 | 4 | 6).
         rows = jnp.asarray([free[0], free[2], free[3]], jnp.int32)
         lens = jnp.asarray([5, 0, s - 1], jnp.int32)
 
