@@ -4,14 +4,17 @@
     python3 benchmark/selftest.py            # exit 0 = all checks hold
     python3 benchmark/selftest.py --quick    # skip the end-to-end rehearsal
 
-1. The quantile-stratified generator offers, for two seeds, the same
-   request count, the same multiset of prompt and output lengths and the
-   same token total, in different orders and at different arrival times.
+1. At the manifest's ``run_seconds`` the quantile-stratified generator
+   offers, for two seeds, the same request count, the same multiset of
+   prompt and output lengths and the same token total, in different orders
+   and at different arrival times.
 2. The trace reduction reproduces known busy and idle figures on the small
    recorded trace kept beside it (``testdata/``) and on a hand-made one.
 3. The roofline functions match operations counted by hand for one BERT
    step and one decode wave; the ``bert`` family's reference, which no cell
-   runs today, agrees with the program's BertBackend at a tiny size.
+   runs today, agrees with the program's BertBackend at a tiny size; PR 27's
+   readers and the rule that chose the window and the bound
+   (``testdata/check_readers.py``, ``testdata/check_spread.py``).
 4. The final line of a (rehearsed) run parses and holds only the contract's
    keys.
 
@@ -52,12 +55,13 @@ def test_generator() -> None:
         manifest = json.load(f)
     files = {c["name"]: os.path.join(ROOT, c["file"])
              for c in manifest["configs"]}
+    seconds = float(manifest["run_seconds"])
     for cell in manifest["workloads"]:
         cfg = T.load_json(files[cell["config"]])
         tr = T.load_json(os.path.join(HERE, "traffic",
                                       cell["traffic"] + ".json"))
-        a = T.build_plan(cfg, tr, 7, 20.0, "window")
-        b = T.build_plan(cfg, tr, 3000000019, 20.0, "window")
+        a = T.build_plan(cfg, tr, 7, seconds, "window")
+        b = T.build_plan(cfg, tr, 3000000019, seconds, "window")
         check(a.multiset() == b.multiset(),
               f"{cell['name']}: two seeds offer the same count "
               f"({len(a)}), length multiset and token total "
@@ -66,13 +70,13 @@ def test_generator() -> None:
                    or a.bodies[0] != b.bodies[0])
         check(differs, f"{cell['name']}: the seeds differ in order or ids")
         if tr["loop"] == "open":
-            check(len(a) == round(tr["rate_per_s"] * 20.0)
+            check(len(a) == round(tr["rate_per_s"] * seconds)
                   and a.due.tolist() != b.due.tolist()
-                  and (a.due >= 0).all() and (a.due < 20.0).all()
+                  and (a.due >= 0).all() and (a.due < seconds).all()
                   and (a.due[1:] >= a.due[:-1]).all(),
                   f"{cell['name']}: N = rate x seconds arrivals, sorted, "
                   f"inside the window, placed by the seed")
-        again = T.build_plan(cfg, tr, 7, 20.0, "window")
+        again = T.build_plan(cfg, tr, 7, seconds, "window")
         check(again.bodies == a.bodies and again.due.tolist()
               == a.due.tolist(), f"{cell['name']}: same seed, same bytes")
 
@@ -125,6 +129,17 @@ def test_trace_reduction() -> None:
     check(out.returncode == 0, "the family kept without a cell still "
           "agrees with the program"
           + ("" if out.returncode == 0 else "\n" + out.stderr))
+    for script, what in (
+            ("check_readers.py", "PR 27's readers read the recorded trace "
+             "and a hand-made context"),
+            ("check_spread.py", "BENCHMARK.json's window and bounds are "
+             "what the rule gives on the recorded sets")):
+        out = subprocess.run(
+            [sys.executable, os.path.join(HERE, "testdata", script)],
+            env=env, capture_output=True, text=True, timeout=300)
+        print(out.stdout, end="")
+        check(out.returncode == 0,
+              what + ("" if out.returncode == 0 else "\n" + out.stderr))
 
 
 def test_final_line() -> None:
