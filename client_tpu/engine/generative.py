@@ -6,13 +6,31 @@ autoregressive backends: every *decode step* is shared across all live
 generation streams. Design, TPU-first:
 
 - The KV cache is a fixed-capacity HBM **arena** pytree owned by one worker
-  (``backend.init_arena``; +1 dummy row absorbs padded lanes), donated into
-  every jitted call so updates are in-place. The arena carries each row's
-  latest token ON DEVICE (``arena["tok"]``), so consecutive decode waves
-  chain with no host round trip between them.
+  (``backend.init_arena``; +1 dummy slot absorbs padded lanes), donated into
+  every jitted call so updates are in-place. What a stream's slot holds is
+  the backend's business: one row per position of ``max_seq_len`` for a
+  full-attention decoder (float32 in ``TinyGptBackend``), the model's own
+  state for another (``models/evabyte.py``: bfloat16 chunk summaries and a
+  window of exact rows). The arena carries each slot's latest token ON DEVICE
+  (``arena["tok"]``), so consecutive decode waves chain with no host round
+  trip between them.
 - **Prefill** (one jit per prompt bucket, admit lanes padded to one fixed
   bucket) writes a batch of prompts' K/V into their arena rows and emits
   each prompt's first token.
+- **Prefill by pieces** (a backend that declares ``prefill_piece =
+  (positions, lanes)``): a prompt is admitted once and consumed ``positions``
+  at a time, the cache carrying the state between pieces; between two decode
+  waves at most one piece is dispatched, so a token gap is bounded by a wave
+  plus a piece, and the first token follows the last piece.  One compiled
+  prefill program, whatever the prompt's length.
+- **Transitions** (a backend that declares ``transition_due(n)`` and
+  ``transition_fn()``): a stream whose dispatch-side length ``n`` is due has
+  the jitted transition queued before its next wave (span
+  ``gen.transition_dispatch``).  The worker knows every stream's
+  dispatch-side length, so the order needs no fetch and no host sync.
+  The scheduler learns all of this from the hooks, never from a model's name;
+  a backend without them takes the paths above with the programs it always
+  compiled.
 - **Decode waves** (one jit per stream-count bucket) advance every live
   stream one token in a single XLA execution: gather input tokens from the
   device-side slots, scatter new K/V at each stream's position, masked
@@ -80,7 +98,8 @@ _log = logging.getLogger("client_tpu")
 class _Stream:
     __slots__ = ("req", "row", "disp_len", "disp_tokens", "f_len",
                  "emitted", "max_new", "seed", "temp", "top_k", "top_p",
-                 "stop", "dead", "throttled_since")
+                 "stop", "dead", "throttled_since", "ids", "consumed",
+                 "transition", "t_prefill")
 
     def __init__(self, req, row, plen, max_new,
                  seed=0, temp=0.0, top_k=0, top_p=1.0, stop=frozenset()):
@@ -98,17 +117,29 @@ class _Stream:
         self.stop = stop          # token ids terminating the stream
         self.dead = False         # retired/cancelled (skip pending lanes)
         self.throttled_since = None  # monotonic mark while backpressured
+        # Prefill by pieces: the prompt still to consume (None once its last
+        # piece is dispatched, and always for a one-shot prefill), how much
+        # of it is dispatched, and when its first piece was.
+        self.ids = None
+        self.consumed = 0
+        self.t_prefill = 0
+        self.transition = False   # a cache transition is due before a wave
+
+
+# The lane of a prefill piece whose prompt is not finished by it.
+_NO_STREAM = _Stream(None, -1, 0, 0)
+_NO_STREAM.dead = True
 
 
 class _Inflight:
     """One dispatched execution whose token fetch is pending."""
 
     __slots__ = ("kind", "streams", "tokens", "waves", "t_disp", "bucket",
-                 "depth", "positions")
+                 "depth", "positions", "rows")
 
     def __init__(self, kind, streams, tokens, waves=1, t_disp=0, bucket=0,
-                 depth=0, positions=0):
-        self.kind = kind          # 'prefill' | 'wave' | 'chunk'
+                 depth=0, positions=0, rows=(0, 0)):
+        self.kind = kind          # 'prefill' | 'piece' | 'wave' | 'chunk'
         self.streams = streams    # lane order, real lanes only
         self.tokens = tokens      # jax.Array future (copy_to_host_async'd)
         self.waves = waves        # logical waves this dispatch advances
@@ -116,6 +147,7 @@ class _Inflight:
         self.bucket = bucket      # wave bucket (0 for prefill)
         self.depth = depth        # waves in flight at a prefill's dispatch
         self.positions = positions  # valid context positions it reads
+        self.rows = rows          # cache rows it reads: (summary, exact)
 
 
 class _WarmupReq:
@@ -255,20 +287,37 @@ class GenerativeScheduler(Scheduler):
         # like any retired lane.  Admits join at chunk boundaries (<= K-1
         # waves of extra TTFT, ~K*step_ms).
         self._chunk = max(1, envcfg.env_int("CLIENT_TPU_GEN_CHUNK"))
+        # What a backend may declare about a cache that is not one slot per
+        # position (module docstring): prefill by pieces, the rows a step
+        # reads, and a transition ordered between two waves.
+        piece = getattr(backend, "prefill_piece", None)
+        self._piece_len, self._piece_lanes = (
+            (int(piece[0]), int(piece[1])) if piece else (0, 0))
+        self._cache_rows = getattr(backend, "cache_rows", None)
+        self._transition_due = getattr(backend, "transition_due", None)
+        self._transition = None
+        if callable(self._transition_due):
+            self._transition = jax.jit(
+                _sp.named_step(backend.transition_fn(), _sp.STEP_TRANSITION),
+                donate_argnums=(1,))
+            # A transition may fall between any two steps of a stream, so
+            # waves are dispatched one at a time.
+            self._chunk = 1
         self._decode_chunk = None
         if self._chunk > 1:
             self._decode_chunk = jax.jit(
                 _sp.named_step(backend.decode_chunk_fn(),
                                _sp.STEP_DECODE_CHUNK),
                 donate_argnums=(1,), static_argnums=(8, 9))
-        self._prompt_buckets = power_buckets(self._max_seq)
+        self._prompt_buckets = ([self._piece_len] if self._piece_len
+                                else power_buckets(self._max_seq))
         self._wave_buckets = power_buckets(self._cap)
         # ONE admit lane bucket: every prefill chunk pads to this, so there
         # is exactly one compiled prefill executable per prompt bucket
         # (round-3's power-of-two admit lanes compiled per (lane, prompt)
         # pair — a lane size first seen under load stalled every stream
         # ~1s mid-measurement).
-        self._admit_lane = min(self._cap, 8)
+        self._admit_lane = self._piece_lanes or min(self._cap, 8)
         # Dispatch-ahead bound: waves in flight before the worker blocks on
         # the oldest fetch. The default (32) was sized to hide a ~70 ms
         # round trip over a ~2 ms device step — a transport that is gone;
@@ -351,7 +400,13 @@ class GenerativeScheduler(Scheduler):
             self._arena, tokens = self._prefill(
                 self.model._params, self._arena, dummy,
                 np.zeros((lane, pb), np.int32), np.ones(lane, np.int32),
-                z_i, z_f, z_i, ones_f, False)
+                z_i, z_f, z_i, ones_f, False,
+                *((z_i,) if self._piece_len else ()))
+        if self._transition is not None:
+            self.model._set_state("warmup: cache transition",
+                                  _sp.STEP_TRANSITION, 1)
+            self._arena = self._transition(
+                self.model._params, self._arena, dummy[:1], z_i[:1])
         for wb in self._wave_buckets:
             self.model._set_state(f"warmup: decode wave bucket={wb}",
                                   _sp.STEP_DECODE, wb)
@@ -429,17 +484,23 @@ class GenerativeScheduler(Scheduler):
             return True
         with span[_sp.S_SWEEP]:
             live = self._sweep()
-        if live:
-            try:
+        pieced = False
+        try:
+            if self._piece_len:
+                pieced = self._dispatch_piece()
+            if live:
+                if self._transition is not None:
+                    self._dispatch_transitions(live)
                 self._dispatch_wave(live)
-            except Exception as exc:  # noqa: BLE001
-                self._reset_arena(exc)
+        except Exception as exc:  # noqa: BLE001
+            self._reset_arena(exc)
         # Consume fetches: non-blocking while results are ready or the
         # pipeline is over depth; forced (blocking on the oldest) when
         # nothing was dispatched — every budget-exhausted stream has
         # its final wave in flight, so this always makes progress.
-        self._drain_fetches(force_one=not live and not pending)
-        if (not live and not pending and not self._inflight
+        self._drain_fetches(
+            force_one=not live and not pending and not pieced)
+        if (not live and not pending and not pieced and not self._inflight
                 and self._streams):
             # Every stream is throttled by transport backpressure:
             # nothing to dispatch, nothing to fetch.  Park briefly so
@@ -516,7 +577,7 @@ class GenerativeScheduler(Scheduler):
             self._rec.exclude(time.monotonic_ns() - t0)
 
     def _has_budget(self, s: _Stream) -> bool:
-        return (not s.dead and s.disp_tokens < s.max_new
+        return (not s.dead and s.ids is None and s.disp_tokens < s.max_new
                 and s.disp_len + 1 < self._max_seq)
 
     def _validate(self, req: InferRequest):
@@ -536,7 +597,10 @@ class GenerativeScheduler(Scheduler):
         if len(ids) + max_new > self._max_seq:
             raise EngineError(
                 f"prompt ({len(ids)}) + max_tokens ({max_new}) exceeds "
-                f"max_seq_len ({self._max_seq})", 400)
+                f"max_seq_len ({self._max_seq})"
+                + (f"; the prompt itself may be any length up to that, "
+                   f"prefilled {self._piece_len} positions a piece"
+                   if self._piece_len else ""), 400)
         vocab = self.model.backend.vocab
         if (ids < 0).any() or (ids >= vocab).any():
             raise EngineError(f"token ids must be in [0, {vocab})", 400)
@@ -565,6 +629,17 @@ class GenerativeScheduler(Scheduler):
                 req.tenant, req.times.queue_ns / 1e9,
                 trace_id=self._trace_id(req))
             ready.append((req, ids, max_new, sampling))
+        if self._piece_len:
+            # Prefill by pieces: a prompt takes its slot now and is consumed
+            # by ``_dispatch_piece``, one piece between two waves.
+            for req, ids, max_new, (seed, temp, top_k, top_p, stop) in ready:
+                stream = _Stream(req, self._free.pop(), len(ids), max_new,
+                                 seed=seed, temp=temp, top_k=top_k,
+                                 top_p=top_p, stop=stop)
+                stream.ids = ids
+                self._streams.append(stream)
+                self._rec.c[_sp.C_PROMPTS_ADMITTED] += 1
+            return
         by_bucket: dict[int, list] = {}
         for entry in ready:
             bucket = next(b for b in self._prompt_buckets
@@ -651,6 +726,80 @@ class GenerativeScheduler(Scheduler):
                                         depth=self._inflight_waves))
         self._inflight_waves += 1
 
+    def _dispatch_piece(self) -> bool:
+        """The next piece of the oldest prompts still prefilling (up to the
+        backend's lanes), as ONE device execution with no host sync; True
+        if one was dispatched.  A prompt's last piece leaves its first token
+        in the slot's device-side token and in the fetch queue, and the
+        stream joins the next wave."""
+        lane, width = self._piece_lanes, self._piece_len
+        todo = [s for s in self._streams if s.ids is not None][:lane]
+        if not todo:
+            return False
+        pad = lane - len(todo)
+        ids_mat = np.zeros((lane, width), np.int32)
+        lens = np.ones(lane, np.int32)
+        starts = np.zeros(lane, np.int32)
+        seeds = np.zeros(lane, np.uint32)
+        temps = np.zeros(lane, np.float32)
+        top_ks = np.zeros(lane, np.int32)
+        top_ps = np.ones(lane, np.float32)
+        for i, s in enumerate(todo):
+            part = s.ids[s.consumed:s.consumed + width]
+            ids_mat[i, :len(part)] = part
+            lens[i], starts[i] = len(part), s.consumed
+            seeds[i] = s.seed & 0xFFFFFFFF
+            temps[i], top_ks[i], top_ps[i] = s.temp, s.top_k, s.top_p
+        rows = np.asarray([s.row for s in todo] + [self._dummy] * pad,
+                          np.int32)
+        self.model._set_state(
+            f"generative prefill piece ({len(todo)} streams, from "
+            f"{[int(x) for x in starts[:len(todo)]]})",
+            _sp.STEP_PREFILL, width)
+        try:
+            with self._rec.span[_sp.S_PREFILL_DISPATCH]:
+                self._arena, tokens = self._prefill(
+                    self.model._params, self._arena, rows, ids_mat, lens,
+                    seeds.astype(np.int32), temps, top_ks, top_ps,
+                    bool((temps > 0.0).any()), starts)
+            tokens.copy_to_host_async()
+        finally:
+            self.model._clear_state()
+        now = time.monotonic_ns()
+        self._rec.c[_sp.C_PREFILL_PIECES] += len(todo)
+        self.stats.record_execution(len(todo))
+        done = []                     # by lane: the stream, if it ended
+        for i, s in enumerate(todo):
+            if not s.consumed:
+                s.t_prefill = now
+            s.consumed += int(lens[i])
+            if s.consumed >= len(s.ids):
+                s.ids = None          # prefilled: live from the next wave
+            done.append(_NO_STREAM if s.ids is not None else s)
+        # The fetch queue keeps dispatch order and the pipeline's depth; a
+        # lane whose prompt goes on carries no stream (its token is junk).
+        ended = [s for s in done if s is not _NO_STREAM]
+        self._inflight.append(_Inflight(
+            "prefill" if ended else "piece", done, tokens,
+            t_disp=min([s.t_prefill for s in ended] or [now]),
+            depth=self._inflight_waves))
+        self._inflight_waves += 1
+        return True
+
+    def _dispatch_transitions(self, live: list) -> None:
+        """Queue the cache transition of every live stream that is due one,
+        before the wave that needs it (device order is dispatch order)."""
+        for s in live:
+            if not s.transition:
+                continue
+            with self._rec.span[_sp.S_TRANSITION_DISPATCH]:
+                self._arena = self._transition(
+                    self.model._params, self._arena,
+                    np.asarray([s.row], np.int32),
+                    np.asarray([s.disp_len], np.int32))
+            s.transition = False
+            self._rec.c[_sp.C_TRANSITIONS] += 1
+
     def _dispatch_wave(self, live: list) -> None:
         """Dispatch decode wave(s) for the live lanes.  Live lanes can
         exceed the largest wave bucket (a ladder edit, a tuner-retired
@@ -717,9 +866,16 @@ class GenerativeScheduler(Scheduler):
         rec.c[_sp.C_DISPATCHES] += 1
         rec.c[_sp.C_INFLIGHT_WAVES] += self._inflight_waves
         positions = k * int(lens.sum()) + len(live) * (k * (k - 1) // 2)
+        n_sum = n_exact = 0
         for s in live:
+            if self._cache_rows is not None:
+                a, b = self._cache_rows(s.disp_len)
+                n_sum, n_exact = n_sum + a, n_exact + b
             s.disp_len += k
             s.disp_tokens += k
+            if self._transition is not None and self._transition_due(
+                    s.disp_len):
+                s.transition = True
         # One device dispatch = one execution in the public stats, chunked
         # or not — execution_count means device executions, and fewer
         # executions per token IS the chunking win the stat should show.
@@ -728,7 +884,8 @@ class GenerativeScheduler(Scheduler):
                                         live, nxt, waves=k,
                                         t_disp=time.monotonic_ns(),
                                         bucket=bucket,
-                                        positions=positions))
+                                        positions=positions,
+                                        rows=(n_sum, n_exact)))
         self._inflight_waves += k
         if (bucket, k) not in self._wave_cost_captured:
             # Once per wave shape: static roofline numerator for this
@@ -792,6 +949,8 @@ class GenerativeScheduler(Scheduler):
                 c[_sp.C_FETCHED_LANES_PADDED] += \
                     head.bucket * head.waves - lanes
                 c[_sp.C_FETCHED_POSITIONS_VALID] += head.positions
+                c[_sp.C_FETCHED_ROWS_SUMMARY] += head.rows[0]
+                c[_sp.C_FETCHED_ROWS_EXACT] += head.rows[1]
                 profiler().record_wave(
                     self.model.config.name, self.model.config.version,
                     bucket=head.bucket, chunk=head.waves,

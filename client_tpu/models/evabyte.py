@@ -1,0 +1,579 @@
+"""EvaByte-style byte-level decoder (`evabyte`): EVA chunked linear attention
+served through the generative path.
+
+The architecture is the public ``EvaByte/EvaByte`` config's (``model_type``
+``evabyte``, ``attention_class`` ``eva``): RMSNorm with a unit offset
+``x / rms(x) * (1 + g)``, rotary positions, a SwiGLU feed-forward, no biases,
+a float32 residual stream and float32 logits over bfloat16 matmuls, an output
+head of ``num_pred_heads x vocab``, and the EVA attention layer
+(arXiv:2302.04542, section 4).  Per head (d = head size, W = ``window``,
+c = ``chunk``; q, k carry RoPE at their own positions), position i lies in
+window j = i // W and attends with **one softmax** to
+
+- the exact keys and values of its own window up to itself
+  (``m // W == j and m <= i``), and
+- one summary per c-position chunk t of every *earlier* window:
+  ``a_m = softmax_{m in t}(phi . k_m)``, ``v~_t = sum_m a_m v_m``,
+  ``k~_t = mean_m k_m + mu`` (phi, mu: learned vectors per head).
+
+**The cache** is the model's own, not one slot per position.  A stream's slot
+is ``[S_rows, H*D]`` per layer and leaf, bfloat16, summaries first and the
+current window's exact rows directly behind them: rows ``[0, n_sum)``
+summaries, rows ``[n_sum, n_sum + w_len)`` the window, and ``live = n_sum +
+w_len`` is what the shared decode kernel (ops/decode_kernel.py, a prefix
+kernel) takes as ``lens``.  Every W positions the window is **dumped**: its
+W / c summaries overwrite the head of its own rows, ``n_sum += W / c``,
+``w_len = 0`` (``transition_fn``; rows behind are stale and masked by
+``lens``, as a padded tail is).  ``max_seq_len`` positions need
+``(max_seq_len / W - 1) * W / c + W`` rows, not ``max_seq_len``.
+
+**Prefill goes by window-sized pieces** (``prefill_piece``): piece p of a
+prompt holds positions ``[pW, pW + m)``, attends to the slot's summaries (a
+prefix every query sees: ``flash_attention(prefix=...)``) and causally to
+itself; a full piece writes its W / c summaries only, the last, partial piece
+writes its exact rows.  The scheduler (engine/generative.py) learns all of this
+from the hooks below (``prefill_piece``, ``cache_rows``, ``transition_due``,
+``transition_fn``), not from the model's name.
+
+Layers run under ``lax.scan`` over stacked weights with the arena in the
+carry (the decode kernel takes the layer index by scalar prefetch), so a
+program holds one copy of a layer whatever the depth.
+
+Tokens are sampled from head 0 only, one byte a wave; the other
+``num_pred_heads - 1`` heads' logits are computed (the head keeps its
+published width) and compared with the reference in tests, not served.
+"""
+
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+from client_tpu import config as envcfg
+from client_tpu.engine.config import ModelConfig, TensorConfig
+from client_tpu.engine.model import ModelBackend
+from client_tpu.models import register_model
+from client_tpu.models.generate import _sample_token
+
+_NEG_INF = -1e30
+
+
+def rms_norm(x, g, eps):
+    """``x / rms(x) * (1 + g)`` in float32 (``norm_add_unit_offset``)."""
+    import jax.numpy as jnp
+
+    x = x.astype(jnp.float32)
+    var = jnp.mean(x * x, axis=-1, keepdims=True)
+    return x * jnp.reciprocal(jnp.sqrt(var + eps)) * (
+        1.0 + g.astype(jnp.float32))
+
+
+def rope(x, pos, theta):
+    """Rotary positions, rotate-half pairing: x ``[..., n, H, D]`` float32,
+    pos ``[..., n]``."""
+    import jax.numpy as jnp
+
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = pos.astype(jnp.float32)[..., None] * inv          # [..., n, D/2]
+    cos = jnp.cos(ang)[..., None, :]
+    sin = jnp.sin(ang)[..., None, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def summarize(k, v, phi, mu, chunk):
+    """Chunk summaries of whole windows.  k, v ``[..., n, H, D]`` (the values
+    the cache holds, as float32; n a multiple of ``chunk``), phi, mu
+    ``[H, D]`` -> (k~, v~) ``[..., n / chunk, H, D]`` float32."""
+    import jax
+    import jax.numpy as jnp
+
+    *lead, n, h, d = k.shape
+    kc = k.reshape(*lead, n // chunk, chunk, h, d)
+    vc = v.reshape(*lead, n // chunk, chunk, h, d)
+    a = jax.nn.softmax(jnp.einsum("...chd,hd->...ch", kc, phi), axis=-2)
+    v_s = jnp.einsum("...ch,...chd->...hd", a, vc)
+    k_s = kc.mean(axis=-3) + mu
+    return k_s, v_s
+
+
+class EvaByteBackend(ModelBackend):
+    """Byte-level decoder: INPUT_IDS [-1] -> streamed (TOKEN, INDEX)."""
+
+    generative = True
+
+    def __init__(self, name: str = "evabyte", n_layers: int = 2,
+                 d_model: int = 64, n_heads: int = 4, d_ff: int = 128,
+                 vocab: int = 320, n_pred_heads: int = 8,
+                 max_seq_len: int = 128, window: int = 32, chunk: int = 4,
+                 rope_theta: float = 100000.0, rms_eps: float = 1e-5,
+                 max_streams: int = 4, seed: int = 0,
+                 prefill_lanes: int = 1, attention_impl: str = "einsum",
+                 attn_impl: str | None = None):
+        if attention_impl not in ("einsum", "flash"):
+            raise ValueError(
+                f"attention_impl must be 'einsum' or 'flash', got "
+                f"{attention_impl!r}")
+        if attn_impl is None:
+            attn_impl = envcfg.env_str("CLIENT_TPU_ATTN_IMPL")
+        if attn_impl not in ("", "reference", "fused"):
+            raise ValueError(
+                f"attn_impl must be 'reference' or 'fused', got "
+                f"{attn_impl!r}")
+        if d_model % n_heads or window % chunk or max_seq_len % window:
+            raise ValueError(
+                "d_model must divide into heads, the window into chunks and "
+                "max_seq_len into windows")
+        if (window // chunk) % 8:
+            raise ValueError(
+                "a window's summaries (window / chunk) must be a multiple "
+                "of 8 rows")
+        self.attention_impl, self.attn_impl = attention_impl, attn_impl
+        self.flash_blocks = (512, 1024)
+        self.decode_block_s: int | None = None
+        self.n_layers, self.d_model = int(n_layers), int(d_model)
+        self.n_heads, self.d_ff = int(n_heads), int(d_ff)
+        self.head_dim = self.d_model // self.n_heads
+        self.vocab, self.n_pred_heads = int(vocab), int(n_pred_heads)
+        self.max_seq_len = int(max_seq_len)
+        self.window, self.chunk = int(window), int(chunk)
+        self.rope_theta, self.rms_eps = float(rope_theta), float(rms_eps)
+        self.max_streams = int(max_streams)
+        self.default_max_tokens = 16
+        self._seed = seed
+        # Summaries a window leaves, and the rows of a slot: the summaries
+        # of every window but the last, then one window of exact rows,
+        # rounded up so that kernel blocks divide it.
+        self.sums_per_window = self.window // self.chunk
+        used = ((self.max_seq_len // self.window - 1) * self.sums_per_window
+                + self.window)
+        step = min(512, self.window)
+        self.slot_rows = -(-used // step) * step
+        # What the scheduler asks (engine/generative.py): a prompt is
+        # consumed ``window`` positions a piece, ``prefill_lanes`` prompts
+        # a call.
+        self.prefill_piece = (self.window, int(prefill_lanes))
+        self.config = ModelConfig(
+            name=name,
+            platform="jax",
+            max_batch_size=0,
+            input=[TensorConfig("INPUT_IDS", "INT32", [-1])],
+            output=[
+                TensorConfig("TOKEN", "INT32", [1]),
+                TensorConfig("INDEX", "UINT32", [1]),
+            ],
+            decoupled=True,
+        )
+
+    # -- what the scheduler asks of a cache that is not slot-per-position --
+
+    def cache_rows(self, n: int) -> tuple[int, int]:
+        """(summary rows, exact rows) a decode step at context length ``n``
+        reads from its slot."""
+        return (n // self.window) * self.sums_per_window, n % self.window
+
+    def transition_due(self, n: int) -> bool:
+        """Whether a stream that *decoded* its way to context length ``n``
+        has to dump its window before its next step (a prompt that ends on
+        a boundary was summarised by its last piece)."""
+        return n > 0 and n % self.window == 0
+
+    # -- params --------------------------------------------------------------
+
+    def _init_params(self):
+        """Seeded weights, **already rounded to bfloat16** (numpy arrays of
+        ``ml_dtypes.bfloat16``): a reference that casts them to float32
+        holds exactly what the chip holds.  Layers are stacked on a leading
+        axis (the programs scan over them)."""
+        import ml_dtypes
+
+        bf16 = ml_dtypes.bfloat16
+        rng = np.random.default_rng(self._seed)
+        d, f, nl = self.d_model, self.d_ff, self.n_layers
+        h, dh = self.n_heads, self.head_dim
+
+        def w(*shape, scale):
+            out = rng.standard_normal(shape, dtype=np.float32)
+            out *= np.float32(scale)
+            return out.astype(bf16)
+
+        def mats(rows, cols):
+            return np.stack([w(rows, cols, scale=1.0 / math.sqrt(rows))
+                             for _ in range(nl)])
+
+        layers = {
+            "ln1": w(nl, d, scale=0.1), "ln2": w(nl, d, scale=0.1),
+            "wq": mats(d, d), "wk": mats(d, d), "wv": mats(d, d),
+            "wo": mats(d, d),
+            "wg": mats(d, f), "wu": mats(d, f), "wd": mats(f, d),
+            # adaptive_phi scores a chunk's keys (unit variance at random
+            # init), adaptive_mu_k shifts its pooled key.
+            "phi": w(nl, h, dh, scale=1.0 / math.sqrt(dh)),
+            "mu": w(nl, h, dh, scale=0.5),
+        }
+        return {
+            "embed": w(self.vocab, d, scale=1.0),
+            "layers": layers,
+            "lnf": w(d, scale=0.1),
+            "head": w(d, self.n_pred_heads * self.vocab,
+                      scale=1.0 / math.sqrt(d)),
+        }
+
+    def place_params(self, params):
+        import jax
+
+        return jax.device_put(params)
+
+    # -- shared blocks --------------------------------------------------------
+
+    def _mm(self, x, w):
+        """bfloat16 operands, float32 result (``fp32_skip_add``: the
+        residual stream the result is added to stays float32)."""
+        import jax.numpy as jnp
+
+        return jnp.matmul(x.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32)
+
+    def _qkv(self, lp, x, pos):
+        """x ``[..., n, d]`` float32 -> q, k (RoPE applied), v ``[..., n,
+        H, D]`` float32."""
+        h = rms_norm(x, lp["ln1"], self.rms_eps)
+        shape = (*x.shape[:-1], self.n_heads, self.head_dim)
+        q = rope(self._mm(h, lp["wq"]).reshape(shape), pos, self.rope_theta)
+        k = rope(self._mm(h, lp["wk"]).reshape(shape), pos, self.rope_theta)
+        return q, k, self._mm(h, lp["wv"]).reshape(shape)
+
+    def _after_attention(self, lp, x, o):
+        import jax
+
+        x = x + self._mm(o.reshape(x.shape), lp["wo"])
+        h = rms_norm(x, lp["ln2"], self.rms_eps)
+        return x + self._mm(
+            jax.nn.silu(self._mm(h, lp["wg"])) * self._mm(h, lp["wu"]),
+            lp["wd"])
+
+    def _logits(self, p, x):
+        """float32 logits of all heads, ``[..., n_pred_heads, vocab]``."""
+        out = self._mm(rms_norm(x, p["lnf"], self.rms_eps), p["head"])
+        return out.reshape(*x.shape[:-1], self.n_pred_heads, self.vocab)
+
+    def _summaries(self, lp, k_c, v_c):
+        """Summaries of whole windows of cache rows ``[..., n, H, D]``
+        (bfloat16, what the cache holds), in the cache's dtype."""
+        import jax.numpy as jnp
+
+        k_s, v_s = summarize(k_c.astype(jnp.float32),
+                             v_c.astype(jnp.float32),
+                             lp["phi"].astype(jnp.float32),
+                             lp["mu"].astype(jnp.float32), self.chunk)
+        return k_s.astype(k_c.dtype), v_s.astype(v_c.dtype)
+
+    def _scan_layers(self, p, body, carry):
+        import jax
+        import jax.numpy as jnp
+
+        carry, _ = jax.lax.scan(
+            lambda c, xs: (body(c, *xs), None), carry,
+            (p["layers"], jnp.arange(self.n_layers, dtype=jnp.int32)))
+        return carry
+
+    # -- full-context forward (no cache) ----------------------------------------
+
+    def make_apply_params(self):
+        """Full-context forward in the served precision, no cache and no
+        pieces: logits of every position and head.  Model-level entry for
+        warm-up and diagnostics; serving goes through the pieces and waves
+        below."""
+        params = self.place_params(self.load_or_init_params(self._init_params))
+
+        def apply(p, inputs):
+            import jax
+            import jax.numpy as jnp
+
+            ids = inputs["INPUT_IDS"].astype("int32")
+            n = ids.shape[0]
+            win, c = self.window, self.chunk
+            pos = jnp.arange(n)
+            n_pad = -(-n // win) * win          # whole windows for pooling
+            tok_ok = ((pos[None, :] // win == pos[:, None] // win)
+                      & (pos[None, :] <= pos[:, None]))
+            ch_ok = (jnp.arange(n_pad // c)[None, :] * c // win
+                     < pos[:, None] // win)
+            scale = 1.0 / math.sqrt(self.head_dim)
+
+            def body(x, lp, _li):
+                q, k, v = self._qkv(lp, x, pos)
+                k_c, v_c = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+                pad = ((0, n_pad - n), (0, 0), (0, 0))
+                k_s, v_s = self._summaries(lp, jnp.pad(k_c, pad),
+                                           jnp.pad(v_c, pad))
+                keys = jnp.concatenate([k_s, k_c]).astype(jnp.float32)
+                vals = jnp.concatenate([v_s, v_c]).astype(jnp.float32)
+                s = jnp.einsum("qhd,khd->hqk", q, keys) * scale
+                ok = jnp.concatenate([ch_ok, tok_ok], axis=1)
+                s = jnp.where(ok[None], s, _NEG_INF)
+                o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), vals)
+                return self._after_attention(lp, x, o)
+
+            x = self._scan_layers(p, body,
+                                  p["embed"][ids].astype(jnp.float32))
+            return {"logits": self._logits(p, x)}
+
+        return apply, params
+
+    # -- generative interface (used by GenerativeScheduler) -------------------
+
+    def init_arena(self, capacity: int):
+        """Cache arena: k/v ``[L, capacity + 1, slot_rows, H*D]`` bfloat16
+        (the +1 slot absorbs padded lanes) plus ``tok`` [R], each slot's
+        latest token on the device.  A slot's rows are summaries then the
+        current window (module docstring), not positions."""
+        import jax.numpy as jnp
+
+        shape = (self.n_layers, capacity + 1, self.slot_rows, self.d_model)
+        return {"k": jnp.zeros(shape, jnp.bfloat16),
+                "v": jnp.zeros(shape, jnp.bfloat16),
+                "tok": jnp.zeros(capacity + 1, jnp.int32)}
+
+    def _use_kernel(self) -> bool:
+        from client_tpu.engine.backend_init import pallas_interpret
+
+        return self.attn_impl == "fused" or (
+            not self.attn_impl and not pallas_interpret())
+
+    def _piece_attention(self, q, k_c, v_c, pk, pv, n_sum):
+        """One piece's attention: q ``[B, W, H, D]`` float32, its own keys
+        and values k_c/v_c (bfloat16) seen causally, and the slot's first
+        ``P`` rows pk/pv ``[B, P, H, D]`` of which the ``n_sum[b]``
+        summaries are seen by every query."""
+        import jax
+        import jax.numpy as jnp
+
+        b, w = q.shape[:2]
+        pre = pk.shape[1]
+        keys = jnp.concatenate([pk, k_c], axis=1)
+        vals = jnp.concatenate([pv, v_c], axis=1)
+        idx = jnp.arange(pre + w)
+        seen = (idx[None, :] < n_sum[:, None]) | (idx[None, :] >= pre)
+        if self.attention_impl == "flash":
+            from client_tpu.engine.backend_init import pallas_interpret
+            from client_tpu.ops.decode_kernel import pick_block_s
+            from client_tpu.ops.flash_attention import flash_attention
+
+            cap_q, cap_k = self.flash_blocks
+            return flash_attention(
+                q.astype(keys.dtype), keys, vals,
+                jnp.where(seen, 0.0, _NEG_INF).astype(jnp.float32),
+                causal=True, prefix=pre, block_q=pick_block_s(w, cap_q),
+                block_k=pick_block_s(pre + w, cap_k),
+                interpret=pallas_interpret()).astype(jnp.float32)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, keys.astype(jnp.float32))
+        s = s / math.sqrt(self.head_dim)
+        causal = (idx[None, :] - pre) <= jnp.arange(w)[:, None]   # [W, P+W]
+        ok = seen[:, None, :] & causal[None]
+        s = jnp.where(ok[:, None], s, _NEG_INF)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                          vals.astype(jnp.float32))
+
+    def piece_logits_fn(self):
+        """(params, arena, rows[B], ids[B, W], lens[B], starts[B]) ->
+        (arena, logits[B, n_pred_heads, vocab] after each lane's last valid
+        position).  One prefill piece: positions ``starts[b] ..
+        starts[b] + lens[b]`` of each lane's prompt (``starts`` a multiple
+        of the window)."""
+        import jax
+        import jax.numpy as jnp
+
+        win, spw, hd = self.window, self.sums_per_window, self.d_model
+        pre = self.slot_rows - win
+
+        def piece(p, arena, rows, ids, lens, starts):
+            b = rows.shape[0]
+            pos = starts[:, None] + jnp.arange(win)[None, :]
+            n_sum = (starts // win) * spw
+            full = lens == win
+
+            def body(carry, lp, li):
+                x, k_a, v_a = carry
+                q, k, v = self._qkv(lp, x, pos)
+                k_c, v_c = k.astype(k_a.dtype), v.astype(v_a.dtype)
+                shape = (b, pre, self.n_heads, self.head_dim)
+
+                def head_rows(leaf):
+                    return jnp.stack([jax.lax.dynamic_slice(
+                        leaf, (li, rows[i], 0, 0), (1, 1, pre, hd))
+                        for i in range(b)]).reshape(shape)
+
+                o = self._piece_attention(q, k_c, v_c, head_rows(k_a),
+                                          head_rows(v_a), n_sum)
+                # A full piece leaves its summaries, a partial one (the
+                # prompt's last) its exact rows; what lies behind either is
+                # beyond ``live`` and never read.
+                k_s, v_s = self._summaries(lp, k_c, v_c)
+                tail = ((0, 0), (0, win - spw), (0, 0))
+
+                def put(leaf, exact, sums):
+                    blk = jnp.where(full[:, None, None],
+                                    jnp.pad(sums.reshape(b, spw, hd), tail),
+                                    exact.reshape(b, win, hd))
+                    for i in range(b):
+                        leaf = jax.lax.dynamic_update_slice(
+                            leaf, blk[i][None, None],
+                            (li, rows[i], n_sum[i], 0))
+                    return leaf
+
+                return (self._after_attention(lp, x, o),
+                        put(k_a, k_c, k_s), put(v_a, v_c, v_s))
+
+            x, k_a, v_a = self._scan_layers(
+                p, body, (p["embed"][ids].astype(jnp.float32),
+                          arena["k"], arena["v"]))
+            logits = self._logits(p, x[jnp.arange(b), lens - 1])
+            return {**arena, "k": k_a, "v": v_a}, logits
+
+        return piece
+
+    def prefill_fn(self):
+        """(params, arena, rows[B], ids[B, W], lens[B], seeds[B], temps[B],
+        top_ks[B], top_ps[B], sample, starts[B]) -> (arena, tokens[B]).
+
+        One **piece** of each lane's prompt (``piece_logits_fn``); the token
+        sampled from head 0 after a lane's last valid position lands in
+        the slot's device-side token, and means something for a prompt's
+        last piece only."""
+        import jax
+        import jax.numpy as jnp
+
+        piece = self.piece_logits_fn()
+
+        def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
+                    sample, starts):
+            arena, logits = piece(p, arena, rows, ids, lens, starts)
+            head0 = logits[:, 0]
+            if sample:
+                tokens = jax.vmap(_sample_token)(
+                    head0, seeds, starts + lens, temps, top_ks, top_ps)
+            else:
+                tokens = jnp.argmax(head0, axis=-1).astype(jnp.int32)
+            return {**arena, "tok": arena["tok"].at[rows].set(tokens)}, tokens
+
+        return prefill
+
+    def decode_logits_fn(self):
+        """(params, arena, rows[B], lens[B]) -> (arena, logits[B,
+        n_pred_heads, vocab]).  One decode step: each lane's input token is
+        its slot's device-side token, its position its context length
+        ``lens[b]``; the new key/value row goes behind the slot's live rows
+        and the query reads them all (one softmax over summaries and
+        window).  The served step is the shared Pallas kernel
+        (ops/decode_kernel.py); ``attn_impl="reference"`` is the XLA oracle
+        on the same arena."""
+        import jax.numpy as jnp
+
+        win, spw = self.window, self.sums_per_window
+        if self._use_kernel():
+            from client_tpu.engine.backend_init import pallas_interpret
+            from client_tpu.ops.decode_kernel import decode_wave_attention
+
+            interpret, block_s = pallas_interpret(), self.decode_block_s
+
+            def attend(k_a, v_a, q, k, v, rows, live, li):
+                return decode_wave_attention(
+                    k_a, v_a, q, k, v, rows, live, layer=None,
+                    layer_index=li, block_s=block_s, interpret=interpret)
+        else:
+            from client_tpu.ops.decode_kernel import \
+                reference_decode_attention
+
+            def attend(k_a, v_a, q, k, v, rows, live, li):
+                return reference_decode_attention(
+                    k_a, v_a, q, k, v, rows, live, layer=li)
+
+        def decode(p, arena, rows, lens):
+            live = (lens // win) * spw + lens % win
+            tokens = arena["tok"][rows]
+
+            def body(carry, lp, li):
+                x, k_a, v_a = carry
+                q, k, v = self._qkv(lp, x[:, None], lens[:, None])
+                k_a, v_a, o = attend(k_a, v_a, q[:, 0], k[:, 0], v[:, 0],
+                                     rows, live, li)
+                return self._after_attention(lp, x, o), k_a, v_a
+
+            x, k_a, v_a = self._scan_layers(
+                p, body, (p["embed"][tokens].astype(jnp.float32),
+                          arena["k"], arena["v"]))
+            return {**arena, "k": k_a, "v": v_a}, self._logits(p, x)
+
+        return decode
+
+    def decode_fn(self):
+        """(params, arena, rows[B], lens[B], seeds[B], temps[B], top_ks[B],
+        top_ps[B], sample) -> (arena, next[B]): ``decode_logits_fn`` and
+        head 0's token, written back to the slots' device-side tokens."""
+        import jax
+        import jax.numpy as jnp
+
+        step = self.decode_logits_fn()
+
+        def decode(p, arena, rows, lens, seeds, temps, top_ks, top_ps,
+                   sample=True):
+            arena, logits = step(p, arena, rows, lens)
+            head0 = logits[:, 0]
+            if sample:
+                nxt = jax.vmap(_sample_token)(
+                    head0, seeds, lens + 1, temps, top_ks, top_ps)
+            else:
+                nxt = jnp.argmax(head0, axis=-1).astype(jnp.int32)
+            return {**arena, "tok": arena["tok"].at[rows].set(nxt)}, nxt
+
+        return decode
+
+    def decode_chunk_fn(self):
+        """Chunked decode is not offered: a dump may fall between any two
+        steps, and the scheduler orders it (it keeps K = 1 for a backend
+        with transitions)."""
+        raise NotImplementedError(
+            "evabyte decodes one wave a dispatch (window dumps are ordered "
+            "between waves)")
+
+    def transition_fn(self):
+        """(params, arena, rows[T], lens[T]) -> arena: **dump** the full
+        window of each lane whose context length ``lens`` is a positive
+        multiple of the window: its W / c summaries overwrite the head of
+        its own rows.  Lanes on the dummy slot summarise junk into junk."""
+        import jax
+        import jax.numpy as jnp
+
+        win, spw, hd = self.window, self.sums_per_window, self.d_model
+        nl = self.n_layers
+
+        def transition(p, arena, rows, lens):
+            src = jnp.maximum(lens // win - 1, 0) * spw
+            lp = p["layers"]
+            k_a, v_a = arena["k"], arena["v"]
+            for i in range(rows.shape[0]):
+                def take(leaf):
+                    return jax.lax.dynamic_slice(
+                        leaf, (0, rows[i], src[i], 0),
+                        (nl, 1, win, hd)).reshape(
+                            nl, win, self.n_heads, self.head_dim)
+
+                k_s, v_s = jax.vmap(
+                    lambda phi, mu, k, v: self._summaries(
+                        {"phi": phi, "mu": mu}, k, v))(
+                            lp["phi"], lp["mu"], take(k_a), take(v_a))
+                k_a = jax.lax.dynamic_update_slice(
+                    k_a, k_s.reshape(nl, 1, spw, hd), (0, rows[i], src[i], 0))
+                v_a = jax.lax.dynamic_update_slice(
+                    v_a, v_s.reshape(nl, 1, spw, hd), (0, rows[i], src[i], 0))
+            return {**arena, "k": k_a, "v": v_a}
+
+        return transition
+
+
+# Opt-in (a default load-all server should not pay its arena); the tiny
+# preset is what the tier-1 tests serve.
+register_model("evabyte", default=False)(EvaByteBackend)

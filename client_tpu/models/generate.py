@@ -9,9 +9,13 @@ share each decode step via a KV-cache arena in HBM
 (client_tpu/engine/generative.py).
 
 TPU-first shapes: the KV cache is one pytree whose k/v leaves are
-``[n_layers, capacity+1, max_seq_len, heads*head_dim]`` (the +1 row absorbs
-padded decode lanes; the heads' features lie side by side on the minor axis
-so the chip's (8, 128) tile holds a leaf without padding); prefill writes a
+``[n_layers, capacity+1, max_seq_len, heads*head_dim]`` float32: for this
+full-attention decoder a stream's slot is one row per position (a slot is the
+backend's to define: ``models/evabyte.py`` keeps bfloat16 chunk summaries and
+a window of exact rows in the same ``[L, R, S, H*D]`` frame, and the scheduler
+and the decode kernel take either).  The +1 slot absorbs padded decode lanes;
+the heads' features lie side by side on the minor axis so the chip's (8, 128)
+tile holds a leaf without padding; prefill writes a
 whole row, each decode wave writes one position per active stream in place
 and reads each live row once (ops/decode_kernel.py) — no dynamic shapes
 anywhere, so XLA compiles one executable per (prompt bucket | wave bucket).
@@ -302,7 +306,8 @@ class TinyGptBackend(ModelBackend):
         return self._kv_mesh
 
     def init_arena(self, capacity: int):
-        """KV arena pytree: k/v of shape [L, R, S, H*D] plus ``tok`` [R] —
+        """KV arena pytree: k/v of shape [L, R, S, H*D] float32 (S =
+        ``max_seq_len``: one row per position) plus ``tok`` [R] —
         each row's latest token, kept ON DEVICE so decode waves chain
         without a host round trip per step (the scheduler pipelines waves
         and fetches emitted tokens asynchronously).  A position's row is

@@ -27,7 +27,8 @@ module-level boolean.
 
 The jitted steps' names are here too: ``jax.jit`` names an XLA module
 ``jit_<fn.__name__>``, and the benchmark's trace reduction keys on
-``jit_decode``/``jit_prefill``/``jit_apply`` — :func:`named_step` makes that a
+``jit_decode``/``jit_prefill``/``jit_transition``/``jit_apply`` —
+:func:`named_step` makes that a
 contract instead of an accident of what a backend calls its inner function.
 """
 
@@ -46,11 +47,17 @@ GEN_WAVE_STAGE = "gen.wave_stage"
 GEN_WAVE_DISPATCH = "gen.wave_dispatch"
 GEN_FETCH_WAIT = "gen.fetch_wait"
 GEN_EMIT = "gen.emit"
+# A cache state transition ordered between two decode waves (a backend that
+# declares ``transition_fn``: EvaByte's window dump); count = transitions'
+# dispatches, never opened for a backend without the hook.
+GEN_TRANSITION_DISPATCH = "gen.transition_dispatch"
 
 GEN_SPANS = (GEN_LOOP, GEN_IDLE, GEN_ADMIT, GEN_PREFILL_DISPATCH, GEN_SWEEP,
-             GEN_WAVE_STAGE, GEN_WAVE_DISPATCH, GEN_FETCH_WAIT, GEN_EMIT)
+             GEN_WAVE_STAGE, GEN_WAVE_DISPATCH, GEN_FETCH_WAIT, GEN_EMIT,
+             GEN_TRANSITION_DISPATCH)
 (S_LOOP, S_IDLE, S_ADMIT, S_PREFILL_DISPATCH, S_SWEEP, S_WAVE_STAGE,
- S_WAVE_DISPATCH, S_FETCH_WAIT, S_EMIT) = range(len(GEN_SPANS))
+ S_WAVE_DISPATCH, S_FETCH_WAIT, S_EMIT,
+ S_TRANSITION_DISPATCH) = range(len(GEN_SPANS))
 
 # Cumulative, monotone: two snapshots difference exactly.  Every counter has
 # a reader (docs/OBSERVABILITY.md, the inventory): a per-layer metric of
@@ -71,11 +78,21 @@ GEN_COUNTERS = (
     # per first token: prefill dispatch to the emit of token 0, and the
     # waves in flight when that prefill was dispatched
     "first_tokens", "first_token_wait_ns", "first_token_inflight_waves",
+    # A backend whose cache is not one slot per position (``cache_rows``,
+    # ``prefill_piece``, ``transition_fn``; 0 for every other backend): the
+    # cache rows a fetched wave read, by kind (``fetched_positions_valid``
+    # keeps meaning context positions); prompts admitted, the pieces their
+    # prefill was dispatched in (a lane's piece counts one), and the cache
+    # transitions dispatched.
+    "fetched_rows_exact", "fetched_rows_summary",
+    "prompts_admitted", "prefill_pieces", "transitions",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
  C_FETCHES_FORCED, C_FIRST_TOKENS, C_FIRST_TOKEN_WAIT_NS,
- C_FIRST_TOKEN_INFLIGHT_WAVES) = range(len(GEN_COUNTERS))
+ C_FIRST_TOKEN_INFLIGHT_WAVES, C_FETCHED_ROWS_EXACT, C_FETCHED_ROWS_SUMMARY,
+ C_PROMPTS_ADMITTED, C_PREFILL_PIECES,
+ C_TRANSITIONS) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 
@@ -95,6 +112,7 @@ STARTUP_FRONTENDS = "startup.frontends"
 STEP_PREFILL = "prefill"
 STEP_DECODE = "decode"
 STEP_DECODE_CHUNK = "decode_chunk"
+STEP_TRANSITION = "transition"
 STEP_APPLY = "apply"
 
 

@@ -4,10 +4,18 @@ The generative engine's hot loop is the decode wave
 (engine/generative.py): for every live stream, write the new token's K/V
 row into the KV arena at ``(row, len)`` and attend the stream's query over
 its valid prefix.  The arena is **lane-dense**: each leaf is
-``[L, R, S, H*D]`` — positions on the second-minor axis, the heads' features
-side by side on the minor axis (GPT-2: 768 = 6 x 128 lanes), so the chip's
-``(8, 128)`` float32 tile holds it without padding and a row of the arena is
-what ``h @ wk`` produced, untransposed.  (The earlier ``[L, R, S, H, D]``
+``[L, R, S, H*D]`` — cache rows on the second-minor axis, the heads' features
+side by side on the minor axis (GPT-2: 768 = 6 x 128 lanes; a 32 x 128
+decoder: 4096), so the chip's tile (``(8, 128)`` float32, ``(16, 128)``
+bfloat16) holds it without padding and a row of the arena is what ``h @ wk``
+produced, untransposed.  The kernel is a **prefix** kernel and knows nothing
+of what a row means: a lane reads rows ``[0, len)`` of its slot and writes row
+``len``.  For a full-attention decoder a row is a position and ``len`` the
+context length; a backend whose cache is the model's own (models/evabyte.py:
+chunk summaries first, the current window's exact keys behind them) passes
+its count of live rows.  Leaves may be float32 or bfloat16: blocks go to the
+MXU in the leaf's dtype (float32 at ``Precision.HIGHEST``, bfloat16 in its one
+native pass), the softmax carry and the accumulator are float32.  (The earlier ``[L, R, S, H, D]``
 leaves had ``[12, 64]`` minor dimensions; the compiler stored them with S
 minor-most and re-laid out the whole leaf around every scatter and gather:
 PERF.md section 6, PR 25.)
@@ -22,15 +30,18 @@ BlockSpec index maps pick each lane's blocks straight out of the arena, so no
   sees as unchanged and does not copy again, and their compute is skipped.
 - **Writes one row per lane and leaf.**  The arena operand is aliased to the
   output (``input_output_aliases``) and the output stays in HBM
-  (``memory_space=ANY``): the kernel copies the aligned 8-row group that
-  holds position ``len`` into VMEM, inserts the new row with an iota mask
-  and copies the group back (HBM is tiled by 8 rows, so one row alone is not
-  a DMA the chip accepts).  Nothing else of the arena is written.
+  (``memory_space=ANY``): the kernel copies the aligned row group that
+  holds row ``len`` into VMEM, inserts the new row with an iota mask and
+  copies the group back (HBM is tiled by 8 rows of float32 and 16 of
+  bfloat16, so one row alone is not a DMA the chip accepts).  Nothing else
+  of the arena is written.
 - **Scores on the MXU.**  The query becomes a block-diagonal ``[Hp, H*D]``
   matrix (row h holds head h's 64 features at their lanes, zero elsewhere),
   so ``scores[h, s] = Qbd @ K_blk^T`` and ``acc[h, :] += p @ V_blk`` are two
-  plain matmuls over lane-dense blocks, in full float32 precision; the
-  output row is read off the block diagonal of ``acc``.
+  plain matmuls over lane-dense blocks (the block-diagonal form costs H
+  times the useful FLOPs: nothing beside a float32 arena's six passes at
+  H = 12, and 0.6 ms of a 4.6 ms memory-bound wave at H = 32 in bfloat16's
+  single pass); the output row is read off the block diagonal of ``acc``.
 
 Attention follows ``_fa_kernel``'s online-softmax carry
 (ops/flash_attention.py) with a *strict* ``pos < len`` mask over the old
@@ -55,9 +66,13 @@ import jax.numpy as jnp
 import numpy as np
 
 _NEG_INF = -1e30
-# HBM arrays are tiled (8, 128): a DMA's slice of the second-minor axis
-# starts and ends on a multiple of 8 rows.
-_ROW_GROUP = 8
+
+
+def row_group(dtype) -> int:
+    """Rows of one HBM tile: arrays are tiled (8, 128) in 32-bit words, so a
+    DMA's slice of the second-minor axis starts and ends on a multiple of 8
+    rows of float32 and of 16 rows of bfloat16."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
 def pick_block_s(seq_len: int, cap: int = 512) -> int:
@@ -73,17 +88,21 @@ def pick_block_s(seq_len: int, cap: int = 512) -> int:
     return best if best is not None else seq_len
 
 
-def _decode_kernel(rows_ref, lens_ref,                    # scalar prefetch
-                   k_ref, v_ref, q_ref, kn_ref, vn_ref,   # inputs
-                   ko_ref, vo_ref, o_ref,                 # outputs
-                   m_ref, l_ref, acc_ref, kbuf, vbuf, sem,    # scratch
-                   *, layer: int, block_s: int, head_dim: int,
-                   sm_scale: float):
+def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
+                   head_dim: int, sm_scale: float):
     """One (lane, key-block) grid step; key blocks iterate innermost so the
-    scratch carries the online-softmax state across one lane's row."""
+    scratch carries the online-softmax state across one lane's row.
+    ``layer`` is a Python int, or ``None`` when the layer index arrives as a
+    third scalar-prefetch operand (a decoder that scans over its layers)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if layer is None:
+        layer = refs[0][0]
+        refs = refs[1:]
+    (k_ref, v_ref, q_ref, kn_ref, vn_ref,               # inputs
+     ko_ref, vo_ref, o_ref,                             # outputs
+     m_ref, l_ref, acc_ref, kbuf, vbuf, sem) = refs     # scratch
     b = pl.program_id(0)
     ik = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -116,14 +135,18 @@ def _decode_kernel(rows_ref, lens_ref,                    # scalar prefetch
     head = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
     own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
     qbd = jnp.where(own, q_ref[0] * sm_scale, 0.0)           # [Hp, H*D]
-    highest = jax.lax.Precision.HIGHEST
+    # The MXU takes the arena's dtype: float32 blocks in full precision,
+    # bfloat16 blocks (products exact in the float32 accumulator) in one pass.
+    cache_dtype = k_ref.dtype
+    highest = (jax.lax.Precision.HIGHEST if cache_dtype == jnp.float32
+               else None)
 
     @pl.when(ik * block_s < length)
     def _block():
         # Scores over the OLD prefix content: strictly pos < length
         # (position `length` is the new token, folded in below).
         s = jax.lax.dot_general(
-            qbd, k_ref[...], (((1,), (1,)), ((), ())),
+            qbd.astype(cache_dtype), k_ref[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=highest)
         pos = ik * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = pos < length                                 # [Hp, block_s]
@@ -137,12 +160,17 @@ def _decode_kernel(rows_ref, lens_ref,                    # scalar prefetch
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p, v_ref[...], preferred_element_type=jnp.float32,
+            p.astype(cache_dtype), v_ref[...],
+            preferred_element_type=jnp.float32,
             precision=highest)                               # [Hp, H*D]
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        kn, vn = kn_ref[0], vn_ref[0]                        # [1, H*D]
+        # The new row as the arena will hold it (rounded to the leaf's
+        # dtype), so this wave and every later one read the same values.
+        kn_c, vn_c = kn_ref[0].astype(cache_dtype), vn_ref[0].astype(
+            cache_dtype)                                     # [1, H*D]
+        kn, vn = kn_c.astype(jnp.float32), vn_c.astype(jnp.float32)
         # Fold in the new token (position `length`, value kn/vn) from
         # registers — it is always valid, so the denominator is > 0 and
         # lanes with an empty prefix (length == 0, i.e. padded lanes on the
@@ -160,8 +188,8 @@ def _decode_kernel(rows_ref, lens_ref,                    # scalar prefetch
             copy.wait()
         ins = jax.lax.broadcasted_iota(
             jnp.int32, kbuf.shape, 0) == length - g0
-        kbuf[...] = jnp.where(ins, kn, kbuf[...])
-        vbuf[...] = jnp.where(ins, vn, vbuf[...])
+        kbuf[...] = jnp.where(ins, kn_c, kbuf[...])
+        vbuf[...] = jnp.where(ins, vn_c, vbuf[...])
         for copy in group_copies(read=False):
             copy.start()
         for copy in group_copies(read=False):
@@ -171,16 +199,18 @@ def _decode_kernel(rows_ref, lens_ref,                    # scalar prefetch
 @functools.partial(jax.jit, static_argnames=("layer", "block_s",
                                              "interpret"))
 def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
-                          layer: int, block_s: int | None = None,
-                          interpret: bool = False):
+                          layer: int | None, block_s: int | None = None,
+                          interpret: bool = False, layer_index=None):
     """One layer's decode wave over the KV arena.
 
-    k_arena/v_arena: ``[L, R, S, H*D]``; q/k_new/v_new: ``[B, H, D]``;
-    rows/lens: ``[B]`` int32 (lane → arena row, valid prefix length).
-    Returns ``(k_arena, v_arena, o)`` with the new K/V written at
-    ``(layer, rows[b], lens[b])`` in place (the arena operands are aliased
-    to the outputs, so a donated arena is never copied) and ``o: [B, H, D]``
-    the attention read over positions ``0 .. lens[b]`` inclusive.
+    k_arena/v_arena: ``[L, R, S, H*D]``, float32 or bfloat16;
+    q/k_new/v_new: ``[B, H, D]``; rows/lens: ``[B]`` int32 (lane → arena
+    slot, live rows of the slot).  Returns ``(k_arena, v_arena, o)`` with
+    the new K/V written at ``(layer, rows[b], lens[b])`` in place (the arena
+    operands are aliased to the outputs, so a donated arena is never copied)
+    and ``o: [B, H, D]`` the attention read over rows ``0 .. lens[b]``
+    inclusive.  ``layer`` is static; a decoder that scans over its layers
+    passes ``layer=None`` and the traced index as ``layer_index``.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -195,22 +225,26 @@ def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
         raise ValueError(f"block_s ({block_s}) must divide max_seq_len "
                          f"({s})")
     hp = -(-h // 8) * 8                  # heads padded to whole sublanes
-    group = math.gcd(s, _ROW_GROUP)
+    group = math.gcd(s, row_group(k_arena.dtype))
+    dynamic = layer is None
+    prefetch = (rows, lens) + (
+        (jnp.asarray(layer_index, jnp.int32).reshape(1),) if dynamic else ())
 
-    def arena_map(b, ik, rows, lens):
+    def arena_map(b, ik, rows, lens, *li):
         # Blocks beyond the last valid position repeat that block's index:
         # the pipeline does not fetch an unchanged block again.
         last = jnp.maximum(lens[b] - 1, 0) // block_s
-        return (layer, rows[b], jnp.minimum(ik, last), 0)
+        return (li[0][0] if dynamic else layer, rows[b],
+                jnp.minimum(ik, last), 0)
 
-    def lane_map(b, ik, rows, lens):
+    def lane_map(b, ik, rows, lens, *li):
         return (b, 0, 0)
 
     block = pl.BlockSpec((None, None, block_s, hd), arena_map)
     vec = pl.BlockSpec((1, 1, hd), lane_map)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(bsz, s // block_s),
         in_specs=[block, block, vec, vec, vec],    # k, v arena; q, kn, vn
         out_specs=[in_hbm, in_hbm, vec],           # k, v arena; o
@@ -234,14 +268,14 @@ def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
             jax.ShapeDtypeStruct(v_arena.shape, v_arena.dtype),
             jax.ShapeDtypeStruct((bsz, 1, hd), q.dtype),
         ],
-        # Operand indices count the scalar-prefetch args: rows=0, lens=1,
-        # k_arena=2, v_arena=3.
-        input_output_aliases={2: 0, 3: 1},
+        # Operand indices count the scalar-prefetch args: rows=0, lens=1
+        # (and the layer index), then k_arena, v_arena.
+        input_output_aliases={len(prefetch): 0, len(prefetch) + 1: 1},
         # K and V blocks double-buffered, plus the matmuls' operand copies.
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=min(100 << 20, 10 * block_bytes + (16 << 20))),
         interpret=interpret,
-    )(rows, lens, k_arena, v_arena, q.reshape(bsz, 1, hd),
+    )(*prefetch, k_arena, v_arena, q.reshape(bsz, 1, hd),
       k_new.reshape(bsz, 1, hd), v_new.reshape(bsz, 1, hd))
     return k_out, v_out, o.reshape(bsz, h, d)
 
@@ -252,13 +286,17 @@ def reference_decode_attention(k_arena, v_arena, q, k_new, v_new, rows,
     new K/V, gather the rows, dense masked softmax over ``pos <= len``) —
     the parity target for the kernel, kept next to it like
     ``reference_attention`` is for flash.  Same arena layout, same
-    signature."""
+    signature (``layer`` may be traced here); the scores are float32 over
+    the values the arena holds."""
     bsz, h, d = q.shape
     s = k_arena.shape[2]
-    k_arena = k_arena.at[layer, rows, lens].set(k_new.reshape(bsz, h * d))
-    v_arena = v_arena.at[layer, rows, lens].set(v_new.reshape(bsz, h * d))
-    ck = k_arena[layer, rows].reshape(bsz, s, h, d)
-    cv = v_arena[layer, rows].reshape(bsz, s, h, d)
+    dt = k_arena.dtype
+    k_arena = k_arena.at[layer, rows, lens].set(
+        k_new.reshape(bsz, h * d).astype(dt))
+    v_arena = v_arena.at[layer, rows, lens].set(
+        v_new.reshape(bsz, h * d).astype(dt))
+    ck = k_arena[layer, rows].reshape(bsz, s, h, d).astype(jnp.float32)
+    cv = v_arena[layer, rows].reshape(bsz, s, h, d).astype(jnp.float32)
     scores = jnp.einsum("bhd,bshd->bhs", q, ck) / np.sqrt(d)
     mask = jnp.arange(s)[None, :] <= lens[:, None]
     scores = jnp.where(mask[:, None, :], scores, _NEG_INF)

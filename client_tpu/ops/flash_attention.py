@@ -31,7 +31,7 @@ _NEG_INF = -1e30
 def _fa_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref,
                m_ref, l_ref, acc_ref,
                *, block_q: int, block_k: int, causal: bool,
-               sm_scale: float):
+               sm_scale: float, prefix: int = 0):
     """One (batch, head, q-block, k-block) grid step.
 
     Grid iterates k innermost (TPU grids run sequentially), so the VMEM
@@ -65,6 +65,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref,
             jnp.int32, (block_q, block_k), 0)
         k_pos = ik * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
+        if prefix:
+            # The first ``prefix`` keys stand before every query (a cache's
+            # summaries); the causal rule holds among the rest.
+            q_pos = q_pos + prefix
         s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
 
     m_prev = m_ref[:]                          # [bq, 1]
@@ -91,25 +95,36 @@ def _fa_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_k", "interpret"))
+    "causal", "block_q", "block_k", "interpret", "prefix"))
 def flash_attention(q, k, v, bias=None, *, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False):
-    """Memory-efficient attention. q/k/v: [B, S, H, D] (same S for q and k
-    here — encoder self-attention); bias: additive [B, S] key mask
-    (0 = attend, -inf/-1e9 = masked) or None. Returns [B, S, H, D]."""
+                    interpret: bool = False, prefix: int = 0):
+    """Memory-efficient attention. q: [B, S, H, D]; k/v: [B, S_k, H, D] with
+    ``S_k = prefix + S``; bias: additive [B, S_k] key mask (0 = attend,
+    -inf/-1e9 = masked) or None. Returns [B, S, H, D].
+
+    ``prefix`` = 0 is self-attention (same S for q and k).  With ``prefix``
+    > 0 the first ``prefix`` keys are a prefix that **every** query sees
+    (subject to ``bias``, which masks the unused part of it) and the causal
+    rule applies to the remaining ``S`` keys: a prefill piece attending to
+    its cache's chunk summaries and, causally, to its own window
+    (models/evabyte.py)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, h, d = q.shape
+    s_k = k.shape[1]
+    if s_k != prefix + s:
+        raise ValueError(
+            f"keys ({s_k}) must be prefix ({prefix}) + queries ({s})")
     block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    if s % block_q or s % block_k:
+    block_k = min(block_k, s_k)
+    if s % block_q or s_k % block_k:
         raise ValueError(
             f"block sizes ({block_q}/{block_k}) must divide the sequence "
-            f"length {s}")
+            f"lengths {s}/{s_k}")
     if bias is None:
-        bias = jnp.zeros((b, s), jnp.float32)
+        bias = jnp.zeros((b, s_k), jnp.float32)
     sm_scale = 1.0 / np.sqrt(d)
 
     # Kernel-internal layout: [B, H, S, D] so blocks tile the (seq, head_dim)
@@ -121,10 +136,10 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
     # legal TPU tile (trailing dims equal-or-aligned to the array's).
     bias3 = bias[:, None, :]
 
-    grid = (b, h, s // block_q, s // block_k)
+    grid = (b, h, s // block_q, s_k // block_k)
     kernel = functools.partial(
         _fa_kernel, block_q=block_q, block_k=block_k, causal=causal,
-        sm_scale=sm_scale)
+        sm_scale=sm_scale, prefix=prefix)
     out = pl.pallas_call(
         kernel,
         grid=grid,

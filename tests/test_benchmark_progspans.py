@@ -127,13 +127,15 @@ class TestReaders:
         by = {m["name"]: m for m in manifest["per_layer"]}
         for name in NEW_METRICS:
             m = by[name]
-            assert m["workloads"] == ["gpt2_small.chat"]
+            assert "gpt2_small.chat" in m["workloads"]
             assert m["moves"] == ("setup_s" if name.endswith(".setup")
                                   else "itl_mean_ms")
             assert callable(reader(name))
-        # appended, nothing before them moved
-        assert [m["name"] for m in manifest["per_layer"]][-len(NEW_METRICS):] \
-            == NEW_METRICS
+        # together and in the order they were added (later PRs append
+        # after them: the manifest only grows at its end)
+        names = [m["name"] for m in manifest["per_layer"]]
+        at = names.index(NEW_METRICS[0])
+        assert names[at:at + len(NEW_METRICS)] == NEW_METRICS
 
     def test_every_counter_a_reader_asks_for_is_in_the_vocabulary(self):
         """The readers ask the snapshot for counters by name: each name is
@@ -258,3 +260,116 @@ class TestHostGaps:
     def test_idle_gaps_ignore_overlapping_programs(self):
         mods = [("a", 0, 10), ("b", 5, 8), ("c", 12, 20), ("d", 20, 25)]
         assert hostgaps.idle_gaps(mods) == [(10, 12)]
+
+
+# -- PR 27's checks as tier-1 tests, and PR 28's readers ----------------------
+
+@pytest.mark.parametrize("script", ["check_readers", "check_spread"])
+def test_the_testdata_checks_pass(script):
+    """``benchmark/testdata/check_readers.py`` (PR 27's four readers on a
+    hand-made context) and ``check_spread.py`` (the manifest's window and
+    bound against the rule on the recorded sets): ``main()`` returns 0."""
+    sys.path.insert(0, os.path.join(BENCH, "testdata"))
+    import importlib
+
+    assert importlib.import_module(script).main() == 0
+
+
+CACHE_METRICS = [
+    "cache_rows_live_share.itl", "cache_summary_row_share.itl",
+    "cache_context_per_row.obs", "window_dumps_per_s.obs",
+    "window_dump_device_share.itl", "decode_attn_roofline.itl",
+]
+CELL = "evabyte_6b5.longdoc"
+
+
+def cache_ctx():
+    """A window of 1000 waves of 10 live lanes, each lane reading 1000
+    summaries and 1000 exact rows at a context of 17000 positions; 20 dumps
+    in 50 s; a trace of 4 s holding 300 whole waves and 20 pieces."""
+    import numpy as np
+
+    with open(os.path.join(BENCH, "configs", "evabyte_6b5.json")) as f:
+        cfg = json.load(f)
+    zero = dict.fromkeys(
+        ("fetched_waves", "fetched_lanes_live", "fetched_positions_valid",
+         "fetched_rows_exact", "fetched_rows_summary", "transitions"), 0)
+    after = dict(fetched_waves=1000, fetched_lanes_live=10_000,
+                 fetched_positions_valid=170_000_000,
+                 fetched_rows_exact=10_000_000,
+                 fetched_rows_summary=10_000_000, transitions=20)
+    layers = cfg["num_hidden_layers"]
+    # the decode kernel at its roofline: 10 lanes x 2001 rows x 4096 x 2 B x
+    # K,V = 327.8 MB a layer at 819 GB/s, 300 waves x 8 layers; traced at
+    # twice that.
+    least = 2 * 10 * 2001 * 4096 * 2 / 819e9
+    trace = {"window_s": 4.0, "modules": {
+        "jit_decode": {"count": 300, "total_s": 3.0, "mean_ms": 10.0},
+        "jit_prefill": {"count": 20, "total_s": 0.9, "mean_ms": 45.0},
+        "jit_transition": {"count": 2, "total_s": 0.002, "mean_ms": 1.0}},
+        "device_ops": [
+            ["decode_wave_attention.3_bf16_8_17_4096_4096_",
+             2 * 300 * layers * least],
+            ["fusion.7_bf16_2048_11008_", 0.5],
+            ["flash_attention.5_bf16_1_32_2048_128_", 20 * layers * 1e-3]]}
+    req = {"prompt_len": np.asarray([2048.0 * 3]),
+           "in_window": np.asarray([True]), "ok": np.asarray([True])}
+    return {"cfg": cfg, "seconds": 50.0, "trace": trace, "req": req,
+            "device": {"kind": "TPU v5e"},
+            "snap_before": snap({"gen.transition_dispatch": (0, 0)}, zero),
+            "snap_after": snap({"gen.transition_dispatch": (20, 8)}, after)}
+
+
+class TestCacheReaders:
+    def test_values_on_a_hand_made_window(self):
+        ctx = cache_ctx()
+        got = {name: reader(name)(ctx) for name in CACHE_METRICS}
+        assert got["cache_rows_live_share.itl"] == pytest.approx(
+            100 * 20_000 / (16 * 4096))
+        assert got["cache_summary_row_share.itl"] == pytest.approx(50.0)
+        assert got["cache_context_per_row.obs"] == pytest.approx(8.5)
+        assert got["window_dumps_per_s.obs"] == pytest.approx(0.4)
+        assert got["window_dump_device_share.itl"] == pytest.approx(0.05)
+        assert got["decode_attn_roofline.itl"] == pytest.approx(
+            50.0 * 2000 / 2001 * (2001 / 2000), rel=1e-3)
+        # a trace that happens to hold no dump reads 0, not nothing
+        del ctx["trace"]["modules"]["jit_transition"]
+        assert reader("window_dump_device_share.itl")(ctx) == 0.0
+        assert all(v is not None and v <= 100 for v in got.values())
+
+    @pytest.mark.parametrize("name", CACHE_METRICS)
+    def test_nothing_to_read_on_the_parent(self, name):
+        """The parent serves none of the counters, the span or the
+        programs, and its configuration has no ``cache_slot_rows``: each
+        reader returns nothing and does not raise."""
+        ctx = cache_ctx()
+        old = {"t": 0.0, "stats": {}, "profile": {"models": {"gpt:1": {
+            "decode_waves": [], "generative": {
+                "spans": {"gen.loop": {"count": 1, "total_ns": 1,
+                                       "max_ns": 1}},
+                "counters": {"fetched_waves": 5}}}}}}
+        with open(os.path.join(BENCH, "configs", "gpt2_small.json")) as f:
+            gpt = json.load(f)
+        parent = dict(ctx, cfg=gpt, snap_before=old, snap_after=old,
+                      trace={"window_s": 4.0, "modules": {"jit_decode": {
+                          "count": 3, "total_s": 1.0, "mean_ms": 4.0}},
+                          "device_ops": [["fusion.1_f32_8_", 1.0]]})
+        assert reader(name)(parent) is None
+        assert reader(name)(dict(parent, trace=None, snap_before=None,
+                                 snap_after=None)) is None
+
+    def test_the_manifest_lists_them_for_the_cell_only(self):
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+        by = {m["name"]: m for m in manifest["per_layer"]}
+        for name in CACHE_METRICS:
+            assert by[name]["workloads"] == [CELL]
+            assert by[name]["moves"] == "itl_mean_ms"
+        for name in ("arena_live_share.itl", "kv_live_share.itl"):
+            assert CELL not in by[name]["workloads"]   # slots x positions
+        from client_tpu.observability import spans
+
+        for name in ("fetched_rows_exact", "fetched_rows_summary",
+                     "prompts_admitted", "prefill_pieces", "transitions"):
+            assert name in spans.GEN_COUNTERS
+        assert "gen.transition_dispatch" in spans.GEN_SPANS

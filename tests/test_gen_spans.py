@@ -112,7 +112,7 @@ class TestLoopSpans:
         _, after, _ = ran
         assert list(after["spans"]) == list(spans.GEN_SPANS)
         assert list(after["counters"]) == list(spans.GEN_COUNTERS)
-        assert len(set(spans.GEN_SPANS)) == 9
+        assert len(set(spans.GEN_SPANS)) == 10
 
     def test_children_partition_the_iteration(self, ran):
         _, after, _ = ran

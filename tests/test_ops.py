@@ -247,6 +247,102 @@ class TestFusedDecodeKernel:
                                   layer=0, block_s=24, interpret=True)
 
 
+class TestDecodeKernelByDtype:
+    """One kernel, two dtypes (ISSUE 28): float32 arenas (GPT-2) and
+    bfloat16 arenas (a 16-row HBM tile, blocks to the MXU in one pass),
+    with the layer static or taken by scalar prefetch (a decoder that scans
+    over its layers)."""
+
+    @staticmethod
+    def _case(dtype, s=64, h=4, d=32):
+        k_a, v_a, q, kn, vn, rows, _ = _decode_case(s=s, h=h, d=d)
+        lens = jnp.asarray([7, 16, s - 1, 0], jnp.int32)
+        return k_a.astype(dtype), v_a.astype(dtype), q, kn, vn, rows, lens
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                          (jnp.bfloat16, 3e-2)])
+    @pytest.mark.parametrize("dynamic", [False, True])
+    def test_parity_with_the_xla_oracle(self, dtype, tol, dynamic):
+        k_a, v_a, q, kn, vn, rows, lens = self._case(dtype)
+        for layer in (0, 1):
+            kw = (dict(layer=None, layer_index=jnp.int32(layer)) if dynamic
+                  else dict(layer=layer))
+            fk, fv, fo = decode_wave_attention(
+                k_a, v_a, q, kn, vn, rows, lens, block_s=16,
+                interpret=True, **kw)
+            rk, rv, ro = reference_decode_attention(
+                k_a, v_a, q, kn, vn, rows, lens, layer=layer)
+            assert fk.dtype == dtype and fo.dtype == jnp.float32
+            assert float(jnp.max(jnp.abs(fo[:3] - ro[:3]))) < tol
+            # The write is exact in the arena's dtype, on every row a real
+            # lane touched, and nothing else of the slot moved.
+            for b in (0, 1, 2):
+                r = int(rows[b])
+                np.testing.assert_array_equal(
+                    np.asarray(fk[layer, r].astype(jnp.float32)),
+                    np.asarray(rk[layer, r].astype(jnp.float32)))
+                np.testing.assert_array_equal(
+                    np.asarray(fv[layer, r].astype(jnp.float32)),
+                    np.asarray(rv[layer, r].astype(jnp.float32)))
+
+    def test_row_group_follows_the_dtype(self):
+        from client_tpu.ops.decode_kernel import row_group
+
+        assert row_group(jnp.float32) == 8
+        assert row_group(jnp.bfloat16) == 16
+
+    def test_float32_program_is_the_one_it_was(self):
+        """For a float32 arena and a static layer the dtype-generic kernel
+        traces to GPT-2's program: the seven operands it always had (the
+        layer index is an eighth only when it is traced), full-precision
+        products and nothing in bfloat16."""
+        k_a, v_a, q, kn, vn, rows, lens = self._case(jnp.float32)
+
+        def call(**kw):
+            jaxpr = jax.make_jaxpr(lambda *a: decode_wave_attention(
+                *a, block_s=16, interpret=False, **kw))(
+                    k_a, v_a, q, kn, vn, rows, lens)
+            (outer,) = jaxpr.jaxpr.eqns
+            (eqn,) = [e for e in outer.params["jaxpr"].jaxpr.eqns
+                      if e.primitive.name == "pallas_call"]
+            return eqn, str(eqn.params["jaxpr"])
+
+        eqn, body = call(layer=0)
+        assert len(eqn.invars) == 7
+        assert "bf16" not in body and "HIGHEST" in body
+        eqn, _ = call(layer=None, layer_index=jnp.int32(0))
+        assert len(eqn.invars) == 8
+
+
+class TestFlashPrefix:
+    """``flash_attention(prefix=P)``: the first P keys stand before every
+    query (masked by the bias where unused), the rest are causal."""
+
+    @pytest.mark.parametrize("n_seen", [0, 5, 16])
+    def test_matches_a_dense_mask(self, n_seen):
+        b, s, h, d, pre = 2, 32, 2, 16, 16
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        q = jax.random.normal(ks[0], (b, s, h, d))
+        k = jax.random.normal(ks[1], (b, pre + s, h, d))
+        v = jax.random.normal(ks[2], (b, pre + s, h, d))
+        idx = jnp.arange(pre + s)
+        seen = (idx < n_seen) | (idx >= pre)
+        bias = jnp.broadcast_to(jnp.where(seen, 0.0, -1e30), (b, pre + s))
+        got = flash_attention(q, k, v, bias, causal=True, prefix=pre,
+                              block_q=8, block_k=16, interpret=True)
+        ok = seen[None, :] & ((idx[None, :] - pre) <= jnp.arange(s)[:, None])
+        sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+        sc = jnp.where(ok[None, None], sc, -1e30)
+        want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
+        assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+
+    def test_rejects_keys_that_are_not_prefix_plus_queries(self):
+        q = jnp.zeros((1, 16, 1, 8))
+        k = jnp.zeros((1, 24, 1, 8))
+        with pytest.raises(ValueError, match="prefix"):
+            flash_attention(q, k, k, causal=True, prefix=16, interpret=True)
+
+
 class TestDecodeKernelGpt2Geometry:
     """The arena access at GPT-2's head geometry (12 heads x 64 on a
     768-lane row): the kernel against the XLA oracle where the block does
