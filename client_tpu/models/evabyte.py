@@ -353,8 +353,6 @@ class EvaByteBackend(ModelBackend):
 
         b, w = q.shape[:2]
         pre = pk.shape[1]
-        keys = jnp.concatenate([pk, k_c], axis=1)
-        vals = jnp.concatenate([pv, v_c], axis=1)
         idx = jnp.arange(pre + w)
         seen = (idx[None, :] < n_sum[:, None]) | (idx[None, :] >= pre)
         if self.attention_impl == "flash":
@@ -362,13 +360,23 @@ class EvaByteBackend(ModelBackend):
             from client_tpu.ops.decode_kernel import pick_block_s
             from client_tpu.ops.flash_attention import flash_attention
 
+            def dense(t):
+                # The kernel's layout is a cache row's: [B, n, H*D].
+                return t.reshape(b, t.shape[1], self.d_model)
+
             cap_q, cap_k = self.flash_blocks
             return flash_attention(
-                q.astype(keys.dtype), keys, vals,
+                dense(q).astype(k_c.dtype),
+                jnp.concatenate([dense(pk), dense(k_c)], axis=1),
+                jnp.concatenate([dense(pv), dense(v_c)], axis=1),
                 jnp.where(seen, 0.0, _NEG_INF).astype(jnp.float32),
-                causal=True, prefix=pre, block_q=pick_block_s(w, cap_q),
+                causal=True, prefix=pre, n_heads=self.n_heads,
+                block_q=pick_block_s(w, cap_q),
                 block_k=pick_block_s(pre + w, cap_k),
-                interpret=pallas_interpret()).astype(jnp.float32)
+                interpret=pallas_interpret()
+            ).astype(jnp.float32).reshape(q.shape)
+        keys = jnp.concatenate([pk, k_c], axis=1)
+        vals = jnp.concatenate([pv, v_c], axis=1)
         s = jnp.einsum("bqhd,bkhd->bhqk", q, keys.astype(jnp.float32))
         s = s / math.sqrt(self.head_dim)
         causal = (idx[None, :] - pre) <= jnp.arange(w)[:, None]   # [W, P+W]

@@ -27,6 +27,7 @@ bit-identical token streams to solo decode.
 
 from __future__ import annotations
 
+import functools
 import math
 from client_tpu import config as envcfg
 
@@ -105,10 +106,15 @@ class TinyGptBackend(ModelBackend):
                 f"attention_impl must be 'einsum' or 'flash', got "
                 f"{attention_impl!r}")
         self.attention_impl = attention_impl
-        # Flash tile caps (block_q, block_k): 512/1024 measured fastest at
-        # s=2048 on v5e (bert.py's sweep); tests shrink them to drive the
-        # multi-block grid at short sequence.
-        self.flash_blocks = (512, 1024)
+        # Flash block caps (block_q, block_k), what one DMA brings.  On v5e
+        # a layer of GPT-2's prefill (8 x 1024 positions) takes 0.36 ms in
+        # one 1024 x 1024 block a head pair, 0.46 in 512 x 1024 and 0.61 in
+        # 512 x 512 though that grid skips a quarter of the rectangle: a
+        # grid step costs more than the arithmetic it saves, so the kernel
+        # cuts the causal triangle inside the block (0.25 ms; PERF.md
+        # section 6, PR 29).  Tests shrink them to drive the multi-block
+        # grid at short sequence.
+        self.flash_blocks = (1024, 1024)
         # Decode-wave implementation: "fused" runs the Pallas kernel
         # (ops/decode_kernel.py): one row written in place, each live row
         # read once.  "reference" is the stacked-XLA oracle (scatter,
@@ -208,8 +214,8 @@ class TinyGptBackend(ModelBackend):
 
         def apply(p, inputs):
             ids = inputs["INPUT_IDS"].astype("int32")
-            x, _ = self._embed_positions(p, ids, 0)
-            x = self._stack(p, x, causal=True)
+            x, _ = self._embed_positions(p, ids[None], 0)
+            x = self._stack(p, x, causal=True)[0]
             logits = _ln(x, p["lnfg"], p["lnfb"]) @ p["head"]
             return {"logits": logits}
 
@@ -229,19 +235,21 @@ class TinyGptBackend(ModelBackend):
     def _embed_positions(self, p, ids, start):
         import jax.numpy as jnp
 
-        n = ids.shape[0]
-        pos = jnp.arange(n) + start
+        pos = jnp.arange(ids.shape[-1]) + start
         return p["embed"][ids] + p["pos"][pos], pos
 
     def _stack(self, p, x, causal, on_kv=None):
-        """Full-context transformer stack (no cache reads). ``on_kv(li, k,
-        v)`` observes each layer's K/V at trace time — the prefill path
-        uses it to populate the KV arena with the same math the plain
-        forward runs."""
+        """Full-context transformer stack (no cache reads) over ``x`` [B, n,
+        d].  ``on_kv(li, k, v)`` observes each layer's K/V at trace time,
+        ``[B, n, H*D]`` as the projections leave them, and returns them as
+        the layer goes on to use them — the prefill path uses it to populate
+        the KV arena with the same math the plain forward runs.  q, k and v
+        stay in that layout from ``h @ w`` to ``@ wo``: it is an arena
+        row's and the flash kernel's."""
         import jax
         import jax.numpy as jnp
 
-        n = x.shape[0]
+        b, n, _ = x.shape
         h_, d_ = self.n_heads, self.head_dim
         pos = jnp.arange(n)
         mask = pos[None, :] <= pos[:, None] if causal else None
@@ -250,37 +258,32 @@ class TinyGptBackend(ModelBackend):
         def attend(q, k, v):
             if use_flash:
                 from client_tpu.engine.backend_init import pallas_interpret
+                from client_tpu.ops.decode_kernel import pick_block_s
                 from client_tpu.ops.flash_attention import flash_attention
-
-                def pick_block(s_len, cap):
-                    best = None
-                    for cand in range(8, min(cap, s_len) + 1, 8):
-                        if s_len % cand == 0:
-                            best = cand
-                    return best if best is not None else s_len
 
                 cap_q, cap_k = self.flash_blocks
                 return flash_attention(
-                    q[None], k[None], v[None], causal=True,
-                    block_q=pick_block(n, cap_q),
-                    block_k=pick_block(n, cap_k),
-                    interpret=pallas_interpret())[0]
-            s = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(d_)
+                    q, k, v, causal=True, n_heads=h_,
+                    block_q=pick_block_s(n, cap_q),
+                    block_k=pick_block_s(n, cap_k),
+                    interpret=pallas_interpret())
+            q, k, v = (t.reshape(b, n, h_, d_) for t in (q, k, v))
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d_)
             if mask is not None:
-                s = jnp.where(mask[None], s, -1e30)
-            return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s), v)
+                s = jnp.where(mask[None, None], s, -1e30)
+            o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s), v)
+            return o.reshape(b, n, self.d_model)
 
         for li, lp in enumerate(p["layers"]):
             h = _ln(x, lp["ln1g"], lp["ln1b"])
-            q = (h @ lp["wq"]).reshape(n, h_, d_)
-            k = (h @ lp["wk"]).reshape(n, h_, d_)
-            v = (h @ lp["wv"]).reshape(n, h_, d_)
+            q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
             if on_kv is not None:
-                on_kv(li, k, v)
-            o = attend(q, k, v)
-            x = x + o.reshape(n, self.d_model) @ lp["wo"]
+                k, v = on_kv(li, k, v)
+            x = x + attend(q, k, v) @ lp["wo"]
             h2 = _ln(x, lp["ln2g"], lp["ln2b"])
-            x = x + self._ffn(lp, h2)
+            # `_ffn` takes one sequence's [T, d] rows (a routed variant
+            # sizes its expert queues by T).
+            x = x + jax.vmap(lambda t, lp=lp: self._ffn(lp, t))(h2)
         return x
 
     # -- generative interface (used by GenerativeScheduler) -------------------
@@ -332,6 +335,46 @@ class TinyGptBackend(ModelBackend):
             arena = shard_arena(arena, self._mesh())
         return arena
 
+    def _use_kernel(self) -> bool:
+        """Whether the arena is the Pallas kernels' (the decode wave and
+        prefill's write): by ``attn_impl``, or unset wherever Mosaic
+        compiles them."""
+        from client_tpu.engine.backend_init import pallas_interpret
+
+        return self.attn_impl == "fused" or (
+            not self.attn_impl and not pallas_interpret())
+
+    def _prompt_rows_writer(self):
+        """``write(k_arena, v_arena, k, v, rows, layer)`` -> the two leaves
+        with ``[layer, rows[b], :n]`` holding lane b's ``[n, H*D]`` slab.
+        Where the decode wave is the kernel, so is this (one DMA a lane and
+        leaf, ops/arena_write.py; per shard of a row-sharded arena); else,
+        and for a prompt bucket shorter than a row group, XLA's in-place
+        scatter."""
+        from client_tpu.engine.backend_init import pallas_interpret
+        from client_tpu.ops.arena_write import (kernel_writes,
+                                                reference_write_prompt_rows,
+                                                write_prompt_rows)
+
+        interpret = pallas_interpret()
+        kernel = self._use_kernel()
+        if kernel and self.kv_shards > 1:
+            from client_tpu.parallel.kv_shard import \
+                sharded_write_prompt_rows
+
+            put = functools.partial(sharded_write_prompt_rows, self._mesh())
+        else:
+            put = write_prompt_rows
+
+        def write(k_a, v_a, k, v, rows, layer):
+            if kernel and kernel_writes(k.shape[1], k_a.dtype):
+                return put(k_a, v_a, k, v, rows, layer=layer,
+                           interpret=interpret)
+            return reference_write_prompt_rows(k_a, v_a, k, v, rows,
+                                               layer=layer)
+
+        return write
+
     def prefill_fn(self):
         """(params, arena, rows[B], ids[B, S_pad], lens[B], seeds[B],
         temps[B], top_ks[B], top_ps[B]) -> (arena, first_tokens[B]).
@@ -342,30 +385,32 @@ class TinyGptBackend(ModelBackend):
         per-admit prefill stalled every live decode stream for each admit).
         Causal masking makes the padded tail invisible to every valid
         query; padded LANES (rows pointing at the dummy row) are absorbed
-        the same way decode waves absorb them.
+        the same way decode waves absorb them.  Each layer's K and V go
+        from the projection into the donated arena's rows as they are
+        produced: nothing is stacked, transposed or staged.
         """
         import jax
+        import jax.numpy as jnp
+
+        write = self._prompt_rows_writer()
 
         def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
                     sample=True):
-            n = ids.shape[1]
-
-            def one(ids_row):
-                x, _pos = self._embed_positions(p, ids_row, 0)
-                ks, vs = [], []
-                x = self._stack(p, x, causal=True,
-                                on_kv=lambda li, k, v:
-                                (ks.append(k.reshape(n, -1)),
-                                 vs.append(v.reshape(n, -1))))
-                import jax.numpy as jnp
-
-                return x, jnp.stack(ks), jnp.stack(vs)  # [S,d],[L,S,H*D]x2
-
-            xB, kB, vB = jax.vmap(one)(ids)              # [B,...]
-            import jax.numpy as jnp
-
             b = rows.shape[0]
-            xf = _ln(xB[jnp.arange(b), lens - 1], p["lnfg"], p["lnfb"])
+            leaves = [arena["k"], arena["v"]]
+
+            def on_kv(li, k, v):
+                # The barrier orders the write before the layer's attention:
+                # left alone the compiler defers all writes to the end and
+                # keeps every layer's K and V alive until then.
+                k_a, v_a, k, v = jax.lax.optimization_barrier(
+                    (*write(*leaves, k, v, rows, li), k, v))
+                leaves[:] = k_a, v_a
+                return k, v
+
+            x, _pos = self._embed_positions(p, ids, 0)       # [B, S_pad, d]
+            x = self._stack(p, x, causal=True, on_kv=on_kv)
+            xf = _ln(x[jnp.arange(b), lens - 1], p["lnfg"], p["lnfb"])
             logits = xf @ p["head"]                      # [B, vocab]
             # `sample` is a STATIC arg: the all-greedy variant (the default
             # workload) compiles without the sort/cumsum/PRNG pipeline —
@@ -375,17 +420,10 @@ class TinyGptBackend(ModelBackend):
                     logits, seeds, lens, temps, top_ks, top_ps)
             else:
                 tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            # Scatter whole prompt rows: [B,L,S,H*D] -> arena [L,rows,:n];
-            # the first token lands in the device-side token slot so the
+            # The first token lands in the device-side token slot so the
             # first decode wave can start without the host fetch.
-            arena = {
-                **arena,
-                "k": arena["k"].at[:, rows, :n].set(
-                    kB.transpose(1, 0, 2, 3)),
-                "v": arena["v"].at[:, rows, :n].set(
-                    vB.transpose(1, 0, 2, 3)),
-                "tok": arena["tok"].at[rows].set(tokens),
-            }
+            arena = {**arena, "k": leaves[0], "v": leaves[1],
+                     "tok": arena["tok"].at[rows].set(tokens)}
             return arena, tokens
 
         return prefill
@@ -438,10 +476,7 @@ class TinyGptBackend(ModelBackend):
         ``attn_impl="reference"`` selects the body below, the per-layer
         scatter/gather/dense-softmax stack kept as the parity oracle.
         """
-        from client_tpu.engine.backend_init import pallas_interpret
-
-        if self.attn_impl == "fused" or (
-                not self.attn_impl and not pallas_interpret()):
+        if self._use_kernel():
             return self._fused_decode_fn()
         import jax
         import jax.numpy as jnp

@@ -8,13 +8,31 @@ running max / denominator / weighted accumulator across key blocks, so HBM
 traffic drops from O(S²) to O(S·D) and the MXU stays fed
 (pallas_guide.md: VMEM ~16 MB/core, MXU 128×128 tiles).
 
-The public layout is the serving models' native [B, S, H, D]; internally
-the kernel runs on [B, H, S, D] (TPU block shapes tile the last two dims —
-pallas requires them (8,128)-aligned or full); masking is an additive
-[B, S_k] bias (0 keep / -inf drop, the
-encoder padding-mask convention) plus an optional causal flag for decoder/
-long-context LM use. ``interpret=True`` runs the same kernel on CPU for the
-hermetic test suite.
+The kernel reads q, k and v **as the projections write them**: ``[B, S,
+H*D]``, the heads' features side by side on the minor axis (the layout of a
+key/value arena row, models/generate.py), and writes its output the same
+way, which is what ``@ wo`` reads.  Its blocks are ``[block, tile]`` slabs of
+that array (``head_tile``): one head where a head fills whole 128-lane tiles
+(D = 128), the tile that ``128 // D`` heads share (D = 64: two), and the
+whole row where neither holds (the tests' narrow models).  A tile's heads are
+told apart as ops/decode_kernel.py tells them: head j's query keeps its own
+lanes and zeros elsewhere, so ``q_j @ K^T`` over the tile's lanes is head j's
+scores, and ``p_j @ V`` is head j's output on its own lanes (the MXU
+contracts 128 deep and writes 128 wide either way).  No operand is transposed
+or copied before the call.  (Until PR 29 the kernel ran on ``[B, H, S, D]``
+and the wrapper transposed q, k, v in and the output back: 4.4 ms of a 21.9
+ms GPT-2 prefill, PERF.md section 6.)  A caller whose arrays are ``[B, S, H,
+D]`` passes them so: the reshape is the identity on a row-major array.
+
+Masking is an additive [B, S_k] bias (0 keep / -inf drop, the encoder
+padding-mask convention) plus an optional causal flag for decoder/
+long-context LM use; a key block that the causal rule masks whole is neither
+fetched (its index map repeats the last block that counts) nor computed.
+Where one block holds every key (GPT-2's 1024 positions) there is nothing to
+carry: each piece of 256 queries takes the keys up to its last one, one
+softmax, and writes its rows (0.25 ms a layer on v5e where the carried form,
+whose scratch updates serialize the heads, takes 0.36; PERF.md section 6).
+``interpret=True`` runs the same kernel on CPU for the hermetic test suite.
 """
 
 from __future__ import annotations
@@ -26,93 +44,197 @@ import jax.numpy as jnp
 import numpy as np
 
 _NEG_INF = -1e30
+_LANES = 128
+# Rows of a piece where one block holds the whole causal problem (sub_q).
+SUB_Q = 256
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref,
-               m_ref, l_ref, acc_ref,
-               *, block_q: int, block_k: int, causal: bool,
-               sm_scale: float, prefix: int = 0):
-    """One (batch, head, q-block, k-block) grid step.
+def head_tile(n_heads: int, head_dim: int) -> int:
+    """Lanes of the unit in which heads are told apart, in a ``[.., H*D]``
+    operand: a head where it fills whole 128-lane tiles, the tile that
+    ``128 // D`` heads share, or the whole row where neither holds."""
+    if head_dim % _LANES == 0:
+        return head_dim
+    if _LANES % head_dim == 0 and (n_heads * head_dim) % _LANES == 0:
+        return _LANES
+    return n_heads * head_dim
 
-    Grid iterates k innermost (TPU grids run sequentially), so the VMEM
-    scratch (m/l/acc) carries the online-softmax state across k blocks of
-    one q block and is re-initialized when the k index wraps to 0.
+
+def _fa_kernel(*refs, block_q: int, block_k: int, sub_q: int,
+               grid_qk: tuple, causal: bool, sm_scale: float, prefix: int,
+               head_dim: int, has_bias: bool):
+    """One (batch, lane tile, q-block, k-block) grid step.
+
+    A block is what one DMA brings; the arithmetic takes its queries
+    ``sub_q`` rows at a time, each piece against the keys of the block that
+    the causal rule lets it see.  Where one block holds all the keys a
+    piece's softmax is whole in one pass and its output is written as it
+    comes.  Otherwise the grid iterates k innermost (TPU grids run
+    sequentially) and the VMEM scratch (m/l/acc, one of each per head of the
+    tile) carries the online-softmax state across the k blocks of one q
+    block, re-initialized when the k index wraps to 0.
     """
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    o_ref = refs[3 + has_bias]
+    # Where the grid has one block along an axis its index is 0 at trace
+    # time, and so is every causal test that follows from it.
+    iq = pl.program_id(2) if grid_qk[0] > 1 else 0
+    ik = pl.program_id(3) if grid_qk[1] > 1 else 0
+    single = grid_qk[1] == 1
+    if not single:
+        m_ref, l_ref, acc_ref = refs[4 + has_bias:]
+    tile = q_ref.shape[-1]
+    heads = tile // head_dim
 
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
 
-    # Blocks arrive as [1, 1, block, d] / [1, 1, block] — drop unit axes.
-    q = q_ref[0, 0]                            # [bq, d]
-    k = k_ref[0, 0]                            # [bk, d]
-    v = v_ref[0, 0]                            # [bk, d]
-    bias = bias_ref[0, 0]                      # [bk]
+    def own(j):
+        """Head j's lanes of the tile."""
+        return (lane >= j * head_dim) & (lane < (j + 1) * head_dim)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)     # [bq, bk]
-    s = s * sm_scale + bias[None, :].astype(jnp.float32)
-    if causal:
-        q_pos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        if prefix:
+    def merge(out, j, o_j):
+        """Head j's lanes of ``o_j`` into the tile's output."""
+        if heads == 1:
+            return o_j
+        return jnp.where(own(j), o_j, 0.0 if out is None else out)
+
+    def piece(a: int, width: int, masked: bool):
+        """Queries ``a * sub_q ..`` of the block against its first ``width``
+        keys."""
+        rows = slice(a * sub_q, (a + 1) * sub_q)
+        q = q_ref[0, rows]                     # [sq, tile]
+        k = k_ref[0, :width]                   # [width, tile]
+        v = v_ref[0, :width]                   # [width, tile]
+        if masked:
+            q_pos = prefix + iq * block_q + a * sub_q + (
+                jax.lax.broadcasted_iota(jnp.int32, (sub_q, width), 0))
+            k_pos = ik * block_k + (
+                jax.lax.broadcasted_iota(jnp.int32, (sub_q, width), 1))
             # The first ``prefix`` keys stand before every query (a cache's
             # summaries); the causal rule holds among the rest.
-            q_pos = q_pos + prefix
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            visible = q_pos >= k_pos
+        out = None
+        for j in range(heads):
+            # Head j's query keeps its own lanes: over the tile's lanes
+            # q_j @ K^T is head j's scores.
+            q_j = q if heads == 1 else jnp.where(own(j), q, 0)
+            s = jax.lax.dot_general(
+                q_j, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)     # [sq, width]
+            s = s * sm_scale
+            if has_bias:
+                s = s + bias_ref[0, 0, :width][None, :].astype(jnp.float32)
+            if masked:
+                s = jnp.where(visible, s, _NEG_INF)
+            m_new = jnp.max(s, axis=-1, keepdims=True)  # [sq, 1]
+            if not single:
+                m_prev = m_ref[j, rows]
+                m_new = jnp.maximum(m_prev, m_new)
+            if has_bias:
+                # A row whose keys so far are all masked: exp(-inf - -inf)
+                # would be NaN.  (Without a bias the first block holds a
+                # key every query sees, so m_new is a real score.)
+                safe_m = jnp.where(m_new <= _NEG_INF, 0.0, m_new)
+                p = jnp.exp(jnp.where(s <= _NEG_INF, -jnp.inf, s) - safe_m)
+            else:
+                safe_m = m_new
+                p = jnp.exp(s - m_new)
+            l_new = jnp.sum(p, axis=-1, keepdims=True)
+            # p_j @ V over the tile's lanes: head j's output on its own
+            # lanes; the other lanes are dropped when the heads are merged.
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)     # [sq, tile]
+            if single:
+                out = merge(out, j, pv / jnp.where(l_new == 0.0, 1.0, l_new))
+                continue
+            correction = jnp.exp(m_prev - safe_m)
+            if has_bias:
+                correction = jnp.where(m_prev <= _NEG_INF, 0.0, correction)
+            l_ref[j, rows] = l_ref[j, rows] * correction + l_new
+            acc_ref[j, rows] = acc_ref[j, rows] * correction + pv
+            m_ref[j, rows] = m_new
+        if single:
+            o_ref[0, rows] = out.astype(o_ref.dtype)
 
-    m_prev = m_ref[:]                          # [bq, 1]
-    l_prev = l_ref[:]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)  # [bq, 1]
-    m_new = jnp.maximum(m_prev, m_cur)
-    # Guard fully-masked rows: exp(-inf - -inf) would be NaN.
-    safe_m = jnp.where(m_new <= _NEG_INF, 0.0, m_new)
-    p = jnp.exp(jnp.where(s <= _NEG_INF, -jnp.inf, s) - safe_m)  # [bq, bk]
-    correction = jnp.where(m_prev <= _NEG_INF, 0.0,
-                           jnp.exp(m_prev - safe_m))
-    l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc_ref[:] * correction + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[:] = m_new
-    l_ref[:] = l_new
-    acc_ref[:] = acc
+    if not single:
+        @pl.when(ik == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        denom = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
-        o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
+    for a in range(block_q // sub_q):
+        if not causal:
+            piece(a, block_k, False)
+            continue
+        q_lo = prefix + iq * block_q + a * sub_q     # the piece's first query
+        k_lo = ik * block_k
+        if isinstance(q_lo - k_lo, int):
+            # The block's place is known: the piece takes the keys up to its
+            # last query (in whole lane tiles) and no others.
+            width = min(block_k,
+                        -(-(q_lo + sub_q - k_lo) // _LANES) * _LANES)
+            piece(a, width, k_lo + width - 1 > q_lo)
+            continue
+        # A block past the piece's last query is skipped (its index map
+        # fetched nothing new); one wholly before its first needs no mask.
+        needed = k_lo <= q_lo + sub_q - 1
+        masked = k_lo + block_k - 1 > q_lo
+        pl.when(needed & ~masked)(functools.partial(piece, a, block_k, False))
+        pl.when(needed & masked)(functools.partial(piece, a, block_k, True))
+
+    if not single:
+        @pl.when(ik == grid_qk[1] - 1)
+        def _finalize():
+            out = None
+            for j in range(heads):
+                l_j = l_ref[j]
+                out = merge(out, j,
+                            acc_ref[j] / jnp.where(l_j == 0.0, 1.0, l_j))
+            o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_k", "interpret", "prefix"))
+    "causal", "block_q", "block_k", "interpret", "prefix", "n_heads",
+    "sub_q"))
 def flash_attention(q, k, v, bias=None, *, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False, prefix: int = 0):
-    """Memory-efficient attention. q: [B, S, H, D]; k/v: [B, S_k, H, D] with
-    ``S_k = prefix + S``; bias: additive [B, S_k] key mask (0 = attend,
-    -inf/-1e9 = masked) or None. Returns [B, S, H, D].
+                    interpret: bool = False, prefix: int = 0,
+                    n_heads: int | None = None, sub_q: int | None = None):
+    """Memory-efficient attention.  q: ``[B, S, H*D]`` with ``n_heads=H``
+    (as ``h @ wq`` leaves it), or ``[B, S, H, D]``; k/v likewise with ``S_k =
+    prefix + S`` positions; bias: additive [B, S_k] key mask (0 = attend,
+    -inf/-1e9 = masked) or None.  Returns q's shape.
 
     ``prefix`` = 0 is self-attention (same S for q and k).  With ``prefix``
     > 0 the first ``prefix`` keys are a prefix that **every** query sees
     (subject to ``bias``, which masks the unused part of it) and the causal
     rule applies to the remaining ``S`` keys: a prefill piece attending to
     its cache's chunk summaries and, causally, to its own window
-    (models/evabyte.py)."""
+    (models/evabyte.py).
+
+    ``block_q``/``block_k`` are what one DMA brings (capped at the sequence
+    lengths); ``sub_q`` cuts a block's queries into pieces for the
+    arithmetic (default: where one block holds the whole causal problem,
+    pieces of ``SUB_Q`` rows, each against the keys up to its last query;
+    else the block)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, s, h, d = q.shape
+    shape = q.shape
+    if q.ndim == 4:
+        n_heads = shape[2]
+        q, k, v = (x.reshape(x.shape[0], x.shape[1], -1) for x in (q, k, v))
+    elif n_heads is None:
+        raise ValueError("[B, S, H*D] operands need n_heads")
+    b, s, hd = q.shape
+    if hd % n_heads:
+        raise ValueError(f"{hd} features do not hold {n_heads} heads")
+    d = hd // n_heads
     s_k = k.shape[1]
     if s_k != prefix + s:
         raise ValueError(
@@ -123,47 +245,55 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
         raise ValueError(
             f"block sizes ({block_q}/{block_k}) must divide the sequence "
             f"lengths {s}/{s_k}")
-    if bias is None:
-        bias = jnp.zeros((b, s_k), jnp.float32)
-    sm_scale = 1.0 / np.sqrt(d)
+    grid_qk = (s // block_q, s_k // block_k)
+    if sub_q is None:
+        whole = causal and grid_qk == (1, 1) and block_q % SUB_Q == 0
+        sub_q = SUB_Q if whole else block_q
+    if block_q % sub_q:
+        raise ValueError(f"pieces of {sub_q} queries must divide the block "
+                         f"({block_q})")
+    tile = head_tile(n_heads, d)
 
-    # Kernel-internal layout: [B, H, S, D] so blocks tile the (seq, head_dim)
-    # trailing dims.
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    # [B, 1, S]: the unit middle dim makes the (1, 1, block_k) bias block a
-    # legal TPU tile (trailing dims equal-or-aligned to the array's).
-    bias3 = bias[:, None, :]
+    def k_block(qi, ki):
+        if not causal:
+            return ki
+        # The last key block a query block sees; later ones repeat it, which
+        # the pipeline takes as unchanged and does not fetch.
+        return jnp.minimum(ki, (prefix + (qi + 1) * block_q - 1) // block_k)
 
-    grid = (b, h, s // block_q, s_k // block_k)
+    q_spec = pl.BlockSpec((1, block_q, tile),
+                          lambda bi, gi, qi, ki: (bi, qi, gi))
+    kv_spec = pl.BlockSpec((1, block_k, tile),
+                           lambda bi, gi, qi, ki: (bi, k_block(qi, ki), gi))
+    operands, in_specs = [q, k, v], [q_spec, kv_spec, kv_spec]
+    if bias is not None:
+        # [B, 1, S_k]: the unit middle dim makes the (1, 1, block_k) bias
+        # block a legal TPU tile (trailing dims equal-or-aligned to the
+        # array's).
+        operands.append(bias[:, None, :])
+        in_specs.append(pl.BlockSpec(
+            (1, 1, block_k),
+            lambda bi, gi, qi, ki: (bi, 0, k_block(qi, ki))))
     kernel = functools.partial(
-        _fa_kernel, block_q=block_q, block_k=block_k, causal=causal,
-        sm_scale=sm_scale, prefix=prefix)
+        _fa_kernel, block_q=block_q, block_k=block_k, sub_q=sub_q,
+        grid_qk=grid_qk, causal=causal, sm_scale=1.0 / np.sqrt(d),
+        prefix=prefix, head_dim=d, has_bias=bias is not None)
+    heads = tile // d
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bi, hi, qi, ki: (bi, 0, ki)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((block_q, d), jnp.float32),   # weighted accumulator
+        grid=(b, hd // tile) + grid_qk,
+        in_specs=in_specs,
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, s, hd), q.dtype),
+        # The carry across key blocks, where there is more than one.
+        scratch_shapes=[] if grid_qk[1] == 1 else [
+            pltpu.VMEM((heads, block_q, 1), jnp.float32),   # running max
+            pltpu.VMEM((heads, block_q, 1), jnp.float32),   # denominator
+            pltpu.VMEM((heads, block_q, tile), jnp.float32),  # accumulator
         ],
         interpret=interpret,
-    )(qt, kt, vt, bias3)
-    return out.transpose(0, 2, 1, 3)
+    )(*operands)
+    return out.reshape(shape)
 
 
 def reference_attention(q, k, v, bias=None, *, causal: bool = False):

@@ -234,3 +234,30 @@ def sharded_decode_attention(mesh, k_arena, v_arena, q, k_new, v_new,
                    out_specs=(arena_spec, arena_spec, rep),
                    check_vma=False)
     return fn(k_arena, v_arena, q, k_new, v_new, rows, lens)
+
+
+def sharded_write_prompt_rows(mesh, k_arena, v_arena, k_new, v_new, rows, *,
+                              layer: int, interpret: bool = False):
+    """Prefill's per-layer write (ops/arena_write.py) over a row-sharded
+    arena: every shard copies the lanes whose rows it holds into its local
+    rows and leaves the others out.  Same signature/returns as
+    ``write_prompt_rows`` plus the mesh."""
+    from client_tpu.ops.arena_write import write_prompt_rows
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    r_loc = k_arena.shape[1] // mesh.shape["kv"]
+
+    def body(k_sh, v_sh, kn, vn, rows):
+        lo = jax.lax.axis_index("kv") * r_loc
+        owned = (rows >= lo) & (rows < lo + r_loc)
+        return write_prompt_rows(
+            k_sh, v_sh, kn, vn, jnp.where(owned, rows - lo, r_loc - 1),
+            owned, layer=layer, interpret=interpret)
+
+    arena_spec = P(None, "kv")
+    rep = P()
+    fn = shard_map(body, mesh=mesh,
+                   in_specs=(arena_spec, arena_spec, rep, rep, rep),
+                   out_specs=(arena_spec, arena_spec), check_vma=False)
+    return fn(k_arena, v_arena, k_new, v_new, rows)

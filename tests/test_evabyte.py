@@ -205,6 +205,34 @@ def test_a_dropped_summary_term_fails(setup, term, monkeypatch):
     assert worst(inside, ref) < TOL
 
 
+@pytest.mark.parametrize("n_sum", [0, 8, 24])
+@pytest.mark.parametrize("heads,width", [(2, 128), (4, 64)])
+def test_piece_attention_flash_reads_cache_rows_as_they_lie(heads, width,
+                                                            n_sum):
+    """``_piece_attention`` through the flash kernel (q, keys and values
+    handed over as ``[B, n, H*D]`` cache rows, no operand transposed) against
+    its einsum path, at the published head width of 128 and at 64 (two heads
+    to a 128-lane tile), with an empty, a partly and a fully used prefix."""
+    be = backend(n_heads=heads, d_model=heads * width,
+                 attention_impl="flash")
+    be.flash_blocks = (16, 32)
+    ks = jax.random.split(jax.random.PRNGKey(width + n_sum), 5)
+    pre = be.slot_rows - W
+    shape = lambda n: (2, n, heads, width)               # noqa: E731
+    q = jax.random.normal(ks[0], shape(W))
+    k_c, v_c = (jax.random.normal(k, shape(W)).astype(jnp.bfloat16)
+                for k in ks[1:3])
+    pk, pv = (jax.random.normal(k, shape(pre)).astype(jnp.bfloat16)
+              for k in ks[3:])
+    seen = jnp.asarray([n_sum, 0], jnp.int32)
+    got = be._piece_attention(q, k_c, v_c, pk, pv, seen)
+    be.attention_impl = "einsum"
+    want = be._piece_attention(q, k_c, v_c, pk, pv, seen)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    # The kernel takes q in the cache's bfloat16, as the served path does.
+    assert float(jnp.max(jnp.abs(got - want))) < 3e-2
+
+
 def test_batched_lanes_equal_solo(setup):
     """Two prompts prefilled in one two-lane piece call and decoded in one
     wave give, lane for lane, the logits each gives alone (to the rounding

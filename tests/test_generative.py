@@ -1245,3 +1245,148 @@ class TestWaveBucketOverflow:
             assert results == solo
         finally:
             eng.shutdown()
+
+
+def _staged_prefill(backend):
+    """The prefill formulation this path had until PR 29, kept as the new
+    one's oracle: one lane at a time under ``vmap`` through ``[.., H, D]``
+    einsum attention, every layer's K and V stacked over layers, transposed
+    and scattered into the arena at the end.  Greedy."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models.generate import _ln
+
+    h_, d_ = backend.n_heads, backend.head_dim
+
+    def prefill(p, arena, rows, ids, lens):
+        n = ids.shape[1]
+        pos = jnp.arange(n)
+        mask = pos[None, :] <= pos[:, None]
+
+        def one(ids_row):
+            x = p["embed"][ids_row] + p["pos"][pos]
+            ks, vs = [], []
+            for lp in p["layers"]:
+                h = _ln(x, lp["ln1g"], lp["ln1b"])
+                q, k, v = ((h @ lp[w]).reshape(n, h_, d_)
+                           for w in ("wq", "wk", "wv"))
+                ks.append(k.reshape(n, -1))
+                vs.append(v.reshape(n, -1))
+                sc = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(d_)
+                sc = jnp.where(mask[None], sc, -1e30)
+                o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(sc), v)
+                x = x + o.reshape(n, -1) @ lp["wo"]
+                x = x + backend._ffn(lp, _ln(x, lp["ln2g"], lp["ln2b"]))
+            return x, jnp.stack(ks), jnp.stack(vs)
+
+        x_b, k_b, v_b = jax.vmap(one)(ids)
+        xf = _ln(x_b[jnp.arange(rows.shape[0]), lens - 1],
+                 p["lnfg"], p["lnfb"])
+        tokens = jnp.argmax(xf @ p["head"], axis=-1).astype(jnp.int32)
+        return {"k": arena["k"].at[:, rows, :n].set(
+                    k_b.transpose(1, 0, 2, 3)),
+                "v": arena["v"].at[:, rows, :n].set(
+                    v_b.transpose(1, 0, 2, 3)),
+                "tok": arena["tok"].at[rows].set(tokens)}, tokens
+
+    return prefill
+
+
+class TestPrefillWritesTheArenaOnce:
+    """``prefill_fn`` (K/V from the projection into the arena's rows, layer
+    by layer; attention on ``[B, n, H*D]``) against the staged formulation:
+    live lanes' rows and first tokens equal, padded lanes absorbed by the
+    dummy row, every other row bitwise what it was."""
+
+    KW = dict(n_layers=2, d_model=128, n_heads=2, d_ff=128, vocab=128,
+              max_seq_len=32, max_streams=8)
+
+    @pytest.mark.parametrize("attention_impl,attn_impl,kv_shards,n", [
+        ("einsum", "reference", 1, 16),   # XLA: the in-place scatter a layer
+        ("einsum", "fused", 1, 16),       # interpreted Pallas: a DMA a lane
+        ("einsum", "fused", 2, 16), ("einsum", "fused", 4, 16),
+        ("einsum", "fused", 1, 4),        # a bucket under a row group
+        ("einsum", "fused", 2, 4),
+        # The flash kernel on [B, n, H*D] (one chip: outside ``shard_map``
+        # a Mosaic call has no partitioning rule).
+        ("flash", "reference", 1, 16), ("flash", "fused", 1, 16),
+        ("flash", "fused", 1, 4),
+    ])
+    def test_against_the_staged_formulation(self, attention_impl, attn_impl,
+                                            kv_shards, n):
+        import jax
+        import jax.numpy as jnp
+
+        from client_tpu.models.generate import TinyGptBackend
+
+        backend = TinyGptBackend(
+            name="pf", attention_impl=attention_impl, attn_impl=attn_impl,
+            kv_shards=kv_shards, **self.KW)
+        backend.flash_blocks = (8, 8)          # a grid of several blocks
+        params = jax.tree_util.tree_map(jnp.asarray, backend._init_params())
+        free, dummy = backend.arena_rows()
+        # An arena with something in every row, placed as the backend
+        # places its own.
+        zeros = backend.init_arena(backend.max_streams)
+        keys = jax.random.split(jax.random.PRNGKey(4), 2)
+        before = {"k": np.asarray(jax.random.normal(keys[0],
+                                                    zeros["k"].shape)),
+                  "v": np.asarray(jax.random.normal(keys[1],
+                                                    zeros["v"].shape)),
+                  "tok": np.full(zeros["tok"].shape, -1, np.int32)}
+        arena = jax.tree_util.tree_map(
+            lambda a, z: jax.device_put(a, z.sharding), before, zeros)
+        rng = np.random.default_rng(n)
+        lens = np.asarray([n, 1, max(1, n - 3), 1, 2], np.int32)
+        rows = np.asarray([free[5], dummy, free[0], dummy, free[-1]],
+                          np.int32)
+        ids = rng.integers(0, 128, (5, n)).astype(np.int32)
+        lanes = (jnp.zeros(5, jnp.int32), jnp.zeros(5, jnp.float32),
+                 jnp.zeros(5, jnp.int32), jnp.ones(5, jnp.float32))
+        got, got_tok = jax.jit(backend.prefill_fn(), static_argnums=(9,),
+                               donate_argnums=(1,))(
+            params, arena, rows, ids, lens, *lanes, False)
+        want, want_tok = jax.jit(_staged_prefill(backend))(
+            params, before, rows, ids, lens)
+        live = [0, 2, 4]
+        np.testing.assert_array_equal(np.asarray(got_tok)[live],
+                                      np.asarray(want_tok)[live])
+        np.testing.assert_array_equal(np.asarray(got["tok"])[rows[live]],
+                                      np.asarray(want_tok)[live])
+        for leaf in ("k", "v"):
+            g, w, b4 = (np.asarray(a[leaf]) for a in (got, want, before))
+            for i in live:
+                np.testing.assert_allclose(g[:, rows[i], :n],
+                                           w[:, rows[i], :n], atol=2e-5)
+            touched = np.zeros(b4.shape[:3], bool)
+            touched[:, rows, :n] = True
+            np.testing.assert_array_equal(g[~touched], b4[~touched])
+            # The dummy row is where the padded lanes went: it changed.
+            assert not np.array_equal(g[:, dummy, :n], b4[:, dummy, :n])
+
+    def test_the_platform_decides_the_write_too(self, monkeypatch):
+        """Unset, the arena write is the kernel where the decode wave is:
+        the compiled program holds Mosaic's call on a chip and none where
+        Pallas would only be interpreted."""
+        import jax
+        import jax.numpy as jnp
+
+        from client_tpu.engine import backend_init
+        from client_tpu.models.generate import TinyGptBackend
+
+        monkeypatch.delenv("CLIENT_TPU_ATTN_IMPL", raising=False)
+        for interpreted, kernel in ((False, True), (True, False)):
+            monkeypatch.setattr(backend_init, "pallas_interpret",
+                                lambda v=interpreted: v)
+            backend = TinyGptBackend(name="w", **self.KW)
+            write = backend._prompt_rows_writer()
+            arena = jax.eval_shape(lambda b=backend: b.init_arena(8))
+            slab = jax.ShapeDtypeStruct((2, 16, 128), jnp.float32)
+            rows = jax.ShapeDtypeStruct((2,), jnp.int32)
+            text = str(jax.make_jaxpr(
+                lambda k_a, v_a, k, v, r, w=write: w(k_a, v_a, k, v, r, 1))(
+                    arena["k"], arena["v"], slab, slab, rows))
+            assert ("pallas_call" in text) == kernel

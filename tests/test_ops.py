@@ -343,6 +343,214 @@ class TestFlashPrefix:
             flash_attention(q, k, k, causal=True, prefix=16, interpret=True)
 
 
+def _dense_mask_attention(q, k, v, bias, *, causal, prefix):
+    """``reference_attention`` with the prefix rule: float32 scores over a
+    dense mask, q/k/v ``[B, S, H, D]``."""
+    s, s_k, d = q.shape[1], k.shape[1], q.shape[3]
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                    k.astype(jnp.float32)) / np.sqrt(d)
+    if bias is not None:
+        sc = sc + bias[:, None, None, :]
+    if causal:
+        ok = (jnp.arange(s_k)[None, :] - prefix) <= jnp.arange(s)[:, None]
+        sc = jnp.where(ok[None, None], sc, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1),
+                      v.astype(jnp.float32))
+
+
+class TestFlashLaneDenseLayout:
+    """The kernel on ``[B, S, H*D]`` operands, as the projections write
+    them: one head a lane block at D = 128, two heads sharing a 128-lane
+    tile at D = 64, the whole row where heads neither fill nor divide a
+    tile; causal blocks skipped and unmasked by their place in the grid."""
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, 2e-2)])
+    @pytest.mark.parametrize("h,d", [(4, 64), (2, 128), (3, 64), (2, 48)])
+    @pytest.mark.parametrize("mode", ["causal", "bias", "causal+bias",
+                                      "prefix"])
+    def test_against_the_dense_oracle(self, mode, h, d, dtype, tol):
+        b, s = 2, 64
+        pre = 32 if mode == "prefix" else 0
+        ks = jax.random.split(jax.random.PRNGKey(h * d), 3)
+        q = jax.random.normal(ks[0], (b, s, h * d), dtype)
+        k = jax.random.normal(ks[1], (b, pre + s, h * d), dtype)
+        v = jax.random.normal(ks[2], (b, pre + s, h * d), dtype)
+        bias = None
+        if mode != "causal":
+            idx = jnp.arange(pre + s)
+            # A masked tail (padding), or a prefix seen only in part.
+            drop = (idx >= 11) & (idx < pre) if pre else idx >= s - 13
+            bias = jnp.broadcast_to(jnp.where(drop, -1e30, 0.0),
+                                    (b, pre + s)).astype(jnp.float32)
+        causal = mode != "bias"
+        got = flash_attention(q, k, v, bias, causal=causal, prefix=pre,
+                              n_heads=h, block_q=16, block_k=32,
+                              interpret=True)
+        assert got.shape == q.shape and got.dtype == dtype
+
+        def heads(t):
+            return t.reshape(b, t.shape[1], h, d)
+
+        want = _dense_mask_attention(heads(q), heads(k), heads(v), bias,
+                                     causal=causal, prefix=pre)
+        err = jnp.abs(got.astype(jnp.float32) - want.reshape(b, s, h * d))
+        assert float(jnp.max(err)) < tol
+
+    @pytest.mark.parametrize("block_q,block_k,sub_q", [
+        (8, 8, None), (16, 64, None), (64, 16, None), (32, 32, 16),
+        (64, 64, None), (64, 64, 16), (64, 64, 64), (512, 512, None),
+        (512, 512, 128), (256, 512, 64)])
+    def test_causal_blocks_in_every_shape(self, block_q, block_k, sub_q):
+        """Blocks that the causal rule masks whole are skipped, blocks it
+        does not touch go unmasked, and where one block holds the whole
+        problem each piece of queries takes the keys up to its last one:
+        the result is the dense one whatever the grid."""
+        b, s, h, d = 1, max(64, block_q), 2, 64
+        q, k, v = _qkv(b, s, h, d)
+        got = flash_attention(q, k, v, causal=True, block_q=block_q,
+                              block_k=block_k, sub_q=sub_q, interpret=True)
+        ref = reference_attention(q, k, v, causal=True)
+        assert float(jnp.max(jnp.abs(got - ref))) < 2e-5
+        with pytest.raises(ValueError, match="pieces"):
+            flash_attention(q, k, v, causal=True, block_q=block_q,
+                            block_k=block_k, sub_q=block_q + 8,
+                            interpret=True)
+
+    def test_both_ranks_are_one_kernel(self):
+        b, s, h, d = 2, 32, 4, 64
+        q, k, v = _qkv(b, s, h, d)
+        four = flash_attention(q, k, v, causal=True, interpret=True)
+        three = flash_attention(*(t.reshape(b, s, h * d) for t in (q, k, v)),
+                                causal=True, n_heads=h, interpret=True)
+        np.testing.assert_array_equal(np.asarray(four).reshape(b, s, h * d),
+                                      np.asarray(three))
+        with pytest.raises(ValueError, match="n_heads"):
+            flash_attention(*(t.reshape(b, s, h * d) for t in (q, k, v)),
+                            interpret=True)
+
+    def test_no_operand_is_transposed(self):
+        """q, k, v reach the kernel as they were given and the output
+        leaves as it is: no transpose in the traced program."""
+        from client_tpu.ops.flash_attention import head_tile
+
+        q = jnp.zeros((2, 32, 12 * 64))
+        jaxpr = jax.make_jaxpr(lambda *a: flash_attention(
+            *a, causal=True, n_heads=12))(q, q, q)
+        (outer,) = jaxpr.jaxpr.eqns
+        names = [e.primitive.name for e in outer.params["jaxpr"].jaxpr.eqns]
+        assert "pallas_call" in names and "transpose" not in names
+        assert (head_tile(12, 64), head_tile(32, 128), head_tile(4, 16),
+                head_tile(3, 64), head_tile(8, 32)) == (128, 128, 64, 192, 128)
+
+
+class TestPromptRowsWrite:
+    """Prefill's write into the arena (ops/arena_write.py): lane b's
+    ``[n, H*D]`` slab lands at ``[layer, rows[b], :n]``; every other row,
+    position and layer is bitwise what it was."""
+
+    def _case(self, n=16, dtype=jnp.float32):
+        ks = jax.random.split(jax.random.PRNGKey(11), 4)
+        shape = (2, 6, 32, 128)
+        k_a = jax.random.normal(ks[0], shape).astype(dtype)
+        v_a = jax.random.normal(ks[1], shape).astype(dtype)
+        kn = jax.random.normal(ks[2], (4, n, 128))
+        vn = jax.random.normal(ks[3], (4, n, 128))
+        # Lanes 1 and 3 are padded: both point at the dummy row (the last).
+        rows = jnp.asarray([2, 5, 0, 5], jnp.int32)
+        return k_a, v_a, kn, vn, rows
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("layer", [0, 1])
+    def test_kernel_writes_the_rows_and_nothing_else(self, layer, dtype):
+        from client_tpu.ops.arena_write import (kernel_writes,
+                                                write_prompt_rows)
+
+        k_a, v_a, kn, vn, rows = self._case(dtype=dtype)
+        assert kernel_writes(16, dtype) and not kernel_writes(4, dtype)
+        assert kernel_writes(8, jnp.float32)
+        assert not kernel_writes(8, jnp.bfloat16)
+        fk, fv = write_prompt_rows(k_a, v_a, kn, vn, rows, layer=layer,
+                                   interpret=True)
+        for got, before, new in ((fk, k_a, kn), (fv, v_a, vn)):
+            got, before = np.asarray(got), np.asarray(before)
+            for b in (0, 2):                       # live lanes
+                np.testing.assert_array_equal(
+                    got[layer, int(rows[b]), :16],
+                    np.asarray(new[b].astype(dtype)))
+            touched = np.zeros(before.shape[:3], bool)
+            touched[layer, np.asarray(rows), :16] = True
+            np.testing.assert_array_equal(got[~touched], before[~touched])
+            # The dummy row took a padded lane's slab (either's).
+            junk = got[layer, 5, :16]
+            assert any(np.array_equal(junk, np.asarray(new[b].astype(dtype)))
+                       for b in (1, 3))
+
+    def test_a_lane_can_be_left_out(self):
+        from client_tpu.ops.arena_write import write_prompt_rows
+
+        k_a, v_a, kn, vn, rows = self._case()
+        fk, _ = write_prompt_rows(k_a, v_a, kn, vn, rows,
+                                  jnp.asarray([1, 0, 0, 0]), layer=1,
+                                  interpret=True)
+        np.testing.assert_array_equal(np.asarray(fk[1, 2, :16]),
+                                      np.asarray(kn[0]))
+        for row in (0, 5):
+            np.testing.assert_array_equal(np.asarray(fk[1, row]),
+                                          np.asarray(k_a[1, row]))
+
+    @pytest.mark.parametrize("n", [4, 16, 32])
+    def test_reference_is_the_same_write(self, n):
+        from client_tpu.ops.arena_write import (reference_write_prompt_rows,
+                                                write_prompt_rows)
+
+        k_a, v_a, kn, vn, _ = self._case(n=n)
+        rows = jnp.asarray([2, 4, 0, 5], jnp.int32)        # distinct rows
+        rk, rv = reference_write_prompt_rows(k_a, v_a, kn, vn, rows, layer=1)
+        np.testing.assert_array_equal(np.asarray(rk[1, 4, :n]),
+                                      np.asarray(kn[1]))
+        np.testing.assert_array_equal(np.asarray(rk[0]), np.asarray(k_a[0]))
+        if n >= 8:
+            fk, fv = write_prompt_rows(k_a, v_a, kn, vn, rows, layer=1,
+                                       interpret=True)
+            np.testing.assert_array_equal(np.asarray(fk), np.asarray(rk))
+            np.testing.assert_array_equal(np.asarray(fv), np.asarray(rv))
+
+    def test_slabs_must_fit_the_rows(self):
+        from client_tpu.ops.arena_write import write_prompt_rows
+
+        k_a, v_a, kn, vn, rows = self._case()
+        with pytest.raises(ValueError, match="fit"):
+            write_prompt_rows(k_a, v_a, kn[..., :64], vn[..., :64], rows,
+                              layer=0, interpret=True)
+
+    @pytest.mark.parametrize("shards", [2, 4])
+    def test_row_sharded_arena_takes_each_lane_on_its_shard(self, shards):
+        from client_tpu.ops.arena_write import reference_write_prompt_rows
+        from client_tpu.parallel.kv_shard import (
+            arena_row_layout,
+            kv_mesh,
+            shard_arena,
+            sharded_write_prompt_rows,
+        )
+
+        mesh = kv_mesh(shards)
+        total, free, dummy = arena_row_layout(8, shards)
+        ks = jax.random.split(jax.random.PRNGKey(2), 4)
+        shape = (2, total, 16, 128)
+        k_a, v_a = (jax.random.normal(k, shape) for k in ks[:2])
+        kn, vn = (jax.random.normal(k, (4, 8, 128)) for k in ks[2:])
+        rows = jnp.asarray([free[0], free[-1], dummy, free[3]], jnp.int32)
+        arena = shard_arena({"k": k_a, "v": v_a,
+                             "tok": jnp.zeros(total, jnp.int32)}, mesh)
+        fk, fv = jax.jit(lambda k, v: sharded_write_prompt_rows(
+            mesh, k, v, kn, vn, rows, layer=1, interpret=True))(
+                arena["k"], arena["v"])
+        rk, rv = reference_write_prompt_rows(k_a, v_a, kn, vn, rows, layer=1)
+        np.testing.assert_array_equal(np.asarray(fk), np.asarray(rk))
+        np.testing.assert_array_equal(np.asarray(fv), np.asarray(rv))
+
+
 class TestDecodeKernelGpt2Geometry:
     """The arena access at GPT-2's head geometry (12 heads x 64 on a
     768-lane row): the kernel against the XLA oracle where the block does

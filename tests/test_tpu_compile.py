@@ -1,4 +1,5 @@
-"""Compile the decode step for the chip, without the chip.
+"""Compile the decode step and the prefill program for the chip, without
+the chip.
 
 The TPU's compiler is installed beside JAX and compiles for a device that is
 described, not attached (``jax.experimental.topologies``).  Nothing runs, so
@@ -8,6 +9,11 @@ decode wave take 900 ms where 4 ms of memory traffic were needed (PERF.md
 section 6, PR 25: the compiler stored the ``[.., 12, 64]`` leaves with the
 sequence axis minor and re-laid out a whole leaf around every scatter and
 gather, 10.6 GB of temporaries that ``memory_stats`` never showed).
+
+``jit_prefill`` had the same fault in another place (PERF.md section 6, PR
+29): q, k and v forced through ``[.., 12, 64]`` shapes around the flash
+kernel, every layer's K and V stacked and scattered at the end, 41% of a 21.9
+ms prefill spent moving bytes.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU's library, and pytest-xdist's
@@ -47,32 +53,61 @@ GEOMETRY = dict(n_layers=2, d_model=768, n_heads=12, d_ff=3072, vocab=1024,
                 max_seq_len=1024, max_streams=48)
 
 
-def _compile_decode(one_chip, monkeypatch, bucket, **overrides):
-    """The scheduler's ``jit_decode`` (arena donated, greedy) compiled for
-    one v5e chip from shapes alone.  Returns (compiled, arena shapes)."""
+def _backend_shapes(monkeypatch, place, **overrides):
+    """A ``TinyGptBackend`` that takes the chip's branches, with the shapes
+    of its parameters and arena placed by ``place(shape, dtype, leaf)``."""
     from client_tpu.engine import backend_init
     from client_tpu.models.generate import TinyGptBackend
-    from client_tpu.observability import spans
 
     # The process sees the CPU; the program under test is the chip's.
     monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
     backend = TinyGptBackend(name="g", **{**GEOMETRY, **overrides})
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, a.dtype, False), jax.eval_shape(
+            lambda: jax.tree_util.tree_map(jnp.asarray,
+                                           backend._init_params())))
+    total = len(backend.arena_rows()[0]) + backend.kv_shards
+    leaf = (backend.n_layers, total, backend.max_seq_len, backend.d_model)
+    arena = {"k": place(leaf, jnp.float32, True),
+             "v": place(leaf, jnp.float32, True),
+             "tok": place((total,), jnp.int32, False)}
+    return backend, params, arena
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
 
-    params = on_chip(jax.eval_shape(
-        lambda: jax.tree_util.tree_map(jnp.asarray, backend._init_params())))
-    arena = on_chip(jax.eval_shape(
-        lambda: backend.init_arena(backend.max_streams)))
-    lanes_i = jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip)
-    lanes_f = jax.ShapeDtypeStruct((bucket,), jnp.float32, sharding=one_chip)
+def _on(sharding):
+    return lambda shape, dtype, _leaf=False: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
+
+
+def _compile_decode(one_chip, monkeypatch, bucket, **overrides):
+    """The scheduler's ``jit_decode`` (arena donated, greedy) compiled for
+    one v5e chip from shapes alone.  Returns (compiled, arena shapes)."""
+    from client_tpu.observability import spans
+
+    place = _on(one_chip)
+    backend, params, arena = _backend_shapes(monkeypatch, place, **overrides)
+    lanes_i, lanes_f = place((bucket,), jnp.int32), place((bucket,),
+                                                          jnp.float32)
     step = jax.jit(spans.named_step(backend.decode_fn(), spans.STEP_DECODE),
                    donate_argnums=(1,), static_argnums=(8,))
     compiled = step.lower(params, arena, lanes_i, lanes_i, lanes_i, lanes_f,
                           lanes_i, lanes_f, False).compile()
+    return compiled, arena
+
+
+def _compile_prefill(place, monkeypatch, n, lanes=8, **overrides):
+    """The scheduler's ``jit_prefill`` (arena donated, greedy) for ``lanes``
+    prompts of ``n`` positions.  Returns (compiled, arena shapes)."""
+    from client_tpu.observability import spans
+
+    backend, params, arena = _backend_shapes(monkeypatch, place, **overrides)
+    lanes_i, lanes_f = place((lanes,), jnp.int32), place((lanes,),
+                                                         jnp.float32)
+    step = jax.jit(spans.named_step(backend.prefill_fn(), spans.STEP_PREFILL),
+                   donate_argnums=(1,), static_argnums=(9,))
+    compiled = step.lower(params, arena, lanes_i, place((lanes, n), jnp.int32),
+                          lanes_i, lanes_i, lanes_f, lanes_i, lanes_f,
+                          False).compile()
     return compiled, arena
 
 
@@ -82,13 +117,19 @@ def _leaf_bytes(arena):
 
 
 def _entry_ops_shaped_like(text, shape):
-    """Instructions of the ENTRY computation whose result has ``shape``,
-    as (name, opcode) pairs."""
+    """Instructions of the ENTRY computation whose result has ``shape`` (or
+    is a tuple that starts with it), as (name, opcode) pairs."""
     entry = text[text.index("ENTRY"):]
     dims = ",".join(str(d) for d in shape)
     pat = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \(?f32\[" + dims
-                     + r"\]\S* ([\w\-]+)\(", re.M)
+                     + r"\][^=]*? ([\w\-]+)\(", re.M)
     return pat.findall(entry)
+
+
+# What may carry an arena leaf's shape: the program's arguments and results,
+# and Mosaic's calls, which update the leaf they are given in place.
+_PASSES_A_LEAF_ON = ("parameter", "get-tuple-element", "custom-call",
+                     "bitcast", "tuple")
 
 
 @pytest.mark.parametrize("bucket", [1, 48])
@@ -107,9 +148,7 @@ def test_decode_step_updates_the_donated_arena_in_place(
     text = compiled.as_text()
     assert "tpu_custom_call" in text          # Mosaic's kernel, compiled
     moved = [(name, op) for name, op in _entry_ops_shaped_like(
-        text, arena["k"].shape)
-        if op not in ("parameter", "get-tuple-element", "custom-call",
-                      "bitcast", "tuple")]
+        text, arena["k"].shape) if op not in _PASSES_A_LEAF_ON]
     assert not moved, f"arena-shaped work in jit_decode: {moved}"
 
 
@@ -172,3 +211,81 @@ def test_row_sharded_arena_compiles_for_four_chips(topo, combine):
         per_chip_leaf = layers * (total // 4) * seq * h * d * 4
         assert memory.alias_size_in_bytes >= 2 * per_chip_leaf
         assert memory.temp_size_in_bytes < per_chip_leaf // 4, memory
+
+
+def test_prefill_moves_each_key_value_and_query_once(one_chip, monkeypatch):
+    """``jit_prefill`` at GPT-2's geometry, 8 lanes x 1024 positions x 12
+    layers: each layer's K and V go from the projection into the donated
+    arena's rows (one DMA a lane and leaf), and the flash kernel reads q, k
+    and v as ``[8, 1024, 768]``, the layout the projections write."""
+    compiled, arena = _compile_prefill(
+        _on(one_chip), monkeypatch, 1024, n_layers=12,
+        attention_impl="flash")
+    text = compiled.as_text()
+    layers = arena["k"].shape[0]
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 2 * layers     # a write and a flash each
+    # Nothing has an arena leaf's shape but what passes a leaf on, and
+    # nothing the shape of every layer's K or V side by side.
+    moved = [(name, op) for name, op in _entry_ops_shaped_like(
+        text, arena["k"].shape) if op not in _PASSES_A_LEAF_ON]
+    assert not moved, f"arena-shaped work in jit_prefill: {moved}"
+    assert not re.search(r"f32\[(8,12|12,8|1,12),1024,768\]", text)
+    # No relayout around the kernel: nothing at all is shaped by heads.
+    assert not re.search(r"\[[\d,]*1024,12,64\]|\[[\d,]*12,1024,64\]", text)
+    memory = compiled.memory_analysis()
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    assert memory.alias_size_in_bytes >= 2 * _leaf_bytes(arena)
+    # A layer's q, k, v and attention output are 25 MB each, its
+    # feed-forward activation 100 MB; one layer's worth is alive at a time
+    # (the staged formulation kept 24 slabs and their transposed copy).
+    slab = 8 * 1024 * 768 * 4
+    assert memory.temp_size_in_bytes < 10 * slab, memory
+
+
+def test_prefill_of_a_short_bucket_and_of_tiny_gpt_compile(one_chip,
+                                                           monkeypatch):
+    """A prompt bucket under a row group takes XLA's scatter, in place; the
+    zoo's default decoder (einsum attention, 4 heads x 64) takes the DMA."""
+    compiled, arena = _compile_prefill(_on(one_chip), monkeypatch, 4)
+    assert "tpu_custom_call" not in compiled.as_text()
+    memory = compiled.memory_analysis()
+    if memory is not None:
+        assert memory.alias_size_in_bytes >= 2 * _leaf_bytes(arena)
+        assert memory.temp_size_in_bytes < _leaf_bytes(arena) // 4, memory
+    compiled, arena = _compile_prefill(
+        _on(one_chip), monkeypatch, 128, n_layers=2, d_model=256, n_heads=4,
+        d_ff=1024, vocab=512, max_seq_len=128, max_streams=64)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_prefill_compiles_over_the_row_sharded_arena(topo, monkeypatch):
+    """``kv_shards=4`` on the 2x2 host: each shard's rows take their lanes'
+    slabs in place, under ``shard_map``."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from client_tpu.models.generate import TinyGptBackend
+
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("kv",))
+    monkeypatch.setattr(TinyGptBackend, "_mesh", lambda self: mesh)
+    rows_sh, rep = NamedSharding(mesh, P(None, "kv")), NamedSharding(mesh,
+                                                                    P())
+
+    def place(shape, dtype, leaf=False):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=rows_sh if leaf else rep)
+
+    # (Einsum attention, as every served sharded arena has: a Mosaic call
+    # outside ``shard_map`` has no partitioning rule; 128 positions keep
+    # its scores small beside the slabs.)
+    compiled, arena = _compile_prefill(place, monkeypatch, 128, kv_shards=4)
+    assert arena["k"].shape[1] == 48 + 4
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    if memory is not None:
+        per_chip_leaf = _leaf_bytes(arena) // 4
+        assert memory.alias_size_in_bytes >= 2 * per_chip_leaf
+        assert memory.temp_size_in_bytes < per_chip_leaf // 2, memory
