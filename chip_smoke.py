@@ -28,9 +28,10 @@ Phases (run in this order; any failure → non-zero exit, no result line):
                cache gains nothing the second time; cold/warm compile seconds
   A  server    the six-model server; every request over the wire; steady
                state compiles nothing; SIGTERM drains to exit 0
-  B  oracle    ``CLIENT_TPU_ATTN_IMPL=reference`` tiny_gpt (the XLA scatter/
-               gather step): tokens equal phase A's, which the served step
-               produced (on a TPU, unset: the Pallas decode-wave kernel)
+  B  oracle    ``tiny_gpt_oracle``: tiny_gpt built with ``attn_impl=
+               "reference"`` (the XLA scatter/gather step): tokens equal
+               phase A's, which the served step produced (on a TPU, unset:
+               the Pallas decode-wave kernel)
   E  four_chip ``bert_base_mc`` on >= 4 devices, else "not run (N device)"
 
 The last stdout line of a passing run is one JSON object:
@@ -820,11 +821,10 @@ def phase_oracle(ctx: dict) -> None:
     mode: Mode = ctx["mode"]
     if "gen_tokens" not in ctx:
         raise SmokeFailure("not run: needs phase A's tokens")
-    srv = Server(mode, "phaseB_oracle", ["tiny_gpt"],
-                 CLIENT_TPU_ATTN_IMPL="reference")
+    srv = Server(mode, "phaseB_oracle", ["tiny_gpt_oracle"])
     try:
         srv.wait_ready()
-        got = check_streams(grpc, srv.urls, "tiny_gpt")["batched"]
+        got = check_streams(grpc, srv.urls, "tiny_gpt_oracle")["batched"]
         for i, (oracle, served) in enumerate(zip(got, ctx["gen_tokens"])):
             if oracle != served:
                 raise SmokeFailure(

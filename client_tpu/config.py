@@ -128,14 +128,6 @@ def env_flag(name: str, environ=None) -> bool:
 
 # -- engine ------------------------------------------------------------------
 register(
-    "CLIENT_TPU_ATTN_IMPL", "", "str",
-    "Generative decode step: `fused` (Pallas decode-wave kernel: the arena "
-    "written in place, each live row read once) or `reference` (the XLA "
-    "scatter/gather oracle the parity checks serve); streams are "
-    "token-identical either way. Unset, a TPU serves `fused` and a "
-    "platform that would only interpret Pallas serves `reference`.",
-    "engine")
-register(
     "CLIENT_TPU_AUTOTUNE", "", "json",
     "Bucket-ladder autotuner: unset/`0`/`off` disables (no thread, no "
     "arena); `1`/`on` takes defaults; else inline JSON or `@/path.json`.",
@@ -152,11 +144,6 @@ register(
     "CLIENT_TPU_GEN_CHUNK", "1", "int",
     "Decode chunk K: one device dispatch advances every stream K tokens "
     "(divides per-wave host overhead by K; adds ≤K−1 waves of TTFT).",
-    "engine")
-register(
-    "CLIENT_TPU_GEN_PIPELINE", "32", "int",
-    "Generative dispatch-ahead depth in waves before the worker blocks "
-    "on the oldest fetch.",
     "engine")
 register(
     "CLIENT_TPU_SEQ_PIPELINE", "2", "int",

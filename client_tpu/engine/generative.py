@@ -28,9 +28,9 @@ generation streams. Design, TPU-first:
   the jitted transition queued before its next wave (span
   ``gen.transition_dispatch``).  The worker knows every stream's
   dispatch-side length, so the order needs no fetch and no host sync.
-  The scheduler learns all of this from the hooks, never from a model's name;
-  a backend without them takes the paths above with the programs it always
-  compiled.
+  The scheduler learns all of this from the contract a served decoder
+  declares (``models/decoder.py`` ``DecoderBackend``: every member has a
+  default there), never from a model's name.
 - **Decode waves** (one jit per stream-count bucket) advance every live
   stream one token in a single XLA execution: gather input tokens from the
   device-side slots, scatter new K/V at each stream's position, masked
@@ -39,8 +39,8 @@ generation streams. Design, TPU-first:
 - **Pipelined dispatch** (round-4): the worker dispatches prefills and
   waves WITHOUT waiting for their results — JAX async dispatch queues them
   on the device in order — and consumes the token fetches asynchronously
-  (``copy_to_host_async`` + ``is_ready``), bounded by a configurable
-  pipeline depth (``CLIENT_TPU_GEN_PIPELINE``, default 32). Emission,
+  (``copy_to_host_async`` + ``is_ready``), bounded by a pipeline depth
+  (``_PIPELINE_DEPTH``). Emission,
   stop-token checks, and retirement happen at fetch time, a few waves
   behind dispatch; over-generated tokens past a stop are discarded (the
   lanes are independent, so junk in a retired lane cannot perturb live
@@ -93,6 +93,15 @@ from client_tpu.observability.costs import ledger
 from client_tpu.observability.profiler import profiler
 
 _log = logging.getLogger("client_tpu")
+
+# Dispatch-ahead bound, in waves in flight before the worker blocks on the
+# oldest fetch (each entry holds a bucket-sized token vector).  What is known:
+# it bounds the junk dispatched behind a cancellation or a stop token; on the
+# chip the runtime's own launch queue fills first and is what bounds the
+# pipeline (PERF.md section 6), so nothing between that bound and 32 changes a
+# token gap.  Whether 32 is the right value is ROADMAP Queue A item 5's
+# question.
+_PIPELINE_DEPTH = 32
 
 
 class _Stream:
@@ -250,35 +259,30 @@ class GenerativeScheduler(Scheduler):
         backend = model.backend
         self._cap = int(backend.max_streams)
         self._max_seq = int(backend.max_seq_len)
-        # Row layout comes from the backend when it can say (sharded KV
-        # arenas carry one junk row per shard, so free rows are not
-        # 0..cap-1 and the dummy row is not `cap` — see
-        # parallel/kv_shard.py); the legacy +1-dummy layout is the
-        # fallback for backends without the hook.
-        rows_of = getattr(backend, "arena_rows", None)
-        if callable(rows_of):
-            free_rows, dummy = rows_of(self._cap)
-            self._rows_init = [int(r) for r in free_rows]
-            self._dummy = int(dummy)
-        else:
-            self._rows_init = list(range(self._cap))
-            self._dummy = self._cap
+        # Sharded KV arenas carry one junk row per shard, so free rows are
+        # not 0..cap-1 and the dummy row is not `cap` (parallel/kv_shard.py).
+        free_rows, dummy = backend.arena_rows(self._cap)
+        self._rows_init = [int(r) for r in free_rows]
+        self._dummy = int(dummy)
         self._arena = backend.init_arena(self._cap)
         from client_tpu.observability.memory import hbm_census
 
         hbm_census().register_provider(
             model.config.name, "kv_arena", self, _census_arena)
         # `sample` is static: all-greedy calls get an executable with no
-        # sampling pipeline in it (prefill arg 9, decode arg 8).
-        # The XLA modules are named here (jit_prefill, jit_decode,
-        # jit_decode_chunk), not by what a backend calls its functions:
-        # the device-trace reduction finds the steps by these names.
+        # sampling pipeline in it; the backend says where it stands in each
+        # program's arguments.  The XLA modules are named here (jit_prefill,
+        # jit_decode, jit_decode_chunk), not by what a backend calls its
+        # functions: the device-trace reduction finds the steps by these
+        # names.
         self._prefill = jax.jit(
             _sp.named_step(backend.prefill_fn(), _sp.STEP_PREFILL),
-            donate_argnums=(1,), static_argnums=(9,))
+            donate_argnums=backend.donate_argnums,
+            static_argnums=backend.prefill_static_argnums)
         self._decode = jax.jit(
             _sp.named_step(backend.decode_fn(), _sp.STEP_DECODE),
-            donate_argnums=(1,), static_argnums=(8,))
+            donate_argnums=backend.donate_argnums,
+            static_argnums=backend.decode_static_argnums)
         # Chunked decode (CLIENT_TPU_GEN_CHUNK > 1): K waves fused into one
         # scanned execution — one dispatch advances every stream K tokens,
         # dividing per-wave Python + transport-command overhead by K.
@@ -287,19 +291,18 @@ class GenerativeScheduler(Scheduler):
         # like any retired lane.  Admits join at chunk boundaries (<= K-1
         # waves of extra TTFT, ~K*step_ms).
         self._chunk = max(1, envcfg.env_int("CLIENT_TPU_GEN_CHUNK"))
-        # What a backend may declare about a cache that is not one slot per
-        # position (module docstring): prefill by pieces, the rows a step
-        # reads, and a transition ordered between two waves.
-        piece = getattr(backend, "prefill_piece", None)
-        self._piece_len, self._piece_lanes = (
-            (int(piece[0]), int(piece[1])) if piece else (0, 0))
-        self._cache_rows = getattr(backend, "cache_rows", None)
-        self._transition_due = getattr(backend, "transition_due", None)
+        # What a backend declares about a cache that is not one slot per
+        # position (module docstring), each None where it is: prefill by
+        # pieces, the rows a step reads, and a transition ordered between
+        # two waves.
+        self._piece_len, self._piece_lanes = backend.prefill_piece or (0, 0)
+        self._cache_rows = backend.cache_rows
+        self._transition_due = backend.transition_due
         self._transition = None
-        if callable(self._transition_due):
+        if self._transition_due is not None:
             self._transition = jax.jit(
                 _sp.named_step(backend.transition_fn(), _sp.STEP_TRANSITION),
-                donate_argnums=(1,))
+                donate_argnums=backend.donate_argnums)
             # A transition may fall between any two steps of a stream, so
             # waves are dispatched one at a time.
             self._chunk = 1
@@ -308,7 +311,8 @@ class GenerativeScheduler(Scheduler):
             self._decode_chunk = jax.jit(
                 _sp.named_step(backend.decode_chunk_fn(),
                                _sp.STEP_DECODE_CHUNK),
-                donate_argnums=(1,), static_argnums=(8, 9))
+                donate_argnums=backend.donate_argnums,
+                static_argnums=backend.decode_chunk_static_argnums)
         self._prompt_buckets = ([self._piece_len] if self._piece_len
                                 else power_buckets(self._max_seq))
         self._wave_buckets = power_buckets(self._cap)
@@ -318,17 +322,12 @@ class GenerativeScheduler(Scheduler):
         # pair — a lane size first seen under load stalled every stream
         # ~1s mid-measurement).
         self._admit_lane = self._piece_lanes or min(self._cap, 8)
-        # Dispatch-ahead bound: waves in flight before the worker blocks on
-        # the oldest fetch. The default (32) was sized to hide a ~70 ms
-        # round trip over a ~2 ms device step — a transport that is gone;
-        # re-decide on the chip (ROADMAP A). Each entry holds only a
-        # bucket-sized token vector.
-        self._depth = max(1, envcfg.env_int("CLIENT_TPU_GEN_PIPELINE"))
+        self._depth = _PIPELINE_DEPTH   # a test sets a shallower one
         self._streams: list[_Stream] = []
         self._inflight: collections.deque[_Inflight] = collections.deque()
         # Depth accounting is in WAVES, not dispatches: a K-chunk counts K,
-        # so CLIENT_TPU_GEN_PIPELINE bounds the same amount of dispatched-
-        # ahead device work (and cancellation junk) in either mode.
+        # so the depth bounds the same amount of dispatched-ahead device
+        # work (and cancellation junk) in either mode.
         self._inflight_waves = 0
         self._free = list(self._rows_init)
         # Fetch-side low-water mark for wave timing: the device is busy
@@ -353,7 +352,7 @@ class GenerativeScheduler(Scheduler):
         """KV arena shard count (1 = single-chip): the autotuner divides
         the arena reservation by this so the planning arena charges the
         PER-DEVICE share, not the global pytree bytes."""
-        return int(getattr(self.model.backend, "kv_shards", 1) or 1)
+        return int(self.model.backend.kv_shards)
 
     def arena_nbytes(self) -> int:
         """Total bytes of the KV arena pytree — the engine's HBM planner
@@ -390,41 +389,37 @@ class GenerativeScheduler(Scheduler):
 
     def _precompile(self) -> None:
         lane = self._admit_lane
-        dummy = np.full(lane, self._dummy, np.int32)  # all lanes padded
-        z_i = np.zeros(lane, np.int32)
-        z_f = np.zeros(lane, np.float32)
-        ones_f = np.ones(lane, np.float32)
+        rows, *sampling = self._stage_lanes([], lane)   # all lanes padded
         for pb in self._prompt_buckets:
             self.model._set_state(f"warmup: prefill prompt bucket={pb}",
                                   _sp.STEP_PREFILL, pb)
             self._arena, tokens = self._prefill(
-                self.model._params, self._arena, dummy,
+                self.model._params, self._arena, rows,
                 np.zeros((lane, pb), np.int32), np.ones(lane, np.int32),
-                z_i, z_f, z_i, ones_f, False,
-                *((z_i,) if self._piece_len else ()))
+                *sampling, False,
+                # A piece's `starts`: the argument only prefill by pieces has.
+                *((np.zeros(lane, np.int32),) if self._piece_len else ()))
         if self._transition is not None:
             self.model._set_state("warmup: cache transition",
                                   _sp.STEP_TRANSITION, 1)
             self._arena = self._transition(
-                self.model._params, self._arena, dummy[:1], z_i[:1])
+                self.model._params, self._arena, rows[:1],
+                np.zeros(1, np.int32))
         for wb in self._wave_buckets:
             self.model._set_state(f"warmup: decode wave bucket={wb}",
                                   _sp.STEP_DECODE, wb)
-            rows = np.full(wb, self._dummy, np.int32)
+            rows, *sampling = self._stage_lanes([], wb)
+            lens = np.zeros(wb, np.int32)
             self._arena, tokens = self._decode(
-                self.model._params, self._arena, rows,
-                np.zeros(wb, np.int32), np.zeros(wb, np.int32),
-                np.zeros(wb, np.float32), np.zeros(wb, np.int32),
-                np.ones(wb, np.float32), False)
+                self.model._params, self._arena, rows, lens, *sampling,
+                False)
             if self._decode_chunk is not None:
                 self.model._set_state(
                     f"warmup: chunked decode bucket={wb} k={self._chunk}",
                     _sp.STEP_DECODE_CHUNK, wb)
                 self._arena, tokens = self._decode_chunk(
-                    self.model._params, self._arena, rows,
-                    np.zeros(wb, np.int32), np.zeros(wb, np.int32),
-                    np.zeros(wb, np.float32), np.zeros(wb, np.int32),
-                    np.ones(wb, np.float32), False, self._chunk)
+                    self.model._params, self._arena, rows, lens, *sampling,
+                    False, self._chunk)
                 tokens = tokens[-1]
         self._jax.block_until_ready(tokens)
         self.model._clear_state()
@@ -670,53 +665,54 @@ class GenerativeScheduler(Scheduler):
                 self._reset_arena(exc, failing=chunk[0][0])
                 return
 
+    def _stage_lanes(self, lanes: list, width: int):
+        """(rows, seeds, temps, top_ks, top_ps), each ``[width]``, as every
+        program takes them: the lanes' streams first, the rest padded onto
+        the dummy row as greedy lanes."""
+        pad = width - len(lanes)
+
+        def column(values, fill, dtype):
+            return np.asarray(values + [fill] * pad, dtype)
+
+        return (column([s.row for s in lanes], self._dummy, np.int32),
+                column([s.seed & 0xFFFFFFFF for s in lanes], 0,
+                       np.uint32).astype(np.int32),
+                column([s.temp for s in lanes], 0.0, np.float32),
+                column([s.top_k for s in lanes], 0, np.int32),
+                column([s.top_p for s in lanes], 1.0, np.float32))
+
     def _prefill_chunk(self, prompt_bucket: int, chunk: list) -> None:
         """One batched prefill dispatch: B admits -> ONE device execution,
         no host sync (the first tokens arrive through the fetch queue)."""
         n = len(chunk)
         lane = self._admit_lane
-        pad = lane - n
-        rows = [self._free.pop() for _ in range(n)]
+        streams = [
+            _Stream(req, self._free.pop(), len(ids), max_new, seed=seed,
+                    temp=temp, top_k=top_k, top_p=top_p, stop=stop)
+            for req, ids, max_new, (seed, temp, top_k, top_p, stop) in chunk]
         try:
             ids_mat = np.zeros((lane, prompt_bucket), np.int32)
             lens = np.ones(lane, np.int32)
-            seeds = np.zeros(lane, np.uint32)
-            temps = np.zeros(lane, np.float32)
-            top_ks = np.zeros(lane, np.int32)
-            top_ps = np.ones(lane, np.float32)
-            for i, (req, ids, max_new, (seed, temp, top_k, top_p,
-                                        stop)) in enumerate(chunk):
+            for i, (_req, ids, *_) in enumerate(chunk):
                 ids_mat[i, :len(ids)] = ids
                 lens[i] = len(ids)
-                seeds[i] = seed & 0xFFFFFFFF
-                temps[i] = temp
-                top_ks[i] = top_k
-                top_ps[i] = top_p
-            seeds = seeds.astype(np.int32)
-            rows_arr = np.asarray(
-                rows + [self._dummy] * pad, np.int32)  # dummy row pads
+            rows, seeds, temps, top_ks, top_ps = self._stage_lanes(
+                streams, lane)
             self.model._set_state(
                 f"generative prefill ({n} streams, prompt "
                 f"bucket={prompt_bucket})", _sp.STEP_PREFILL, prompt_bucket)
             try:
                 self._arena, tokens = self._prefill(
-                    self.model._params, self._arena, rows_arr, ids_mat,
+                    self.model._params, self._arena, rows, ids_mat,
                     lens, seeds, temps, top_ks, top_ps,
                     bool((temps > 0.0).any()))
                 tokens.copy_to_host_async()
             finally:
                 self.model._clear_state()
         except Exception:
-            self._free.extend(rows)
+            self._free.extend(s.row for s in streams)
             raise
-        streams = []
-        for i, (req, ids, max_new, (seed, temp, top_k, top_p,
-                                    stop)) in enumerate(chunk):
-            stream = _Stream(req, rows[i], len(ids), max_new,
-                             seed=seed, temp=temp, top_k=top_k, top_p=top_p,
-                             stop=stop)
-            streams.append(stream)
-            self._streams.append(stream)
+        self._streams.extend(streams)
         # Executions are counted at dispatch (round-3 semantics): fetch-time
         # counting would drop waves whose lanes all retired before the
         # fetch, and everything discarded by an arena reset.
@@ -736,22 +732,14 @@ class GenerativeScheduler(Scheduler):
         todo = [s for s in self._streams if s.ids is not None][:lane]
         if not todo:
             return False
-        pad = lane - len(todo)
         ids_mat = np.zeros((lane, width), np.int32)
         lens = np.ones(lane, np.int32)
         starts = np.zeros(lane, np.int32)
-        seeds = np.zeros(lane, np.uint32)
-        temps = np.zeros(lane, np.float32)
-        top_ks = np.zeros(lane, np.int32)
-        top_ps = np.ones(lane, np.float32)
         for i, s in enumerate(todo):
             part = s.ids[s.consumed:s.consumed + width]
             ids_mat[i, :len(part)] = part
             lens[i], starts[i] = len(part), s.consumed
-            seeds[i] = s.seed & 0xFFFFFFFF
-            temps[i], top_ks[i], top_ps[i] = s.temp, s.top_k, s.top_p
-        rows = np.asarray([s.row for s in todo] + [self._dummy] * pad,
-                          np.int32)
+        rows, seeds, temps, top_ks, top_ps = self._stage_lanes(todo, lane)
         self.model._set_state(
             f"generative prefill piece ({len(todo)} streams, from "
             f"{[int(x) for x in starts[:len(todo)]]})",
@@ -760,7 +748,7 @@ class GenerativeScheduler(Scheduler):
             with self._rec.span[_sp.S_PREFILL_DISPATCH]:
                 self._arena, tokens = self._prefill(
                     self.model._params, self._arena, rows, ids_mat, lens,
-                    seeds.astype(np.int32), temps, top_ks, top_ps,
+                    seeds, temps, top_ks, top_ps,
                     bool((temps > 0.0).any()), starts)
             tokens.copy_to_host_async()
         finally:
@@ -821,16 +809,9 @@ class GenerativeScheduler(Scheduler):
     def _stage_and_dispatch(self, live: list) -> None:
         rec = self._rec
         bucket = next(b for b in self._wave_buckets if b >= len(live))
-        pad = bucket - len(live)
-        rows = np.asarray([s.row for s in live] + [self._dummy] * pad,
-                          np.int32)
-        lens = np.asarray([s.disp_len for s in live] + [0] * pad, np.int32)
-        seeds = np.asarray([s.seed & 0xFFFFFFFF for s in live] + [0] * pad,
-                           np.uint32).astype(np.int32)
-        temps = np.asarray([s.temp for s in live] + [0.0] * pad, np.float32)
-        top_ks = np.asarray([s.top_k for s in live] + [0] * pad, np.int32)
-        top_ps = np.asarray([s.top_p for s in live] + [1.0] * pad,
-                            np.float32)
+        rows, seeds, temps, top_ks, top_ps = self._stage_lanes(live, bucket)
+        lens = np.asarray([s.disp_len for s in live]
+                          + [0] * (bucket - len(live)), np.int32)
         # Chunk only when every live lane has K steps of sequence headroom:
         # a scanned step past max_seq would CLIP its k/v scatter onto the
         # last position (jax .at[] semantics) and corrupt it.  Budget

@@ -66,6 +66,7 @@ def _import_all() -> None:
         bert,
         dlrm,
         ensembles,
+        evabyte,
         generate,
         simple,
         ssd,

@@ -1,0 +1,381 @@
+"""The served decoder: what the generative scheduler asks of a model, and the
+one decode step every such model runs.
+
+``engine/generative.py`` knows no model.  It builds its programs from a
+:class:`DecoderBackend` and reads the contract below, every member of which has
+a documented default here; a model file supplies its parts once and the rest
+(the kernel-or-oracle choice, the decode-step frame, the chunked step, the
+sampling tail, the decoupled ``ModelConfig``) is written in this module.
+
+**The contract the scheduler reads**
+
+- ``max_streams``, ``max_seq_len``, ``vocab``, ``default_max_tokens``.
+- ``init_arena(capacity)``: the cache pytree ``{"k", "v": [L, R, S, H*D],
+  "tok": [R]}``, donated into every program.  What a slot's ``S`` rows hold is
+  the model's business.
+- ``arena_rows(capacity)`` -> (free rows, dummy row) and ``kv_shards`` (1).
+- ``prefill_piece``: ``None`` (a whole prompt a lane, one program a prompt
+  bucket) or ``(positions, lanes)`` (a prompt is consumed ``positions`` a
+  piece, one program).
+- ``cache_rows``: ``None`` (a step reads one row a position) or ``(n) ->
+  (summary rows, exact rows)`` a step at context length ``n`` reads.
+- ``transition_due`` / ``transition_fn``: ``None``, or ``(n) -> bool`` and the
+  builder of ``(params, arena, rows[T], lens[T]) -> arena``, ordered before the
+  wave of a stream that decoded its way to a due length.
+- The programs, by their positional arguments (``PREFILL_ARGS``,
+  ``DECODE_ARGS``, ``DECODE_CHUNK_ARGS``): ``prefill_fn()`` -> (arena,
+  tokens[B]) takes the trailing ``starts`` only with ``prefill_piece``;
+  ``decode_fn()`` -> (arena, tokens[B]); ``decode_chunk_fn()`` -> (arena,
+  tokens[k, B]).  ``sample`` (and the chunk's ``k``) are static, the arena is
+  donated: ``*_static_argnums`` and ``donate_argnums`` say so by position.
+
+**The parts a model supplies** (``B`` lanes of a wave; ``lp`` one layer's
+weights; ``li`` its index, a Python int or a traced scalar):
+``_embed(p, tokens, pos)`` -> x ``[B, d]``; ``_qkv(lp, x, pos)`` -> q, k, v
+``[B, H, D]``; ``_after_attention(lp, x, o)`` -> x; ``_logits(p, x)`` and,
+where they are not ``[B, vocab]``, ``_served(logits)`` picking those tokens
+are sampled from; ``_walk_layers(p, body, carry)`` folding ``body(carry, lp,
+li)`` over the layers (an unrolled loop, a ``lax.scan``); ``_live_rows(lens)``,
+the rows of each slot a step at context length ``lens`` may read (default:
+``lens``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+from client_tpu.engine.config import ModelConfig, TensorConfig
+from client_tpu.engine.model import ModelBackend
+
+PREFILL_ARGS = ("params", "arena", "rows", "ids", "lens", "seeds", "temps",
+                "top_ks", "top_ps", "sample", "starts")
+DECODE_ARGS = ("params", "arena", "rows", "lens", "seeds", "temps", "top_ks",
+               "top_ps", "sample")
+DECODE_CHUNK_ARGS = DECODE_ARGS + ("k",)
+
+
+def _sample_token(logits, seed, ctx_len, temp, top_k, top_p):
+    """Per-stream token choice, fully jit-traceable (vmap over streams).
+
+    - ``temp <= 0`` → greedy argmax (the default; bit-identical to the
+      pre-sampling engine).
+    - Otherwise: temperature-scaled logits, top-k rank cut (``top_k == 0``
+      keeps all), nucleus top-p cumulative cut (first token always kept),
+      then a categorical draw.
+
+    Determinism contract: the PRNG key is ``fold_in(PRNGKey(seed),
+    ctx_len)`` where ``ctx_len`` is the context length at sampling time —
+    a pure function of (request seed, position), NOT of batch composition,
+    so batched decode stays bit-identical to solo decode under sampling.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    greedy = jnp.argmax(logits).astype(jnp.int32)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), ctx_len)
+    scaled = logits / jnp.maximum(temp, 1e-6)
+    order = jnp.argsort(-scaled)
+    sl = scaled[order]
+    probs = jax.nn.softmax(sl)
+    cum = jnp.cumsum(probs)
+    idx = jnp.arange(sl.shape[0])
+    keep = ((cum - probs) < top_p) & jnp.where(top_k > 0, idx < top_k, True)
+    keep = keep.at[0].set(True)
+    choice = jax.random.categorical(key, jnp.where(keep, sl, -jnp.inf))
+    sampled = order[choice].astype(jnp.int32)
+    return jnp.where(temp <= 0.0, greedy, sampled)
+
+
+def sample_into_slots(arena, rows, logits, seeds, ctx, temps, top_ks, top_ps,
+                      sample):
+    """The tail of every program that emits a token: (arena, tokens[B]) with
+    each lane's token chosen from ``logits[b]`` at context length ``ctx[b]``
+    and left in the slot's device-side token, where the next wave finds it
+    without the host.  ``sample`` is STATIC: an all-greedy program compiles
+    without the sort/cumsum/PRNG pipeline (``jnp.where`` alone would keep
+    both)."""
+    import jax
+    import jax.numpy as jnp
+
+    if sample:
+        tokens = jax.vmap(_sample_token)(logits, seeds, ctx, temps, top_ks,
+                                         top_ps)
+    else:
+        tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return {**arena, "tok": arena["tok"].at[rows].set(tokens)}, tokens
+
+
+class DecoderBackend(ModelBackend):
+    """A decoder served token by token: INPUT_IDS [-1] -> streamed (TOKEN,
+    INDEX) responses, ended by an empty ``triton_final_response`` like every
+    decoupled model here.  ``max_tokens`` bounds a request's generation."""
+
+    generative = True
+
+    prefill_piece: tuple[int, int] | None = None
+    cache_rows = None
+    transition_due = None
+    transition_fn = None
+    donate_argnums = (PREFILL_ARGS.index("arena"),)
+    prefill_static_argnums = (PREFILL_ARGS.index("sample"),)
+    decode_static_argnums = (DECODE_ARGS.index("sample"),)
+    decode_chunk_static_argnums = (DECODE_CHUNK_ARGS.index("sample"),
+                                   DECODE_CHUNK_ARGS.index("k"))
+
+    def __init__(self, name: str, *, vocab: int, max_seq_len: int,
+                 max_streams: int, attention_impl: str,
+                 attn_impl: str | None, kv_shards: int = 1):
+        # Prefill's attention.  "einsum": XLA-scheduled O(S^2) scores, right
+        # for short prompts; "flash": the Pallas kernel (causal).
+        if attention_impl not in ("einsum", "flash"):
+            # A silent fallback would serve the quadratic path at 2048+ —
+            # the cliff the option exists to avoid.
+            raise ValueError(
+                f"attention_impl must be 'einsum' or 'flash', got "
+                f"{attention_impl!r}")
+        self.attention_impl = attention_impl
+        # The decode wave.  "fused": the Pallas kernel (ops/decode_kernel.py),
+        # one row written in place and each live row read once.  "reference":
+        # the XLA oracle next to it (scatter, gather, dense masked softmax) on
+        # the same arena: same math, same sampling sequence, token-identical
+        # streams; the parity tests and chip_smoke's phase B serve it, and so
+        # do the GSPMD-sharded families (parallel/serving.py), whose programs
+        # XLA has to partition.  Unset, the platform decides (`_use_kernel`).
+        if attn_impl not in (None, "", "reference", "fused"):
+            raise ValueError(
+                f"attn_impl must be 'reference' or 'fused', got "
+                f"{attn_impl!r}")
+        self.attn_impl = attn_impl or ""
+        # KV arena shards over a "kv" mesh axis (parallel/kv_shard.py); the
+        # row-sharded layout and the shard_map'd kernel go together.
+        self.kv_shards = int(kv_shards)
+        if self.kv_shards < 1:
+            raise ValueError(f"kv_shards must be >= 1, got {kv_shards}")
+        if self.kv_shards > 1:
+            if self.attn_impl == "reference":
+                raise ValueError(
+                    "kv_shards > 1 requires attn_impl='fused' (the "
+                    "sharded arena is served by the shard_map'd kernel)")
+            self.attn_impl = "fused"
+            if max_streams % self.kv_shards:
+                raise ValueError(
+                    f"max_streams ({max_streams}) must be divisible by "
+                    f"kv_shards ({self.kv_shards})")
+        # Kernel knobs: key-block tile (None = auto divisor of a slot's
+        # rows) and the cross-shard combine ("ring" remote-DMA kernel |
+        # "psum" XLA collective).
+        self.decode_block_s: int | None = None
+        self.kv_combine = "ring"
+        self._kv_mesh = None
+        self.vocab, self.max_seq_len = int(vocab), int(max_seq_len)
+        self.max_streams = int(max_streams)
+        self.default_max_tokens = 16
+        self.config = ModelConfig(
+            name=name,
+            platform="jax",
+            max_batch_size=0,
+            input=[TensorConfig("INPUT_IDS", "INT32", [-1])],
+            output=[
+                TensorConfig("TOKEN", "INT32", [1]),
+                TensorConfig("INDEX", "UINT32", [1]),
+            ],
+            decoupled=True,
+        )
+
+    def place_params(self, params):
+        """Device placement hook; sharded variants override with
+        per-tensor NamedShardings (parallel/serving.py)."""
+        import jax
+
+        return jax.device_put(params)
+
+    # -- the arena's rows -----------------------------------------------------
+
+    def arena_rows(self, capacity: int | None = None):
+        """(free_rows, dummy_row) of the arena this backend builds: which
+        rows the scheduler may hand to streams, and the junk row padded
+        lanes point at.  Single-chip: rows 0..cap-1 plus the trailing
+        dummy; sharded: one junk row per shard (parallel/kv_shard.py), so
+        the free list is non-contiguous and the scheduler must not assume
+        ``row == lane`` arithmetic."""
+        cap = self.max_streams if capacity is None else int(capacity)
+        if self.kv_shards == 1:
+            return list(range(cap)), cap
+        from client_tpu.parallel.kv_shard import arena_row_layout
+
+        _total, free, dummy = arena_row_layout(cap, self.kv_shards)
+        return free, dummy
+
+    def _mesh(self):
+        if self._kv_mesh is None:
+            from client_tpu.parallel.kv_shard import kv_mesh
+
+            self._kv_mesh = kv_mesh(self.kv_shards)
+        return self._kv_mesh
+
+    def _live_rows(self, lens):
+        return lens
+
+    def _served(self, logits):
+        return logits
+
+    # -- kernel or oracle -----------------------------------------------------
+
+    def _use_kernel(self) -> bool:
+        """Whether the arena is the Pallas kernels' (the decode wave and
+        prefill's write): by ``attn_impl``, or unset wherever Mosaic
+        compiles them (a TPU) and not where Pallas would only be
+        interpreted."""
+        from client_tpu.engine.backend_init import pallas_interpret
+
+        return self.attn_impl == "fused" or (
+            not self.attn_impl and not pallas_interpret())
+
+    def _decode_attend(self):
+        """``attend(k_arena, v_arena, q, k, v, rows, live, layer)`` ->
+        (k_arena, v_arena, o): one layer of a wave.  Lane b's new ``k, v``
+        ``[B, H, D]`` go to row ``live[b]`` of slot ``rows[b]`` and ``q``
+        reads rows ``0 .. live[b]``.  The kernel is one Pallas grid over the
+        donated arena (with ``kv_shards > 1`` its shard_map form over the
+        row-sharded arena); ``layer`` may be traced (a decoder that scans
+        its layers) except over shards."""
+        from client_tpu.engine.backend_init import pallas_interpret
+        from client_tpu.ops.decode_kernel import (decode_wave_attention,
+                                                  reference_decode_attention)
+
+        interpret, block_s = pallas_interpret(), self.decode_block_s
+        if not self._use_kernel():
+            def attend(k_a, v_a, q, k, v, rows, live, layer):
+                return reference_decode_attention(
+                    k_a, v_a, q, k, v, rows, live, layer=layer)
+        elif self.kv_shards > 1:
+            from client_tpu.parallel.kv_shard import \
+                sharded_decode_attention
+
+            mesh, combine = self._mesh(), self.kv_combine
+
+            def attend(k_a, v_a, q, k, v, rows, live, layer):
+                return sharded_decode_attention(
+                    mesh, k_a, v_a, q, k, v, rows, live, layer=layer,
+                    block_s=block_s, interpret=interpret, combine=combine)
+        else:
+            def attend(k_a, v_a, q, k, v, rows, live, layer):
+                static = isinstance(layer, int)
+                return decode_wave_attention(
+                    k_a, v_a, q, k, v, rows, live,
+                    layer=layer if static else None,
+                    layer_index=None if static else layer,
+                    block_s=block_s, interpret=interpret)
+
+        return attend
+
+    def _prompt_rows_writer(self):
+        """``write(k_arena, v_arena, k, v, rows, layer)`` -> the two leaves
+        with ``[layer, rows[b], :n]`` holding lane b's ``[n, H*D]`` slab.
+        Where the decode wave is the kernel, so is this (one DMA a lane and
+        leaf, ops/arena_write.py; per shard of a row-sharded arena); else,
+        and for a prompt bucket shorter than a row group, XLA's in-place
+        scatter."""
+        from client_tpu.engine.backend_init import pallas_interpret
+        from client_tpu.ops.arena_write import (kernel_writes,
+                                                reference_write_prompt_rows,
+                                                write_prompt_rows)
+
+        interpret = pallas_interpret()
+        kernel = self._use_kernel()
+        if kernel and self.kv_shards > 1:
+            from client_tpu.parallel.kv_shard import \
+                sharded_write_prompt_rows
+
+            put = functools.partial(sharded_write_prompt_rows, self._mesh())
+        else:
+            put = write_prompt_rows
+
+        def write(k_a, v_a, k, v, rows, layer):
+            if kernel and kernel_writes(k.shape[1], k_a.dtype):
+                return put(k_a, v_a, k, v, rows, layer=layer,
+                           interpret=interpret)
+            return reference_write_prompt_rows(k_a, v_a, k, v, rows,
+                                               layer=layer)
+
+        return write
+
+    # -- the decode step ------------------------------------------------------
+
+    def decode_logits_fn(self):
+        """(params, arena, rows[B], lens[B]) -> (arena, logits as
+        ``_logits`` leaves them).  One decode step: each lane's input token
+        is GATHERED from its slot's device-side token (written by prefill /
+        the previous wave), its position is its context length ``lens[b]``;
+        each layer writes the lane's new key/value row behind the slot's
+        live rows and reads them all (``_decode_attend``)."""
+        attend = self._decode_attend()
+
+        def step(p, arena, rows, lens):
+            live = self._live_rows(lens)
+            tokens = arena["tok"][rows]
+
+            def layer(carry, lp, li):
+                x, k_a, v_a = carry
+                q, k, v = self._qkv(lp, x, lens)
+                k_a, v_a, o = attend(k_a, v_a, q, k, v, rows, live, li)
+                return self._after_attention(lp, x, o), k_a, v_a
+
+            x, k_a, v_a = self._walk_layers(
+                p, layer,
+                (self._embed(p, tokens, lens), arena["k"], arena["v"]))
+            return {**arena, "k": k_a, "v": v_a}, self._logits(p, x)
+
+        return step
+
+    def decode_fn(self):
+        """``DECODE_ARGS`` -> (arena, next[B]): ``decode_logits_fn`` and the
+        sampled (or greedy) next token, written back to the slots, so
+        consecutive waves chain on the device with no host round trip — the
+        scheduler dispatches waves ahead and fetches tokens asynchronously.
+        The context at sampling is ``lens + 1`` (the token just written
+        occupies position ``lens``): prefill's fold sequence, continued."""
+        step = self.decode_logits_fn()
+
+        def decode(p, arena, rows, lens, seeds, temps, top_ks, top_ps,
+                   sample=True):
+            arena, logits = step(p, arena, rows, lens)
+            return sample_into_slots(arena, rows, self._served(logits),
+                                     seeds, lens + 1, temps, top_ks, top_ps,
+                                     sample)
+
+        return decode
+
+    def decode_chunk_fn(self):
+        """``DECODE_CHUNK_ARGS`` -> (arena, tokens[k, B]).
+
+        K decode waves in ONE device execution via ``lax.scan`` over the
+        single-wave body: each scanned step gathers its inputs from the
+        token slots the previous step wrote, so the whole chunk chains on
+        the device and one dispatch advances every live stream K tokens.
+        ``k`` is static (one executable per (wave bucket, K)); the per-step
+        math is ``decode_fn``'s, so sampling's fold_in(seed, ctx_len)
+        sequence is that of K separate waves.  Not offered by a backend
+        with a transition: one may fall between any two steps, and the
+        scheduler orders it."""
+        if self.transition_due is not None:
+            raise NotImplementedError(
+                f"{self.config.name} decodes one wave a dispatch (its cache "
+                "transitions are ordered between waves)")
+        import jax
+
+        decode = self.decode_fn()
+
+        def decode_chunk(p, arena, rows, lens, seeds, temps, top_ks,
+                         top_ps, sample=True, k=2):
+            def body(carry, _):
+                arena_c, lens_c = carry
+                arena_c, nxt = decode(p, arena_c, rows, lens_c, seeds,
+                                      temps, top_ks, top_ps, sample)
+                return (arena_c, lens_c + 1), nxt
+
+            (arena, _), toks = jax.lax.scan(body, (arena, lens), None,
+                                            length=k)
+            return arena, toks  # [k, B]
+
+        return decode_chunk

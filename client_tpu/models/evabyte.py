@@ -32,8 +32,10 @@ prompt holds positions ``[pW, pW + m)``, attends to the slot's summaries (a
 prefix every query sees: ``flash_attention(prefix=...)``) and causally to
 itself; a full piece writes its W / c summaries only, the last, partial piece
 writes its exact rows.  The scheduler (engine/generative.py) learns all of this
-from the hooks below (``prefill_piece``, ``cache_rows``, ``transition_due``,
-``transition_fn``), not from the model's name.
+from what the backend declares of ``models/decoder.py``'s contract
+(``prefill_piece``, ``cache_rows``, ``transition_due``, ``transition_fn``), not
+from the model's name; the decode step is that module's frame over the parts
+below.
 
 Layers run under ``lax.scan`` over stacked weights with the arena in the
 carry (the decode kernel takes the layer index by scalar prefetch), so a
@@ -50,11 +52,8 @@ import math
 
 import numpy as np
 
-from client_tpu import config as envcfg
-from client_tpu.engine.config import ModelConfig, TensorConfig
-from client_tpu.engine.model import ModelBackend
 from client_tpu.models import register_model
-from client_tpu.models.generate import _sample_token
+from client_tpu.models.decoder import DecoderBackend, sample_into_slots
 
 _NEG_INF = -1e30
 
@@ -99,10 +98,9 @@ def summarize(k, v, phi, mu, chunk):
     return k_s, v_s
 
 
-class EvaByteBackend(ModelBackend):
-    """Byte-level decoder: INPUT_IDS [-1] -> streamed (TOKEN, INDEX)."""
-
-    generative = True
+class EvaByteBackend(DecoderBackend):
+    """Byte-level decoder (``models/decoder.py`` for what it is served
+    through)."""
 
     def __init__(self, name: str = "evabyte", n_layers: int = 2,
                  d_model: int = 64, n_heads: int = 4, d_ff: int = 128,
@@ -112,16 +110,9 @@ class EvaByteBackend(ModelBackend):
                  max_streams: int = 4, seed: int = 0,
                  prefill_lanes: int = 1, attention_impl: str = "einsum",
                  attn_impl: str | None = None):
-        if attention_impl not in ("einsum", "flash"):
-            raise ValueError(
-                f"attention_impl must be 'einsum' or 'flash', got "
-                f"{attention_impl!r}")
-        if attn_impl is None:
-            attn_impl = envcfg.env_str("CLIENT_TPU_ATTN_IMPL")
-        if attn_impl not in ("", "reference", "fused"):
-            raise ValueError(
-                f"attn_impl must be 'reference' or 'fused', got "
-                f"{attn_impl!r}")
+        super().__init__(name, vocab=vocab, max_seq_len=max_seq_len,
+                         max_streams=max_streams,
+                         attention_impl=attention_impl, attn_impl=attn_impl)
         if d_model % n_heads or window % chunk or max_seq_len % window:
             raise ValueError(
                 "d_model must divide into heads, the window into chunks and "
@@ -130,18 +121,13 @@ class EvaByteBackend(ModelBackend):
             raise ValueError(
                 "a window's summaries (window / chunk) must be a multiple "
                 "of 8 rows")
-        self.attention_impl, self.attn_impl = attention_impl, attn_impl
         self.flash_blocks = (512, 1024)
-        self.decode_block_s: int | None = None
         self.n_layers, self.d_model = int(n_layers), int(d_model)
         self.n_heads, self.d_ff = int(n_heads), int(d_ff)
         self.head_dim = self.d_model // self.n_heads
-        self.vocab, self.n_pred_heads = int(vocab), int(n_pred_heads)
-        self.max_seq_len = int(max_seq_len)
+        self.n_pred_heads = int(n_pred_heads)
         self.window, self.chunk = int(window), int(chunk)
         self.rope_theta, self.rms_eps = float(rope_theta), float(rms_eps)
-        self.max_streams = int(max_streams)
-        self.default_max_tokens = 16
         self._seed = seed
         # Summaries a window leaves, and the rows of a slot: the summaries
         # of every window but the last, then one window of exact rows,
@@ -155,17 +141,6 @@ class EvaByteBackend(ModelBackend):
         # consumed ``window`` positions a piece, ``prefill_lanes`` prompts
         # a call.
         self.prefill_piece = (self.window, int(prefill_lanes))
-        self.config = ModelConfig(
-            name=name,
-            platform="jax",
-            max_batch_size=0,
-            input=[TensorConfig("INPUT_IDS", "INT32", [-1])],
-            output=[
-                TensorConfig("TOKEN", "INT32", [1]),
-                TensorConfig("INDEX", "UINT32", [1]),
-            ],
-            decoupled=True,
-        )
 
     # -- what the scheduler asks of a cache that is not slot-per-position --
 
@@ -221,11 +196,6 @@ class EvaByteBackend(ModelBackend):
                       scale=1.0 / math.sqrt(d)),
         }
 
-    def place_params(self, params):
-        import jax
-
-        return jax.device_put(params)
-
     # -- shared blocks --------------------------------------------------------
 
     def _mm(self, x, w):
@@ -237,6 +207,11 @@ class EvaByteBackend(ModelBackend):
                           preferred_element_type=jnp.float32)
 
     def _qkv(self, lp, x, pos):
+        """A wave's: B sequences of the one position ``pos[b]``."""
+        q, k, v = self._qkv_rows(lp, x[:, None], pos[:, None])
+        return q[:, 0], k[:, 0], v[:, 0]
+
+    def _qkv_rows(self, lp, x, pos):
         """x ``[..., n, d]`` float32 -> q, k (RoPE applied), v ``[..., n,
         H, D]`` float32."""
         h = rms_norm(x, lp["ln1"], self.rms_eps)
@@ -259,6 +234,9 @@ class EvaByteBackend(ModelBackend):
         out = self._mm(rms_norm(x, p["lnf"], self.rms_eps), p["head"])
         return out.reshape(*x.shape[:-1], self.n_pred_heads, self.vocab)
 
+    def _served(self, logits):
+        return logits[:, 0]
+
     def _summaries(self, lp, k_c, v_c):
         """Summaries of whole windows of cache rows ``[..., n, H, D]``
         (bfloat16, what the cache holds), in the cache's dtype."""
@@ -270,7 +248,16 @@ class EvaByteBackend(ModelBackend):
                              lp["mu"].astype(jnp.float32), self.chunk)
         return k_s.astype(k_c.dtype), v_s.astype(v_c.dtype)
 
-    def _scan_layers(self, p, body, carry):
+    def _embed(self, p, tokens, pos):
+        import jax.numpy as jnp
+
+        return p["embed"][tokens].astype(jnp.float32)   # positions: RoPE
+
+    def _live_rows(self, lens):
+        win = self.window
+        return (lens // win) * self.sums_per_window + lens % win
+
+    def _walk_layers(self, p, body, carry):
         import jax
         import jax.numpy as jnp
 
@@ -304,7 +291,7 @@ class EvaByteBackend(ModelBackend):
             scale = 1.0 / math.sqrt(self.head_dim)
 
             def body(x, lp, _li):
-                q, k, v = self._qkv(lp, x, pos)
+                q, k, v = self._qkv_rows(lp, x, pos)
                 k_c, v_c = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
                 pad = ((0, n_pad - n), (0, 0), (0, 0))
                 k_s, v_s = self._summaries(lp, jnp.pad(k_c, pad),
@@ -317,7 +304,7 @@ class EvaByteBackend(ModelBackend):
                 o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), vals)
                 return self._after_attention(lp, x, o)
 
-            x = self._scan_layers(p, body,
+            x = self._walk_layers(p, body,
                                   p["embed"][ids].astype(jnp.float32))
             return {"logits": self._logits(p, x)}
 
@@ -336,12 +323,6 @@ class EvaByteBackend(ModelBackend):
         return {"k": jnp.zeros(shape, jnp.bfloat16),
                 "v": jnp.zeros(shape, jnp.bfloat16),
                 "tok": jnp.zeros(capacity + 1, jnp.int32)}
-
-    def _use_kernel(self) -> bool:
-        from client_tpu.engine.backend_init import pallas_interpret
-
-        return self.attn_impl == "fused" or (
-            not self.attn_impl and not pallas_interpret())
 
     def _piece_attention(self, q, k_c, v_c, pk, pv, n_sum):
         """One piece's attention: q ``[B, W, H, D]`` float32, its own keys
@@ -405,7 +386,7 @@ class EvaByteBackend(ModelBackend):
 
             def body(carry, lp, li):
                 x, k_a, v_a = carry
-                q, k, v = self._qkv(lp, x, pos)
+                q, k, v = self._qkv_rows(lp, x, pos)
                 k_c, v_c = k.astype(k_a.dtype), v.astype(v_a.dtype)
                 shape = (b, pre, self.n_heads, self.head_dim)
 
@@ -435,7 +416,7 @@ class EvaByteBackend(ModelBackend):
                 return (self._after_attention(lp, x, o),
                         put(k_a, k_c, k_s), put(v_a, v_c, v_s))
 
-            x, k_a, v_a = self._scan_layers(
+            x, k_a, v_a = self._walk_layers(
                 p, body, (p["embed"][ids].astype(jnp.float32),
                           arena["k"], arena["v"]))
             logits = self._logits(p, x[jnp.arange(b), lens - 1])
@@ -451,101 +432,16 @@ class EvaByteBackend(ModelBackend):
         sampled from head 0 after a lane's last valid position lands in
         the slot's device-side token, and means something for a prompt's
         last piece only."""
-        import jax
-        import jax.numpy as jnp
-
         piece = self.piece_logits_fn()
 
         def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
                     sample, starts):
             arena, logits = piece(p, arena, rows, ids, lens, starts)
-            head0 = logits[:, 0]
-            if sample:
-                tokens = jax.vmap(_sample_token)(
-                    head0, seeds, starts + lens, temps, top_ks, top_ps)
-            else:
-                tokens = jnp.argmax(head0, axis=-1).astype(jnp.int32)
-            return {**arena, "tok": arena["tok"].at[rows].set(tokens)}, tokens
+            return sample_into_slots(arena, rows, self._served(logits),
+                                     seeds, starts + lens, temps, top_ks,
+                                     top_ps, sample)
 
         return prefill
-
-    def decode_logits_fn(self):
-        """(params, arena, rows[B], lens[B]) -> (arena, logits[B,
-        n_pred_heads, vocab]).  One decode step: each lane's input token is
-        its slot's device-side token, its position its context length
-        ``lens[b]``; the new key/value row goes behind the slot's live rows
-        and the query reads them all (one softmax over summaries and
-        window).  The served step is the shared Pallas kernel
-        (ops/decode_kernel.py); ``attn_impl="reference"`` is the XLA oracle
-        on the same arena."""
-        import jax.numpy as jnp
-
-        win, spw = self.window, self.sums_per_window
-        if self._use_kernel():
-            from client_tpu.engine.backend_init import pallas_interpret
-            from client_tpu.ops.decode_kernel import decode_wave_attention
-
-            interpret, block_s = pallas_interpret(), self.decode_block_s
-
-            def attend(k_a, v_a, q, k, v, rows, live, li):
-                return decode_wave_attention(
-                    k_a, v_a, q, k, v, rows, live, layer=None,
-                    layer_index=li, block_s=block_s, interpret=interpret)
-        else:
-            from client_tpu.ops.decode_kernel import \
-                reference_decode_attention
-
-            def attend(k_a, v_a, q, k, v, rows, live, li):
-                return reference_decode_attention(
-                    k_a, v_a, q, k, v, rows, live, layer=li)
-
-        def decode(p, arena, rows, lens):
-            live = (lens // win) * spw + lens % win
-            tokens = arena["tok"][rows]
-
-            def body(carry, lp, li):
-                x, k_a, v_a = carry
-                q, k, v = self._qkv(lp, x[:, None], lens[:, None])
-                k_a, v_a, o = attend(k_a, v_a, q[:, 0], k[:, 0], v[:, 0],
-                                     rows, live, li)
-                return self._after_attention(lp, x, o), k_a, v_a
-
-            x, k_a, v_a = self._scan_layers(
-                p, body, (p["embed"][tokens].astype(jnp.float32),
-                          arena["k"], arena["v"]))
-            return {**arena, "k": k_a, "v": v_a}, self._logits(p, x)
-
-        return decode
-
-    def decode_fn(self):
-        """(params, arena, rows[B], lens[B], seeds[B], temps[B], top_ks[B],
-        top_ps[B], sample) -> (arena, next[B]): ``decode_logits_fn`` and
-        head 0's token, written back to the slots' device-side tokens."""
-        import jax
-        import jax.numpy as jnp
-
-        step = self.decode_logits_fn()
-
-        def decode(p, arena, rows, lens, seeds, temps, top_ks, top_ps,
-                   sample=True):
-            arena, logits = step(p, arena, rows, lens)
-            head0 = logits[:, 0]
-            if sample:
-                nxt = jax.vmap(_sample_token)(
-                    head0, seeds, lens + 1, temps, top_ks, top_ps)
-            else:
-                nxt = jnp.argmax(head0, axis=-1).astype(jnp.int32)
-            return {**arena, "tok": arena["tok"].at[rows].set(nxt)}, nxt
-
-        return decode
-
-    def decode_chunk_fn(self):
-        """Chunked decode is not offered: a dump may fall between any two
-        steps, and the scheduler orders it (it keeps K = 1 for a backend
-        with transitions)."""
-        raise NotImplementedError(
-            "evabyte decodes one wave a dispatch (window dumps are ordered "
-            "between waves)")
 
     def transition_fn(self):
         """(params, arena, rows[T], lens[T]) -> arena: **dump** the full

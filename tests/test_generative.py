@@ -1114,34 +1114,45 @@ class TestFusedDecode:
         finally:
             chunk_eng.shutdown()
 
-    def test_env_var_selects_impl(self, monkeypatch):
+    def test_the_constructor_selects_impl(self, monkeypatch):
+        """``attn_impl=`` selects, a sharded arena is the kernel's, and the
+        environment is not read."""
         from client_tpu.models.generate import TinyGptBackend
 
-        monkeypatch.setenv("CLIENT_TPU_ATTN_IMPL", "reference")
-        assert TinyGptBackend(name="e1", **self.KW).attn_impl == "reference"
-        # Explicit ctor arg wins over the env.
-        assert TinyGptBackend(name="e2", attn_impl="fused",
+        monkeypatch.setenv("CLIENT_TPU_" + "ATTN_IMPL", "reference")
+        assert TinyGptBackend(name="e1", **self.KW).attn_impl == ""
+        assert TinyGptBackend(name="e2", attn_impl="reference",
+                              **self.KW).attn_impl == "reference"
+        assert TinyGptBackend(name="e3", attn_impl="fused",
                               **self.KW).attn_impl == "fused"
-        monkeypatch.delenv("CLIENT_TPU_ATTN_IMPL")
-        assert TinyGptBackend(name="e3", **self.KW).attn_impl == ""
         # A sharded arena is the kernel's whatever the platform.
         assert TinyGptBackend(name="e4", kv_shards=2,
                               **self.KW).attn_impl == "fused"
 
-    @pytest.mark.parametrize("interpreted,step", [
-        (False, "_fused_decode_fn"), (True, "decode")])
-    def test_unset_the_platform_decides(self, monkeypatch, interpreted,
-                                        step):
+    @pytest.mark.parametrize("interpreted", [False, True])
+    def test_unset_the_platform_decides(self, monkeypatch, interpreted):
         """No setting: the kernel wherever Mosaic compiles it, the XLA step
         where Pallas would only be interpreted (this suite's CPU)."""
+        import jax
+        import jax.numpy as jnp
+
         from client_tpu.engine import backend_init
         from client_tpu.models.generate import TinyGptBackend
 
-        monkeypatch.delenv("CLIENT_TPU_ATTN_IMPL", raising=False)
         monkeypatch.setattr(backend_init, "pallas_interpret",
                             lambda: interpreted)
         backend = TinyGptBackend(name="p", **self.KW)
-        assert step in backend.decode_fn().__qualname__
+        params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+            jnp.asarray, backend._init_params()))
+        arena = jax.eval_shape(lambda: backend.init_arena(8))
+        lanes_i = jax.ShapeDtypeStruct((2,), jnp.int32)
+        lanes_f = jax.ShapeDtypeStruct((2,), jnp.float32)
+        text = str(jax.make_jaxpr(
+            backend.decode_fn(),
+            static_argnums=backend.decode_static_argnums)(
+                params, arena, lanes_i, lanes_i, lanes_i, lanes_f, lanes_i,
+                lanes_f, False))
+        assert ("pallas_call" in text) == (not interpreted)
 
     def test_invalid_configs_rejected(self):
         from client_tpu.models.generate import TinyGptBackend
@@ -1377,7 +1388,6 @@ class TestPrefillWritesTheArenaOnce:
         from client_tpu.engine import backend_init
         from client_tpu.models.generate import TinyGptBackend
 
-        monkeypatch.delenv("CLIENT_TPU_ATTN_IMPL", raising=False)
         for interpreted, kernel in ((False, True), (True, False)):
             monkeypatch.setattr(backend_init, "pallas_interpret",
                                 lambda v=interpreted: v)
