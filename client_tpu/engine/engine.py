@@ -667,6 +667,10 @@ class TpuEngine:
 
         inner = req.response_callback
         chunks: list[int] = []
+        if req.token_sink is not None:
+            # Tokens that leave by the wave hand-off pass no callback: the
+            # scheduler stamps them here (types.TokenSink).
+            req.token_sink.chunk_ts_ns = chunks
 
         def _traced(resp: InferResponse) -> None:
             if not resp.final:

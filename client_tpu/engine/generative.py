@@ -14,9 +14,12 @@ generation streams. Design, TPU-first:
   window of exact rows). The arena carries each slot's latest token ON DEVICE
   (``arena["tok"]``), so consecutive decode waves chain with no host round
   trip between them.
-- **Prefill** (one jit per prompt bucket, admit lanes padded to one fixed
-  bucket) writes a batch of prompts' K/V into their arena rows and emits
-  each prompt's first token.
+- **Prefill** (one jit per prompt bucket and lane count) writes a batch of
+  prompts' K/V into their arena rows and emits each prompt's first token.
+  An admit's lanes pad up to the smallest count of the bucket's ladder
+  (``_lane_ladder``: 8 lanes always, fewer only where halving the program
+  pays for compiling it), every count compiled before the bucket's first
+  dispatch.
 - **Prefill by pieces** (a backend that declares ``prefill_piece =
   (positions, lanes)``): a prompt is admitted once and consumed ``positions``
   at a time, the cache carrying the state between pieces; between two decode
@@ -59,7 +62,12 @@ into it as ``TraceAnnotation``s of the same names.
 
 Tokens stream out through the ordinary decoupled response protocol
 (``triton_final_response`` terminates), so the gRPC stream frontend and the
-C API serve generative models without modification.
+C API serve generative models without modification.  A frontend that can
+take a whole wave at once says so on the request (``InferRequest.token_sink``):
+its streams' tokens leave as ONE ``TokenWave`` per fetched wave (no response
+object, no array and no queue operation per token on the worker; what the
+writer does with the wave is the frontend's, ``server/sse.py``); only the
+final response and errors of such a stream take the per-response path.
 """
 
 from __future__ import annotations
@@ -86,11 +94,14 @@ from client_tpu.engine.types import (
     EngineError,
     InferRequest,
     InferResponse,
+    TokenWave,
     now_ns,
+    token_response,
 )
 from client_tpu.observability import spans as _sp
 from client_tpu.observability.costs import ledger
 from client_tpu.observability.profiler import profiler
+from client_tpu.observability.tracing import MAX_CHUNK_EVENTS
 
 _log = logging.getLogger("client_tpu")
 
@@ -103,16 +114,27 @@ _log = logging.getLogger("client_tpu")
 # question.
 _PIPELINE_DEPTH = 32
 
+# A lane count joins a prompt bucket's ladder only where the program it halves
+# holds more tokens than this.  Device time alone would put the mark near 1024
+# (on the v5e 480 tokens of float32 weights balance their own read; under that
+# a prefill costs the same with one lane or eight), but every program is also
+# 2.5 s of a launch (trace, lower and load, cache warm: PERF.md section 6,
+# PR 31), so the ladder is kept to the halving that saves most: 8 x 1024
+# positions (12.9 ms) against 4 x 1024 (7.1 ms).
+_LANE_WORTH_TOKENS = 4096
+
 
 class _Stream:
     __slots__ = ("req", "row", "disp_len", "disp_tokens", "f_len",
                  "emitted", "max_new", "seed", "temp", "top_k", "top_p",
                  "stop", "dead", "throttled_since", "ids", "consumed",
-                 "transition", "t_prefill")
+                 "transition", "t_prefill", "sink")
 
     def __init__(self, req, row, plen, max_new,
                  seed=0, temp=0.0, top_k=0, top_p=1.0, stop=frozenset()):
         self.req = req
+        # Where its tokens go by the wave (None: one response per token).
+        self.sink = req.token_sink if req is not None else None
         self.row = row
         self.disp_len = plen      # context length at the next dispatch
         self.disp_tokens = 1      # tokens whose generation is dispatched
@@ -316,12 +338,15 @@ class GenerativeScheduler(Scheduler):
         self._prompt_buckets = ([self._piece_len] if self._piece_len
                                 else power_buckets(self._max_seq))
         self._wave_buckets = power_buckets(self._cap)
-        # ONE admit lane bucket: every prefill chunk pads to this, so there
-        # is exactly one compiled prefill executable per prompt bucket
-        # (round-3's power-of-two admit lanes compiled per (lane, prompt)
-        # pair — a lane size first seen under load stalled every stream
-        # ~1s mid-measurement).
+        # The most lanes a prefill holds, and per prompt bucket the ladder
+        # of lane counts a chunk pads up to (``_lane_ladder``).  A bucket's
+        # whole ladder runs once before its first real dispatch
+        # (``_warm_ladder``): a lane size first seen under load would stall
+        # every stream for a compile (round-3's ~1 s mid-measurement).
         self._admit_lane = self._piece_lanes or min(self._cap, 8)
+        self._ladders = {pb: self._lane_ladder(pb)
+                         for pb in self._prompt_buckets}
+        self._ladders_warm: set[tuple[int, bool]] = set()
         self._depth = _PIPELINE_DEPTH   # a test sets a shallower one
         self._streams: list[_Stream] = []
         self._inflight: collections.deque[_Inflight] = collections.deque()
@@ -387,24 +412,49 @@ class GenerativeScheduler(Scheduler):
         if req.error is not None:
             raise EngineError(f"generative warmup failed: {req.error}", 500)
 
-    def _precompile(self) -> None:
-        lane = self._admit_lane
-        rows, *sampling = self._stage_lanes([], lane)   # all lanes padded
-        for pb in self._prompt_buckets:
-            self.model._set_state(f"warmup: prefill prompt bucket={pb}",
-                                  _sp.STEP_PREFILL, pb)
-            self._arena, tokens = self._prefill(
+    def _lane_ladder(self, bucket: int) -> list[int]:
+        """The lane counts a prefill of prompt bucket ``bucket`` may be
+        dispatched with: powers of two up to ``_admit_lane``, a smaller one
+        only where the next one's program holds more tokens than
+        ``_LANE_WORTH_TOKENS``, so where dropping its padded lanes saves the
+        device more than the program costs a launch.  A backend that
+        prefills by pieces keeps its own lanes."""
+        lanes = power_buckets(self._admit_lane)
+        if self._piece_len:
+            return lanes[-1:]
+        return [lane for lane, above in zip(lanes, lanes[1:])
+                if above * bucket > _LANE_WORTH_TOKENS] + lanes[-1:]
+
+    def _warm_ladder(self, bucket: int, sample: bool, but: int = 0) -> None:
+        """Run every lane count of a prompt bucket's ladder (``but`` the one
+        about to run anyway) once with every lane padded onto the dummy row,
+        so that no admit size compiles under load."""
+        if (bucket, sample) in self._ladders_warm:
+            return
+        for lane in self._ladders[bucket]:
+            if lane == but:
+                continue
+            self.model._set_state(
+                f"warmup: prefill prompt bucket={bucket} lanes={lane}",
+                _sp.STEP_PREFILL, bucket)
+            rows, *sampling = self._stage_lanes([], lane)
+            self._arena, _ = self._prefill(
                 self.model._params, self._arena, rows,
-                np.zeros((lane, pb), np.int32), np.ones(lane, np.int32),
-                *sampling, False,
+                np.zeros((lane, bucket), np.int32), np.ones(lane, np.int32),
+                *sampling, sample,
                 # A piece's `starts`: the argument only prefill by pieces has.
                 *((np.zeros(lane, np.int32),) if self._piece_len else ()))
+        self._ladders_warm.add((bucket, sample))
+
+    def _precompile(self) -> None:
+        for pb in self._prompt_buckets:
+            self._warm_ladder(pb, False)
         if self._transition is not None:
             self.model._set_state("warmup: cache transition",
                                   _sp.STEP_TRANSITION, 1)
             self._arena = self._transition(
-                self.model._params, self._arena, rows[:1],
-                np.zeros(1, np.int32))
+                self.model._params, self._arena,
+                np.asarray([self._dummy], np.int32), np.zeros(1, np.int32))
         for wb in self._wave_buckets:
             self.model._set_state(f"warmup: decode wave bucket={wb}",
                                   _sp.STEP_DECODE, wb)
@@ -685,7 +735,7 @@ class GenerativeScheduler(Scheduler):
         """One batched prefill dispatch: B admits -> ONE device execution,
         no host sync (the first tokens arrive through the fetch queue)."""
         n = len(chunk)
-        lane = self._admit_lane
+        lane = next(b for b in self._ladders[prompt_bucket] if b >= n)
         streams = [
             _Stream(req, self._free.pop(), len(ids), max_new, seed=seed,
                     temp=temp, top_k=top_k, top_p=top_p, stop=stop)
@@ -698,14 +748,16 @@ class GenerativeScheduler(Scheduler):
                 lens[i] = len(ids)
             rows, seeds, temps, top_ks, top_ps = self._stage_lanes(
                 streams, lane)
-            self.model._set_state(
-                f"generative prefill ({n} streams, prompt "
-                f"bucket={prompt_bucket})", _sp.STEP_PREFILL, prompt_bucket)
+            sample = bool((temps > 0.0).any())
             try:
+                self._warm_ladder(prompt_bucket, sample, but=lane)
+                self.model._set_state(
+                    f"generative prefill ({n} streams, prompt "
+                    f"bucket={prompt_bucket})", _sp.STEP_PREFILL,
+                    prompt_bucket)
                 self._arena, tokens = self._prefill(
                     self.model._params, self._arena, rows, ids_mat,
-                    lens, seeds, temps, top_ks, top_ps,
-                    bool((temps > 0.0).any()))
+                    lens, seeds, temps, top_ks, top_ps, sample)
                 tokens.copy_to_host_async()
             finally:
                 self.model._clear_state()
@@ -717,6 +769,8 @@ class GenerativeScheduler(Scheduler):
         # counting would drop waves whose lanes all retired before the
         # fetch, and everything discarded by an arena reset.
         self.stats.record_execution(n)
+        self._rec.c[_sp.C_PREFILL_LANES_LIVE] += n
+        self._rec.c[_sp.C_PREFILL_LANES_PADDED] += lane - n
         self._inflight.append(_Inflight("prefill", streams, tokens,
                                         t_disp=time.monotonic_ns(),
                                         depth=self._inflight_waves))
@@ -969,18 +1023,28 @@ class GenerativeScheduler(Scheduler):
         [K, B]; emit them in wave order so stop/budget retirement lands
         mid-chunk exactly where a per-wave dispatch would have retired
         (surplus lanes past a retirement are junk and are discarded like
-        any dead lane)."""
+        any dead lane).
+
+        What only the worker can do happens here, lane by lane: lengths,
+        stop tokens, budgets, first-token clocks, retirement.  The tokens
+        themselves leave once per fetch: the lanes whose frontend declared a
+        ``token_sink`` as ONE ``TokenWave`` to their writer, the others as
+        one ``InferResponse`` each.  Final responses follow the record, so
+        a stream's last token is never behind its end."""
         c = self._rec.c
         prefill = head.kind == "prefill"
         waves = toks if head.kind == "chunk" else toks[None]
-        for kk in range(waves.shape[0]):
-            for i, s in enumerate(head.streams):
+        t = now_ns()                  # one clock read a fetch
+        version = str(self.model.config.version)
+        records: dict = {}            # writer -> TokenWave
+        ended = []
+        for row in waves.tolist():
+            for s, tok in zip(head.streams, row):
                 if s.dead:
                     continue  # retired/cancelled lanes: discard junk
-                tok = int(waves[kk, i])
                 if prefill:
                     # TTFT from inside: prefill dispatch to token 0.
-                    t = s.req.times.first_token = now_ns()
+                    s.req.times.first_token = t
                     c[_sp.C_FIRST_TOKENS] += 1
                     c[_sp.C_FIRST_TOKEN_WAIT_NS] += t - head.t_disp
                     c[_sp.C_FIRST_TOKEN_INFLIGHT_WAVES] += head.depth
@@ -989,27 +1053,51 @@ class GenerativeScheduler(Scheduler):
                 if tok in s.stop:
                     # Stop tokens terminate without being emitted.
                     self._retire(s)
+                    ended.append(s)
                     continue
-                self._emit_token(s, tok)
+                sink = s.sink
+                if sink is None:
+                    self._respond(s.req, token_response(
+                        s.req, version, tok, s.emitted))
+                    c[_sp.C_EMITTED_TOKENS_CALLBACK] += 1
+                else:
+                    wave = records.get(sink.writer)
+                    if wave is None:
+                        wave = records[sink.writer] = TokenWave(version)
+                    wave.sinks.append(sink)
+                    wave.tokens.append(tok)
+                    wave.indices.append(s.emitted)
+                    stamps = sink.chunk_ts_ns
+                    if stamps is not None and len(stamps) < MAX_CHUNK_EVENTS:
+                        stamps.append(t)
+                s.emitted += 1
                 if (s.emitted >= s.max_new
                         or s.f_len + 1 >= self._max_seq):
                     self._retire(s)
+                    ended.append(s)
+        for writer, wave in records.items():
+            c[_sp.C_EMIT_HANDOFFS] += 1
+            c[_sp.C_EMITTED_TOKENS] += len(wave.tokens)
+            try:
+                writer.post(wave)
+            except Exception:  # noqa: BLE001 — a frontend's fault must not
+                # kill the sole worker; its streams end by their own
+                # back-pressure or cancel.
+                _log.exception(
+                    "stream writer refused a wave (model '%s')",
+                    self.model.config.name)
+        for s in ended:
+            self._respond(s.req, InferResponse(
+                model_name=s.req.model_name,
+                model_version=s.req.model_version or version,
+                request_id=s.req.request_id,
+                outputs={},
+                parameters={"triton_final_response": True},
+                final=True,
+                times=s.req.times,
+            ))
 
     # -- stream lifecycle ------------------------------------------------------
-
-    def _emit_token(self, s: _Stream, token: int) -> None:
-        self._respond(s.req, InferResponse(
-            model_name=s.req.model_name,
-            model_version=s.req.model_version or
-            str(self.model.config.version),
-            request_id=s.req.request_id,
-            outputs={"TOKEN": np.array([token], np.int32),
-                     "INDEX": np.array([s.emitted], np.uint32)},
-            parameters={"triton_final_response": False},
-            final=False,
-            times=s.req.times,
-        ))
-        s.emitted += 1
 
     def _drop(self, s: _Stream) -> None:
         """Remove from the active set and release the row. The row is safe
@@ -1039,22 +1127,15 @@ class GenerativeScheduler(Scheduler):
         return self._row_bytes
 
     def _retire(self, s: _Stream) -> None:
+        """A stream's last token is fetched: free its row and close its
+        account.  Its final response is the caller's to send, behind the
+        fetch's tokens (``_emit_fetched``)."""
         self._drop(s)
         s.req.times.compute_input_end = s.req.times.compute_start
         s.req.times.compute_infer_end = now_ns()
         s.req.times.compute_output_end = s.req.times.compute_infer_end
         self.stats.record_request(s.req.times, success=True,
                                   tenant=s.req.tenant)
-        self._respond(s.req, InferResponse(
-            model_name=s.req.model_name,
-            model_version=s.req.model_version or
-            str(self.model.config.version),
-            request_id=s.req.request_id,
-            outputs={},
-            parameters={"triton_final_response": True},
-            final=True,
-            times=s.req.times,
-        ))
 
     def _all_tracked_streams(self) -> list:
         """Active streams plus any stream referenced only by in-flight

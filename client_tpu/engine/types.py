@@ -150,6 +150,15 @@ class InferRequest:
     # for this request instead of flooding the queue — the slow-consumer
     # shed becomes the stalled-consumer last resort, not the first line.
     backpressure: Callable[[], bool] | None = None
+    # Wave hand-off: a stream frontend that can take a whole decode wave's
+    # tokens in one call declares its stream's :class:`TokenSink` here, at
+    # submit.  The generative scheduler then posts ONE :class:`TokenWave`
+    # per fetched wave to ``token_sink.writer`` and no per-token
+    # ``InferResponse``; the stream's final response (and every error) still
+    # arrives on ``response_callback``, after the wave that held its last
+    # token.  None (every other frontend and scheduler): one
+    # ``InferResponse`` per token on ``response_callback``, as ever.
+    token_sink: "TokenSink | None" = None
 
     def cancel(self) -> None:
         self.cancelled = True
@@ -174,6 +183,37 @@ class InferRequest:
         return [o.name for o in self.outputs]
 
 
+class TokenSink:
+    """One stream's end of the wave hand-off (``InferRequest.token_sink``).
+
+    ``writer`` takes the records: ``writer.post(wave)`` is called once per
+    fetched wave, on the scheduler's worker thread, with every lane of the
+    wave that names this writer; it must not block.  ``chunk_ts_ns`` is the
+    engine's: where a traced request keeps the clock of its first streamed
+    tokens (``observability.tracing``), None for an untraced one."""
+
+    __slots__ = ("writer", "chunk_ts_ns")
+
+    def __init__(self, writer):
+        self.writer = writer
+        self.chunk_ts_ns: list[int] | None = None
+
+
+class TokenWave:
+    """What one fetched wave hands a stream writer: lane by lane the sink,
+    its token and the token's index in its stream (parallel lists, emission
+    order: a K-chunk fetch holds a stream K times).  ``model_version`` is
+    the serving model's, for a request that named none."""
+
+    __slots__ = ("model_version", "sinks", "tokens", "indices")
+
+    def __init__(self, model_version: str):
+        self.model_version = model_version
+        self.sinks: list[TokenSink] = []
+        self.tokens: list[int] = []
+        self.indices: list[int] = []
+
+
 @dataclass
 class InferResponse:
     model_name: str
@@ -195,3 +235,20 @@ class InferResponse:
             error=err,
             times=req.times,
         )
+
+
+def token_response(req: InferRequest, model_version: str, token: int,
+                   index: int) -> InferResponse:
+    """A generation stream's response for one token: what a stream without a
+    ``token_sink`` receives per token, and what a sink's writer renders its
+    wire template from."""
+    return InferResponse(
+        model_name=req.model_name,
+        model_version=req.model_version or model_version,
+        request_id=req.request_id,
+        outputs={"TOKEN": np.array([token], np.int32),
+                 "INDEX": np.array([index], np.uint32)},
+        parameters={"triton_final_response": False},
+        final=False,
+        times=req.times,
+    )

@@ -86,13 +86,22 @@ GEN_COUNTERS = (
     # transitions dispatched.
     "fetched_rows_exact", "fetched_rows_summary",
     "prompts_admitted", "prefill_pieces", "transitions",
+    # How tokens leave the worker: ``emit_handoffs`` wave records posted to
+    # the stream writers (one per fetch and writer) carrying
+    # ``emitted_tokens``; ``emitted_tokens_callback`` tokens that left as one
+    # ``InferResponse`` each (a stream whose frontend declared no sink).
+    "emit_handoffs", "emitted_tokens", "emitted_tokens_callback",
+    # per one-shot prefill dispatch: lanes holding a prompt, and lanes padded
+    # up to the program's lane count
+    "prefill_lanes_live", "prefill_lanes_padded",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
  C_FETCHES_FORCED, C_FIRST_TOKENS, C_FIRST_TOKEN_WAIT_NS,
  C_FIRST_TOKEN_INFLIGHT_WAVES, C_FETCHED_ROWS_EXACT, C_FETCHED_ROWS_SUMMARY,
- C_PROMPTS_ADMITTED, C_PREFILL_PIECES,
- C_TRANSITIONS) = range(len(GEN_COUNTERS))
+ C_PROMPTS_ADMITTED, C_PREFILL_PIECES, C_TRANSITIONS, C_EMIT_HANDOFFS,
+ C_EMITTED_TOKENS, C_EMITTED_TOKENS_CALLBACK, C_PREFILL_LANES_LIVE,
+ C_PREFILL_LANES_PADDED) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

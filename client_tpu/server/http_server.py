@@ -43,6 +43,7 @@ from client_tpu.protocol.pushback import (
     format_retry_after_s,
 )
 from client_tpu.server.classification import classify_output
+from client_tpu.server.sse import StreamWriter, json_response_dict
 
 _ROUTES: list[tuple[str, re.Pattern, str]] = [
     ("GET", re.compile(r"^/v2/health/live$"), "health_live"),
@@ -592,45 +593,37 @@ class _Handler(BaseHTTPRequestHandler):
         """Server-sent events: one `data: <v2 response JSON>` event per
         decoupled response, chunked transfer, terminated by the final-flag
         response. A dead client cancels the request (the generative
-        scheduler then frees its KV arena slot)."""
+        scheduler then frees its KV arena slot).  The events are not
+        written by this thread: the stream is declared on the request
+        (``server/sse.py``), a generative scheduler hands the server's
+        stream writer a whole wave's tokens at once, and this thread parks
+        until the stream's last byte has left."""
         req = self._parse_generate_request(name, version)
-        responses = self._stream_responses(req)
         self.send_response(200)
         self.send_header("Content-Type", "text/event-stream")
         self.send_header("Transfer-Encoding", "chunked")
         self.send_header("Cache-Control", "no-cache")
         self.end_headers()
         self.wfile.flush()  # time-to-first-header, not time-to-first-token
-
-        def chunk(payload: bytes) -> None:
-            self.wfile.write(f"{len(payload):X}\r\n".encode() + payload +
-                             b"\r\n")
-            self.wfile.flush()
-
         # Headers are out: from here every outcome must stay inside the
         # chunked body (a second status line would desync the stream), and
         # an abandoned request must stop generating.
+        sock = self.connection
+        timeout = sock.gettimeout()
+        sock.setblocking(False)  # the writer's sends never block
+        stream = self.stream_writer.open(
+            req, sock, self._stream_pending_limit(),
+            envcfg.env_float("CLIENT_TPU_STREAM_WRITER_DELAY_MS") / 1e3)
         try:
-            for resp in responses:
-                if resp.error is not None:
-                    chunk(b"data: " + json.dumps(
-                        {"error": str(resp.error)}).encode() + b"\n\n")
-                    break
-                if resp.outputs or not resp.final:
-                    chunk(b"data: " + json.dumps(
-                        self._json_response_dict(resp),
-                        separators=(",", ":")).encode() + b"\n\n")
-            chunk(b"")  # terminal chunk
-        except (BrokenPipeError, ConnectionResetError):
-            req.cancel()  # dead client: stop generating for it
-        except Exception as exc:  # noqa: BLE001 — mid-stream failure
-            req.cancel()
             try:
-                chunk(b"data: " + json.dumps(
-                    {"error": str(exc)}).encode() + b"\n\n")
-                chunk(b"")
-            except OSError:
-                pass
+                self.engine.async_infer(req, stream.respond)
+            except Exception as exc:  # noqa: BLE001 — refused at submit
+                stream.fail(exc)
+            stream.wait(self.GENERATE_STALL_TIMEOUT_S)
+        finally:
+            sock.settimeout(timeout)
+        if stream.broken:
+            self.close_connection = True
 
     def _parse_generate_request(self, name, version) -> InferRequest:
         req = self._parse_infer_request(name, version)
@@ -654,9 +647,10 @@ class _Handler(BaseHTTPRequestHandler):
         return max(1, envcfg.env_int("CLIENT_TPU_STREAM_PENDING_LIMIT"))
 
     def _stream_responses(self, req: InferRequest):
-        """Submit and yield responses until the final one; a stall cancels
-        the request and raises 504; a backlog past STREAM_PENDING_LIMIT
-        cancels it too (logged)."""
+        """Submit and yield responses until the final one (``/generate``'s
+        collector; ``/generate_stream`` has ``server/sse.py``); a stall
+        cancels the request and raises 504; a backlog past
+        STREAM_PENDING_LIMIT cancels it too (logged)."""
         import queue as q
 
         out_q: q.Queue = q.Queue()
@@ -736,24 +730,11 @@ class _Handler(BaseHTTPRequestHandler):
                 if resp.error is not None or resp.final:
                     return
 
-    def _json_response_dict(self, resp) -> dict:
+    @staticmethod
+    def _json_response_dict(resp) -> dict:
         """v2 response head with all tensors as JSON data (no binary tails
         — SSE events and collected arrays are text)."""
-        from client_tpu.protocol.dtypes import np_to_wire_dtype
-
-        head: dict = {"model_name": resp.model_name,
-                      "model_version": str(resp.model_version)}
-        if resp.request_id:
-            head["id"] = resp.request_id
-        if resp.parameters:
-            head["parameters"] = dict(resp.parameters)
-        head["outputs"] = [
-            rest.build_tensor_json(out_name, arr,
-                                   np_to_wire_dtype(arr.dtype), arr.shape,
-                                   binary=False)[0]
-            for out_name, arr in resp.outputs.items()
-        ]
-        return head
+        return json_response_dict(resp)
 
     def _parse_infer_request(self, name, version=None) -> InferRequest:
         body = self._read_body()
@@ -919,8 +900,12 @@ class HttpInferenceServer:
     def __init__(self, engine: TpuEngine, host: str = "127.0.0.1",
                  port: int = 8000, verbose: bool = False,
                  certfile: str | None = None, keyfile: str | None = None):
+        # One writer thread for every SSE stream of this server, started
+        # with the first stream.
+        self._stream_writer = StreamWriter()
         handler = type("BoundHandler", (_Handler,),
-                       {"engine": engine, "verbose": verbose})
+                       {"engine": engine, "verbose": verbose,
+                        "stream_writer": self._stream_writer})
         self.engine = engine
         # socketserver's default accept backlog (5) drops connections under
         # concurrent-client bursts — raise it before the socket listens.
@@ -957,5 +942,6 @@ class HttpInferenceServer:
     def stop(self) -> None:
         self.httpd.shutdown()
         self.httpd.server_close()
+        self._stream_writer.stop()
         if self._thread:
             self._thread.join(timeout=5)

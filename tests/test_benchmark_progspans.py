@@ -373,3 +373,68 @@ class TestCacheReaders:
                      "prompts_admitted", "prefill_pieces", "transitions"):
             assert name in spans.GEN_COUNTERS
         assert "gen.transition_dispatch" in spans.GEN_SPANS
+
+
+# -- PR 31's readers: how tokens leave the worker, how full a prefill is --------
+
+HANDOFF_METRICS = {
+    # name -> (cells, the share worked out by hand from the two snapshots)
+    # 9000 - 1000 tokens by the wave, 1100 - 100 one response each
+    "emit_wave_handoff_share.itl": (
+        ["gpt2_small.chat", "evabyte_6b5.longdoc"], 100 * 8000 / 9000),
+    # 700 - 100 lanes held a prompt, 250 - 50 were padding
+    "prefill_live_lane_share.itl": (["gpt2_small.chat"], 100 * 600 / 800),
+}
+HANDOFF_BEFORE = {"emit_handoffs": 40, "emitted_tokens": 1000,
+                  "emitted_tokens_callback": 100, "prefill_lanes_live": 100,
+                  "prefill_lanes_padded": 50}
+HANDOFF_AFTER = {"emit_handoffs": 300, "emitted_tokens": 9000,
+                 "emitted_tokens_callback": 1100, "prefill_lanes_live": 700,
+                 "prefill_lanes_padded": 250}
+
+
+def run_reader(name):
+    """By its manifest name, the way the harness loads it."""
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    from run import load_reader
+
+    return load_reader(name)
+
+
+class TestHandoffReaders:
+    @pytest.mark.parametrize("name", HANDOFF_METRICS)
+    def test_share_worked_out_by_hand(self, name):
+        ctx = {"snap_before": snap({}, HANDOFF_BEFORE),
+               "snap_after": snap({}, HANDOFF_AFTER)}
+        assert run_reader(name)(ctx) == pytest.approx(
+            HANDOFF_METRICS[name][1])
+
+    @pytest.mark.parametrize("name", HANDOFF_METRICS)
+    def test_nothing_where_the_counters_are_missing(self, name):
+        """The parent serves ``generative`` without the five counters, an
+        older one no ``generative`` at all, and a window that saw no token
+        or no prefill has nothing to divide by: None each time, never 0 and
+        never a raise."""
+        read = run_reader(name)
+        parent = snap({}, {"fetched_waves": 5, "drains": 3})
+        assert read({"snap_before": parent, "snap_after": parent}) is None
+        bare = {"t": 0.0, "stats": {}, "profile": {"models": {"gpt:1": {
+            "decode_waves": []}}}}
+        assert read({"snap_before": bare, "snap_after": bare}) is None
+        assert read({"snap_before": None, "snap_after": None}) is None
+        still = snap({}, HANDOFF_AFTER)
+        assert read({"snap_before": still, "snap_after": still}) is None
+
+    def test_the_manifest_ends_with_them(self):
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+        tail = manifest["per_layer"][-len(HANDOFF_METRICS):]
+        assert [m["name"] for m in tail] == list(HANDOFF_METRICS)
+        for m in tail:
+            assert m["workloads"] == HANDOFF_METRICS[m["name"]][0]
+            assert (m["layer"], m["moves"], m["source"], m["unit"]) == (
+                "generative scheduler", "itl_mean_ms", "program_counter", "%")
+        from client_tpu.observability import spans
+
+        assert set(HANDOFF_BEFORE) <= set(spans.GEN_COUNTERS)

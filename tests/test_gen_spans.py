@@ -239,6 +239,129 @@ class TestLaneCounters:
         assert d["first_tokens"] + d["fetched_lanes_live"] > len(got)
 
 
+class _Collector:
+    """A stream writer as the scheduler sees one: takes whole waves."""
+
+    def __init__(self):
+        self.waves = []
+
+    def post(self, wave):
+        self.waves.append(wave)
+
+
+def _sink_stream(engine, writer, prompt, max_tokens, **params):
+    """Start one stream that declares a token sink; returns (sink, join)
+    where join() -> the responses its callback saw."""
+    from client_tpu.engine.types import TokenSink
+
+    sink, seen, done = TokenSink(writer), [], threading.Event()
+
+    def cb(resp):
+        seen.append(resp)
+        if resp.final:
+            done.set()
+
+    req = InferRequest(
+        model_name=MODEL, inputs={"INPUT_IDS": np.asarray(prompt, np.int32)},
+        parameters={"max_tokens": max_tokens, **params})
+    req.token_sink = sink
+    engine.async_infer(req, cb)
+
+    def join():
+        assert done.wait(120), "stream did not finish"
+        return seen
+
+    return sink, join
+
+
+EMIT_COUNTERS = ["emit_handoffs", "emitted_tokens", "emitted_tokens_callback",
+                 "prefill_lanes_live", "prefill_lanes_padded"]
+
+
+class TestEmitCounters:
+    @pytest.mark.parametrize("name", EMIT_COUNTERS)
+    def test_served_and_monotone(self, engine, ran, name):
+        before, after, _ = ran
+        assert name in spans.GEN_COUNTERS
+        assert after["counters"][name] >= before["counters"][name] >= 0
+        assert getattr(spans, "C_" + name.upper()) \
+            == spans.GEN_COUNTERS.index(name)
+
+    def test_callback_streams_count_a_token_each(self, ran):
+        """No stream of the known traffic declared a sink: every token left
+        as a response of its own, none by a wave's record."""
+        before, after, streams = ran
+        d = _delta(before, after)
+        assert d["emitted_tokens_callback"] == sum(
+            len(t) for _, _, t in streams)
+        assert d["emitted_tokens"] == d["emit_handoffs"] == 0
+
+    def test_prefill_lanes_add_up_to_the_programs_dispatched(self, ran):
+        """A 64-position model has one 8-lane program a prompt bucket: the
+        lanes that held a prompt and the padded ones are 8 a dispatch."""
+        before, after, streams = ran
+        d = _delta(before, after)
+        calls = after["spans"][spans.GEN_PREFILL_DISPATCH]["count"] \
+            - before["spans"][spans.GEN_PREFILL_DISPATCH]["count"]
+        assert d["prefill_lanes_live"] == len(streams)
+        assert d["prefill_lanes_live"] + d["prefill_lanes_padded"] \
+            == 8 * calls
+
+    def test_sink_and_callback_streams_together(self, engine):
+        """Streams that declare a sink leave by the wave, the others by the
+        response, in the same waves: the two counters sum to the tokens the
+        clients received, one hand-off a fetch and writer, and a sink
+        stream's callback sees its final response only, after its tokens."""
+        before = _gen(engine)
+        one, two = _Collector(), _Collector()
+        plain = [_stream(engine, [1, 2, 3], 6), _stream(engine, [9, 9], 4)]
+        sinks = [_sink_stream(engine, one, [1, 2, 3], 6),
+                 _sink_stream(engine, one, [4, 5], 5, stop_token_ids=0),
+                 _sink_stream(engine, two, [1, 2, 3], 3)]
+        got_plain = [j() for j in plain]
+        finals = [j() for _, j in sinks]
+        d = _delta(before, _gen(engine))
+        by_sink = {id(s): [] for s, _ in sinks}
+        for w in one.waves + two.waves:
+            assert w.model_version == "1"
+            assert len(w.sinks) == len(w.tokens) == len(w.indices) > 0
+            for s, tok, idx in zip(w.sinks, w.tokens, w.indices):
+                assert idx == len(by_sink[id(s)])      # in order, no gap
+                by_sink[id(s)].append(tok)
+        assert {id(s) for w in one.waves for s in w.sinks} \
+            <= {id(sinks[0][0]), id(sinks[1][0])}      # a writer's own lanes
+        received = [by_sink[id(s)] for s, _ in sinks]
+        assert received[0] == got_plain[0]             # the same greedy tokens
+        assert received[2] == got_plain[0][:3]
+        assert d["emitted_tokens"] == sum(map(len, received))
+        assert d["emitted_tokens_callback"] == sum(map(len, got_plain))
+        assert d["emit_handoffs"] == len(one.waves) + len(two.waves)
+        assert d["first_tokens"] == 5
+        for seen in finals:
+            assert len(seen) == 1 and seen[0].final and not seen[0].outputs
+            assert seen[0].error is None
+
+    def test_a_traced_sink_stream_keeps_its_chunk_clock(self, engine):
+        """``/v2/trace/requests`` shows a chunk per streamed token; tokens
+        that leave by the wave pass no callback, so the scheduler stamps
+        them where the engine's recorder reads."""
+        from client_tpu.engine.types import TokenSink
+        from client_tpu.observability.tracing import TraceContext
+
+        sink, done = TokenSink(_Collector()), threading.Event()
+        req = InferRequest(
+            model_name=MODEL, inputs={"INPUT_IDS": np.asarray([3, 4], np.int32)},
+            parameters={"max_tokens": 5},
+            trace=TraceContext.from_traceparent(None))
+        req.token_sink = sink
+        engine.async_infer(req, lambda r: r.final and done.set())
+        assert done.wait(120)
+        assert len(sink.chunk_ts_ns) == 5
+        assert sink.chunk_ts_ns == sorted(sink.chunk_ts_ns)
+        events = engine.request_trace_export(req.trace.trace_id)["traceEvents"]
+        assert len([e for e in events if e["name"] == "chunk"]) == 5
+
+
 class TestRecorder:
     def test_snapshot_sees_whole_iterations_only(self):
         p = EfficiencyProfiler()

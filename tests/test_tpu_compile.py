@@ -244,6 +244,24 @@ def test_prefill_moves_each_key_value_and_query_once(one_chip, monkeypatch):
     assert memory.temp_size_in_bytes < 10 * slab, memory
 
 
+@pytest.mark.parametrize("lanes", [1, 2, 4])
+def test_prefill_compiles_at_every_lane_count_of_the_ladder(
+        one_chip, monkeypatch, lanes):
+    """The scheduler picks a prefill's lane count from a ladder (PR 31: 4
+    lanes beside 8 at the 1024 bucket, 2 and 1 at longer ones): at GPT-2's
+    widths the program compiles for the chip at each, with the same two
+    kernels a layer and nothing arena-shaped moved."""
+    compiled, arena = _compile_prefill(
+        _on(one_chip), monkeypatch, 1024, lanes=lanes,
+        attention_impl="flash")
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 2 * arena["k"].shape[0]
+    moved = [(name, op) for name, op in _entry_ops_shaped_like(
+        text, arena["k"].shape) if op not in _PASSES_A_LEAF_ON]
+    assert not moved, f"arena-shaped work in jit_prefill: {moved}"
+
+
 def test_prefill_of_a_short_bucket_and_of_tiny_gpt_compile(one_chip,
                                                            monkeypatch):
     """A prompt bucket under a row group takes XLA's scatter, in place; the
