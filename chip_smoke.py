@@ -96,6 +96,14 @@ FLASH_F32_ATOL = 2e-2
 # The decode kernel does its dot products on the VPU in float32, so against
 # the oracle at precision=highest only summation order differs.
 DECODE_ATOL = 2e-5
+# The latent decode kernel on a bfloat16 cache: scores are exact products
+# summed in float32 on both sides (the case's scale is a power of two), the
+# kernel rounds the probabilities to bfloat16 before the weighted sum and
+# the oracle does not: 2^-9 of O(1) outputs, with headroom.
+LATENT_ATOL = 2e-2
+# The grouped matmul: bfloat16 operands (exact products), float32 sums in
+# another order than the oracle's, over up to 7680 terms of O(1/sqrt(k)).
+GROUPED_ATOL = 1e-3
 
 
 class SmokeFailure(Exception):
@@ -1069,6 +1077,114 @@ def kernels_child(rehearsal: bool) -> int:
         # benchmark's geometry; lengths around the 512-position block edge.
         decode_case("decode_wave_attention(gpt2)", 2, 17, 1024, 8,
                     [700, 0, 1023, 511, 512, 513, 1, 0], h=12)
+
+    def latent_case(name, layers, rows, seq, bsz, heads, rank, rope_dim,
+                    lens_list):
+        """``latent_wave_attention`` (one shared row a position, bfloat16)
+        against its oracle: the output, the arena bitwise, untouched rows."""
+        from client_tpu.ops.decode_kernel import (
+            latent_row_width,
+            latent_wave_attention,
+            reference_latent_attention,
+        )
+
+        w = latent_row_width(rank, rope_dim)
+        ks = jax.random.split(jax.random.PRNGKey(17), 3)
+        c_a = jax.random.normal(ks[0], (layers, rows, seq, w)).astype(
+            jnp.bfloat16)
+        # Queries as the arena's dtype holds them, so kernel and oracle
+        # multiply the same numbers.
+        q = jax.random.normal(ks[1], (bsz, heads, w)).astype(
+            jnp.bfloat16).astype(jnp.float32)
+        new = jax.random.normal(ks[2], (bsz, w))
+        rows_ix = np.arange(bsz, dtype=np.int32) * 2 % (rows - 1)
+        rows_ix[-1] = rows - 1
+        lens = np.asarray(lens_list, np.int32)
+        lens[-1] = 0
+        layer, kw = layers - 1, {"value_dim": rank, "sm_scale": 1.0 / 32}
+        fn = functools.partial(latent_wave_attention, layer=layer,
+                               interpret=interpret, **kw)
+        args = (c_a, q, new, jnp.asarray(rows_ix), jnp.asarray(lens))
+        compiled = mosaic(fn, *args)
+        fc, fo = (np.asarray(x) for x in fn(*args))
+        with jax.default_matmul_precision("highest"):
+            rc, ro = (np.asarray(x) for x in reference_latent_attention(
+                *args, layer=layer, **kw))
+        live = np.ones(bsz, bool)
+        live[-1] = False
+        err = float(np.max(np.abs(fo[live] - ro[live])))
+        bitwise = np.array_equal(fc, rc)
+        c_in = np.asarray(c_a)
+        touched = np.zeros(c_in.shape[:3], bool)
+        touched[layer, rows_ix, lens] = True
+        preserved = np.array_equal(fc[~touched], c_in[~touched])
+        ok = err <= LATENT_ATOL and bitwise and preserved \
+            and bool(np.all(np.isfinite(fo[live])))
+        if not rehearsal and not compiled:
+            ok = False
+        report(name, ok, f"arena [{layers},{rows},{seq},{w}] bfloat16, wave "
+               f"{bsz} x {heads} heads: max |diff| {err:.2e} (<= "
+               f"{LATENT_ATOL:g}), arena == oracle bitwise {bitwise}, "
+               f"untouched rows preserved {preserved}, mosaic custom call "
+               f"{'present' if compiled else 'ABSENT'}")
+
+    def grouped_case(name, experts, k, n, pairs, tile_m):
+        """``grouped_matmul`` against its dense oracle on one sorted
+        layout: a skewed routing with one expert untouched."""
+        from client_tpu.ops.grouped_matmul import (
+            capacity_rows,
+            grouped_matmul,
+            plan_groups,
+            reference_grouped_matmul,
+        )
+
+        rng = np.random.default_rng(19)
+        expert = rng.integers(0, experts + 2, pairs).clip(0, experts)
+        expert[expert == 1] = 0          # expert 1 untouched, 0 busiest
+        rows = capacity_rows(pairs, experts, tile_m)
+        plan = plan_groups(jnp.asarray(expert, jnp.int32), experts, tile_m,
+                           rows)
+        ks = jax.random.split(jax.random.PRNGKey(23), 2)
+        x = jax.random.normal(ks[0], (pairs, k)).astype(jnp.bfloat16)
+        xs = jnp.zeros((rows + 1, k), jnp.bfloat16).at[plan["dest"]].set(
+            x)[:rows]
+        wts = (jax.random.normal(ks[1], (experts, k, n)) / np.sqrt(k)
+               ).astype(jnp.bfloat16)
+        fn = functools.partial(grouped_matmul, tile_m=tile_m,
+                               interpret=interpret)
+        args = (xs, wts, plan["tile_expert"], plan["n_tiles"])
+        compiled = mosaic(fn, *args)
+        used = int(plan["n_tiles"][0]) * tile_m
+        got = np.asarray(fn(*args))[:used]
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(reference_grouped_matmul(
+                xs, wts, plan["padded"]))[:used]
+        err = float(np.max(np.abs(got - want)))
+        sizes = np.asarray(plan["sizes"])
+        ok = err <= GROUPED_ATOL and bool(np.all(np.isfinite(got))) \
+            and sizes[1] == 0 and int(sizes.sum()) == int(
+                (expert < experts).sum())
+        if not rehearsal and not compiled:
+            ok = False
+        report(name, ok, f"{pairs} pairs over {experts} experts of "
+               f"[{k},{n}] bfloat16 (groups {sizes.tolist()}): max |diff| "
+               f"{err:.2e} (<= {GROUPED_ATOL:g}), mosaic custom call "
+               f"{'present' if compiled else 'ABSENT'}")
+
+    if rehearsal:
+        latent_case("latent_wave_attention(tiny)", 2, 9, 64, 4, 4, 32, 8,
+                    [7, 63, 16, 0])
+        grouped_case("grouped_matmul(tiny)", 4, 32, 256, 24, 8)
+    else:
+        # The latent cell's widths: 128 heads on a 512 + 64 -> 640-lane row,
+        # 4096 positions, lengths around the 512-row block edge; 16 experts
+        # of 7680 x 4096 (gate | up) and 2048 x 7680 (down).
+        latent_case("latent_wave_attention(128 heads x 576)", 2, 17, 4096, 8,
+                    128, 512, 64, [700, 0, 4095, 511, 512, 513, 1, 0])
+        grouped_case("grouped_matmul(16 x 7680 x 4096)", 16, 7680, 4096,
+                     1024, 16)
+        grouped_case("grouped_matmul(16 x 2048 x 7680)", 16, 2048, 7680,
+                     1024, 16)
 
     if len(devices) >= 4:
         from client_tpu.parallel.kv_shard import (

@@ -34,6 +34,10 @@ generation streams. Design, TPU-first:
   The scheduler learns all of this from the contract a served decoder
   declares (``models/decoder.py`` ``DecoderBackend``: every member has a
   default there), never from a model's name.
+- **What only the device can count** (a backend that declares
+  ``wave_stats``, names of ``spans.GEN_COUNTERS``): the decode program returns
+  that many int32 behind a wave's tokens (what a sparse expert layer routed),
+  one fetch brings both, and the counters move when the tokens arrive.
 - **Decode waves** (one jit per stream-count bucket) advance every live
   stream one token in a single XLA execution: gather input tokens from the
   device-side slots, scatter new K/V at each stream's position, masked
@@ -319,6 +323,10 @@ class GenerativeScheduler(Scheduler):
         # two waves.
         self._piece_len, self._piece_lanes = backend.prefill_piece or (0, 0)
         self._cache_rows = backend.cache_rows
+        # Counters only the device can fill (``wave_stats``): that many
+        # int32 ride behind a decode wave's tokens.
+        self._wave_stats = [_sp.GEN_COUNTERS.index(name)
+                            for name in backend.wave_stats]
         self._transition_due = backend.transition_due
         self._transition = None
         if self._transition_due is not None:
@@ -964,6 +972,12 @@ class GenerativeScheduler(Scheduler):
                 self._reset_arena(exc)
                 break
             drained = True
+            if self._wave_stats and head.kind in ("wave", "chunk"):
+                n = len(self._wave_stats)
+                stats = toks[..., -n:].reshape(-1, n).sum(axis=0)
+                toks = toks[..., :-n]
+                for i, v in zip(self._wave_stats, stats.tolist()):
+                    c[i] += v
             # Wave timing: the device ran this dispatch from
             # max(its dispatch, the previous fetch) until now — pipelined
             # waves complete back to back, so the deltas between

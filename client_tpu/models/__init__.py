@@ -38,6 +38,16 @@ def register_model(name: str, default: bool = True):
     return deco
 
 
+@register_model("pangu_moe", default=False)
+def _pangu_moe() -> ModelBackend:
+    """The sparse-expert decoder with a latent cache, at its tiny preset.
+    Opt-in, and imported when it is built: a launch of any other model
+    imports none of it (models/pangu_moe.py, ops/grouped_matmul.py)."""
+    from client_tpu.models.pangu_moe import PanguMoeBackend
+
+    return PanguMoeBackend()
+
+
 def model_names() -> list[str]:
     _import_all()
     return sorted(_REGISTRY)

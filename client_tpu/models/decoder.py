@@ -12,7 +12,13 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
 - ``max_streams``, ``max_seq_len``, ``vocab``, ``default_max_tokens``.
 - ``init_arena(capacity)``: the cache pytree ``{"k", "v": [L, R, S, H*D],
   "tok": [R]}``, donated into every program.  What a slot's ``S`` rows hold is
-  the model's business.
+  the model's business, and so is which leaves there are: ``cache_leaves``
+  names those a decode step carries through its layers (``("k", "v")``; a
+  latent cache has one, ``("c",)``, ``[L, R, S, W]``).
+- ``wave_stats``: ``()``, or names of ``spans.GEN_COUNTERS`` that only the
+  device can count (what a wave's tokens were routed to): the decode program
+  then returns that many int32 behind its ``B`` tokens (``_wave_stats(x)``),
+  and the scheduler adds them to the counters when the wave's tokens arrive.
 - ``arena_rows(capacity)`` -> (free rows, dummy row) and ``kv_shards`` (1).
 - ``prefill_piece``: ``None`` (a whole prompt a lane, one program a prompt
   bucket) or ``(positions, lanes)`` (a prompt is consumed ``positions`` a
@@ -30,9 +36,16 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
   donated: ``*_static_argnums`` and ``donate_argnums`` say so by position.
 
 **The parts a model supplies** (``B`` lanes of a wave; ``lp`` one layer's
-weights; ``li`` its index, a Python int or a traced scalar):
-``_embed(p, tokens, pos)`` -> x ``[B, d]``; ``_qkv(lp, x, pos)`` -> q, k, v
-``[B, H, D]``; ``_after_attention(lp, x, o)`` -> x; ``_logits(p, x)`` and,
+weights; ``li`` its index, a Python int or a traced scalar; ``x`` the model's
+own carry between layers: activations ``[B, d]``, or a pytree where a layer
+hands on more than those, as models/pangu_moe.py's routing counts):
+``_embed(p, tokens, pos)`` -> x; ``_qkv(lp, x, pos)`` -> q, k, v ``[B, H,
+D]`` or, with ``latent_attention = (value lanes, score scale)`` declared (one
+shared row a position, ops/decode_kernel.py ``latent_wave_attention``), q
+``[B, H, W]`` and the new row ``[B, W]``, and ``_attention_output(lp, o)``
+taking the rows' weighted sum ``[B, H, value lanes]`` to what
+``_after_attention`` reads; ``_after_attention(lp, x, o)`` -> x;
+``_logits(p, x)`` and,
 where they are not ``[B, vocab]``, ``_served(logits)`` picking those tokens
 are sampled from; ``_walk_layers(p, body, carry)`` folding ``body(carry, lp,
 li)`` over the layers (an unrolled loop, a ``lax.scan``); ``_live_rows(lens)``,
@@ -113,6 +126,9 @@ class DecoderBackend(ModelBackend):
     generative = True
 
     prefill_piece: tuple[int, int] | None = None
+    cache_leaves: tuple[str, ...] = ("k", "v")
+    latent_attention: tuple[int, float] | None = None
+    wave_stats: tuple[str, ...] = ()
     cache_rows = None
     transition_due = None
     transition_fn = None
@@ -238,13 +254,36 @@ class DecoderBackend(ModelBackend):
         reads rows ``0 .. live[b]``.  The kernel is one Pallas grid over the
         donated arena (with ``kv_shards > 1`` its shard_map form over the
         row-sharded arena); ``layer`` may be traced (a decoder that scans
-        its layers) except over shards."""
+        its layers) except over shards.  With ``latent_attention`` declared:
+        ``attend(c_arena, q, new_row, rows, live, layer)`` -> (c_arena, o),
+        the one leaf's kernel or its oracle."""
         from client_tpu.engine.backend_init import pallas_interpret
         from client_tpu.ops.decode_kernel import (decode_wave_attention,
-                                                  reference_decode_attention)
+                                                  latent_wave_attention,
+                                                  reference_decode_attention,
+                                                  reference_latent_attention)
 
         interpret, block_s = pallas_interpret(), self.decode_block_s
-        if not self._use_kernel():
+        if self.latent_attention is not None:
+            value_dim, sm_scale = self.latent_attention
+            if self.kv_shards > 1:
+                raise NotImplementedError(
+                    "a latent cache has one key/value head: there is "
+                    "nothing to shard by rows' heads (kv_shards > 1)")
+            kernel = self._use_kernel()
+
+            def attend(c_a, q, new, rows, live, layer):
+                if not kernel:
+                    return reference_latent_attention(
+                        c_a, q, new, rows, live, layer=layer,
+                        value_dim=value_dim, sm_scale=sm_scale)
+                static = isinstance(layer, int)
+                return latent_wave_attention(
+                    c_a, q, new, rows, live, value_dim=value_dim,
+                    sm_scale=sm_scale, layer=layer if static else None,
+                    layer_index=None if static else layer,
+                    block_s=block_s, interpret=interpret)
+        elif not self._use_kernel():
             def attend(k_a, v_a, q, k, v, rows, live, layer):
                 return reference_decode_attention(
                     k_a, v_a, q, k, v, rows, live, layer=layer)
@@ -302,29 +341,49 @@ class DecoderBackend(ModelBackend):
 
     # -- the decode step ------------------------------------------------------
 
-    def decode_logits_fn(self):
-        """(params, arena, rows[B], lens[B]) -> (arena, logits as
-        ``_logits`` leaves them).  One decode step: each lane's input token
-        is GATHERED from its slot's device-side token (written by prefill /
-        the previous wave), its position is its context length ``lens[b]``;
-        each layer writes the lane's new key/value row behind the slot's
-        live rows and reads them all (``_decode_attend``)."""
+    def _attention_output(self, lp, o):
+        return o
+
+    def _wave_stats(self, x):
+        raise NotImplementedError
+
+    def _decode_hidden_fn(self):
+        """(params, arena, rows[B], lens[B]) -> (arena, x after the last
+        layer).  One decode step: each lane's input token is GATHERED from
+        its slot's device-side token (written by prefill / the previous
+        wave), its position is its context length ``lens[b]``; each layer
+        writes the lane's new cache row behind the slot's live rows and
+        reads them all (``_decode_attend``), whatever leaves the cache has
+        (``cache_leaves``)."""
         attend = self._decode_attend()
+        names = self.cache_leaves
 
         def step(p, arena, rows, lens):
             live = self._live_rows(lens)
             tokens = arena["tok"][rows]
 
             def layer(carry, lp, li):
-                x, k_a, v_a = carry
-                q, k, v = self._qkv(lp, x, lens)
-                k_a, v_a, o = attend(k_a, v_a, q, k, v, rows, live, li)
-                return self._after_attention(lp, x, o), k_a, v_a
+                x, *cache = carry
+                *cache, o = attend(*cache, *self._qkv(lp, x, lens), rows,
+                                   live, li)
+                return (self._after_attention(
+                    lp, x, self._attention_output(lp, o)), *cache)
 
-            x, k_a, v_a = self._walk_layers(
-                p, layer,
-                (self._embed(p, tokens, lens), arena["k"], arena["v"]))
-            return {**arena, "k": k_a, "v": v_a}, self._logits(p, x)
+            x, *cache = self._walk_layers(
+                p, layer, (self._embed(p, tokens, lens),
+                           *(arena[name] for name in names)))
+            return {**arena, **dict(zip(names, cache))}, x
+
+        return step
+
+    def decode_logits_fn(self):
+        """``_decode_hidden_fn`` and the logits as ``_logits`` leaves
+        them: (params, arena, rows[B], lens[B]) -> (arena, logits)."""
+        hidden = self._decode_hidden_fn()
+
+        def step(p, arena, rows, lens):
+            arena, x = hidden(p, arena, rows, lens)
+            return arena, self._logits(p, x)
 
         return step
 
@@ -334,15 +393,23 @@ class DecoderBackend(ModelBackend):
         consecutive waves chain on the device with no host round trip — the
         scheduler dispatches waves ahead and fetches tokens asynchronously.
         The context at sampling is ``lens + 1`` (the token just written
-        occupies position ``lens``): prefill's fold sequence, continued."""
-        step = self.decode_logits_fn()
+        occupies position ``lens``): prefill's fold sequence, continued.
+        A backend that declares ``wave_stats`` returns them behind the
+        tokens, ``[B + len(wave_stats)]``: one fetch brings both."""
+        import jax.numpy as jnp
+
+        hidden = self._decode_hidden_fn()
 
         def decode(p, arena, rows, lens, seeds, temps, top_ks, top_ps,
                    sample=True):
-            arena, logits = step(p, arena, rows, lens)
-            return sample_into_slots(arena, rows, self._served(logits),
-                                     seeds, lens + 1, temps, top_ks, top_ps,
-                                     sample)
+            arena, x = hidden(p, arena, rows, lens)
+            arena, tokens = sample_into_slots(
+                arena, rows, self._served(self._logits(p, x)), seeds,
+                lens + 1, temps, top_ks, top_ps, sample)
+            if self.wave_stats:
+                tokens = jnp.concatenate(
+                    [tokens, self._wave_stats(x).astype(tokens.dtype)])
+            return arena, tokens
 
         return decode
 

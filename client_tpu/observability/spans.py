@@ -94,6 +94,12 @@ GEN_COUNTERS = (
     # per one-shot prefill dispatch: lanes holding a prompt, and lanes padded
     # up to the program's lane count
     "prefill_lanes_live", "prefill_lanes_padded",
+    # What only the device can count, for a backend that declares
+    # ``wave_stats`` (0 for every other): a sparse expert layer's routing,
+    # summed over the layers and the fetched waves: the (token, expert) pairs
+    # whose expert is held here, the busiest held expert's pairs (a layer's
+    # largest group), and the held experts that got at least one token.
+    "expert_pairs_local", "expert_pairs_busiest", "experts_touched",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
@@ -101,7 +107,8 @@ GEN_COUNTERS = (
  C_FIRST_TOKEN_INFLIGHT_WAVES, C_FETCHED_ROWS_EXACT, C_FETCHED_ROWS_SUMMARY,
  C_PROMPTS_ADMITTED, C_PREFILL_PIECES, C_TRANSITIONS, C_EMIT_HANDOFFS,
  C_EMITTED_TOKENS, C_EMITTED_TOKENS_CALLBACK, C_PREFILL_LANES_LIVE,
- C_PREFILL_LANES_PADDED) = range(len(GEN_COUNTERS))
+ C_PREFILL_LANES_PADDED, C_EXPERT_PAIRS_LOCAL, C_EXPERT_PAIRS_BUSIEST,
+ C_EXPERTS_TOUCHED) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

@@ -302,3 +302,190 @@ def reference_decode_attention(k_arena, v_arena, q, k_new, v_new, rows,
     scores = jnp.where(mask[:, None, :], scores, _NEG_INF)
     o = jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(scores), cv)
     return k_arena, v_arena, o
+
+
+# -- latent rows: one shared key/value row a position -------------------------
+
+def latent_row_width(rank: int, rope_dim: int) -> int:
+    """Lanes of a latent cache row ``[c (rank) | k_r (rope_dim) | 0]``: the
+    two parts side by side, padded to whole 128-lane tiles (512 + 64 -> 640).
+    One leaf and not two (512 and 128): the bytes are the same, and one leaf
+    is one block DMA a grid step, one score matmul over the row and one row
+    group written a lane; the zero lanes cost a ninth of the row's reads."""
+    return -(-(rank + rope_dim) // 128) * 128
+
+
+def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
+                   value_dim: int, sm_scale: float):
+    """One (lane, row-block) grid step of ``latent_wave_attention``: as
+    ``_decode_kernel`` but every head reads the same row, whose first
+    ``value_dim`` lanes are also the value."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if layer is None:
+        layer = refs[0][0]
+        refs = refs[1:]
+    (c_ref, q_ref, new_ref,                             # inputs
+     co_ref, o_ref,                                     # outputs
+     m_ref, l_ref, acc_ref, buf, sem) = refs            # scratch
+    b = pl.program_id(0)
+    ik = pl.program_id(1)
+    nk = pl.num_programs(1)
+    row = rows_ref[b]
+    length = lens_ref[b]                 # valid prefix length (strict)
+    group = buf.shape[0]
+    g0 = pl.multiple_of((length // group) * group, group)
+    hbm = co_ref.at[layer, row, pl.ds(g0, group)]
+    cache_dtype = c_ref.dtype
+    highest = (jax.lax.Precision.HIGHEST if cache_dtype == jnp.float32
+               else None)
+    q = (q_ref[0] * sm_scale).astype(cache_dtype)        # [Hp, W]
+
+    @pl.when(ik == 0)
+    def _init():
+        pltpu.make_async_copy(hbm, buf, sem.at[0]).start()
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(ik * block_s < length)
+    def _block():
+        blk = c_ref[...]                                 # [block_s, W]
+        s = jax.lax.dot_general(
+            q, blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=highest)
+        pos = ik * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = pos < length                             # [Hp, block_s]
+        s = jnp.where(valid, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p.astype(cache_dtype), blk[:, :value_dim],
+            preferred_element_type=jnp.float32,
+            precision=highest)                           # [Hp, V]
+
+    @pl.when(ik == nk - 1)
+    def _finalize():
+        # The new row as the cache will hold it, folded in from registers
+        # (position ``length``: always valid, so a padded lane with an empty
+        # prefix reads exactly its own row and never divides by zero).
+        new_c = new_ref[0].astype(cache_dtype)           # [1, W]
+        new = new_c.astype(jnp.float32)
+        s_new = jnp.sum(q.astype(jnp.float32) * new, axis=1, keepdims=True)
+        m_fin = jnp.maximum(m_ref[...], s_new)
+        p_new = jnp.exp(s_new - m_fin)
+        corr = jnp.exp(m_ref[...] - m_fin)
+        l_fin = l_ref[...] * corr + p_new
+        o_ref[0] = ((acc_ref[...] * corr + p_new * new[:, :value_dim])
+                    / l_fin).astype(o_ref.dtype)
+        # The one write into the arena: the new row, inside its row group.
+        pltpu.make_async_copy(hbm, buf, sem.at[0]).wait()
+        ins = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0) == length - g0
+        buf[...] = jnp.where(ins, new_c, buf[...])
+        back = pltpu.make_async_copy(buf, hbm, sem.at[1])
+        back.start()
+        back.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("layer", "value_dim", "sm_scale",
+                                             "block_s", "interpret"))
+def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
+                          value_dim: int, sm_scale: float,
+                          block_s: int | None = None,
+                          interpret: bool = False, layer_index=None):
+    """One layer's decode wave over a **latent** cache: one row a position,
+    shared by every head (multi-head latent attention with the key/value
+    up-projection absorbed into the query and the output).
+
+    c_arena ``[L, R, S, W]`` (float32 or bfloat16), a row ``[c | k_r | 0]``;
+    q ``[B, H, W]`` float32, head h's ``[q_nope W_kb | q_rope | 0]``;
+    new_row ``[B, W]``; rows/lens ``[B]`` int32.  Returns ``(c_arena, o)``:
+    the new row written at ``(layer, rows[b], lens[b])`` in place and ``o
+    [B, H, value_dim]`` float32, ``softmax(q . row * sm_scale)`` over rows
+    ``0 .. lens[b]`` inclusive applied to the rows' first ``value_dim`` lanes.
+    Grid, scalar prefetch, skipped blocks, the row-group write and the
+    folded-in new row are ``decode_wave_attention``'s; the products differ:
+    ``scores [H, s] = Q [H, W] @ C_blk^T`` and ``acc [H, V] += p @ C_blk[:,
+    :V]``, so a block is read once for all heads and nothing is
+    block-diagonal."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, _, s, w = c_arena.shape
+    bsz, h, wq = q.shape
+    if wq != w or new_row.shape != (bsz, w):
+        raise ValueError(f"cache rows hold {w} lanes, q {wq}, the new row "
+                         f"{new_row.shape}")
+    if block_s is None:
+        block_s = pick_block_s(s)
+    if s % block_s:
+        raise ValueError(f"block_s ({block_s}) must divide the slot's rows "
+                         f"({s})")
+    hp = -(-h // 8) * 8
+    if hp != h:
+        q = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
+    group = math.gcd(s, row_group(c_arena.dtype))
+    dynamic = layer is None
+    prefetch = (rows, lens) + (
+        (jnp.asarray(layer_index, jnp.int32).reshape(1),) if dynamic else ())
+
+    def arena_map(b, ik, rows, lens, *li):
+        last = jnp.maximum(lens[b] - 1, 0) // block_s
+        return (li[0][0] if dynamic else layer, rows[b],
+                jnp.minimum(ik, last), 0)
+
+    def lane_map(b, ik, rows, lens, *li):
+        return (b, 0, 0)
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(bsz, s // block_s),
+        in_specs=[pl.BlockSpec((None, None, block_s, w), arena_map),
+                  pl.BlockSpec((1, hp, w), lane_map),
+                  pl.BlockSpec((1, 1, w), lane_map)],
+        out_specs=[in_hbm, pl.BlockSpec((1, hp, value_dim), lane_map)],
+        scratch_shapes=[
+            pltpu.VMEM((hp, 1), jnp.float32),          # running max
+            pltpu.VMEM((hp, 1), jnp.float32),          # running denominator
+            pltpu.VMEM((hp, value_dim), jnp.float32),  # weighted accumulator
+            pltpu.VMEM((group, w), c_arena.dtype),     # the new row's group
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    kernel = functools.partial(_latent_kernel, layer=layer, block_s=block_s,
+                               value_dim=value_dim, sm_scale=sm_scale)
+    block_bytes = block_s * w * c_arena.dtype.itemsize
+    c_out, o = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(c_arena.shape, c_arena.dtype),
+                   jax.ShapeDtypeStruct((bsz, hp, value_dim), jnp.float32)],
+        input_output_aliases={len(prefetch): 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(100 << 20, 6 * block_bytes + (24 << 20))),
+        interpret=interpret,
+        name="latent_wave_attention",
+    )(*prefetch, c_arena, q, new_row.reshape(bsz, 1, w))
+    return c_out, o[:, :h]
+
+
+def reference_latent_attention(c_arena, q, new_row, rows, lens, *, layer,
+                               value_dim: int, sm_scale: float):
+    """XLA oracle of ``latent_wave_attention``: scatter the new row, gather
+    each lane's slot, dense masked softmax over ``pos <= len`` in float32
+    over the values the cache holds."""
+    s = c_arena.shape[2]
+    c_arena = c_arena.at[layer, rows, lens].set(new_row.astype(c_arena.dtype))
+    c = c_arena[layer, rows].astype(jnp.float32)             # [B, S, W]
+    scores = jnp.einsum("bhw,bsw->bhs", q, c) * sm_scale
+    mask = jnp.arange(s)[None, :] <= lens[:, None]
+    scores = jnp.where(mask[:, None, :], scores, _NEG_INF)
+    o = jnp.einsum("bhs,bsv->bhv", jax.nn.softmax(scores),
+                   c[..., :value_dim])
+    return c_arena, o

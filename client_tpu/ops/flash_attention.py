@@ -200,15 +200,23 @@ def _fa_kernel(*refs, block_q: int, block_k: int, sub_q: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "block_q", "block_k", "interpret", "prefix", "n_heads",
-    "sub_q"))
+    "sub_q", "sm_scale"))
 def flash_attention(q, k, v, bias=None, *, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False, prefix: int = 0,
-                    n_heads: int | None = None, sub_q: int | None = None):
+                    n_heads: int | None = None, sub_q: int | None = None,
+                    sm_scale: float | None = None):
     """Memory-efficient attention.  q: ``[B, S, H*D]`` with ``n_heads=H``
     (as ``h @ wq`` leaves it), or ``[B, S, H, D]``; k/v likewise with ``S_k =
     prefix + S`` positions; bias: additive [B, S_k] key mask (0 = attend,
     -inf/-1e9 = masked) or None.  Returns q's shape.
+
+    **Values of another width than the keys** (multi-head latent attention
+    before its up-projection is absorbed: q and k ``H x 192`` padded to ``H x
+    256``, v ``H x 128``): v is ``[B, S_k, H*Dv]`` and so is what comes back,
+    where both widths fill whole 128-lane tiles (a head is then its own
+    tile, of either operand).  ``sm_scale`` replaces ``1 / sqrt(D)`` where
+    the scores' scale is not the operand's width (the padding above).
 
     ``prefix`` = 0 is self-attention (same S for q and k).  With ``prefix``
     > 0 the first ``prefix`` keys are a prefix that **every** query sees
@@ -225,16 +233,22 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    shape = q.shape
+    shape = q.shape[:-1] + v.shape[-1:]
     if q.ndim == 4:
         n_heads = shape[2]
         q, k, v = (x.reshape(x.shape[0], x.shape[1], -1) for x in (q, k, v))
     elif n_heads is None:
         raise ValueError("[B, S, H*D] operands need n_heads")
     b, s, hd = q.shape
-    if hd % n_heads:
-        raise ValueError(f"{hd} features do not hold {n_heads} heads")
-    d = hd // n_heads
+    hd_v = v.shape[-1]
+    if hd % n_heads or hd_v % n_heads:
+        raise ValueError(f"{hd} (values {hd_v}) features do not hold "
+                         f"{n_heads} heads")
+    d, d_v = hd // n_heads, hd_v // n_heads
+    if d_v != d and (d % _LANES or d_v % _LANES):
+        raise ValueError(
+            f"values of another width than the keys ({d_v} against {d}) "
+            f"need both to fill whole {_LANES}-lane tiles")
     s_k = k.shape[1]
     if s_k != prefix + s:
         raise ValueError(
@@ -253,6 +267,7 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
         raise ValueError(f"pieces of {sub_q} queries must divide the block "
                          f"({block_q})")
     tile = head_tile(n_heads, d)
+    tile_v = tile if d_v == d else d_v
 
     def k_block(qi, ki):
         if not causal:
@@ -261,11 +276,18 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
         # the pipeline takes as unchanged and does not fetch.
         return jnp.minimum(ki, (prefix + (qi + 1) * block_q - 1) // block_k)
 
-    q_spec = pl.BlockSpec((1, block_q, tile),
-                          lambda bi, gi, qi, ki: (bi, qi, gi))
-    kv_spec = pl.BlockSpec((1, block_k, tile),
-                           lambda bi, gi, qi, ki: (bi, k_block(qi, ki), gi))
-    operands, in_specs = [q, k, v], [q_spec, kv_spec, kv_spec]
+    def spec(rows, lanes, row_of):
+        return pl.BlockSpec((1, rows, lanes), lambda bi, gi, qi, ki: (
+            bi, row_of(qi, ki), gi))
+
+    def q_block(qi, ki):
+        return qi
+
+    q_spec, o_spec = spec(block_q, tile, q_block), spec(block_q, tile_v,
+                                                        q_block)
+    kv_spec, v_spec = spec(block_k, tile, k_block), spec(block_k, tile_v,
+                                                         k_block)
+    operands, in_specs = [q, k, v], [q_spec, kv_spec, v_spec]
     if bias is not None:
         # [B, 1, S_k]: the unit middle dim makes the (1, 1, block_k) bias
         # block a legal TPU tile (trailing dims equal-or-aligned to the
@@ -276,20 +298,21 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
             lambda bi, gi, qi, ki: (bi, 0, k_block(qi, ki))))
     kernel = functools.partial(
         _fa_kernel, block_q=block_q, block_k=block_k, sub_q=sub_q,
-        grid_qk=grid_qk, causal=causal, sm_scale=1.0 / np.sqrt(d),
+        grid_qk=grid_qk, causal=causal,
+        sm_scale=1.0 / np.sqrt(d) if sm_scale is None else sm_scale,
         prefix=prefix, head_dim=d, has_bias=bias is not None)
     heads = tile // d
     out = pl.pallas_call(
         kernel,
         grid=(b, hd // tile) + grid_qk,
         in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, hd), q.dtype),
+        out_specs=o_spec,
+        out_shape=jax.ShapeDtypeStruct((b, s, hd_v), q.dtype),
         # The carry across key blocks, where there is more than one.
         scratch_shapes=[] if grid_qk[1] == 1 else [
             pltpu.VMEM((heads, block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((heads, block_q, 1), jnp.float32),   # denominator
-            pltpu.VMEM((heads, block_q, tile), jnp.float32),  # accumulator
+            pltpu.VMEM((heads, block_q, tile_v), jnp.float32),  # accumulator
         ],
         interpret=interpret,
     )(*operands)

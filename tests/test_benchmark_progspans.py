@@ -426,13 +426,18 @@ class TestHandoffReaders:
         still = snap({}, HANDOFF_AFTER)
         assert read({"snap_before": still, "snap_after": still}) is None
 
-    def test_the_manifest_ends_with_them(self):
+    def test_the_manifest_holds_them_side_by_side(self):
+        """Where PR 31 appended them (later entries stand behind), each for
+        the cells it named then and whatever cells joined since."""
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
-        tail = manifest["per_layer"][-len(HANDOFF_METRICS):]
+        names = [m["name"] for m in manifest["per_layer"]]
+        first = names.index(next(iter(HANDOFF_METRICS)))
+        tail = manifest["per_layer"][first:first + len(HANDOFF_METRICS)]
         assert [m["name"] for m in tail] == list(HANDOFF_METRICS)
         for m in tail:
-            assert m["workloads"] == HANDOFF_METRICS[m["name"]][0]
+            cells = HANDOFF_METRICS[m["name"]][0]
+            assert m["workloads"][:len(cells)] == cells
             assert (m["layer"], m["moves"], m["source"], m["unit"]) == (
                 "generative scheduler", "itl_mean_ms", "program_counter", "%")
         from client_tpu.observability import spans

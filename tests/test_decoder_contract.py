@@ -17,14 +17,14 @@ from client_tpu.models.decoder import (DECODE_ARGS, DECODE_CHUNK_ARGS,
                                        PREFILL_ARGS, DecoderBackend)
 
 SERVED = ["tiny_gpt", "tiny_gpt_long", "tiny_gpt_oracle", "evabyte",
-          "tiny_gpt_mc", "moe_gpt_mc"]
+          "tiny_gpt_mc", "moe_gpt_mc", "pangu_moe"]
 # What `GenerativeScheduler.__init__` reads of a backend before it starts its
 # worker; a backend that hides one cannot be scheduled.
 READ_AT_CONSTRUCTION = ["max_streams", "max_seq_len", "arena_rows",
                         "init_arena", "prefill_fn", "decode_fn",
                         "donate_argnums", "prefill_static_argnums",
                         "decode_static_argnums", "prefill_piece",
-                        "cache_rows", "transition_due"]
+                        "cache_rows", "transition_due", "wave_stats"]
 
 
 @pytest.fixture(scope="module")
@@ -53,6 +53,8 @@ def test_a_served_decoder_declares_the_whole_contract(engines, name):
         backend.prefill_piece or (0, 0))
     assert sched._cache_rows == backend.cache_rows
     assert sched._transition_due == backend.transition_due
+    assert len(sched._wave_stats) == len(backend.wave_stats)
+    assert set(backend.cache_leaves) < set(sched._arena)
     assert (sched._transition is None) == (backend.transition_fn is None)
     assert sched.arena_shards() == backend.kv_shards
     # `sample`, `k` and the arena stand where the argument lists say.

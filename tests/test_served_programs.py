@@ -1,0 +1,126 @@
+"""The programs of the served decoders the benchmark already measures are the
+ones they were: what ``models/decoder.py``, ``ops/decode_kernel.py`` and
+``ops/flash_attention.py`` gain for a new decoder (cache leaves a backend
+names, a latent kernel, values of another width than the keys, a wave's
+counts behind its tokens) changes nothing that ``gpt2_small`` or
+``evabyte_6b5`` runs.
+
+For each family's decode wave and prefill (or piece) program at a tiny preset,
+lowered for the TPU with the kernels in: the StableHLO with the kernels' bodies
+taken out (a body carries the source path of the checkout) and, apart, the
+Pallas kernels' own jaxprs, each against the hash recorded at commit 6b8c7c9
+(PR 31).  A PR that means to change one of these programs records the new
+hash here and says so; one that does not has a guard.
+
+    python - <<'X'          # to record: run from the repo root
+    import tests.test_served_programs as t; t.record()
+    X
+"""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+RECORDED = {
+    ("evabyte", "decode"): ("f9e9e642bfd4a463", "56eee8a35b23e421"),
+    ("evabyte", "prefill"): ("d6eccbae5564229b", "d3dffbbfe8efac92"),
+    ("gpt", "decode"): ("92758237abe83ac2", "61153d74d471a1af"),
+    ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),
+}
+
+
+def _backend(family):
+    if family == "evabyte":
+        from client_tpu.models.evabyte import EvaByteBackend
+
+        return EvaByteBackend(seed=3, max_seq_len=128, window=32, chunk=4,
+                              attention_impl="flash", attn_impl="fused",
+                              prefill_lanes=2)
+    from client_tpu.models.generate import TinyGptBackend
+
+    return TinyGptBackend(attention_impl="flash", attn_impl="fused")
+
+
+def _program(family, which):
+    """(function, static and donated argument numbers, abstract
+    arguments)."""
+    be = _backend(family)
+    params = jax.eval_shape(be._init_params)
+    arena = jax.eval_shape(lambda: be.init_arena(4))
+
+    def i32(*s):
+        return jax.ShapeDtypeStruct(s, jnp.int32)
+
+    def f32(*s):
+        return jax.ShapeDtypeStruct(s, jnp.float32)
+
+    if which == "decode":
+        return be.decode_fn(), (be.decode_static_argnums,
+                                be.donate_argnums), (
+            params, arena, i32(4), i32(4), i32(4), f32(4), i32(4), f32(4),
+            False)
+    piece = be.prefill_piece
+    lanes, width = (piece[1], piece[0]) if piece else (2, 64)
+    args = (params, arena, i32(lanes), i32(lanes, width), i32(lanes),
+            i32(lanes), f32(lanes), i32(lanes), f32(lanes), False)
+    return be.prefill_fn(), (be.prefill_static_argnums,
+                             be.donate_argnums), args + (
+        (i32(lanes),) if piece else ())
+
+
+def _kernel_bodies(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(str(eqn.params["jaxpr"])
+                       + str(eqn.params["grid_mapping"].grid))
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _kernel_bodies(inner, out)
+    return out
+
+
+def _digest(text):
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def hashes(family, which):
+    fn, (static, donated), args = _program(family, which)
+    text = jax.jit(fn, static_argnums=static,
+                   donate_argnums=donated).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    bodies = _kernel_bodies(
+        jax.make_jaxpr(fn, static_argnums=static)(*args).jaxpr, [])
+    assert bodies and text.count("tpu_custom_call") >= 1
+    return (_digest(re.sub(r'backend_config = "[^"]*"',
+                           'backend_config = ""', text)),
+            _digest("".join(bodies)))
+
+
+@pytest.fixture()
+def on_the_chips_branches(monkeypatch):
+    from client_tpu.engine import backend_init
+
+    monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("part", ["program", "kernels"])
+@pytest.mark.parametrize("family,which", sorted(RECORDED))
+def test_the_program_is_the_one_it_was(on_the_chips_branches, family, which,
+                                       part):
+    got = hashes(family, which)[part == "kernels"]
+    assert got == RECORDED[family, which][part == "kernels"], (
+        f"{family}'s {which} {part} changed: if that is meant, record {got}")
+
+
+def record():
+    from client_tpu.engine import backend_init
+
+    backend_init.pallas_interpret = lambda: False
+    for key in sorted(RECORDED):
+        print(key, hashes(*key))

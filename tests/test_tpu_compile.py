@@ -307,3 +307,62 @@ def test_prefill_compiles_over_the_row_sharded_arena(topo, monkeypatch):
         per_chip_leaf = _leaf_bytes(arena) // 4
         assert memory.alias_size_in_bytes >= 2 * per_chip_leaf
         assert memory.temp_size_in_bytes < per_chip_leaf // 2, memory
+
+
+# -- the latent, sparse-expert decoder at its published widths (PR 32) -----------
+
+PANGU = dict(n_layers=5, n_dense=1, d_model=7680, n_heads=128, q_rank=1536,
+             kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128, d_ff=18432,
+             d_expert=2048, n_experts=256, experts_held=16, top_k=8,
+             vocab=19200, max_seq_len=4096, piece=512, max_streams=128,
+             attention_impl="flash")
+
+
+def _pangu_decode(one_chip, monkeypatch, bucket):
+    """``pangu_ultra_moe``'s ``jit_decode`` for one v5e chip from shapes
+    alone (13.2 GB of weights and cache that nothing allocates)."""
+    from client_tpu.engine import backend_init
+    from client_tpu.models.pangu_moe import PanguMoeBackend
+    from client_tpu.observability import spans
+
+    monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
+    place = _on(one_chip)
+    backend = PanguMoeBackend(name="p", **PANGU)
+    params = jax.tree_util.tree_map(
+        lambda leaf: place(leaf.shape, jnp.dtype(leaf.dtype)),
+        backend._init_params())
+    arena = jax.tree_util.tree_map(
+        lambda a: place(a.shape, a.dtype),
+        jax.eval_shape(lambda: backend.init_arena(backend.max_streams)))
+    lanes_i, lanes_f = place((bucket,), jnp.int32), place((bucket,),
+                                                          jnp.float32)
+    step = jax.jit(spans.named_step(backend.decode_fn(), spans.STEP_DECODE),
+                   donate_argnums=backend.donate_argnums,
+                   static_argnums=backend.decode_static_argnums)
+    compiled = step.lower(params, arena, lanes_i, lanes_i, lanes_i, lanes_f,
+                          lanes_i, lanes_f, False).compile()
+    return compiled, arena, params
+
+
+def test_latent_expert_decode_step_compiles_at_published_widths(
+        one_chip, monkeypatch):
+    """A full wave of 128 lanes: the latent kernel (128 heads on a 640-lane
+    row) and the grouped matmuls (16 experts of 7680 x 4096 and 2048 x 7680)
+    compile under Mosaic's memory limits, the donated cache is updated in
+    place, no expert matrix is copied in front of a kernel, and what is
+    returned is the tokens and the wave's three counts."""
+    compiled, arena, params = _pangu_decode(one_chip, monkeypatch, 128)
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w+)\.\d+ = [^=]*? custom-call\(", text)
+    assert calls.count("latent_wave_attention") == 5
+    assert calls.count("grouped_matmul") == 8
+    memory = compiled.memory_analysis()
+    if memory is not None:
+        cache = math.prod(arena["c"].shape) * 2
+        assert memory.alias_size_in_bytes >= cache
+        # Compiled temporaries: activations and the sorted layout's rows,
+        # far under one expert layer's 1.5 GB of matrices.
+        assert memory.temp_size_in_bytes < 0.5e9
+    experts = r"bf16\[16,7680,4096\]|bf16\[16,2048,7680\]"
+    assert not re.findall(r"= (?:" + experts + r")[^=]*? copy\(", text)
+    assert "s32[131]" in text          # 128 tokens and three counts
