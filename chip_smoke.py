@@ -1092,16 +1092,16 @@ def kernels_child(rehearsal: bool) -> int:
         ks = jax.random.split(jax.random.PRNGKey(17), 3)
         c_a = jax.random.normal(ks[0], (layers, rows, seq, w)).astype(
             jnp.bfloat16)
-        # Queries as the arena's dtype holds them, so kernel and oracle
-        # multiply the same numbers.
-        q = jax.random.normal(ks[1], (bsz, heads, w)).astype(
-            jnp.bfloat16).astype(jnp.float32)
+        # The query as a model hands it over: ``[B, W, H]``, scaled, in the
+        # arena's dtype, so kernel and oracle multiply the same numbers.
+        q = (jax.random.normal(ks[1], (bsz, w, heads)) / 32).astype(
+            jnp.bfloat16)
         new = jax.random.normal(ks[2], (bsz, w))
         rows_ix = np.arange(bsz, dtype=np.int32) * 2 % (rows - 1)
         rows_ix[-1] = rows - 1
         lens = np.asarray(lens_list, np.int32)
         lens[-1] = 0
-        layer, kw = layers - 1, {"value_dim": rank, "sm_scale": 1.0 / 32}
+        layer, kw = layers - 1, {"value_dim": rank}
         fn = functools.partial(latent_wave_attention, layer=layer,
                                interpret=interpret, **kw)
         args = (c_a, q, new, jnp.asarray(rows_ix), jnp.asarray(lens))

@@ -40,11 +40,15 @@ weights; ``li`` its index, a Python int or a traced scalar; ``x`` the model's
 own carry between layers: activations ``[B, d]``, or a pytree where a layer
 hands on more than those, as models/pangu_moe.py's routing counts):
 ``_embed(p, tokens, pos)`` -> x; ``_qkv(lp, x, pos)`` -> q, k, v ``[B, H,
-D]`` or, with ``latent_attention = (value lanes, score scale)`` declared (one
-shared row a position, ops/decode_kernel.py ``latent_wave_attention``), q
-``[B, H, W]`` and the new row ``[B, W]``, and ``_attention_output(lp, o)``
-taking the rows' weighted sum ``[B, H, value lanes]`` to what
-``_after_attention`` reads; ``_after_attention(lp, x, o)`` -> x;
+D]`` or, with ``latent_attention = value lanes`` declared (one shared row a
+position, ops/decode_kernel.py ``latent_wave_attention``), the absorbed
+query as the kernel takes it, ``[B, W, H]`` in the cache's dtype with the
+score scale in it, and the new row ``[B, W]``, and ``_attention_output(lp,
+o)`` taking the rows' weighted sum as the kernel leaves it, ``[B, value
+lanes, H]`` float32, to what ``_after_attention`` reads (heads along the
+minor axis on both sides of the kernel: a model's einsums write and read
+that layout);
+``_after_attention(lp, x, o)`` -> x;
 ``_logits(p, x)`` and,
 where they are not ``[B, vocab]``, ``_served(logits)`` picking those tokens
 are sampled from; ``_walk_layers(p, body, carry)`` folding ``body(carry, lp,
@@ -127,7 +131,7 @@ class DecoderBackend(ModelBackend):
 
     prefill_piece: tuple[int, int] | None = None
     cache_leaves: tuple[str, ...] = ("k", "v")
-    latent_attention: tuple[int, float] | None = None
+    latent_attention: int | None = None
     wave_stats: tuple[str, ...] = ()
     cache_rows = None
     transition_due = None
@@ -265,7 +269,7 @@ class DecoderBackend(ModelBackend):
 
         interpret, block_s = pallas_interpret(), self.decode_block_s
         if self.latent_attention is not None:
-            value_dim, sm_scale = self.latent_attention
+            value_dim = self.latent_attention
             if self.kv_shards > 1:
                 raise NotImplementedError(
                     "a latent cache has one key/value head: there is "
@@ -276,11 +280,11 @@ class DecoderBackend(ModelBackend):
                 if not kernel:
                     return reference_latent_attention(
                         c_a, q, new, rows, live, layer=layer,
-                        value_dim=value_dim, sm_scale=sm_scale)
+                        value_dim=value_dim)
                 static = isinstance(layer, int)
                 return latent_wave_attention(
                     c_a, q, new, rows, live, value_dim=value_dim,
-                    sm_scale=sm_scale, layer=layer if static else None,
+                    layer=layer if static else None,
                     layer_index=None if static else layer,
                     block_s=block_s, interpret=interpret)
         elif not self._use_kernel():

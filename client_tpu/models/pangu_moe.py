@@ -34,7 +34,9 @@ dropped, and an expert no token chose is not read.
 reads.  **Decode absorbs** the up-projection: ``q_lat = q_nope W_kb^T`` per
 head, scores ``[q_lat | q_rope] . row``, ``o_lat = p c``, ``o_h = o_lat
 W_vb`` (``latent_attention`` of models/decoder.py's contract; the kernel is
-ops/decode_kernel.py ``latent_wave_attention``).  **Prefill does not**: a
+ops/decode_kernel.py ``latent_wave_attention``, which takes the query and
+leaves ``o_lat`` with the heads along the minor axis: the two einsums write
+and read that layout).  **Prefill does not**: a
 piece of ``piece`` positions computes ``k_nope`` and ``v`` of its own rows and,
 from the cache, of the rows before it, and attends with the flash kernel
 (per head q and k ``nope + rope`` wide, padded to whole tiles, v ``v_dim``).
@@ -206,7 +208,7 @@ class PanguMoeBackend(DecoderBackend):
         qk = self.nope_dim + self.rope_dim
         self.qk_pad = -(-qk // 128) * 128 if qk > 128 else qk
         self.sm_scale = 1.0 / math.sqrt(qk)
-        self.latent_attention = (self.kv_rank, self.sm_scale)
+        self.latent_attention = self.kv_rank
         self.prefill_piece = (self.piece, 1)
 
     # -- params --------------------------------------------------------------
@@ -330,23 +332,27 @@ class PanguMoeBackend(DecoderBackend):
             axis=-1).astype(dtype)
 
     def _qkv(self, lp, x, pos):
-        """A wave's absorbed query and new row: ``q [B, H, W]`` = ``[q_nope
-        W_kb^T | q_rope | 0]`` and the row ``[B, W]``."""
+        """A wave's absorbed query and new row: ``q [B, W, H]``, column h
+        ``[q_nope W_kb^T | q_rope | 0] * sm_scale`` (scaled in float32, then
+        rounded to the cache's dtype: what the kernel multiplies), and the
+        row ``[B, W]``."""
         import jax.numpy as jnp
 
         # The wave's lanes stand where a sequence's positions would.
         q_nope, q_rope, c, k_r = self._queries_and_rows(lp, x["h"], pos)
-        q_lat = self._heads_mm("bhn,hnr->bhr", q_nope, lp["wkb"])
+        q_lat = self._heads_mm("bhn,hnr->brh", q_nope, lp["wkb"])
         pad = self.row_width - self.kv_rank - self.rope_dim
         q = jnp.concatenate(
-            [q_lat, q_rope,
-             jnp.zeros((*q_lat.shape[:2], pad), jnp.float32)], axis=-1)
-        return q, self._cache_rows_of(c, k_r, jnp.float32)
+            [q_lat, q_rope.swapaxes(1, 2),
+             jnp.zeros((q_lat.shape[0], pad, self.n_heads), jnp.float32)],
+            axis=1)
+        return ((q * self.sm_scale).astype(jnp.dtype(self.dtype)),
+                self._cache_rows_of(c, k_r, jnp.float32))
 
     def _attention_output(self, lp, o):
-        """``o_lat [B, H, kv_rank]`` -> ``concat_h(o_lat W_vb) [B, H *
+        """``o_lat [B, kv_rank, H]`` -> ``concat_h(o_lat W_vb) [B, H *
         v_dim]``."""
-        return self._heads_mm("bhr,hrv->bhv", o, lp["wvb"]).reshape(
+        return self._heads_mm("brh,hrv->bhv", o, lp["wvb"]).reshape(
             o.shape[0], self.n_heads * self.v_dim)
 
     def _keys_values(self, lp, c):

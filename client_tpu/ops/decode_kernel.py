@@ -316,10 +316,12 @@ def latent_row_width(rank: int, rope_dim: int) -> int:
 
 
 def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
-                   value_dim: int, sm_scale: float):
+                   value_dim: int):
     """One (lane, row-block) grid step of ``latent_wave_attention``: as
     ``_decode_kernel`` but every head reads the same row, whose first
-    ``value_dim`` lanes are also the value."""
+    ``value_dim`` lanes are also the value.  The heads run along the lanes:
+    scores ``[block_s, H]``, the softmax carry ``[1, H]``, the accumulator
+    ``[V, H]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -340,7 +342,6 @@ def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
     cache_dtype = c_ref.dtype
     highest = (jax.lax.Precision.HIGHEST if cache_dtype == jnp.float32
                else None)
-    q = (q_ref[0] * sm_scale).astype(cache_dtype)        # [Hp, W]
 
     @pl.when(ik == 0)
     def _init():
@@ -351,23 +352,25 @@ def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
 
     @pl.when(ik * block_s < length)
     def _block():
+        # Both products stream the block's rows past a stationary 128-wide
+        # operand: Q^T, then p.
         blk = c_ref[...]                                 # [block_s, W]
-        s = jax.lax.dot_general(
-            q, blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=highest)
-        pos = ik * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = pos < length                             # [Hp, block_s]
+        s = jnp.dot(blk, q_ref[0], preferred_element_type=jnp.float32,
+                    precision=highest)                   # [block_s, Hp]
+        pos = ik * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (block_s, 1), 0)
+        valid = pos < length
         s = jnp.where(valid, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev = m_ref[...]                              # [1, Hp]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(cache_dtype), blk[:, :value_dim],
-            preferred_element_type=jnp.float32,
-            precision=highest)                           # [Hp, V]
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=0, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            blk[:, :value_dim], p.astype(cache_dtype),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=highest)                           # [V, Hp]
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -375,13 +378,14 @@ def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
         # (position ``length``: always valid, so a padded lane with an empty
         # prefix reads exactly its own row and never divides by zero).
         new_c = new_ref[0].astype(cache_dtype)           # [1, W]
-        new = new_c.astype(jnp.float32)
-        s_new = jnp.sum(q.astype(jnp.float32) * new, axis=1, keepdims=True)
+        s_new = jnp.dot(new_c, q_ref[0], preferred_element_type=jnp.float32,
+                        precision=highest)               # [1, Hp]
         m_fin = jnp.maximum(m_ref[...], s_new)
         p_new = jnp.exp(s_new - m_fin)
         corr = jnp.exp(m_ref[...] - m_fin)
         l_fin = l_ref[...] * corr + p_new
-        o_ref[0] = ((acc_ref[...] * corr + p_new * new[:, :value_dim])
+        value = new_c[:, :value_dim].astype(jnp.float32).T   # [V, 1]
+        o_ref[0] = ((acc_ref[...] * corr + value * p_new)
                     / l_fin).astype(o_ref.dtype)
         # The one write into the arena: the new row, inside its row group.
         pltpu.make_async_copy(hbm, buf, sem.at[0]).wait()
@@ -392,43 +396,53 @@ def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
         back.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("layer", "value_dim", "sm_scale",
-                                             "block_s", "interpret"))
+@functools.partial(jax.jit, static_argnames=("layer", "value_dim", "block_s",
+                                             "interpret"))
 def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
-                          value_dim: int, sm_scale: float,
-                          block_s: int | None = None,
+                          value_dim: int, block_s: int | None = None,
                           interpret: bool = False, layer_index=None):
     """One layer's decode wave over a **latent** cache: one row a position,
     shared by every head (multi-head latent attention with the key/value
     up-projection absorbed into the query and the output).
 
     c_arena ``[L, R, S, W]`` (float32 or bfloat16), a row ``[c | k_r | 0]``;
-    q ``[B, H, W]`` float32, head h's ``[q_nope W_kb | q_rope | 0]``;
-    new_row ``[B, W]``; rows/lens ``[B]`` int32.  Returns ``(c_arena, o)``:
-    the new row written at ``(layer, rows[b], lens[b])`` in place and ``o
-    [B, H, value_dim]`` float32, ``softmax(q . row * sm_scale)`` over rows
-    ``0 .. lens[b]`` inclusive applied to the rows' first ``value_dim`` lanes.
-    Grid, scalar prefetch, skipped blocks, the row-group write and the
-    folded-in new row are ``decode_wave_attention``'s; the products differ:
-    ``scores [H, s] = Q [H, W] @ C_blk^T`` and ``acc [H, V] += p @ C_blk[:,
-    :V]``, so a block is read once for all heads and nothing is
-    block-diagonal."""
+    q ``[B, W, H]`` **in the cache's dtype**, column h head h's ``[q_nope
+    W_kb | q_rope | 0]`` times the score scale; new_row ``[B, W]``; rows/lens
+    ``[B]`` int32.  Returns ``(c_arena, o)``: the new row written at
+    ``(layer, rows[b], lens[b])`` in place and ``o [B, value_dim, H]``
+    float32, ``softmax(row . q)`` over rows ``0 .. lens[b]`` inclusive
+    applied to the rows' first ``value_dim`` lanes.  Grid, scalar prefetch,
+    skipped blocks and the row-group write are ``decode_wave_attention``'s;
+    the products differ.  A lane has only its 128 heads to put beside a
+    block of 512 rows, so the **block is the operand that streams through
+    the MXU** and the 128-wide one stands still: ``scores [s, H] = C_blk [s,
+    W] @ Q^T [W, H]`` and ``acc [V, H] += C_blk[:, :V]^T @ p [s, H]`` load 5
+    + 4 tiles of 128 x 128 a block with 512 rows past each, where heads as
+    rows loaded the block's 20 + 16 tiles with 128 rows past each.  The
+    heads lie along the lanes, so the softmax reduces down the sublanes
+    (adds of whole registers, no reduction across lanes) and its carry is a
+    ``[1, H]`` vector; the query arrives scaled and rounded, so no grid step
+    touches it.  Fewer heads than a lane tile are padded to one.  (On the
+    v5e a live block fell 1.46 -> 1.33 us and a layer's 1024 grid steps
+    without one 0.35 -> 0.30 ms; Mosaic transposes the block's value lanes
+    for the second product at 0.05 us a block: PERF.md section 6, PR 33.)"""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     _, _, s, w = c_arena.shape
-    bsz, h, wq = q.shape
-    if wq != w or new_row.shape != (bsz, w):
-        raise ValueError(f"cache rows hold {w} lanes, q {wq}, the new row "
-                         f"{new_row.shape}")
+    bsz, wq, h = q.shape
+    if wq != w or new_row.shape != (bsz, w) or q.dtype != c_arena.dtype:
+        raise ValueError(
+            f"cache rows hold {w} lanes of {c_arena.dtype}, q {wq} of "
+            f"{q.dtype}, the new row {new_row.shape}")
     if block_s is None:
         block_s = pick_block_s(s)
     if s % block_s:
         raise ValueError(f"block_s ({block_s}) must divide the slot's rows "
                          f"({s})")
-    hp = -(-h // 8) * 8
+    hp = -(-h // 128) * 128
     if hp != h:
-        q = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, hp - h)))
     group = math.gcd(s, row_group(c_arena.dtype))
     dynamic = layer is None
     prefetch = (rows, lens) + (
@@ -447,45 +461,46 @@ def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
         num_scalar_prefetch=len(prefetch),
         grid=(bsz, s // block_s),
         in_specs=[pl.BlockSpec((None, None, block_s, w), arena_map),
-                  pl.BlockSpec((1, hp, w), lane_map),
+                  pl.BlockSpec((1, w, hp), lane_map),
                   pl.BlockSpec((1, 1, w), lane_map)],
-        out_specs=[in_hbm, pl.BlockSpec((1, hp, value_dim), lane_map)],
+        out_specs=[in_hbm, pl.BlockSpec((1, value_dim, hp), lane_map)],
         scratch_shapes=[
-            pltpu.VMEM((hp, 1), jnp.float32),          # running max
-            pltpu.VMEM((hp, 1), jnp.float32),          # running denominator
-            pltpu.VMEM((hp, value_dim), jnp.float32),  # weighted accumulator
+            pltpu.VMEM((1, hp), jnp.float32),          # running max
+            pltpu.VMEM((1, hp), jnp.float32),          # running denominator
+            pltpu.VMEM((value_dim, hp), jnp.float32),  # weighted accumulator
             pltpu.VMEM((group, w), c_arena.dtype),     # the new row's group
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     kernel = functools.partial(_latent_kernel, layer=layer, block_s=block_s,
-                               value_dim=value_dim, sm_scale=sm_scale)
+                               value_dim=value_dim)
     block_bytes = block_s * w * c_arena.dtype.itemsize
     c_out, o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(c_arena.shape, c_arena.dtype),
-                   jax.ShapeDtypeStruct((bsz, hp, value_dim), jnp.float32)],
+                   jax.ShapeDtypeStruct((bsz, value_dim, hp), jnp.float32)],
         input_output_aliases={len(prefetch): 0},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=min(100 << 20, 6 * block_bytes + (24 << 20))),
         interpret=interpret,
         name="latent_wave_attention",
     )(*prefetch, c_arena, q, new_row.reshape(bsz, 1, w))
-    return c_out, o[:, :h]
+    return c_out, o[:, :, :h]
 
 
 def reference_latent_attention(c_arena, q, new_row, rows, lens, *, layer,
-                               value_dim: int, sm_scale: float):
-    """XLA oracle of ``latent_wave_attention``: scatter the new row, gather
-    each lane's slot, dense masked softmax over ``pos <= len`` in float32
-    over the values the cache holds."""
+                               value_dim: int):
+    """XLA oracle of ``latent_wave_attention`` (same operands, same
+    result): scatter the new row, gather each lane's slot, dense masked
+    softmax over ``pos <= len`` in float32 over the values the cache and the
+    query hold."""
     s = c_arena.shape[2]
     c_arena = c_arena.at[layer, rows, lens].set(new_row.astype(c_arena.dtype))
     c = c_arena[layer, rows].astype(jnp.float32)             # [B, S, W]
-    scores = jnp.einsum("bhw,bsw->bhs", q, c) * sm_scale
+    scores = jnp.einsum("bsw,bwh->bsh", c, q.astype(jnp.float32))
     mask = jnp.arange(s)[None, :] <= lens[:, None]
-    scores = jnp.where(mask[:, None, :], scores, _NEG_INF)
-    o = jnp.einsum("bhs,bsv->bhv", jax.nn.softmax(scores),
-                   c[..., :value_dim])
+    scores = jnp.where(mask[:, :, None], scores, _NEG_INF)
+    o = jnp.einsum("bsv,bsh->bvh", c[..., :value_dim],
+                   jax.nn.softmax(scores, axis=1))
     return c_arena, o

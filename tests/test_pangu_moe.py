@@ -275,28 +275,45 @@ def test_padded_lanes_route_nowhere():
 
 # -- the kernels against their oracles -------------------------------------------
 
+# heads, kv rank, rope lanes, lengths: the base case (lanes with an empty
+# prefix, a full slot, a length on a block's edge); a head count that is no
+# multiple of 8; values narrower than the row by more than one tile (64 of
+# 384 lanes); live rows that end one short of and one past a block's edge;
+# a full lane tile of heads on a one-tile row.
+LATENT_CASES = {
+    "base": (4, 32, 8, [0, 17, 63, 16, 0]),
+    "six_heads": (6, 32, 8, [0, 17, 63, 16, 0]),
+    "narrow_values": (4, 64, 200, [5, 17, 63, 16, 0]),
+    "around_a_block_edge": (4, 32, 8, [15, 17, 31, 33, 47]),
+    "128_heads": (128, 32, 8, [0, 17, 63, 16, 1]),
+}
+
+
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
                                         (jnp.bfloat16, 2e-2)])
 @pytest.mark.parametrize("scanned", [False, True])
-def test_latent_kernel_parity(dtype, atol, scanned):
-    """The row written in place, every other row bitwise kept, lanes with an
-    empty prefix, a full slot and a length on a block's edge."""
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+def test_latent_kernel_parity(dtype, atol, scanned, case):
+    """The query ``[B, W, H]`` in the cache's dtype and the result ``[B, V,
+    H]``, against the oracle: the row written in place and exact, every
+    other row bitwise kept."""
     rng = np.random.default_rng(0)
-    layers, slots, s, h, rank, rp = 2, 7, 64, 4, 32, 8
+    h, rank, rp, lens = LATENT_CASES[case]
+    layers, slots, s = 2, 7, 64
     w = latent_row_width(rank, rp)
     c = jnp.asarray(rng.standard_normal((layers, slots, s, w)), dtype)
-    q = jnp.asarray(rng.standard_normal((5, h, w)), jnp.float32)
+    q = jnp.asarray(0.25 * rng.standard_normal((5, w, h)), dtype)
     new = jnp.asarray(rng.standard_normal((5, w)), jnp.float32)
     rows = jnp.asarray([3, 0, 5, 1, 6], jnp.int32)
-    lens = jnp.asarray([0, 17, 63, 16, 0], jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
     kw = dict(layer=None, layer_index=jnp.int32(1)) if scanned \
         else dict(layer=1)
     got_c, got = latent_wave_attention(
-        c, q, new, rows, lens, value_dim=rank, sm_scale=0.25, block_s=16,
-        interpret=True, **kw)
+        c, q, new, rows, lens, value_dim=rank, block_s=16, interpret=True,
+        **kw)
     want_c, want = reference_latent_attention(
-        c, q, new, rows, lens, layer=1, value_dim=rank, sm_scale=0.25)
-    assert got.shape == (5, h, rank)
+        c, q, new, rows, lens, layer=1, value_dim=rank)
+    assert got.shape == (5, rank, h) and got.dtype == jnp.float32
     assert float(jnp.abs(got - want).max()) < atol
     assert bool((got_c == want_c).all())
     touched = np.zeros(c.shape[:3], bool)
@@ -306,6 +323,28 @@ def test_latent_kernel_parity(dtype, atol, scanned):
     assert np.array_equal(
         np.asarray(got_c)[1, np.asarray(rows), np.asarray(lens)],
         np.asarray(new.astype(dtype)))
+
+
+def test_the_wave_query_is_what_the_kernel_used_to_round_to():
+    """``_qkv`` scales in float32 and rounds to the cache's dtype: bit for
+    bit the ``(q * sm_scale).astype(bfloat16)`` the kernel made of a float32
+    ``[q_nope W_kb^T | q_rope | 0]`` until PR 33, in the kernel's layout."""
+    be = backend()
+    lp = jax.tree_util.tree_map(jnp.asarray, be._init_params()["layers"][1])
+    x = {"h": jnp.asarray(np.random.default_rng(3).standard_normal((6, 64)),
+                          jnp.float32)}
+    pos = jnp.asarray([0, 1, 7, 30, 31, 63], jnp.int32)
+    q, row = be._qkv(lp, x, pos)
+    q_nope, q_rope, _, _ = be._queries_and_rows(lp, x["h"], pos)
+    q_lat = be._heads_mm("bhn,hnr->bhr", q_nope, lp["wkb"])
+    was = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros((6, be.n_heads, be.row_width - be.kv_rank
+                                   - be.rope_dim), jnp.float32)], axis=-1)
+    was = (was * be.sm_scale).astype(jnp.bfloat16)           # [B, H, W]
+    assert q.dtype == jnp.bfloat16 and q.shape == (6, be.row_width, 4)
+    assert np.array_equal(np.asarray(q, np.float32),
+                          np.asarray(was, np.float32).transpose(0, 2, 1))
+    assert row.shape == (6, be.row_width) and row.dtype == jnp.float32
 
 
 @pytest.mark.parametrize("tile_m,skew", [(8, False), (16, True)])

@@ -9,8 +9,10 @@ For each family's decode wave and prefill (or piece) program at a tiny preset,
 lowered for the TPU with the kernels in: the StableHLO with the kernels' bodies
 taken out (a body carries the source path of the checkout) and, apart, the
 Pallas kernels' own jaxprs, each against the hash recorded at commit 6b8c7c9
-(PR 31).  A PR that means to change one of these programs records the new
-hash here and says so; one that does not has a guard.
+(PR 31); ``pangu``'s (models/pangu_moe.py: the latent kernel, the grouped
+matmuls, prefill by flash pieces) at the tree PR 33 left.  A PR that means to
+change one of these programs records the new hash here and says so; one that
+does not has a guard.
 
     python - <<'X'          # to record: run from the repo root
     import tests.test_served_programs as t; t.record()
@@ -29,6 +31,8 @@ RECORDED = {
     ("evabyte", "prefill"): ("d6eccbae5564229b", "d3dffbbfe8efac92"),
     ("gpt", "decode"): ("92758237abe83ac2", "61153d74d471a1af"),
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),
+    ("pangu", "decode"): ("b73c536a3102de37", "25dcc382561a488e"),
+    ("pangu", "prefill"): ("2947c2e62c0d448d", "fa56a8062981bfe9"),
 }
 
 
@@ -39,6 +43,14 @@ def _backend(family):
         return EvaByteBackend(seed=3, max_seq_len=128, window=32, chunk=4,
                               attention_impl="flash", attn_impl="fused",
                               prefill_lanes=2)
+    if family == "pangu":
+        from client_tpu.models.pangu_moe import PanguMoeBackend
+
+        # Heads of whole 128-lane tiles, as the flash pieces need them.
+        return PanguMoeBackend(seed=3, n_heads=2, nope_dim=192, rope_dim=64,
+                               v_dim=128, kv_rank=128, max_seq_len=32,
+                               piece=16, attention_impl="flash",
+                               attn_impl="fused")
     from client_tpu.models.generate import TinyGptBackend
 
     return TinyGptBackend(attention_impl="flash", attn_impl="fused")
@@ -48,7 +60,12 @@ def _program(family, which):
     """(function, static and donated argument numbers, abstract
     arguments)."""
     be = _backend(family)
-    params = jax.eval_shape(be._init_params)
+    if family == "pangu":       # weights made when asked for: shapes only
+        params = jax.tree_util.tree_map(
+            lambda w: jax.ShapeDtypeStruct(w.shape, jnp.dtype(w.dtype)),
+            be._init_params())
+    else:
+        params = jax.eval_shape(be._init_params)
     arena = jax.eval_shape(lambda: be.init_arena(4))
 
     def i32(*s):

@@ -366,3 +366,18 @@ def test_latent_expert_decode_step_compiles_at_published_widths(
     experts = r"bf16\[16,7680,4096\]|bf16\[16,2048,7680\]"
     assert not re.findall(r"= (?:" + experts + r")[^=]*? copy\(", text)
     assert "s32[131]" in text          # 128 tokens and three counts
+    # The absorbed query goes from the fusion that scales and rounds it into
+    # the kernel as it lies (``[lanes, 640, heads]``), and nothing re-lays the
+    # kernel's float32 result.  What XLA does re-lay, once a layer each, is
+    # the batched products' side of it (a batch dimension cannot be the minor
+    # one of a dot's result or operand): ``q_nope W_kb`` as it leaves the
+    # dot, in float32 as at the parent, and the result rounded to bfloat16 on
+    # its way into ``W_vb``.
+    moved = r" (?:copy|transpose)\((%[\w.-]+)"
+    assert not re.findall(
+        r"= \w+\[128,(?:640,128|128,640)\][^=]*?" + moved, text)
+    relaid = re.findall(r"= (\w+)\[128,512,128\][^=]*?" + moved, text)
+    dtypes = [d for d, _ in relaid]
+    assert dtypes.count("f32") <= 5 and dtypes.count("bf16") <= 5, relaid
+    assert not [src for d, src in relaid
+                if d == "f32" and "latent_wave_attention" in src]
