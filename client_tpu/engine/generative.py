@@ -38,6 +38,13 @@ generation streams. Design, TPU-first:
   ``wave_stats``, names of ``spans.GEN_COUNTERS``): the decode program returns
   that many int32 behind a wave's tokens (what a sparse expert layer routed),
   one fetch brings both, and the counters move when the tokens arrive.
+- **A stream's record** (a backend that declares ``stream_record``, int32 a
+  position, and a request whose parameters say ``record``): the programs
+  return every position's row behind their tokens (pieces and waves alike);
+  the worker keeps the rows of the streams that asked and hands a stream its
+  whole record, ``RECORD [positions, stream_record]``, with its final
+  response.  No other stream's path moves: the rows of a fetch nobody asked
+  for are dropped with a slice.
 - **Decode waves** (one jit per stream-count bucket) advance every live
   stream one token in a single XLA execution: gather input tokens from the
   device-side slots, scatter new K/V at each stream's position, masked
@@ -132,7 +139,7 @@ class _Stream:
     __slots__ = ("req", "row", "disp_len", "disp_tokens", "f_len",
                  "emitted", "max_new", "seed", "temp", "top_k", "top_p",
                  "stop", "dead", "throttled_since", "ids", "consumed",
-                 "transition", "t_prefill", "sink")
+                 "transition", "t_prefill", "sink", "record")
 
     def __init__(self, req, row, plen, max_new,
                  seed=0, temp=0.0, top_k=0, top_p=1.0, stop=frozenset()):
@@ -159,6 +166,9 @@ class _Stream:
         self.consumed = 0
         self.t_prefill = 0
         self.transition = False   # a cache transition is due before a wave
+        # The rows of its record fetched so far, piece by piece and wave by
+        # wave (None: it did not ask for one).
+        self.record = None
 
 
 # The lane of a prefill piece whose prompt is not finished by it.
@@ -170,10 +180,10 @@ class _Inflight:
     """One dispatched execution whose token fetch is pending."""
 
     __slots__ = ("kind", "streams", "tokens", "waves", "t_disp", "bucket",
-                 "depth", "positions", "rows")
+                 "depth", "positions", "rows", "pieces")
 
     def __init__(self, kind, streams, tokens, waves=1, t_disp=0, bucket=0,
-                 depth=0, positions=0, rows=(0, 0)):
+                 depth=0, positions=0, rows=(0, 0), pieces=()):
         self.kind = kind          # 'prefill' | 'piece' | 'wave' | 'chunk'
         self.streams = streams    # lane order, real lanes only
         self.tokens = tokens      # jax.Array future (copy_to_host_async'd)
@@ -183,6 +193,7 @@ class _Inflight:
         self.depth = depth        # waves in flight at a prefill's dispatch
         self.positions = positions  # valid context positions it reads
         self.rows = rows          # cache rows it reads: (summary, exact)
+        self.pieces = pieces      # a piece's lanes: (stream, valid positions)
 
 
 class _WarmupReq:
@@ -327,6 +338,13 @@ class GenerativeScheduler(Scheduler):
         # int32 ride behind a decode wave's tokens.
         self._wave_stats = [_sp.GEN_COUNTERS.index(name)
                             for name in backend.wave_stats]
+        # int32 a position behind every program's tokens, for the streams
+        # that ask for their record (module docstring).
+        self._record = int(backend.stream_record)
+        if self._record and not self._piece_len:
+            raise ValueError(
+                f"{model.config.name}: a stream's record is kept piece by "
+                "piece; the backend declares no prefill_piece")
         self._transition_due = backend.transition_due
         self._transition = None
         if self._transition_due is not None:
@@ -690,6 +708,8 @@ class GenerativeScheduler(Scheduler):
                                  seed=seed, temp=temp, top_k=top_k,
                                  top_p=top_p, stop=stop)
                 stream.ids = ids
+                if self._record and req.parameters.get("record"):
+                    stream.record = []
                 self._streams.append(stream)
                 self._rec.c[_sp.C_PROMPTS_ADMITTED] += 1
             return
@@ -817,6 +837,10 @@ class GenerativeScheduler(Scheduler):
             self.model._clear_state()
         now = time.monotonic_ns()
         self._rec.c[_sp.C_PREFILL_PIECES] += len(todo)
+        held = int(lens[:len(todo)].sum())
+        self._rec.c[_sp.C_PREFILL_POSITIONS_VALID] += held
+        self._rec.c[_sp.C_PREFILL_POSITIONS_PADDED] += \
+            len(todo) * width - held
         self.stats.record_execution(len(todo))
         done = []                     # by lane: the stream, if it ended
         for i, s in enumerate(todo):
@@ -832,7 +856,8 @@ class GenerativeScheduler(Scheduler):
         self._inflight.append(_Inflight(
             "prefill" if ended else "piece", done, tokens,
             t_disp=min([s.t_prefill for s in ended] or [now]),
-            depth=self._inflight_waves))
+            depth=self._inflight_waves,
+            pieces=list(zip(todo, lens.tolist())) if self._record else ()))
         self._inflight_waves += 1
         return True
 
@@ -978,6 +1003,8 @@ class GenerativeScheduler(Scheduler):
                 toks = toks[..., :-n]
                 for i, v in zip(self._wave_stats, stats.tolist()):
                     c[i] += v
+            if self._record:
+                toks = self._keep_records(head, toks)
             # Wave timing: the device ran this dispatch from
             # max(its dispatch, the previous fetch) until now — pipelined
             # waves complete back to back, so the deltas between
@@ -1031,6 +1058,24 @@ class GenerativeScheduler(Scheduler):
                 # Two waves' tokens leave back to back: the pairs a
                 # client sees as one long gap and one of nothing.
                 c[_sp.C_DRAINS_MULTI] += 1
+
+    def _keep_records(self, head: _Inflight, toks):
+        """A fetch's tokens, its positions' rows of the streams' records cut
+        off behind them (``[lanes | lanes x positions x stream_record]``, a
+        chunk's one such row a wave); the streams that asked keep theirs."""
+        lanes = head.bucket or self._piece_lanes
+        rows = toks[..., lanes:]
+        if head.pieces:
+            rows = rows.reshape(lanes, -1, self._record)
+            for i, (s, valid) in enumerate(head.pieces):
+                if s.record is not None:
+                    s.record.append(rows[i, :valid])
+        else:
+            rows = rows.reshape(-1, lanes, self._record)
+            for i, s in enumerate(head.streams):
+                if s.record is not None:
+                    s.record.append(rows[:, i])
+        return toks[..., :lanes]
 
     def _emit_fetched(self, head: _Inflight, toks) -> None:
         """Emit one fetch's tokens.  A chunked fetch is K stacked waves
@@ -1101,11 +1146,16 @@ class GenerativeScheduler(Scheduler):
                     "stream writer refused a wave (model '%s')",
                     self.model.config.name)
         for s in ended:
+            outputs = {}
+            if s.record is not None:
+                # A row a position consumed: the prompt's, and one a wave
+                # it took part in (a chunk's surplus rows go).
+                outputs["RECORD"] = np.concatenate(s.record)[:s.f_len]
             self._respond(s.req, InferResponse(
                 model_name=s.req.model_name,
                 model_version=s.req.model_version or version,
                 request_id=s.req.request_id,
-                outputs={},
+                outputs=outputs,
                 parameters={"triton_final_response": True},
                 final=True,
                 times=s.req.times,

@@ -580,7 +580,11 @@ class Model:
         """Pre-compile every bucket with zero inputs so first real requests
         don't pay XLA compile latency (first compile ~20-40s on TPU)."""
         cfg = self.config
-        if self._apply is None:
+        if self._apply is None or getattr(self.backend, "generative", False):
+            # A generative backend's tokens come from its scheduler's
+            # programs, which that warms; no request reaches its
+            # full-context ``apply`` (a diagnostic entry), and compiling it
+            # here cost a published-size decoder 8.5 s of every launch.
             return
         _log.info("model '%s': warmup over buckets %s",
                   cfg.name, cfg.effective_buckets())

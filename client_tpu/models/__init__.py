@@ -48,6 +48,15 @@ def _pangu_moe() -> ModelBackend:
     return PanguMoeBackend()
 
 
+@register_model("kimi_linear", default=False)
+def _kimi_linear() -> ModelBackend:
+    """The hybrid decoder (a recurrent state beside a latent cache), at its
+    tiny preset.  Opt-in, and imported when it is built, as ``pangu_moe``."""
+    from client_tpu.models.kimi_linear import KimiLinearBackend
+
+    return KimiLinearBackend()
+
+
 def model_names() -> list[str]:
     _import_all()
     return sorted(_REGISTRY)

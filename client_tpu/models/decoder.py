@@ -15,10 +15,30 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
   the model's business, and so is which leaves there are: ``cache_leaves``
   names those a decode step carries through its layers (``("k", "v")``; a
   latent cache has one, ``("c",)``, ``[L, R, S, W]``).
+- **Layer kinds.**  ``layer_kinds``: ``None`` (every layer reads rows: its
+  leaves are ``cache_leaves``, indexed by the layer's number), or one entry a
+  layer, ``"rows"`` or ``"state"``.  A ``"state"`` layer keeps no row a
+  position: it owns a fixed-size state a slot, advanced in place by every
+  step (``state_leaves``, e.g. ``("s", "conv")``: ``[layers of the kind, R,
+  ...]``).  A leaf's leading axis counts **the layers of its kind**, and the
+  frame hands a layer the leaves of its kind and its index among them
+  (``_layer_kind``).  Rows left in a slot by its last stream are masked by
+  ``lens``; a state is not, so the first piece of a prompt (``starts == 0``)
+  starts from zeros, and padded lanes (on the junk slot) and padded positions
+  leave every live slot's state as it was.
 - ``wave_stats``: ``()``, or names of ``spans.GEN_COUNTERS`` that only the
   device can count (what a wave's tokens were routed to): the decode program
   then returns that many int32 behind its ``B`` tokens (``_wave_stats(x)``),
   and the scheduler adds them to the counters when the wave's tokens arrive.
+- ``stream_record``: ``0``, or how many int32 a position the programs leave
+  behind their tokens for a stream that asks for its record (request
+  parameter ``record``; needs ``prefill_piece``): the decode program returns
+  ``[B tokens | B x stream_record | wave_stats]`` (``_record(x, logits,
+  tokens)`` -> ``[B, stream_record]``) and a piece ``[lanes tokens | lanes x
+  positions x stream_record]``.  What the ints say is the backend's
+  (models/latent_moe.py: which held experts each expert layer chose, and a
+  few of the logits the token was chosen from); the scheduler hands a stream
+  its positions' rows with its final response (``RECORD``).
 - ``arena_rows(capacity)`` -> (free rows, dummy row) and ``kv_shards`` (1).
 - ``prefill_piece``: ``None`` (a whole prompt a lane, one program a prompt
   bucket) or ``(positions, lanes)`` (a prompt is consumed ``positions`` a
@@ -49,6 +69,11 @@ lanes, H]`` float32, to what ``_after_attention`` reads (heads along the
 minor axis on both sides of the kernel: a model's einsums write and read
 that layout);
 ``_after_attention(lp, x, o)`` -> x;
+where ``layer_kinds`` names ``"state"`` layers, ``_advance(lp, x, *state
+leaves, rows, lens, index)`` -> (*state leaves, o): the layer's mixer on the
+slots' states (what a state is and which kernel advances it are the model's;
+it chooses kernel or oracle by ``_use_kernel()``), ``o`` what
+``_after_attention`` reads;
 ``_logits(p, x)`` and,
 where they are not ``[B, vocab]``, ``_served(logits)`` picking those tokens
 are sampled from; ``_walk_layers(p, body, carry)`` folding ``body(carry, lp,
@@ -122,6 +147,20 @@ def sample_into_slots(arena, rows, logits, seeds, ctx, temps, top_ks, top_ps,
     return {**arena, "tok": arena["tok"].at[rows].set(tokens)}, tokens
 
 
+def logit_bits(logits, tokens, samples: int):
+    """``[B, 1 + samples]`` int32: the float32 bits of each lane's logit of
+    its token and of the row's first ``samples`` ids (a fixed sample of the
+    row: what a comparison weighs a program's precision by); the form that
+    rides behind int32 tokens in a ``stream_record``."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = logits.astype(jnp.float32)
+    at = jnp.take_along_axis(logits, tokens[:, None], axis=-1)
+    return jax.lax.bitcast_convert_type(
+        jnp.concatenate([at, logits[:, :samples]], axis=-1), jnp.int32)
+
+
 class DecoderBackend(ModelBackend):
     """A decoder served token by token: INPUT_IDS [-1] -> streamed (TOKEN,
     INDEX) responses, ended by an empty ``triton_final_response`` like every
@@ -131,8 +170,11 @@ class DecoderBackend(ModelBackend):
 
     prefill_piece: tuple[int, int] | None = None
     cache_leaves: tuple[str, ...] = ("k", "v")
+    layer_kinds: tuple[str, ...] | None = None
+    state_leaves: tuple[str, ...] = ()
     latent_attention: int | None = None
     wave_stats: tuple[str, ...] = ()
+    stream_record = 0
     cache_rows = None
     transition_due = None
     transition_fn = None
@@ -345,10 +387,21 @@ class DecoderBackend(ModelBackend):
 
     # -- the decode step ------------------------------------------------------
 
+    def _layer_kind(self, li):
+        """(kind, index among the layers of that kind) of layer ``li``; with
+        kinds declared ``li`` is a Python int (the layers are a loop)."""
+        if self.layer_kinds is None:
+            return "rows", li
+        kind = self.layer_kinds[li]
+        return kind, self.layer_kinds[:li].count(kind)
+
     def _attention_output(self, lp, o):
         return o
 
     def _wave_stats(self, x):
+        raise NotImplementedError
+
+    def _record(self, x, logits, tokens):
         raise NotImplementedError
 
     def _decode_hidden_fn(self):
@@ -358,25 +411,32 @@ class DecoderBackend(ModelBackend):
         wave), its position is its context length ``lens[b]``; each layer
         writes the lane's new cache row behind the slot's live rows and
         reads them all (``_decode_attend``), whatever leaves the cache has
-        (``cache_leaves``)."""
+        (``cache_leaves``), or advances the slot's state in place
+        (``_advance`` on ``state_leaves``), by the layer's kind."""
         attend = self._decode_attend()
-        names = self.cache_leaves
+        held = len(self.cache_leaves)
+        names = self.cache_leaves + self.state_leaves
 
         def step(p, arena, rows, lens):
             live = self._live_rows(lens)
             tokens = arena["tok"][rows]
 
             def layer(carry, lp, li):
-                x, *cache = carry
-                *cache, o = attend(*cache, *self._qkv(lp, x, lens), rows,
-                                   live, li)
-                return (self._after_attention(
-                    lp, x, self._attention_output(lp, o)), *cache)
+                x, *leaves = carry
+                cache, state = leaves[:held], leaves[held:]
+                kind, ki = self._layer_kind(li)
+                if kind == "rows":
+                    *cache, o = attend(*cache, *self._qkv(lp, x, lens), rows,
+                                       live, ki)
+                    o = self._attention_output(lp, o)
+                else:
+                    *state, o = self._advance(lp, x, *state, rows, lens, ki)
+                return (self._after_attention(lp, x, o), *cache, *state)
 
-            x, *cache = self._walk_layers(
+            x, *leaves = self._walk_layers(
                 p, layer, (self._embed(p, tokens, lens),
                            *(arena[name] for name in names)))
-            return {**arena, **dict(zip(names, cache))}, x
+            return {**arena, **dict(zip(names, leaves))}, x
 
         return step
 
@@ -398,8 +458,9 @@ class DecoderBackend(ModelBackend):
         scheduler dispatches waves ahead and fetches tokens asynchronously.
         The context at sampling is ``lens + 1`` (the token just written
         occupies position ``lens``): prefill's fold sequence, continued.
-        A backend that declares ``wave_stats`` returns them behind the
-        tokens, ``[B + len(wave_stats)]``: one fetch brings both."""
+        A backend that declares ``stream_record`` or ``wave_stats`` returns
+        them behind the tokens, ``[B | B x stream_record | wave_stats]``: one
+        fetch brings all."""
         import jax.numpy as jnp
 
         hidden = self._decode_hidden_fn()
@@ -407,12 +468,17 @@ class DecoderBackend(ModelBackend):
         def decode(p, arena, rows, lens, seeds, temps, top_ks, top_ps,
                    sample=True):
             arena, x = hidden(p, arena, rows, lens)
+            logits = self._served(self._logits(p, x))
             arena, tokens = sample_into_slots(
-                arena, rows, self._served(self._logits(p, x)), seeds,
-                lens + 1, temps, top_ks, top_ps, sample)
+                arena, rows, logits, seeds, lens + 1, temps, top_ks, top_ps,
+                sample)
+            behind = []
+            if self.stream_record:
+                behind.append(self._record(x, logits, tokens).reshape(-1))
             if self.wave_stats:
-                tokens = jnp.concatenate(
-                    [tokens, self._wave_stats(x).astype(tokens.dtype)])
+                behind.append(self._wave_stats(x).astype(tokens.dtype))
+            if behind:
+                tokens = jnp.concatenate([tokens, *behind])
             return arena, tokens
 
         return decode

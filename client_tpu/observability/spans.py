@@ -100,6 +100,10 @@ GEN_COUNTERS = (
     # whose expert is held here, the busiest held expert's pairs (a layer's
     # largest group), and the held experts that got at least one token.
     "expert_pairs_local", "expert_pairs_busiest", "experts_touched",
+    # per dispatched prefill piece: its positions that held a prompt token,
+    # and those that were padding up to the piece (which a layer with a
+    # recurrent state has to step over without moving the state).
+    "prefill_positions_valid", "prefill_positions_padded",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
@@ -108,7 +112,8 @@ GEN_COUNTERS = (
  C_PROMPTS_ADMITTED, C_PREFILL_PIECES, C_TRANSITIONS, C_EMIT_HANDOFFS,
  C_EMITTED_TOKENS, C_EMITTED_TOKENS_CALLBACK, C_PREFILL_LANES_LIVE,
  C_PREFILL_LANES_PADDED, C_EXPERT_PAIRS_LOCAL, C_EXPERT_PAIRS_BUSIEST,
- C_EXPERTS_TOUCHED) = range(len(GEN_COUNTERS))
+ C_EXPERTS_TOUCHED, C_PREFILL_POSITIONS_VALID,
+ C_PREFILL_POSITIONS_PADDED) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

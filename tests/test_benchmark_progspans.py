@@ -264,7 +264,8 @@ class TestHostGaps:
 
 # -- PR 27's checks as tier-1 tests, and PR 28's readers ----------------------
 
-@pytest.mark.parametrize("script", ["check_readers", "check_spread"])
+@pytest.mark.parametrize("script", ["check_readers", "check_spread",
+                                    "check_kimi_linear"])
 def test_the_testdata_checks_pass(script):
     """``benchmark/testdata/check_readers.py`` (PR 27's four readers on a
     hand-made context) and ``check_spread.py`` (the manifest's window and

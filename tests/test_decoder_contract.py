@@ -17,14 +17,15 @@ from client_tpu.models.decoder import (DECODE_ARGS, DECODE_CHUNK_ARGS,
                                        PREFILL_ARGS, DecoderBackend)
 
 SERVED = ["tiny_gpt", "tiny_gpt_long", "tiny_gpt_oracle", "evabyte",
-          "tiny_gpt_mc", "moe_gpt_mc", "pangu_moe"]
+          "tiny_gpt_mc", "moe_gpt_mc", "pangu_moe", "kimi_linear"]
 # What `GenerativeScheduler.__init__` reads of a backend before it starts its
 # worker; a backend that hides one cannot be scheduled.
 READ_AT_CONSTRUCTION = ["max_streams", "max_seq_len", "arena_rows",
                         "init_arena", "prefill_fn", "decode_fn",
                         "donate_argnums", "prefill_static_argnums",
                         "decode_static_argnums", "prefill_piece",
-                        "cache_rows", "transition_due", "wave_stats"]
+                        "cache_rows", "transition_due", "wave_stats",
+                        "stream_record"]
 
 
 @pytest.fixture(scope="module")
@@ -34,6 +35,18 @@ def engines():
         name, TpuEngine(build_repository([name])))
     for eng in built.values():
         eng.shutdown()
+
+
+@pytest.mark.parametrize("name", ["tiny_gpt", "kimi_linear"])
+def test_warming_a_served_decoder_compiles_no_full_context_apply(engines,
+                                                                 name):
+    """The launcher's ``--warmup`` warms a generative model's programs
+    through its scheduler; the model-level ``apply`` no request reaches is
+    left alone (8.5 s of a published-size launch: PERF.md section 6, PR
+    34)."""
+    model = engines(name)._schedulers[name].model
+    model.warmup()
+    assert model._apply is not None and not model._compiled
 
 
 @pytest.mark.parametrize("name", SERVED)
@@ -54,7 +67,27 @@ def test_a_served_decoder_declares_the_whole_contract(engines, name):
     assert sched._cache_rows == backend.cache_rows
     assert sched._transition_due == backend.transition_due
     assert len(sched._wave_stats) == len(backend.wave_stats)
+    # A stream's record: off unless declared, and then kept piece by piece.
+    assert sched._record == backend.stream_record
+    assert not backend.stream_record or backend.prefill_piece
     assert set(backend.cache_leaves) < set(sched._arena)
+    # Layer kinds: none declared means every layer reads rows and is its own
+    # index; declared, each kind's leaves are as deep as its layers.
+    assert set(backend.state_leaves) < set(sched._arena)
+    if backend.layer_kinds is None:
+        assert backend.state_leaves == ()
+        assert backend._layer_kind(3) == ("rows", 3)
+    else:
+        kinds = backend.layer_kinds
+        assert set(kinds) == {"rows", "state"}
+        assert [backend._layer_kind(li) for li in range(len(kinds))] == [
+            (kind, kinds[:li].count(kind)) for li, kind in enumerate(kinds)]
+        for leaves, kind in ((backend.cache_leaves, "rows"),
+                             (backend.state_leaves, "state")):
+            assert leaves
+            for leaf in leaves:
+                assert sched._arena[leaf].shape[0] == kinds.count(kind)
+                assert sched._arena[leaf].shape[1] == backend.max_streams + 1
     assert (sched._transition is None) == (backend.transition_fn is None)
     assert sched.arena_shards() == backend.kv_shards
     # `sample`, `k` and the arena stand where the argument lists say.

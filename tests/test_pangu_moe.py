@@ -25,8 +25,9 @@ import family  # noqa: E402
 from client_tpu.engine import TpuEngine  # noqa: E402
 from client_tpu.engine.repository import ModelRepository  # noqa: E402
 from client_tpu.engine.types import InferRequest  # noqa: E402
-from client_tpu.models import pangu_moe as pangu_mod  # noqa: E402
-from client_tpu.models.pangu_moe import PanguMoeBackend, SeededWeight  # noqa: E402
+from client_tpu.models import latent_moe  # noqa: E402
+from client_tpu.models.latent_moe import SeededWeight  # noqa: E402
+from client_tpu.models.pangu_moe import PanguMoeBackend  # noqa: E402
 from client_tpu.observability import spans  # noqa: E402
 from client_tpu.ops.decode_kernel import (  # noqa: E402
     latent_row_width,
@@ -185,27 +186,44 @@ def routed_part(be, lp, h):
     """What this share's held experts add, by the program."""
     lp = jax.tree_util.tree_map(jnp.asarray, lp)
     y, counts, _ = be._experts(lp, jnp.asarray(h), jnp.ones(len(h), bool),
-                               pangu_mod.TILE_M_WAVE)
+                               latent_moe.TILE_M_WAVE)
     return np.asarray(y), np.asarray(counts)
 
 
-def test_the_sixteen_shares_add_up_to_the_uncut_layer():
-    """Every share computes the terms of its own expert; all sixteen routed
+def _kimi_backend(**kw):
+    from client_tpu.models.kimi_linear import KimiLinearBackend
+
+    return KimiLinearBackend(**{"seed": 5, "max_seq_len": SEQ,
+                                "piece": PIECE, **kw})
+
+
+# (family, its backend, experts a share holds, the gate's scale): sixteen
+# shares of one expert, and eight shares of two whose gate has a selection
+# bias (models/kimi_linear.py; eight of 32 at the published widths).
+SHARES = {"pangu_moe": (backend, 1, 2.5),
+          "kimi_linear": (_kimi_backend, 2, 2.446)}
+
+
+@pytest.mark.parametrize("name", sorted(SHARES))
+def test_the_shares_add_up_to_the_uncut_layer(name):
+    """Every share computes the terms of its own experts; all the routed
     parts and the shared expert counted once are the uncut reference's
     layer.  (The weights are the model's, whichever share holds them.)"""
-    kw = dict(dtype="float32", n_experts=16, experts_held=1, top_k=4)
-    shares = [backend(first_expert=s, **kw) for s in range(16)]
+    build, held, scale = SHARES[name]
+    ref = family.load(name)
+    kw = dict(dtype="float32", n_experts=16, experts_held=held, top_k=4)
+    shares = [build(first_expert=s, **kw) for s in range(0, 16, held)]
     layers = [expert_layers_of(be)[0] for be in shares]
     h = np.random.default_rng(1).standard_normal((24, 64)).astype(np.float32)
     uncut = dict(layers[0])
     uncut["egu"] = np.concatenate([lp["egu"] for lp in layers])
     uncut["ed"] = np.concatenate([lp["ed"] for lp in layers])
     with jax.default_matmul_precision("highest"):
-        want, chosen, _ = fam.expert_layer(
+        want, chosen, _ = ref.expert_layer(
             {k: v if k in ("egu", "ed") else jnp.asarray(v)
-             for k, v in uncut.items()}, jnp.asarray(h), top_k=4, scale=2.5,
-            first=0)
-        shared = np.asarray(fam.swiglu(jnp.asarray(h), uncut["sgu"],
+             for k, v in uncut.items()}, jnp.asarray(h), top_k=4,
+            scale=scale, first=0)
+        shared = np.asarray(ref.swiglu(jnp.asarray(h), uncut["sgu"],
                                        uncut["sd"]))
     parts = [routed_part(be, lp, h) for be, lp in zip(shares, layers)]
     total = shared + sum(y for y, _ in parts)
@@ -550,7 +568,7 @@ def test_the_configuration_file_is_the_catalog_row_but_for_its_cuts():
     for key in CFG:
         if key not in ("family", "serve"):
             assert cfg[key] == CFG[key], key
-    assert cfg["serve"]["expert_tile_rows"] == pangu_mod.TILE_M_WAVE
+    assert cfg["serve"]["expert_tile_rows"] == latent_moe.TILE_M_WAVE
     assert fam.wave_rows(cfg) == capacity_rows(128 * 8, 16, 16) == 1264
 
 

@@ -10,7 +10,10 @@ lowered for the TPU with the kernels in: the StableHLO with the kernels' bodies
 taken out (a body carries the source path of the checkout) and, apart, the
 Pallas kernels' own jaxprs, each against the hash recorded at commit 6b8c7c9
 (PR 31); ``pangu``'s (models/pangu_moe.py: the latent kernel, the grouped
-matmuls, prefill by flash pieces) at the tree PR 33 left.  A PR that means to
+matmuls, prefill by flash pieces) at the tree PR 33 left; ``kimi``'s
+(models/kimi_linear.py: the state kernel beside the latent one, the chunked
+piece) at the tree PR 34 left, which moved what ``pangu`` and ``kimi`` share
+into models/latent_moe.py and left ``pangu``'s two as they were.  A PR that means to
 change one of these programs records the new hash here and says so; one that
 does not has a guard.
 
@@ -31,6 +34,8 @@ RECORDED = {
     ("evabyte", "prefill"): ("d6eccbae5564229b", "d3dffbbfe8efac92"),
     ("gpt", "decode"): ("92758237abe83ac2", "61153d74d471a1af"),
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),
+    ("kimi", "decode"): ("3c7634b1c3eb0637", "a5f23242e0be48b2"),
+    ("kimi", "prefill"): ("93c6ccfe91d5a268", "5dd18e6e271299fb"),
     ("pangu", "decode"): ("b73c536a3102de37", "25dcc382561a488e"),
     ("pangu", "prefill"): ("2947c2e62c0d448d", "fa56a8062981bfe9"),
 }
@@ -51,6 +56,16 @@ def _backend(family):
                                v_dim=128, kv_rank=128, max_seq_len=32,
                                piece=16, attention_impl="flash",
                                attn_impl="fused")
+    if family == "kimi":
+        from client_tpu.models.kimi_linear import KimiLinearBackend
+
+        lin = {"kda_layers": [1, 2, 3], "full_attn_layers": [4],
+               "num_heads": 2, "head_dim": 128, "short_conv_kernel_size": 4}
+        return KimiLinearBackend(seed=3, n_heads=2, nope_dim=192,
+                                 rope_dim=64, v_dim=128, kv_rank=128,
+                                 linear_attn=lin, max_seq_len=32, piece=16,
+                                 attention_impl="flash",
+                                 attn_impl="fused")
     from client_tpu.models.generate import TinyGptBackend
 
     return TinyGptBackend(attention_impl="flash", attn_impl="fused")
@@ -60,7 +75,7 @@ def _program(family, which):
     """(function, static and donated argument numbers, abstract
     arguments)."""
     be = _backend(family)
-    if family == "pangu":       # weights made when asked for: shapes only
+    if family in ("pangu", "kimi"):     # weights made when asked for
         params = jax.tree_util.tree_map(
             lambda w: jax.ShapeDtypeStruct(w.shape, jnp.dtype(w.dtype)),
             be._init_params())

@@ -381,3 +381,57 @@ def test_latent_expert_decode_step_compiles_at_published_widths(
     assert dtypes.count("f32") <= 5 and dtypes.count("bf16") <= 5, relaid
     assert not [src for d, src in relaid
                 if d == "f32" and "latent_wave_attention" in src]
+
+
+# -- the hybrid decoder at its published widths (PR 34) ----------------------------
+
+KIMI = dict(n_layers=8, n_dense=1, d_model=2304, n_heads=32,
+            linear_attn={"kda_layers": [1, 2, 3, 5, 6, 7],
+                         "full_attn_layers": [4, 8], "num_heads": 32,
+                         "head_dim": 128, "short_conv_kernel_size": 4},
+            kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128, d_ff=9216,
+            d_expert=1024, n_experts=256, experts_held=32, top_k=8,
+            vocab=20480, max_seq_len=8192, piece=512,
+            max_streams=256, attention_impl="flash")
+
+
+def test_state_and_latent_decode_step_compiles_at_published_widths(
+        one_chip, monkeypatch):
+    """A full wave of 256 lanes over both caches: the state kernel (a block
+    of heads of 128 x 128 float32 a grid step), the latent kernel at 32 heads
+    and the grouped matmuls of 32 held experts compile under Mosaic's limits;
+    the three donated leaves (8.7 GB) are updated in place, and what is
+    returned is the tokens and the wave's three counts."""
+    from client_tpu.engine import backend_init
+    from client_tpu.models.kimi_linear import KimiLinearBackend
+    from client_tpu.observability import spans
+
+    monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
+    place = _on(one_chip)
+    backend = KimiLinearBackend(name="k", **KIMI)
+    params = jax.tree_util.tree_map(
+        lambda leaf: place(leaf.shape, jnp.dtype(leaf.dtype)),
+        backend._init_params())
+    arena = jax.tree_util.tree_map(
+        lambda a: place(a.shape, a.dtype),
+        jax.eval_shape(lambda: backend.init_arena(backend.max_streams)))
+    lanes_i, lanes_f = place((256,), jnp.int32), place((256,), jnp.float32)
+    step = jax.jit(spans.named_step(backend.decode_fn(), spans.STEP_DECODE),
+                   donate_argnums=backend.donate_argnums,
+                   static_argnums=backend.decode_static_argnums)
+    compiled = step.lower(params, arena, lanes_i, lanes_i, lanes_i, lanes_f,
+                          lanes_i, lanes_f, False).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w+)\.\d+ = [^=]*? custom-call\(", text)
+    assert calls.count("kda_wave_update") == 6
+    assert calls.count("latent_wave_attention") == 2
+    assert calls.count("grouped_matmul") == 14
+    # 256 tokens, the lanes' rows of their streams' records, three counts.
+    assert f"s32[{256 + 256 * backend.stream_record + 3}]" in text
+    memory = compiled.memory_analysis()
+    if memory is not None:
+        leaves = sum(math.prod(arena[k].shape) * arena[k].dtype.itemsize
+                     for k in ("c", "s", "conv"))
+        assert memory.alias_size_in_bytes >= leaves
+        # No copy of a state leaf (3.2 GB) or of the rows (5.4 GB) beside it.
+        assert memory.temp_size_in_bytes < 1.0e9, memory
