@@ -1,11 +1,12 @@
 """The grouped expert matmuls' share of their roofline: the least seconds
 the chip needs for a full wave's gate-and-up and down products at the mean
 pairs and touched experts of the window's waves (the family's
-``expert_ffn``: the touched experts' matrices read once), over the device
-time of the ``grouped_matmul`` operations of the full wave's program that are
-among the trace's ten longest (``kernel_share``).  Waves of a smaller bucket
-run operations of other shapes: the steps are scaled by the full bucket's
-share of the window's waves."""
+``expert_ffn``: the touched experts' matrices read once) times every call
+the trace holds of the full wave's ``grouped_matmul`` groups, over those
+calls' device time (``kernel_share``).  Waves of a smaller bucket run
+operations of other shapes (``wave_rows``), so the groups' events are the
+full bucket's alone; the full bucket's share of the window's waves scales
+the steps of a trace reduced before PR 39 only."""
 import family
 import reduce
 

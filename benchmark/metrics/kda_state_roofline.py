@@ -1,9 +1,9 @@
 """The state kernel's share of its roofline: the least seconds the chip needs
 for one KDA layer's ``kda_wave_update`` at the mean live lanes of the window's
 waves (the family's ``kda_update``: the live lanes' states read once and
-written once, float32), over the device time of the operations of that name
-among the trace's ten longest (``kernel_share``: each found operation is one
-layer's call a step).  Nothing where the family has no such kernel."""
+written once, float32) times every call of that name the trace holds in
+``jit_decode``, over those calls' device time (``kernel_share``).  Nothing
+where the family has no such kernel."""
 import family
 
 

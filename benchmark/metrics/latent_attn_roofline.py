@@ -1,9 +1,8 @@
 """The latent decode kernel's share of its roofline: the least seconds the
 chip needs for one layer's ``latent_wave_attention`` at the mean live lanes
 and context rows of the window's waves (the family's ``latent_attention``:
-live rows read once, bfloat16), over the device time of the operations of
-that name among the trace's ten longest (``kernel_share``: each found
-operation is one layer's call a step)."""
+live rows read once, bfloat16) times every call of that name the trace holds
+in ``jit_decode``, over those calls' device time (``kernel_share``)."""
 import family
 
 

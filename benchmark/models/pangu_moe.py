@@ -378,24 +378,25 @@ def wave_rows(cfg: dict) -> int:
     return -(-worst // tile) * tile
 
 
-def kernel_share(ctx, parts, calls_share: float = 1.0):
+def kernel_share(ctx, parts, old_calls_share: float = 1.0):
     """A kernel's roofline share from the trace.  ``parts``: ``[(predicate
-    on an operation's label, (flops, bytes) of one call)]``.  The least
-    seconds of the operations **found** among the trace's longest, each a
-    call a ``jit_decode`` step (the layers are not under a ``scan``, so a
-    layer's kernel is an operation of its own, and one that is not among the
-    ten is neither in the numerator nor in the denominator), over those
-    operations' device time; ``calls_share`` is the share of the steps that
-    ran the program the operations are of."""
+    on a group's name, (flops, bytes) of one call)]``.  The least seconds of
+    every call the trace holds of the groups of ``jit_decode`` that a
+    predicate accepts (``reduce.kernel_groups``: the layers are not under a
+    ``scan``, so each layer's kernel is an operation of its own, and all of
+    them are one group) over those groups' device time.  Nothing where the
+    trace holds no event of the kernel.  ``old_calls_share`` serves a trace
+    reduced before PR 39 only: the share of the steps that ran the program
+    the operations are of."""
+    import reduce
     import roofline
 
-    tr = ctx["trace"] or {}
-    step = (tr.get("modules") or {}).get("jit_decode")
-    found = [(seconds, cost) for name, seconds in tr.get("device_ops") or []
-             for match, cost in parts if match(name)]
-    if not found or not step:
+    found = [(seconds, calls, cost) for match, cost in parts
+             for seconds, calls in reduce.kernel_groups(
+                 ctx, match, old_calls_share)]
+    if not found:
         return None
     peaks = roofline.peaks_for(ctx["device"]["kind"])
-    least = sum(roofline.min_seconds(*cost, peaks)[0] for _, cost in found)
-    return (100.0 * step["count"] * calls_share * least
-            / sum(seconds for seconds, _ in found))
+    least = sum(calls * roofline.min_seconds(*cost, peaks)[0]
+                for _, calls, cost in found)
+    return 100.0 * least / sum(seconds for seconds, _, _ in found)

@@ -308,6 +308,30 @@ def step_roofline(ctx):
     return 100.0 * least[0] * 1e3 / ms if least else None
 
 
+def kernel_groups(ctx, match,
+                  old_calls_a_step: float = 1.0) -> list[tuple[float, float]]:
+    """``[(device seconds, calls)]`` of the groups of ``jit_decode`` whose
+    name ``match`` accepts, from the trace's table of every operation
+    (``tracereduce``'s ``program_ops``): a call is an event the trace holds,
+    whatever its rank among the operations.  Nothing to read gives ``[]``.
+
+    A trace reduced before PR 39 (a kept ``context.json``; the tests under
+    ``tests/``, which that PR could not edit) has no table, and its
+    ``device_ops`` are its ten longest operations, not the breakdown's lines:
+    each that ``match`` accepts is taken as ``old_calls_a_step`` calls a step
+    of the program.  Where the table is, ``device_ops`` is printed, not read."""
+    tr = ctx["trace"] or {}
+    if "program_ops" in tr:
+        return [(seconds, float(events)) for name, (seconds, events)
+                in (tr["program_ops"].get("jit_decode") or {}).items()
+                if match(name)]
+    step = (tr.get("modules") or {}).get("jit_decode")
+    if not step:
+        return []
+    return [(seconds, step["count"] * old_calls_a_step)
+            for name, seconds in tr.get("device_ops") or [] if match(name)]
+
+
 def device_idle_share(ctx):
     tr = ctx["trace"]
     if not tr or tr.get("idle_share") is None:

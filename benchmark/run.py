@@ -3,9 +3,11 @@
 --seconds <s> --trace <0|1>
 
 The last line of standard output is one JSON object (``correct``,
-``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
-``breakdown``).  Everything the cell is made of is data the harness finds by
-name through ``BENCHMARK.json``: the configuration file, the traffic file
+``attempted``, ``failed``, ``metrics``, ``device``, traced ``breakdown``,
+and last ``compared``: the numbers that decided ``correct`` beside their
+limits, which are the last line of standard error as well).  Everything
+the cell is made of is data the harness finds by name through
+``BENCHMARK.json``: the configuration file, the traffic file
 ``benchmark/traffic/<traffic>.json`` and one reader per metric,
 ``benchmark/metrics/<metric>.py``.
 
@@ -474,6 +476,7 @@ def main() -> int:
             red_env.update(JAX_PLATFORMS="cpu",
                            JAX_ENABLE_COMPILATION_CACHE="false")
             out = os.path.join(tmp, "trace.json")
+            t_r = time.monotonic()
             rc = spawn([sys.executable, os.path.join(HERE, "tracereduce.py"),
                         trace_dir, out] + (["--inspect"] if art else []),
                        red_env).wait(timeout=600)
@@ -481,6 +484,8 @@ def main() -> int:
                 raise BenchFailure(f"trace reduction exited {rc}")
             with open(out) as f:
                 trace = json.load(f)
+            say(f"{tag}trace of {trace.get('trace_bytes', 0)} bytes reduced "
+                f"in {time.monotonic() - t_r:.1f}s")
             trace.update(win["trace_info"])
             if art:
                 for pb in glob.glob(os.path.join(
@@ -529,6 +534,15 @@ def main() -> int:
             device["window_s"] = trace["window_s"]
             result["breakdown"] = {"device_ops": trace["device_ops"],
                                    "idle_gaps": trace["idle_gaps"]}
+        # What decided ``correct``, each number beside its limit (the
+        # family's verdict names both), last in the line and on stderr.
+        result["compared"] = {
+            **{k: v for k, v in verdict.items()
+               if isinstance(v, (bool, int, float, str))},
+            "compiles_in_window": compiles, "compiles_in_window_limit": 0,
+            "server_exit": server_rc, "server_exit_limit": 0}
+        print(f"{tag}compared: " + json.dumps(result["compared"]),
+              file=sys.stderr, flush=True)
         if art:
             with open(os.path.join(art, "context.json"), "w") as f:
                 json.dump({"phases": phases, "verdict": verdict,

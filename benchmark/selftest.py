@@ -39,7 +39,7 @@ import traffic as T  # noqa: E402
 
 CELL = "gpt2_small.chat"
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
-                 "breakdown"}
+                 "breakdown", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes", "busy_s",
                "window_s"}
 

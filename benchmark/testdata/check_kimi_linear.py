@@ -53,8 +53,8 @@ def snap(counters):
 def hand_made_ctx(cfg):
     """100 waves of 250 live lanes at 3900 rows a lane; pieces that held 9000
     prompt positions and 1000 padded ones; a traced 4 s with 150 decode steps
-    in which four of the six layers' state kernels are among the ten
-    longest operations, 0.3 s each."""
+    that hold the six layers' state kernels (900 events, 1.8 s), shorter
+    than other operations, and the kernel's name in a piece as well."""
     after = {"fetched_waves": 100, "fetched_lanes_live": 25000,
              "fetched_positions_valid": 25000 * 3900,
              "prefill_positions_valid": 9000,
@@ -63,10 +63,13 @@ def hand_made_ctx(cfg):
              "experts_touched": 100 * 7 * 31}
     trace = {"window_s": 4.0,
              "modules": {"jit_decode": {"count": 150, "mean_ms": 20.0}},
-             "device_ops": [[f"kda_wave_update.{i}_f32_6_257_32_128_128_",
-                             0.3] for i in (3, 5, 7, 9)]
-             + [["latent_wave_attention.1_bf16_2_257_8192_640_", 0.5],
-                ["fusion.7_f32_256_2304_", 0.2]]}
+             "program_ops": {
+                 "jit_decode": {
+                     "kda_wave_update_f32_6_257_32_128_128_": [1.8, 900],
+                     "latent_wave_attention_bf16_2_257_8192_640_": [2.0, 300],
+                     "fusion_f32_256_2304_": [2.5, 4500]},
+                 "jit_prefill": {
+                     "kda_wave_update_f32_6_257_32_128_128_": [0.4, 60]}}}
     return {"cfg": cfg, "traffic": {"max_model_len": 8192},
             "snap_before": snap({k: 0 for k in after}),
             "snap_after": snap(after), "trace": trace,
@@ -89,17 +92,18 @@ def readers(cfg) -> int:
         *fam.kda_update(cfg, 250.0), roofline.peaks_for("TPU v5 lite"))
     got = reader(KDA)(ctx)
     status |= check(bound == "memory"
-                    and near(got, 100 * 150 * 4 * least / (4 * 0.3)),
-                    f"hand-made trace: four state kernels found, 150 steps "
-                    f"each against {least * 1e3:.3f} ms a call: {got:.2f}% "
-                    f"of the memory roofline, by the calls found and not by "
-                    f"six layers")
+                    and near(got, 100 * 900 * least / 1.8),
+                    f"hand-made trace: 900 events of the state kernel in "
+                    f"jit_decode, 1.8 s, against {least * 1e3:.3f} ms a call: "
+                    f"{got:.2f}% of the memory roofline, by the events the "
+                    f"trace holds and not by steps x six layers")
     parent = dict(ctx, snap_before=snap({"fetched_waves": 0}),
                   snap_after=snap({"fetched_waves": 100,
                                    "fetched_lanes_live": 25000,
                                    "fetched_positions_valid": 1}),
                   trace={"window_s": 4.0, "modules": ctx["trace"]["modules"],
-                         "device_ops": [["fusion.7_f32_", 0.2]]})
+                         "program_ops": {"jit_decode": {
+                             "fusion_f32_": [0.2, 150]}}})
     bare = dict(ctx, snap_before=None, snap_after=None, trace=None)
     other = dict(ctx, cfg=load_json(os.path.join(
         BENCH, "configs", "gpt2_small.json")))

@@ -4,6 +4,14 @@
 hand-made context whose figures can be worked out on paper, and the two
 trace readers on ``recorded_v5e.xplane.pb.gz`` (four ``jit_prefill`` programs
 of a v5e trace) against figures taken from the events directly.
+
+The four kernel readers (``KERNELS``) on a reduced trace in which every call
+of the kernel is shorter than ten other operations (PR 39: what a faster
+kernel makes of a trace): each reads its share from every call the table of
+operations holds, 1 / 0.7 times as much with the calls 30% shorter, and
+nothing only where the table holds no event of the kernel.  Then the trace
+reduction's own check (``check_trace.py``), so that the tests that run this
+file hold the reduction too.
 """
 
 import os
@@ -15,8 +23,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 sys.path.insert(0, BENCH)
 
+import family  # noqa: E402
+import roofline  # noqa: E402
 import tracereduce as tr  # noqa: E402
 from run import load_reader as reader  # noqa: E402  (by manifest name)
+from traffic import load_json  # noqa: E402
 
 STEP, SHARE, CALLS, STALL = (
     "prefill_step_device_ms.itl", "prefill_device_share.itl",
@@ -63,8 +74,111 @@ def check(ok, what):
     return 0 if ok else 1
 
 
-def main() -> int:
+# (metric, configuration, calls of the kernel a decode step, the window's
+# counters over 1000 waves)
+STEPS, KIND = 200, "TPU v5 lite"
+PANGU = dict(fetched_lanes_live=125_000, fetched_positions_valid=187_500_000,
+             expert_pairs_local=240_000, experts_touched=62_000)
+KERNELS = [
+    ("latent_attn_roofline.itl", "pangu_ultra_moe", 5, PANGU),
+    ("expert_ffn_roofline.itl", "pangu_ultra_moe", 4, PANGU),
+    ("kda_state_roofline.itl", "kimi_linear", 6,
+     dict(fetched_lanes_live=250_000, fetched_positions_valid=975_000_000,
+          expert_pairs_local=1_750_000, experts_touched=217_000)),
+    ("decode_attn_roofline.itl", "evabyte_6b5", 8,
+     dict(fetched_lanes_live=15_000, fetched_positions_valid=255_000_000,
+          fetched_rows_exact=15_000_000, fetched_rows_summary=15_000_000)),
+]
+
+
+def kernel_parts(metric, cfg, counters):
+    """{group of the kernel in ``jit_decode``: least seconds of one call at
+    the counters' means}, by the family's cost functions."""
+    fam = family.load(cfg["family"])
+    peaks = roofline.peaks_for(KIND)
+    lanes = counters["fetched_lanes_live"] / 1000
+    rows = counters["fetched_positions_valid"] / counters["fetched_lanes_live"]
+    if metric.startswith("decode_attn"):
+        per_lane = (counters["fetched_rows_exact"]
+                    + counters["fetched_rows_summary"]) / 1000 / lanes
+        return {"decode_wave_attention_bf16_8_17_4096_4096_":
+                roofline.min_seconds(
+                    *fam.decode_attention(cfg, lanes, per_lane), peaks)[0]}
+    if metric.startswith("kda_state"):
+        return {"kda_wave_update_f32_6_257_32_128_128_":
+                roofline.min_seconds(*fam.kda_update(cfg, lanes), peaks)[0]}
+    if metric.startswith("latent_attn"):
+        return {"latent_wave_attention_bf16_5_129_4096_640_":
+                roofline.min_seconds(
+                    *fam.latent_attention(cfg, lanes, rows), peaks)[0]}
+    n_moe = int(cfg["num_hidden_layers"]) - int(cfg["first_k_dense_replace"])
+    pairs = counters["expert_pairs_local"] / 1000 / n_moe
+    touched = counters["experts_touched"] / 1000 / n_moe
+    width = {"up": 2 * int(cfg["moe_intermediate_size"]),
+             "down": int(cfg["hidden_size"])}
+    return {f"grouped_matmul_f32_{fam.wave_rows(cfg)}_{n}_":
+            roofline.min_seconds(
+                *fam.expert_ffn(cfg, pairs, touched, part), peaks)[0]
+            for part, n in width.items()}
+
+
+def kernel_ctx(cfg, counters, kernel_groups):
+    """1000 waves in the window, all of the full bucket; a trace of 200 decode
+    steps whose ten longest groups are other operations; the kernel's name
+    once more in ``jit_prefill``, which no reader may count."""
+    lanes = int(cfg["serve"]["kwargs"]["max_streams"])
+
+    def snap(c, waves):
+        return {"profile": {"models": {"m:1": {
+            "generative": {"spans": {}, "counters": c},
+            "decode_waves": [{"bucket": lanes, "waves": waves,
+                              "device_s": 0.0}]}}}}
+    after = dict(counters, fetched_waves=1000)
+    table = {"jit_decode": {f"fusion_f32_{i}_": [10.0 + i, STEPS]
+                            for i in range(10)},
+             "jit_prefill": {g: [9.0, 7] for g in kernel_groups}}
+    table["jit_decode"].update(kernel_groups)
+    return {"cfg": cfg, "traffic": {}, "device": {"kind": KIND},
+            "snap_before": snap(dict.fromkeys(after, 0), 0),
+            "snap_after": snap(after, 1000),
+            "trace": {"window_s": 4.0, "program_ops": table,
+                      "modules": {"jit_decode": {"count": STEPS}},
+                      "device_ops": tr.breakdown_ops(
+                          table, {"jit_decode": STEPS, "jit_prefill": 7})}}
+
+
+def kernel_readers() -> int:
     status = 0
+    for metric, config, calls, counters in KERNELS:
+        cfg = load_json(os.path.join(BENCH, "configs", config + ".json"))
+        least = kernel_parts(metric, cfg, counters)
+        events = calls * STEPS
+
+        def groups(scale):          # every call at twice its least time
+            return {g: [scale * 2 * t * events, events]
+                    for g, t in least.items()}
+        ctx = kernel_ctx(cfg, counters, groups(1.0))
+        longest = sorted(s for s, _ in ctx["trace"]["program_ops"][
+            "jit_decode"].values())[-10:]
+        got = reader(metric)(ctx)
+        faster = reader(metric)(kernel_ctx(cfg, counters, groups(0.7)))
+        shown = tuple("jit_decode/" + g.rstrip("_") for g in least)
+        status |= check(
+            min(longest) > max(s for s, _ in groups(1.0).values())
+            and not any(line.startswith(shown)
+                        for line, _ in ctx["trace"]["device_ops"])
+            and near(got, 50.0) and near(faster, 50.0 / 0.7)
+            and reader(metric)(kernel_ctx(cfg, counters, {})) is None
+            and reader(metric)(dict(ctx, trace=None)) is None,
+            f"{metric} on {config}: {calls} calls a step, every one below "
+            f"the tenth longest operation, read {got!r}% from "
+            f"{events * len(least)} events; 30% shorter calls {faster!r}%; "
+            f"no event of the kernel in jit_decode: nothing")
+    return status
+
+
+def main() -> int:
+    status = kernel_readers()
     ctx = hand_made_ctx()
     status |= check(near(reader(STALL)(ctx), 100 * 70 / 120),
                     "hand-made gaps: 70 of 120 ms lie in gaps over three "
@@ -105,7 +219,9 @@ def main() -> int:
         and near(share, RECORDED_PREFILL_SHARE, 1e-6),
         f"recorded_v5e.xplane.pb.gz: {len(pre)} prefill programs, "
         f"{len(whole)} whole, {step!r} ms each, {share!r}% of the piece")
-    return status
+    import check_trace
+
+    return status | check_trace.main()
 
 
 # From the piece's events, as noted when this check was written (PR 27): the

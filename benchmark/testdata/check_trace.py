@@ -3,14 +3,18 @@
 
 1. A hand-made trace (an XSpace text proto written below): two programs on
    one device plane, operations that overlap and abut, a gap of known
-   length.  Every figure can be worked out on paper.
+   length.  Every figure can be worked out on paper, the table of every
+   operation by program and group with them; a second one (``NESTED``) holds
+   a ``while`` that encloses two operations and one that ran in no program.
 2. ``recorded_v5e*.xplane.pb.gz``: pieces of real traces recorded on the TPU
    v5e by this benchmark, cut to their first programs to stay small
    (``cut_trace.py``).  Their figures were computed once by an independent
    brute-force method (a 1 ns occupancy raster, ``raster_busy_ns`` below)
-   and are recomputed that way here as well.
+   and are recomputed that way here as well, and so is every group's self
+   time and event count (``raster_table``).
 """
 
+import bisect
 import os
 import sys
 
@@ -63,6 +67,67 @@ planes {
 """
 
 
+# jit_decode(9) runs [0, 10) us.  In it a ``while`` [1, 9) encloses fusion.3
+# [2, 4) and a kernel [3, 6), which overlap without one enclosing the other,
+# and fusion.4 [4.5, 5.5), which the kernel encloses: the while's own time is
+# 8 - |[2, 6)| = 4 us, the kernel's 3 - 1 = 2, fusion.3 keeps its 2, fusion.4
+# its 1: 9 us in all over a union of 8 (the overlap [3, 4) counts twice).
+# fusion.3 runs once more at [12, 13), in no program.
+NESTED = """
+planes {
+  id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Modules" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000 } }
+  lines { id: 2 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 2 offset_ps: 1000000 duration_ps: 8000000 }
+    events { metadata_id: 3 offset_ps: 2000000 duration_ps: 2000000 }
+    events { metadata_id: 4 offset_ps: 3000000 duration_ps: 3000000 }
+    events { metadata_id: 5 offset_ps: 4500000 duration_ps: 1000000 }
+    events { metadata_id: 3 offset_ps: 12000000 duration_ps: 1000000 } }
+  event_metadata { key: 1 value { id: 1 name: "jit_decode(9)" } }
+  event_metadata { key: 2 value { id: 2
+    name: "%while.2 = (s32[], bf16[8,16]{1,0}) while(%tuple.1)" } }
+  event_metadata { key: 3 value { id: 3
+    name: "%fusion.3 = f32[16,8]{1,0} fusion(%p.1), kind=kLoop" } }
+  event_metadata { key: 4 value { id: 4
+    name: "%my_kernel.7 = bf16[2,8]{1,0} custom-call(%p.2)" } }
+  event_metadata { key: 5 value { id: 5
+    name: "%fusion.4 = f32[16,8]{1,0} fusion(%p.3), kind=kLoop" } }
+}
+"""
+
+
+def raster_table(ops, mods) -> dict:
+    """Independent of ``self_ns`` and ``program_of``: for every event the
+    nanoseconds of its interval that no event it encloses covers, counted on
+    a raster (of two events over one interval the first in the line's order
+    encloses the second); its program by a scan of every module event."""
+    table: dict = {}
+    by_start = sorted((a, b, j) for j, (_, a, b) in enumerate(ops))
+    for i, (name, s, e) in enumerate(ops):
+        cover = bytearray(int(e - s))
+        k = bisect.bisect_left(by_start, (s,))
+        while k < len(by_start) and by_start[k][0] < e:
+            a, b, j = by_start[k]
+            if j != i and b <= e and ((a, b) != (s, e) or j > i):
+                cover[int(a - s):int(b - s)] = b"\x01" * int(b - a)
+            k += 1
+        inside = [m for m, a, b in mods if a <= s and e <= b]
+        program = tr.strip_hash(inside[-1]) if inside else tr.NO_PROGRAM
+        cell = table.setdefault(program, {}).setdefault(
+            tr.op_group(name), [0, 0])
+        cell[0] += len(cover) - sum(cover)
+        cell[1] += 1
+    return table
+
+
+def same_table(got: dict, want_ns: dict) -> bool:
+    return (got.keys() == want_ns.keys() and all(
+        got[p].keys() == want_ns[p].keys() and all(
+            got[p][g][1] == n and near(got[p][g][0], ns / 1e9, 1e-12)
+            for g, (ns, n) in want_ns[p].items()) for p in want_ns))
+
+
 def raster_busy_ns(intervals) -> int:
     """Independent of union_seconds: mark every nanosecond that any
     interval covers, then count."""
@@ -92,12 +157,42 @@ def main() -> int:
           and r["modules"]["jit_apply"]["count"] == 2
           and near(r["modules"]["jit_apply"]["mean_ms"], 0.003)
           and near(r["modules"]["jit_prefill"]["mean_ms"], 0.002)
-          and r["idle_gaps"] == [["before_jit_prefill_77_", 2e-6]]
-          and sorted(n for n, _ in r["device_ops"])
-          == ["copy-done.2", "fusion.1"]
-          and all(near(t, 4e-6) for _, t in r["device_ops"]))
+          and r["idle_gaps"] == [["before_jit_prefill_77_", 2e-6]])
     print(("ok   " if ok else "FAIL ") + "hand-made trace: busy 7.5 us of "
           "10 us, idle 25%, one 2 us gap, step means 3 us and 2 us")
+    if not ok:
+        print(r)
+        return 1
+    # on paper: jit_apply's two runs hold fusion [0,1), [3,4), [8,10) and
+    # copy-done [0.5,3) (it overlaps the first fusion and is not inside it);
+    # jit_prefill holds copy-done [6,7.5).
+    ok = (same_table(r["program_ops"], {
+        "jit_apply": {"fusion": (4000, 3), "copy-done": (2500, 1)},
+        "jit_prefill": {"copy-done": (1500, 1)}})
+        and near(r["program_ops_union_s"]["jit_apply"], 6e-6)
+        and r["device_ops"] == [["jit_apply/fusion_x2", 4e-6],
+                                ["jit_apply/copy-done_x1", 2.5e-6],
+                                ["jit_prefill/copy-done_x1", 1.5e-6]])
+    print(("ok   " if ok else "FAIL ") + "hand-made trace: every operation "
+          "by program and group, 6.5 us of jit_apply's groups over a union "
+          "of 6 us; the breakdown names program, group and calls a program")
+    if not ok:
+        print(r)
+        return 1
+    pd = ProfileData.from_serialized_xspace(
+        ProfileData.text_proto_to_serialized_xspace(NESTED))
+    r = tr.reduce_trace(pd)
+    _, ops, mods = tr.device_lines(pd)[0]
+    ok = (same_table(r["program_ops"], {
+        "jit_decode": {"while_s32_": (4000, 1), "fusion_f32_16_8_": (3000, 2),
+                       "my_kernel_bf16_2_8_": (2000, 1)},
+        tr.NO_PROGRAM: {"fusion_f32_16_8_": (1000, 1)}})
+        and same_table(r["program_ops"], raster_table(ops, mods))
+        and near(r["program_ops_union_s"]["jit_decode"], 8e-6)
+        and near(r["busy_s"], 9e-6))
+    print(("ok   " if ok else "FAIL ") + "hand-made while: its own 4 us of "
+          "8, not beside its body; two operations that overlap keep their "
+          "lengths (9 us over a union of 8); one operation in no program")
     if not ok:
         print(r)
         return 1
@@ -119,19 +214,38 @@ def main() -> int:
             return 1
         pd = tr.load(rec)
         r = tr.reduce_trace(pd)
-        ops = [(s, e) for _, s, e in tr.device_lines(pd)[0][1]]
+        _, events, mods = tr.device_lines(pd)[0]
+        ops = [(s, e) for _, s, e in events]
         busy = raster_busy_ns(ops) / 1e9
         window = (max(e for _, e in ops) - min(s for s, _ in ops)) / 1e9
+        table = r["program_ops"]
+        groups = sum(len(g) for g in table.values())
         ok = (near(r["busy_s"], busy, 1e-6)
               and near(r["window_s"], window, 1e-6)
               and near(r["busy_s"], known_busy, 1e-6)
-              and near(r["window_s"], known_window, 1e-6))
+              and near(r["window_s"], known_window, 1e-6)
+              and same_table(table, raster_table(events, mods))
+              and sum(n for g in table.values() for _, n in g.values())
+              == len(events)
+              and all(near(sum(s for s, _ in table[p].values()), u, 1e-9)
+                      for p, u in r["program_ops_union_s"].items()))
         print(("ok   " if ok else "FAIL ") + f"{name}: busy "
               f"{r['busy_s']:.9f} s of {r['window_s']:.9f} s (raster "
               f"{busy:.9f} of {window:.9f}; idle "
-              f"{100 * r['idle_share']:.3f}%)")
+              f"{100 * r['idle_share']:.3f}%); {len(events)} operations in "
+              f"{groups} groups of {sorted(table)}, each group's self time "
+              f"and events as the raster gives them, summing to the "
+              f"program's union of intervals")
         status |= 0 if ok else 1
-    return status
+    # The third recorded piece (gpt2_small.chat's programs and host spans,
+    # PR 25) holds no operation line: an empty table, nothing to print.
+    r = tr.reduce_trace(tr.load(os.path.join(
+        HERE, "recorded_v5e_chat_hostspans.xplane.pb.gz")))
+    ok = (r["program_ops"] == {} and r["device_ops"] == []
+          and r["modules"]["jit_decode"]["count"] == 6 and r["busy_s"] > 0)
+    print(("ok   " if ok else "FAIL ") + "recorded_v5e_chat_hostspans."
+          "xplane.pb.gz: six programs, no operation line, an empty table")
+    return status | (0 if ok else 1)
 
 
 # Pieces of traces recorded on the TPU v5e by this benchmark (my chip runs,
