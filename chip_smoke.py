@@ -1181,6 +1181,12 @@ def kernels_child(rehearsal: bool) -> int:
         # of 7680 x 4096 (gate | up) and 2048 x 7680 (down).
         latent_case("latent_wave_attention(128 heads x 576)", 2, 17, 4096, 8,
                     128, 512, 64, [700, 0, 4095, 511, 512, 513, 1, 0])
+        # The hybrid cell's: 32 heads in a tile of 128 lanes, slots of 8192
+        # rows (sixteen blocks for three buffers), lanes without a live row
+        # between long ones, a full slot, lengths around a block's edge.
+        latent_case("latent_wave_attention(32 heads x 576, 8192 rows)", 2,
+                    17, 8192, 8, 32, 512, 64,
+                    [3300, 0, 8191, 0, 1024, 1025, 5119, 0])
         grouped_case("grouped_matmul(16 x 7680 x 4096)", 16, 7680, 4096,
                      1024, 16)
         grouped_case("grouped_matmul(16 x 2048 x 7680)", 16, 2048, 7680,

@@ -50,6 +50,20 @@ k/v being written) is folded in at the finalize step from registers, so the
 kernel never reads back its own write and the order of the group's
 write-back against the pipeline's block reads cannot matter.
 
+The **latent** kernel (``latent_wave_attention``: one row a position, shared
+by every head) has the same contract and another walk.  Its grid is the lanes
+alone and the arena stays in HBM: a lane's program loops over the lane's
+``ceil(len / block_s)`` live blocks, a trip count read from the prefetched
+``lens``, and the wave's live blocks, lane after lane, are one stream of
+``make_async_copy`` through a ring of three VMEM buffers, two copies ahead of
+the products.  No step exists without a block, a lane of length 0 copies
+none, and a lane's first block is on its way before the lane before it has
+finished.  (On the v5e, against the ``(B, S // block_s)`` grid it had: a live
+block 1.36 -> 1.20 us, a lane without one 2.81 -> 1.29 us where a slot has
+eight blocks, a call at ``pangu_ultra_moe.reasoning``'s lengths 0.83 -> 0.59
+ms and at ``kimi_linear.longgen``'s 2.87 -> 2.16 ms: PERF.md section 6,
+PR 40.)
+
 ``interpret=True`` runs the same kernel on CPU; the tier-1 suite and
 ci_check drive it that way (tests/test_ops.py parity suite).  The sharded
 cross-chip variant wraps this kernel per shard — see
@@ -310,18 +324,36 @@ def latent_row_width(rank: int, rope_dim: int) -> int:
     """Lanes of a latent cache row ``[c (rank) | k_r (rope_dim) | 0]``: the
     two parts side by side, padded to whole 128-lane tiles (512 + 64 -> 640).
     One leaf and not two (512 and 128): the bytes are the same, and one leaf
-    is one block DMA a grid step, one score matmul over the row and one row
+    is one block DMA a block of rows, one score matmul over the row and one row
     group written a lane; the zero lanes cost a ninth of the row's reads."""
     return -(-(rank + rope_dim) // 128) * 128
 
 
+# Rows of a latent block, and the VMEM buffers the wave's blocks go round.
+# Chosen on the v5e (PERF.md section 6, PR 40).  A shorter block wastes less
+# of a lane's last one, but the products' cost hardly falls with it (1.15 us
+# a block of 512 with no copy at all, 1.04 of 256), so a call at
+# ``pangu_ultra_moe.reasoning``'s lengths reads 0.60 ms at 512 and 0.96 at
+# 256 (0.68, 0.98 and 0.78 at 1024 through two buffers).  Two buffers leave
+# the live block where the grid had it (1.33 us: a copy then starts into the
+# buffer the block before was just read from); three take it to 1.20, four
+# read the same.
+_LATENT_BLOCK_S = 512
+_LATENT_RING = 3
+
+
 def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
                    value_dim: int):
-    """One (lane, row-block) grid step of ``latent_wave_attention``: as
-    ``_decode_kernel`` but every head reads the same row, whose first
-    ``value_dim`` lanes are also the value.  The heads run along the lanes:
-    scores ``[block_s, H]``, the softmax carry ``[1, H]``, the accumulator
-    ``[V, H]``."""
+    """One lane of ``latent_wave_attention``: a loop over the lane's live
+    blocks, ``ceil(len / block_s)`` of them.  The wave's live blocks, lane
+    after lane, are one stream through a ring of VMEM buffers: ``walk``
+    (SMEM) holds the lane and block of the next one to fetch and how many
+    were fetched and used, so the copies run ahead of the products across a
+    lane's end, over lanes without a live row, and a lane never starts with
+    an exposed copy.  As ``_decode_kernel`` but every head reads the same
+    row, whose first ``value_dim`` lanes are also the value.  The heads run
+    along the lanes: scores ``[block_s, H]``, the softmax carry ``[1, H]``,
+    the accumulator ``[V, H]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -330,34 +362,81 @@ def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
         refs = refs[1:]
     (c_ref, q_ref, new_ref,                             # inputs
      co_ref, o_ref,                                     # outputs
-     m_ref, l_ref, acc_ref, buf, sem) = refs            # scratch
+     m_ref, l_ref, acc_ref, blocks, buf, sem, walk) = refs      # scratch
+    del c_ref                            # aliased: ``co_ref`` is the cache
     b = pl.program_id(0)
-    ik = pl.program_id(1)
-    nk = pl.num_programs(1)
-    row = rows_ref[b]
+    lanes = pl.num_programs(0)
     length = lens_ref[b]                 # valid prefix length (strict)
-    group = buf.shape[0]
-    g0 = pl.multiple_of((length // group) * group, group)
-    hbm = co_ref.at[layer, row, pl.ds(g0, group)]
-    cache_dtype = c_ref.dtype
+    n_blocks = pl.cdiv(length, block_s)
+    ring = blocks.shape[0]
+    group = buf.shape[1]
+    # ``lax.div`` and ``lax.rem`` on these counts, which are never negative:
+    # ``//`` and ``%`` lower through a traced function each, 0.1-0.2 s of a
+    # wave program's first use in the serving process (PERF.md section 6,
+    # PR 40).
+    g0 = pl.multiple_of(jax.lax.div(length, group) * group, group)
+    mine = jax.lax.rem(b, 2)
+    hbm = co_ref.at[layer, rows_ref[b], pl.ds(g0, group)]
+    cache_dtype = co_ref.dtype
     highest = (jax.lax.Precision.HIGHEST if cache_dtype == jnp.float32
                else None)
 
-    @pl.when(ik == 0)
-    def _init():
-        pltpu.make_async_copy(hbm, buf, sem.at[0]).start()
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def block_copy(lane, i, n):
+        """Block ``i`` of ``lane``'s slot, the wave's ``n``-th live one."""
+        start = pl.multiple_of(i * block_s, block_s)
+        slot = jax.lax.rem(n, ring)
+        return pltpu.make_async_copy(
+            co_ref.at[layer, rows_ref[lane], pl.ds(start, block_s)],
+            blocks.at[slot], sem.at[2 + slot])
 
-    @pl.when(ik * block_s < length)
-    def _block():
+    def live_lane(lane):
+        """The first lane from ``lane`` on that has a live row, or
+        ``lanes``."""
+        return jax.lax.while_loop(
+            lambda j: (j < lanes) & (lens_ref[jax.lax.min(j, lanes - 1)]
+                                     == 0),
+            lambda j: j + 1, lane)
+
+    def fetch():
+        """Start the copy of the wave's next live block, if one is left."""
+        at = walk[0]
+
+        @pl.when(at < lanes)
+        def _():
+            i, n = walk[1], walk[2]
+            block_copy(at, i, n).start()
+            walk[2] = n + 1
+            last = i + 1 == pl.cdiv(lens_ref[at], block_s)
+            walk[1] = jax.lax.select(last, jnp.zeros_like(i), i + 1)
+
+            @pl.when(last)
+            def _():
+                walk[0] = live_lane(at + 1)
+
+    @pl.when(b == 0)
+    def _first():
+        walk[0] = live_lane(0)
+        walk[1] = 0
+        walk[2] = 0
+        walk[3] = 0
+        jax.lax.fori_loop(0, ring - 1, lambda _, c: fetch(), None)
+
+    pltpu.make_async_copy(hbm, buf.at[mine], sem.at[0]).start()
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    used = walk[3]
+
+    def block(i, carry):
+        n = used + i
+        fetch()              # into the buffer of the block used before this
+        block_copy(b, i, n).wait()
         # Both products stream the block's rows past a stationary 128-wide
         # operand: Q^T, then p.
-        blk = c_ref[...]                                 # [block_s, W]
+        blk = blocks[jax.lax.rem(n, ring)]               # [block_s, W]
         s = jnp.dot(blk, q_ref[0], preferred_element_type=jnp.float32,
                     precision=highest)                   # [block_s, Hp]
-        pos = ik * block_s + jax.lax.broadcasted_iota(
+        pos = i * block_s + jax.lax.broadcasted_iota(
             jnp.int32, (block_s, 1), 0)
         valid = pos < length
         s = jnp.where(valid, s, _NEG_INF)
@@ -371,28 +450,43 @@ def _latent_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
             blk[:, :value_dim], p.astype(cache_dtype),
             (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
             precision=highest)                           # [V, Hp]
+        return carry
 
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        # The new row as the cache will hold it, folded in from registers
-        # (position ``length``: always valid, so a padded lane with an empty
-        # prefix reads exactly its own row and never divides by zero).
-        new_c = new_ref[0].astype(cache_dtype)           # [1, W]
-        s_new = jnp.dot(new_c, q_ref[0], preferred_element_type=jnp.float32,
-                        precision=highest)               # [1, Hp]
-        m_fin = jnp.maximum(m_ref[...], s_new)
-        p_new = jnp.exp(s_new - m_fin)
-        corr = jnp.exp(m_ref[...] - m_fin)
-        l_fin = l_ref[...] * corr + p_new
-        value = new_c[:, :value_dim].astype(jnp.float32).T   # [V, 1]
-        o_ref[0] = ((acc_ref[...] * corr + value * p_new)
-                    / l_fin).astype(o_ref.dtype)
-        # The one write into the arena: the new row, inside its row group.
-        pltpu.make_async_copy(hbm, buf, sem.at[0]).wait()
-        ins = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0) == length - g0
-        buf[...] = jnp.where(ins, new_c, buf[...])
-        back = pltpu.make_async_copy(buf, hbm, sem.at[1])
-        back.start()
+    jax.lax.fori_loop(0, n_blocks, block, None)
+    walk[3] = used + n_blocks
+
+    # The new row as the cache will hold it, folded in from registers
+    # (position ``length``: always valid, so a padded lane with an empty
+    # prefix reads exactly its own row and never divides by zero).
+    new_c = new_ref[0].astype(cache_dtype)               # [1, W]
+    s_new = jnp.dot(new_c, q_ref[0], preferred_element_type=jnp.float32,
+                    precision=highest)                   # [1, Hp]
+    m_fin = jnp.maximum(m_ref[...], s_new)
+    p_new = jnp.exp(s_new - m_fin)
+    corr = jnp.exp(m_ref[...] - m_fin)
+    l_fin = l_ref[...] * corr + p_new
+    value = new_c[:, :value_dim].astype(jnp.float32).T   # [V, 1]
+    o_ref[0] = ((acc_ref[...] * corr + value * p_new)
+                / l_fin).astype(o_ref.dtype)
+    # The one write into the arena: the new row, inside its row group.  Two
+    # group buffers take turns, so the write-back is waited for a lane on,
+    # behind that lane's blocks.  Nothing reads what is in flight: a wave's
+    # lanes hold slots of their own, but for the padded lanes on the dummy
+    # slot, whose groups differ in the one row each of them replaces.
+    pltpu.make_async_copy(hbm, buf.at[mine], sem.at[0]).wait()
+    ins = jax.lax.broadcasted_iota(
+        jnp.int32, buf.shape[1:], 0) == length - g0
+    buf[mine] = jnp.where(ins, new_c, buf[mine])
+
+    @pl.when(b > 0)
+    def _():     # the lane before's (a wait reads the size, not the place)
+        pltpu.make_async_copy(buf.at[1 - mine], hbm, sem.at[1]).wait()
+
+    back = pltpu.make_async_copy(buf.at[mine], hbm, sem.at[1])
+    back.start()
+
+    @pl.when(b == lanes - 1)
+    def _():
         back.wait()
 
 
@@ -411,9 +505,24 @@ def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
     ``[B]`` int32.  Returns ``(c_arena, o)``: the new row written at
     ``(layer, rows[b], lens[b])`` in place and ``o [B, value_dim, H]``
     float32, ``softmax(row . q)`` over rows ``0 .. lens[b]`` inclusive
-    applied to the rows' first ``value_dim`` lanes.  Grid, scalar prefetch,
-    skipped blocks and the row-group write are ``decode_wave_attention``'s;
-    the products differ.  A lane has only its 128 heads to put beside a
+    applied to the rows' first ``value_dim`` lanes.  Scalar prefetch and
+    the row-group write are ``decode_wave_attention``'s; the walk and the
+    products differ.
+
+    **The walk.**  The grid is the lanes, in order; the arena stays in HBM.
+    A lane loops over its ``ceil(len / block_s)`` live blocks and no others,
+    and the wave's live blocks are one stream through a ring of
+    ``_LATENT_RING`` VMEM buffers (``_latent_kernel``): each iteration starts
+    the copy of the block two ahead, which may be the next live lane's, then
+    waits for its own.  A lane of length 0 (a padded lane on the dummy row)
+    copies no block.  The row group's write-back is waited for a lane on.
+    (On the v5e, against a grid step a block place: 588 of a
+    ``pangu_ultra_moe.reasoning`` call's 1024 steps and 2200 of a
+    ``kimi_linear.longgen`` call's 3950 held no block at 0.29 us each, and a
+    live step's 0.29 us ran behind its copy and products, not beside them;
+    timings in the module docstring and PERF.md section 6, PR 40.)
+
+    **The products.**  A lane has only its 128 heads to put beside a
     block of 512 rows, so the **block is the operand that streams through
     the MXU** and the 128-wide one stands still: ``scores [s, H] = C_blk [s,
     W] @ Q^T [W, H]`` and ``acc [V, H] += C_blk[:, :V]^T @ p [s, H]`` load 5
@@ -421,11 +530,12 @@ def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
     rows loaded the block's 20 + 16 tiles with 128 rows past each.  The
     heads lie along the lanes, so the softmax reduces down the sublanes
     (adds of whole registers, no reduction across lanes) and its carry is a
-    ``[1, H]`` vector; the query arrives scaled and rounded, so no grid step
-    touches it.  Fewer heads than a lane tile are padded to one.  (On the
-    v5e a live block fell 1.46 -> 1.33 us and a layer's 1024 grid steps
-    without one 0.35 -> 0.30 ms; Mosaic transposes the block's value lanes
-    for the second product at 0.05 us a block: PERF.md section 6, PR 33.)"""
+    ``[1, H]`` vector; the query arrives scaled and rounded, so no block's
+    work touches it.  Fewer heads than a lane tile are padded to one.  (On
+    the v5e, under the grid of then, a live block fell 1.46 -> 1.33 us and a
+    layer's 1024 grid steps without one 0.35 -> 0.30 ms; Mosaic transposes
+    the block's value lanes for the second product at 0.05 us a block:
+    PERF.md section 6, PR 33.)"""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -436,7 +546,7 @@ def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
             f"cache rows hold {w} lanes of {c_arena.dtype}, q {wq} of "
             f"{q.dtype}, the new row {new_row.shape}")
     if block_s is None:
-        block_s = pick_block_s(s)
+        block_s = pick_block_s(s, _LATENT_BLOCK_S)
     if s % block_s:
         raise ValueError(f"block_s ({block_s}) must divide the slot's rows "
                          f"({s})")
@@ -448,19 +558,14 @@ def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
     prefetch = (rows, lens) + (
         (jnp.asarray(layer_index, jnp.int32).reshape(1),) if dynamic else ())
 
-    def arena_map(b, ik, rows, lens, *li):
-        last = jnp.maximum(lens[b] - 1, 0) // block_s
-        return (li[0][0] if dynamic else layer, rows[b],
-                jnp.minimum(ik, last), 0)
-
-    def lane_map(b, ik, rows, lens, *li):
+    def lane_map(b, rows, lens, *li):
         return (b, 0, 0)
 
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(bsz, s // block_s),
-        in_specs=[pl.BlockSpec((None, None, block_s, w), arena_map),
+        grid=(bsz,),
+        in_specs=[in_hbm,
                   pl.BlockSpec((1, w, hp), lane_map),
                   pl.BlockSpec((1, 1, w), lane_map)],
         out_specs=[in_hbm, pl.BlockSpec((1, value_dim, hp), lane_map)],
@@ -468,8 +573,11 @@ def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
             pltpu.VMEM((1, hp), jnp.float32),          # running max
             pltpu.VMEM((1, hp), jnp.float32),          # running denominator
             pltpu.VMEM((value_dim, hp), jnp.float32),  # weighted accumulator
-            pltpu.VMEM((group, w), c_arena.dtype),     # the new row's group
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((_LATENT_RING, block_s, w), c_arena.dtype),   # blocks
+            pltpu.VMEM((2, group, w), c_arena.dtype),  # the new row's group
+            # One semaphore the group's read, one its write-back, one a block.
+            pltpu.SemaphoreType.DMA((2 + _LATENT_RING,)),
+            pltpu.SMEM((4,), jnp.int32),               # the walk
         ],
     )
     kernel = functools.partial(_latent_kernel, layer=layer, block_s=block_s,
@@ -482,6 +590,7 @@ def latent_wave_attention(c_arena, q, new_row, rows, lens, *, layer,
                    jax.ShapeDtypeStruct((bsz, value_dim, hp), jnp.float32)],
         input_output_aliases={len(prefetch): 0},
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=min(100 << 20, 6 * block_bytes + (24 << 20))),
         interpret=interpret,
         name="latent_wave_attention",

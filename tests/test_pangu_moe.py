@@ -6,6 +6,7 @@ expert-parallel group against the uncut layer, dropless routing, the two new
 kernels against their oracles, the wave's routing counts through the
 scheduler, and the benchmark family's readers and arithmetic."""
 
+import functools
 import os
 import sys
 import threading
@@ -304,6 +305,20 @@ LATENT_CASES = {
     "narrow_values": (4, 64, 200, [5, 17, 63, 16, 0]),
     "around_a_block_edge": (4, 32, 8, [15, 17, 31, 33, 47]),
     "128_heads": (128, 32, 8, [0, 17, 63, 16, 1]),
+    # The walk over a lane's live blocks (PR 40): the wave's live blocks are
+    # one stream through a ring of three buffers, fetched two ahead across
+    # the lanes' ends.  No lane with a block; one lane alone; empty lanes
+    # between long ones (the copies started across lanes skip them and are
+    # not lost); the last lane the longest; every block of every slot live;
+    # lengths on a block's edge (``around_a_block_edge`` has its two sides);
+    # a slot (a fifth entry: its rows) of eight blocks for three buffers.
+    "no_live_lane": (4, 32, 8, [0, 0, 0, 0, 0]),
+    "one_lane": (4, 32, 8, [41]),
+    "empty_lanes_between": (4, 32, 8, [61, 0, 0, 55, 3]),
+    "last_lane_longest": (4, 32, 8, [3, 17, 0, 20, 63]),
+    "full_slots": (4, 32, 8, [63, 63, 63, 63, 63]),
+    "on_a_block_edge": (4, 32, 8, [16, 32, 48, 0, 33]),
+    "eight_blocks": (4, 32, 8, [127, 70, 0, 9, 100], 128),
 }
 
 
@@ -316,13 +331,14 @@ def test_latent_kernel_parity(dtype, atol, scanned, case):
     H]``, against the oracle: the row written in place and exact, every
     other row bitwise kept."""
     rng = np.random.default_rng(0)
-    h, rank, rp, lens = LATENT_CASES[case]
-    layers, slots, s = 2, 7, 64
+    h, rank, rp, lens, *slot_rows = LATENT_CASES[case]
+    layers, slots, s = 2, 7, *(slot_rows or [64])
+    lanes = len(lens)
     w = latent_row_width(rank, rp)
     c = jnp.asarray(rng.standard_normal((layers, slots, s, w)), dtype)
-    q = jnp.asarray(0.25 * rng.standard_normal((5, w, h)), dtype)
-    new = jnp.asarray(rng.standard_normal((5, w)), jnp.float32)
-    rows = jnp.asarray([3, 0, 5, 1, 6], jnp.int32)
+    q = jnp.asarray(0.25 * rng.standard_normal((lanes, w, h)), dtype)
+    new = jnp.asarray(rng.standard_normal((lanes, w)), jnp.float32)
+    rows = jnp.asarray([3, 0, 5, 1, 6][:lanes], jnp.int32)
     lens = jnp.asarray(lens, jnp.int32)
     kw = dict(layer=None, layer_index=jnp.int32(1)) if scanned \
         else dict(layer=1)
@@ -331,7 +347,7 @@ def test_latent_kernel_parity(dtype, atol, scanned, case):
         **kw)
     want_c, want = reference_latent_attention(
         c, q, new, rows, lens, layer=1, value_dim=rank)
-    assert got.shape == (5, rank, h) and got.dtype == jnp.float32
+    assert got.shape == (lanes, rank, h) and got.dtype == jnp.float32
     assert float(jnp.abs(got - want).max()) < atol
     assert bool((got_c == want_c).all())
     touched = np.zeros(c.shape[:3], bool)
@@ -341,6 +357,30 @@ def test_latent_kernel_parity(dtype, atol, scanned, case):
     assert np.array_equal(
         np.asarray(got_c)[1, np.asarray(rows), np.asarray(lens)],
         np.asarray(new.astype(dtype)))
+
+
+@pytest.mark.parametrize("slot_rows", [4096, 8192])
+def test_latent_kernel_grid_is_the_lanes(slot_rows):
+    """No grid axis over a slot's blocks, so no step without a block
+    (PR 40): the grid is the wave's lanes whatever the slot holds, the cache
+    goes in and out whole and in HBM (aliased), and its blocks of 512 rows,
+    the size the chip chose, go through a ring of three VMEM buffers."""
+    lanes, w = 8, 640
+    c = jax.ShapeDtypeStruct((2, 9, slot_rows, w), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((lanes, w, 128), jnp.bfloat16)
+    new = jax.ShapeDtypeStruct((lanes, w), jnp.float32)
+    i32 = jax.ShapeDtypeStruct((lanes,), jnp.int32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        latent_wave_attention, layer=1, value_dim=512))(c, q, new, i32, i32)
+    (call,) = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (lanes,)
+    assert call.params["input_output_aliases"] == ((2, 0),)
+    refs = [str(v.aval) for v in call.params["jaxpr"].invars]
+    arena = f"Ref<any>{{bfloat16[2,9,{slot_rows},{w}]}}"
+    assert refs.count(arena) == 2
+    assert [r for r in refs if f"512,{w}]" in r] == [
+        f"Ref<vmem>{{bfloat16[3,512,{w}]}}"]
 
 
 def test_the_wave_query_is_what_the_kernel_used_to_round_to():

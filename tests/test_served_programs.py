@@ -13,7 +13,10 @@ Pallas kernels' own jaxprs, each against the hash recorded at commit 6b8c7c9
 matmuls, prefill by flash pieces) at the tree PR 33 left; ``kimi``'s
 (models/kimi_linear.py: the state kernel beside the latent one, the chunked
 piece) at the tree PR 34 left, which moved what ``pangu`` and ``kimi`` share
-into models/latent_moe.py and left ``pangu``'s two as they were.  A PR that means to
+into models/latent_moe.py and left ``pangu``'s two as they were; the kernels
+of ``pangu``'s and ``kimi``'s decode waves at the tree PR 40 left (the latent
+kernel walks a lane's live blocks: its body and its grid changed, the
+programs around it did not).  A PR that means to
 change one of these programs records the new hash here and says so; one that
 does not has a guard.
 
@@ -34,9 +37,9 @@ RECORDED = {
     ("evabyte", "prefill"): ("d6eccbae5564229b", "d3dffbbfe8efac92"),
     ("gpt", "decode"): ("92758237abe83ac2", "61153d74d471a1af"),
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),
-    ("kimi", "decode"): ("3c7634b1c3eb0637", "a5f23242e0be48b2"),
+    ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),
     ("kimi", "prefill"): ("93c6ccfe91d5a268", "5dd18e6e271299fb"),
-    ("pangu", "decode"): ("b73c536a3102de37", "25dcc382561a488e"),
+    ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),
     ("pangu", "prefill"): ("2947c2e62c0d448d", "fa56a8062981bfe9"),
 }
 
