@@ -139,7 +139,7 @@ class _Stream:
     __slots__ = ("req", "row", "disp_len", "disp_tokens", "f_len",
                  "emitted", "max_new", "seed", "temp", "top_k", "top_p",
                  "stop", "dead", "throttled_since", "ids", "consumed",
-                 "transition", "t_prefill", "sink", "record")
+                 "transition", "sink", "record")
 
     def __init__(self, req, row, plen, max_new,
                  seed=0, temp=0.0, top_k=0, top_p=1.0, stop=frozenset()):
@@ -160,11 +160,10 @@ class _Stream:
         self.dead = False         # retired/cancelled (skip pending lanes)
         self.throttled_since = None  # monotonic mark while backpressured
         # Prefill by pieces: the prompt still to consume (None once its last
-        # piece is dispatched, and always for a one-shot prefill), how much
-        # of it is dispatched, and when its first piece was.
+        # piece is dispatched, and always for a one-shot prefill) and how
+        # much of it is dispatched.
         self.ids = None
         self.consumed = 0
-        self.t_prefill = 0
         self.transition = False   # a cache transition is due before a wave
         # The rows of its record fetched so far, piece by piece and wave by
         # wave (None: it did not ask for one).
@@ -180,20 +179,21 @@ class _Inflight:
     """One dispatched execution whose token fetch is pending."""
 
     __slots__ = ("kind", "streams", "tokens", "waves", "t_disp", "bucket",
-                 "depth", "positions", "rows", "pieces")
+                 "depth", "positions", "rows", "pieces", "fresh")
 
     def __init__(self, kind, streams, tokens, waves=1, t_disp=0, bucket=0,
-                 depth=0, positions=0, rows=(0, 0), pieces=()):
+                 depth=0, positions=0, rows=(0, 0), pieces=(), fresh=()):
         self.kind = kind          # 'prefill' | 'piece' | 'wave' | 'chunk'
         self.streams = streams    # lane order, real lanes only
         self.tokens = tokens      # jax.Array future (copy_to_host_async'd)
         self.waves = waves        # logical waves this dispatch advances
-        self.t_disp = t_disp      # monotonic ns at dispatch (wave timing)
+        self.t_disp = t_disp      # monotonic ns at a wave's dispatch
         self.bucket = bucket      # wave bucket (0 for prefill)
         self.depth = depth        # waves in flight at a prefill's dispatch
         self.positions = positions  # valid context positions it reads
         self.rows = rows          # cache rows it reads: (summary, exact)
         self.pieces = pieces      # a piece's lanes: (stream, valid positions)
+        self.fresh = fresh        # a wave's lanes that decode their first token
 
 
 class _WarmupReq:
@@ -385,6 +385,14 @@ class GenerativeScheduler(Scheduler):
         # from max(dispatch, previous fetch) to this fetch, so pipelined
         # waves are not double-counted (see _drain_fetches).
         self._last_fetch_ns = 0
+        # The token gap as this worker produces it (the ``gap_*`` counters):
+        # the previous decode fetch (0: none since the last ``gen.idle``, so
+        # the next decode fetch closes no gap) and whether a prefill call's
+        # fetch came since.  The fetch queue keeps dispatch order and
+        # dispatch order is device order, so a prefill head popped between
+        # two decode heads ran between those two waves on the chip.
+        self._last_decode_fetch_ns = 0
+        self._prefill_since_decode = False
         # (bucket, chunk) wave shapes whose static cost model has been
         # captured — decode waves never pass Model.execute_timed, so the
         # roofline numerator is pulled here, once per shape.
@@ -522,7 +530,7 @@ class GenerativeScheduler(Scheduler):
         # a new request joins the *next* wave, never waits for a stream
         # to finish.
         if not self._streams and not self._inflight:
-            with span[_sp.S_IDLE]:
+            with self._idle():
                 item = self.queue.get()
             if item is _SHUTDOWN:
                 return True
@@ -581,7 +589,7 @@ class GenerativeScheduler(Scheduler):
             # (engine.shutdown joins this thread), and a warmup
             # sentinel must not rot behind throttled streams.
             try:
-                with span[_sp.S_IDLE]:
+                with self._idle():
                     item = self.queue.get(timeout=0.001)
             except _queue.Empty:
                 return False
@@ -596,9 +604,16 @@ class GenerativeScheduler(Scheduler):
                 # core — the loop-top opportunistic admit takes it the
                 # moment a slot frees.
                 self.queue.put_front(item)
-                with span[_sp.S_IDLE]:
+                with self._idle():
                     time.sleep(0.001)
         return False
+
+    def _idle(self):
+        """``gen.idle``.  The device drains while the worker waits for work,
+        so a token gap across it is no wave-to-wave interval: the next decode
+        fetch closes none (the ``gap_*`` counters)."""
+        self._last_decode_fetch_ns = 0
+        return self._rec.span[_sp.S_IDLE]
 
     def _sweep(self) -> list:
         """Drop cancelled streams and return the lanes of the next wave."""
@@ -743,6 +758,18 @@ class GenerativeScheduler(Scheduler):
                 self._reset_arena(exc, failing=chunk[0][0])
                 return
 
+    def _count_started(self, req: InferRequest, now: int) -> None:
+        """A prompt's first prefill call (its first piece, or its one-shot
+        program) returned at ``now``: its two waits up to here.  The engine's
+        queue, the line for a prefill call and ``first_token_wait_ns`` (from
+        ``now`` on) partition ``first_token - queue_start`` of the request."""
+        times = req.times
+        times.prefill_start = now
+        c = self._rec.c
+        c[_sp.C_PROMPTS_STARTED] += 1
+        c[_sp.C_ADMIT_WAIT_NS] += times.queue_ns
+        c[_sp.C_PREFILL_LINE_WAIT_NS] += now - times.compute_start
+
     def _stage_lanes(self, lanes: list, width: int):
         """(rows, seeds, temps, top_ks, top_ps), each ``[width]``, as every
         program takes them: the lanes' streams first, the rest padded onto
@@ -799,8 +826,10 @@ class GenerativeScheduler(Scheduler):
         self.stats.record_execution(n)
         self._rec.c[_sp.C_PREFILL_LANES_LIVE] += n
         self._rec.c[_sp.C_PREFILL_LANES_PADDED] += lane - n
+        now = time.monotonic_ns()
+        for s in streams:
+            self._count_started(s.req, now)
         self._inflight.append(_Inflight("prefill", streams, tokens,
-                                        t_disp=time.monotonic_ns(),
                                         depth=self._inflight_waves))
         self._inflight_waves += 1
 
@@ -810,10 +839,16 @@ class GenerativeScheduler(Scheduler):
         if one was dispatched.  A prompt's last piece leaves its first token
         in the slot's device-side token and in the fetch queue, and the
         stream joins the next wave."""
-        lane, width = self._piece_lanes, self._piece_len
-        todo = [s for s in self._streams if s.ids is not None][:lane]
+        todo = [s for s in self._streams
+                if s.ids is not None][:self._piece_lanes]
         if not todo:
             return False
+        with self._rec.span[_sp.S_PREFILL_STAGE]:
+            self._stage_and_dispatch_piece(todo)
+        return True
+
+    def _stage_and_dispatch_piece(self, todo: list) -> None:
+        lane, width = self._piece_lanes, self._piece_len
         ids_mat = np.zeros((lane, width), np.int32)
         lens = np.ones(lane, np.int32)
         starts = np.zeros(lane, np.int32)
@@ -845,21 +880,18 @@ class GenerativeScheduler(Scheduler):
         done = []                     # by lane: the stream, if it ended
         for i, s in enumerate(todo):
             if not s.consumed:
-                s.t_prefill = now
+                self._count_started(s.req, now)
             s.consumed += int(lens[i])
             if s.consumed >= len(s.ids):
                 s.ids = None          # prefilled: live from the next wave
             done.append(_NO_STREAM if s.ids is not None else s)
         # The fetch queue keeps dispatch order and the pipeline's depth; a
         # lane whose prompt goes on carries no stream (its token is junk).
-        ended = [s for s in done if s is not _NO_STREAM]
         self._inflight.append(_Inflight(
-            "prefill" if ended else "piece", done, tokens,
-            t_disp=min([s.t_prefill for s in ended] or [now]),
-            depth=self._inflight_waves,
+            "prefill" if any(s is not _NO_STREAM for s in done) else "piece",
+            done, tokens, depth=self._inflight_waves,
             pieces=list(zip(todo, lens.tolist())) if self._record else ()))
         self._inflight_waves += 1
-        return True
 
     def _dispatch_transitions(self, live: list) -> None:
         """Queue the cache transition of every live stream that is due one,
@@ -935,10 +967,13 @@ class GenerativeScheduler(Scheduler):
         rec.c[_sp.C_INFLIGHT_WAVES] += self._inflight_waves
         positions = k * int(lens.sum()) + len(live) * (k * (k - 1) // 2)
         n_sum = n_exact = 0
+        fresh = []                    # lanes whose first decode token this is
         for s in live:
             if self._cache_rows is not None:
                 a, b = self._cache_rows(s.disp_len)
                 n_sum, n_exact = n_sum + a, n_exact + b
+            if s.disp_tokens == 1:
+                fresh.append(s)
             s.disp_len += k
             s.disp_tokens += k
             if self._transition is not None and self._transition_due(
@@ -953,7 +988,7 @@ class GenerativeScheduler(Scheduler):
                                         t_disp=time.monotonic_ns(),
                                         bucket=bucket,
                                         positions=positions,
-                                        rows=(n_sum, n_exact)))
+                                        rows=(n_sum, n_exact), fresh=fresh))
         self._inflight_waves += k
         if (bucket, k) not in self._wave_cost_captured:
             # Once per wave shape: static roofline numerator for this
@@ -1012,8 +1047,11 @@ class GenerativeScheduler(Scheduler):
             # (the first fetch after an idle gap also carries host
             # staging; steady-state waves dominate the histogram).
             t_done = time.monotonic_ns()
-            if head.kind != "prefill" and head.bucket:
+            if not head.bucket:
+                self._prefill_since_decode = True
+            else:
                 decode_fetches += 1
+                self._count_gap(head, t_done)
                 busy_ns = max(
                     0, t_done - max(head.t_disp, self._last_fetch_ns))
                 # The device has run the wave: its lanes, padding and
@@ -1058,6 +1096,32 @@ class GenerativeScheduler(Scheduler):
                 # Two waves' tokens leave back to back: the pairs a
                 # client sees as one long gap and one of nothing.
                 c[_sp.C_DRAINS_MULTI] += 1
+
+    def _count_gap(self, head: _Inflight, t_done: int) -> None:
+        """The token gap a decode fetch closes, for each of its lanes: the
+        time since the previous decode fetch (a K-chunk: K gaps of a Kth),
+        and apart the gaps that held a prefill call, once however many it
+        held.  Never a prefill head's own interval: where the worker blocks
+        in the dispatch it pops a piece and a wave microseconds apart, and
+        only their sum is the device's.  A lane's first gap runs from its
+        token 0, which its prefill's fetch emitted inside this interval or
+        before it, and waited for no prefill: it is counted as it was, among
+        the plain gaps."""
+        c = self._rec.c
+        last, behind = self._last_decode_fetch_ns, self._prefill_since_decode
+        self._last_decode_fetch_ns = t_done
+        self._prefill_since_decode = False
+        if not last:
+            return
+        lanes = len(head.streams)
+        fresh = [s.req.times.first_token for s in head.fresh if not s.dead]
+        c[_sp.C_GAP_LANES] += lanes * head.waves
+        c[_sp.C_GAP_LANE_NS] += (t_done - last) * lanes \
+            + len(fresh) * last - sum(fresh)
+        if behind:
+            waited = lanes - len(fresh)
+            c[_sp.C_GAP_LANES_BEHIND_PREFILL] += waited * head.waves
+            c[_sp.C_GAP_LANE_BEHIND_PREFILL_NS] += (t_done - last) * waited
 
     def _keep_records(self, head: _Inflight, toks):
         """A fetch's tokens, its positions' rows of the streams' records cut
@@ -1105,7 +1169,8 @@ class GenerativeScheduler(Scheduler):
                     # TTFT from inside: prefill dispatch to token 0.
                     s.req.times.first_token = t
                     c[_sp.C_FIRST_TOKENS] += 1
-                    c[_sp.C_FIRST_TOKEN_WAIT_NS] += t - head.t_disp
+                    c[_sp.C_FIRST_TOKEN_WAIT_NS] += \
+                        t - s.req.times.prefill_start
                     c[_sp.C_FIRST_TOKEN_INFLIGHT_WAVES] += head.depth
                 else:
                     s.f_len += 1

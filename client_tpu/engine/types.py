@@ -59,6 +59,11 @@ class RequestTimes:
     # four phases above keep their meaning (compute_infer is the whole
     # stream).
     first_token: int = 0
+    # Generative streams only: the prompt's first prefill call returned (its
+    # first piece, or its one-shot program).  compute_start -> here is its
+    # wait in the line for that call; here -> first_token the prefill itself
+    # and the pipeline ahead of it.
+    prefill_start: int = 0
 
     @property
     def queue_ns(self) -> int:

@@ -12,7 +12,8 @@ Three families of names, constants here and nowhere else:
   is the whole iteration (inclusive); every other span is exclusive of the
   spans opened inside it (``gen.admit`` is ``_admit_batch`` less its
   ``gen.prefill_dispatch``, ``gen.wave_stage`` is ``_dispatch_one_wave`` less
-  its ``gen.wave_dispatch``), so the children partition the iteration and
+  its ``gen.wave_dispatch``, ``gen.prefill_stage`` is ``_dispatch_piece`` less
+  its ``gen.prefill_dispatch``), so the children partition the iteration and
   ``gen.loop`` less their sum is the loop's own bookkeeping.
 - ``exec.*`` — the batcher's three phases inside ``Model.execute_timed``.
   Their aggregate times already live in the profiler's bucket table
@@ -51,13 +52,18 @@ GEN_EMIT = "gen.emit"
 # declares ``transition_fn``: EvaByte's window dump); count = transitions'
 # dispatches, never opened for a backend without the hook.
 GEN_TRANSITION_DISPATCH = "gen.transition_dispatch"
+# ``_dispatch_piece`` less its ``gen.prefill_dispatch``: the staging and the
+# bookkeeping of a piece, as ``gen.wave_stage`` is of a wave.  Only a backend
+# that prefills by pieces opens it.
+GEN_PREFILL_STAGE = "gen.prefill_stage"
 
+# Positional (the index constants, the snapshot's order): new names go last.
 GEN_SPANS = (GEN_LOOP, GEN_IDLE, GEN_ADMIT, GEN_PREFILL_DISPATCH, GEN_SWEEP,
              GEN_WAVE_STAGE, GEN_WAVE_DISPATCH, GEN_FETCH_WAIT, GEN_EMIT,
-             GEN_TRANSITION_DISPATCH)
+             GEN_TRANSITION_DISPATCH, GEN_PREFILL_STAGE)
 (S_LOOP, S_IDLE, S_ADMIT, S_PREFILL_DISPATCH, S_SWEEP, S_WAVE_STAGE,
- S_WAVE_DISPATCH, S_FETCH_WAIT, S_EMIT,
- S_TRANSITION_DISPATCH) = range(len(GEN_SPANS))
+ S_WAVE_DISPATCH, S_FETCH_WAIT, S_EMIT, S_TRANSITION_DISPATCH,
+ S_PREFILL_STAGE) = range(len(GEN_SPANS))
 
 # Cumulative, monotone: two snapshots difference exactly.  Every counter has
 # a reader (docs/OBSERVABILITY.md, the inventory): a per-layer metric of
@@ -104,6 +110,18 @@ GEN_COUNTERS = (
     # and those that were padding up to the piece (which a layer with a
     # recurrent state has to step over without moving the state).
     "prefill_positions_valid", "prefill_positions_padded",
+    # per prompt, at its first prefill dispatch (its first piece, or its
+    # one-shot program): how many, the engine's queue before the admit
+    # (``RequestTimes.queue_ns``) and the slot taken to that dispatch.  With
+    # ``first_token_wait_ns`` the three partition ``first_token -
+    # queue_start`` of every request.
+    "prompts_started", "admit_wait_ns", "prefill_line_wait_ns",
+    # per decode fetch that follows a decode fetch with no ``gen.idle``
+    # between them: the token gap as the worker produces it, weighted by the
+    # wave's live lanes (a K-chunk: K gaps of a Kth), and the part of both
+    # whose gap held at least one prefill call (counted once a gap).
+    "gap_lanes", "gap_lane_ns", "gap_lanes_behind_prefill",
+    "gap_lane_behind_prefill_ns",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
@@ -112,8 +130,10 @@ GEN_COUNTERS = (
  C_PROMPTS_ADMITTED, C_PREFILL_PIECES, C_TRANSITIONS, C_EMIT_HANDOFFS,
  C_EMITTED_TOKENS, C_EMITTED_TOKENS_CALLBACK, C_PREFILL_LANES_LIVE,
  C_PREFILL_LANES_PADDED, C_EXPERT_PAIRS_LOCAL, C_EXPERT_PAIRS_BUSIEST,
- C_EXPERTS_TOUCHED, C_PREFILL_POSITIONS_VALID,
- C_PREFILL_POSITIONS_PADDED) = range(len(GEN_COUNTERS))
+ C_EXPERTS_TOUCHED, C_PREFILL_POSITIONS_VALID, C_PREFILL_POSITIONS_PADDED,
+ C_PROMPTS_STARTED, C_ADMIT_WAIT_NS, C_PREFILL_LINE_WAIT_NS, C_GAP_LANES,
+ C_GAP_LANE_NS, C_GAP_LANES_BEHIND_PREFILL,
+ C_GAP_LANE_BEHIND_PREFILL_NS) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

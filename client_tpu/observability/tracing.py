@@ -132,6 +132,12 @@ def build_request_trace(ctx: TraceContext, model_name: str, request_id: str,
     if first_token and times.compute_start \
             and first_token >= times.compute_start:
         spans.append(Span("prefill", times.compute_start, first_token))
+        # Inside it: the slot taken to the prompt's first prefill call, the
+        # line a prompt stands in while older prompts' pieces go first.
+        prefill_start = getattr(times, "prefill_start", 0)
+        if times.compute_start <= prefill_start <= first_token:
+            spans.append(Span("prefill_wait", times.compute_start,
+                              prefill_start))
     return RequestTrace(
         trace_id=ctx.trace_id, span_id=ctx.span_id,
         parent_span_id=ctx.parent_span_id, model_name=model_name,

@@ -444,3 +444,97 @@ class TestHandoffReaders:
         from client_tpu.observability import spans
 
         assert set(HANDOFF_BEFORE) <= set(spans.GEN_COUNTERS)
+
+
+# -- PR 41's readers: a prompt's two waits, the token gap by what stood between
+# two waves, the host's work around a piece -------------------------------------
+
+ALL_CELLS = ["gpt2_small.chat", "evabyte_6b5.longdoc",
+             "pangu_ultra_moe.reasoning", "kimi_linear.longgen"]
+GAP_BEFORE = {"prompts_started": 10, "admit_wait_ns": 50_000_000,
+              "prefill_line_wait_ns": 4_000_000_000, "gap_lanes": 1000,
+              "gap_lane_ns": 20_000_000_000, "gap_lanes_behind_prefill": 400,
+              "gap_lane_behind_prefill_ns": 12_000_000_000}
+GAP_AFTER = {"prompts_started": 30, "admit_wait_ns": 290_000_000,
+             "prefill_line_wait_ns": 84_000_000_000, "gap_lanes": 11_000,
+             "gap_lane_ns": 320_000_000_000,
+             "gap_lanes_behind_prefill": 6400,
+             "gap_lane_behind_prefill_ns": 252_000_000_000}
+# The window, by hand: 20 prompts started, 240 ms and 80 s of waits; 10 000
+# gaps summing 300 s, of which 6000 behind a prefill sum 240 s (40 ms each)
+# and 4000 plain sum 60 s (15 ms each): a prefill costs a stream 25 ms, and
+# 6000 x 25 ms = 150 s of the 300 are the prefills'.
+GAP_METRICS = {
+    # name -> (cells, source, unit, the value by hand)
+    "admit_wait_ms_mean.obs": (ALL_CELLS, "program_counter", "ms", 12.0),
+    "prefill_line_wait_ms_mean.obs": (ALL_CELLS, "program_counter", "ms",
+                                      4000.0),
+    "gaps_behind_prefill_share.obs": (ALL_CELLS, "program_counter", "%",
+                                      60.0),
+    "prefill_gap_cost_ms.itl": (ALL_CELLS, "program_counter", "ms", 25.0),
+    "prefill_gap_share.itl": (ALL_CELLS, "program_counter", "%", 50.0),
+    "prefill_stage_ms_mean.itl": (ALL_CELLS[1:], "program_span", "ms", 0.75),
+}
+STAGE_BEFORE = {"gen.prefill_stage": (100, 60), "gen.prefill_dispatch":
+                (100, 900)}
+STAGE_AFTER = {"gen.prefill_stage": (900, 660), "gen.prefill_dispatch":
+               (900, 9000)}
+
+
+class TestGapReaders:
+    @pytest.mark.parametrize("name", GAP_METRICS)
+    def test_value_worked_out_by_hand(self, name):
+        ctx = {"snap_before": snap(STAGE_BEFORE, GAP_BEFORE),
+               "snap_after": snap(STAGE_AFTER, GAP_AFTER)}
+        assert run_reader(name)(ctx) == pytest.approx(GAP_METRICS[name][3])
+
+    @pytest.mark.parametrize("name", GAP_METRICS)
+    def test_nothing_without_the_counters_or_with_an_empty_class(self, name):
+        """The parent serves ``generative`` without the seven counters and
+        the span, an older one no ``generative`` at all; a window in which
+        nothing started, or (for the two that subtract the classes) whose
+        gaps all held a prefill or none did, has nothing to divide by:
+        None each time, never 0 and never a raise."""
+        read = run_reader(name)
+        parent = snap({"gen.prefill_dispatch": (5, 20)},
+                      {"fetched_waves": 5, "first_tokens": 3})
+        assert read({"snap_before": parent, "snap_after": parent}) is None
+        grown = snap({"gen.prefill_dispatch": (9, 40)},
+                     {"fetched_waves": 50, "first_tokens": 7})
+        assert read({"snap_before": parent, "snap_after": grown}) is None
+        bare = {"t": 0.0, "stats": {}, "profile": {"models": {"gpt:1": {
+            "decode_waves": []}}}}
+        assert read({"snap_before": bare, "snap_after": bare}) is None
+        assert read({"snap_before": None, "snap_after": None}) is None
+        still = snap(STAGE_AFTER, GAP_AFTER)
+        assert read({"snap_before": still, "snap_after": still}) is None
+        if name in ("prefill_gap_cost_ms.itl", "prefill_gap_share.itl"):
+            before = snap({}, GAP_BEFORE)
+            for behind in (0, 10_000):       # no gap held a prefill; all did
+                after = dict(GAP_AFTER, gap_lanes_behind_prefill=400 + behind,
+                             gap_lane_behind_prefill_ns=12_000_000_000
+                             + behind * 30_000_000)
+                assert read({"snap_before": before,
+                             "snap_after": snap({}, after)}) is None
+
+    def test_the_manifest_holds_them_at_its_end(self):
+        """Appended in the issue's order behind everything older, each for
+        its cells and whatever cells join later; the scheduler's layer,
+        lower is better, recorded beside ``itl_mean_ms``."""
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+        names = [m["name"] for m in manifest["per_layer"]]
+        first = names.index(next(iter(GAP_METRICS)))
+        assert first > names.index("prefill_padded_position_share.itl")
+        tail = manifest["per_layer"][first:first + len(GAP_METRICS)]
+        assert [m["name"] for m in tail] == list(GAP_METRICS)
+        for m in tail:
+            cells, source, unit, _ = GAP_METRICS[m["name"]]
+            assert m["workloads"][:len(cells)] == cells
+            assert (m["layer"], m["moves"], m["better"], m["source"],
+                    m["unit"]) == ("generative scheduler", "itl_mean_ms",
+                                   "lower", source, unit)
+        from client_tpu.observability import spans
+
+        assert set(GAP_BEFORE) <= set(spans.GEN_COUNTERS)
+        assert "gen.prefill_stage" in spans.GEN_SPANS

@@ -69,13 +69,28 @@ def _gen(engine, model=MODEL):
                 and sched._rec.open is sched._rec.span[spans.S_IDLE]:
             break
         time.sleep(0.005)
-    snap = engine.profile_snapshot(model=model)
-    return snap["models"][f"{model}:1"]["generative"]
+    return _gen_or_zero(engine, model)
 
 
 @pytest.fixture(scope="module")
 def engine():
     eng = _engine()
+    yield eng
+    eng.shutdown()
+
+
+PIECE = "spans_eva"      # prefills by one-lane pieces of 32 positions
+
+
+@pytest.fixture(scope="module")
+def piece_engine():
+    from client_tpu.models.evabyte import EvaByteBackend
+
+    repo = ModelRepository()
+    repo.register_backend(EvaByteBackend(
+        name=PIECE, seed=3, max_seq_len=128, window=32, chunk=4))
+    eng = TpuEngine(repo)
+    eng._schedulers[PIECE].warmup()
     yield eng
     eng.shutdown()
 
@@ -92,9 +107,10 @@ def ran(engine):
     return before, _gen(engine), out
 
 
-def _gen_or_zero(engine):
-    snap = engine.profile_snapshot(model=MODEL)
-    g = snap["models"].get(f"{MODEL}:1", {}).get("generative")
+def _gen_or_zero(engine, model=MODEL):
+    """All zeros for a worker that has committed no iteration yet."""
+    snap = engine.profile_snapshot(model=model)
+    g = snap["models"].get(f"{model}:1", {}).get("generative")
     if g is None:
         g = {"spans": {s: {"count": 0, "total_ns": 0, "max_ns": 0}
                        for s in spans.GEN_SPANS},
@@ -112,7 +128,7 @@ class TestLoopSpans:
         _, after, _ = ran
         assert list(after["spans"]) == list(spans.GEN_SPANS)
         assert list(after["counters"]) == list(spans.GEN_COUNTERS)
-        assert len(set(spans.GEN_SPANS)) == 10
+        assert len(set(spans.GEN_SPANS)) == 11
 
     def test_children_partition_the_iteration(self, ran):
         _, after, _ = ran
@@ -400,6 +416,252 @@ class TestRecorder:
         rec.end_loop()
         p.reset()
         assert p.snapshot()["models"] == {}
+
+
+# -- PR 41: a piece's span, a prompt's two waits, the token gap by class --------
+
+WAYS = {"one_shot": (MODEL, [1, 2, 3, 4, 5]),
+        "pieces": (PIECE, list(range(1, 81)))}     # three pieces of 32
+
+
+def _way(way, engine, piece_engine):
+    model, prompt = WAYS[way]
+    return (engine if way == "one_shot" else piece_engine), model, prompt
+
+
+def _held_batch(eng, model, prompts, max_tokens):
+    """Send ``prompts`` so that ONE ``_admit_batch`` takes them all: the
+    worker is held inside a warm-up sentinel while they queue.  Returns the
+    requests (their ``times`` are the stamps) once every stream has ended."""
+    from client_tpu.engine.generative import _WarmupReq
+
+    sched = eng._schedulers[model]
+    _gen(eng, model)                      # parked in its blocking wait
+    gate, real = threading.Event(), sched._precompile
+    sched._precompile = lambda: gate.wait(60)
+    hold = _WarmupReq()
+    try:
+        sched.queue.put(hold)
+        deadline = time.monotonic() + 10
+        while sched._rec.open is sched._rec.span[spans.S_IDLE] \
+                and time.monotonic() < deadline:
+            time.sleep(0.001)
+        reqs, done = [], []
+        for prompt in prompts:
+            ev = threading.Event()
+            req = InferRequest(
+                model_name=model,
+                inputs={"INPUT_IDS": np.asarray(prompt, np.int32)},
+                parameters={"max_tokens": max_tokens})
+            eng.async_infer(req, lambda r, ev=ev: (
+                r.final or r.error is not None) and ev.set())
+            reqs.append(req)
+            done.append(ev)
+    finally:
+        gate.set()
+        sched._precompile = real
+    assert hold.done.wait(60)
+    assert all(ev.wait(120) for ev in done)
+    return reqs
+
+
+class TestPrefillStage:
+    @pytest.mark.parametrize("way", list(WAYS))
+    def test_children_never_exceed_the_loop_at_any_snapshot(
+            self, engine, piece_engine, way):
+        """Snapshots taken while streams run see whole iterations only:
+        with ``gen.prefill_stage`` among the children their totals still
+        never exceed ``gen.loop``'s.  Only a backend that prefills by pieces
+        opens the span, once a piece, around the piece's jitted call."""
+        eng, model, prompt = _way(way, engine, piece_engine)
+        before = _gen(eng, model)["spans"]
+        joins = [_stream(eng, prompt, 6, model=model) for _ in range(3)]
+        snaps = []
+        while len(snaps) < 200 and (
+                eng._schedulers[model]._streams or not snaps):
+            snaps.append(_gen_or_zero(eng, model)["spans"])
+        for j in joins:
+            j()
+        snaps.append(_gen(eng, model)["spans"])
+        for g in snaps:
+            assert sum(g[s]["total_ns"] for s in CHILDREN) \
+                <= g[spans.GEN_LOOP]["total_ns"]
+        stage, call = (
+            snaps[-1][s]["count"] - before[s]["count"]
+            for s in (spans.GEN_PREFILL_STAGE, spans.GEN_PREFILL_DISPATCH))
+        assert call > 0
+        assert stage == (0 if way == "one_shot" else call)
+        if way == "pieces":
+            assert call == 9 and snaps[-1][spans.GEN_PREFILL_STAGE][
+                "total_ns"] > before[spans.GEN_PREFILL_STAGE]["total_ns"]
+
+
+class TestAPromptsTwoWaits:
+    @pytest.mark.parametrize("way", list(WAYS))
+    def test_three_waits_partition_the_first_token(self, engine,
+                                                   piece_engine, way):
+        """N prompts admitted in one batch: ``prompts_started`` is N once
+        all have started, and the three counters are the sums of the
+        requests' own stamps, so together they are exactly ``first_token -
+        queue_start`` summed over the requests."""
+        eng, model, prompt = _way(way, engine, piece_engine)
+        before = _gen(eng, model)
+        n = 3
+        reqs = _held_batch(eng, model, [prompt] * n, 4)
+        d = _delta(before, _gen(eng, model))
+        t = [r.times for r in reqs]
+        assert d["prompts_started"] == d["first_tokens"] == n
+        for x in t:
+            assert 0 < x.queue_start <= x.compute_start < x.prefill_start \
+                < x.first_token
+            assert x.first_token - x.queue_start == x.queue_ns + (
+                x.prefill_start - x.compute_start) + (
+                x.first_token - x.prefill_start)
+        assert d["admit_wait_ns"] == sum(x.queue_ns for x in t) > 0
+        assert d["prefill_line_wait_ns"] == sum(
+            x.prefill_start - x.compute_start for x in t) > 0
+        assert d["first_token_wait_ns"] == sum(
+            x.first_token - x.prefill_start for x in t)
+        assert d["admit_wait_ns"] + d["prefill_line_wait_ns"] \
+            + d["first_token_wait_ns"] == sum(
+                x.first_token - x.queue_start for x in t)
+        # one batch: the slots were taken in the same call
+        assert max(x.compute_start for x in t) \
+            - min(x.compute_start for x in t) < 50e6
+        started = [x.prefill_start for x in t]
+        if way == "pieces":
+            # one lane a piece, oldest prompt first: each prompt's three
+            # pieces go before the next prompt's first
+            assert d["prompts_admitted"] == n and d["prefill_pieces"] == 9
+            assert started == sorted(started) and len(set(started)) == n
+        else:
+            # one program holds them all: they leave the line together
+            assert len(set(started)) == 1
+
+    def test_a_request_trace_shows_the_line_inside_prefill(self):
+        from client_tpu.engine.types import RequestTimes
+        from client_tpu.observability.tracing import (
+            TraceContext, build_request_trace)
+
+        t = RequestTimes(received=1, queue_start=1, compute_start=5,
+                         compute_input_end=5, compute_infer_end=30,
+                         compute_output_end=30, first_token=20,
+                         prefill_start=12)
+        by = {s.name: (s.start_ns, s.end_ns) for s in build_request_trace(
+            TraceContext("a" * 32, "b" * 16, ""), "m", "r", t, ok=True).spans}
+        assert by["prefill"] == (5, 20) and by["prefill_wait"] == (5, 12)
+        assert by["queue"] == (1, 5)
+        t.prefill_start = 0        # a program without the stamp: no child
+        names = [s.name for s in build_request_trace(
+            TraceContext("a" * 32, "b" * 16, ""), "m", "r", t, ok=True).spans]
+        assert "prefill" in names and "prefill_wait" not in names
+
+
+class _Ready:
+    """A fetched token array as ``_drain_fetches`` takes one."""
+
+    def __init__(self, shape):
+        self.shape = shape
+
+    def is_ready(self):
+        return True
+
+    def __array__(self, dtype=None, copy=None):
+        return np.zeros(self.shape, np.int32)
+
+
+# Heads in fetch order: (kind, lanes, K, t_done ns, first_token of a fresh
+# lane or None); "idle" is the worker's gen.idle.
+SCRIPT = [("wave", 3, 1, 100, None), ("piece", 0, 1, 130, None),
+          ("wave", 3, 1, 150, None), ("wave", 3, 1, 170, None),
+          ("prefill", 1, 1, 200, None), ("prefill", 1, 1, 230, None),
+          ("wave", 4, 1, 260, 230), "idle", ("wave", 4, 1, 1000, None),
+          ("chunk", 4, 2, 1060, None)]
+# By hand.  100: no decode fetch before it.  150: 50 x 3 behind the piece.
+# 170: 20 x 3 plain.  260: 90 behind two prefills, counted once: the three
+# waiting lanes 90 each behind, the fresh lane 260 - 230 = 30 plain.  1000:
+# across the idle, in neither class.  1060: a 2-chunk, 2 gaps of 30 a lane.
+BY_HAND = {"gap_lanes": 3 + 3 + 4 + 8,
+           "gap_lane_ns": 150 + 60 + (270 + 30) + 240,
+           "gap_lanes_behind_prefill": 3 + 3,
+           "gap_lane_behind_prefill_ns": 150 + 270,
+           "fetched_lanes_live": 3 + 3 + 3 + 4 + 4 + 8}
+
+
+@pytest.fixture(scope="module")
+def scripted():
+    """The counters after ``_drain_fetches`` took SCRIPT's heads at SCRIPT's
+    times, on a scheduler whose worker is parked (nothing else moves)."""
+    import types
+
+    from client_tpu.engine import generative as G
+
+    eng = _engine(name="spans_gaps", max_streams=4, max_seq_len=16,
+                  n_layers=1)
+    sched = eng._schedulers["spans_gaps"]
+    _stream(eng, [1, 2], 2, model="spans_gaps")()
+    _gen(eng, "spans_gaps")
+    clock = iter(h[3] for h in SCRIPT if h != "idle")
+    real_time, real_emit = G.time, sched._emit_fetched
+    G.time = types.SimpleNamespace(monotonic_ns=lambda: next(clock),
+                                   monotonic=time.monotonic, sleep=time.sleep)
+    sched._emit_fetched = lambda head, toks: None
+    try:
+        assert sched._last_decode_fetch_ns == 0     # parked in gen.idle
+        for h in SCRIPT:
+            if h == "idle":
+                with sched._idle():
+                    pass
+                continue
+            kind, lanes, k, _, first = h
+            streams = [G._Stream(InferRequest(model_name="spans_gaps",
+                                              inputs={}), i, 4, 8)
+                       for i in range(lanes)]
+            fresh = ()
+            if first is not None:
+                streams[-1].req.times.first_token = first
+                fresh = streams[-1:]
+            decode = kind in ("wave", "chunk")
+            sched._inflight.append(G._Inflight(
+                kind, streams, _Ready((k, 4) if kind == "chunk" else (4,)),
+                waves=k, bucket=4 if decode else 0, fresh=fresh))
+            sched._inflight_waves += k
+            sched._drain_fetches()
+        assert not sched._inflight
+        counters = dict(zip(spans.GEN_COUNTERS, sched._rec.c))
+    finally:
+        G.time, sched._emit_fetched = real_time, real_emit
+        eng.shutdown()
+    return counters
+
+
+class TestGapCounters:
+    @pytest.mark.parametrize("name", list(BY_HAND))
+    def test_a_scripted_order_of_heads_by_hand(self, scripted, name):
+        assert scripted[name] == BY_HAND[name]
+        assert scripted["gap_lanes"] <= scripted["fetched_lanes_live"]
+        assert scripted["gap_lanes_behind_prefill"] <= scripted["gap_lanes"]
+
+    @pytest.mark.parametrize("way", list(WAYS))
+    def test_served_streams_close_gaps_of_both_classes(
+            self, engine, piece_engine, way):
+        """Real streams: every counted lane is a fetched lane, a class is
+        part of the whole, and a stream that decodes while another prompt
+        prefills waits behind it."""
+        eng, model, prompt = _way(way, engine, piece_engine)
+        before = _gen(eng, model)
+        first = _stream(eng, prompt[:3], 12, model=model)
+        time.sleep(0.05)                  # decoding when the others arrive
+        rest = [_stream(eng, prompt, 4, model=model) for _ in range(2)]
+        for j in [first] + rest:
+            j()
+        d = _delta(before, _gen(eng, model))
+        assert 0 < d["gap_lanes"] <= d["fetched_lanes_live"]
+        assert 0 <= d["gap_lanes_behind_prefill"] <= d["gap_lanes"]
+        assert 0 <= d["gap_lane_behind_prefill_ns"] <= d["gap_lane_ns"]
+        assert d["gap_lane_ns"] > 0
+        # every decode fetch but the first after an idle closes a gap
+        assert d["gap_lanes"] >= d["fetched_lanes_live"] - 3 * 3
 
 
 class TestPrefillSpanOfARequest:
@@ -711,33 +973,57 @@ class TestTraceClock:
         assert spans.begin(spans.EXEC_RUN) is None
         spans.end(None)
 
-    def test_cpu_trace_holds_the_host_spans(self, engine, tmp_path):
+    @pytest.mark.parametrize("way", ["one_shot", "pieces"])
+    def test_cpu_trace_holds_the_host_spans(self, engine, piece_engine,
+                                            tmp_path, way):
+        """Every ``gen.*`` span the worker closed while the trace was on is
+        in the trace, as often as the profile counted it.  The worker is
+        parked in ``gen.idle`` when the trace starts and when it stops, so
+        the one iteration (and its idle) that straddles each end is the
+        only difference: the expected counts come from the program's own
+        counters over the same stretch, not from how a few streams happened
+        to fall into iterations."""
         from jax.profiler import ProfileData
 
-        engine.trace.update({"trace_level": ["TIMESTAMPS"],
-                             "log_dir": str(tmp_path)})
+        eng, model, prompt = {
+            "one_shot": (engine, MODEL, [1, 2]),
+            "pieces": (piece_engine, PIECE, list(range(1, 71)))}[way]
+        before = _gen(eng, model)["spans"]
+        eng.trace.update({"trace_level": ["TIMESTAMPS"],
+                          "log_dir": str(tmp_path)})
         try:
             assert spans.trace_active()
-            joins = [_stream(engine, [i, i + 1], 4) for i in range(1, 4)]
-            for j in joins:
-                j()
-            _gen(engine)
+            for _ in range(3):
+                _stream(eng, prompt, 4, model=model)()
+            after = _gen(eng, model)["spans"]
         finally:
-            engine.trace.update({"trace_level": ["OFF"]})
+            eng.trace.update({"trace_level": ["OFF"]})
         assert not spans.trace_active()
         files = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
         assert len(files) == 1
         pd = ProfileData.from_file(str(files[0]))
-        seen = {}
+        seen = dict.fromkeys(spans.GEN_SPANS, 0)
         for plane in pd.planes:
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith("gen."):
-                        seen[e.name] = seen.get(e.name, 0) + 1
-        assert seen.get(spans.GEN_WAVE_DISPATCH, 0) >= 3
-        assert seen.get(spans.GEN_FETCH_WAIT, 0) >= 3
-        assert seen.get(spans.GEN_LOOP, 0) >= 3
-        assert set(seen) <= set(spans.GEN_SPANS)
+                        seen[e.name] += 1     # KeyError: not the vocabulary
+        closed = {name: after[name]["count"] - before[name]["count"]
+                  for name in spans.GEN_SPANS}
+        straddling = (spans.GEN_LOOP, spans.GEN_IDLE)
+        for name in spans.GEN_SPANS:
+            if name in straddling:
+                # the first stream's iteration began before the trace did
+                assert closed[name] - 1 <= seen[name] <= closed[name], name
+            else:
+                assert seen[name] == closed[name], name
+        assert seen[spans.GEN_WAVE_DISPATCH] == 9      # 3 streams x 3 waves
+        assert seen[spans.GEN_LOOP] >= 2
+        # a piece's staging encloses its jitted call, once a piece
+        assert seen[spans.GEN_PREFILL_STAGE] == (
+            0 if way == "one_shot" else seen[spans.GEN_PREFILL_DISPATCH])
+        assert seen[spans.GEN_PREFILL_DISPATCH] == (
+            3 if way == "one_shot" else 9)             # 70 positions by 32
 
     def test_batcher_phases_are_exec_annotations(self, tmp_path):
         from jax.profiler import ProfileData
