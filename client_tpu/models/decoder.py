@@ -56,7 +56,7 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
   donated: ``*_static_argnums`` and ``donate_argnums`` say so by position.
 
 **The parts a model supplies** (``B`` lanes of a wave; ``lp`` one layer's
-weights; ``li`` its index, a Python int or a traced scalar; ``x`` the model's
+weights; ``li`` its index, a Python int; ``x`` the model's
 own carry between layers: activations ``[B, d]``, or a pytree where a layer
 hands on more than those, as models/pangu_moe.py's routing counts):
 ``_embed(p, tokens, pos)`` -> x; ``_qkv(lp, x, pos)`` -> q, k, v ``[B, H,
@@ -76,10 +76,14 @@ it chooses kernel or oracle by ``_use_kernel()``), ``o`` what
 ``_after_attention`` reads;
 ``_logits(p, x)`` and,
 where they are not ``[B, vocab]``, ``_served(logits)`` picking those tokens
-are sampled from; ``_walk_layers(p, body, carry)`` folding ``body(carry, lp,
-li)`` over the layers (an unrolled loop, a ``lax.scan``); ``_live_rows(lens)``,
-the rows of each slot a step at context length ``lens`` may read (default:
-``lens``).
+are sampled from; ``_live_rows(lens)``, the rows of each slot a step at
+context length ``lens`` may read (default: ``lens``).  The layers are walked
+by ``_walk_layers(p, body, carry)``, written here: ``body(carry, lp, li)``
+over ``p["layers"]``, a list of one tree of leaves a layer, in a Python loop
+(``li`` a Python int).  A weight is then a parameter of the program, read by
+its product where it lies; a ``lax.scan`` over stacked leaves made the
+compiler write out and re-lay a layer's slice every iteration (PERF.md
+section 6, PR 42), and no served model overrides the loop.
 """
 
 from __future__ import annotations
@@ -281,6 +285,11 @@ class DecoderBackend(ModelBackend):
     def _served(self, logits):
         return logits
 
+    def _walk_layers(self, p, body, carry):
+        for li, lp in enumerate(p["layers"]):
+            carry = body(carry, lp, li)
+        return carry
+
     # -- kernel or oracle -----------------------------------------------------
 
     def _use_kernel(self) -> bool:
@@ -299,10 +308,11 @@ class DecoderBackend(ModelBackend):
         ``[B, H, D]`` go to row ``live[b]`` of slot ``rows[b]`` and ``q``
         reads rows ``0 .. live[b]``.  The kernel is one Pallas grid over the
         donated arena (with ``kv_shards > 1`` its shard_map form over the
-        row-sharded arena); ``layer`` may be traced (a decoder that scans
-        its layers) except over shards.  With ``latent_attention`` declared:
-        ``attend(c_arena, q, new_row, rows, live, layer)`` -> (c_arena, o),
-        the one leaf's kernel or its oracle."""
+        row-sharded arena); ``layer`` may be traced except over shards (no
+        served decoder hands one over since PR 42, when the last ``scan``
+        over layers went: ROADMAP Queue C).  With ``latent_attention``
+        declared: ``attend(c_arena, q, new_row, rows, live, layer)`` ->
+        (c_arena, o), the one leaf's kernel or its oracle."""
         from client_tpu.engine.backend_init import pallas_interpret
         from client_tpu.ops.decode_kernel import (decode_wave_attention,
                                                   latent_wave_attention,
@@ -388,8 +398,7 @@ class DecoderBackend(ModelBackend):
     # -- the decode step ------------------------------------------------------
 
     def _layer_kind(self, li):
-        """(kind, index among the layers of that kind) of layer ``li``; with
-        kinds declared ``li`` is a Python int (the layers are a loop)."""
+        """(kind, index among the layers of that kind) of layer ``li``."""
         if self.layer_kinds is None:
             return "rows", li
         kind = self.layer_kinds[li]

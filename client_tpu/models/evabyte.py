@@ -37,9 +37,23 @@ from what the backend declares of ``models/decoder.py``'s contract
 from the model's name; the decode step is that module's frame over the parts
 below.
 
-Layers run under ``lax.scan`` over stacked weights with the arena in the
-carry (the decode kernel takes the layer index by scalar prefetch), so a
-program holds one copy of a layer whatever the depth.
+Layers are walked one by one, a Python loop over **leaves of their own**
+(``p["layers"][li]["wo"]`` is ``[d, d]``), the arena's leaves whole in the
+carry and the layer's number a Python int (``models/decoder.py``'s
+``_walk_layers``, as every served decoder).  Every weight is then an entry
+parameter of the program, read by its product where it lies.  Under a
+``lax.scan`` over stacked leaves ``[L, d, d]`` the compiler wrote ``wq[li]``
+and ``wk[li]`` out every iteration and copied each into the layout its
+product wanted, 1.30 ms of an 11.86 ms wave at the published widths; as
+leaves of their own it still transposed them, so the three projections of a
+layer's input are served ``[out, in]`` (``OUT_IN``, ``_mm_t``) and no
+program copies a matrix (PERF.md section 6, PR 42;
+``tests/test_tpu_compile.py``).  The price is a program that grows with the
+depth (eight copies of a layer at ``evabyte_6b5``'s cut; a wave bucket
+lowers in 2.4 s where one copy took 1.5): at 32 layers on one chip the
+compile time is worth a second look.  The host's tree stays stacked and
+``[in, out]`` (``_init_params``: a checkpoint and the benchmark's reference
+hold that form) and is split where it is placed (``place_params``).
 
 Tokens are sampled from head 0 only, one byte a wave; the other
 ``num_pred_heads - 1`` heads' logits are computed (the head keeps its
@@ -56,6 +70,11 @@ from client_tpu.models import register_model
 from client_tpu.models.decoder import DecoderBackend, sample_into_slots
 
 _NEG_INF = -1e30
+# The matrices served ``[out, in]`` and contracted on their minor axis
+# (``_mm_t``): with a wave's 16 rows on the other side the compiler wants the
+# products that go through ``rope`` that way round, and a piece's 2048 rows
+# want ``wv`` so too.
+OUT_IN = ("wq", "wk", "wv")
 
 
 def rms_norm(x, g, eps):
@@ -161,7 +180,8 @@ class EvaByteBackend(DecoderBackend):
         """Seeded weights, **already rounded to bfloat16** (numpy arrays of
         ``ml_dtypes.bfloat16``): a reference that casts them to float32
         holds exactly what the chip holds.  Layers are stacked on a leading
-        axis (the programs scan over them)."""
+        axis: the form a checkpoint holds and ``benchmark/reference.py``
+        reads; the programs take ``split_layers`` of it."""
         import ml_dtypes
 
         bf16 = ml_dtypes.bfloat16
@@ -196,6 +216,34 @@ class EvaByteBackend(DecoderBackend):
                       scale=1.0 / math.sqrt(d)),
         }
 
+    def split_layers(self, params):
+        """The tree the programs take, of the stacked one: each layer's
+        weights as leaves of their own, ``params["layers"][li][name]``,
+        those of ``OUT_IN`` turned ``[out, in]`` (on the host views of the
+        stacked arrays: nothing is copied)."""
+        layers = params["layers"]
+        return {**params, "layers": [
+            {name: leaf[li].T if name in OUT_IN else leaf[li]
+             for name, leaf in layers.items()}
+            for li in range(self.n_layers)]}
+
+    def place_params(self, params):
+        """Split on the host and placed leaf by leaf, so that the stacked
+        tree never stands on the device beside the served one."""
+        import jax
+
+        def put(leaf):
+            if leaf.flags.c_contiguous:
+                return jax.device_put(leaf)
+            # A turned view goes over as it lies and the device turns it:
+            # numpy copies one element by element, 0.13 s a matrix at the
+            # published widths, 3 s of set-up a model.
+            return jax.device_put(leaf.T).T
+
+        return jax.tree_util.tree_map(
+            put, self.split_layers(jax.tree_util.tree_map(np.asarray,
+                                                          params)))
+
     # -- shared blocks --------------------------------------------------------
 
     def _mm(self, x, w):
@@ -205,6 +253,16 @@ class EvaByteBackend(DecoderBackend):
 
         return jnp.matmul(x.astype(w.dtype), w,
                           preferred_element_type=jnp.float32)
+
+    def _mm_t(self, x, w):
+        """``_mm`` by a matrix served ``[out, in]`` (``OUT_IN``): the same
+        products and sums, contracted on both minor axes."""
+        import jax
+        import jax.numpy as jnp
+
+        return jax.lax.dot_general(
+            x.astype(w.dtype), w, (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     def _qkv(self, lp, x, pos):
         """A wave's: B sequences of the one position ``pos[b]``."""
@@ -216,15 +274,22 @@ class EvaByteBackend(DecoderBackend):
         H, D]`` float32."""
         h = rms_norm(x, lp["ln1"], self.rms_eps)
         shape = (*x.shape[:-1], self.n_heads, self.head_dim)
-        q = rope(self._mm(h, lp["wq"]).reshape(shape), pos, self.rope_theta)
-        k = rope(self._mm(h, lp["wk"]).reshape(shape), pos, self.rope_theta)
-        return q, k, self._mm(h, lp["wv"]).reshape(shape)
+        q = rope(self._mm_t(h, lp["wq"]).reshape(shape), pos,
+                 self.rope_theta)
+        k = rope(self._mm_t(h, lp["wk"]).reshape(shape), pos,
+                 self.rope_theta)
+        return q, k, self._mm_t(h, lp["wv"]).reshape(shape)
 
-    def _after_attention(self, lp, x, o):
+    def _after_attention(self, lp, x, o, fence=None):
+        """``fence``: applied to (x, h) between the attention's output
+        projection and the feed-forward (a piece's; see
+        ``piece_logits_fn``)."""
         import jax
 
         x = x + self._mm(o.reshape(x.shape), lp["wo"])
         h = rms_norm(x, lp["ln2"], self.rms_eps)
+        if fence is not None:
+            x, h = fence((x, h))
         return x + self._mm(
             jax.nn.silu(self._mm(h, lp["wg"])) * self._mm(h, lp["wu"]),
             lp["wd"])
@@ -256,15 +321,6 @@ class EvaByteBackend(DecoderBackend):
     def _live_rows(self, lens):
         win = self.window
         return (lens // win) * self.sums_per_window + lens % win
-
-    def _walk_layers(self, p, body, carry):
-        import jax
-        import jax.numpy as jnp
-
-        carry, _ = jax.lax.scan(
-            lambda c, xs: (body(c, *xs), None), carry,
-            (p["layers"], jnp.arange(self.n_layers, dtype=jnp.int32)))
-        return carry
 
     # -- full-context forward (no cache) ----------------------------------------
 
@@ -384,8 +440,19 @@ class EvaByteBackend(DecoderBackend):
             n_sum = (starts // win) * spw
             full = lens == win
 
+            # Three fences a layer keep the unrolled piece at the time it
+            # had as a ``scan``'s body.  With eight layers in sight the
+            # compiler's memory-space assignment gives the fast memory to
+            # prefetched weights (of no use to products of 2048 rows, which
+            # the MXU bounds) where the loop's body kept activations there:
+            # 55.65 ms a piece with none, 54.2 / 54.3 / 53.0 with one,
+            # 50.7 with all three against the scan's 51.03 (PERF.md section
+            # 6, PR 42).  A wave, which the weights' reads bound, is better
+            # off without them (11.80 ms against 11.83).
+            fence = jax.lax.optimization_barrier
+
             def body(carry, lp, li):
-                x, k_a, v_a = carry
+                x, k_a, v_a = fence(carry)
                 q, k, v = self._qkv_rows(lp, x, pos)
                 k_c, v_c = k.astype(k_a.dtype), v.astype(v_a.dtype)
                 shape = (b, pre, self.n_heads, self.head_dim)
@@ -397,6 +464,7 @@ class EvaByteBackend(DecoderBackend):
 
                 o = self._piece_attention(q, k_c, v_c, head_rows(k_a),
                                           head_rows(v_a), n_sum)
+                x, o, k_c, v_c = fence((x, o, k_c, v_c))
                 # A full piece leaves its summaries, a partial one (the
                 # prompt's last) its exact rows; what lies behind either is
                 # beyond ``live`` and never read.
@@ -413,7 +481,7 @@ class EvaByteBackend(DecoderBackend):
                             (li, rows[i], n_sum[i], 0))
                     return leaf
 
-                return (self._after_attention(lp, x, o),
+                return (self._after_attention(lp, x, o, fence),
                         put(k_a, k_c, k_s), put(v_a, v_c, v_s))
 
             x, k_a, v_a = self._walk_layers(
@@ -456,7 +524,10 @@ class EvaByteBackend(DecoderBackend):
 
         def transition(p, arena, rows, lens):
             src = jnp.maximum(lens // win - 1, 0) * spw
-            lp = p["layers"]
+            # All layers at once: the two small leaves stacked in the
+            # program (2 x L x H x D values).
+            phi, mu = (jnp.stack([lp[name] for lp in p["layers"]])
+                       for name in ("phi", "mu"))
             k_a, v_a = arena["k"], arena["v"]
             for i in range(rows.shape[0]):
                 def take(leaf):
@@ -468,7 +539,7 @@ class EvaByteBackend(DecoderBackend):
                 k_s, v_s = jax.vmap(
                     lambda phi, mu, k, v: self._summaries(
                         {"phi": phi, "mu": mu}, k, v))(
-                            lp["phi"], lp["mu"], take(k_a), take(v_a))
+                            phi, mu, take(k_a), take(v_a))
                 k_a = jax.lax.dynamic_update_slice(
                     k_a, k_s.reshape(nl, 1, spw, hd), (0, rows[i], src[i], 0))
                 v_a = jax.lax.dynamic_update_slice(

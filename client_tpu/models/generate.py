@@ -156,11 +156,6 @@ class TinyGptBackend(DecoderBackend):
     def _logits(self, p, x):
         return _ln(x, p["lnfg"], p["lnfb"]) @ p["head"]
 
-    def _walk_layers(self, p, body, carry):
-        for li, lp in enumerate(p["layers"]):
-            carry = body(carry, lp, li)
-        return carry
-
     def _stack(self, p, x, causal, on_kv=None):
         """Full-context transformer stack (no cache reads) over ``x`` [B, n,
         d].  ``on_kv(li, k, v)`` observes each layer's K/V at trace time,

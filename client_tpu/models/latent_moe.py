@@ -450,11 +450,6 @@ class LatentMoeDecoder(DecoderBackend):
             [jnp.stack([self.held_mask(r) for r in x["route"]], axis=1),
              logit_bits(logits, tokens, RECORD_LOGITS)], axis=1)
 
-    def _walk_layers(self, p, body, carry):
-        for li, lp in enumerate(p["layers"]):
-            carry = body(carry, lp, li)
-        return carry
-
     # -- a prefill piece's latent attention -------------------------------------
 
     def _piece_attention(self, lp, q_nope, q_rope, own, before, impl=None):

@@ -108,6 +108,77 @@ def test_weights_are_already_bfloat16(setup):
         np.testing.assert_array_equal(a, b)
 
 
+def restacked(served):
+    """The host's stacked tree again, of the served one (``split_layers``
+    undone): a layer's leaves stacked, those served ``[out, in]`` turned
+    back."""
+    layers = served["layers"]
+    return {**served, "layers": {
+        name: np.stack([np.asarray(lp[name]).T if name in eva_mod.OUT_IN
+                        else np.asarray(lp[name]) for lp in layers])
+        for name in layers[0]}}
+
+
+def bits(a):
+    a = np.asarray(a)
+    assert a.dtype == jnp.bfloat16
+    return a.view(np.uint16)
+
+
+LEAVES = ["embed", "lnf", "head"] + [
+    f"layers/{name}" for name in ("ln1", "ln2", "wq", "wk", "wv", "wo", "wg",
+                                  "wu", "wd", "phi", "mu")]
+
+
+def leaf_at(tree, path):
+    for key in path.split("/"):
+        tree = tree[key]
+    return tree
+
+
+@pytest.fixture(scope="module")
+def placed_again(setup):
+    be, params, *_ = setup
+    placed = be.place_params(params)
+    assert isinstance(placed["layers"], list)
+    assert len(placed["layers"]) == be.n_layers
+    return restacked(placed)
+
+
+@pytest.mark.parametrize("path", LEAVES)
+def test_the_placed_tree_holds_the_hosts_weights_bit_for_bit(
+        setup, placed_again, path):
+    """The reference reads ``_init_params()``, the chip what
+    ``place_params`` made of it (a layer's leaves of their own, the three
+    projections of its input as ``[out, in]``): re-stacked, the same bits."""
+    _, params, *_ = setup
+    assert jax.tree_util.tree_structure(placed_again) \
+        == jax.tree_util.tree_structure(params)
+    want, got = leaf_at(params, path), leaf_at(placed_again, path)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(bits(got), bits(want))
+
+
+def test_a_checkpoint_in_the_stacked_form_still_loads(setup, tmp_path):
+    """A checkpoint holds the stacked tree ``_init_params`` returns; a
+    backend pointed at it serves those weights, split as the seeded ones
+    are."""
+    from client_tpu.engine.checkpoint import save_params
+
+    be, params, _, ids, _ = setup
+    other = backend(seed=11)                 # its own init must not show
+    other.weights_path = save_params(str(tmp_path / "stacked"), params)
+    apply, placed = other.make_apply_params()
+    again = restacked(placed)
+    for path in LEAVES:
+        np.testing.assert_array_equal(bits(leaf_at(again, path)),
+                                      bits(leaf_at(params, path)))
+    seeded = be.make_apply_params()[1]
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(apply)(placed, {"INPUT_IDS": ids[:40]})["logits"]),
+        np.asarray(jax.jit(apply)(seeded, {"INPUT_IDS": ids[:40]})["logits"]))
+
+
 def test_apply_params_against_the_reference(setup):
     be, _, _, ids, ref = setup
     apply, placed = be.make_apply_params()

@@ -16,9 +16,12 @@ piece) at the tree PR 34 left, which moved what ``pangu`` and ``kimi`` share
 into models/latent_moe.py and left ``pangu``'s two as they were; the kernels
 of ``pangu``'s and ``kimi``'s decode waves at the tree PR 40 left (the latent
 kernel walks a lane's live blocks: its body and its grid changed, the
-programs around it did not).  A PR that means to
-change one of these programs records the new hash here and says so; one that
-does not has a guard.
+programs around it did not); ``evabyte``'s four at the tree PR 42 left (its
+layers walked in a loop over leaves of their own, ``wq``, ``wk`` and ``wv``
+served ``[out, in]``, a piece fenced: the other three families' eight, which
+walk the same loop of ``models/decoder.py`` now, did not move).  A PR that
+means to change one of these programs records the new hash here and says so;
+one that does not has a guard.
 
     python - <<'X'          # to record: run from the repo root
     import tests.test_served_programs as t; t.record()
@@ -33,8 +36,8 @@ import jax.numpy as jnp
 import pytest
 
 RECORDED = {
-    ("evabyte", "decode"): ("f9e9e642bfd4a463", "56eee8a35b23e421"),
-    ("evabyte", "prefill"): ("d6eccbae5564229b", "d3dffbbfe8efac92"),
+    ("evabyte", "decode"): ("b5818e6565080505", "835b9664667eda57"),
+    ("evabyte", "prefill"): ("f73e0dc2333af8de", "e59f91f604bb6804"),
     ("gpt", "decode"): ("92758237abe83ac2", "61153d74d471a1af"),
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),
     ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),
@@ -82,6 +85,8 @@ def _program(family, which):
         params = jax.tree_util.tree_map(
             lambda w: jax.ShapeDtypeStruct(w.shape, jnp.dtype(w.dtype)),
             be._init_params())
+    elif family == "evabyte":           # the host's tree, split as placed
+        params = jax.eval_shape(lambda: be.split_layers(be._init_params()))
     else:
         params = jax.eval_shape(be._init_params)
     arena = jax.eval_shape(lambda: be.init_arena(4))

@@ -435,3 +435,109 @@ def test_state_and_latent_decode_step_compiles_at_published_widths(
         assert memory.alias_size_in_bytes >= leaves
         # No copy of a state leaf (3.2 GB) or of the rows (5.4 GB) beside it.
         assert memory.temp_size_in_bytes < 1.0e9, memory
+
+
+# -- the byte-level decoder at its published widths (PR 42) ------------------
+
+EVABYTE = dict(n_layers=2, d_model=4096, n_heads=32, d_ff=11008, vocab=320,
+               n_pred_heads=8, max_seq_len=32768, window=2048, chunk=16,
+               max_streams=16, attention_impl="flash")
+
+
+def _evabyte_program(one_chip, monkeypatch, which):
+    """``evabyte_6b5``'s ``jit_decode`` (a full wave of 16) or ``jit_prefill``
+    (one piece of 2048) for one v5e chip from shapes alone, two of the
+    cell's eight layers.  Returns (optimised text, arena shapes, memory)."""
+    from client_tpu.engine import backend_init
+    from client_tpu.models.evabyte import EvaByteBackend
+    from client_tpu.observability import spans
+
+    monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
+    place = _on(one_chip)
+    backend = EvaByteBackend(name="e", **EVABYTE)
+    n, d, f = backend.n_layers, backend.d_model, backend.d_ff
+    heads = (n, backend.n_heads, backend.head_dim)
+    # The host's stacked tree by its shapes (the seeded one is 0.8 GB of
+    # draws at these widths), split as ``place_params`` splits it.
+    stacked = jax.tree_util.tree_map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16),
+        {"embed": (backend.vocab, d), "lnf": (d,),
+         "head": (d, backend.n_pred_heads * backend.vocab),
+         "layers": {"ln1": (n, d), "ln2": (n, d), "wq": (n, d, d),
+                    "wk": (n, d, d), "wv": (n, d, d), "wo": (n, d, d),
+                    "wg": (n, d, f), "wu": (n, d, f), "wd": (n, f, d),
+                    "phi": heads, "mu": heads}},
+        is_leaf=lambda x: isinstance(x, tuple))
+    params = jax.tree_util.tree_map(
+        lambda a: place(a.shape, a.dtype),
+        jax.eval_shape(backend.split_layers, stacked))
+    arena = jax.tree_util.tree_map(
+        lambda a: place(a.shape, a.dtype),
+        jax.eval_shape(lambda: backend.init_arena(backend.max_streams)))
+    if which == "decode":
+        lanes_i, lanes_f = place((16,), jnp.int32), place((16,), jnp.float32)
+        step = jax.jit(
+            spans.named_step(backend.decode_fn(), spans.STEP_DECODE),
+            donate_argnums=backend.donate_argnums,
+            static_argnums=backend.decode_static_argnums)
+        lowered = step.lower(params, arena, lanes_i, lanes_i, lanes_i,
+                             lanes_f, lanes_i, lanes_f, False)
+    else:
+        lane_i, lane_f = place((1,), jnp.int32), place((1,), jnp.float32)
+        step = jax.jit(
+            spans.named_step(backend.prefill_fn(), spans.STEP_PREFILL),
+            donate_argnums=backend.donate_argnums,
+            static_argnums=backend.prefill_static_argnums)
+        lowered = step.lower(params, arena, lane_i,
+                             place((1, backend.window), jnp.int32), lane_i,
+                             lane_i, lane_f, lane_i, lane_f, False, lane_i)
+    compiled = lowered.compile()
+    return compiled.as_text(), arena, compiled.memory_analysis()
+
+
+def _weights_moved(text):
+    """Instructions that write a weight matrix out again: outside every
+    fused computation (what a fusion calls is part of its one pass), a
+    ``copy`` or a ``dynamic-slice`` fusion whose result is a ``bf16`` matrix
+    of a layer's shapes, leading 1s allowed.  (An asynchronous ``slice`` or
+    ``copy-start`` of one is the compiler's prefetch, in the layout the
+    matrix has.)"""
+    fused = set(re.findall(r" fusion\([^\n]*?calls=%([\w.\-]+)", text))
+    shapes = r"(?:1,)*(?:4096,4096|4096,11008|11008,4096)"
+    pat = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = bf16\[" + shapes
+                     + r"\][^=]*? ([\w\-]+)\(", re.M)
+    moved = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", comp)
+        if head and head.group(1) not in fused:
+            moved += [(name, op) for name, op in pat.findall(comp)
+                      if op == "copy"
+                      or (op == "fusion" and "dynamic-slice" in name)]
+    return moved
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_byte_decoder_reads_every_weight_where_it_lies(one_chip, monkeypatch,
+                                                       which):
+    """At the cell's widths (4096, 32 x 128, ffn 11008, 16 + 1 slots of 4096
+    rows) neither program writes a layer's matrix out again before its
+    product: under a ``scan`` over stacked leaves the wave sliced ``wq`` and
+    ``wk`` out of ``[L, 4096, 4096]`` and copied each to ``{1,2,0}`` every
+    iteration, 1.30 of an 11.86 ms step (PERF.md section 6, PR 42); as
+    ``[in, out]`` leaves of their own they were still transposed.  The
+    donated arena is updated in place."""
+    text, arena, memory = _evabyte_program(one_chip, monkeypatch, which)
+    assert not _weights_moved(text), _weights_moved(text)
+    calls = re.findall(r"%(\w+)\.\d+ = [^=]*? custom-call\(", text)
+    kernel = {"decode": "decode_wave_attention",
+              "prefill": "flash_attention"}[which]
+    assert calls.count(kernel) == EVABYTE["n_layers"]
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    leaf = _leaf_bytes(arena)
+    assert memory.alias_size_in_bytes >= 2 * leaf
+    # A wave's temporaries are its activations; a piece's 2048 rows of
+    # q, k, v and the feed-forward's 11008 columns in float32 (90 MB each):
+    # 256 MB where the ``scan``'s body took 91, of a leaf's 1.14 GB.
+    assert memory.temp_size_in_bytes < {"decode": leaf // 64,
+                                        "prefill": leaf // 2}[which], memory
