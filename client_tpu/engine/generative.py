@@ -179,10 +179,11 @@ class _Inflight:
     """One dispatched execution whose token fetch is pending."""
 
     __slots__ = ("kind", "streams", "tokens", "waves", "t_disp", "bucket",
-                 "depth", "positions", "rows", "pieces", "fresh")
+                 "depth", "positions", "rows", "pieces", "fresh", "by_kind")
 
     def __init__(self, kind, streams, tokens, waves=1, t_disp=0, bucket=0,
-                 depth=0, positions=0, rows=(0, 0), pieces=(), fresh=()):
+                 depth=0, positions=0, rows=(0, 0), pieces=(), fresh=(),
+                 by_kind=(0, 0, 0)):
         self.kind = kind          # 'prefill' | 'piece' | 'wave' | 'chunk'
         self.streams = streams    # lane order, real lanes only
         self.tokens = tokens      # jax.Array future (copy_to_host_async'd)
@@ -194,6 +195,8 @@ class _Inflight:
         self.rows = rows          # cache rows it reads: (summary, exact)
         self.pieces = pieces      # a piece's lanes: (stream, valid positions)
         self.fresh = fresh        # a wave's lanes that decode their first token
+        self.by_kind = by_kind    # rows it reads: (ring, whole-context), and
+        #                           its lanes past the ring
 
 
 class _WarmupReq:
@@ -334,6 +337,7 @@ class GenerativeScheduler(Scheduler):
         # two waves.
         self._piece_len, self._piece_lanes = backend.prefill_piece or (0, 0)
         self._cache_rows = backend.cache_rows
+        self._rows_by_kind = backend.cache_rows_by_kind
         # Counters only the device can fill (``wave_stats``): that many
         # int32 ride behind a decode wave's tokens.
         self._wave_stats = [_sp.GEN_COUNTERS.index(name)
@@ -967,11 +971,17 @@ class GenerativeScheduler(Scheduler):
         rec.c[_sp.C_INFLIGHT_WAVES] += self._inflight_waves
         positions = k * int(lens.sum()) + len(live) * (k * (k - 1) // 2)
         n_sum = n_exact = 0
+        by_kind = [0, 0, 0]
         fresh = []                    # lanes whose first decode token this is
         for s in live:
             if self._cache_rows is not None:
                 a, b = self._cache_rows(s.disp_len)
                 n_sum, n_exact = n_sum + a, n_exact + b
+            if self._rows_by_kind is not None:
+                for step in range(k):
+                    for i, n in enumerate(
+                            self._rows_by_kind(s.disp_len + step)):
+                        by_kind[i] += n
             if s.disp_tokens == 1:
                 fresh.append(s)
             s.disp_len += k
@@ -988,7 +998,8 @@ class GenerativeScheduler(Scheduler):
                                         t_disp=time.monotonic_ns(),
                                         bucket=bucket,
                                         positions=positions,
-                                        rows=(n_sum, n_exact), fresh=fresh))
+                                        rows=(n_sum, n_exact), fresh=fresh,
+                                        by_kind=by_kind))
         self._inflight_waves += k
         if (bucket, k) not in self._wave_cost_captured:
             # Once per wave shape: static roofline numerator for this
@@ -1065,6 +1076,9 @@ class GenerativeScheduler(Scheduler):
                 c[_sp.C_FETCHED_POSITIONS_VALID] += head.positions
                 c[_sp.C_FETCHED_ROWS_SUMMARY] += head.rows[0]
                 c[_sp.C_FETCHED_ROWS_EXACT] += head.rows[1]
+                c[_sp.C_FETCHED_ROWS_WINDOW] += head.by_kind[0]
+                c[_sp.C_FETCHED_ROWS_GLOBAL] += head.by_kind[1]
+                c[_sp.C_FETCHED_LANES_PAST_WINDOW] += head.by_kind[2]
                 profiler().record_wave(
                     self.model.config.name, self.model.config.version,
                     bucket=head.bucket, chunk=head.waves,

@@ -57,6 +57,16 @@ def _kimi_linear() -> ModelBackend:
     return KimiLinearBackend()
 
 
+@register_model("smallthinker", default=False)
+def _smallthinker() -> ModelBackend:
+    """The decoder with window and global layers (a ring beside whole-context
+    rows in one arena), grouped-query heads and ReGLU experts, at its tiny
+    preset.  Opt-in, and imported when it is built, as ``pangu_moe``."""
+    from client_tpu.models.smallthinker import SmallThinkerBackend
+
+    return SmallThinkerBackend()
+
+
 def model_names() -> list[str]:
     _import_all()
     return sorted(_REGISTRY)

@@ -25,7 +25,19 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
   (``_layer_kind``).  Rows left in a slot by its last stream are masked by
   ``lens``; a state is not, so the first piece of a prompt (``starts == 0``)
   starts from zeros, and padded lanes (on the junk slot) and padded positions
-  leave every live slot's state as it was.
+  leave every live slot's state as it was.  A ``"ring"`` layer reads rows
+  from leaves of **another length** (``ring_leaves``, e.g. ``("kw", "vw")``:
+  ``[layers of the kind, R, ring rows, Hkv*D]`` beside ``cache_leaves``' ``[..,
+  R, S, Hkv*D]``: one arena, two row shapes): a slot's ``ring rows`` hold its
+  last that many positions, position n at row ``n mod ring rows`` (a
+  sliding-window layer).  A step at context length ``lens`` writes row
+  ``lens mod ring rows`` and reads the ``min(lens, ring rows)`` live rows but
+  the one it overwrites (ops/decode_kernel.py ``window_wave_attention``); the
+  write position and the live rows are the kind's, ``_live_rows`` is the
+  ``"rows"`` kind's alone.  ``ring_window``: ``None`` (a step sees as many
+  keys as the ring has rows), or fewer.  The order of a ring's rows means
+  nothing to the softmax: a model rotates a key before it is written
+  (``_ring_qkv``).
 - ``wave_stats``: ``()``, or names of ``spans.GEN_COUNTERS`` that only the
   device can count (what a wave's tokens were routed to): the decode program
   then returns that many int32 behind its ``B`` tokens (``_wave_stats(x)``),
@@ -45,6 +57,12 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
   piece, one program).
 - ``cache_rows``: ``None`` (a step reads one row a position) or ``(n) ->
   (summary rows, exact rows)`` a step at context length ``n`` reads.
+- ``cache_rows_by_kind``: ``None``, or ``(n) -> (ring rows, other rows, past)``:
+  what a step at context length ``n`` reads from its ``"ring"`` layers and from
+  its ``"rows"`` layers, each summed over the layers of the kind, and whether
+  the context has outgrown the ring (1 | 0): the scheduler's counters
+  ``fetched_rows_window``, ``fetched_rows_global``,
+  ``fetched_lanes_past_window``.
 - ``transition_due`` / ``transition_fn``: ``None``, or ``(n) -> bool`` and the
   builder of ``(params, arena, rows[T], lens[T]) -> arena``, ordered before the
   wave of a stream that decoded its way to a due length.
@@ -60,7 +78,9 @@ weights; ``li`` its index, a Python int; ``x`` the model's
 own carry between layers: activations ``[B, d]``, or a pytree where a layer
 hands on more than those, as models/pangu_moe.py's routing counts):
 ``_embed(p, tokens, pos)`` -> x; ``_qkv(lp, x, pos)`` -> q, k, v ``[B, H,
-D]`` or, with ``latent_attention = value lanes`` declared (one shared row a
+D]`` (k and v ``[B, Hkv, D]`` where the rows are grouped-query rows; a
+``"ring"`` layer's come from ``_ring_qkv``, by default the same) or, with
+``latent_attention = value lanes`` declared (one shared row a
 position, ops/decode_kernel.py ``latent_wave_attention``), the absorbed
 query as the kernel takes it, ``[B, W, H]`` in the cache's dtype with the
 score scale in it, and the new row ``[B, W]``, and ``_attention_output(lp,
@@ -176,10 +196,13 @@ class DecoderBackend(ModelBackend):
     cache_leaves: tuple[str, ...] = ("k", "v")
     layer_kinds: tuple[str, ...] | None = None
     state_leaves: tuple[str, ...] = ()
+    ring_leaves: tuple[str, ...] = ()
+    ring_window: int | None = None
     latent_attention: int | None = None
     wave_stats: tuple[str, ...] = ()
     stream_record = 0
     cache_rows = None
+    cache_rows_by_kind = None
     transition_due = None
     transition_fn = None
     donate_argnums = (PREFILL_ARGS.index("arena"),)
@@ -302,7 +325,7 @@ class DecoderBackend(ModelBackend):
         return self.attn_impl == "fused" or (
             not self.attn_impl and not pallas_interpret())
 
-    def _decode_attend(self):
+    def _decode_attend(self, ring: bool = False):
         """``attend(k_arena, v_arena, q, k, v, rows, live, layer)`` ->
         (k_arena, v_arena, o): one layer of a wave.  Lane b's new ``k, v``
         ``[B, H, D]`` go to row ``live[b]`` of slot ``rows[b]`` and ``q``
@@ -312,15 +335,33 @@ class DecoderBackend(ModelBackend):
         served decoder hands one over since PR 42, when the last ``scan``
         over layers went: ROADMAP Queue C).  With ``latent_attention``
         declared: ``attend(c_arena, q, new_row, rows, live, layer)`` ->
-        (c_arena, o), the one leaf's kernel or its oracle."""
+        (c_arena, o), the one leaf's kernel or its oracle.  With ``ring``:
+        the ``"ring"`` kind's, over ``ring_leaves`` (``live`` is then the
+        context length: the write position and the live rows follow from
+        it and the leaf's length)."""
         from client_tpu.engine.backend_init import pallas_interpret
         from client_tpu.ops.decode_kernel import (decode_wave_attention,
                                                   latent_wave_attention,
                                                   reference_decode_attention,
-                                                  reference_latent_attention)
+                                                  reference_latent_attention,
+                                                  window_wave_attention)
 
         interpret, block_s = pallas_interpret(), self.decode_block_s
-        if self.latent_attention is not None:
+        if ring:
+            if self.kv_shards > 1:
+                raise NotImplementedError(
+                    "a ring of rows is one chip's (kv_shards > 1)")
+            kernel, window = self._use_kernel(), self.ring_window
+
+            def attend(k_a, v_a, q, k, v, rows, lens, layer):
+                if not kernel:
+                    return reference_decode_attention(
+                        k_a, v_a, q, k, v, rows, lens, layer=layer,
+                        ring=True, window=window)
+                return window_wave_attention(
+                    k_a, v_a, q, k, v, rows, lens, layer=layer,
+                    block_s=block_s, interpret=interpret, window=window)
+        elif self.latent_attention is not None:
             value_dim = self.latent_attention
             if self.kv_shards > 1:
                 raise NotImplementedError(
@@ -407,6 +448,9 @@ class DecoderBackend(ModelBackend):
     def _attention_output(self, lp, o):
         return o
 
+    def _ring_qkv(self, lp, x, pos):
+        return self._qkv(lp, x, pos)
+
     def _wave_stats(self, x):
         raise NotImplementedError
 
@@ -420,11 +464,14 @@ class DecoderBackend(ModelBackend):
         wave), its position is its context length ``lens[b]``; each layer
         writes the lane's new cache row behind the slot's live rows and
         reads them all (``_decode_attend``), whatever leaves the cache has
-        (``cache_leaves``), or advances the slot's state in place
-        (``_advance`` on ``state_leaves``), by the layer's kind."""
+        (``cache_leaves``), does so in the slot's ring (``ring_leaves``), or
+        advances the slot's state in place (``_advance`` on
+        ``state_leaves``), by the layer's kind."""
         attend = self._decode_attend()
+        around = self._decode_attend(ring=True) if self.ring_leaves else None
         held = len(self.cache_leaves)
-        names = self.cache_leaves + self.state_leaves
+        first_state = held + len(self.ring_leaves)
+        names = self.cache_leaves + self.ring_leaves + self.state_leaves
 
         def step(p, arena, rows, lens):
             live = self._live_rows(lens)
@@ -432,15 +479,21 @@ class DecoderBackend(ModelBackend):
 
             def layer(carry, lp, li):
                 x, *leaves = carry
-                cache, state = leaves[:held], leaves[held:]
+                cache, ring, state = (leaves[:held], leaves[held:first_state],
+                                      leaves[first_state:])
                 kind, ki = self._layer_kind(li)
                 if kind == "rows":
                     *cache, o = attend(*cache, *self._qkv(lp, x, lens), rows,
                                        live, ki)
                     o = self._attention_output(lp, o)
+                elif kind == "ring":
+                    *ring, o = around(*ring, *self._ring_qkv(lp, x, lens),
+                                      rows, lens, ki)
+                    o = self._attention_output(lp, o)
                 else:
                     *state, o = self._advance(lp, x, *state, rows, lens, ki)
-                return (self._after_attention(lp, x, o), *cache, *state)
+                return (self._after_attention(lp, x, o), *cache, *ring,
+                        *state)
 
             x, *leaves = self._walk_layers(
                 p, layer, (self._embed(p, tokens, lens),

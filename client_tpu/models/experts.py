@@ -1,0 +1,323 @@
+"""The sparse expert layer of a served decoder, and what goes with it: one
+copy.
+
+Three decoders route every token to a few of many small feed-forward experts:
+``models/pangu_moe.py`` and ``models/kimi_linear.py`` (a latent cache; sigmoid
+scores, SwiGLU experts, a shared expert beside them: ``models/latent_moe.py``)
+and ``models/smallthinker.py`` (key/value rows in two leaves; a softmax over
+the chosen logits, ReLU-gated experts, no shared expert).  What does not
+differ between them lives here, in :class:`ExpertDecoder`:
+
+- weights made when asked for (:class:`SeededWeight`) and put on the device a
+  leaf at a time;
+- **the router** (``route``): float32 at full precision whatever the matmuls',
+  the score function a parameter (``router_score``: ``"sigmoid"`` |
+  ``"softmax"``);
+- **the experts held here** (``_experts``): the backend holds ``experts_held``
+  of the routed experts, ``first_expert ..``; the (token, expert) pairs held
+  here are sorted by expert and multiplied in groups (ops/grouped_matmul.py),
+  the activation between the two products a parameter (``expert_act``:
+  ``"silu"`` | ``"relu"``, a name of ``jax.nn``): no pair is dropped, and an
+  expert no token chose is not read.  What absent experts would add is left
+  out; nothing stands in for other chips or their exchange;
+- the wave's carry (``_embed``: activations, routing counts, choices, live
+  lanes), the final norm and head (``_logits``) and the three counters behind
+  a wave's tokens (``wave_stats``: pairs held here,
+  the busiest held expert's, held experts touched, each summed over the
+  expert layers; padded lanes route nowhere);
+- **a stream's record** of its routing (``held_mask``): one bit a held expert
+  in int32 words, 32 experts a word, the one thing about a routing that a
+  share's output depends on discontinuously.
+
+A model sets ``d_model, d_expert, n_experts, experts_held, first_expert,
+top_k, routed_scale, dtype, _seed`` and, where they differ from the defaults,
+``router_score`` and ``expert_act``.
+"""
+
+from __future__ import annotations
+
+import concurrent.futures
+import math
+import os
+
+import numpy as np
+
+from client_tpu.models.decoder import DecoderBackend
+
+_CHUNK = 1 << 24          # elements of a weight made by one task
+_BLOCK = 1 << 17          # elements made at a time (cache-sized)
+# Rows of a grouped matmul's tile: a wave's groups are a few rows (16 is
+# bfloat16's sublane tile), a prefill piece's some dozens.
+TILE_M_WAVE, TILE_M_PIECE = 16, 64
+# Logits of a row's first ids in a stream's record, beside its token's.
+RECORD_LOGITS = 8
+
+
+def record_width(expert_layers: int) -> int:
+    """int32 a position of a stream's record."""
+    return expert_layers + 1 + RECORD_LOGITS
+
+
+def rms_norm(x, g, eps):
+    """``x / rms(x) * g`` in float32."""
+    import jax.numpy as jnp
+
+    x = x.astype(jnp.float32)
+    var = jnp.mean(x * x, axis=-1, keepdims=True)
+    return x * jnp.reciprocal(jnp.sqrt(var + eps)) * g.astype(jnp.float32)
+
+
+class SeededWeight:
+    """A weight that is made when it is asked for: ``offset + scale * N(0,
+    1)`` from its own seed, **rounded to bfloat16** whatever dtype it is asked
+    in, so a reference that asks for float32 (``np.asarray(w, np.float32)``)
+    holds exactly what the chip holds and never a second copy.  Chunks of
+    ``_CHUNK`` elements have seeds of their own and are filled by as many
+    threads as the process may use (numpy's generators release the
+    interpreter lock): the values do not depend on the thread count.  With
+    ``first`` given, entry i of the leading axis is made from ``first + i``
+    alone: the experts a share holds are the model's, whichever share holds
+    them."""
+
+    def __init__(self, seed, shape, scale, offset=0.0, dtype="bfloat16",
+                 first=None):
+        self.seed, self.shape = tuple(int(s) for s in seed), tuple(shape)
+        self.scale, self.offset = float(scale), float(offset)
+        self.dtype = str(dtype)          # "bfloat16" | "float32"
+        self.first = first
+
+    def _spans(self):
+        """(lo, hi, seed) of every chunk of the flattened weight."""
+        n = int(np.prod(self.shape))
+        unit = n if self.first is None else n // self.shape[0]
+        return [(u + lo, u + min(lo + _CHUNK, unit),
+                 [*self.seed, lo // _CHUNK] + (
+                     [] if self.first is None else [self.first + u // unit]))
+                for u in range(0, n, unit) for lo in range(0, unit, _CHUNK)]
+
+    def _fill(self, out, lo, hi, seed):
+        """Chunk ``[lo, hi)`` of the flattened weight into ``out`` (float32,
+        or uint16 holding bfloat16's bits), a block at a time and in place:
+        whole-chunk temporaries would be mapped and unmapped by every thread
+        at once, which the kernel serializes."""
+        rng = np.random.default_rng(seed)
+        wide = out.dtype == np.float32
+        scratch = None if wide else np.empty(_BLOCK, np.float32)
+        carry = np.empty(_BLOCK, np.uint32)
+        for a in range(lo, hi, _BLOCK):
+            b = min(a + _BLOCK, hi)
+            part = out[a:b] if wide else scratch[:b - a]
+            rng.standard_normal(b - a, dtype=np.float32, out=part)
+            part *= np.float32(self.scale)
+            if self.offset:
+                part += np.float32(self.offset)
+            bits, t = part.view(np.uint32), carry[:b - a]
+            np.right_shift(bits, 16, out=t)      # round to nearest even
+            t &= np.uint32(1)
+            t += np.uint32(0x7FFF)
+            bits += t
+            if wide:
+                bits &= np.uint32(0xFFFF0000)
+            else:
+                np.right_shift(bits, 16, out=t)
+                out[a:b] = t
+
+    def __array__(self, dtype=None, copy=None):
+        import ml_dtypes
+
+        wide = self.dtype == "float32" or (
+            dtype is not None and np.dtype(dtype) == np.float32)
+        out = np.empty(int(np.prod(self.shape)),
+                       np.float32 if wide else np.uint16)
+        spans = self._spans()
+        workers = max(1, min(len(spans), len(os.sched_getaffinity(0))))
+        with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+            list(pool.map(lambda s: self._fill(out, *s), spans))
+        out = out.reshape(self.shape)
+        return out if wide else out.view(ml_dtypes.bfloat16)
+
+
+class ExpertDecoder(DecoderBackend):
+    """The shared parts above."""
+
+    wave_stats = ("expert_pairs_local", "expert_pairs_busiest",
+                  "experts_touched")
+    router_score = "sigmoid"
+    expert_act = "silu"
+    routed_scale = 1.0
+
+    def _check_experts(self):
+        if (self.first_expert + self.experts_held > self.n_experts
+                or self.top_k > self.n_experts):
+            raise ValueError(
+                f"experts {self.first_expert}.."
+                f"{self.first_expert + self.experts_held} and "
+                f"top {self.top_k} do not fit a router of {self.n_experts}")
+        if self.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(f"router_score {self.router_score!r}")
+
+    # -- params --------------------------------------------------------------
+
+    def _weight_makers(self):
+        """``w(*shape, scale, ...)``, ``mat(rows, cols)`` and ``gain(n)``:
+        ``SeededWeight`` leaves numbered in the order they are asked for.  A
+        float32 model's weights are still rounded to bfloat16 values: the
+        same numbers in both forms of the program."""
+        count = iter(range(1 << 20))
+
+        def w(*shape, scale, offset=0.0, dtype=None, first=None):
+            return SeededWeight((self._seed, next(count)), shape, scale,
+                                offset, dtype or self.dtype, first)
+
+        def mat(rows, cols):
+            return w(rows, cols, scale=1.0 / math.sqrt(rows))
+
+        def gain(n):
+            return w(n, scale=0.1, offset=1.0)
+
+        return w, mat, gain
+
+    def place_params(self, params):
+        """Leaf by leaf: a weight is made, put on the device and let go, so
+        the host never holds the model."""
+        import jax
+
+        return jax.tree_util.tree_map(
+            lambda leaf: jax.device_put(np.asarray(leaf)), params)
+
+    # -- shared blocks --------------------------------------------------------
+
+    def _mm(self, x, w):
+        """Operands in the weights' dtype, float32 result."""
+        import jax.numpy as jnp
+
+        return jnp.matmul(x.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32)
+
+    def route(self, lp, h):
+        """The router: h ``[n, d]`` float32 (normed) -> (experts ``[n, k]``,
+        weights ``[n, k]`` float32); float32 at full precision whatever the
+        matmuls'.  ``router_score = "sigmoid"``: ``s = sigmoid(h W_g)``, the
+        ``top_k`` largest of ``s`` (of ``s + b`` where the gate has a
+        selection bias, ``router_bias``), weights ``s_i / sum s_i *
+        routed_scale``.  ``"softmax"``: the ``top_k`` largest logits and a
+        softmax over those alone (a softmax over all of them renormalised
+        over the chosen is the same numbers)."""
+        import jax
+        import jax.numpy as jnp
+
+        s = jnp.matmul(h, lp["router"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        if self.router_score == "softmax":
+            top_s, top_i = jax.lax.top_k(s, self.top_k)
+            return top_i, jax.nn.softmax(top_s, axis=-1)
+        s = jax.nn.sigmoid(s)
+        if "router_bias" in lp:
+            _, top_i = jax.lax.top_k(s + lp["router_bias"], self.top_k)
+            top_s = jnp.take_along_axis(s, top_i, axis=-1)
+        else:
+            # No bias: the scores are ``top_k``'s own values.  A zero bias
+            # through the branch above would be one path, but it gives
+            # models/pangu_moe.py another program than the recorded one
+            # (an add and a gather more; tests/test_served_programs.py).
+            top_s, top_i = jax.lax.top_k(s, self.top_k)
+        weights = top_s / top_s.sum(-1, keepdims=True) * self.routed_scale
+        return top_i, weights
+
+    def _experts(self, lp, h, live, tile_m, routing=None):
+        """The held experts' part of the layer for tokens h ``[n, d]``:
+        ``sum_i w_i E_i(h)`` over the chosen experts held here (``E(h) = W_d
+        (act(h W_g) * h W_u)``, ``act`` by ``expert_act``), (pairs here,
+        the busiest expert's, experts touched), and every token's choices
+        ``[n, k]``."""
+        import jax
+        import jax.numpy as jnp
+
+        from client_tpu.engine.backend_init import pallas_interpret
+        from client_tpu.ops.grouped_matmul import (capacity_rows,
+                                                   grouped_matmul,
+                                                   plan_groups,
+                                                   reference_grouped_matmul)
+
+        n, held, k = h.shape[0], self.experts_held, self.top_k
+        # (A router that does not read h has routed already: ``routing``.)
+        top_i, weights = self.route(lp, h) if routing is None else routing
+        here = ((top_i >= self.first_expert)
+                & (top_i < self.first_expert + held) & live[:, None])
+        expert = jnp.where(here, top_i - self.first_expert, held).reshape(-1)
+        rows = capacity_rows(n * min(k, held), held, tile_m)
+        plan = plan_groups(expert.astype(jnp.int32), held, tile_m, rows)
+        # The sorted layout by gather: row r holds the token of the pair
+        # that goes there, a zero row where none does.
+        token = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
+        src = jnp.full(rows + 1, n, jnp.int32).at[plan["dest"]].set(
+            token)[:rows]
+        wdt = lp["egu"].dtype
+        xs = jnp.concatenate([h.astype(wdt), jnp.zeros((1, h.shape[1]), wdt)
+                              ])[src]
+        if self._use_kernel():
+            def gmm(x, w):
+                return grouped_matmul(x, w, plan["tile_expert"],
+                                      plan["n_tiles"], tile_m=tile_m,
+                                      interpret=pallas_interpret())
+        else:
+            def gmm(x, w):
+                return reference_grouped_matmul(x, w, plan["padded"])
+        gu = gmm(xs, lp["egu"])
+        f = gu.shape[-1] // 2
+        act = getattr(jax.nn, self.expert_act)
+        ys = gmm((act(gu[:, :f]) * gu[:, f:]).astype(wdt), lp["ed"])
+        # Back to tokens: a pair's row by ``dest``; rows no pair points at
+        # (the kernel leaves those behind the last tile unwritten) are
+        # never read.
+        dest = plan["dest"].reshape(n, k)
+        got = dest < rows
+        picked = ys[jnp.where(got, dest, 0)]                  # [n, k, d]
+        y = jnp.sum(jnp.where(got[..., None], picked, 0.0)
+                    * weights[..., None], axis=1)
+        sizes = plan["sizes"]
+        counts = jnp.stack([sizes.sum(), sizes.max(),
+                            (sizes > 0).sum()]).astype(jnp.int32)
+        return y, counts, top_i
+
+    # -- the decode step's parts (models/decoder.py) ---------------------------
+
+    def _embed(self, p, tokens, pos):
+        """The wave's carry: activations, the routing counts and the
+        choices so far (a tuple that grows by a layer's ``[B, k]``; nothing
+        a served program returns, so nothing it computes), and which lanes
+        hold a stream (a padded lane's length is 0)."""
+        import jax.numpy as jnp
+
+        return {"h": p["embed"][tokens].astype(jnp.float32),
+                "stats": jnp.zeros(3, jnp.int32), "route": (),
+                "live": pos > 0}
+
+    def _logits(self, p, x):
+        h = x["h"] if isinstance(x, dict) else x
+        return self._mm(rms_norm(h, p["lnf"], self.rms_eps), p["head"])
+
+    def _wave_stats(self, x):
+        return x["stats"]
+
+    def held_mask(self, top_i, word: int = 0):
+        """Choices ``[..., k]`` -> int32 ``[...]``: bit ``e`` set where held
+        expert ``first_expert + 32 * word + e`` is among them (a ``top_k``'s
+        choices are distinct, so the sum is the union).  A share of more
+        than 32 experts takes ``held_words`` words a layer."""
+        import jax
+        import jax.numpy as jnp
+
+        e = top_i - (self.first_expert + 32 * word)
+        bits = jnp.where((e >= 0) & (e < min(32, self.experts_held
+                                             - 32 * word)),
+                         jnp.left_shift(jnp.uint32(1),
+                                        jnp.clip(e, 0, 31).astype(jnp.uint32)),
+                         jnp.uint32(0))
+        return jax.lax.bitcast_convert_type(
+            bits.sum(axis=-1, dtype=jnp.uint32), jnp.int32)
+
+    @property
+    def held_words(self) -> int:
+        """int32 words that hold one bit a held expert."""
+        return -(-self.experts_held // 32)

@@ -1,0 +1,444 @@
+"""Window and global layers in one decoder, grouped-query heads, sparse ReGLU
+experts routed before attention (`smallthinker`), served through the
+generative path.
+
+The architecture is the public ``SmallThinker-21BA3B-Instruct`` config's:
+layers in periods of four (``sliding_window_layout`` = ``rope_layout`` = ``[0,
+1, 1, 1]``), the first **global** (every earlier position, **no positions**),
+the other three **window** layers (the last ``window`` positions, rotary
+positions); ``n_heads`` query heads over ``n_kv_heads`` key/value heads (query
+head i reads key/value head ``i // (n_heads / n_kv_heads)``); every layer's
+feed-forward a router over ``n_experts`` small ReLU-gated experts, no shared
+expert, no dense layer; RMSNorm, no biases, an untied head; a float32 residual
+stream and float32 logits over bfloat16 matmuls.  With x ``[n, d]``:
+
+- *Block*: ``h = N1(x)``; **the router first**: ``r = h W_r`` (float32), ``E``
+  the ``top_k`` largest, ``w = softmax(r[E])``; ``q, k, v = h W_q, h W_k, h
+  W_v``; a window layer rotates q and k (RoPE over the whole head, the
+  rotate-half pairing); scores ``q . k / sqrt(D)``, key j for query t iff ``j
+  <= t`` and, in a window layer, ``t - window < j``; ``x += (softmax(scores) v)
+  W_o``; ``h2 = N2(x)``; ``x += sum_{e in E} w_e W_d^e (relu(h2 W_g^e) * (h2
+  W_u^e))``.  A final RMSNorm and ``x W_head``.  (That the router reads
+  ``N1(x)``, what the attention's projections read, is how this file reads
+  "router placed before attention".)
+
+**The cache is one arena with two row shapes** (``models/decoder.py``'s layer
+kinds): the global layers' leaves ``kg, vg [layers of the kind, R,
+max_seq_len, Hkv*D]`` hold a row a position; the window layers' ``kw, vw
+[layers of the kind, R, window, Hkv*D]`` are **rings**, position n at row ``n
+mod window`` (``ring_rows``: a window that is no multiple of the piece is
+rounded up to one, and the rows that hold older positions are masked).  A
+decode step writes that row and reads the ``min(n, window)`` live rows but the
+one it overwrites (``ops/decode_kernel.py`` ``window_wave_attention``; the
+global layers' is ``decode_wave_attention``, both with grouped-query rows).
+**Keys are rotated before they are written**, so a ring's order means nothing
+to the softmax.  A slot is ``(3 window + max_seq_len) / (4 max_seq_len)`` of
+what it would be with every layer global.
+
+**Prefill goes by pieces** of ``piece`` positions (``prefill_piece``), one
+prompt a call.  Piece i of a prompt has ``i * piece`` rows before it in a
+global layer and ``min(i * piece, window)`` in a window layer, so a layer
+holds one branch a count (``lax.switch``: nothing masked is computed but
+inside the band's two edge blocks): the piece's queries attend to the rows
+before them and, causally, to their own, with the flash kernel's band and
+grouped-query heads (``ops/flash_attention.py``).  A ring that is full is read
+whole, oldest position first, **before** the piece's rows overwrite its oldest
+block (a ring holds whole pieces: a piece never wraps); a
+prompt's last piece writes its valid rows only, the rows behind them being
+positions a later step still reads.
+
+The expert layer (router, grouped matmuls, the lazily made weights, a wave's
+counters, the words of a stream's record) is ``models/experts.py``'s, shared
+with ``models/latent_moe.py``; this backend holds all ``n_experts`` by default
+(``experts_held``, ``first_expert``: a share of them as one chip of an
+expert-parallel group would, what the absent ones add left out).  Layers are a
+Python loop over per-layer weights: a matrix is an operand as it lies.
+
+**A stream's record** (``stream_record``; ``record=True``): for every position
+the programs consumed, ``held_words`` int32 an expert layer (bit e of word w:
+held expert ``first_expert + 32 w + e`` was chosen) and the float32 bits of
+``1 + RECORD_LOGITS`` logits of the row its token was chosen from.
+"""
+
+from __future__ import annotations
+
+import math
+
+from client_tpu.models.decoder import logit_bits, sample_into_slots
+from client_tpu.models.evabyte import rope
+from client_tpu.models.experts import (RECORD_LOGITS, TILE_M_PIECE,
+                                       TILE_M_WAVE, ExpertDecoder,
+                                       record_width, rms_norm)
+
+_NEG_INF = -1e30
+
+
+class SmallThinkerBackend(ExpertDecoder):
+    """The decoder above (``models/decoder.py`` for what it is served
+    through).  ``dtype="float32"`` makes weights, cache and matmuls float32
+    (the tests' exact comparison); the served form is bfloat16."""
+
+    cache_leaves = ("kg", "vg")
+    ring_leaves = ("kw", "vw")
+    router_score = "softmax"
+    expert_act = "relu"
+
+    def __init__(self, name: str = "smallthinker", n_layers: int = 4,
+                 d_model: int = 64, n_heads: int = 4, n_kv_heads: int = 2,
+                 head_dim: int = 16, d_expert: int = 32, n_experts: int = 8,
+                 experts_held: int | None = None, first_expert: int = 0,
+                 top_k: int = 2, window: int = 16,
+                 window_layout=(0, 1, 1, 1), rope_layout=None,
+                 vocab: int = 96, max_seq_len: int = 64, piece: int = 8,
+                 rope_theta: float = 1500000.0, rms_eps: float = 1e-6,
+                 max_streams: int = 4, seed: int = 0,
+                 attention_impl: str = "einsum",
+                 attn_impl: str | None = None, dtype: str = "bfloat16",
+                 record: bool = False):
+        super().__init__(name, vocab=vocab, max_seq_len=max_seq_len,
+                         max_streams=max_streams,
+                         attention_impl=attention_impl, attn_impl=attn_impl)
+        self.n_layers, self.d_model = int(n_layers), int(d_model)
+        self.n_heads, self.n_kv_heads = int(n_heads), int(n_kv_heads)
+        self.head_dim, self.d_expert = int(head_dim), int(d_expert)
+        self.n_experts, self.first_expert = int(n_experts), int(first_expert)
+        self.experts_held = int(n_experts if experts_held is None
+                                else experts_held)
+        self.top_k, self.window, self.piece = int(top_k), int(window), int(
+            piece)
+        self.rope_theta, self.rms_eps = float(rope_theta), float(rms_eps)
+        self.dtype = str(dtype)
+        self._seed = seed
+        # Which layers slide and which rotate, a layer each (the config's
+        # two lists, a period or the whole depth).  The frame hands a layer
+        # over by its kind, so the layers of a kind rotate alike.
+        def per_layer(layout):
+            return [bool(layout[i % len(layout)])
+                    for i in range(self.n_layers)]
+
+        slides = per_layer(window_layout)
+        rotates = slides if rope_layout is None else per_layer(rope_layout)
+        self.layer_kinds = tuple("ring" if s else "rows" for s in slides)
+        self.rotate = {kind: rotates[self.layer_kinds.index(kind)]
+                       for kind in set(self.layer_kinds)}
+        if any(r != self.rotate[k]
+               for r, k in zip(rotates, self.layer_kinds)):
+            raise ValueError("the layers of a kind (window | global) rotate "
+                             "alike or not at all")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{n_heads} query heads over {n_kv_heads} "
+                             "key/value heads")
+        # A ring holds whole pieces (a piece never wraps): the window's keys
+        # rounded up to them.
+        self.ring_rows = -(-self.window // self.piece) * self.piece
+        if self.ring_rows != self.window:
+            self.ring_window = self.window
+        if self.max_seq_len % self.piece or self.ring_rows > self.max_seq_len:
+            raise ValueError("max_seq_len divides into prefill pieces, and "
+                             "a window's ring fits a slot")
+        self._check_experts()
+        self.prefill_piece = (self.piece, 1)
+        self.stream_record = record_width(
+            self.n_layers * self.held_words) if record else 0
+
+    # -- what the scheduler counts (models/decoder.py) ---------------------------
+
+    def cache_rows_by_kind(self, n: int) -> tuple[int, int, int]:
+        """(ring rows, whole-context rows, past the ring) of a decode step at
+        context length ``n``: a window layer reads its live rows but the one
+        it overwrites, a global layer every position's."""
+        rings = self.layer_kinds.count("ring")
+        return (rings * min(n, self.window - 1),
+                (self.n_layers - rings) * n, int(n > self.window))
+
+    # -- params --------------------------------------------------------------
+
+    def _init_params(self):
+        """Seeded weights as ``SeededWeight`` leaves (made, and rounded to
+        bfloat16, when asked for).  Layers are a list; a layer holds its two
+        norms, the four projections, the router (float32) and the held
+        experts' stacked ``egu [E, d, 2f]`` (gate | up) and ``ed [E, f,
+        d]``."""
+        d, hd = self.d_model, self.head_dim
+        f, e = self.d_expert, self.experts_held
+        w, mat, gain = self._weight_makers()
+
+        def layer():
+            return {
+                "ln1": gain(d), "ln2": gain(d),
+                "wq": mat(d, self.n_heads * hd),
+                "wk": mat(d, self.n_kv_heads * hd),
+                "wv": mat(d, self.n_kv_heads * hd),
+                "wo": mat(self.n_heads * hd, d),
+                "router": w(d, self.n_experts, scale=1.0 / math.sqrt(d),
+                            dtype="float32"),
+                "egu": w(e, d, 2 * f, scale=1.0 / math.sqrt(d),
+                         first=self.first_expert),
+                "ed": w(e, f, d, scale=1.0 / math.sqrt(f),
+                        first=self.first_expert)}
+
+        return {"embed": w(self.vocab, d, scale=1.0),
+                "layers": [layer() for _ in range(self.n_layers)],
+                "lnf": gain(d), "head": mat(d, self.vocab)}
+
+    # -- the model's own blocks -------------------------------------------------
+
+    def _project(self, lp, x, pos, rotate: bool):
+        """x ``[n, d]`` float32 -> q ``[n, H, D]``, k, v ``[n, Hkv, D]``
+        float32, q and k rotated to ``pos`` where the layer rotates."""
+        h = rms_norm(x, lp["ln1"], self.rms_eps)
+        n = x.shape[0]
+        q = self._mm(h, lp["wq"]).reshape(n, self.n_heads, self.head_dim)
+        k = self._mm(h, lp["wk"]).reshape(n, self.n_kv_heads, self.head_dim)
+        v = self._mm(h, lp["wv"]).reshape(n, self.n_kv_heads, self.head_dim)
+        if rotate:
+            q, k = rope(q, pos, self.rope_theta), rope(k, pos,
+                                                       self.rope_theta)
+        return q, k, v
+
+    def _router_input(self, lp, x):
+        """What the router reads of a layer's input x: what the attention's
+        projections read (the one thing the published config leaves to a
+        reader; a method of its own so that the benchmark's control can
+        serve the other reading)."""
+        return rms_norm(x, lp["ln1"], self.rms_eps)
+
+    def _after_rows(self, lp, x, o, live, tile_m):
+        """The block behind its attention, for rows x ``[n, d]`` (the
+        layer's input) and their heads' outputs o ``[n, H * D]`` -> (x,
+        routing counts, choices ``[n, k]``).  The router reads what the
+        attention read."""
+        routing = self.route(lp, self._router_input(lp, x))
+        x = x + self._mm(o, lp["wo"])
+        y, counts, top_i = self._experts(
+            lp, rms_norm(x, lp["ln2"], self.rms_eps), live, tile_m,
+            routing=routing)
+        return x + y, counts, top_i
+
+    def _words(self, top_i):
+        """Choices ``[..., k]`` -> the record's words ``[..., held_words]``."""
+        import jax.numpy as jnp
+
+        return jnp.stack([self.held_mask(top_i, w)
+                          for w in range(self.held_words)], axis=-1)
+
+    # -- the decode step's parts (models/decoder.py) ---------------------------
+
+    def _qkv(self, lp, x, pos):
+        return self._project(lp, x["h"], pos, self.rotate["rows"])
+
+    def _ring_qkv(self, lp, x, pos):
+        return self._project(lp, x["h"], pos, self.rotate["ring"])
+
+    def _after_attention(self, lp, x, o):
+        h, stats, top_i = self._after_rows(
+            lp, x["h"], o.reshape(o.shape[0], -1), x["live"], TILE_M_WAVE)
+        return {**x, "h": h, "stats": x["stats"] + stats,
+                "route": x["route"] + (top_i,)}
+
+    def _record(self, x, logits, tokens):
+        """A wave's rows of the streams' record ``[B, stream_record]``."""
+        import jax.numpy as jnp
+
+        return jnp.concatenate(
+            [self._words(r) for r in x["route"]]
+            + [logit_bits(logits, tokens, RECORD_LOGITS)], axis=1)
+
+    # -- attention over a piece or a whole context --------------------------------
+
+    def _attend(self, q, own_k, own_v, before_k, before_v, window,
+                impl=None):
+        """A piece's attention: q ``[n, H, D]`` float32 against the keys and
+        values of the rows before it ``[P, Hkv*D]`` and, causally, of its own
+        ``[n, Hkv*D]`` (both as the cache holds them), in a band of ``window``
+        keys where one is given, by ``impl`` (the backend's
+        ``attention_impl`` unless given).  -> ``[n, H * D]`` float32."""
+        import jax
+        import jax.numpy as jnp
+
+        n, pre = own_k.shape[0], before_k.shape[0]
+        k_all = jnp.concatenate([before_k, own_k]) if pre else own_k
+        v_all = jnp.concatenate([before_v, own_v]) if pre else own_v
+        h, hk, d = self.n_heads, self.n_kv_heads, self.head_dim
+        if (impl or self.attention_impl) == "flash":
+            from client_tpu.engine.backend_init import pallas_interpret
+            from client_tpu.ops.flash_attention import flash_attention
+
+            return flash_attention(
+                q.reshape(1, n, h * d).astype(k_all.dtype), k_all[None],
+                v_all[None], causal=True, prefix=pre, window=window,
+                n_heads=h, n_kv_heads=hk, block_q=n, block_k=n,
+                interpret=pallas_interpret())[0].astype(jnp.float32)
+        group = h // hk
+        k_f = jnp.repeat(k_all.astype(jnp.float32).reshape(pre + n, hk, d),
+                         group, axis=1)
+        v_f = jnp.repeat(v_all.astype(jnp.float32).reshape(pre + n, hk, d),
+                         group, axis=1)
+        s = jnp.einsum("qhd,khd->hqk", q, k_f) / math.sqrt(d)
+        ago = (pre + jnp.arange(n)[:, None]) - jnp.arange(pre + n)[None, :]
+        seen = ago >= 0
+        if window is not None:
+            seen = seen & (ago < window)
+        s = jnp.where(seen[None], s, _NEG_INF)
+        return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1),
+                          v_f).reshape(n, h * d)
+
+    def _piece_layer(self, lp, leaves, kind, ki, row, start, n_valid, x,
+                     pos):
+        """One layer's attention for a piece, its rows written: ``leaves`` the
+        (K, V) leaves of the layer's kind, ``ki`` its index among them.  ->
+        (K leaf, V leaf, o ``[piece, H * D]``)."""
+        import jax
+        import jax.numpy as jnp
+
+        n, ring = self.piece, kind == "ring"
+        hd = self.n_kv_heads * self.head_dim
+        k_a, v_a = leaves
+        q, k, v = self._project(lp, x, pos, self.rotate[kind])
+        own_k, own_v = (t.reshape(n, hd).astype(k_a.dtype) for t in (k, v))
+
+        def rows_of(leaf, count):
+            return jax.lax.dynamic_slice(
+                leaf, (ki, row, 0, 0), (1, 1, count, hd))[0, 0]
+
+        def attend(pre, rolled=False):
+            before = [rows_of(leaf, pre) for leaf in (k_a, v_a)]
+            if rolled:
+                # A full ring, oldest position first: row (start mod ring)
+                # holds position start - ring.
+                before = [jnp.roll(b, -(start % self.ring_rows), axis=0)
+                          for b in before]
+            return self._attend(q, own_k, own_v, *before,
+                                self.window if ring else None)
+
+        if ring:
+            full = self.ring_rows // n
+            branches = [lambda pre=i * n: attend(pre) for i in range(full)]
+            branches.append(lambda: attend(self.ring_rows, rolled=True))
+            o = jax.lax.switch(jnp.minimum(start // n, full), branches)
+            at = start % self.ring_rows
+            # A prompt's last piece: the rows behind its valid ones hold
+            # positions a later step still reads.
+            valid = (jnp.arange(n) < n_valid)[:, None]
+            own_k, own_v = (
+                jnp.where(valid, own, jax.lax.dynamic_slice(
+                    leaf, (ki, row, at, 0), (1, 1, n, hd))[0, 0])
+                for own, leaf in ((own_k, k_a), (own_v, v_a)))
+        else:
+            o = jax.lax.switch(
+                start // n, [lambda pre=i * n: attend(pre)
+                             for i in range(self.max_seq_len // n)])
+            at = start
+        k_a, v_a = (jax.lax.dynamic_update_slice(
+            leaf, own[None, None], (ki, row, at, 0))
+            for leaf, own in ((k_a, own_k), (v_a, own_v)))
+        return k_a, v_a, o
+
+    # -- full-context forward (no cache) ----------------------------------------
+
+    def make_apply_params(self):
+        """Full-context forward in the served precision, no cache and no
+        pieces: logits of every position, and each layer's choices
+        ``[layers, n, top_k]``.  Model-level entry for warm-up and
+        diagnostics; serving goes through pieces and waves."""
+        params = self.place_params(self.load_or_init_params(self._init_params))
+
+        def apply(p, inputs):
+            import jax.numpy as jnp
+
+            ids = inputs["INPUT_IDS"].astype("int32")
+            n = ids.shape[0]
+            pos = jnp.arange(n)
+            live = jnp.ones(n, bool)
+            cdt = jnp.dtype(self.dtype)
+            hd = self.n_kv_heads * self.head_dim
+            x = p["embed"][ids].astype(jnp.float32)
+            routes = []
+            for lp, kind in zip(p["layers"], self.layer_kinds):
+                q, k, v = self._project(lp, x, pos, self.rotate[kind])
+                own_k, own_v = (t.reshape(n, hd).astype(cdt) for t in (k, v))
+                o = self._attend(q, own_k, own_v, own_k[:0], own_v[:0],
+                                 self.window if kind == "ring" else None,
+                                 impl="einsum")
+                x, _, top_i = self._after_rows(lp, x, o, live, TILE_M_PIECE)
+                routes.append(top_i)
+            return {"logits": self._logits(p, x),
+                    "routing": jnp.stack(routes)}
+
+        return apply, params
+
+    # -- generative interface (used by GenerativeScheduler) -------------------
+
+    def init_arena(self, capacity: int):
+        """``kg, vg [global layers, R, max_seq_len, Hkv*D]`` and ``kw, vw
+        [window layers, R, window, Hkv*D]`` in the model's dtype (``R =
+        capacity + 1``: the last slot absorbs padded lanes) and ``tok [R]``,
+        each slot's latest token on the device."""
+        import jax.numpy as jnp
+
+        r, dt = capacity + 1, jnp.dtype(self.dtype)
+        hd = self.n_kv_heads * self.head_dim
+        rings = self.layer_kinds.count("ring")
+        whole = (self.n_layers - rings, r, self.max_seq_len, hd)
+        ring = (rings, r, self.ring_rows, hd)
+        return {"kg": jnp.zeros(whole, dt), "vg": jnp.zeros(whole, dt),
+                "kw": jnp.zeros(ring, dt), "vw": jnp.zeros(ring, dt),
+                "tok": jnp.zeros(r, jnp.int32)}
+
+    def piece_hidden_fn(self):
+        """(params, arena, rows[1], ids[1, piece], lens[1], starts[1]) ->
+        (arena, x ``[piece, d]``, choices ``[layers, piece, top_k]``): one
+        prefill piece, positions ``starts .. starts + lens`` of the lane's
+        prompt (``starts`` a multiple of the piece)."""
+        import jax.numpy as jnp
+
+        n = self.piece
+
+        def piece(p, arena, rows, ids, lens, starts):
+            row, start = rows[0], starts[0]
+            pos = start + jnp.arange(n)
+            live = jnp.arange(n) < lens[0]
+            leaves = {"rows": [arena["kg"], arena["vg"]],
+                      "ring": [arena["kw"], arena["vw"]]}
+            x = p["embed"][ids[0]].astype(jnp.float32)
+            routes = []
+            for li, lp in enumerate(p["layers"]):
+                kind, ki = self._layer_kind(li)
+                *leaves[kind], o = self._piece_layer(
+                    lp, leaves[kind], kind, ki, row, start, lens[0], x, pos)
+                x, _, top_i = self._after_rows(lp, x, o, live, TILE_M_PIECE)
+                routes.append(top_i)
+            (kg, vg), (kw, vw) = leaves["rows"], leaves["ring"]
+            return ({**arena, "kg": kg, "vg": vg, "kw": kw, "vw": vw}, x,
+                    jnp.stack(routes))
+
+        return piece
+
+    def prefill_fn(self):
+        """``PREFILL_ARGS`` -> (arena, tokens[1]): one **piece** of the
+        lane's prompt; the token sampled after its last valid position lands
+        in the slot's device-side token, and means something for a prompt's
+        last piece only.  With ``stream_record`` the piece's rows of the
+        record follow the token, ``[1 + piece x stream_record]``."""
+        piece = self.piece_hidden_fn()
+
+        def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
+                    sample, starts):
+            import jax.numpy as jnp
+
+            arena, x, routes = piece(p, arena, rows, ids, lens, starts)
+            logits = self._logits(p, x[lens - 1])
+            arena, tokens = sample_into_slots(
+                arena, rows, logits, seeds, starts + lens, temps, top_ks,
+                top_ps, sample)
+            if not self.stream_record:
+                return arena, tokens
+            last = jnp.arange(self.piece) == lens[0] - 1
+            rec = jnp.concatenate(
+                [self._words(r) for r in routes]
+                + [jnp.where(last[:, None],
+                             logit_bits(logits, tokens, RECORD_LOGITS), 0)],
+                axis=1)
+            return arena, jnp.concatenate([tokens, rec.reshape(-1)])
+
+        return prefill

@@ -122,6 +122,12 @@ GEN_COUNTERS = (
     # whose gap held at least one prefill call (counted once a gap).
     "gap_lanes", "gap_lane_ns", "gap_lanes_behind_prefill",
     "gap_lane_behind_prefill_ns",
+    # A backend with two kinds of row cache (``cache_rows_by_kind``; 0 for
+    # every other), per decode fetch: the rows the live lanes read from
+    # their rings (sliding-window layers) and from their whole-context
+    # leaves, each summed over the layers of the kind, and the live lanes
+    # whose context has outgrown the ring (those the ring saves reads for).
+    "fetched_rows_window", "fetched_rows_global", "fetched_lanes_past_window",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
@@ -133,7 +139,8 @@ GEN_COUNTERS = (
  C_EXPERTS_TOUCHED, C_PREFILL_POSITIONS_VALID, C_PREFILL_POSITIONS_PADDED,
  C_PROMPTS_STARTED, C_ADMIT_WAIT_NS, C_PREFILL_LINE_WAIT_NS, C_GAP_LANES,
  C_GAP_LANE_NS, C_GAP_LANES_BEHIND_PREFILL,
- C_GAP_LANE_BEHIND_PREFILL_NS) = range(len(GEN_COUNTERS))
+ C_GAP_LANE_BEHIND_PREFILL_NS, C_FETCHED_ROWS_WINDOW, C_FETCHED_ROWS_GLOBAL,
+ C_FETCHED_LANES_PAST_WINDOW) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

@@ -103,14 +103,24 @@ def pick_block_s(seq_len: int, cap: int = 512) -> int:
 
 
 def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
-                   head_dim: int, sm_scale: float):
+                   head_dim: int, sm_scale: float, ring: int = 0,
+                   ring_rows: int = 0, q_group: int = 0):
     """One (lane, key-block) grid step; key blocks iterate innermost so the
     scratch carries the online-softmax state across one lane's row.
-    ``layer`` is a Python int, or ``None`` when the layer index arrives as a
-    third scalar-prefetch operand (a decoder that scans over its layers)."""
+    ``layer`` is a Python int, or ``None`` when the layer index arrives as
+    the last scalar-prefetch operand (a decoder that scans over its layers).
+    ``ring`` > 0: the row a lane writes arrives apart from its count of live
+    rows (a third scalar-prefetch operand), and a step sees ``ring`` keys, the
+    new one among them: old content that many positions back or more is
+    masked (with ``ring`` the slot's ``ring_rows``, the written row alone).
+    ``q_group`` > 0: grouped-query rows, ``q_group`` query heads to a key
+    head; q and o are ``[Hp, D]`` a lane."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    write_ref = None
+    if ring:
+        write_ref, refs = refs[0], refs[1:]
     if layer is None:
         layer = refs[0][0]
         refs = refs[1:]
@@ -122,13 +132,16 @@ def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
     nk = pl.num_programs(1)
     row = rows_ref[b]
     length = lens_ref[b]                 # valid prefix length (strict)
+    # The row the new token goes to: behind the live rows, or in a ring the
+    # row of the position that leaves the window.
+    write = length if write_ref is None else write_ref[b]
     hp, hd = acc_ref.shape
     group = kbuf.shape[0]
-    g0 = pl.multiple_of((length // group) * group, group)
+    g0 = pl.multiple_of((write // group) * group, group)
 
     def group_copies(read: bool):
-        """The aligned row group around position ``length``, K and V:
-        arena -> VMEM (``read``) or back."""
+        """The aligned row group around row ``write``, K and V: arena ->
+        VMEM (``read``) or back."""
         out = []
         for i, (arena, buf) in enumerate(((ko_ref, kbuf), (vo_ref, vbuf))):
             hbm = arena.at[layer, row, pl.ds(g0, group)]
@@ -147,8 +160,15 @@ def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
     # Block-diagonal query: row h keeps head h's lanes of the scaled q.
     lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
     head = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+    if q_group:
+        # Query head i reads key head i // q_group: its features stand at
+        # that key head's lanes (a padded head's at none).
+        head = head // q_group
     own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
-    qbd = jnp.where(own, q_ref[0] * sm_scale, 0.0)           # [Hp, H*D]
+    q_row = q_ref[0] * sm_scale
+    if q_group:
+        q_row = jnp.concatenate([q_row] * (hd // head_dim), axis=1)
+    qbd = jnp.where(own, q_row, 0.0)                         # [Hp, H*D]
     # The MXU takes the arena's dtype: float32 blocks in full precision,
     # bfloat16 blocks (products exact in the float32 accumulator) in one pass.
     cache_dtype = k_ref.dtype
@@ -164,6 +184,15 @@ def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
             preferred_element_type=jnp.float32, precision=highest)
         pos = ik * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = pos < length                                 # [Hp, block_s]
+        if ring and ring == ring_rows:
+            # A full ring's row ``write`` holds the position that has just
+            # left the window (under a full ring, write == length: no row).
+            valid = valid & (pos != write)
+        elif ring:
+            # A window of fewer keys than the ring has rows: row ``pos``
+            # holds the position ``ago`` steps back.
+            ago = jax.lax.rem(write - pos + (ring_rows - 1), ring_rows) + 1
+            valid = valid & (ago < ring)
         s = jnp.where(valid, s, _NEG_INF)
         m_prev = m_ref[...]                                  # [Hp, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -195,19 +224,120 @@ def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
         corr = jnp.exp(m_ref[...] - m_fin)
         l_fin = l_ref[...] * corr + p_new
         acc = (acc_ref[...] * corr + p_new * vn) / l_fin
-        o_ref[0] = jnp.sum(jnp.where(own, acc, 0.0), axis=0,
-                           keepdims=True).astype(o_ref.dtype)
+        if q_group:
+            # Row i's output stands at its key head's lanes.
+            key_head = jax.lax.broadcasted_iota(
+                jnp.int32, (hp, head_dim), 0) // q_group
+            o_ref[0] = sum(
+                jnp.where(key_head == j,
+                          acc[:, j * head_dim:(j + 1) * head_dim], 0.0)
+                for j in range(hd // head_dim)).astype(o_ref.dtype)
+        else:
+            o_ref[0] = jnp.sum(jnp.where(own, acc, 0.0), axis=0,
+                               keepdims=True).astype(o_ref.dtype)
         # The one write into the arena: the new row, inside its row group.
         for copy in group_copies(read=True):
             copy.wait()
         ins = jax.lax.broadcasted_iota(
-            jnp.int32, kbuf.shape, 0) == length - g0
+            jnp.int32, kbuf.shape, 0) == write - g0
         kbuf[...] = jnp.where(ins, kn_c, kbuf[...])
         vbuf[...] = jnp.where(ins, vn_c, vbuf[...])
         for copy in group_copies(read=False):
             copy.start()
         for copy in group_copies(read=False):
             copy.wait()
+
+
+def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
+                    layer, block_s, interpret, layer_index, ring: int):
+    """``decode_wave_attention`` and, with ``ring``,
+    ``window_wave_attention``: one kernel, static switches."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, _, s, hd = k_arena.shape
+    bsz, h, d = q.shape
+    # Grouped-query rows: a row holds fewer key heads than q has heads.
+    q_group = 0 if h * d == hd else h * d // hd
+    if h * d != hd and (hd % d or h % (hd // d)):
+        raise ValueError(f"arena rows hold {hd} features, q has {h} x {d}")
+    if block_s is None:
+        block_s = pick_block_s(s)
+    if s % block_s:
+        raise ValueError(f"block_s ({block_s}) must divide max_seq_len "
+                         f"({s})")
+    if ring > s:
+        raise ValueError(f"a window of {ring} keys in a ring of {s} rows")
+    hp = -(-h // 8) * 8                  # heads padded to whole sublanes
+    group = math.gcd(s, row_group(k_arena.dtype))
+    dynamic = layer is None
+    if ring:
+        # Context length n: position n goes to row n mod S, and the live
+        # rows are the min(n, S) the slot holds.
+        prefetch = (rows, jnp.minimum(lens, s), jax.lax.rem(lens, s))
+    else:
+        prefetch = (rows, lens)
+    if dynamic:
+        prefetch += (jnp.asarray(layer_index, jnp.int32).reshape(1),)
+
+    def arena_map(b, ik, rows, lens, *more):
+        # Blocks beyond the last valid position repeat that block's index:
+        # the pipeline does not fetch an unchanged block again.
+        last = jnp.maximum(lens[b] - 1, 0) // block_s
+        return (more[-1][0] if dynamic else layer, rows[b],
+                jnp.minimum(ik, last), 0)
+
+    def lane_map(b, ik, rows, lens, *more):
+        return (b, 0, 0)
+
+    block = pl.BlockSpec((None, None, block_s, hd), arena_map)
+    vec = pl.BlockSpec((1, 1, hd), lane_map)
+    # Grouped-query rows: a lane's q and o are its heads' rows, [Hp, D].
+    q_vec = pl.BlockSpec((1, hp, d), lane_map) if q_group else vec
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(bsz, s // block_s),
+        in_specs=[block, block, q_vec, vec, vec],  # k, v arena; q, kn, vn
+        out_specs=[in_hbm, in_hbm, q_vec],         # k, v arena; o
+        scratch_shapes=[
+            pltpu.VMEM((hp, 1), jnp.float32),      # running max
+            pltpu.VMEM((hp, 1), jnp.float32),      # running denominator
+            pltpu.VMEM((hp, hd), jnp.float32),     # weighted accumulator
+            pltpu.VMEM((group, hd), k_arena.dtype),    # K row group
+            pltpu.VMEM((group, hd), v_arena.dtype),    # V row group
+            pltpu.SemaphoreType.DMA((4,)),
+        ],
+    )
+    kernel = functools.partial(_decode_kernel, layer=layer, block_s=block_s,
+                               head_dim=d, sm_scale=1.0 / np.sqrt(d),
+                               ring=ring, ring_rows=s, q_group=q_group)
+    block_bytes = block_s * hd * k_arena.dtype.itemsize
+    if q_group:
+        q_in = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
+    else:
+        q_in = q.reshape(bsz, 1, hd)
+    k_out, v_out, o = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(k_arena.shape, k_arena.dtype),
+            jax.ShapeDtypeStruct(v_arena.shape, v_arena.dtype),
+            jax.ShapeDtypeStruct(q_in.shape, q.dtype),
+        ],
+        # Operand indices count the scalar-prefetch args: rows=0, lens=1
+        # (the written rows, the layer index), then k_arena, v_arena.
+        input_output_aliases={len(prefetch): 0, len(prefetch) + 1: 1},
+        # K and V blocks double-buffered, plus the matmuls' operand copies.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(100 << 20, 10 * block_bytes + (16 << 20))),
+        interpret=interpret,
+        # The window layers' calls are told from the global ones by name
+        # in a trace.
+        **({"name": "window_wave_attention"} if ring else {}),
+    )(*prefetch, k_arena, v_arena, q_in,
+      k_new.reshape(bsz, 1, hd), v_new.reshape(bsz, 1, hd))
+    return k_out, v_out, o[:, :h] if q_group else o.reshape(bsz, h, d)
 
 
 @functools.partial(jax.jit, static_argnames=("layer", "block_s",
@@ -225,94 +355,75 @@ def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
     and ``o: [B, H, D]`` the attention read over rows ``0 .. lens[b]``
     inclusive.  ``layer`` is static; a decoder that scans over its layers
     passes ``layer=None`` and the traced index as ``layer_index``.
+
+    **Grouped-query rows.**  Where a row holds fewer key heads than q has
+    heads (``[L, R, S, Hkv*D]``, k_new/v_new ``[B, Hkv, D]``, ``H`` a multiple
+    of ``Hkv``), query head i reads key head ``i // (H / Hkv)``: the
+    block-diagonal query is ``[Hp, Hkv*D]`` with head i's features on that key
+    head's lanes, so the MXU pays ``Hkv`` times the useful products, not
+    ``H``, and a row is read once for all the heads of its group.
     """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    return _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens,
+                           layer=layer, block_s=block_s, interpret=interpret,
+                           layer_index=layer_index, ring=0)
 
-    _, _, s, hd = k_arena.shape
-    bsz, h, d = q.shape
-    if h * d != hd:
-        raise ValueError(f"arena rows hold {hd} features, q has {h} x {d}")
-    if block_s is None:
-        block_s = pick_block_s(s)
-    if s % block_s:
-        raise ValueError(f"block_s ({block_s}) must divide max_seq_len "
-                         f"({s})")
-    hp = -(-h // 8) * 8                  # heads padded to whole sublanes
-    group = math.gcd(s, row_group(k_arena.dtype))
-    dynamic = layer is None
-    prefetch = (rows, lens) + (
-        (jnp.asarray(layer_index, jnp.int32).reshape(1),) if dynamic else ())
 
-    def arena_map(b, ik, rows, lens, *li):
-        # Blocks beyond the last valid position repeat that block's index:
-        # the pipeline does not fetch an unchanged block again.
-        last = jnp.maximum(lens[b] - 1, 0) // block_s
-        return (li[0][0] if dynamic else layer, rows[b],
-                jnp.minimum(ik, last), 0)
-
-    def lane_map(b, ik, rows, lens, *li):
-        return (b, 0, 0)
-
-    block = pl.BlockSpec((None, None, block_s, hd), arena_map)
-    vec = pl.BlockSpec((1, 1, hd), lane_map)
-    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(bsz, s // block_s),
-        in_specs=[block, block, vec, vec, vec],    # k, v arena; q, kn, vn
-        out_specs=[in_hbm, in_hbm, vec],           # k, v arena; o
-        scratch_shapes=[
-            pltpu.VMEM((hp, 1), jnp.float32),      # running max
-            pltpu.VMEM((hp, 1), jnp.float32),      # running denominator
-            pltpu.VMEM((hp, hd), jnp.float32),     # weighted accumulator
-            pltpu.VMEM((group, hd), k_arena.dtype),    # K row group
-            pltpu.VMEM((group, hd), v_arena.dtype),    # V row group
-            pltpu.SemaphoreType.DMA((4,)),
-        ],
-    )
-    kernel = functools.partial(_decode_kernel, layer=layer, block_s=block_s,
-                               head_dim=d, sm_scale=1.0 / np.sqrt(d))
-    block_bytes = block_s * hd * k_arena.dtype.itemsize
-    k_out, v_out, o = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_arena.shape, k_arena.dtype),
-            jax.ShapeDtypeStruct(v_arena.shape, v_arena.dtype),
-            jax.ShapeDtypeStruct((bsz, 1, hd), q.dtype),
-        ],
-        # Operand indices count the scalar-prefetch args: rows=0, lens=1
-        # (and the layer index), then k_arena, v_arena.
-        input_output_aliases={len(prefetch): 0, len(prefetch) + 1: 1},
-        # K and V blocks double-buffered, plus the matmuls' operand copies.
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=min(100 << 20, 10 * block_bytes + (16 << 20))),
-        interpret=interpret,
-    )(*prefetch, k_arena, v_arena, q.reshape(bsz, 1, hd),
-      k_new.reshape(bsz, 1, hd), v_new.reshape(bsz, 1, hd))
-    return k_out, v_out, o.reshape(bsz, h, d)
+@functools.partial(jax.jit, static_argnames=("layer", "block_s",
+                                             "interpret", "window"))
+def window_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
+                          layer: int | None, block_s: int | None = None,
+                          interpret: bool = False, layer_index=None,
+                          window: int | None = None):
+    """``decode_wave_attention`` over a **ring**: a slot's ``S`` rows hold its
+    last ``S`` positions, position n at row ``n mod S`` (a sliding-window
+    layer's cache).  ``lens`` are context lengths: lane b's new K/V go to row
+    ``lens[b] mod S`` and ``o`` is the attention over the new token and the
+    ``min(lens[b], S)`` live rows **but the one it overwrites**, whose old
+    content is the position that has just left the window (the kernel folds
+    the new token in from registers and never reads back its own write).
+    The order of a ring's rows does not matter to a softmax: what a position
+    is has to be in its key already (rotated before it is written).
+    ``window`` < ``S``: a step sees that many keys, the new one among them
+    (a window that is no multiple of what fills a ring; rows that hold
+    older positions are masked, not skipped).  The same kernel under another
+    name, so that a trace tells the two apart."""
+    return _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens,
+                           layer=layer, block_s=block_s, interpret=interpret,
+                           layer_index=layer_index,
+                           ring=k_arena.shape[2] if window is None
+                           else window)
 
 
 def reference_decode_attention(k_arena, v_arena, q, k_new, v_new, rows,
-                               lens, *, layer: int):
+                               lens, *, layer: int, ring: bool = False,
+                               window: int | None = None):
     """XLA oracle with the reference path's exact semantics (scatter the
     new K/V, gather the rows, dense masked softmax over ``pos <= len``) —
     the parity target for the kernel, kept next to it like
     ``reference_attention`` is for flash.  Same arena layout, same
     signature (``layer`` may be traced here); the scores are float32 over
-    the values the arena holds."""
+    the values the arena holds.  Grouped-query rows (fewer key heads than
+    q has heads) are repeated; with ``ring`` the new row goes to ``lens mod
+    S`` (``window_wave_attention``: once the ring is full every row is
+    live, the written one included; with ``window`` < ``S`` the rows that
+    hold the ``window`` newest positions)."""
     bsz, h, d = q.shape
-    s = k_arena.shape[2]
+    s, hd = k_arena.shape[2:]
     dt = k_arena.dtype
-    k_arena = k_arena.at[layer, rows, lens].set(
-        k_new.reshape(bsz, h * d).astype(dt))
-    v_arena = v_arena.at[layer, rows, lens].set(
-        v_new.reshape(bsz, h * d).astype(dt))
-    ck = k_arena[layer, rows].reshape(bsz, s, h, d).astype(jnp.float32)
-    cv = v_arena[layer, rows].reshape(bsz, s, h, d).astype(jnp.float32)
+    at = lens % s if ring else lens
+    k_arena = k_arena.at[layer, rows, at].set(
+        k_new.reshape(bsz, hd).astype(dt))
+    v_arena = v_arena.at[layer, rows, at].set(
+        v_new.reshape(bsz, hd).astype(dt))
+    ck = k_arena[layer, rows].reshape(bsz, s, hd // d, d).astype(jnp.float32)
+    cv = v_arena[layer, rows].reshape(bsz, s, hd // d, d).astype(jnp.float32)
+    if h * d != hd:
+        ck, cv = (jnp.repeat(c, h * d // hd, axis=2) for c in (ck, cv))
     scores = jnp.einsum("bhd,bshd->bhs", q, ck) / np.sqrt(d)
     mask = jnp.arange(s)[None, :] <= lens[:, None]
+    if window is not None:
+        # Row r holds the position ``(at - r) mod S`` steps back.
+        mask = mask & ((at[:, None] - jnp.arange(s)[None, :]) % s < window)
     scores = jnp.where(mask[:, None, :], scores, _NEG_INF)
     o = jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(scores), cv)
     return k_arena, v_arena, o

@@ -62,7 +62,7 @@ def head_tile(n_heads: int, head_dim: int) -> int:
 
 def _fa_kernel(*refs, block_q: int, block_k: int, sub_q: int,
                grid_qk: tuple, causal: bool, sm_scale: float, prefix: int,
-               head_dim: int, has_bias: bool):
+               head_dim: int, has_bias: bool, window: int | None = None):
     """One (batch, lane tile, q-block, k-block) grid step.
 
     A block is what one DMA brings; the arithmetic takes its queries
@@ -116,6 +116,10 @@ def _fa_kernel(*refs, block_q: int, block_k: int, sub_q: int,
             # The first ``prefix`` keys stand before every query (a cache's
             # summaries); the causal rule holds among the rest.
             visible = q_pos >= k_pos
+            if window is not None:
+                # The band: a query sees its last ``window`` keys, itself
+                # among them (positions count the prefix's keys too).
+                visible = visible & (q_pos - k_pos < window)
         out = None
         for j in range(heads):
             # Head j's query keeps its own lanes: over the tile's lanes
@@ -178,12 +182,19 @@ def _fa_kernel(*refs, block_q: int, block_k: int, sub_q: int,
             # last query (in whole lane tiles) and no others.
             width = min(block_k,
                         -(-(q_lo + sub_q - k_lo) // _LANES) * _LANES)
-            piece(a, width, k_lo + width - 1 > q_lo)
+            piece(a, width, k_lo + width - 1 > q_lo or (
+                window is not None and q_lo + sub_q - 1 - k_lo >= window))
             continue
         # A block past the piece's last query is skipped (its index map
         # fetched nothing new); one wholly before its first needs no mask.
         needed = k_lo <= q_lo + sub_q - 1
         masked = k_lo + block_k - 1 > q_lo
+        if window is not None:
+            # So is a block wholly before the band of the piece's first
+            # query; one that holds a key before its last query's band is
+            # masked.
+            needed = needed & (k_lo + block_k - 1 > q_lo - window)
+            masked = masked | (q_lo + sub_q - 1 - k_lo >= window)
         pl.when(needed & ~masked)(functools.partial(piece, a, block_k, False))
         pl.when(needed & masked)(functools.partial(piece, a, block_k, True))
 
@@ -200,12 +211,14 @@ def _fa_kernel(*refs, block_q: int, block_k: int, sub_q: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "block_q", "block_k", "interpret", "prefix", "n_heads",
-    "sub_q", "sm_scale"))
+    "sub_q", "sm_scale", "window", "n_kv_heads"))
 def flash_attention(q, k, v, bias=None, *, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False, prefix: int = 0,
                     n_heads: int | None = None, sub_q: int | None = None,
-                    sm_scale: float | None = None):
+                    sm_scale: float | None = None,
+                    window: int | None = None,
+                    n_kv_heads: int | None = None):
     """Memory-efficient attention.  q: ``[B, S, H*D]`` with ``n_heads=H``
     (as ``h @ wq`` leaves it), or ``[B, S, H, D]``; k/v likewise with ``S_k =
     prefix + S`` positions; bias: additive [B, S_k] key mask (0 = attend,
@@ -225,6 +238,16 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
     its cache's chunk summaries and, causally, to its own window
     (models/evabyte.py).
 
+    **A band** (``causal`` with ``window``; a sliding-window layer): a query
+    sees its last ``window`` keys, itself among them, the ``prefix`` keys
+    counted as the positions before the queries (a piece attending to its
+    ring's rows and to itself: models/smallthinker.py).  A key block the band
+    masks whole, before it or behind it, is neither fetched nor computed.
+    **Grouped-query heads** (``n_kv_heads`` < ``n_heads``; k and v ``[B, S_k,
+    Hkv*D]``): query head i reads key head ``i // (H / Hkv)``; a head has to
+    fill whole 128-lane tiles (it is then its own tile of either operand,
+    and the key's tile is picked by the index map).
+
     ``block_q``/``block_k`` are what one DMA brings (capped at the sequence
     lengths); ``sub_q`` cuts a block's queries into pieces for the
     arithmetic (default: where one block holds the whole causal problem,
@@ -241,6 +264,20 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
         raise ValueError("[B, S, H*D] operands need n_heads")
     b, s, hd = q.shape
     hd_v = v.shape[-1]
+    q_group = 1
+    if n_kv_heads is not None and n_kv_heads != n_heads:
+        d = hd // n_heads
+        if (window is not None and not causal) or n_heads % n_kv_heads or (
+                d % _LANES or k.shape[-1] != n_kv_heads * d
+                or hd_v != n_kv_heads * d):
+            raise ValueError(
+                f"grouped-query heads: {n_heads} x {d} queries over "
+                f"{n_kv_heads} key heads need heads of whole {_LANES}-lane "
+                f"tiles, k {k.shape} and v {v.shape} of the same width")
+        q_group = n_heads // n_kv_heads
+        shape, hd_v = q.shape, hd
+    if window is not None and not causal:
+        raise ValueError("a window is a band under the causal rule")
     if hd % n_heads or hd_v % n_heads:
         raise ValueError(f"{hd} (values {hd_v}) features do not hold "
                          f"{n_heads} heads")
@@ -274,19 +311,26 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
             return ki
         # The last key block a query block sees; later ones repeat it, which
         # the pipeline takes as unchanged and does not fetch.
-        return jnp.minimum(ki, (prefix + (qi + 1) * block_q - 1) // block_k)
+        last = (prefix + (qi + 1) * block_q - 1) // block_k
+        if window is None:
+            return jnp.minimum(ki, last)
+        # And the first: the block of the first key in the band of the
+        # query block's first query.
+        first = jnp.maximum(prefix + qi * block_q - window + 1, 0) // block_k
+        return jnp.clip(ki, first, last)
 
-    def spec(rows, lanes, row_of):
+    def spec(rows, lanes, row_of, heads_a_tile=1):
         return pl.BlockSpec((1, rows, lanes), lambda bi, gi, qi, ki: (
-            bi, row_of(qi, ki), gi))
+            bi, row_of(qi, ki),
+            gi if heads_a_tile == 1 else gi // heads_a_tile))
 
     def q_block(qi, ki):
         return qi
 
     q_spec, o_spec = spec(block_q, tile, q_block), spec(block_q, tile_v,
                                                         q_block)
-    kv_spec, v_spec = spec(block_k, tile, k_block), spec(block_k, tile_v,
-                                                         k_block)
+    kv_spec, v_spec = (spec(block_k, tile, k_block, q_group),
+                       spec(block_k, tile_v, k_block, q_group))
     operands, in_specs = [q, k, v], [q_spec, kv_spec, v_spec]
     if bias is not None:
         # [B, 1, S_k]: the unit middle dim makes the (1, 1, block_k) bias
@@ -300,7 +344,8 @@ def flash_attention(q, k, v, bias=None, *, causal: bool = False,
         _fa_kernel, block_q=block_q, block_k=block_k, sub_q=sub_q,
         grid_qk=grid_qk, causal=causal,
         sm_scale=1.0 / np.sqrt(d) if sm_scale is None else sm_scale,
-        prefix=prefix, head_dim=d, has_bias=bias is not None)
+        prefix=prefix, head_dim=d, has_bias=bias is not None,
+        **({} if window is None else {"window": window}))
     heads = tile // d
     out = pl.pallas_call(
         kernel,

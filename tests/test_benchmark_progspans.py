@@ -364,7 +364,11 @@ class TestCacheReaders:
             manifest = json.load(f)
         by = {m["name"]: m for m in manifest["per_layer"]}
         for name in CACHE_METRICS:
-            assert by[name]["workloads"] == [CELL]
+            # (The decode kernel's share is read on the second cell that
+            # runs the kernel as well: its global layers' calls, PR 43.)
+            others = (["smallthinker_21b.mixed"]
+                      if name == "decode_attn_roofline.itl" else [])
+            assert by[name]["workloads"] == [CELL] + others
             assert by[name]["moves"] == "itl_mean_ms"
         for name in ("arena_live_share.itl", "kv_live_share.itl"):
             assert CELL not in by[name]["workloads"]   # slots x positions

@@ -147,6 +147,7 @@ from client_tpu.ops.decode_kernel import (  # noqa: E402
     decode_wave_attention,
     pick_block_s,
     reference_decode_attention,
+    window_wave_attention,
 )
 from client_tpu.parallel.kv_shard import (  # noqa: E402
     arena_row_layout,
@@ -706,3 +707,184 @@ class TestShardedKvArena:
     def test_kv_mesh_rejects_oversubscription(self):
         with pytest.raises(ValueError, match="device"):
             kv_mesh(1024)
+
+
+# -- grouped-query rows, a ring, a band (PR 43) ---------------------------------
+
+def _grouped_case(s=32, h=6, hkv=2, d=16, bsz=4, dtype=jnp.float32, seed=3):
+    """An arena of grouped-query rows (``hkv`` key heads a row, ``h`` query
+    heads) and one wave; lane 3 is a padded lane on the dummy row."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    k_arena = jax.random.normal(ks[0], (2, 5, s, hkv * d)).astype(dtype)
+    v_arena = jax.random.normal(ks[1], (2, 5, s, hkv * d)).astype(dtype)
+    q = jax.random.normal(ks[2], (bsz, h, d))
+    kn = jax.random.normal(ks[3], (bsz, hkv, d))
+    vn = jax.random.normal(ks[4], (bsz, hkv, d))
+    rows = jnp.asarray([0, 2, 1, 4], jnp.int32)[:bsz]
+    return k_arena, v_arena, q, kn, vn, rows
+
+
+def _ring_by_hand(k_arena, v_arena, q, kn, vn, row, n, layer, window):
+    """One lane by positions, nothing shared with the oracle's masks: the
+    ring's rows put back in order of position, the last ``window - 1`` of
+    them and the new token under one softmax."""
+    s = k_arena.shape[2]
+    d = q.shape[-1]
+    group = q.shape[0] * d // k_arena.shape[3]
+    first = max(0, n - s)
+    keys = [np.asarray(k_arena[layer, row, p % s], np.float32)
+            for p in range(first, n)] + [np.asarray(kn, np.float32).ravel()]
+    vals = [np.asarray(v_arena[layer, row, p % s], np.float32)
+            for p in range(first, n)] + [np.asarray(vn, np.float32).ravel()]
+    keys = np.stack(keys)[-window:].reshape(-1, kn.shape[0], d)
+    vals = np.stack(vals)[-window:].reshape(-1, kn.shape[0], d)
+    out = []
+    for i in range(q.shape[0]):
+        sc = keys[:, i // group] @ np.asarray(q[i]) / np.sqrt(d)
+        p = np.exp(sc - sc.max())
+        out.append((p / p.sum()) @ vals[:, i // group])
+    return np.stack(out)
+
+
+class TestGroupedQueryRowsAndRing:
+    """``decode_wave_attention`` with fewer key heads a row than q has heads,
+    and ``window_wave_attention`` (the same kernel over a ring), interpreted,
+    against ``reference_decode_attention`` and against a lane worked out by
+    positions."""
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, 2e-2)])
+    @pytest.mark.parametrize("block_s", [8, 32])
+    def test_grouped_query_rows_match_the_oracle(self, block_s, dtype, tol):
+        k_a, v_a, q, kn, vn, rows = _grouped_case(dtype=dtype)
+        lens = jnp.asarray([7, 31, 16, 0], jnp.int32)
+        fk, fv, fo = decode_wave_attention(
+            k_a, v_a, q, kn, vn, rows, lens, layer=1, block_s=block_s,
+            interpret=True)
+        rk, rv, ro = reference_decode_attention(
+            k_a, v_a, q, kn, vn, rows, lens, layer=1)
+        assert float(jnp.max(jnp.abs(fo[:3] - ro[:3]))) < tol
+        np.testing.assert_array_equal(np.asarray(fk), np.asarray(rk))
+        np.testing.assert_array_equal(np.asarray(fv), np.asarray(rv))
+
+    # Lengths under, at and over the ring (32 rows): the first overwrite is
+    # at 32, the ring has wrapped twice at 77.
+    @pytest.mark.parametrize("length", [0, 5, 31, 32, 33, 40, 63, 64, 77])
+    @pytest.mark.parametrize("window", [None, 31, 27])
+    def test_ring_at_every_length(self, length, window):
+        k_a, v_a, q, kn, vn, _ = _grouped_case(bsz=1)
+        rows = jnp.asarray([2], jnp.int32)
+        lens = jnp.asarray([length], jnp.int32)
+        fk, fv, fo = window_wave_attention(
+            k_a, v_a, q, kn, vn, rows, lens, layer=0, block_s=8,
+            interpret=True, window=window)
+        rk, rv, ro = reference_decode_attention(
+            k_a, v_a, q, kn, vn, rows, lens, layer=0, ring=True,
+            window=window)
+        assert float(jnp.max(jnp.abs(fo - ro))) < 2e-5
+        np.testing.assert_array_equal(np.asarray(fk), np.asarray(rk))
+        np.testing.assert_array_equal(np.asarray(fv), np.asarray(rv))
+        # The written row is ``length mod 32`` and no other.
+        changed = np.nonzero((np.asarray(fk[0, 2]) != np.asarray(
+            k_a[0, 2])).any(-1))[0]
+        assert changed.tolist() == [length % 32]
+        want = _ring_by_hand(k_a, v_a, q[0], kn[0], vn[0], 2, length, 0,
+                             32 if window is None else window)
+        assert float(np.abs(np.asarray(fo[0]) - want).max()) < 2e-5
+
+    def test_a_wave_of_lanes_over_the_ring(self):
+        k_a, v_a, q, kn, vn, rows = _grouped_case()
+        lens = jnp.asarray([5, 32, 70, 0], jnp.int32)
+        _, _, fo = window_wave_attention(
+            k_a, v_a, q, kn, vn, rows, lens, layer=1, block_s=16,
+            interpret=True)
+        _, _, ro = reference_decode_attention(
+            k_a, v_a, q, kn, vn, rows, lens, layer=1, ring=True)
+        assert float(jnp.max(jnp.abs(fo[:3] - ro[:3]))) < 2e-5
+        assert bool(jnp.all(jnp.isfinite(fo)))
+
+    def test_rows_that_hold_no_whole_group_are_refused(self):
+        k_a, v_a, q, kn, vn, rows = _grouped_case(h=5)
+        with pytest.raises(ValueError, match="features"):
+            decode_wave_attention(k_a, v_a, q, kn, vn, rows,
+                                  jnp.zeros(4, jnp.int32), layer=0,
+                                  interpret=True)
+        k_a, v_a, q, kn, vn, rows = _grouped_case()
+        with pytest.raises(ValueError, match="window"):
+            window_wave_attention(k_a, v_a, q, kn, vn, rows,
+                                  jnp.zeros(4, jnp.int32), layer=0,
+                                  interpret=True, window=33)
+
+
+def _band_attention(q, k, v, prefix, window):
+    """``reference_attention`` under a band mask, key heads repeated: q ``[B,
+    S, H, D]``, k and v ``[B, prefix + S, Hkv, D]``."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    ago = (prefix + jnp.arange(q.shape[1])[:, None]) - jnp.arange(
+        k.shape[1])[None, :]
+    seen = ago >= 0
+    if window is not None:
+        seen = seen & (ago < window)
+    bias = jnp.where(seen, 0.0, -1e30)
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    return jnp.einsum("bhqk,bkhd->bqhd",
+                      jax.nn.softmax(sc + bias[None, None], -1), v)
+
+
+class TestFlashBandAndGroupedQueryHeads:
+    """``flash_attention(window=W)`` and ``n_kv_heads``, interpreted, against
+    a dense band mask; block sizes that leave whole key blocks before the
+    band (neither fetched nor computed) and behind the causal edge."""
+
+    # (queries, prefix, window, heads, key heads, head size, block_q,
+    # block_k, sub_q)
+    @pytest.mark.parametrize("case", [
+        (64, 0, 24, 4, 4, 16, 16, 8, None),     # blocks before the band
+        (64, 0, 24, 4, 2, 128, 16, 8, None),    # and grouped-query heads
+        (64, 0, 8, 2, 2, 16, 8, 8, None),       # a band of one block
+        (32, 64, 40, 4, 2, 128, 32, 32, None),  # a prefix cut by the band
+        (32, 64, 64, 2, 1, 128, 32, 32, None),  # the piece's full ring
+        (16, 48, 16, 2, 2, 128, 16, 16, None),  # the prefix masked whole
+        (32, 0, 8, 2, 1, 128, 32, 32, 8),       # one block, cut in pieces
+        (64, 0, None, 4, 2, 128, 16, 16, None),  # grouped, no band
+        (64, 0, 1000, 4, 1, 128, 16, 32, None),  # a band wider than all
+    ])
+    def test_matches_a_dense_band_mask(self, case):
+        s, prefix, window, h, hkv, d, bq, bk, sub = case
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = jax.random.normal(ks[0], (1, s, h, d))
+        k = jax.random.normal(ks[1], (1, prefix + s, hkv, d))
+        v = jax.random.normal(ks[2], (1, prefix + s, hkv, d))
+        got = flash_attention(
+            q.reshape(1, s, h * d), k.reshape(1, -1, hkv * d),
+            v.reshape(1, -1, hkv * d), causal=True, prefix=prefix,
+            window=window, n_heads=h, n_kv_heads=hkv, block_q=bq,
+            block_k=bk, sub_q=sub, interpret=True).reshape(q.shape)
+        want = _band_attention(q, k, v, prefix, window)
+        assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+
+    def test_the_band_skips_the_blocks_it_masks_whole(self):
+        """Junk (NaN) in a key block wholly before the band never reaches
+        the output: the block is not computed."""
+        s, d, window = 64, 16, 16
+        ks = jax.random.split(jax.random.PRNGKey(6), 3)
+        q, k, v = (jax.random.normal(key, (1, s, 2, d)) for key in ks)
+        # Queries 48.. see keys 33..: key block 0 (0..15) is before every
+        # band of the last query block, and behind no earlier block's edge.
+        got = flash_attention(q, k, v, causal=True, window=window,
+                              block_q=16, block_k=16, interpret=True)
+        junk_v = v.at[:, :16].set(jnp.nan)
+        again = flash_attention(q, k, junk_v, causal=True, window=window,
+                                block_q=16, block_k=16, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got[:, 32:]),
+                                      np.asarray(again[:, 32:]))
+
+    def test_what_cannot_be_served_is_refused(self):
+        q = jnp.zeros((1, 16, 4 * 16))
+        kv = jnp.zeros((1, 16, 2 * 16))
+        with pytest.raises(ValueError, match="grouped-query"):
+            flash_attention(q, kv, kv, causal=True, n_heads=4, n_kv_heads=2,
+                            interpret=True)
+        with pytest.raises(ValueError, match="band"):
+            flash_attention(q, q, q, n_heads=4, window=8, interpret=True)

@@ -19,7 +19,14 @@ kernel walks a lane's live blocks: its body and its grid changed, the
 programs around it did not); ``evabyte``'s four at the tree PR 42 left (its
 layers walked in a loop over leaves of their own, ``wq``, ``wk`` and ``wv``
 served ``[out, in]``, a piece fenced: the other three families' eight, which
-walk the same loop of ``models/decoder.py`` now, did not move).  A PR that
+walk the same loop of ``models/decoder.py`` now, did not move);
+``smallthinker``'s two (models/smallthinker.py: the decode kernel with
+grouped-query rows, over whole-context leaves and over a ring; prefill by
+pieces through the flash kernel's band) at the tree PR 43 left, which gave
+``models/decoder.py`` the ``"ring"`` layer kind and both kernels their static
+switches, and moved the expert layer from models/latent_moe.py to
+models/experts.py (activation and score function its parameters): the other
+four families' sixteen hashes did not move.  A PR that
 means to change one of these programs records the new hash here and says so;
 one that does not has a guard.
 
@@ -44,6 +51,8 @@ RECORDED = {
     ("kimi", "prefill"): ("93c6ccfe91d5a268", "5dd18e6e271299fb"),
     ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),
     ("pangu", "prefill"): ("2947c2e62c0d448d", "fa56a8062981bfe9"),
+    ("smallthinker", "decode"): ("4f8b9c5fcb45a8ec", "f82898d490500dda"),
+    ("smallthinker", "prefill"): ("312ebd7279ae9bc1", "ffec3fbbb48d7b3e"),
 }
 
 
@@ -72,6 +81,16 @@ def _backend(family):
                                  linear_attn=lin, max_seq_len=32, piece=16,
                                  attention_impl="flash",
                                  attn_impl="fused")
+    if family == "smallthinker":
+        from client_tpu.models.smallthinker import SmallThinkerBackend
+
+        # Heads of whole 128-lane tiles, as the flash pieces need them; two
+        # periods, a ring of two pieces.
+        return SmallThinkerBackend(seed=3, n_layers=8, n_heads=4,
+                                   n_kv_heads=2, head_dim=128,
+                                   max_seq_len=64, window=32, piece=16,
+                                   attention_impl="flash", attn_impl="fused",
+                                   record=True)
     from client_tpu.models.generate import TinyGptBackend
 
     return TinyGptBackend(attention_impl="flash", attn_impl="fused")
@@ -81,7 +100,7 @@ def _program(family, which):
     """(function, static and donated argument numbers, abstract
     arguments)."""
     be = _backend(family)
-    if family in ("pangu", "kimi"):     # weights made when asked for
+    if family in ("pangu", "kimi", "smallthinker"):   # made when asked for
         params = jax.tree_util.tree_map(
             lambda w: jax.ShapeDtypeStruct(w.shape, jnp.dtype(w.dtype)),
             be._init_params())

@@ -541,3 +541,116 @@ def test_byte_decoder_reads_every_weight_where_it_lies(one_chip, monkeypatch,
     # 256 MB where the ``scan``'s body took 91, of a leaf's 1.14 GB.
     assert memory.temp_size_in_bytes < {"decode": leaf // 64,
                                         "prefill": leaf // 2}[which], memory
+
+
+# -- window and global layers in one decoder at its published widths (PR 43) ----
+
+SMALLTHINKER = dict(n_layers=8, d_model=2560, n_heads=28, n_kv_heads=4,
+                    head_dim=128, d_expert=768, n_experts=64, top_k=6,
+                    window=4096, vocab=151936, max_seq_len=16384, piece=512,
+                    max_streams=48, attention_impl="flash", record=True)
+
+
+def _smallthinker_program(one_chip, monkeypatch, which):
+    """``smallthinker_21b``'s ``jit_decode`` (a full wave of 48) or
+    ``jit_prefill`` (one piece of 512) for one v5e chip from shapes alone
+    (13.7 GB of weights and cache that nothing allocates).  Returns
+    (optimised text, arena shapes, memory)."""
+    from client_tpu.engine import backend_init
+    from client_tpu.models.smallthinker import SmallThinkerBackend
+    from client_tpu.observability import spans
+
+    monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
+    place = _on(one_chip)
+    backend = SmallThinkerBackend(name="s", **SMALLTHINKER)
+    params = jax.tree_util.tree_map(
+        lambda leaf: place(leaf.shape, jnp.dtype(leaf.dtype)),
+        backend._init_params())
+    arena = jax.tree_util.tree_map(
+        lambda a: place(a.shape, a.dtype),
+        jax.eval_shape(lambda: backend.init_arena(backend.max_streams)))
+    if which == "decode":
+        lanes_i, lanes_f = place((48,), jnp.int32), place((48,), jnp.float32)
+        step = jax.jit(
+            spans.named_step(backend.decode_fn(), spans.STEP_DECODE),
+            donate_argnums=backend.donate_argnums,
+            static_argnums=backend.decode_static_argnums)
+        lowered = step.lower(params, arena, lanes_i, lanes_i, lanes_i,
+                             lanes_f, lanes_i, lanes_f, False)
+    else:
+        lane_i, lane_f = place((1,), jnp.int32), place((1,), jnp.float32)
+        step = jax.jit(
+            spans.named_step(backend.prefill_fn(), spans.STEP_PREFILL),
+            donate_argnums=backend.donate_argnums,
+            static_argnums=backend.prefill_static_argnums)
+        lowered = step.lower(params, arena, lane_i,
+                             place((1, 512), jnp.int32), lane_i, lane_i,
+                             lane_f, lane_i, lane_f, False, lane_i)
+    compiled = lowered.compile()
+    return compiled.as_text(), arena, compiled.memory_analysis(), backend
+
+
+def _written_out_again(text, shapes):
+    """Instructions outside every fused computation that write an array of
+    one of ``shapes`` (a regular expression over the dimensions) out again:
+    a ``copy`` or a ``transpose`` of it, or a ``dynamic-slice`` fusion that
+    yields it (``_weights_moved``'s rule)."""
+    fused = set(re.findall(r" fusion\([^\n]*?calls=%([\w.\-]+)", text))
+    pat = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[(?:1,)*(" + shapes
+                     + r")\][^=]*? ([\w\-]+)\(", re.M)
+    moved = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", comp)
+        if head and head.group(1) not in fused:
+            moved += [(name, op, shape)
+                      for name, shape, op in pat.findall(comp)
+                      if op in ("copy", "transpose")
+                      or (op == "fusion" and "dynamic-slice" in name)]
+    return moved
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_window_and_global_decoder_compiles_at_published_widths(
+        one_chip, monkeypatch, which):
+    """At the cell's widths (2560, 28 query heads over 4 key heads of 128, 64
+    experts of 768, 151936 ids; 48 + 1 slots of 6 x 4096 ring rows and 2 x
+    16384 rows): the wave's two attention kinds are one kernel under two
+    names, six ring calls and two whole-context calls, grouped-query rows of
+    512 lanes; a piece holds a flash call for every count of rows before it
+    (9 a window layer, 32 a global one); sixteen grouped matmuls either way.
+    Neither program writes a weight or a cache leaf out again: every matrix
+    is read by its product where it lies, and the donated arena's four
+    leaves are updated in place."""
+    text, arena, memory, backend = _smallthinker_program(
+        one_chip, monkeypatch, which)
+    calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
+    assert calls.count("grouped_matmul") == 16
+    if which == "decode":
+        assert calls.count("window_wave_attention") == 6
+        assert calls.count("decode_wave_attention") == 2
+        # 48 tokens, a record row a lane and the wave's three counts.
+        assert f"s32[{48 + 48 * backend.stream_record + 3}]" in text
+    else:
+        assert calls.count("flash_attention") == 6 * 9 + 2 * 32
+    weights = (r"2560,3584|3584,2560|2560,512|64,2560,1536|64,768,2560"
+               r"|151936,2560|2560,151936")
+    leaves = r"[26],49,(?:4096|16384),512"
+    moved = _written_out_again(text, weights + "|" + leaves)
+    if which == "prefill":
+        # (The 2560 rows before a slot's sixth piece, sliced out of a leaf
+        # for the flash call, have W_k's shape: one a layer and leaf.)
+        rows = [m for m in moved if m[1:] == ("fusion", "2560,512")]
+        assert len(rows) == 16, moved
+        moved = [m for m in moved if m not in rows]
+    assert not moved, moved
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    cache = sum(math.prod(arena[k].shape) * 2 for k in ("kg", "vg", "kw",
+                                                         "vw"))
+    assert memory.alias_size_in_bytes >= cache
+    # A wave's temporaries are its activations (14 MB); a piece's the rows
+    # before it (a global layer's 15872 x 512 of K and of V), its scores'
+    # operands and the sorted layout's 7104 rows (57 MB): far under one ring
+    # leaf's 1.2 GB or one layer's 0.8 GB of matrices.
+    assert memory.temp_size_in_bytes < 0.2e9, memory
+    assert 13.6e9 < memory.argument_size_in_bytes < 13.8e9
