@@ -20,45 +20,55 @@ leaves had ``[12, 64]`` minor dimensions; the compiler stored them with S
 minor-most and re-laid out the whole leaf around every scatter and gather:
 PERF.md section 6, PR 25.)
 
-One Pallas grid per layer, ``(B, S // block_s)`` with the key-block index
-innermost; the lane's ``(row, len)`` pair arrives by scalar prefetch and the
-BlockSpec index maps pick each lane's blocks straight out of the arena, so no
-``[B, S, ...]`` gather exists anywhere.  What a wave does to the arena:
+One Pallas call per layer whose grid is the lanes, in order; the lane's
+``(row, len)`` pair arrives by scalar prefetch and the arena stays in HBM
+(``memory_space=ANY``, aliased to the output), so no ``[B, S, ...]`` gather
+exists anywhere.  What a wave does to the arena:
 
-- **Reads each live row once.**  Blocks that hold no valid position are not
-  fetched: their index map repeats the last valid block, which the pipeline
-  sees as unchanged and does not copy again, and their compute is skipped.
-- **Writes one row per lane and leaf.**  The arena operand is aliased to the
-  output (``input_output_aliases``) and the output stays in HBM
-  (``memory_space=ANY``): the kernel copies the aligned row group that
-  holds row ``len`` into VMEM, inserts the new row with an iota mask and
-  copies the group back (HBM is tiled by 8 rows of float32 and 16 of
-  bfloat16, so one row alone is not a DMA the chip accepts).  Nothing else
-  of the arena is written.
+- **Copies a lane's live rows and nothing else.**  A lane's program loops
+  over its ``ceil(len / block_s)`` live blocks, a trip count read from the
+  prefetched ``lens``; the wave's live blocks, lane after lane, are one
+  stream of ``make_async_copy`` through a ring of three VMEM places (a K and
+  a V buffer each), two blocks ahead of the products and across a lane's
+  end.  No step exists without a block, a lane of length 0 (a padded lane on
+  the dummy row) copies none, and a lane's last block is copied only as far
+  as its live rows go, in quanta of whole row groups
+  (``wave_block_rows``, ``tail_quantum``, ``copied_rows``).  The products
+  run over the whole block under the ``pos < len`` mask; the rows of a place
+  that no copy filled hold an earlier block's (the value places are zeroed
+  once a call, because ``0 x NaN`` is NaN and a scratch buffer's first
+  content is anything).  (On the v5e, against the ``(B, S // 512)`` grid of
+  BlockSpec blocks it had, which copied every last block whole and stepped
+  over the places behind it: a call at ``evabyte_6b5.longdoc``'s lengths
+  0.891 -> 0.737 ms, at ``smallthinker_21b.mixed``'s 1.202 -> 0.972 over
+  whole contexts and 0.521 -> 0.489 over rings, at ``gpt2_small.chat``'s
+  0.302 -> 0.260; the copies run at 680-745 GB/s, so the time is the copied
+  bytes': PERF.md section 6, PR 44.)
+- **Writes one row per lane and leaf.**  The kernel copies the aligned row
+  group that holds the written row into VMEM (the read starts at the lane's
+  head), inserts the new row with an iota mask and copies the group back
+  (HBM is tiled by 8 rows of float32 and 16 of bfloat16, so one row alone is
+  not a DMA the chip accepts); two group buffers take turns, so the
+  write-back is waited for a lane on.  Nothing else of the arena is written.
 - **Scores on the MXU.**  The query becomes a block-diagonal ``[Hp, H*D]``
   matrix (row h holds head h's 64 features at their lanes, zero elsewhere),
   so ``scores[h, s] = Qbd @ K_blk^T`` and ``acc[h, :] += p @ V_blk`` are two
   plain matmuls over lane-dense blocks (the block-diagonal form costs H
   times the useful FLOPs: nothing beside a float32 arena's six passes at
-  H = 12, and 0.6 ms of a 4.6 ms memory-bound wave at H = 32 in bfloat16's
-  single pass); the output row is read off the block diagonal of ``acc``.
+  H = 12, and hidden behind the copies at H = 32 in bfloat16's single
+  pass); the output row is read off the block diagonal of ``acc``.
 
 Attention follows ``_fa_kernel``'s online-softmax carry
 (ops/flash_attention.py) with a *strict* ``pos < len`` mask over the old
 arena content; the new token's term (position ``len``, whose value is the
-k/v being written) is folded in at the finalize step from registers, so the
+k/v being written) is folded in at the lane's end from registers, so the
 kernel never reads back its own write and the order of the group's
-write-back against the pipeline's block reads cannot matter.
+write-back against the block copies cannot matter.
 
 The **latent** kernel (``latent_wave_attention``: one row a position, shared
-by every head) has the same contract and another walk.  Its grid is the lanes
-alone and the arena stays in HBM: a lane's program loops over the lane's
-``ceil(len / block_s)`` live blocks, a trip count read from the prefetched
-``lens``, and the wave's live blocks, lane after lane, are one stream of
-``make_async_copy`` through a ring of three VMEM buffers, two copies ahead of
-the products.  No step exists without a block, a lane of length 0 copies
-none, and a lane's first block is on its way before the lane before it has
-finished.  (On the v5e, against the ``(B, S // block_s)`` grid it had: a live
+by every head) has the same contract and the same walk over one leaf, its
+blocks copied whole (it had the walk first, PR 40; one walker for the two
+bodies is ROADMAP C13's).  (On the v5e, against the ``(B, S // block_s)`` grid it had: a live
 block 1.36 -> 1.20 us, a lane without one 2.81 -> 1.29 us where a slot has
 eight blocks, a call at ``pangu_ultra_moe.reasoning``'s lengths 0.83 -> 0.59
 ms and at ``kimi_linear.longgen``'s 2.87 -> 2.16 ms: PERF.md section 6,
@@ -74,6 +84,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -91,10 +102,9 @@ def row_group(dtype) -> int:
 
 def pick_block_s(seq_len: int, cap: int = 512) -> int:
     """Largest multiple-of-8 divisor of ``seq_len`` up to ``cap`` (falls
-    back to ``seq_len`` itself when no aligned divisor exists).  A block is
-    ``block_s x H*D`` floats of K and of V, double-buffered: 512 x 768 is
-    1.5 MB each, long enough a DMA to run near HBM bandwidth and short
-    enough that a row's tail beyond ``len`` is mostly skipped."""
+    back to ``seq_len`` itself when no aligned divisor exists): the rows of
+    a block of the latent walk and of the flash kernels' tiles; the wave
+    kernel's come from ``wave_block_rows``."""
     best = None
     for cand in range(8, min(cap, seq_len) + 1, 8):
         if seq_len % cand == 0:
@@ -102,15 +112,92 @@ def pick_block_s(seq_len: int, cap: int = 512) -> int:
     return best if best is not None else seq_len
 
 
-def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
+# The wave kernel's walk, chosen on the v5e at the three served shapes and
+# their cells' lengths (PERF.md section 6, PR 44; a call in ms, block rows /
+# quantum rows).  Under one stream of copies the block hardly matters and
+# the quantum does, because the time is the copied bytes': bfloat16 rows of
+# 8 KB (16 lanes, 2060 live rows each) 0.737 at 128 / 16, 0.739 at 256 / 16,
+# 0.741 at 512 / 16, 0.745 at 512 / 32, 0.760 at 512 / 128, 0.796 at
+# 1024 / 256; float32 rows of 3 KB (33 lanes of 860, 15 without a row) 0.260
+# at 256 / 16, 0.261 at 512 / 16, 0.269 at 256 / 8 (a copy of 24 KB is too
+# short) and 0.282-0.288 at 128; bfloat16 rows of 1 KB (46 lanes of 7660)
+# 0.969 at 512 / 16, 0.970 at 512 / 32 and 1024 / 32, 0.972 at 1024 / 64,
+# 0.984 at 256 / 64 and 1024 / 256, 1.001 at 2048 / 512.  So: a block of at
+# most 1 MiB a leaf (1-4 MB of VMEM a place; 128, 256 and 1024 rows there),
+# its last copied in sixteenths or, where those are no whole row groups, in
+# row groups.  Two places read 1-2% slower than three, four the same.
+_WAVE_BLOCK_BYTES = 1 << 20
+_WAVE_RING = 3
+_TAIL_PARTS = 16
+
+
+def wave_block_rows(slot_rows: int, width: int, dtype) -> int:
+    """Rows of a block of the wave kernel's walk over leaves ``[..,
+    slot_rows, width]``: as many as ``_WAVE_BLOCK_BYTES`` of one leaf
+    hold."""
+    return pick_block_s(
+        slot_rows, max(8, _WAVE_BLOCK_BYTES // (width * jnp.dtype(
+            dtype).itemsize)))
+
+
+def tail_quantum(block_s: int, group: int) -> int:
+    """Rows of the pieces a lane's last block is copied in: the block's
+    ``_TAIL_PARTS``-th, or the smallest part above it that is whole row
+    groups (a DMA's slice starts and ends on one), else the whole block."""
+    for parts in range(_TAIL_PARTS, 1, -1):
+        if block_s % (parts * group) == 0:
+            return block_s // parts
+    return block_s
+
+
+def _live_blocks(n, block_s: int, quantum: int):
+    """(blocks, quanta of the last one) that a lane of ``n`` live rows makes
+    the kernel copy: a Python int, or a traced count, which goes through
+    ``lax``'s primitives (it is never negative, and ``//`` and even ``+`` on
+    a tracer go through a traced function each: PERF.md section 6, PR 40 and
+    44).  Without a live row there is no block, and the second count means
+    nothing."""
+    add, sub, mul, div = (
+        (operator.add, operator.sub, operator.mul, operator.floordiv)
+        if isinstance(n, int)
+        else (jax.lax.add, jax.lax.sub, jax.lax.mul, jax.lax.div))
+    blocks = div(add(n, block_s - 1), block_s)
+    tail = sub(n, mul(sub(blocks, 1), block_s))
+    return blocks, div(add(tail, quantum - 1), quantum)
+
+
+def copied_rows(n: int, slot_rows: int, width: int, dtype,
+                block_s: int | None = None) -> int:
+    """Rows of a leaf ``[.., slot_rows, width]`` that a lane of ``n`` live
+    rows makes the wave kernel copy out of the arena: every block before its
+    last whole, the last as far as its live rows' quanta go (a ring's ``n``
+    is ``min(context, slot_rows)``).  Times the row's bytes and two leaves
+    this is what a call moves a lane, but for the row group it reads and
+    writes (``row_group(dtype)`` rows a leaf each way); the kernel's own trip
+    counts come from the same ``_live_blocks``."""
+    if block_s is None:
+        block_s = wave_block_rows(slot_rows, width, dtype)
+    quantum = tail_quantum(block_s, math.gcd(slot_rows, row_group(dtype)))
+    blocks, last = _live_blocks(int(min(n, slot_rows)), block_s, quantum)
+    return (blocks - 1) * block_s + last * quantum if blocks else 0
+
+
+def _decode_kernel(rows_ref, lens_ref, *refs, block_s: int, quantum: int,
                    head_dim: int, sm_scale: float, ring: int = 0,
                    ring_rows: int = 0, q_group: int = 0):
-    """One (lane, key-block) grid step; key blocks iterate innermost so the
-    scratch carries the online-softmax state across one lane's row.
-    ``layer`` is a Python int, or ``None`` when the layer index arrives as
-    the last scalar-prefetch operand (a decoder that scans over its layers).
+    """One lane of the wave: a loop over the lane's live blocks,
+    ``ceil(len / block_s)`` of them, the online-softmax state in scratch.
+    The wave's live blocks, lane after lane, are one stream of copies
+    through a ring of VMEM places (a K and a V buffer each): ``walk`` (SMEM)
+    holds the lane and block of the next one to fetch and how many were
+    fetched and used, so the copies run ahead of the products across a
+    lane's end and over lanes without a live row (``_latent_kernel``'s walk,
+    with two leaves).  A lane's last block is copied only as far as its live
+    rows' quanta go.
+    The layer index is the last scalar-prefetch operand, so one body serves
+    every layer of a wave program.
     ``ring`` > 0: the row a lane writes arrives apart from its count of live
-    rows (a third scalar-prefetch operand), and a step sees ``ring`` keys, the
+    rows (a scalar-prefetch operand before it), and a step sees ``ring`` keys, the
     new one among them: old content that many positions back or more is
     masked (with ``ring`` the slot's ``ring_rows``, the written row alone).
     ``q_group`` > 0: grouped-query rows, ``q_group`` query heads to a key
@@ -121,68 +208,159 @@ def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
     write_ref = None
     if ring:
         write_ref, refs = refs[0], refs[1:]
-    if layer is None:
-        layer = refs[0][0]
-        refs = refs[1:]
+    layer, refs = refs[0][0], refs[1:]
     (k_ref, v_ref, q_ref, kn_ref, vn_ref,               # inputs
      ko_ref, vo_ref, o_ref,                             # outputs
-     m_ref, l_ref, acc_ref, kbuf, vbuf, sem) = refs     # scratch
+     m_ref, l_ref, acc_ref, qbd_ref, kblocks, vblocks,  # scratch
+     kbuf, vbuf, sem, walk) = refs
+    del k_ref, v_ref                     # aliased: the outputs are the arena
     b = pl.program_id(0)
-    ik = pl.program_id(1)
-    nk = pl.num_programs(1)
+    lanes = pl.num_programs(0)
     row = rows_ref[b]
     length = lens_ref[b]                 # valid prefix length (strict)
+    n_blocks, last_quanta = _live_blocks(length, block_s, quantum)
     # The row the new token goes to: behind the live rows, or in a ring the
     # row of the position that leaves the window.
     write = length if write_ref is None else write_ref[b]
     hp, hd = acc_ref.shape
-    group = kbuf.shape[0]
-    g0 = pl.multiple_of((write // group) * group, group)
+    places = kblocks.shape[0]
+    parts = block_s // quantum
+    group = kbuf.shape[1]
+    # Scalar arithmetic on traced counts goes through ``lax``'s primitives
+    # here and below: an operator on a tracer is a traced function, a third
+    # of a millisecond each where the program is built, and this body is
+    # built once a layer and wave bucket (PERF.md section 6, PR 44).
+    add, mul, select = jax.lax.add, jax.lax.mul, jax.lax.select
+    g0 = pl.multiple_of(mul(jax.lax.div(write, group), group), group)
+    mine = jax.lax.rem(b, 2)
+    cache_dtype = ko_ref.dtype
+    # The MXU takes the arena's dtype: float32 blocks in full precision,
+    # bfloat16 blocks (products exact in the float32 accumulator) in one pass.
+    highest = (jax.lax.Precision.HIGHEST if cache_dtype == jnp.float32
+               else None)
 
-    def group_copies(read: bool):
-        """The aligned row group around row ``write``, K and V: arena ->
-        VMEM (``read``) or back."""
-        out = []
-        for i, (arena, buf) in enumerate(((ko_ref, kbuf), (vo_ref, vbuf))):
-            hbm = arena.at[layer, row, pl.ds(g0, group)]
-            out.append(pltpu.make_async_copy(hbm, buf, sem.at[i]) if read
-                       else pltpu.make_async_copy(buf, hbm, sem.at[2 + i]))
-        return out
+    # The aligned row group around row ``write``, K and V: arena -> VMEM
+    # and back.  Built once: every start and wait below is one of these.
+    group_reads, group_writes = [], []
+    for j, (arena, buf) in enumerate(((ko_ref, kbuf), (vo_ref, vbuf))):
+        hbm = arena.at[layer, row, pl.ds(g0, group)]
+        group_reads.append(
+            pltpu.make_async_copy(hbm, buf.at[mine], sem.at[j]))
+        group_writes.append(
+            pltpu.make_async_copy(buf.at[mine], hbm, sem.at[2 + j]))
 
-    @pl.when(ik == 0)
-    def _init():
-        for copy in group_copies(read=True):
-            copy.start()
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def block_copies(go, n, blocks, quanta, i, slot=None):
+        """``go`` (start or wait for) the copies of a lane's block ``i``,
+        the wave's ``n``-th live one, K and V into place ``n mod places``:
+        one copy a leaf of a whole block, and of the lane's last block (of
+        ``blocks``) a copy a quantum as far as its live rows go
+        (``quanta``).  ``slot`` is the lane's; a wait reads a copy's size,
+        not its place, and gives none."""
+        place = jax.lax.rem(n, places)
+        sem0 = add(mul(place, 2), 4)
+        lyr, slt, plc = (0, 0, 0) if slot is None else (layer, slot, place)
+
+        def copies(size, at=0):
+            src = dst = 0
+            if slot is not None:
+                src = pl.multiple_of(add(mul(i, block_s), at), size)
+                dst = at if isinstance(at, int) else pl.multiple_of(at, size)
+            for j, (arena, buf) in enumerate(((ko_ref, kblocks),
+                                              (vo_ref, vblocks))):
+                go(pltpu.make_async_copy(
+                    arena.at[lyr, slt, pl.ds(src, size)],
+                    buf.at[plc, pl.ds(dst, size)], sem.at[add(sem0, j)]))
+
+        whole = jax.lax.bitwise_or(jax.lax.lt(add(i, 1), blocks),
+                                   jax.lax.eq(quanta, parts))
+
+        @pl.when(whole)
+        def _():
+            copies(block_s)
+
+        @pl.when(jax.lax.bitwise_not(whole))
+        def _():
+            jax.lax.fori_loop(
+                0, quanta, lambda j, c: copies(
+                    quantum, 0 if slot is None else mul(j, quantum)), None)
+
+    def fetch(_, carry):
+        """Start the copies of the wave's next live block, if one is left:
+        ``walk`` holds its lane (or one before it without a live row) and
+        block, and how many blocks were fetched."""
+        at = jax.lax.while_loop(
+            lambda j: jax.lax.bitwise_and(
+                jax.lax.lt(j, lanes),
+                jax.lax.eq(lens_ref[jax.lax.min(j, lanes - 1)], 0)),
+            lambda j: add(j, 1), walk[0])
+        walk[0] = at
+
+        @pl.when(jax.lax.lt(at, lanes))
+        def _():
+            i, n = walk[1], walk[2]
+            blocks, quanta = _live_blocks(lens_ref[at], block_s, quantum)
+            block_copies(lambda copy: copy.start(), n, blocks, quanta, i,
+                         rows_ref[at])
+            last = jax.lax.eq(add(i, 1), blocks)
+            walk[0] = select(last, add(at, 1), at)
+            walk[1] = select(last, jnp.zeros_like(i), add(i, 1))
+            walk[2] = add(n, 1)
+        return carry
+
+    @pl.when(b == 0)
+    def _first():
+        # A place's rows behind a last block's quanta keep what an earlier
+        # block left there, and the second product multiplies them by p = 0:
+        # they have to be finite, which a scratch buffer at its first use is
+        # not.  (Keys need nothing: a dead row's score is replaced, not
+        # multiplied.)
+        def zero(i, carry):
+            at = pl.multiple_of(mul(i, quantum), quantum)
+            for place in range(places):
+                vblocks[place, pl.ds(at, quantum)] = jnp.zeros(
+                    (quantum, hd), cache_dtype)
+            return carry
+
+        jax.lax.fori_loop(0, parts, zero, None)
+        for i in range(4):
+            walk[i] = 0
+
+    for copy in group_reads:
+        copy.start()
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Block-diagonal query: row h keeps head h's lanes of the scaled q.
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+    lane_ix = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
     head = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
     if q_group:
         # Query head i reads key head i // q_group: its features stand at
         # that key head's lanes (a padded head's at none).
-        head = head // q_group
-    own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
+        head = jax.lax.div(head, q_group)
+    own = (lane_ix >= head * head_dim) & (lane_ix < (head + 1) * head_dim)
     q_row = q_ref[0] * sm_scale
     if q_group:
         q_row = jnp.concatenate([q_row] * (hd // head_dim), axis=1)
     qbd = jnp.where(own, q_row, 0.0)                         # [Hp, H*D]
-    # The MXU takes the arena's dtype: float32 blocks in full precision,
-    # bfloat16 blocks (products exact in the float32 accumulator) in one pass.
-    cache_dtype = k_ref.dtype
-    highest = (jax.lax.Precision.HIGHEST if cache_dtype == jnp.float32
-               else None)
+    qbd_ref[...] = qbd.astype(cache_dtype)
+    used = walk[3]
 
-    @pl.when(ik * block_s < length)
-    def _block():
+    def block(i, carry):
+        n = add(used, i)
+        # One copy ahead into the place of the block used before this; the
+        # wave's first block starts the ring's.
+        jax.lax.fori_loop(
+            0, select(jax.lax.eq(n, 0), jnp.full_like(n, places),
+                      jnp.ones_like(n)), fetch, None)
+        block_copies(lambda copy: copy.wait(), n, n_blocks, last_quanta, i)
+        place = jax.lax.rem(n, places)
         # Scores over the OLD prefix content: strictly pos < length
         # (position `length` is the new token, folded in below).
         s = jax.lax.dot_general(
-            qbd.astype(cache_dtype), k_ref[...], (((1,), (1,)), ((), ())),
+            qbd_ref[...], kblocks[place], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=highest)
-        pos = ik * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        pos = i * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = pos < length                                 # [Hp, block_s]
         if ring and ring == ring_rows:
             # A full ring's row ``write`` holds the position that has just
@@ -196,62 +374,87 @@ def _decode_kernel(rows_ref, lens_ref, *refs, layer, block_s: int,
         s = jnp.where(valid, s, _NEG_INF)
         m_prev = m_ref[...]                                  # [Hp, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # Block 0 always holds position 0 < length, so m_new is a real
-        # score whenever this body runs.
         p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(cache_dtype), v_ref[...],
+            p.astype(cache_dtype), vblocks[place],
             preferred_element_type=jnp.float32,
             precision=highest)                               # [Hp, H*D]
+        return carry
 
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        # The new row as the arena will hold it (rounded to the leaf's
-        # dtype), so this wave and every later one read the same values.
-        kn_c, vn_c = kn_ref[0].astype(cache_dtype), vn_ref[0].astype(
-            cache_dtype)                                     # [1, H*D]
-        kn, vn = kn_c.astype(jnp.float32), vn_c.astype(jnp.float32)
-        # Fold in the new token (position `length`, value kn/vn) from
-        # registers — it is always valid, so the denominator is > 0 and
-        # lanes with an empty prefix (length == 0, i.e. padded lanes on the
-        # dummy row) come out as exactly vn instead of NaN.
-        s_new = jnp.sum(qbd * kn, axis=1, keepdims=True)     # [Hp, 1]
-        m_fin = jnp.maximum(m_ref[...], s_new)
-        p_new = jnp.exp(s_new - m_fin)
-        corr = jnp.exp(m_ref[...] - m_fin)
-        l_fin = l_ref[...] * corr + p_new
-        acc = (acc_ref[...] * corr + p_new * vn) / l_fin
-        if q_group:
-            # Row i's output stands at its key head's lanes.
-            key_head = jax.lax.broadcasted_iota(
-                jnp.int32, (hp, head_dim), 0) // q_group
-            o_ref[0] = sum(
-                jnp.where(key_head == j,
-                          acc[:, j * head_dim:(j + 1) * head_dim], 0.0)
-                for j in range(hd // head_dim)).astype(o_ref.dtype)
-        else:
-            o_ref[0] = jnp.sum(jnp.where(own, acc, 0.0), axis=0,
-                               keepdims=True).astype(o_ref.dtype)
-        # The one write into the arena: the new row, inside its row group.
-        for copy in group_copies(read=True):
+    jax.lax.fori_loop(0, n_blocks, block, None)
+    walk[3] = add(used, n_blocks)
+
+    # The new row as the arena will hold it (rounded to the leaf's dtype),
+    # so this wave and every later one read the same values.
+    kn_c, vn_c = kn_ref[0].astype(cache_dtype), vn_ref[0].astype(
+        cache_dtype)                                         # [1, H*D]
+    kn, vn = kn_c.astype(jnp.float32), vn_c.astype(jnp.float32)
+    # Fold in the new token (position `length`, value kn/vn) from registers:
+    # it is always valid, so the denominator is > 0 and lanes with an empty
+    # prefix (length == 0, i.e. padded lanes on the dummy row) come out as
+    # exactly vn instead of NaN.
+    s_new = jnp.sum(qbd * kn, axis=1, keepdims=True)         # [Hp, 1]
+    m_fin = jnp.maximum(m_ref[...], s_new)
+    p_new = jnp.exp(s_new - m_fin)
+    corr = jnp.exp(m_ref[...] - m_fin)
+    l_fin = l_ref[...] * corr + p_new
+    acc = (acc_ref[...] * corr + p_new * vn) / l_fin
+    if q_group:
+        # Row i's output stands at its key head's lanes.
+        key_head = jax.lax.div(jax.lax.broadcasted_iota(
+            jnp.int32, (hp, head_dim), 0), q_group)
+        o_ref[0] = sum(
+            jnp.where(key_head == j,
+                      acc[:, j * head_dim:(j + 1) * head_dim], 0.0)
+            for j in range(hd // head_dim)).astype(o_ref.dtype)
+    else:
+        o_ref[0] = jnp.sum(jnp.where(own, acc, 0.0), axis=0,
+                           keepdims=True).astype(o_ref.dtype)
+    # The one write into the arena: the new row, inside its row group, whose
+    # read has been under way since the lane's head.  Two group buffers take
+    # turns, so the write-back is waited for a lane on, behind that lane's
+    # blocks.  Nothing reads what is in flight: a wave's lanes hold slots of
+    # their own, but for the padded lanes on the dummy slot, which copy no
+    # block and whose groups differ in the one row each of them replaces.
+    for copy in group_reads:
+        copy.wait()
+    ins = jax.lax.broadcasted_iota(
+        jnp.int32, kbuf.shape[1:], 0) == write - g0
+    kbuf[mine] = jnp.where(ins, kn_c, kbuf[mine])
+    vbuf[mine] = jnp.where(ins, vn_c, vbuf[mine])
+
+    @pl.when(b > 0)
+    def _():     # the lane before's (a wait reads the size, not the place)
+        for copy in group_writes:
             copy.wait()
-        ins = jax.lax.broadcasted_iota(
-            jnp.int32, kbuf.shape, 0) == write - g0
-        kbuf[...] = jnp.where(ins, kn_c, kbuf[...])
-        vbuf[...] = jnp.where(ins, vn_c, vbuf[...])
-        for copy in group_copies(read=False):
-            copy.start()
-        for copy in group_copies(read=False):
+
+    for copy in group_writes:
+        copy.start()
+
+    @pl.when(b == lanes - 1)
+    def _():
+        for copy in group_writes:
             copy.wait()
 
 
-def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
-                    layer, block_s, interpret, layer_index, ring: int):
+def _layer_operand(layer, layer_index):
+    """The layer as the kernel takes it: an operand, static or traced."""
+    return jnp.asarray(layer_index if layer is None else layer, jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("block_s", "interpret", "ring"))
+def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, layer, *,
+                    block_s, interpret, ring: int):
     """``decode_wave_attention`` and, with ``ring``,
-    ``window_wave_attention``: one kernel, static switches."""
+    ``window_wave_attention``: one kernel, static switches.  ``layer`` is an
+    operand (scalar prefetch) whether the caller's is a Python int or traced:
+    a wave program's calls of one shape are then one traced function and one
+    lowered kernel, where a static layer made each call its own (0.1 s a
+    layer and wave bucket to build, 40-84 of them a served model: PERF.md
+    section 6, PR 44)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -262,7 +465,7 @@ def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
     if h * d != hd and (hd % d or h % (hd // d)):
         raise ValueError(f"arena rows hold {hd} features, q has {h} x {d}")
     if block_s is None:
-        block_s = pick_block_s(s)
+        block_s = wave_block_rows(s, hd, k_arena.dtype)
     if s % block_s:
         raise ValueError(f"block_s ({block_s}) must divide max_seq_len "
                          f"({s})")
@@ -270,49 +473,47 @@ def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
         raise ValueError(f"a window of {ring} keys in a ring of {s} rows")
     hp = -(-h // 8) * 8                  # heads padded to whole sublanes
     group = math.gcd(s, row_group(k_arena.dtype))
-    dynamic = layer is None
+    quantum = tail_quantum(block_s, group)
     if ring:
         # Context length n: position n goes to row n mod S, and the live
         # rows are the min(n, S) the slot holds.
         prefetch = (rows, jnp.minimum(lens, s), jax.lax.rem(lens, s))
     else:
         prefetch = (rows, lens)
-    if dynamic:
-        prefetch += (jnp.asarray(layer_index, jnp.int32).reshape(1),)
+    prefetch += (layer.reshape(1),)
 
-    def arena_map(b, ik, rows, lens, *more):
-        # Blocks beyond the last valid position repeat that block's index:
-        # the pipeline does not fetch an unchanged block again.
-        last = jnp.maximum(lens[b] - 1, 0) // block_s
-        return (more[-1][0] if dynamic else layer, rows[b],
-                jnp.minimum(ik, last), 0)
-
-    def lane_map(b, ik, rows, lens, *more):
+    def lane_map(b, *prefetched):
         return (b, 0, 0)
 
-    block = pl.BlockSpec((None, None, block_s, hd), arena_map)
     vec = pl.BlockSpec((1, 1, hd), lane_map)
     # Grouped-query rows: a lane's q and o are its heads' rows, [Hp, D].
     q_vec = pl.BlockSpec((1, hp, d), lane_map) if q_group else vec
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    dtype = k_arena.dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(bsz, s // block_s),
-        in_specs=[block, block, q_vec, vec, vec],  # k, v arena; q, kn, vn
-        out_specs=[in_hbm, in_hbm, q_vec],         # k, v arena; o
+        grid=(bsz,),
+        in_specs=[in_hbm, in_hbm, q_vec, vec, vec],    # k, v arena; q, kn, vn
+        out_specs=[in_hbm, in_hbm, q_vec],             # k, v arena; o
         scratch_shapes=[
             pltpu.VMEM((hp, 1), jnp.float32),      # running max
             pltpu.VMEM((hp, 1), jnp.float32),      # running denominator
             pltpu.VMEM((hp, hd), jnp.float32),     # weighted accumulator
-            pltpu.VMEM((group, hd), k_arena.dtype),    # K row group
-            pltpu.VMEM((group, hd), v_arena.dtype),    # V row group
-            pltpu.SemaphoreType.DMA((4,)),
+            pltpu.VMEM((hp, hd), dtype),           # block-diagonal query
+            pltpu.VMEM((_WAVE_RING, block_s, hd), dtype),      # K blocks
+            pltpu.VMEM((_WAVE_RING, block_s, hd), dtype),      # V blocks
+            pltpu.VMEM((2, group, hd), dtype),     # K row group, by turns
+            pltpu.VMEM((2, group, hd), dtype),     # V row group
+            # The groups' reads (K, V) and write-backs, then K and V a place.
+            pltpu.SemaphoreType.DMA((4 + 2 * _WAVE_RING,)),
+            pltpu.SMEM((4,), jnp.int32),           # the walk
         ],
     )
-    kernel = functools.partial(_decode_kernel, layer=layer, block_s=block_s,
-                               head_dim=d, sm_scale=1.0 / np.sqrt(d),
-                               ring=ring, ring_rows=s, q_group=q_group)
-    block_bytes = block_s * hd * k_arena.dtype.itemsize
+    kernel = functools.partial(_decode_kernel, block_s=block_s,
+                               quantum=quantum, head_dim=d,
+                               sm_scale=1.0 / np.sqrt(d), ring=ring,
+                               ring_rows=s, q_group=q_group)
+    block_bytes = block_s * hd * dtype.itemsize
     if q_group:
         q_in = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
     else:
@@ -321,27 +522,27 @@ def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(k_arena.shape, k_arena.dtype),
-            jax.ShapeDtypeStruct(v_arena.shape, v_arena.dtype),
+            jax.ShapeDtypeStruct(k_arena.shape, dtype),
+            jax.ShapeDtypeStruct(v_arena.shape, dtype),
             jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         ],
         # Operand indices count the scalar-prefetch args: rows=0, lens=1
         # (the written rows, the layer index), then k_arena, v_arena.
         input_output_aliases={len(prefetch): 0, len(prefetch) + 1: 1},
-        # K and V blocks double-buffered, plus the matmuls' operand copies.
+        # The ring's K and V places, plus the matmuls' operand copies.
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=min(100 << 20, 10 * block_bytes + (16 << 20))),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(100 << 20, (2 * _WAVE_RING + 4)
+                                 * block_bytes + (16 << 20))),
         interpret=interpret,
-        # The window layers' calls are told from the global ones by name
-        # in a trace.
-        **({"name": "window_wave_attention"} if ring else {}),
+        # The names the calls have in a trace, where the window layers'
+        # are told from the global ones by them.
+        name="window_wave_attention" if ring else "decode_wave_attention",
     )(*prefetch, k_arena, v_arena, q_in,
       k_new.reshape(bsz, 1, hd), v_new.reshape(bsz, 1, hd))
     return k_out, v_out, o[:, :h] if q_group else o.reshape(bsz, h, d)
 
 
-@functools.partial(jax.jit, static_argnames=("layer", "block_s",
-                                             "interpret"))
 def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
                           layer: int | None, block_s: int | None = None,
                           interpret: bool = False, layer_index=None):
@@ -353,8 +554,8 @@ def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
     the new K/V written at ``(layer, rows[b], lens[b])`` in place (the arena
     operands are aliased to the outputs, so a donated arena is never copied)
     and ``o: [B, H, D]`` the attention read over rows ``0 .. lens[b]``
-    inclusive.  ``layer`` is static; a decoder that scans over its layers
-    passes ``layer=None`` and the traced index as ``layer_index``.
+    inclusive.  ``layer`` is a Python int or, with ``layer=None``, the
+    traced ``layer_index``; the kernel takes either as an operand.
 
     **Grouped-query rows.**  Where a row holds fewer key heads than q has
     heads (``[L, R, S, Hkv*D]``, k_new/v_new ``[B, Hkv, D]``, ``H`` a multiple
@@ -364,12 +565,10 @@ def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
     ``H``, and a row is read once for all the heads of its group.
     """
     return _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens,
-                           layer=layer, block_s=block_s, interpret=interpret,
-                           layer_index=layer_index, ring=0)
+                           _layer_operand(layer, layer_index),
+                           block_s=block_s, interpret=interpret, ring=0)
 
 
-@functools.partial(jax.jit, static_argnames=("layer", "block_s",
-                                             "interpret", "window"))
 def window_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
                           layer: int | None, block_s: int | None = None,
                           interpret: bool = False, layer_index=None,
@@ -388,8 +587,8 @@ def window_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
     older positions are masked, not skipped).  The same kernel under another
     name, so that a trace tells the two apart."""
     return _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens,
-                           layer=layer, block_s=block_s, interpret=interpret,
-                           layer_index=layer_index,
+                           _layer_operand(layer, layer_index),
+                           block_s=block_s, interpret=interpret,
                            ring=k_arena.shape[2] if window is None
                            else window)
 

@@ -144,9 +144,13 @@ def test_long_context_bert_through_engine():
 
 
 from client_tpu.ops.decode_kernel import (  # noqa: E402
+    copied_rows,
     decode_wave_attention,
     pick_block_s,
     reference_decode_attention,
+    row_group,
+    tail_quantum,
+    wave_block_rows,
     window_wave_attention,
 )
 from client_tpu.parallel.kv_shard import (  # noqa: E402
@@ -195,15 +199,20 @@ class TestFusedDecodeKernel:
                 np.testing.assert_array_equal(
                     np.asarray(fv[layer, r, ln]), np.asarray(rv[layer, r, ln]))
 
-    @pytest.mark.parametrize("length", [0, 1, 7, 8, 15, 31])
-    def test_every_prefix_length(self, length):
-        """Scatter offset and strict mask at block boundaries (8/16) and
-        the edges (empty prefix, full arena row)."""
-        k_a, v_a, q, kn, vn, _, _ = _decode_case(bsz=1)
+    # Slots of 32 rows in blocks of 8 (one quantum a block), and of 64 rows
+    # in blocks of 32 whose last is copied in quanta of 8: lengths 0, 1,
+    # around a quantum (7, 8, 9), around a block (31, 32, 33) and ``S - 1``.
+    @pytest.mark.parametrize("s,block_s,length", [
+        (32, 8, n) for n in (0, 1, 7, 8, 15, 31)] + [
+        (64, 32, n) for n in (0, 1, 7, 8, 9, 31, 32, 33, 63)])
+    def test_every_prefix_length(self, s, block_s, length):
+        """Scatter offset and strict mask at quantum and block boundaries
+        and the edges (empty prefix, full arena row)."""
+        k_a, v_a, q, kn, vn, _, _ = _decode_case(bsz=1, s=s)
         rows = jnp.asarray([1], jnp.int32)
         lens = jnp.asarray([length], jnp.int32)
         fk, fv, fo = decode_wave_attention(
-            k_a, v_a, q, kn, vn, rows, lens, layer=0, block_s=8,
+            k_a, v_a, q, kn, vn, rows, lens, layer=0, block_s=block_s,
             interpret=True)
         rk, rv, ro = reference_decode_attention(
             k_a, v_a, q, kn, vn, rows, lens, layer=0)
@@ -212,6 +221,67 @@ class TestFusedDecodeKernel:
                                       np.asarray(rk[0, 1]))
         np.testing.assert_array_equal(np.asarray(fv[0, 1]),
                                       np.asarray(rv[0, 1]))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("length", [1, 8, 9, 24, 33, 47, 63])
+    def test_copies_cover_copied_rows_and_no_row_more(self, length, dtype):
+        """``copied_rows`` is what the kernel's copies cover: values behind
+        it may be NaN and the output does not see them (they were never in
+        VMEM); a NaN in the last row it counts, where that row is dead,
+        reaches the output through ``p = 0`` times it.  The interpreter
+        hands the kernel scratch buffers full of NaN, as a chip hands it
+        anything: rows of a place no copy filled are part of every case."""
+        k_a, v_a, q, kn, vn, _, _ = _decode_case(bsz=2, s=64, d=64)
+        k_a, v_a = k_a.astype(dtype), v_a.astype(dtype)
+        rows = jnp.asarray([1, 3], jnp.int32)
+        lens = jnp.asarray([length, 5], jnp.int32)
+        group = row_group(dtype)
+        assert tail_quantum(32, group) == group
+        copied = copied_rows(length, 64, 128, dtype, 32)
+        assert copied == -(-length // group) * group
+        _, _, want = reference_decode_attention(
+            k_a, v_a, q, kn, vn, rows, lens, layer=0)
+        # Not the written row's group: that one is copied apart, and back.
+        behind = max(copied, (length // group + 1) * group)
+        poisoned = v_a.at[0, 1, behind:].set(jnp.nan)
+        _, fv, fo = decode_wave_attention(
+            k_a, poisoned, q, kn, vn, rows, lens, layer=0, block_s=32,
+            interpret=True)
+        tol = 2e-5 if dtype == jnp.float32 else 3e-2
+        assert float(jnp.max(jnp.abs(fo - want))) < tol
+        assert bool(jnp.all(jnp.isnan(fv[0, 1, behind:])))
+        if copied > length + 1:
+            poisoned = v_a.at[0, 1, copied - 1].set(jnp.nan)
+            _, _, fo = decode_wave_attention(
+                k_a, poisoned, q, kn, vn, rows, lens, layer=0, block_s=32,
+                interpret=True)
+            assert bool(jnp.any(jnp.isnan(fo[0])))
+            assert bool(jnp.all(jnp.isfinite(fo[1])))
+
+    def test_copied_rows_at_the_served_shapes(self):
+        """A block of at most 1 MiB a leaf, its last in sixteenths or row
+        groups: the three served leaves."""
+        served = {  # slot rows, row width, dtype: block, quantum
+            (4096, 4096, jnp.bfloat16): (128, 16),      # evabyte_6b5
+            (1024, 768, jnp.float32): (256, 16),        # gpt2_small
+            (16384, 512, jnp.bfloat16): (1024, 64),     # smallthinker, global
+            (4096, 512, jnp.bfloat16): (1024, 64),      # its rings
+        }
+        for (s, w, dtype), (block, quantum) in served.items():
+            assert wave_block_rows(s, w, dtype) == block
+            assert tail_quantum(block, row_group(dtype)) == quantum
+            assert copied_rows(0, s, w, dtype) == 0
+            assert copied_rows(1, s, w, dtype) == quantum
+            assert copied_rows(block, s, w, dtype) == block
+            assert copied_rows(block + 1, s, w, dtype) == block + quantum
+            assert copied_rows(s - 1, s, w, dtype) == s
+            # A ring's live rows are the slot's at most.
+            assert copied_rows(3 * s + 5, s, w, dtype) == s
+        assert copied_rows(830, 1024, 768, jnp.float32) == 832
+        assert copied_rows(2061, 4096, 4096, jnp.bfloat16) == 2064
+        # A block that is no whole row groups is copied whole.
+        assert tail_quantum(8, 16) == 8
+        assert copied_rows(3, 32, 32, jnp.bfloat16, 8) == 8
 
     def test_untouched_rows_survive_aliasing(self):
         """input_output_aliases updates in place: rows no lane points at
@@ -293,25 +363,29 @@ class TestDecodeKernelByDtype:
         assert row_group(jnp.bfloat16) == 16
 
     def test_float32_program_is_the_one_it_was(self):
-        """For a float32 arena and a static layer the dtype-generic kernel
-        traces to GPT-2's program: the seven operands it always had (the
-        layer index is an eighth only when it is traced), full-precision
-        products and nothing in bfloat16."""
+        """For a float32 arena the dtype-generic kernel traces to GPT-2's
+        program: full-precision products and nothing in bfloat16; the layer
+        is its eighth operand whether it is a Python int or traced (PR 44:
+        one body a wave program, not one a layer), so two layers are one
+        traced function."""
         k_a, v_a, q, kn, vn, rows, lens = self._case(jnp.float32)
 
-        def call(**kw):
-            jaxpr = jax.make_jaxpr(lambda *a: decode_wave_attention(
-                *a, block_s=16, interpret=False, **kw))(
+        def call(*layers):
+            jaxpr = jax.make_jaxpr(lambda *a: [decode_wave_attention(
+                *a, block_s=16, interpret=False, **kw) for kw in layers])(
                     k_a, v_a, q, kn, vn, rows, lens)
-            (outer,) = jaxpr.jaxpr.eqns
-            (eqn,) = [e for e in outer.params["jaxpr"].jaxpr.eqns
+            outer = [e for e in jaxpr.jaxpr.eqns
+                     if e.primitive.name in ("pjit", "jit")]
+            (eqn,) = [e for e in outer[0].params["jaxpr"].jaxpr.eqns
                       if e.primitive.name == "pallas_call"]
-            return eqn, str(eqn.params["jaxpr"])
+            return outer, eqn, str(eqn.params["jaxpr"])
 
-        eqn, body = call(layer=0)
-        assert len(eqn.invars) == 7
+        outer, eqn, body = call(dict(layer=0), dict(layer=1))
+        assert len(eqn.invars) == 8
         assert "bf16" not in body and "HIGHEST" in body
-        eqn, _ = call(layer=None, layer_index=jnp.int32(0))
+        assert len(outer) == 2
+        assert outer[0].params["jaxpr"] is outer[1].params["jaxpr"]
+        _, eqn, _ = call(dict(layer=None, layer_index=jnp.int32(0)))
         assert len(eqn.invars) == 8
 
 
@@ -589,8 +663,14 @@ class TestDecodeKernelGpt2Geometry:
         np.testing.assert_array_equal(np.asarray(fk), np.asarray(rk))
         np.testing.assert_array_equal(np.asarray(fv), np.asarray(rv))
 
-    def test_padded_lanes_on_the_dummy_row(self):
-        lens = [5, None, 30, None, None]
+    @pytest.mark.parametrize("lens", [
+        [5, None, 30, None, None],      # in the middle and at the end
+        [None, 5, 30],                  # the first lane
+        [None, None, 47, 9, None],      # both ends
+        [12],                           # a wave of one lane
+        [None],                         # and of one padded lane
+    ], ids=str)
+    def test_padded_lanes_on_the_dummy_row(self, lens):
         k_a, v_a, q, kn, vn, rows, lens_a = self._case(lens)
         fk, fv, fo = decode_wave_attention(
             k_a, v_a, q, kn, vn, rows, lens_a, layer=0, block_s=16,
@@ -598,7 +678,8 @@ class TestDecodeKernelGpt2Geometry:
         _, _, ro = reference_decode_attention(
             k_a, v_a, q, kn, vn, rows, lens_a, layer=0)
         live = np.asarray([n is not None for n in lens])
-        assert float(jnp.max(jnp.abs(fo[live] - ro[live]))) < 2e-5
+        if live.any():
+            assert float(jnp.max(jnp.abs(fo[live] - ro[live]))) < 2e-5
         # A padded lane attends to its own token alone: exactly v_new.
         np.testing.assert_allclose(np.asarray(fo[~live]),
                                    np.asarray(vn[~live]), rtol=1e-6)
@@ -606,6 +687,12 @@ class TestDecodeKernelGpt2Geometry:
         dummy = self.ROWS - 1
         np.testing.assert_array_equal(np.asarray(fk[0, dummy, 1:]),
                                       np.asarray(k_a[0, dummy, 1:]))
+        # Every live lane's slot but for its one new row is as it was.
+        for b in np.nonzero(live)[0]:
+            n = lens[b]
+            np.testing.assert_array_equal(
+                np.delete(np.asarray(fv[0, b]), n, 0),
+                np.delete(np.asarray(v_a[0, b]), n, 0))
 
     def test_a_wave_writes_one_position_per_lane_and_nothing_else(self):
         lens = [3, 47, None, 16]
@@ -754,10 +841,13 @@ class TestGroupedQueryRowsAndRing:
 
     @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                            (jnp.bfloat16, 2e-2)])
-    @pytest.mark.parametrize("block_s", [8, 32])
-    def test_grouped_query_rows_match_the_oracle(self, block_s, dtype, tol):
+    @pytest.mark.parametrize("block_s,lens", [
+        (8, [7, 31, 16, 0]), (32, [7, 31, 16, 0]),
+        (16, [15, 16, 17, 0]), (32, [0, 8, 9, 1])])
+    def test_grouped_query_rows_match_the_oracle(self, block_s, lens, dtype,
+                                                 tol):
         k_a, v_a, q, kn, vn, rows = _grouped_case(dtype=dtype)
-        lens = jnp.asarray([7, 31, 16, 0], jnp.int32)
+        lens = jnp.asarray(lens, jnp.int32)
         fk, fv, fo = decode_wave_attention(
             k_a, v_a, q, kn, vn, rows, lens, layer=1, block_s=block_s,
             interpret=True)
@@ -771,12 +861,13 @@ class TestGroupedQueryRowsAndRing:
     # at 32, the ring has wrapped twice at 77.
     @pytest.mark.parametrize("length", [0, 5, 31, 32, 33, 40, 63, 64, 77])
     @pytest.mark.parametrize("window", [None, 31, 27])
-    def test_ring_at_every_length(self, length, window):
+    @pytest.mark.parametrize("block_s", [8, 32])
+    def test_ring_at_every_length(self, length, window, block_s):
         k_a, v_a, q, kn, vn, _ = _grouped_case(bsz=1)
         rows = jnp.asarray([2], jnp.int32)
         lens = jnp.asarray([length], jnp.int32)
         fk, fv, fo = window_wave_attention(
-            k_a, v_a, q, kn, vn, rows, lens, layer=0, block_s=8,
+            k_a, v_a, q, kn, vn, rows, lens, layer=0, block_s=block_s,
             interpret=True, window=window)
         rk, rv, ro = reference_decode_attention(
             k_a, v_a, q, kn, vn, rows, lens, layer=0, ring=True,
@@ -792,15 +883,22 @@ class TestGroupedQueryRowsAndRing:
                              32 if window is None else window)
         assert float(np.abs(np.asarray(fo[0]) - want).max()) < 2e-5
 
-    def test_a_wave_of_lanes_over_the_ring(self):
-        k_a, v_a, q, kn, vn, rows = _grouped_case()
-        lens = jnp.asarray([5, 32, 70, 0], jnp.int32)
+    # Rings empty, part full, full and wrapped in one wave; a wave that
+    # starts and one that ends with lanes without a row.
+    @pytest.mark.parametrize("lens", [[5, 32, 70, 0], [0, 0, 40, 31],
+                                      [33, 64, 0, 0], [0, 9, 0, 100]],
+                             ids=str)
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, 2e-2)])
+    def test_a_wave_of_lanes_over_the_ring(self, lens, dtype, tol):
+        k_a, v_a, q, kn, vn, rows = _grouped_case(dtype=dtype)
+        lens = jnp.asarray(lens, jnp.int32)
         _, _, fo = window_wave_attention(
             k_a, v_a, q, kn, vn, rows, lens, layer=1, block_s=16,
             interpret=True)
         _, _, ro = reference_decode_attention(
             k_a, v_a, q, kn, vn, rows, lens, layer=1, ring=True)
-        assert float(jnp.max(jnp.abs(fo[:3] - ro[:3]))) < 2e-5
+        assert float(jnp.max(jnp.abs(fo - ro))) < tol
         assert bool(jnp.all(jnp.isfinite(fo)))
 
     def test_rows_that_hold_no_whole_group_are_refused(self):

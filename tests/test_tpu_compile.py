@@ -654,3 +654,56 @@ def test_window_and_global_decoder_compiles_at_published_widths(
     # leaf's 1.2 GB or one layer's 0.8 GB of matrices.
     assert memory.temp_size_in_bytes < 0.2e9, memory
     assert 13.6e9 < memory.argument_size_in_bytes < 13.8e9
+
+
+# The three served configurations' cache leaves: slots, rows a slot, lanes a
+# row, dtype; a full wave's lanes, query heads, key heads, the head's width.
+_SERVED_LEAVES = {
+    "evabyte_6b5": ("decode", (8, 17, 4096, 4096), jnp.bfloat16, 16, 32, 32,
+                    128),
+    "gpt2_small": ("decode", (12, 49, 1024, 768), jnp.float32, 48, 12, 12,
+                   64),
+    "smallthinker_21b.global": ("decode", (2, 49, 16384, 512), jnp.bfloat16,
+                                48, 28, 4, 128),
+    "smallthinker_21b.ring": ("window", (6, 49, 4096, 512), jnp.bfloat16, 48,
+                              28, 4, 128),
+}
+
+
+@pytest.mark.parametrize("leaf", sorted(_SERVED_LEAVES))
+def test_wave_kernel_walks_live_blocks_at_the_served_shapes(one_chip, leaf):
+    """The decode-wave kernel alone, at each served leaf's shape and a full
+    wave: it compiles for the v5e (the ring of block places fits the fast
+    memory, every copy's slice lies on row groups), its call keeps the name
+    the benchmark's readers look for in a trace
+    (``decode_attn_roofline.itl``, ``window_attn_roofline.itl``), both leaves
+    are updated where they lie and nothing else of their size exists."""
+    from client_tpu.ops import decode_kernel as dk
+
+    kind, shape, dtype, lanes, h, hkv, d = _SERVED_LEAVES[leaf]
+    fn = (dk.decode_wave_attention if kind == "decode"
+          else dk.window_wave_attention)
+
+    def sd(s, t):
+        return jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda ka, va, q, kn, vn, rows, lens: fn(
+            ka, va, q, kn, vn, rows, lens, layer=1),
+        donate_argnums=(0, 1)).lower(
+            sd(shape, dtype), sd(shape, dtype), sd((lanes, h, d), jnp.float32),
+            sd((lanes, hkv, d), jnp.float32), sd((lanes, hkv, d), jnp.float32),
+            sd((lanes,), jnp.int32), sd((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    (call,) = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
+    assert call == kind + "_wave_attention"
+    # A block of at most 1 MiB a leaf: three places of K and of V.
+    block = dk.wave_block_rows(shape[2], shape[3], dtype)
+    assert block * shape[3] * jnp.dtype(dtype).itemsize <= 1 << 20
+    assert block % dk.tail_quantum(block, dk.row_group(dtype)) == 0
+    memory = compiled.memory_analysis()
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    leaf_bytes = math.prod(shape) * jnp.dtype(dtype).itemsize
+    assert memory.alias_size_in_bytes >= 2 * leaf_bytes
+    assert memory.temp_size_in_bytes < leaf_bytes // 64, memory
