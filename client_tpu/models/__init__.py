@@ -67,6 +67,17 @@ def _smallthinker() -> ModelBackend:
     return SmallThinkerBackend()
 
 
+@register_model("nemotron_h", default=False)
+def _nemotron_h() -> ModelBackend:
+    """The decoder whose state-space, attention and expert layers are each a
+    block of their own (a state and key/value rows in one arena, un-gated
+    squared-ReLU experts), at its tiny preset.  Opt-in, and imported when it
+    is built, as ``pangu_moe``."""
+    from client_tpu.models.nemotron_h import NemotronHBackend
+
+    return NemotronHBackend()
+
+
 def model_names() -> list[str]:
     _import_all()
     return sorted(_REGISTRY)

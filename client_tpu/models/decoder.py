@@ -93,7 +93,8 @@ where ``layer_kinds`` names ``"state"`` layers, ``_advance(lp, x, *state
 leaves, rows, lens, index)`` -> (*state leaves, o): the layer's mixer on the
 slots' states (what a state is and which kernel advances it are the model's;
 it chooses kernel or oracle by ``_use_kernel()``), ``o`` what
-``_after_attention`` reads;
+``_after_attention`` reads; where it names ``"none"`` layers,
+``_feed_forward(lp, x)`` -> x, the whole of such a layer;
 ``_logits(p, x)`` and,
 where they are not ``[B, vocab]``, ``_served(logits)`` picking those tokens
 are sampled from; ``_live_rows(lens)``, the rows of each slot a step at
@@ -169,6 +170,43 @@ def sample_into_slots(arena, rows, logits, seeds, ctx, temps, top_ks, top_ps,
     else:
         tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return {**arena, "tok": arena["tok"].at[rows].set(tokens)}, tokens
+
+
+def slot_tails(leaf, ki, rows):
+    """A wave's lanes' rows of a fixed-size leaf ``[layers of the kind, R,
+    width]`` (a slot's convolution tail): -> (pick ``[B, R]``, the layer's
+    slots ``[R, width]``, the lanes' rows ``[B, width]``).
+
+    The rows leave and enter the leaf through a one-hot product, lanes by
+    slots (exact: a row of it holds one 1, and a bfloat16 value times 1
+    summed in float32 is the value): XLA lowers a gather and a scatter of 256
+    rows of such a leaf to loops of 256 slices, 2.2 ms a layer on the v5e
+    where the product and one pass over the layer's slots take a tenth of
+    that (PERF.md section 6, PR 34)."""
+    import jax
+    import jax.numpy as jnp
+
+    slots = leaf[ki]                                           # [R, width]
+    pick = (rows[:, None] == jnp.arange(slots.shape[0])[None, :]
+            ).astype(leaf.dtype)                               # [B, R]
+    tail = jnp.matmul(pick, slots, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32).astype(leaf.dtype)
+    return pick, slots, tail
+
+
+def put_slot_tails(leaf, ki, pick, slots, ext):
+    """``slot_tails``' way back.  ``ext [B, taps, channels]``: each lane's
+    tail with this step's input behind it; all but the oldest go back into
+    the lane's slot of the layer.  A slot that several lanes name (the junk
+    slot) is left their sum."""
+    import jax
+    import jax.numpy as jnp
+
+    put = jnp.matmul(pick.T, ext[:, 1:].reshape(ext.shape[0], -1),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32).astype(leaf.dtype)
+    slots = jnp.where(pick.any(axis=0)[:, None], put, slots)
+    return jax.lax.dynamic_update_slice(leaf, slots[None], (ki, 0, 0))
 
 
 def logit_bits(logits, tokens, samples: int):
@@ -451,6 +489,9 @@ class DecoderBackend(ModelBackend):
     def _ring_qkv(self, lp, x, pos):
         return self._qkv(lp, x, pos)
 
+    def _feed_forward(self, lp, x):
+        raise NotImplementedError
+
     def _wave_stats(self, x):
         raise NotImplementedError
 
@@ -464,9 +505,10 @@ class DecoderBackend(ModelBackend):
         wave), its position is its context length ``lens[b]``; each layer
         writes the lane's new cache row behind the slot's live rows and
         reads them all (``_decode_attend``), whatever leaves the cache has
-        (``cache_leaves``), does so in the slot's ring (``ring_leaves``), or
+        (``cache_leaves``), does so in the slot's ring (``ring_leaves``),
         advances the slot's state in place (``_advance`` on
-        ``state_leaves``), by the layer's kind."""
+        ``state_leaves``), or touches no leaf at all (``_feed_forward``), by
+        the layer's kind."""
         attend = self._decode_attend()
         around = self._decode_attend(ring=True) if self.ring_leaves else None
         held = len(self.cache_leaves)
@@ -482,6 +524,8 @@ class DecoderBackend(ModelBackend):
                 cache, ring, state = (leaves[:held], leaves[held:first_state],
                                       leaves[first_state:])
                 kind, ki = self._layer_kind(li)
+                if kind == "none":
+                    return (self._feed_forward(lp, x), *leaves)
                 if kind == "rows":
                     *cache, o = attend(*cache, *self._qkv(lp, x, lens), rows,
                                        live, ki)

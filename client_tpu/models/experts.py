@@ -1,12 +1,16 @@
 """The sparse expert layer of a served decoder, and what goes with it: one
 copy.
 
-Three decoders route every token to a few of many small feed-forward experts:
+Four decoders route every token to a few of many small feed-forward experts:
 ``models/pangu_moe.py`` and ``models/kimi_linear.py`` (a latent cache; sigmoid
-scores, SwiGLU experts, a shared expert beside them: ``models/latent_moe.py``)
-and ``models/smallthinker.py`` (key/value rows in two leaves; a softmax over
-the chosen logits, ReLU-gated experts, no shared expert).  What does not
-differ between them lives here, in :class:`ExpertDecoder`:
+scores, SwiGLU experts, a shared expert beside them: ``models/latent_moe.py``),
+``models/smallthinker.py`` (key/value rows in two leaves; a softmax over
+the chosen logits, ReLU-gated experts, no shared expert) and
+``models/nemotron_h.py`` (expert layers that are blocks of their own between
+state-space and attention layers; sigmoid scores with a selection bias,
+**un-gated** squared-ReLU experts of two matrices, a shared expert of the same
+form).  What does not differ between them lives here, in
+:class:`ExpertDecoder`:
 
 - weights made when asked for (:class:`SeededWeight`) and put on the device a
   leaf at a time;
@@ -16,8 +20,14 @@ differ between them lives here, in :class:`ExpertDecoder`:
 - **the experts held here** (``_experts``): the backend holds ``experts_held``
   of the routed experts, ``first_expert ..``; the (token, expert) pairs held
   here are sorted by expert and multiplied in groups (ops/grouped_matmul.py),
-  the activation between the two products a parameter (``expert_act``:
-  ``"silu"`` | ``"relu"``, a name of ``jax.nn``): no pair is dropped, and an
+  what stands between the two products two parameters: the expert's form
+  (``expert_form``: ``"gated"``, ``E(h) = W_d (act(h W_g) * h W_u)`` over a
+  fused ``egu [E, d, 2f]``, or ``"plain"``, ``E(h) = W_d act(h W_u)`` over ``eu
+  [E, f, d]``, ``W_u`` lying as ``W_d`` does: two matrices an expert, not
+  three) and the activation
+  (``expert_act``: ``"silu"`` | ``"relu"``, a name of ``jax.nn``, or
+  ``"relu2"``, the ReLU squared); ``_dense_expert`` is one expert of that form
+  on plain matrices (a shared expert).  No pair is dropped, and an
   expert no token chose is not read.  What absent experts would add is left
   out; nothing stands in for other chips or their exchange;
 - the wave's carry (``_embed``: activations, routing counts, choices, live
@@ -27,11 +37,15 @@ differ between them lives here, in :class:`ExpertDecoder`:
   expert layers; padded lanes route nowhere);
 - **a stream's record** of its routing (``held_mask``): one bit a held expert
   in int32 words, 32 experts a word, the one thing about a routing that a
-  share's output depends on discontinuously.
+  share's output depends on discontinuously; ``_words``, ``_record`` and
+  ``prefill_fn`` leave ``held_words`` words an expert layer and a few logits
+  behind a program's tokens (``stream_record`` of the decoder's contract),
+  for a model whose ``piece_hidden_fn`` returns a piece's choices
+  (models/latent_moe.py keeps its one-word form).
 
 A model sets ``d_model, d_expert, n_experts, experts_held, first_expert,
-top_k, routed_scale, dtype, _seed`` and, where they differ from the defaults,
-``router_score`` and ``expert_act``.
+top_k, routed_scale, dtype, rms_eps, _seed`` and, where they differ from the
+defaults, ``router_score``, ``expert_form`` and ``expert_act``.
 """
 
 from __future__ import annotations
@@ -42,7 +56,8 @@ import os
 
 import numpy as np
 
-from client_tpu.models.decoder import DecoderBackend
+from client_tpu.models.decoder import (DecoderBackend, logit_bits,
+                                       sample_into_slots)
 
 _CHUNK = 1 << 24          # elements of a weight made by one task
 _BLOCK = 1 << 17          # elements made at a time (cache-sized)
@@ -143,6 +158,7 @@ class ExpertDecoder(DecoderBackend):
     wave_stats = ("expert_pairs_local", "expert_pairs_busiest",
                   "experts_touched")
     router_score = "sigmoid"
+    expert_form = "gated"
     expert_act = "silu"
     routed_scale = 1.0
 
@@ -155,6 +171,8 @@ class ExpertDecoder(DecoderBackend):
                 f"top {self.top_k} do not fit a router of {self.n_experts}")
         if self.router_score not in ("sigmoid", "softmax"):
             raise ValueError(f"router_score {self.router_score!r}")
+        if self.expert_form not in ("gated", "plain"):
+            raise ValueError(f"expert_form {self.expert_form!r}")
 
     # -- params --------------------------------------------------------------
 
@@ -224,13 +242,30 @@ class ExpertDecoder(DecoderBackend):
         weights = top_s / top_s.sum(-1, keepdims=True) * self.routed_scale
         return top_i, weights
 
+    def _between(self, u):
+        """What stands between an expert's two products: ``u [..., 2f]``
+        (gate | up) -> ``act(gate) * up`` where the form is gated, ``u [...,
+        f]`` -> ``act(u)`` where it is plain."""
+        import jax
+
+        act = ((lambda t: jax.nn.relu(t) ** 2) if self.expert_act == "relu2"
+               else getattr(jax.nn, self.expert_act))
+        if self.expert_form == "plain":
+            return act(u)
+        f = u.shape[-1] // 2
+        return act(u[..., :f]) * u[..., f:]
+
+    def _dense_expert(self, h, up, down):
+        """One expert of the layer's form on plain matrices (a shared
+        expert): h ``[n, d]`` -> ``[n, d]`` float32."""
+        return self._mm(self._between(self._mm(h, up)), down)
+
     def _experts(self, lp, h, live, tile_m, routing=None):
         """The held experts' part of the layer for tokens h ``[n, d]``:
-        ``sum_i w_i E_i(h)`` over the chosen experts held here (``E(h) = W_d
-        (act(h W_g) * h W_u)``, ``act`` by ``expert_act``), (pairs here,
+        ``sum_i w_i E_i(h)`` over the chosen experts held here (``E`` by
+        ``expert_form`` and ``expert_act``: ``_between``), (pairs here,
         the busiest expert's, experts touched), and every token's choices
         ``[n, k]``."""
-        import jax
         import jax.numpy as jnp
 
         from client_tpu.engine.backend_init import pallas_interpret
@@ -252,21 +287,23 @@ class ExpertDecoder(DecoderBackend):
         token = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
         src = jnp.full(rows + 1, n, jnp.int32).at[plan["dest"]].set(
             token)[:rows]
-        wdt = lp["egu"].dtype
+        # (A plain expert's ``eu`` lies ``[E, f, d]``, as ``ed`` does.)
+        plain = self.expert_form == "plain"
+        up = lp["eu" if plain else "egu"]
+        wdt = up.dtype
         xs = jnp.concatenate([h.astype(wdt), jnp.zeros((1, h.shape[1]), wdt)
                               ])[src]
         if self._use_kernel():
-            def gmm(x, w):
+            def gmm(x, w, **how):
                 return grouped_matmul(x, w, plan["tile_expert"],
                                       plan["n_tiles"], tile_m=tile_m,
-                                      interpret=pallas_interpret())
+                                      interpret=pallas_interpret(), **how)
         else:
-            def gmm(x, w):
-                return reference_grouped_matmul(x, w, plan["padded"])
-        gu = gmm(xs, lp["egu"])
-        f = gu.shape[-1] // 2
-        act = getattr(jax.nn, self.expert_act)
-        ys = gmm((act(gu[:, :f]) * gu[:, f:]).astype(wdt), lp["ed"])
+            def gmm(x, w, transposed=False):
+                return reference_grouped_matmul(
+                    x, w.swapaxes(1, 2) if transposed else w, plan["padded"])
+        mid = gmm(xs, up, transposed=True) if plain else gmm(xs, up)
+        ys = gmm(self._between(mid).astype(wdt), lp["ed"])
         # Back to tokens: a pair's row by ``dest``; rows no pair points at
         # (the kernel leaves those behind the last tile unwritten) are
         # never read.
@@ -321,3 +358,49 @@ class ExpertDecoder(DecoderBackend):
     def held_words(self) -> int:
         """int32 words that hold one bit a held expert."""
         return -(-self.experts_held // 32)
+
+    def _words(self, top_i):
+        """Choices ``[..., k]`` -> the record's words ``[..., held_words]``."""
+        import jax.numpy as jnp
+
+        return jnp.stack([self.held_mask(top_i, w)
+                          for w in range(self.held_words)], axis=-1)
+
+    def _record(self, x, logits, tokens):
+        """A wave's rows of the streams' record ``[B, stream_record]``."""
+        import jax.numpy as jnp
+
+        return jnp.concatenate(
+            [self._words(r) for r in x["route"]]
+            + [logit_bits(logits, tokens, RECORD_LOGITS)], axis=1)
+
+    def prefill_fn(self):
+        """``PREFILL_ARGS`` -> (arena, tokens[1]): one **piece** of the
+        lane's prompt (``piece_hidden_fn``: (arena, x ``[piece, d]``, choices
+        ``[expert layers, piece, top_k]``)); the token sampled after its last
+        valid position lands in the slot's device-side token, and means
+        something for a prompt's last piece only.  With ``stream_record`` the
+        piece's rows of the record follow the token, ``[1 + piece x
+        stream_record]``."""
+        piece = self.piece_hidden_fn()
+
+        def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
+                    sample, starts):
+            import jax.numpy as jnp
+
+            arena, x, routes = piece(p, arena, rows, ids, lens, starts)
+            logits = self._logits(p, x[lens - 1])
+            arena, tokens = sample_into_slots(
+                arena, rows, logits, seeds, starts + lens, temps, top_ks,
+                top_ps, sample)
+            if not self.stream_record:
+                return arena, tokens
+            last = jnp.arange(self.piece) == lens[0] - 1
+            rec = jnp.concatenate(
+                [self._words(r) for r in routes]
+                + [jnp.where(last[:, None],
+                             logit_bits(logits, tokens, RECORD_LOGITS), 0)],
+                axis=1)
+            return arena, jnp.concatenate([tokens, rec.reshape(-1)])
+
+        return prefill

@@ -1,0 +1,94 @@
+"""A prefill piece's attention over key/value rows with grouped-query heads:
+one copy.
+
+``models/smallthinker.py`` (its global layers; its window layers put a band
+and a ring around the same attention) and ``models/nemotron_h.py`` (its
+attention layers) both hold ``n_heads`` query heads over ``n_kv_heads``
+key/value heads of ``head_dim`` in two leaves ``[layers of the kind, R,
+max_seq_len, Hkv*D]`` and prefill by pieces of ``piece`` positions, one prompt
+a call.  Piece i of a prompt has exactly ``i * piece`` rows before it, so a
+layer holds one branch a count (``lax.switch``) and computes nothing that is
+masked but inside the causal block: the piece's queries attend to the rows
+before them and, causally, to their own (the flash kernel's grouped-query
+heads, ``ops/flash_attention.py``, or dense scores, by ``attention_impl``),
+and the piece's rows are written behind them.
+"""
+
+from __future__ import annotations
+
+import math
+
+_NEG_INF = -1e30
+
+
+class GroupedQueryPieces:
+    """The shared parts above, for a backend that sets ``n_heads, n_kv_heads,
+    head_dim, piece, max_seq_len`` and ``attention_impl``."""
+
+    def _attend(self, q, own_k, own_v, before_k, before_v, window,
+                impl=None):
+        """A piece's attention: q ``[n, H, D]`` float32 against the keys and
+        values of the rows before it ``[P, Hkv*D]`` and, causally, of its own
+        ``[n, Hkv*D]`` (both as the cache holds them), in a band of ``window``
+        keys where one is given, by ``impl`` (the backend's
+        ``attention_impl`` unless given).  -> ``[n, H * D]`` float32."""
+        import jax
+        import jax.numpy as jnp
+
+        n, pre = own_k.shape[0], before_k.shape[0]
+        k_all = jnp.concatenate([before_k, own_k]) if pre else own_k
+        v_all = jnp.concatenate([before_v, own_v]) if pre else own_v
+        h, hk, d = self.n_heads, self.n_kv_heads, self.head_dim
+        if (impl or self.attention_impl) == "flash":
+            from client_tpu.engine.backend_init import pallas_interpret
+            from client_tpu.ops.flash_attention import flash_attention
+
+            return flash_attention(
+                q.reshape(1, n, h * d).astype(k_all.dtype), k_all[None],
+                v_all[None], causal=True, prefix=pre, window=window,
+                n_heads=h, n_kv_heads=hk, block_q=n, block_k=n,
+                interpret=pallas_interpret())[0].astype(jnp.float32)
+        group = h // hk
+        k_f = jnp.repeat(k_all.astype(jnp.float32).reshape(pre + n, hk, d),
+                         group, axis=1)
+        v_f = jnp.repeat(v_all.astype(jnp.float32).reshape(pre + n, hk, d),
+                         group, axis=1)
+        s = jnp.einsum("qhd,khd->hqk", q, k_f) / math.sqrt(d)
+        ago = (pre + jnp.arange(n)[:, None]) - jnp.arange(pre + n)[None, :]
+        seen = ago >= 0
+        if window is not None:
+            seen = seen & (ago < window)
+        s = jnp.where(seen[None], s, _NEG_INF)
+        return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1),
+                          v_f).reshape(n, h * d)
+
+    def _rows_before(self, leaf, ki, row, count):
+        """The first ``count`` rows of slot ``row`` in layer ``ki`` of a
+        leaf."""
+        import jax
+
+        return jax.lax.dynamic_slice(
+            leaf, (ki, row, 0, 0),
+            (1, 1, count, self.n_kv_heads * self.head_dim))[0, 0]
+
+    def _piece_rows(self, k_a, v_a, ki, row, start, q, own_k, own_v):
+        """A whole-context layer's part of a piece: q ``[piece, H, D]``
+        against the slot's ``start`` rows before the piece and its own
+        ``own_k, own_v [piece, Hkv*D]`` (as the cache holds them), which are
+        written behind them.  -> (K leaf, V leaf, o ``[piece, H * D]``)."""
+        import jax
+
+        n = self.piece
+
+        def attend(pre):
+            before = [self._rows_before(leaf, ki, row, pre)
+                      for leaf in (k_a, v_a)]
+            return self._attend(q, own_k, own_v, *before, None)
+
+        o = jax.lax.switch(
+            start // n, [lambda pre=i * n: attend(pre)
+                         for i in range(self.max_seq_len // n)])
+        k_a, v_a = (jax.lax.dynamic_update_slice(
+            leaf, own[None, None], (ki, row, start, 0))
+            for leaf, own in ((k_a, own_k), (v_a, own_v)))
+        return k_a, v_a, o

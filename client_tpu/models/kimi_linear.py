@@ -246,38 +246,24 @@ class KimiLinearBackend(LatentMoeDecoder):
         (ops/kda.py: the kernel, or its oracle where the arena is not the
         kernels').
 
-        The tails leave and enter the leaf through a one-hot product, lanes
-        by slots (exact: a row of it holds one 1, and a bfloat16 value times
-        1 summed in float32 is the value): XLA lowers a gather and a scatter
-        of 256 rows of this leaf to loops of 256 slices, 2.2 ms a layer on
-        the v5e where the product and one pass over the layer's slots take
-        a tenth of that (PERF.md section 6, PR 34).  A slot that several
-        lanes name (the junk slot) is left their sum."""
-        import jax
+        The tails leave and enter the leaf through a one-hot product
+        (models/decoder.py ``slot_tails``)."""
         import jax.numpy as jnp
 
         from client_tpu.engine.backend_init import pallas_interpret
+        from client_tpu.models.decoder import put_slot_tails, slot_tails
         from client_tpu.ops.kda import kda_wave_update, reference_kda_update
 
         del lens
-        hi, f32 = jax.lax.Precision.HIGHEST, jnp.float32
         h = rms_norm(x["h"], lp["ln1"], self.rms_eps)
         new = self._mm(h, lp["wqkv"]).astype(conv_a.dtype)
         lanes, width = new.shape
-        slots = conv_a[ki]                                     # [R, tail]
-        pick = (rows[:, None] == jnp.arange(slots.shape[0])[None, :]
-                ).astype(conv_a.dtype)                         # [B, R]
-        tail = jnp.matmul(pick, slots, precision=hi,
-                          preferred_element_type=f32).astype(conv_a.dtype)
+        pick, slots, tail = slot_tails(conv_a, ki, rows)
         ext = jnp.concatenate(
             [tail.reshape(lanes, self.taps - 1, width), new[:, None]], axis=1)
         q, k, v, g, beta, gate = (
             t[:, 0] for t in self._kda_inputs(lp, h[:, None], ext))
-        put = jnp.matmul(pick.T, ext[:, 1:].reshape(lanes, -1), precision=hi,
-                         preferred_element_type=f32).astype(conv_a.dtype)
-        slots = jnp.where(pick.any(axis=0)[:, None], put, slots)
-        conv_a = jax.lax.dynamic_update_slice(conv_a, slots[None],
-                                              (ki, 0, 0))
+        conv_a = put_slot_tails(conv_a, ki, pick, slots, ext)
         if self._use_kernel():
             s_a, o = kda_wave_update(s_a, q, k, v, g, beta, rows, layer=ki,
                                      interpret=pallas_interpret())

@@ -41,7 +41,9 @@ global layer and ``min(i * piece, window)`` in a window layer, so a layer
 holds one branch a count (``lax.switch``: nothing masked is computed but
 inside the band's two edge blocks): the piece's queries attend to the rows
 before them and, causally, to their own, with the flash kernel's band and
-grouped-query heads (``ops/flash_attention.py``).  A ring that is full is read
+grouped-query heads (``ops/flash_attention.py``; the attention and a global
+layer's part of a piece are ``models/grouped_query.py``'s, shared with
+``models/nemotron_h.py``).  A ring that is full is read
 whole, oldest position first, **before** the piece's rows overwrite its oldest
 block (a ring holds whole pieces: a piece never wraps); a
 prompt's last piece writes its valid rows only, the rows behind them being
@@ -64,16 +66,13 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.decoder import logit_bits, sample_into_slots
 from client_tpu.models.evabyte import rope
-from client_tpu.models.experts import (RECORD_LOGITS, TILE_M_PIECE,
-                                       TILE_M_WAVE, ExpertDecoder,
-                                       record_width, rms_norm)
-
-_NEG_INF = -1e30
+from client_tpu.models.experts import (TILE_M_PIECE, TILE_M_WAVE,
+                                       ExpertDecoder, record_width, rms_norm)
+from client_tpu.models.grouped_query import GroupedQueryPieces
 
 
-class SmallThinkerBackend(ExpertDecoder):
+class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
     """The decoder above (``models/decoder.py`` for what it is served
     through).  ``dtype="float32"`` makes weights, cache and matmuls float32
     (the tests' exact comparison); the served form is bfloat16."""
@@ -215,13 +214,6 @@ class SmallThinkerBackend(ExpertDecoder):
             routing=routing)
         return x + y, counts, top_i
 
-    def _words(self, top_i):
-        """Choices ``[..., k]`` -> the record's words ``[..., held_words]``."""
-        import jax.numpy as jnp
-
-        return jnp.stack([self.held_mask(top_i, w)
-                          for w in range(self.held_words)], axis=-1)
-
     # -- the decode step's parts (models/decoder.py) ---------------------------
 
     def _qkv(self, lp, x, pos):
@@ -236,52 +228,7 @@ class SmallThinkerBackend(ExpertDecoder):
         return {**x, "h": h, "stats": x["stats"] + stats,
                 "route": x["route"] + (top_i,)}
 
-    def _record(self, x, logits, tokens):
-        """A wave's rows of the streams' record ``[B, stream_record]``."""
-        import jax.numpy as jnp
-
-        return jnp.concatenate(
-            [self._words(r) for r in x["route"]]
-            + [logit_bits(logits, tokens, RECORD_LOGITS)], axis=1)
-
-    # -- attention over a piece or a whole context --------------------------------
-
-    def _attend(self, q, own_k, own_v, before_k, before_v, window,
-                impl=None):
-        """A piece's attention: q ``[n, H, D]`` float32 against the keys and
-        values of the rows before it ``[P, Hkv*D]`` and, causally, of its own
-        ``[n, Hkv*D]`` (both as the cache holds them), in a band of ``window``
-        keys where one is given, by ``impl`` (the backend's
-        ``attention_impl`` unless given).  -> ``[n, H * D]`` float32."""
-        import jax
-        import jax.numpy as jnp
-
-        n, pre = own_k.shape[0], before_k.shape[0]
-        k_all = jnp.concatenate([before_k, own_k]) if pre else own_k
-        v_all = jnp.concatenate([before_v, own_v]) if pre else own_v
-        h, hk, d = self.n_heads, self.n_kv_heads, self.head_dim
-        if (impl or self.attention_impl) == "flash":
-            from client_tpu.engine.backend_init import pallas_interpret
-            from client_tpu.ops.flash_attention import flash_attention
-
-            return flash_attention(
-                q.reshape(1, n, h * d).astype(k_all.dtype), k_all[None],
-                v_all[None], causal=True, prefix=pre, window=window,
-                n_heads=h, n_kv_heads=hk, block_q=n, block_k=n,
-                interpret=pallas_interpret())[0].astype(jnp.float32)
-        group = h // hk
-        k_f = jnp.repeat(k_all.astype(jnp.float32).reshape(pre + n, hk, d),
-                         group, axis=1)
-        v_f = jnp.repeat(v_all.astype(jnp.float32).reshape(pre + n, hk, d),
-                         group, axis=1)
-        s = jnp.einsum("qhd,khd->hqk", q, k_f) / math.sqrt(d)
-        ago = (pre + jnp.arange(n)[:, None]) - jnp.arange(pre + n)[None, :]
-        seen = ago >= 0
-        if window is not None:
-            seen = seen & (ago < window)
-        s = jnp.where(seen[None], s, _NEG_INF)
-        return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1),
-                          v_f).reshape(n, h * d)
+    # -- a piece's attention (models/grouped_query.py) ----------------------------
 
     def _piece_layer(self, lp, leaves, kind, ki, row, start, n_valid, x,
                      pos):
@@ -297,38 +244,31 @@ class SmallThinkerBackend(ExpertDecoder):
         q, k, v = self._project(lp, x, pos, self.rotate[kind])
         own_k, own_v = (t.reshape(n, hd).astype(k_a.dtype) for t in (k, v))
 
-        def rows_of(leaf, count):
-            return jax.lax.dynamic_slice(
-                leaf, (ki, row, 0, 0), (1, 1, count, hd))[0, 0]
+        if not ring:
+            return self._piece_rows(k_a, v_a, ki, row, start, q, own_k, own_v)
 
         def attend(pre, rolled=False):
-            before = [rows_of(leaf, pre) for leaf in (k_a, v_a)]
+            before = [self._rows_before(leaf, ki, row, pre)
+                      for leaf in (k_a, v_a)]
             if rolled:
                 # A full ring, oldest position first: row (start mod ring)
                 # holds position start - ring.
                 before = [jnp.roll(b, -(start % self.ring_rows), axis=0)
                           for b in before]
-            return self._attend(q, own_k, own_v, *before,
-                                self.window if ring else None)
+            return self._attend(q, own_k, own_v, *before, self.window)
 
-        if ring:
-            full = self.ring_rows // n
-            branches = [lambda pre=i * n: attend(pre) for i in range(full)]
-            branches.append(lambda: attend(self.ring_rows, rolled=True))
-            o = jax.lax.switch(jnp.minimum(start // n, full), branches)
-            at = start % self.ring_rows
-            # A prompt's last piece: the rows behind its valid ones hold
-            # positions a later step still reads.
-            valid = (jnp.arange(n) < n_valid)[:, None]
-            own_k, own_v = (
-                jnp.where(valid, own, jax.lax.dynamic_slice(
-                    leaf, (ki, row, at, 0), (1, 1, n, hd))[0, 0])
-                for own, leaf in ((own_k, k_a), (own_v, v_a)))
-        else:
-            o = jax.lax.switch(
-                start // n, [lambda pre=i * n: attend(pre)
-                             for i in range(self.max_seq_len // n)])
-            at = start
+        full = self.ring_rows // n
+        branches = [lambda pre=i * n: attend(pre) for i in range(full)]
+        branches.append(lambda: attend(self.ring_rows, rolled=True))
+        o = jax.lax.switch(jnp.minimum(start // n, full), branches)
+        at = start % self.ring_rows
+        # A prompt's last piece: the rows behind its valid ones hold
+        # positions a later step still reads.
+        valid = (jnp.arange(n) < n_valid)[:, None]
+        own_k, own_v = (
+            jnp.where(valid, own, jax.lax.dynamic_slice(
+                leaf, (ki, row, at, 0), (1, 1, n, hd))[0, 0])
+            for own, leaf in ((own_k, k_a), (own_v, v_a)))
         k_a, v_a = (jax.lax.dynamic_update_slice(
             leaf, own[None, None], (ki, row, at, 0))
             for leaf, own in ((k_a, own_k), (v_a, own_v)))
@@ -413,32 +353,3 @@ class SmallThinkerBackend(ExpertDecoder):
                     jnp.stack(routes))
 
         return piece
-
-    def prefill_fn(self):
-        """``PREFILL_ARGS`` -> (arena, tokens[1]): one **piece** of the
-        lane's prompt; the token sampled after its last valid position lands
-        in the slot's device-side token, and means something for a prompt's
-        last piece only.  With ``stream_record`` the piece's rows of the
-        record follow the token, ``[1 + piece x stream_record]``."""
-        piece = self.piece_hidden_fn()
-
-        def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
-                    sample, starts):
-            import jax.numpy as jnp
-
-            arena, x, routes = piece(p, arena, rows, ids, lens, starts)
-            logits = self._logits(p, x[lens - 1])
-            arena, tokens = sample_into_slots(
-                arena, rows, logits, seeds, starts + lens, temps, top_ks,
-                top_ps, sample)
-            if not self.stream_record:
-                return arena, tokens
-            last = jnp.arange(self.piece) == lens[0] - 1
-            rec = jnp.concatenate(
-                [self._words(r) for r in routes]
-                + [jnp.where(last[:, None],
-                             logit_bits(logits, tokens, RECORD_LOGITS), 0)],
-                axis=1)
-            return arena, jnp.concatenate([tokens, rec.reshape(-1)])
-
-        return prefill

@@ -85,7 +85,9 @@ def pick_tile_n(k: int, n: int, itemsize: int, cap_bytes: int = 16 << 20):
     """Columns of a weight block: the largest multiple-of-128 divisor of
     ``n`` whose ``[k, tile_n]`` block stays under ``cap_bytes`` (double
     buffered it has to fit the core's 128 MiB beside the row tiles); ``n``
-    itself where no such divisor exists (the tests' narrow layers)."""
+    itself where no such divisor exists (a narrow layer; a published width
+    that is no multiple of 128, as an un-gated expert's 1856, is served from a
+    leaf that lies the other way: ``grouped_matmul``'s ``transposed``)."""
     best = None
     for cand in range(128, n + 1, 128):
         if n % cand == 0 and k * cand * itemsize <= cap_bytes:
@@ -93,31 +95,44 @@ def pick_tile_n(k: int, n: int, itemsize: int, cap_bytes: int = 16 << 20):
     return best if best is not None else n
 
 
-def _gmm_kernel(te_ref, nt_ref, x_ref, w_ref, o_ref):
+def _gmm_kernel(te_ref, nt_ref, x_ref, w_ref, o_ref, *, transposed=False):
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(1) < nt_ref[0])
     def _tile():
-        o_ref[...] = jnp.dot(
-            x_ref[...], w_ref[...],
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        if transposed:          # the weight block lies [N, K]
+            prod = jax.lax.dot_general(
+                x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            prod = jnp.dot(x_ref[...], w_ref[...],
+                           preferred_element_type=jnp.float32)
+        o_ref[...] = prod.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_m", "tile_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_m", "tile_n", "interpret",
+                                             "transposed"))
 def grouped_matmul(x, w, tile_expert, n_tiles, *, tile_m: int,
-                   tile_n: int | None = None, interpret: bool = False):
+                   tile_n: int | None = None, interpret: bool = False,
+                   transposed: bool = False):
     """``x [M, K]`` in ``plan_groups``' layout (M a multiple of ``tile_m``),
     ``w [E, K, N]`` -> ``[M, N]`` float32 with rows of tile t holding ``x_t @
-    w[tile_expert[t]]`` for ``t < n_tiles`` and junk behind."""
+    w[tile_expert[t]]`` for ``t < n_tiles`` and junk behind.  ``transposed``:
+    the weights lie ``[E, N, K]`` and a tile holds ``x_t @ w[e]^T`` (a leaf
+    whose ``N`` is no multiple of 128 lanes lies with ``K`` minor: the other
+    way the chip's layout pads every row of it, and the compiler re-lays the
+    whole leaf around the call); a weight block is then all ``N`` rows."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     m, k = x.shape
-    _, kw, n = w.shape
+    _, kw, n = w.swapaxes(1, 2).shape if transposed else w.shape
     if kw != k or m % tile_m or tile_expert.shape != (m // tile_m,):
         raise ValueError(f"x {x.shape}, w {w.shape}, tile_m {tile_m} and "
                          f"{tile_expert.shape[0]} tiles do not fit together")
-    if tile_n is None:
+    if transposed:
+        tile_n = n
+    elif tile_n is None:
         tile_n = pick_tile_n(k, n, w.dtype.itemsize)
     if n % tile_n:
         raise ValueError(f"tile_n ({tile_n}) must divide {n}")
@@ -125,20 +140,27 @@ def grouped_matmul(x, w, tile_expert, n_tiles, *, tile_m: int,
     def used(t, nt):
         return jnp.minimum(t, jnp.maximum(nt[0] - 1, 0))
 
+    if transposed:
+        kernel = functools.partial(_gmm_kernel, transposed=True)
+        w_spec = pl.BlockSpec((None, n, k),
+                              lambda j, t, te, nt: (te[t], 0, 0))
+    else:
+        kernel = _gmm_kernel
+        w_spec = pl.BlockSpec((None, k, tile_n),
+                              lambda j, t, te, nt: (te[t], 0, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n // tile_n, m // tile_m),
         in_specs=[
             pl.BlockSpec((tile_m, k), lambda j, t, te, nt: (used(t, nt), 0)),
-            pl.BlockSpec((None, k, tile_n),
-                         lambda j, t, te, nt: (te[t], 0, j)),
+            w_spec,
         ],
         out_specs=pl.BlockSpec((tile_m, tile_n),
                                lambda j, t, te, nt: (used(t, nt), j)),
     )
     w_block = k * tile_n * w.dtype.itemsize
     return pl.pallas_call(
-        _gmm_kernel,
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(

@@ -364,9 +364,11 @@ class TestCacheReaders:
             manifest = json.load(f)
         by = {m["name"]: m for m in manifest["per_layer"]}
         for name in CACHE_METRICS:
-            # (The decode kernel's share is read on the second cell that
-            # runs the kernel as well: its global layers' calls, PR 43.)
-            others = (["smallthinker_21b.mixed"]
+            # (The decode kernel's share is read on the other cells that
+            # run the kernel as well: a global layer's calls, PR 43, and an
+            # attention layer's between state-space layers, PR 45.)
+            others = (["smallthinker_21b.mixed",
+                       "nemotron3_nano_30b.assistant"]
                       if name == "decode_attn_roofline.itl" else [])
             assert by[name]["workloads"] == [CELL] + others
             assert by[name]["moves"] == "itl_mean_ms"

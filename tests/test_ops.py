@@ -986,3 +986,152 @@ class TestFlashBandAndGroupedQueryHeads:
                             interpret=True)
         with pytest.raises(ValueError, match="band"):
             flash_attention(q, q, q, n_heads=4, window=8, interpret=True)
+
+
+# -- the state-space update and the un-gated grouped product (PR 45) -------------
+
+from client_tpu.ops import ssd  # noqa: E402
+from client_tpu.ops.grouped_matmul import (  # noqa: E402
+    capacity_rows,
+    grouped_matmul,
+    pick_tile_n,
+    plan_groups,
+    reference_grouped_matmul,
+)
+
+
+def _ssd_operands(n, heads=4, p=8, groups=2, state=16, strong=False, seed=0):
+    rng = np.random.default_rng(seed)
+    dt = rng.uniform(0.001, 8.0 if strong else 0.1, (n, heads))
+    return (jnp.asarray(rng.normal(size=(n, heads, p)), jnp.float32),
+            jnp.asarray(dt, jnp.float32),
+            -jnp.asarray(rng.uniform(1, 16, heads), jnp.float32),
+            jnp.asarray(rng.normal(size=(n, groups, state)), jnp.float32),
+            jnp.asarray(rng.normal(size=(n, groups, state)), jnp.float32),
+            jnp.asarray(rng.normal(size=(heads, state, p)), jnp.float32))
+
+
+class TestSsd:
+    @pytest.mark.parametrize("chunk", [1, 4, 16, 32])
+    @pytest.mark.parametrize("strong", [False, True])
+    @pytest.mark.parametrize("pack", [1, 2])
+    def test_the_chunked_form_is_the_recurrence_from_a_state(self, chunk,
+                                                             strong, pack):
+        """From a non-zero start state, at any chunk, packed as the arena
+        packs it or a state a head; under a decay of ``exp(-128)`` a step
+        nothing overflows (no ``1 / exp(G)`` is formed)."""
+        x, dt, a, b, c, s0 = _ssd_operands(32, strong=strong)
+        want_y, want_s = ssd.ssd_recurrence(x, dt, a, b, c, s0)
+        got_y, got_s = ssd.ssd_chunk_scan(x, dt, a, b, c,
+                                          ssd.pack_state(s0, pack),
+                                          chunk=chunk)
+        assert np.isfinite(np.asarray(got_y)).all()
+        assert np.abs(np.asarray(got_y - want_y)).max() < 2e-4
+        assert np.abs(np.asarray(ssd.unpack_state(got_s, pack) - want_s)
+                      ).max() < 2e-5
+
+    def test_the_recurrence_is_the_published_line(self):
+        """``S = exp(dt A) S + (dt x) B^T``, ``y = S C``, head h on group ``h
+        // (H / G)``, written out with numpy for one position."""
+        x, dt, a, b, c, s0 = (np.asarray(t, np.float64)
+                              for t in _ssd_operands(1, seed=3))
+        y, s = ssd.ssd_recurrence(*(jnp.asarray(t, jnp.float32) for t in
+                                    (x, dt, a, b, c, s0)))
+        for h in range(4):
+            g = h // 2
+            want = (np.exp(dt[0, h] * a[h]) * s0[h].T
+                    + np.outer(dt[0, h] * x[0, h], b[0, g]))       # [P, N]
+            assert np.abs(np.asarray(s)[h].T - want).max() < 1e-5
+            assert np.abs(np.asarray(y)[0, h] - want @ c[0, g]).max() < 1e-4
+
+    def test_a_padded_position_moves_nothing(self):
+        x, dt, a, b, c, s0 = _ssd_operands(16, seed=1)
+        dt = dt.at[11:].set(0.0)
+        _, s_all = ssd.ssd_chunk_scan(x, dt, a, b, c, s0, chunk=8)
+        _, s_cut = ssd.ssd_recurrence(x[:11], dt[:11], a, b[:11], c[:11], s0)
+        assert np.abs(np.asarray(s_all - s_cut)).max() < 2e-5
+
+    def test_packing_is_its_own_inverse_and_puts_a_groups_heads_side_by_side(
+            self):
+        s = jnp.arange(2 * 4 * 3 * 5, dtype=jnp.float32).reshape(2, 4, 3, 5)
+        packed = ssd.pack_state(s, 2)
+        assert packed.shape == (2, 2, 3, 10)
+        assert np.array_equal(np.asarray(packed[1, 0, :, 5:]),
+                              np.asarray(s[1, 1]))
+        assert np.array_equal(np.asarray(ssd.unpack_state(packed, 2)),
+                              np.asarray(s))
+
+    @pytest.mark.parametrize("layer", [1, "traced"])
+    @pytest.mark.parametrize("heads,groups,pack,block", [
+        (4, 2, 2, 32), (8, 2, 2, 2), (8, 8, 1, 4), (64, 8, 2, 32)])
+    def test_wave_kernel_parity_in_place(self, heads, groups, pack, block,
+                                         layer, monkeypatch):
+        """The kernel (interpreted) against its oracle: the lanes' slots
+        advanced, every other slot and layer bit for bit as it was, whatever
+        the block of packed heads; two padded lanes on the junk slot."""
+        monkeypatch.setattr(ssd, "HEAD_BLOCK", block)
+        p, state, slots = 8, 16, 6
+        x, dt, a, b, c, _ = _ssd_operands(5, heads, p, groups, state, seed=2)
+        rng = np.random.default_rng(4)
+        arena = ssd.pack_state(jnp.asarray(
+            rng.normal(size=(3, slots, heads, state, p)), jnp.float32), pack)
+        rows = jnp.asarray([4, 0, 2, 5, 5], jnp.int32)
+        want_s, want_y = ssd.reference_ssd_update(arena, x, dt, a, b, c, rows,
+                                                  layer=1)
+        at = 1 if layer == 1 else jnp.asarray(1, jnp.int32)
+        got_s, got_y = jax.jit(
+            lambda s, ly: ssd.ssd_wave_update(s, x, dt, a, b, c, rows,
+                                              layer=ly, interpret=True),
+            static_argnums=(1,) if layer == 1 else ())(arena, at)
+        live = np.asarray([4, 0, 2])
+        assert np.abs(np.asarray(got_y - want_y))[:3].max() < 1e-5
+        assert np.abs(np.asarray(got_s - want_s))[1, live].max() < 1e-6
+        untouched = np.asarray(got_s).copy()
+        untouched[1, [4, 0, 2, 5]] = np.asarray(arena)[1, [4, 0, 2, 5]]
+        assert np.array_equal(untouched, np.asarray(arena))
+
+    def test_wave_kernel_refuses_heads_that_do_not_lie_in_the_leaf(self):
+        x, dt, a, b, c, _ = _ssd_operands(2, heads=4, groups=2)
+        # Four packed heads of 16 lanes for four heads of 8: neither packed
+        # nor plain.
+        arena = jnp.zeros((1, 3, 4, 16, 16), jnp.float32)
+        with pytest.raises(ValueError, match="do not lie"):
+            ssd.ssd_wave_update(arena, x, dt, a, b, c,
+                                jnp.asarray([0, 1], jnp.int32), layer=0,
+                                interpret=True)
+
+
+@pytest.mark.parametrize("width", [24, 1856 // 8, 200])
+@pytest.mark.parametrize("skew", [False, True])
+def test_transposed_grouped_matmul_at_a_width_off_the_lanes(width, skew):
+    """The un-gated expert's up product: weights ``[E, f, d]`` with ``f`` no
+    multiple of 128, multiplied transposed, one block an expert: the kernel
+    (interpreted) against the oracle on the same layout, and against the
+    plain form on the swapped weights."""
+    rng = np.random.default_rng(6)
+    n_experts, d, tile_m, pairs = 5, 64, 8, 37
+    expert = (np.minimum(rng.geometric(0.6, pairs) - 1, n_experts)
+              if skew else rng.integers(0, n_experts + 1, pairs))
+    rows = capacity_rows(pairs, n_experts, tile_m)
+    plan = plan_groups(jnp.asarray(expert, jnp.int32), n_experts, tile_m,
+                       rows)
+    xs = jnp.asarray(rng.normal(size=(rows, d)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(n_experts, width, d)), jnp.float32)
+    got = grouped_matmul(xs, w, plan["tile_expert"], plan["n_tiles"],
+                         tile_m=tile_m, interpret=True, transposed=True)
+    want = reference_grouped_matmul(xs, w.swapaxes(1, 2), plan["padded"])
+    used = int(plan["n_tiles"][0]) * tile_m
+    assert got.shape == (rows, width)
+    assert np.abs(np.asarray(got - want))[:used].max() < 1e-4
+    plain = grouped_matmul(xs, w.swapaxes(1, 2), plan["tile_expert"],
+                           plan["n_tiles"], tile_m=tile_m, interpret=True)
+    assert np.abs(np.asarray(got - plain))[:used].max() < 1e-4
+    with pytest.raises(ValueError, match="do not fit"):
+        grouped_matmul(xs, w[:, :, :-1], plan["tile_expert"], plan["n_tiles"],
+                       tile_m=tile_m, interpret=True, transposed=True)
+
+
+def test_pick_tile_n_falls_back_to_a_width_that_has_no_lane_divisor():
+    assert pick_tile_n(2688, 1856, 2) == 1856       # 14.5 x 128: one block
+    assert pick_tile_n(1856, 2688, 2) == 2688       # 21 x 128, 9.98 MB
+    assert pick_tile_n(2560, 1536, 2) == 1536

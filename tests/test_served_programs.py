@@ -31,7 +31,15 @@ four families' sixteen hashes did not move; ``gpt``'s, ``evabyte``'s and
 kernel walks a lane's live blocks, the lanes its grid, a lane's last block
 copied by quanta, and takes the layer as an operand, so that a program's
 calls of one shape are one function: both hashes of the three moved, the
-three prefill programs and ``pangu``'s and ``kimi``'s four did not).  A PR that
+three prefill programs and ``pangu``'s and ``kimi``'s four did not);
+``nemotron``'s two (models/nemotron_h.py: the state-space kernel of
+ops/ssd.py, the decode kernel with grouped-query rows, the transposed grouped
+matmul of an un-gated expert; the chunked piece) at the tree PR 45 left, which
+gave ``models/decoder.py`` the ``"none"`` layer kind and ``models/experts.py``
+the expert's form, and lifted a wave's tails (``slot_tails``), a piece's
+grouped-query attention (models/grouped_query.py) and a stream's record out
+of ``kimi_linear.py`` and ``smallthinker.py``: the other five families'
+twenty hashes did not move.  A PR that
 means to change one of these programs records the new hash here and says so;
 one that does not has a guard.
 
@@ -54,6 +62,8 @@ RECORDED = {
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),
     ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),
     ("kimi", "prefill"): ("93c6ccfe91d5a268", "5dd18e6e271299fb"),
+    ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),
+    ("nemotron", "prefill"): ("8582c0278c7cfb1e", "34bbc0cdbc93323f"),
     ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),
     ("pangu", "prefill"): ("2947c2e62c0d448d", "fa56a8062981bfe9"),
     ("smallthinker", "decode"): ("44c3bd43dd161186", "93891480d1ba5ee1"),
@@ -96,6 +106,17 @@ def _backend(family):
                                    max_seq_len=64, window=32, piece=16,
                                    attention_impl="flash", attn_impl="fused",
                                    record=True)
+    if family == "nemotron":
+        from client_tpu.models.nemotron_h import NemotronHBackend
+
+        # Heads of whole 128-lane tiles, as the flash pieces need them; two
+        # state heads of 64 side by side in a row of the state's leaf.
+        return NemotronHBackend(seed=3, pattern="MEM*E", n_heads=4,
+                                n_kv_heads=2, head_dim=128, mamba_heads=4,
+                                mamba_head_dim=64, n_groups=2, state_size=128,
+                                max_seq_len=32, piece=16, chunk=8,
+                                attention_impl="flash", attn_impl="fused",
+                                record=True)
     from client_tpu.models.generate import TinyGptBackend
 
     return TinyGptBackend(attention_impl="flash", attn_impl="fused")
@@ -105,7 +126,8 @@ def _program(family, which):
     """(function, static and donated argument numbers, abstract
     arguments)."""
     be = _backend(family)
-    if family in ("pangu", "kimi", "smallthinker"):   # made when asked for
+    if family in ("pangu", "kimi", "smallthinker", "nemotron"):
+        # (made when asked for)
         params = jax.tree_util.tree_map(
             lambda w: jax.ShapeDtypeStruct(w.shape, jnp.dtype(w.dtype)),
             be._init_params())

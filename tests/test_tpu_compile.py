@@ -656,6 +656,102 @@ def test_window_and_global_decoder_compiles_at_published_widths(
     assert 13.6e9 < memory.argument_size_in_bytes < 13.8e9
 
 
+# -- state-space, attention and expert layers as blocks of their own (PR 45) ----
+
+NEMOTRON = dict(
+    pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+    n_layers=13, d_model=2688, n_heads=32, n_kv_heads=2, head_dim=128,
+    mamba_heads=64, mamba_head_dim=64, n_groups=8, state_size=128,
+    conv_kernel=4, chunk=128, d_expert=1856, d_shared=3712, n_experts=128,
+    experts_held=64, top_k=6, routed_scale=2.5, vocab=65536,
+    max_seq_len=4096, piece=512, max_streams=256, attention_impl="flash",
+    record=True)
+
+
+def _nemotron_program(one_chip, monkeypatch, which):
+    """``nemotron3_nano_30b``'s ``jit_decode`` (a full wave of 256) or
+    ``jit_prefill`` (one piece of 512) for one v5e chip from shapes alone
+    (13.3 GB of weights and cache that nothing allocates).  Returns
+    (optimised text, arena shapes, memory, backend)."""
+    from client_tpu.engine import backend_init
+    from client_tpu.models.nemotron_h import NemotronHBackend
+    from client_tpu.observability import spans
+
+    monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
+    place = _on(one_chip)
+    backend = NemotronHBackend(name="n", **NEMOTRON)
+    params = jax.tree_util.tree_map(
+        lambda leaf: place(leaf.shape, jnp.dtype(leaf.dtype)),
+        backend._init_params())
+    arena = jax.tree_util.tree_map(
+        lambda a: place(a.shape, a.dtype),
+        jax.eval_shape(lambda: backend.init_arena(backend.max_streams)))
+    if which == "decode":
+        lanes_i, lanes_f = place((256,), jnp.int32), place((256,),
+                                                           jnp.float32)
+        step = jax.jit(
+            spans.named_step(backend.decode_fn(), spans.STEP_DECODE),
+            donate_argnums=backend.donate_argnums,
+            static_argnums=backend.decode_static_argnums)
+        lowered = step.lower(params, arena, lanes_i, lanes_i, lanes_i,
+                             lanes_f, lanes_i, lanes_f, False)
+    else:
+        lane_i, lane_f = place((1,), jnp.int32), place((1,), jnp.float32)
+        step = jax.jit(
+            spans.named_step(backend.prefill_fn(), spans.STEP_PREFILL),
+            donate_argnums=backend.donate_argnums,
+            static_argnums=backend.prefill_static_argnums)
+        lowered = step.lower(params, arena, lane_i,
+                             place((1, 512), jnp.int32), lane_i, lane_i,
+                             lane_f, lane_i, lane_f, False, lane_i)
+    compiled = lowered.compile()
+    return compiled.as_text(), arena, compiled.memory_analysis(), backend
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_state_attention_and_expert_blocks_compile_at_published_widths(
+        one_chip, monkeypatch, which):
+    """At the cell's widths (2688; 64 state heads of 64 x 128 in 8 groups,
+    packed two a row; 32 query heads over 2 key heads of 128; 64 held
+    un-gated experts of 1856 and a shared one of 3712; 65536 ids; 256 + 1
+    slots of 4096): a wave is six state calls, two grouped-query decode calls
+    and ten grouped matmuls (an expert is two matrices), a piece a flash call
+    for every count of rows before it (8 an attention layer) and the chunked
+    form in plain XLA.  Neither program copies a weight (the experts' ``[64,
+    1856, 2688]`` leaves, ``W_in``'s three blocks, the head) or writes a
+    state, tail or row leaf out again: the donated arena's four leaves are
+    updated in place, a piece's slot of state among them."""
+    text, arena, memory, backend = _nemotron_program(one_chip, monkeypatch,
+                                                     which)
+    calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
+    assert calls.count("grouped_matmul") == 10
+    if which == "decode":
+        assert calls.count("ssd_wave_update") == 6
+        assert calls.count("decode_wave_attention") == 2
+        # 256 tokens, a record row a lane and the wave's three counts.
+        assert f"s32[{256 + 256 * backend.stream_record + 3}]" in text
+    else:
+        assert calls.count("flash_attention") == 2 * 8
+        assert "ssd_wave_update" not in calls
+    weights = (r"64,1856,2688|2688,4096|2688,6144|4096,2688|2688,3712"
+               r"|3712,2688|65536,2688|2688,65536")
+    leaves = r"6,257,32,128,128|6,257,18432|2,257,4096,256"
+    moved = _written_out_again(text, weights + "|" + leaves)
+    assert not moved, moved
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    cache = sum(math.prod(arena[k].shape) * arena[k].dtype.itemsize
+                for k in ("s", "conv", "k", "v"))
+    assert memory.alias_size_in_bytes >= cache
+    # A wave's temporaries are its activations (16 MB), a piece's the sorted
+    # layout's 7104 rows and the chunks' pairwise decays (73 MB): far under
+    # the 3.2 GB state leaf or one expert leaf's 0.64 GB, which this program
+    # copied whole before its leaves lay as they do (PERF.md section 6, PR
+    # 45).
+    assert memory.temp_size_in_bytes < 0.2e9, memory
+    assert 13.2e9 < memory.argument_size_in_bytes < 13.4e9
+
+
 # The three served configurations' cache leaves: slots, rows a slot, lanes a
 # row, dtype; a full wave's lanes, query heads, key heads, the head's width.
 _SERVED_LEAVES = {
