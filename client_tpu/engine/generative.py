@@ -25,7 +25,10 @@ generation streams. Design, TPU-first:
   at a time, the cache carrying the state between pieces; between two decode
   waves at most one piece is dispatched, so a token gap is bounded by a wave
   plus a piece, and the first token follows the last piece.  One compiled
-  prefill program, whatever the prompt's length.
+  prefill program a lane count (every power of two up to the backend's
+  ``lanes``, all warmed with the model), whatever the prompt's length; a
+  piece runs with the smallest count that holds the prompts standing in
+  line, and a lone prompt's piece goes at once, in the one-lane program.
 - **Transitions** (a backend that declares ``transition_due(n)`` and
   ``transition_fn()``): a stream whose dispatch-side length ``n`` is due has
   the jitted transition queued before its next wave (span
@@ -179,11 +182,12 @@ class _Inflight:
     """One dispatched execution whose token fetch is pending."""
 
     __slots__ = ("kind", "streams", "tokens", "waves", "t_disp", "bucket",
-                 "depth", "positions", "rows", "pieces", "fresh", "by_kind")
+                 "depth", "positions", "rows", "pieces", "fresh", "by_kind",
+                 "lanes")
 
     def __init__(self, kind, streams, tokens, waves=1, t_disp=0, bucket=0,
                  depth=0, positions=0, rows=(0, 0), pieces=(), fresh=(),
-                 by_kind=(0, 0, 0)):
+                 by_kind=(0, 0, 0), lanes=0):
         self.kind = kind          # 'prefill' | 'piece' | 'wave' | 'chunk'
         self.streams = streams    # lane order, real lanes only
         self.tokens = tokens      # jax.Array future (copy_to_host_async'd)
@@ -197,6 +201,7 @@ class _Inflight:
         self.fresh = fresh        # a wave's lanes that decode their first token
         self.by_kind = by_kind    # rows it reads: (ring, whole-context), and
         #                           its lanes past the ring
+        self.lanes = lanes        # a piece call's compiled lanes
 
 
 class _WarmupReq:
@@ -456,10 +461,12 @@ class GenerativeScheduler(Scheduler):
         only where the next one's program holds more tokens than
         ``_LANE_WORTH_TOKENS``, so where dropping its padded lanes saves the
         device more than the program costs a launch.  A backend that
-        prefills by pieces keeps its own lanes."""
+        prefills by pieces has every power of two up to its own lanes: a
+        piece runs with the smallest that holds the prompts in line, since a
+        lane with no prompt costs a piece's whole mixers for nothing."""
         lanes = power_buckets(self._admit_lane)
         if self._piece_len:
-            return lanes[-1:]
+            return lanes
         return [lane for lane, above in zip(lanes, lanes[1:])
                 if above * bucket > _LANE_WORTH_TOKENS] + lanes[-1:]
 
@@ -852,7 +859,8 @@ class GenerativeScheduler(Scheduler):
         return True
 
     def _stage_and_dispatch_piece(self, todo: list) -> None:
-        lane, width = self._piece_lanes, self._piece_len
+        width = self._piece_len
+        lane = next(b for b in self._ladders[width] if b >= len(todo))
         ids_mat = np.zeros((lane, width), np.int32)
         lens = np.ones(lane, np.int32)
         starts = np.zeros(lane, np.int32)
@@ -861,6 +869,8 @@ class GenerativeScheduler(Scheduler):
             ids_mat[i, :len(part)] = part
             lens[i], starts[i] = len(part), s.consumed
         rows, seeds, temps, top_ks, top_ps = self._stage_lanes(todo, lane)
+        sample = bool((temps > 0.0).any())
+        self._warm_ladder(width, sample, but=lane)
         self.model._set_state(
             f"generative prefill piece ({len(todo)} streams, from "
             f"{[int(x) for x in starts[:len(todo)]]})",
@@ -869,8 +879,7 @@ class GenerativeScheduler(Scheduler):
             with self._rec.span[_sp.S_PREFILL_DISPATCH]:
                 self._arena, tokens = self._prefill(
                     self.model._params, self._arena, rows, ids_mat, lens,
-                    seeds, temps, top_ks, top_ps,
-                    bool((temps > 0.0).any()), starts)
+                    seeds, temps, top_ks, top_ps, sample, starts)
             tokens.copy_to_host_async()
         finally:
             self.model._clear_state()
@@ -893,7 +902,7 @@ class GenerativeScheduler(Scheduler):
         # lane whose prompt goes on carries no stream (its token is junk).
         self._inflight.append(_Inflight(
             "prefill" if any(s is not _NO_STREAM for s in done) else "piece",
-            done, tokens, depth=self._inflight_waves,
+            done, tokens, depth=self._inflight_waves, lanes=lane,
             pieces=list(zip(todo, lens.tolist())) if self._record else ()))
         self._inflight_waves += 1
 
@@ -1141,7 +1150,7 @@ class GenerativeScheduler(Scheduler):
         """A fetch's tokens, its positions' rows of the streams' records cut
         off behind them (``[lanes | lanes x positions x stream_record]``, a
         chunk's one such row a wave); the streams that asked keep theirs."""
-        lanes = head.bucket or self._piece_lanes
+        lanes = head.bucket or head.lanes
         rows = toks[..., lanes:]
         if head.pieces:
             rows = rows.reshape(lanes, -1, self._record)

@@ -54,7 +54,8 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
 - ``arena_rows(capacity)`` -> (free rows, dummy row) and ``kv_shards`` (1).
 - ``prefill_piece``: ``None`` (a whole prompt a lane, one program a prompt
   bucket) or ``(positions, lanes)`` (a prompt is consumed ``positions`` a
-  piece, one program).
+  piece; ``prefill_fn()`` is called with every power of two of lanes up to
+  ``lanes``, a program each: the one that holds the prompts in line).
 - ``cache_rows``: ``None`` (a step reads one row a position) or ``(n) ->
   (summary rows, exact rows)`` a step at context length ``n`` reads.
 - ``cache_rows_by_kind``: ``None``, or ``(n) -> (ring rows, other rows, past)``:

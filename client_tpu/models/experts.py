@@ -64,6 +64,8 @@ _BLOCK = 1 << 17          # elements made at a time (cache-sized)
 # Rows of a grouped matmul's tile: a wave's groups are a few rows (16 is
 # bfloat16's sublane tile), a prefill piece's some dozens.
 TILE_M_WAVE, TILE_M_PIECE = 16, 64
+# Tokens whose pairs come back from the sorted layout in one gather.
+BACK_ROWS = 512
 # Logits of a row's first ids in a stream's record, beside its token's.
 RECORD_LOGITS = 8
 
@@ -309,9 +311,16 @@ class ExpertDecoder(DecoderBackend):
         # never read.
         dest = plan["dest"].reshape(n, k)
         got = dest < rows
-        picked = ys[jnp.where(got, dest, 0)]                  # [n, k, d]
-        y = jnp.sum(jnp.where(got[..., None], picked, 0.0)
-                    * weights[..., None], axis=1)
+
+        def back(part):
+            picked = ys[jnp.where(got[part], dest[part], 0)]   # [., k, d]
+            return jnp.sum(jnp.where(got[part][..., None], picked, 0.0)
+                           * weights[part][..., None], axis=1)
+
+        # (The gather of more tokens' pairs at once than a piece holds reads
+        # three times slower a token on the v5e: PERF.md section 6, PR 47.)
+        y = back(...) if n <= BACK_ROWS else jnp.concatenate(
+            [back(slice(i, i + BACK_ROWS)) for i in range(0, n, BACK_ROWS)])
         sizes = plan["sizes"]
         counts = jnp.stack([sizes.sum(), sizes.max(),
                             (sizes > 0).sum()]).astype(jnp.int32)
@@ -375,32 +384,40 @@ class ExpertDecoder(DecoderBackend):
             + [logit_bits(logits, tokens, RECORD_LOGITS)], axis=1)
 
     def prefill_fn(self):
-        """``PREFILL_ARGS`` -> (arena, tokens[1]): one **piece** of the
-        lane's prompt (``piece_hidden_fn``: (arena, x ``[piece, d]``, choices
-        ``[expert layers, piece, top_k]``)); the token sampled after its last
-        valid position lands in the slot's device-side token, and means
-        something for a prompt's last piece only.  With ``stream_record`` the
-        piece's rows of the record follow the token, ``[1 + piece x
-        stream_record]``."""
+        """``PREFILL_ARGS`` -> (arena, tokens[L]): one **piece** of each
+        lane's prompt (``piece_hidden_fn``: (arena, x ``[L * piece, d]``,
+        choices ``[expert layers, L * piece, top_k]``, lane after lane)); the
+        token sampled after a lane's last valid position lands in its slot's
+        device-side token, and means something for a prompt's last piece
+        only.  With ``stream_record`` the pieces' rows of the record follow
+        the tokens, ``[L + L x piece x stream_record]``."""
         piece = self.piece_hidden_fn()
 
         def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
                     sample, starts):
             import jax.numpy as jnp
 
+            lanes = rows.shape[0]
             arena, x, routes = piece(p, arena, rows, ids, lens, starts)
-            logits = self._logits(p, x[lens - 1])
+            # Each lane's last valid row of x.  (One lane keeps the lines the
+            # recorded programs of the backends that hold one were lowered
+            # from: tests/test_served_programs.py.)
+            at = lens - 1 if lanes == 1 else (
+                lens - 1 + self.piece * np.arange(lanes, dtype=np.int32))
+            logits = self._logits(p, x[at])
             arena, tokens = sample_into_slots(
                 arena, rows, logits, seeds, starts + lens, temps, top_ks,
                 top_ps, sample)
             if not self.stream_record:
                 return arena, tokens
-            last = jnp.arange(self.piece) == lens[0] - 1
-            rec = jnp.concatenate(
-                [self._words(r) for r in routes]
-                + [jnp.where(last[:, None],
-                             logit_bits(logits, tokens, RECORD_LOGITS), 0)],
-                axis=1)
+            last = (jnp.arange(self.piece) == lens[0] - 1 if lanes == 1
+                    else jnp.arange(lanes * self.piece)
+                    == jnp.repeat(at, self.piece))
+            words, last = [self._words(r) for r in routes], last[:, None]
+            bits = logit_bits(logits, tokens, RECORD_LOGITS)
+            if lanes > 1:
+                bits = jnp.repeat(bits, self.piece, axis=0)
+            rec = jnp.concatenate(words + [jnp.where(last, bits, 0)], axis=1)
             return arena, jnp.concatenate([tokens, rec.reshape(-1)])
 
         return prefill

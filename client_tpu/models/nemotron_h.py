@@ -37,11 +37,15 @@ them, in the model's dtype, one row a slot), a \\* layer a ``"rows"`` layer
 leaf, no mixer.  **Decode** advances a wave's states in place
 (``ssd_wave_update``, or its oracle where the arena is not the kernels') and
 reads the lanes' rows with the grouped-query decode kernel.  **Prefill** is by
-pieces (``prefill_piece``), one prompt a call: an M layer runs the chunked
-form (``ssd_chunk_scan``) from the slot's state and tail and writes both back
-(a padded position has ``dt = 0``: it moves nothing, and the tail is that of
-the last valid positions; a prompt's first piece starts from zeros); a \\*
-layer is models/grouped_query.py's.
+pieces (``prefill_piece``), one prompt or two a call: every
+matrix product over positions (the projections, the shared expert, and above
+all the held experts' grouped matmuls) sees all lanes' positions as one batch
+and reads its weights once; the mixers run a lane at a time: an M layer runs
+the chunked form (``ssd_chunk_scan``) from the lane's slot's state and tail
+and writes both back (a padded position has ``dt = 0``: it moves nothing, and
+the tail is that of the last valid positions; a prompt's first piece starts
+from zeros); a \\* layer is models/grouped_query.py's, a lane's own count
+of rows before it.
 
 The projection's ``xBC`` is rounded to the model's dtype before the
 convolution, in a wave and in a piece alike: the tail a slot carries is then
@@ -130,7 +134,10 @@ class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
         self.dtype = str(dtype)
         self._seed = seed
         self._check_experts()
-        self.prefill_piece = (self.piece, 1)
+        # Two prompts a piece program at most (what was measured: PERF.md
+        # section 6, PR 47); the scheduler runs the smallest compiled count
+        # that holds those standing in line.
+        self.prefill_piece = (self.piece, 2)
         self.stream_record = record_width(
             self.layer_kinds.count("none") * self.held_words) if record else 0
 
@@ -377,68 +384,94 @@ class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
                               dt),
             "tok": jnp.zeros(r, jnp.int32)}
 
-    def _piece_state_layer(self, lp, s_a, conv_a, ki, row, fresh, n_valid,
-                           x):
-        """An M layer's part of a piece: the chunked form from the slot's
-        state and tail (zeros for a prompt's first piece), both written
-        back.  -> (s_a, conv_a, o ``[piece, d_inner]``)."""
+    def _piece_state_layer(self, lp, s_a, conv_a, ki, rows, fresh, lens, x):
+        """An M layer's part of a piece of ``L`` lanes, x ``[L * piece, d]``:
+        the projections over every lane's positions at once, then a lane at a
+        time the convolution, the chunked form from its slot's state and tail
+        (zeros for a prompt's first piece), both written back, and the gated
+        norm.  -> (s_a, conv_a, o ``[L * piece, d_inner]``)."""
         import jax
         import jax.numpy as jnp
 
         from client_tpu.ops.ssd import ssd_chunk_scan
 
         n = self.piece
-        valid = jnp.arange(n) < n_valid
         z, new, dt = self._ssm_project(lp, x, conv_a.dtype)
-        tail = jnp.where(fresh, 0, conv_a[ki, row]).reshape(-1, self.conv_dim)
-        ext = jnp.concatenate([tail, new])
-        xs, b, c = self._ssm_inputs(lp, ext, n)
-        y, s = ssd_chunk_scan(
-            xs, jnp.where(valid[:, None], dt, 0.0), -jnp.exp(lp["a_log"]),
-            b, c, jnp.where(fresh, 0.0, s_a[ki, row]), chunk=self.chunk)
-        s_a = jax.lax.dynamic_update_slice(
-            s_a, s.astype(s_a.dtype)[None, None], (ki, row, 0, 0, 0))
-        # The inputs of the last valid positions (with the old tail's, where
-        # the piece holds fewer than a tail).
-        tail = jax.lax.dynamic_slice(ext, (n_valid, 0),
-                                     (self.taps - 1, self.conv_dim))
-        conv_a = jax.lax.dynamic_update_slice(
-            conv_a, tail.reshape(1, 1, -1), (ki, row, 0))
-        return s_a, conv_a, self._ssm_output(lp, y, xs, z)
+        a, outs = -jnp.exp(lp["a_log"]), []
+        for i in range(rows.shape[0]):
+            own = slice(i * n, (i + 1) * n)
+            valid = jnp.arange(n) < lens[i]
+            tail = jnp.where(fresh[i], 0, conv_a[ki, rows[i]]).reshape(
+                -1, self.conv_dim)
+            ext = jnp.concatenate([tail, new[own]])
+            xs, b, c = self._ssm_inputs(lp, ext, n)
+            y, s = ssd_chunk_scan(
+                xs, jnp.where(valid[:, None], dt[own], 0.0), a, b, c,
+                jnp.where(fresh[i], 0.0, s_a[ki, rows[i]]), chunk=self.chunk)
+            s_a = jax.lax.dynamic_update_slice(
+                s_a, s.astype(s_a.dtype)[None, None], (ki, rows[i], 0, 0, 0))
+            # The inputs of the last valid positions (with the old tail's,
+            # where the piece holds fewer than a tail).
+            tail = jax.lax.dynamic_slice(ext, (lens[i], 0),
+                                         (self.taps - 1, self.conv_dim))
+            conv_a = jax.lax.dynamic_update_slice(
+                conv_a, tail.reshape(1, 1, -1), (ki, rows[i], 0))
+            outs.append(self._ssm_output(lp, y, xs, z[own]))
+        return s_a, conv_a, jnp.concatenate(outs)
 
     def piece_hidden_fn(self):
-        """(params, arena, rows[1], ids[1, piece], lens[1], starts[1]) ->
-        (arena, x ``[piece, d]``, choices ``[expert layers, piece, top_k]``):
-        one prefill piece, positions ``starts .. starts + lens`` of the
-        lane's prompt (``starts`` a multiple of the piece)."""
+        """(params, arena, rows[L], ids[L, piece], lens[L], starts[L]) ->
+        (arena, x ``[L * piece, d]``, choices ``[expert layers, L * piece,
+        top_k]``), lane after lane: one prefill piece of each of ``L``
+        prompts, positions ``starts .. starts + lens`` of a lane's prompt
+        (``starts`` a multiple of the piece).  Whatever is a matrix product
+        over positions sees all lanes' positions as one batch, so a weight,
+        and above all a layer's held experts, is read once a program; the
+        mixers run a lane at a time, each from its own slot."""
         import jax.numpy as jnp
 
         n = self.piece
         hd = self.n_kv_heads * self.head_dim
 
         def piece(p, arena, rows, ids, lens, starts):
-            row, start = rows[0], starts[0]
-            live = jnp.arange(n) < lens[0]
+            lanes = rows.shape[0]
+            at = jnp.arange(n)
+            live = (at < lens[:, None]).reshape(-1)
+            # The sorted layout's tile from the rows this call holds: half a
+            # piece's where an expert's mean share of the call's (token,
+            # expert) pairs fits in half.  (On the v5e at the cell's widths,
+            # ms a program by tile, PERF.md section 6, PR 47: a share of 24
+            # rows 15.20 | 13.70 | 13.96 in tiles of 16 | 32 | 64, of 48 rows
+            # 22.56 | 22.49 | 24.4 in 32 | 64 | 128.)
+            share = lanes * n * self.top_k / self.n_experts
+            half = TILE_M_PIECE // 2
+            tile_m = half if share <= half else TILE_M_PIECE
             k_a, v_a = arena["k"], arena["v"]
             s_a, conv_a = arena["s"], arena["conv"]
-            x = p["embed"][ids[0]].astype(jnp.float32)
+            x = p["embed"][ids.reshape(-1)].astype(jnp.float32)
             routes = []
             for li, lp in enumerate(p["layers"]):
                 kind, ki = self._layer_kind(li)
                 if kind == "none":
-                    x, _, top_i = self._expert_block(lp, x, live,
-                                                     TILE_M_PIECE)
+                    x, _, top_i = self._expert_block(lp, x, live, tile_m)
                     routes.append(top_i)
                     continue
                 if kind == "state":
                     s_a, conv_a, o = self._piece_state_layer(
-                        lp, s_a, conv_a, ki, row, start == 0, lens[0], x)
+                        lp, s_a, conv_a, ki, rows, starts == 0, lens, x)
                 else:
-                    q, k, v = self._project(lp, x, start + jnp.arange(n))
-                    own_k, own_v = (t.reshape(n, hd).astype(k_a.dtype)
+                    q, k, v = self._project(
+                        lp, x, (starts[:, None] + at).reshape(-1))
+                    own_k, own_v = (t.reshape(-1, hd).astype(k_a.dtype)
                                     for t in (k, v))
-                    k_a, v_a, o = self._piece_rows(k_a, v_a, ki, row, start,
-                                                   q, own_k, own_v)
+                    outs = []
+                    for i in range(lanes):
+                        own = slice(i * n, (i + 1) * n)
+                        k_a, v_a, o = self._piece_rows(
+                            k_a, v_a, ki, rows[i], starts[i], q[own],
+                            own_k[own], own_v[own])
+                        outs.append(o)
+                    o = jnp.concatenate(outs)
                 x = x + self._mm(o, lp["wo"])
             return ({**arena, "k": k_a, "v": v_a, "s": s_a, "conv": conv_a},
                     x, jnp.stack(routes))

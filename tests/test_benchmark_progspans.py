@@ -544,3 +544,50 @@ class TestGapReaders:
 
         assert set(GAP_BEFORE) <= set(spans.GEN_COUNTERS)
         assert "gen.prefill_stage" in spans.GEN_SPANS
+
+
+# -- PR 47's reader: the prompts a piece program held ---------------------------
+
+LANES = "prefill_lanes_per_call.obs"
+# (pieces, programs) at the window's two ends -> the value by hand.
+LANE_WINDOWS = {
+    "every_piece_alone": ((40, 40), (1040, 1040), 1.0),
+    "half_the_pieces_paired": ((40, 40), (1240, 840), 1.5),
+    "a_one_shot_prefill_counts_no_piece": ((0, 40), (0, 1040), None),
+    "no_piece_in_the_window": ((40, 40), (40, 40), None),
+}
+
+
+class TestLanesPerCall:
+    @pytest.mark.parametrize("window", sorted(LANE_WINDOWS))
+    def test_pieces_over_programs(self, window):
+        """``prefill_pieces`` counts a lane's piece each, the span
+        ``gen.prefill_dispatch`` a program each; nothing where no piece was
+        counted (a one-shot prefill's programs are not pieces)."""
+        (p0, c0), (p1, c1), want = LANE_WINDOWS[window]
+        ctx = {"snap_before": snap({"gen.prefill_dispatch": (c0, 9)},
+                                   {"prefill_pieces": p0}),
+               "snap_after": snap({"gen.prefill_dispatch": (c1, 99)},
+                                  {"prefill_pieces": p1})}
+        got = run_reader(LANES)(ctx)
+        assert got is None if want is None else got == pytest.approx(want)
+
+    def test_nothing_from_a_program_without_the_profile(self):
+        read = run_reader(LANES)
+        assert read({"snap_before": None, "snap_after": None}) is None
+        bare = {"t": 0.0, "stats": {}, "profile": {"models": {"gpt:1": {
+            "decode_waves": []}}}}
+        assert read({"snap_before": bare, "snap_after": bare}) is None
+
+    def test_the_manifest_holds_it_last_for_the_cells_that_prefill_by_pieces(
+            self):
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+        last = manifest["per_layer"][-1]
+        stage = next(m for m in manifest["per_layer"]
+                     if m["name"] == "prefill_stage_ms_mean.itl")
+        assert last == {"name": LANES, "unit": "lanes", "better": "higher",
+                        "source": "program_counter",
+                        "layer": "generative scheduler",
+                        "moves": "itl_mean_ms",
+                        "workloads": stage["workloads"]}

@@ -557,6 +557,74 @@ class TestAPromptsTwoWaits:
         assert "prefill" in names and "prefill_wait" not in names
 
 
+# -- PR 47: a piece program of as many lanes as prompts stand in line -----------
+
+LANES = "spans_eva_two"  # pieces of 32 positions, two prompts a program at most
+
+
+@pytest.fixture(scope="module")
+def lane_engine():
+    from client_tpu.models.evabyte import EvaByteBackend
+
+    repo = ModelRepository()
+    repo.register_backend(EvaByteBackend(
+        name=LANES, seed=3, max_seq_len=128, window=32, chunk=4,
+        prefill_lanes=2))
+    eng = TpuEngine(repo)
+    eng._schedulers[LANES].warmup()
+    yield eng, eng.profile_snapshot(model=LANES)["compiles"]["count"]
+    eng.shutdown()
+
+
+# Prompts admitted in one batch (their lengths) -> the lanes of each piece
+# program, in order: the two oldest prompts go paired while both have pieces
+# left, a prompt with nobody beside it goes alone and at once.
+LINES = {
+    "one_waits": ([80], [1, 1, 1]),
+    "two_wait": ([80, 80], [2, 2, 2]),
+    "three_wait": ([80, 80, 80], [2, 2, 2, 1, 1, 1]),
+    "a_short_one_beside_a_long_one": ([20, 80], [2, 1, 1]),
+    "the_third_steps_up": ([20, 80, 30], [2, 2, 1]),
+}
+
+
+class TestPieceLanes:
+    @pytest.mark.parametrize("line", sorted(LINES))
+    def test_a_piece_program_holds_the_prompts_that_wait(self, lane_engine,
+                                                         line):
+        """``prefill_pieces`` counts lanes' pieces and ``gen.prefill_dispatch``
+        programs: a backend of two lanes runs its one-lane program when one
+        prompt stands in line and its two-lane one when two do, every prompt
+        gets the tokens it gets alone, and nothing compiles after the
+        warm-up, which ran both lane counts."""
+        eng, compiles = lane_engine
+        lengths, want = LINES[line]
+        prompts = [list(range(1 + i, 1 + i + n))
+                   for i, n in enumerate(lengths)]
+        alone = [_stream(eng, p, 4, model=LANES)() for p in prompts]
+        before = _gen(eng, LANES)
+        _held_batch(eng, LANES, prompts, 4)
+        after = _gen(eng, LANES)
+        d = _delta(before, after)
+        calls = after["spans"][spans.GEN_PREFILL_DISPATCH]["count"] \
+            - before["spans"][spans.GEN_PREFILL_DISPATCH]["count"]
+        assert d["prefill_pieces"] == sum(want)
+        assert calls == len(want)
+        assert d["prefill_positions_valid"] == sum(lengths)
+        assert d["first_tokens"] == len(prompts)
+        together = [_stream(eng, p, 4, model=LANES) for p in prompts]
+        assert [j() for j in together] == alone
+        assert eng.profile_snapshot(model=LANES)["compiles"]["count"] \
+            == compiles
+
+    def test_the_ladder_is_the_powers_of_two_up_to_the_backends_lanes(
+            self, lane_engine, piece_engine):
+        """A backend of one lane has a ladder of one: the program it ran
+        before there was a ladder, and no other."""
+        assert lane_engine[0]._schedulers[LANES]._ladders == {32: [1, 2]}
+        assert piece_engine._schedulers[PIECE]._ladders == {32: [1]}
+
+
 class _Ready:
     """A fetched token array as ``_drain_fetches`` takes one."""
 

@@ -58,12 +58,17 @@ def test_the_ladder_exists_only_where_a_lane_is_worth_a_program(served):
     assert sum(len(v) - 1 for v in sched._ladders.values()) == 1
     assert sched._lane_ladder(2048) == [2, 4, 8]
     assert sched._lane_ladder(8192) == [1, 2, 4, 8]
-    # a backend that prefills by pieces keeps its own lanes
+    # a backend that prefills by pieces has every power of two up to its
+    # own lanes, whatever the piece holds (a lane with no prompt costs a
+    # piece's mixers for nothing); one lane is a ladder of one
     sched._piece_len = 2048
     try:
-        assert sched._lane_ladder(2048) == [8]
+        assert sched._lane_ladder(2048) == [1, 2, 4, 8]
+        assert sched._lane_ladder(16) == [1, 2, 4, 8]
+        sched._admit_lane = 1
+        assert sched._lane_ladder(2048) == [1]
     finally:
-        sched._piece_len = 0
+        sched._piece_len, sched._admit_lane = 0, 8
 
 
 @pytest.mark.parametrize("lanes", [1, 2, 4])

@@ -39,9 +39,16 @@ gave ``models/decoder.py`` the ``"none"`` layer kind and ``models/experts.py``
 the expert's form, and lifted a wave's tails (``slot_tails``), a piece's
 grouped-query attention (models/grouped_query.py) and a stream's record out
 of ``kimi_linear.py`` and ``smallthinker.py``: the other five families'
-twenty hashes did not move.  A PR that
-means to change one of these programs records the new hash here and says so;
-one that does not has a guard.
+twenty hashes did not move; ``nemotron``'s piece programs at the tree PR 47
+left, whose piece holds as many prompts as its backend declares lanes (two:
+``prefill``, the program of both lanes; ``prefill_1``, the one-lane program
+of its ladder): the projections
+and the expert block over all lanes' positions at once, the mixers a lane at
+a time.  ``models/experts.py`` ``prefill_fn`` takes any lane count for it and
+lowers to the recorded programs for the three other families that prefill
+through it with one lane: the other eleven pairs of hashes did not move.  A
+PR that means to change one of these programs records the new hash here and
+says so; one that does not has a guard.
 
     python - <<'X'          # to record: run from the repo root
     import tests.test_served_programs as t; t.record()
@@ -63,7 +70,8 @@ RECORDED = {
     ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),
     ("kimi", "prefill"): ("93c6ccfe91d5a268", "5dd18e6e271299fb"),
     ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),
-    ("nemotron", "prefill"): ("8582c0278c7cfb1e", "34bbc0cdbc93323f"),
+    ("nemotron", "prefill"): ("412e3a406d01a832", "036da1223ab7372f"),
+    ("nemotron", "prefill_1"): ("2e42a7ed9a4722d7", "72f715ae5cf8c153"),
     ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),
     ("pangu", "prefill"): ("2947c2e62c0d448d", "fa56a8062981bfe9"),
     ("smallthinker", "decode"): ("44c3bd43dd161186", "93891480d1ba5ee1"),
@@ -123,8 +131,9 @@ def _backend(family):
 
 
 def _program(family, which):
-    """(function, static and donated argument numbers, abstract
-    arguments)."""
+    """(function, static and donated argument numbers, abstract arguments);
+    ``which``: ``decode``, ``prefill`` (a piece backend's declared lanes) or
+    ``prefill_<lanes>``."""
     be = _backend(family)
     if family in ("pangu", "kimi", "smallthinker", "nemotron"):
         # (made when asked for)
@@ -150,6 +159,8 @@ def _program(family, which):
             False)
     piece = be.prefill_piece
     lanes, width = (piece[1], piece[0]) if piece else (2, 64)
+    if which.startswith("prefill_"):    # another lane count of its ladder
+        lanes = int(which.split("_")[1])
     args = (params, arena, i32(lanes), i32(lanes, width), i32(lanes),
             i32(lanes), f32(lanes), i32(lanes), f32(lanes), False)
     return be.prefill_fn(), (be.prefill_static_argnums,
