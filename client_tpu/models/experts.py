@@ -41,7 +41,8 @@ form).  What does not differ between them lives here, in
   ``prefill_fn`` leave ``held_words`` words an expert layer and a few logits
   behind a program's tokens (``stream_record`` of the decoder's contract),
   for a model whose ``piece_hidden_fn`` returns a piece's choices
-  (models/latent_moe.py keeps its one-word form).
+  (models/latent_moe.py keeps its one-word form: its own ``_record`` and
+  ``_piece_words``).
 
 A model sets ``d_model, d_expert, n_experts, experts_held, first_expert,
 top_k, routed_scale, dtype, rms_eps, _seed`` and, where they differ from the
@@ -262,6 +263,21 @@ class ExpertDecoder(DecoderBackend):
         expert): h ``[n, d]`` -> ``[n, d]`` float32."""
         return self._mm(self._between(self._mm(h, up)), down)
 
+    def _piece_tile(self, tokens: int) -> int:
+        """The sorted layout's tile for a piece call of ``tokens`` positions:
+        half a piece's where a held expert's mean share of the call's (token,
+        expert) pairs fits in half; a tile over the share only lengthens the
+        layout that every operation around the products walks.  (On the v5e
+        at the cells' widths, ms a program by tile, PERF.md section 6: 64 of
+        128 experts of 1856 held, 6 a token, PR 47: a share of 24 rows 15.20
+        | 13.70 | 13.96 in tiles of 16 | 32 | 64, of 48 rows 22.56 | 22.49 |
+        24.4 in 32 | 64 | 128; 32 of 256 experts of 1024 held, 8 a token, PR
+        48: a share of 16 rows 18.06 | 17.36 | 17.47 in 16 | 32 | 64, of 32
+        rows 32.19 | 33.51 in 32 | 64.)"""
+        share = tokens * self.top_k / self.n_experts
+        half = TILE_M_PIECE // 2
+        return half if share <= half else TILE_M_PIECE
+
     def _experts(self, lp, h, live, tile_m, routing=None):
         """The held experts' part of the layer for tokens h ``[n, d]``:
         ``sum_i w_i E_i(h)`` over the chosen experts held here (``E`` by
@@ -318,7 +334,9 @@ class ExpertDecoder(DecoderBackend):
                            * weights[part][..., None], axis=1)
 
         # (The gather of more tokens' pairs at once than a piece holds reads
-        # three times slower a token on the v5e: PERF.md section 6, PR 47.)
+        # three times slower a token on the v5e at 2688 lanes and 6 choices,
+        # and as fast as the cut one at 2304 and 8: PERF.md section 6, PR 47
+        # and 48.)
         y = back(...) if n <= BACK_ROWS else jnp.concatenate(
             [back(slice(i, i + BACK_ROWS)) for i in range(0, n, BACK_ROWS)])
         sizes = plan["sizes"]
@@ -383,6 +401,11 @@ class ExpertDecoder(DecoderBackend):
             [self._words(r) for r in x["route"]]
             + [logit_bits(logits, tokens, RECORD_LOGITS)], axis=1)
 
+    def _piece_words(self, routes):
+        """A piece's choices ``[expert layers, n, top_k]`` -> its record's
+        words, ``[n, held_words]`` a layer."""
+        return [self._words(r) for r in routes]
+
     def prefill_fn(self):
         """``PREFILL_ARGS`` -> (arena, tokens[L]): one **piece** of each
         lane's prompt (``piece_hidden_fn``: (arena, x ``[L * piece, d]``,
@@ -413,7 +436,7 @@ class ExpertDecoder(DecoderBackend):
             last = (jnp.arange(self.piece) == lens[0] - 1 if lanes == 1
                     else jnp.arange(lanes * self.piece)
                     == jnp.repeat(at, self.piece))
-            words, last = [self._words(r) for r in routes], last[:, None]
+            words, last = self._piece_words(routes), last[:, None]
             bits = logit_bits(logits, tokens, RECORD_LOGITS)
             if lanes > 1:
                 bits = jnp.repeat(bits, self.piece, axis=0)

@@ -35,11 +35,15 @@ a slot's last stream are masked by ``lens``; a state is not, so a prompt's
 first piece (``starts == 0``) starts from a zero state and a zero tail.
 **Decode** advances a wave's states in place (ops/kda.py ``kda_wave_update``,
 or its oracle where the arena is not the kernels').  **Prefill** is by pieces
-(``prefill_piece``), one prompt a call: a KDA layer runs the chunked form
-(``kda_chunk_scan``) from the slot's state and tail and writes both back (a
-padded position has ``g = 0, beta = 0``: it moves nothing, and the tail is
-that of the last valid positions); a latent layer holds its own ``lax.switch``
-over the count of rows before the piece.
+(``prefill_piece``), of one prompt or of two a call, as many as stand in line
+(this backend declares two lanes; ``models/pangu_moe.py`` keeps
+models/latent_moe.py's one): what multiplies positions by a large weight sees
+both prompts' rows as one batch, so a layer's held experts are read once a
+program; the mixers run a lane at a time, each from its own slot: a KDA layer
+runs the chunked form (``kda_chunk_scan``) from the slot's state and tail and
+writes both back (a padded position has ``g = 0, beta = 0``: it moves nothing,
+and the tail is that of the last valid positions); a latent layer holds its
+own ``lax.switch`` over the count of rows before a lane's piece.
 
 The projection's output is rounded to the model's dtype before the
 convolution, in a wave and in a piece alike: the tail a slot carries is then
@@ -128,6 +132,10 @@ class KimiLinearBackend(LatentMoeDecoder):
         # A stream may ask for its record (models/latent_moe.py).
         self.stream_record = record_width(self.n_layers - self.n_dense)
         self._latent_setup()
+        # Two prompts a piece program at most (what was measured: PERF.md
+        # section 6, PR 48); the scheduler runs the smallest compiled count
+        # that holds those standing in line.
+        self.prefill_piece = (self.piece, 2)
 
     # -- params --------------------------------------------------------------
 
@@ -333,62 +341,75 @@ class KimiLinearBackend(LatentMoeDecoder):
                               dt),
             "tok": jnp.zeros(r, jnp.int32)}
 
-    def _piece_state_layer(self, lp, s_a, conv_a, ki, row, fresh, n_valid,
-                           x):
-        """A KDA layer's part of a piece: the chunked form from the slot's
-        state and tail (zeros for a prompt's first piece), both written
-        back.  -> (s_a, conv_a, o ``[piece, H * d_v]``)."""
+    def _piece_state_layer(self, lp, s_a, conv_a, ki, rows, starts, lens, x):
+        """A KDA layer's part of a piece of ``L`` lanes, x ``[L * piece, d]``
+        (``rows, starts, lens``: a scalar a lane): ``wqkv`` over every lane's
+        positions at once, then a lane at a time the convolution, the gates
+        (``_kda_inputs``: its five small matrices are read a lane), the
+        chunked form from the slot's state and tail (zeros for a prompt's
+        first piece), both written back, and the heads' norm.  -> (s_a,
+        conv_a, o ``[L * piece, H * d_v]``)."""
         import jax
         import jax.numpy as jnp
 
         from client_tpu.ops.kda import kda_chunk_scan
 
         n, width = self.piece, conv_a.shape[-1] // (self.taps - 1)
-        valid = jnp.arange(n) < n_valid
         h = rms_norm(x, lp["ln1"], self.rms_eps)
-        tail = jnp.where(fresh, 0, conv_a[ki, row]).reshape(-1, width)
-        ext = jnp.concatenate(
-            [tail, self._mm(h, lp["wqkv"]).astype(conv_a.dtype)])
-        q, k, v, g, beta, gate = self._kda_inputs(lp, h, ext)
-        o, s = kda_chunk_scan(
-            q, k, v, jnp.where(valid[:, None, None], g, 0.0),
-            jnp.where(valid[:, None], beta, 0.0),
-            jnp.where(fresh, 0.0, s_a[ki, row]), chunk=self.chunk)
-        s_a = jax.lax.dynamic_update_slice(
-            s_a, s.astype(s_a.dtype)[None, None], (ki, row, 0, 0, 0))
-        # The inputs of the last valid positions (with the old tail's, where
-        # the piece holds fewer than a tail).
-        tail = jax.lax.dynamic_slice(ext, (n_valid, 0),
-                                     (self.taps - 1, width))
-        conv_a = jax.lax.dynamic_update_slice(
-            conv_a, tail.reshape(1, 1, -1), (ki, row, 0))
-        return s_a, conv_a, self._kda_output(lp, o, gate)
+        new, outs = self._mm(h, lp["wqkv"]).astype(conv_a.dtype), []
+        for i, (row, start, n_valid) in enumerate(zip(rows, starts, lens)):
+            own, fresh = slice(i * n, (i + 1) * n), start == 0
+            valid = jnp.arange(n) < n_valid
+            tail = jnp.where(fresh, 0, conv_a[ki, row]).reshape(-1, width)
+            ext = jnp.concatenate([tail, new[own]])
+            q, k, v, g, beta, gate = self._kda_inputs(lp, h[own], ext)
+            o, s = kda_chunk_scan(
+                q, k, v, jnp.where(valid[:, None, None], g, 0.0),
+                jnp.where(valid[:, None], beta, 0.0),
+                jnp.where(fresh, 0.0, s_a[ki, row]), chunk=self.chunk)
+            s_a = jax.lax.dynamic_update_slice(
+                s_a, s.astype(s_a.dtype)[None, None], (ki, row, 0, 0, 0))
+            # The inputs of the last valid positions (with the old tail's,
+            # where the piece holds fewer than a tail).
+            tail = jax.lax.dynamic_slice(ext, (n_valid, 0),
+                                         (self.taps - 1, width))
+            conv_a = jax.lax.dynamic_update_slice(
+                conv_a, tail.reshape(1, 1, -1), (ki, row, 0))
+            outs.append(self._kda_output(lp, o, gate))
+        return s_a, conv_a, jnp.concatenate(outs)
 
     def piece_hidden_fn(self):
-        """(params, arena, rows[1], ids[1, piece], lens[1], starts[1]) ->
-        (arena, x ``[piece, d]``, choices ``[expert layers, piece, top_k]``):
-        one prefill piece, positions ``starts .. starts + lens`` of the
-        lane's prompt (``starts`` a multiple of the piece)."""
+        """(params, arena, rows[L], ids[L, piece], lens[L], starts[L]) ->
+        (arena, x ``[L * piece, d]``, choices ``[expert layers, L * piece,
+        top_k]``), lane after lane: one prefill piece of each of ``L``
+        prompts, positions ``starts .. starts + lens`` of a lane's prompt
+        (``starts`` a multiple of the piece).  The embedding, ``wqkv``, the
+        latent layers' projections, ``wo`` and the feed-forward see all
+        lanes' positions as one batch: one plan and one pair of grouped
+        matmuls an expert layer, so the held experts are read once a
+        program; the mixers run a lane at a time, each from its own slot."""
         import jax.numpy as jnp
 
         n = self.piece
 
         def piece(p, arena, rows, ids, lens, starts):
-            row, start = rows[0], starts[0]
-            live = jnp.arange(n) < lens[0]
+            lanes = rows.shape[0]
+            live = (jnp.arange(n) < lens[:, None]).reshape(-1)
+            tile_m = self._piece_tile(lanes * n)
+            rows, starts, lens = ([t[i] for i in range(lanes)]
+                                  for t in (rows, starts, lens))
             c_a, s_a, conv_a = arena["c"], arena["s"], arena["conv"]
-            x = p["embed"][ids[0]].astype(jnp.float32)
+            x = p["embed"][ids.reshape(-1)].astype(jnp.float32)
             routes = []
             for li, lp in enumerate(p["layers"]):
                 kind, ki = self._layer_kind(li)
                 if kind == "rows":
-                    c_a, o = self._piece_latent_layer(lp, c_a, ki, row,
-                                                      start, x, None)
+                    c_a, o = self._piece_latent_layer(lp, c_a, ki, rows,
+                                                      starts, x, None)
                 else:
                     s_a, conv_a, o = self._piece_state_layer(
-                        lp, s_a, conv_a, ki, row, start == 0, lens[0], x)
-                x, _, route = self._after_rows(lp, x, o, live,
-                                               TILE_M_PIECE)
+                        lp, s_a, conv_a, ki, rows, starts, lens, x)
+                x, _, route = self._after_rows(lp, x, o, live, tile_m)
                 routes += route
             return ({**arena, "c": c_a, "s": s_a, "conv": conv_a}, x,
                     jnp.stack(routes))

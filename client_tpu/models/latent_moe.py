@@ -19,7 +19,11 @@ What does not differ between them lives here, in :class:`LatentMoeDecoder`:
   head q and k ``nope + rope`` wide, padded to whole tiles, v ``v_dim``).
   Piece i of a prompt has exactly ``i * piece`` rows before it, so a latent
   layer holds one branch a count (``lax.switch``) and computes nothing that is
-  masked; the switch is the layer's, not the model's;
+  masked; the switch is the layer's, not the model's.  A piece program holds
+  one prompt a call (``prefill_piece = (piece, 1)``, what
+  ``models/pangu_moe.py`` keeps) or as many as a model declares
+  (``models/kimi_linear.py``: two): the projections over every lane's rows at
+  once, the switch, the flash call and the rows' write a lane at a time;
 - **the expert layer** beside its shared expert (``_ffn``): the router, the
   held experts' grouped matmuls, the lazily made weights, the wave's carry and
   its three counters, the final norm and head, and the words of a stream's
@@ -49,7 +53,6 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.decoder import sample_into_slots
 from client_tpu.models.experts import (RECORD_LOGITS, TILE_M_PIECE,  # noqa: F401
                                        TILE_M_WAVE, ExpertDecoder,
                                        SeededWeight, record_width, rms_norm)
@@ -249,57 +252,39 @@ class LatentMoeDecoder(ExpertDecoder):
             "hqk,khd->qhd", jax.nn.softmax(s, -1),
             v.astype(jnp.float32)).reshape(n, h * self.v_dim)
 
-    def _piece_latent_layer(self, lp, c_a, li, row, start, x, pos):
-        """A latent layer's part of a piece: the piece's queries against the
-        slot's ``start`` rows before it and its own (one ``lax.switch``
-        branch a count of earlier rows: ``start`` is a multiple of the
-        piece), its rows written behind them.  ``li`` is the layer's index
-        into ``c_a``.  -> (c_a, o ``[piece, H * v_dim]``)."""
+    def _piece_words(self, routes):
+        """A piece's choices ``[expert layers, n, top_k]`` -> its record's
+        words ``[n, expert layers]``: one word a layer."""
+        return [self.held_mask(routes).T]
+
+    def _piece_latent_layer(self, lp, c_a, li, rows, starts, x, pos):
+        """A latent layer's part of a piece of ``L`` lanes, x ``[L * piece,
+        d]`` (``rows, starts``: a scalar a lane): the projections over every
+        lane's positions at once, then a lane at a time the piece's queries
+        against the slot's ``start`` rows before it and its own (one
+        ``lax.switch`` branch a count of earlier rows: ``start`` is a
+        multiple of the piece), its rows written behind them.  ``li`` is the
+        layer's index into ``c_a``.  -> (c_a, o ``[L * piece, H * v_dim]``)."""
         import jax
+        import jax.numpy as jnp
 
         n, w = self.piece, self.row_width
         q_nope, q_rope, c, k_r = self._queries_and_rows(lp, x, pos)
-        own = self._cache_rows_of(c, k_r, c_a.dtype)
+        rows_new, outs = self._cache_rows_of(c, k_r, c_a.dtype), []
+        for i, (row, start) in enumerate(zip(rows, starts)):
+            lane = slice(i * n, (i + 1) * n)
+            own = rows_new[lane]
 
-        def attend(pre):
-            before = jax.lax.dynamic_slice(
-                c_a, (li, row, 0, 0), (1, 1, pre, w))[0, 0]
-            return self._piece_attention(lp, q_nope, q_rope, own, before)
+            def attend(pre):        # (traced at once, by the switch below)
+                before = jax.lax.dynamic_slice(
+                    c_a, (li, row, 0, 0), (1, 1, pre, w))[0, 0]
+                return self._piece_attention(lp, q_nope[lane], q_rope[lane],
+                                             own, before)
 
-        o = jax.lax.switch(
-            start // n,
-            [lambda pre=i * n: attend(pre)
-             for i in range(self.max_seq_len // n)])
-        return jax.lax.dynamic_update_slice(
-            c_a, own[None, None], (li, row, start, 0)), o
-
-    def prefill_fn(self):
-        """``PREFILL_ARGS`` -> (arena, tokens[1]): one **piece** of the
-        lane's prompt; the token sampled after its last valid position lands
-        in the slot's device-side token, and means something for a prompt's
-        last piece only.  With ``stream_record`` the piece's rows of the
-        record follow the token, ``[1 + piece x stream_record]``."""
-        piece = self.piece_hidden_fn()
-
-        def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
-                    sample, starts):
-            import jax.numpy as jnp
-
-            arena, x, routes = piece(p, arena, rows, ids, lens, starts)
-            logits = self._logits(p, x[lens - 1])
-            arena, tokens = sample_into_slots(
-                arena, rows, logits, seeds, starts + lens, temps, top_ks,
-                top_ps, sample)
-            if not self.stream_record:
-                return arena, tokens
-            from client_tpu.models.decoder import logit_bits
-
-            last = jnp.arange(self.piece) == lens[0] - 1
-            rec = jnp.concatenate(
-                [self.held_mask(routes).T,
-                 jnp.where(last[:, None],
-                           logit_bits(logits, tokens, RECORD_LOGITS), 0)],
-                axis=1)
-            return arena, jnp.concatenate([tokens, rec.reshape(-1)])
-
-        return prefill
+            outs.append(jax.lax.switch(
+                start // n,
+                [lambda pre=j * n: attend(pre)
+                 for j in range(self.max_seq_len // n)]))
+            c_a = jax.lax.dynamic_update_slice(
+                c_a, own[None, None], (li, row, start, 0))
+        return c_a, jnp.concatenate(outs)

@@ -437,15 +437,7 @@ class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
             lanes = rows.shape[0]
             at = jnp.arange(n)
             live = (at < lens[:, None]).reshape(-1)
-            # The sorted layout's tile from the rows this call holds: half a
-            # piece's where an expert's mean share of the call's (token,
-            # expert) pairs fits in half.  (On the v5e at the cell's widths,
-            # ms a program by tile, PERF.md section 6, PR 47: a share of 24
-            # rows 15.20 | 13.70 | 13.96 in tiles of 16 | 32 | 64, of 48 rows
-            # 22.56 | 22.49 | 24.4 in 32 | 64 | 128.)
-            share = lanes * n * self.top_k / self.n_experts
-            half = TILE_M_PIECE // 2
-            tile_m = half if share <= half else TILE_M_PIECE
+            tile_m = self._piece_tile(lanes * n)
             k_a, v_a = arena["k"], arena["v"]
             s_a, conv_a = arena["s"], arena["conv"]
             x = p["embed"][ids.reshape(-1)].astype(jnp.float32)

@@ -249,8 +249,8 @@ class PanguMoeBackend(LatentMoeDecoder):
             x = p["embed"][ids[0]].astype(jnp.float32)
             routes = []
             for li, lp in enumerate(p["layers"]):
-                c_a, o = self._piece_latent_layer(lp, c_a, li, row, start, x,
-                                                  pos)
+                c_a, o = self._piece_latent_layer(lp, c_a, li, [row],
+                                                  [start], x, pos)
                 x, _, route = self._after_rows(lp, x, o, live,
                                                TILE_M_PIECE)
                 routes += route

@@ -559,20 +559,36 @@ class TestAPromptsTwoWaits:
 
 # -- PR 47: a piece program of as many lanes as prompts stand in line -----------
 
-LANES = "spans_eva_two"  # pieces of 32 positions, two prompts a program at most
-
-
-@pytest.fixture(scope="module")
-def lane_engine():
+# Backends whose pieces are 32 positions and hold two prompts a program at
+# most: one that takes its lanes as an option, and one whose piece program
+# declares them (``models/kimi_linear.py``, PR 48: float32, so that a lane's
+# tokens are the lone prompt's to the bit).
+def _two_lane_evabyte(name):
     from client_tpu.models.evabyte import EvaByteBackend
 
+    return EvaByteBackend(name=name, seed=3, max_seq_len=128, window=32,
+                          chunk=4, prefill_lanes=2)
+
+
+def _two_lane_kimi(name):
+    from client_tpu.models.kimi_linear import KimiLinearBackend
+
+    return KimiLinearBackend(name=name, seed=3, max_seq_len=128, piece=32,
+                             dtype="float32")
+
+
+LANES = {"spans_eva_two": _two_lane_evabyte,
+         "spans_kimi_two": _two_lane_kimi}
+
+
+@pytest.fixture(scope="module", params=sorted(LANES))
+def lane_engine(request):
+    name = request.param
     repo = ModelRepository()
-    repo.register_backend(EvaByteBackend(
-        name=LANES, seed=3, max_seq_len=128, window=32, chunk=4,
-        prefill_lanes=2))
+    repo.register_backend(LANES[name](name))
     eng = TpuEngine(repo)
-    eng._schedulers[LANES].warmup()
-    yield eng, eng.profile_snapshot(model=LANES)["compiles"]["count"]
+    eng._schedulers[name].warmup()
+    yield eng, eng.profile_snapshot(model=name)["compiles"]["count"], name
     eng.shutdown()
 
 
@@ -597,14 +613,14 @@ class TestPieceLanes:
         prompt stands in line and its two-lane one when two do, every prompt
         gets the tokens it gets alone, and nothing compiles after the
         warm-up, which ran both lane counts."""
-        eng, compiles = lane_engine
+        eng, compiles, model = lane_engine
         lengths, want = LINES[line]
         prompts = [list(range(1 + i, 1 + i + n))
                    for i, n in enumerate(lengths)]
-        alone = [_stream(eng, p, 4, model=LANES)() for p in prompts]
-        before = _gen(eng, LANES)
-        _held_batch(eng, LANES, prompts, 4)
-        after = _gen(eng, LANES)
+        alone = [_stream(eng, p, 4, model=model)() for p in prompts]
+        before = _gen(eng, model)
+        _held_batch(eng, model, prompts, 4)
+        after = _gen(eng, model)
         d = _delta(before, after)
         calls = after["spans"][spans.GEN_PREFILL_DISPATCH]["count"] \
             - before["spans"][spans.GEN_PREFILL_DISPATCH]["count"]
@@ -612,16 +628,17 @@ class TestPieceLanes:
         assert calls == len(want)
         assert d["prefill_positions_valid"] == sum(lengths)
         assert d["first_tokens"] == len(prompts)
-        together = [_stream(eng, p, 4, model=LANES) for p in prompts]
+        together = [_stream(eng, p, 4, model=model) for p in prompts]
         assert [j() for j in together] == alone
-        assert eng.profile_snapshot(model=LANES)["compiles"]["count"] \
+        assert eng.profile_snapshot(model=model)["compiles"]["count"] \
             == compiles
 
     def test_the_ladder_is_the_powers_of_two_up_to_the_backends_lanes(
             self, lane_engine, piece_engine):
         """A backend of one lane has a ladder of one: the program it ran
         before there was a ladder, and no other."""
-        assert lane_engine[0]._schedulers[LANES]._ladders == {32: [1, 2]}
+        eng, _, model = lane_engine
+        assert eng._schedulers[model]._ladders == {32: [1, 2]}
         assert piece_engine._schedulers[PIECE]._ladders == {32: [1]}
 
 

@@ -281,6 +281,151 @@ def test_padded_lanes_and_padded_positions_leave_a_live_slot_bit_for_bit(
         assert np.abs(a - b).max() < 2e-5
 
 
+# Two lanes of one piece call: (tokens prefilled before the call, tokens the
+# call holds) a lane, each lane's prompt its own.
+TWO_LANES = {
+    "two_first_pieces": [(0, 16), (0, 16)],
+    "a_first_piece_beside_a_third": [(0, 16), (32, 9)],
+    "a_third_beside_a_second_cut_short": [(32, 16), (16, 5)],
+    "a_lane_shorter_than_the_convolution": [(0, 2), (16, 16)],
+    "a_first_piece_of_one_token": [(16, 11), (0, 1)],
+}
+
+
+def _piece_args(lanes, slots):
+    """A piece call's (rows, ids, lens, starts) for ``lanes`` [(prompt,
+    before, held)]."""
+    buf = np.zeros((len(lanes), PIECE), np.int32)
+    for i, (ids, before, held) in enumerate(lanes):
+        buf[i, :held] = ids[before:before + held]
+    return (np.asarray(slots, np.int32), buf,
+            np.asarray([held for _, _, held in lanes], np.int32),
+            np.asarray([before for _, before, _ in lanes], np.int32))
+
+
+def _two_lanes(dtype, attn_impl, case):
+    """The case's two lanes through one two-lane call (``pair``) and through
+    two one-lane calls (``solo``), both after the same one-lane pieces
+    before.  -> (pair, solo, per lane (x, routes) of each, the lanes)."""
+    be = backend(dtype=dtype, attn_impl=attn_impl)
+    pair, solo = Served(be), Served(be)
+    lanes = [(ids_of(48, seed=30 + i), before, held)
+             for i, (before, held) in enumerate(TWO_LANES[case])]
+    for srv in (pair, solo):
+        for slot, (ids, before, _) in enumerate(lanes):
+            if before:
+                srv.prefill(ids[:before], slot=slot)
+    pair.arena, x, routes = pair.piece(pair.params, pair.arena,
+                                       *_piece_args(lanes, [0, 1]))
+    x, routes = np.asarray(x), np.asarray(routes)
+    got = [(x[i * PIECE:(i + 1) * PIECE],
+            routes[:, i * PIECE:(i + 1) * PIECE]) for i in range(2)]
+    want = []
+    for slot, lane in enumerate(lanes):
+        solo.arena, x, routes = solo.piece(solo.params, solo.arena,
+                                           *_piece_args([lane], [slot]))
+        want.append((np.asarray(x), np.asarray(routes)))
+    return pair, solo, got, want, lanes
+
+
+@pytest.mark.parametrize("attn_impl", ["reference", "fused"])
+@pytest.mark.parametrize("case", sorted(TWO_LANES))
+def test_two_lanes_of_a_piece_are_the_lanes_alone_bit_for_bit(attn_impl,
+                                                              case):
+    """float32: two prompts' pieces in one program (``wqkv``, the latent
+    projections and the feed-forward over both lanes' positions at once, the
+    mixers a lane at a time, each from its own slot and its own ``start``,
+    fresh or continued) leave every slot's states, tails and latent rows, and
+    give every valid position's activations and choices, exactly as the same
+    two pieces do one lane at a time."""
+    pair, solo, got, want, lanes = _two_lanes("float32", attn_impl, case)
+    for leaf in ("s", "conv", "c"):
+        assert np.array_equal(np.asarray(pair.arena[leaf][:, :3]),
+                              np.asarray(solo.arena[leaf][:, :3])), leaf
+    for (x, routes), (x1, routes1), (_, _, held) in zip(got, want, lanes):
+        assert np.array_equal(x[:held], x1[:held])
+        assert np.array_equal(routes[:, :held], routes1[:, :held])
+
+
+@pytest.mark.parametrize("case", ["a_first_piece_beside_a_third",
+                                  "a_lane_shorter_than_the_convolution"])
+def test_two_bfloat16_lanes_are_the_lanes_alone_within_the_files_limits(case):
+    """bfloat16: a matmul over twice the rows, and a sorted layout in other
+    tiles, may round an activation the other way, so the two forms agree to
+    the file's limits and not to the bit; the states are float32 and agree
+    far closer."""
+    pair, solo, got, want, lanes = _two_lanes("bfloat16", "fused", case)
+    for slot in (0, 1):
+        for a, b in zip(pair.slot(slot), solo.slot(slot)):
+            assert np.abs(a - b).max() < TOL_BF16 / 10
+    for (x, _), (x1, _), (_, _, held) in zip(got, want, lanes):
+        logits, logits1 = (np.asarray(pair.be._logits(pair.params, t[:held]))
+                           for t in (x, x1))
+        assert np.abs(logits - logits1).max() < TOL_BF16
+
+
+@pytest.mark.parametrize("attn_impl", ["reference", "fused"])
+def test_a_padded_second_lane_leaves_every_live_slot_untouched(attn_impl):
+    """A two-lane call whose second lane holds no prompt (the junk slot, one
+    position, as the scheduler pads it): slots 0 and 2 hold live streams and
+    stand bit for bit in state, tail and latent rows, and slot 1's piece is
+    the one-lane program's."""
+    be = backend(dtype="float32", attn_impl=attn_impl)
+    pair, solo = Served(be), Served(be)
+    ids = ids_of(40, seed=41)
+    for srv in (pair, solo):
+        srv.walk(ids_of(30, seed=42), 20, slot=0)
+        srv.walk(ids_of(25, seed=43), 18, slot=2)
+        srv.prefill(ids[:16], slot=1)
+    rows, buf, lens, starts = _piece_args([(ids, 16, 16)], [1])
+    pair.arena, x, _ = pair.piece(
+        pair.params, pair.arena, np.asarray([1, 3], np.int32),
+        np.concatenate([buf, np.zeros_like(buf)]), np.asarray([16, 1],
+                                                              np.int32),
+        np.asarray([16, 0], np.int32))
+    solo.arena, x1, _ = solo.piece(solo.params, solo.arena, rows, buf, lens,
+                                   starts)
+    assert np.array_equal(np.asarray(x)[:16], np.asarray(x1))
+    for leaf in ("s", "conv", "c"):
+        assert np.array_equal(np.asarray(pair.arena[leaf][:, :3]),
+                              np.asarray(solo.arena[leaf][:, :3])), leaf
+
+
+@pytest.mark.parametrize("sample", [False, True])
+def test_two_lanes_tokens_and_record_rows_are_the_lanes_alone(sample):
+    """The whole prefill program: a token a lane from its own last valid
+    position into its own slot, and the record laid ``[L | L x piece x
+    stream_record]`` as the scheduler cuts it, lane after lane."""
+    be = backend(dtype="float32")
+    step = jax.jit(be.prefill_fn(), static_argnums=be.prefill_static_argnums)
+    params = be.place_params(be._init_params())
+    lanes = [(ids_of(48, seed=50), 0, 7), (ids_of(48, seed=51), 0, 16)]
+    width = PIECE * be.stream_record
+
+    def run(which, slots):
+        rows, buf, lens, starts = _piece_args(which, slots)
+        n = len(which)
+        arena, out = step(
+            params, be.init_arena(3), rows, buf, lens,
+            np.asarray(slots, np.int32) + 5,          # a seed a slot
+            np.full(n, 0.9 if sample else 0.0, np.float32),
+            np.full(n, 8, np.int32), np.full(n, 0.95, np.float32), sample,
+            starts)
+        out = np.asarray(out)
+        assert out.shape == (n * (1 + width),)
+        return (np.asarray(arena["tok"]), out[:n],
+                out[n:].reshape(n, PIECE, be.stream_record))
+
+    tok, tokens, record = run(lanes, [2, 0])
+    for i, (lane, slot) in enumerate(zip(lanes, (2, 0))):
+        tok1, tokens1, record1 = run([lane], [slot])
+        assert tokens[i] == tokens1[0] == tok[slot] == tok1[slot]
+        assert np.array_equal(record[i, :lane[2]], record1[0, :lane[2]])
+        # A lane's logits stand in its last valid row and nowhere else.
+        assert (record[i, :lane[2] - 1, -9:] == 0).all()
+        assert record[i, lane[2] - 1, -9:].any()
+
+
 @pytest.mark.parametrize("piece,chunk", [(64, 16), (32, 8), (8, 8)])
 def test_a_prompt_cut_into_1_2_and_5_pieces_gives_one_state(piece, chunk):
     """A prompt of 40 positions as one piece, two and five: the same state,
@@ -503,7 +648,7 @@ def test_the_backend_built_from_the_file_is_the_issues_arena():
     assert arena["c"].shape == (2, 257, 8192, 640)
     assert arena["s"].shape == (6, 257, 32, 128, 128)
     assert arena["conv"].shape == (6, 257, 3 * 12288)
-    assert be.prefill_piece == (512, 1)
+    assert be.prefill_piece == (512, 2)
     params = be._init_params()
     total = sum(int(np.prod(w.shape))
                 for w in jax.tree_util.tree_leaves(params))

@@ -46,7 +46,14 @@ of its ladder): the projections
 and the expert block over all lanes' positions at once, the mixers a lane at
 a time.  ``models/experts.py`` ``prefill_fn`` takes any lane count for it and
 lowers to the recorded programs for the three other families that prefill
-through it with one lane: the other eleven pairs of hashes did not move.  A
+through it with one lane: the other eleven pairs of hashes did not move;
+``kimi``'s piece programs at the tree PR 48 left, whose backend declares two
+lanes as ``nemotron``'s does (``prefill``, ``prefill_1``: ``wqkv``, the latent
+projections and the feed-forward over both lanes' positions, the chunked
+form, the switch and the flash call a lane at a time), with
+models/latent_moe.py's own ``prefill_fn`` gone for models/experts.py's (the
+record's one word a layer through ``_piece_words``): ``pangu``'s two, ``kimi``'s
+decode wave and the ten other pairs did not move.  A
 PR that means to change one of these programs records the new hash here and
 says so; one that does not has a guard.
 
@@ -68,7 +75,8 @@ RECORDED = {
     ("gpt", "decode"): ("c7d0eebb4f86770a", "2445b260a28de378"),
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),
     ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),
-    ("kimi", "prefill"): ("93c6ccfe91d5a268", "5dd18e6e271299fb"),
+    ("kimi", "prefill"): ("0719e01ccc5ce5e0", "083a795658c4ced4"),
+    ("kimi", "prefill_1"): ("8e944d9b2ab178e0", "11c370955249a916"),
     ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),
     ("nemotron", "prefill"): ("412e3a406d01a832", "036da1223ab7372f"),
     ("nemotron", "prefill_1"): ("2e42a7ed9a4722d7", "72f715ae5cf8c153"),

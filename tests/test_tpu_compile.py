@@ -395,16 +395,11 @@ KIMI = dict(n_layers=8, n_dense=1, d_model=2304, n_heads=32,
             max_streams=256, attention_impl="flash")
 
 
-def test_state_and_latent_decode_step_compiles_at_published_widths(
-        one_chip, monkeypatch):
-    """A full wave of 256 lanes over both caches: the state kernel (a block
-    of heads of 128 x 128 float32 a grid step), the latent kernel at 32 heads
-    and the grouped matmuls of 32 held experts compile under Mosaic's limits;
-    the three donated leaves (8.7 GB) are updated in place, and what is
-    returned is the tokens and the wave's three counts."""
+def _kimi_shapes(one_chip, monkeypatch):
+    """``kimi_linear`` at the cell's widths with the chip's branches taken:
+    (place, backend, the shapes of its parameters, of its arena)."""
     from client_tpu.engine import backend_init
     from client_tpu.models.kimi_linear import KimiLinearBackend
-    from client_tpu.observability import spans
 
     monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
     place = _on(one_chip)
@@ -415,6 +410,19 @@ def test_state_and_latent_decode_step_compiles_at_published_widths(
     arena = jax.tree_util.tree_map(
         lambda a: place(a.shape, a.dtype),
         jax.eval_shape(lambda: backend.init_arena(backend.max_streams)))
+    return place, backend, params, arena
+
+
+def test_state_and_latent_decode_step_compiles_at_published_widths(
+        one_chip, monkeypatch):
+    """A full wave of 256 lanes over both caches: the state kernel (a block
+    of heads of 128 x 128 float32 a grid step), the latent kernel at 32 heads
+    and the grouped matmuls of 32 held experts compile under Mosaic's limits;
+    the three donated leaves (8.7 GB) are updated in place, and what is
+    returned is the tokens and the wave's three counts."""
+    from client_tpu.observability import spans
+
+    place, backend, params, arena = _kimi_shapes(one_chip, monkeypatch)
     lanes_i, lanes_f = place((256,), jnp.int32), place((256,), jnp.float32)
     step = jax.jit(spans.named_step(backend.decode_fn(), spans.STEP_DECODE),
                    donate_argnums=backend.donate_argnums,
@@ -435,6 +443,59 @@ def test_state_and_latent_decode_step_compiles_at_published_widths(
         assert memory.alias_size_in_bytes >= leaves
         # No copy of a state leaf (3.2 GB) or of the rows (5.4 GB) beside it.
         assert memory.temp_size_in_bytes < 1.0e9, memory
+
+
+@pytest.mark.parametrize("lanes", [1, 2])
+def test_state_and_latent_piece_programs_compile_at_published_widths(
+        one_chip, monkeypatch, lanes):
+    """Both of the hybrid decoder's piece programs (a piece of 512 of each of
+    ``lanes`` prompts; the backend declares two) for one v5e chip from shapes
+    alone: **fourteen grouped matmuls whatever the lanes** (one plan and one
+    pair of products an expert layer for every lane's positions: the 32 held
+    experts of a layer are read once a program), a lane's own flash calls (a
+    branch a count of rows before it, in both latent layers) and the chunked
+    form in plain XLA.  No program copies a weight or writes a state, tail or
+    latent leaf out again: the donated arena's three leaves (8.7 GB) are
+    updated in place, each lane's slot among them."""
+    from client_tpu.observability import spans
+
+    place, backend, params, arena = _kimi_shapes(one_chip, monkeypatch)
+    assert backend.prefill_piece == (512, 2)
+    lane_i, lane_f = place((lanes,), jnp.int32), place((lanes,), jnp.float32)
+    step = jax.jit(spans.named_step(backend.prefill_fn(), spans.STEP_PREFILL),
+                   donate_argnums=backend.donate_argnums,
+                   static_argnums=backend.prefill_static_argnums)
+    compiled = step.lower(params, arena, lane_i,
+                          place((lanes, 512), jnp.int32), lane_i, lane_i,
+                          lane_f, lane_i, lane_f, False, lane_i).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
+    assert calls.count("grouped_matmul") == 14
+    assert calls.count("flash_attention") == lanes * 2 * (8192 // 512)
+    assert "kda_wave_update" not in calls
+    # The sorted layout in tiles of 32 rows for either count (a held
+    # expert's mean share of the call's pairs is 16 rows a lane; the layout
+    # holds any routing of the call's 4096 pairs a lane).
+    assert f"bf16[{5088 if lanes == 1 else 9184},2304]" in text
+    # A token and 512 record rows a lane.
+    assert f"s32[{lanes * (1 + 512 * backend.stream_record)}]" in text
+    weights = (r"32,2304,2048|32,1024,2304|2304,12288|4096,2304|2304,18432"
+               r"|9216,2304|2304,2048|1024,2304|20480,2304|2304,20480")
+    leaves = r"2,257,8192,640|6,257,32,128,128|6,257,36864"
+    moved = _written_out_again(text, weights + "|" + leaves)
+    assert not moved, moved
+    memory = compiled.memory_analysis()
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    cache = sum(math.prod(arena[k].shape) * arena[k].dtype.itemsize
+                for k in ("c", "s", "conv"))
+    assert memory.alias_size_in_bytes >= cache
+    # A piece's temporaries are the sorted layout, the chunks' pairwise
+    # decays and a lane's keys and values of up to 8192 rows: far under the
+    # 3.2 GB state leaf or the 5.4 GB of latent rows (the arena and weights
+    # are 13.1 GB of the chip's 16.9).
+    assert memory.temp_size_in_bytes < 0.4e9, memory
+    assert 12.9e9 < memory.argument_size_in_bytes < 13.2e9
 
 
 # -- the byte-level decoder at its published widths (PR 42) ------------------
