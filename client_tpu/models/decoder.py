@@ -92,7 +92,7 @@ that layout);
 ``_after_attention(lp, x, o)`` -> x;
 where ``layer_kinds`` names ``"state"`` layers, ``_advance(lp, x, *state
 leaves, rows, lens, index)`` -> (*state leaves, o): the layer's mixer on the
-slots' states (what a state is and which kernel advances it are the model's;
+slots' states (models/state_layer.py's, around the recurrence a model names;
 it chooses kernel or oracle by ``_use_kernel()``), ``o`` what
 ``_after_attention`` reads; where it names ``"none"`` layers,
 ``_feed_forward(lp, x)`` -> x, the whole of such a layer;

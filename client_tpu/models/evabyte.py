@@ -68,6 +68,8 @@ import numpy as np
 
 from client_tpu.models import register_model
 from client_tpu.models.decoder import DecoderBackend, sample_into_slots
+# (``rope`` by this name too: benchmark/testdata's controls import it here.)
+from client_tpu.models.layers import rms_norm, rope
 
 _NEG_INF = -1e30
 # The matrices served ``[out, in]`` and contracted on their minor axis
@@ -75,30 +77,6 @@ _NEG_INF = -1e30
 # products that go through ``rope`` that way round, and a piece's 2048 rows
 # want ``wv`` so too.
 OUT_IN = ("wq", "wk", "wv")
-
-
-def rms_norm(x, g, eps):
-    """``x / rms(x) * (1 + g)`` in float32 (``norm_add_unit_offset``)."""
-    import jax.numpy as jnp
-
-    x = x.astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jnp.reciprocal(jnp.sqrt(var + eps)) * (
-        1.0 + g.astype(jnp.float32))
-
-
-def rope(x, pos, theta):
-    """Rotary positions, rotate-half pairing: x ``[..., n, H, D]`` float32,
-    pos ``[..., n]``."""
-    import jax.numpy as jnp
-
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos.astype(jnp.float32)[..., None] * inv          # [..., n, D/2]
-    cos = jnp.cos(ang)[..., None, :]
-    sin = jnp.sin(ang)[..., None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
 def summarize(k, v, phi, mu, chunk):
@@ -272,7 +250,7 @@ class EvaByteBackend(DecoderBackend):
     def _qkv_rows(self, lp, x, pos):
         """x ``[..., n, d]`` float32 -> q, k (RoPE applied), v ``[..., n,
         H, D]`` float32."""
-        h = rms_norm(x, lp["ln1"], self.rms_eps)
+        h = rms_norm(x, lp["ln1"], self.rms_eps, unit_offset=True)
         shape = (*x.shape[:-1], self.n_heads, self.head_dim)
         q = rope(self._mm_t(h, lp["wq"]).reshape(shape), pos,
                  self.rope_theta)
@@ -287,7 +265,7 @@ class EvaByteBackend(DecoderBackend):
         import jax
 
         x = x + self._mm(o.reshape(x.shape), lp["wo"])
-        h = rms_norm(x, lp["ln2"], self.rms_eps)
+        h = rms_norm(x, lp["ln2"], self.rms_eps, unit_offset=True)
         if fence is not None:
             x, h = fence((x, h))
         return x + self._mm(
@@ -296,7 +274,8 @@ class EvaByteBackend(DecoderBackend):
 
     def _logits(self, p, x):
         """float32 logits of all heads, ``[..., n_pred_heads, vocab]``."""
-        out = self._mm(rms_norm(x, p["lnf"], self.rms_eps), p["head"])
+        out = self._mm(rms_norm(x, p["lnf"], self.rms_eps, unit_offset=True),
+                       p["head"])
         return out.reshape(*x.shape[:-1], self.n_pred_heads, self.vocab)
 
     def _served(self, logits):

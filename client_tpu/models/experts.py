@@ -42,7 +42,10 @@ form).  What does not differ between them lives here, in
   behind a program's tokens (``stream_record`` of the decoder's contract),
   for a model whose ``piece_hidden_fn`` returns a piece's choices
   (models/latent_moe.py keeps its one-word form: its own ``_record`` and
-  ``_piece_words``).
+  ``_piece_words``);
+- **the piece program** (``piece_hidden_fn``) and the full-context forward
+  (``make_apply_params``): one walk over the layers by their kinds
+  (``_walk_kinds``) around the parts a backend supplies a kind.
 
 A model sets ``d_model, d_expert, n_experts, experts_held, first_expert,
 top_k, routed_scale, dtype, rms_eps, _seed`` and, where they differ from the
@@ -59,6 +62,7 @@ import numpy as np
 
 from client_tpu.models.decoder import (DecoderBackend, logit_bits,
                                        sample_into_slots)
+from client_tpu.models.layers import rms_norm
 
 _CHUNK = 1 << 24          # elements of a weight made by one task
 _BLOCK = 1 << 17          # elements made at a time (cache-sized)
@@ -74,15 +78,6 @@ RECORD_LOGITS = 8
 def record_width(expert_layers: int) -> int:
     """int32 a position of a stream's record."""
     return expert_layers + 1 + RECORD_LOGITS
-
-
-def rms_norm(x, g, eps):
-    """``x / rms(x) * g`` in float32."""
-    import jax.numpy as jnp
-
-    x = x.astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jnp.reciprocal(jnp.sqrt(var + eps)) * g.astype(jnp.float32)
 
 
 class SeededWeight:
@@ -406,6 +401,102 @@ class ExpertDecoder(DecoderBackend):
         words, ``[n, held_words]`` a layer."""
         return [self._words(r) for r in routes]
 
+    def _after_attention(self, lp, x, o):
+        h, stats, route = self._after_rows(
+            lp, x["h"], o.reshape(o.shape[0], -1), x["live"], TILE_M_WAVE)
+        return {**x, "h": h, "stats": x["stats"] + stats,
+                "route": x["route"] + route}
+
+    def _walk_kinds(self, p, x, live, tile_m, mixer):
+        """x ``[n, d]`` through the layers by their kinds: ``mixer(kind, ki,
+        lp, x)`` -> o for a layer that has one, then ``_after_rows``; a
+        ``"none"`` layer is ``_expert_block``.  -> (x, choices ``[expert
+        layers, n, top_k]``)."""
+        import jax.numpy as jnp
+
+        routes = []
+        for li, lp in enumerate(p["layers"]):
+            kind, ki = self._layer_kind(li)
+            if kind == "none":
+                x, _, top_i = self._expert_block(lp, x, live, tile_m)
+                routes.append(top_i)
+                continue
+            x, _, route = self._after_rows(lp, x, mixer(kind, ki, lp, x),
+                                           live, tile_m)
+            routes += route
+        return x, jnp.stack(routes)
+
+    def make_apply_params(self):
+        """Full-context forward in the served precision: no cache, no pieces,
+        nothing absorbed, a state walked position by position.  Logits of
+        every position, and each expert layer's choices ``[expert layers, n,
+        top_k]``.  A backend supplies a kind's mixer over a whole prompt,
+        ``_full_rows_layer``, ``_full_ring_layer``, ``_full_state_layer``
+        ``(lp, x, pos)`` -> o.  Model-level entry (the engine takes the placed
+        weights from it) and the tests' reference; serving goes through
+        pieces and waves."""
+        params = self.place_params(self.load_or_init_params(self._init_params))
+
+        def apply(p, inputs):
+            import jax.numpy as jnp
+
+            ids = inputs["INPUT_IDS"].astype("int32")
+            n = ids.shape[0]
+            pos = jnp.arange(n)
+            x, routes = self._walk_kinds(
+                p, p["embed"][ids].astype(jnp.float32), jnp.ones(n, bool),
+                TILE_M_PIECE,
+                lambda kind, ki, lp, x: getattr(self, f"_full_{kind}_layer")(
+                    lp, x, pos))
+            return {"logits": self._logits(p, x), "routing": routes}
+
+        return apply, params
+
+    def piece_hidden_fn(self):
+        """(params, arena, rows[L], ids[L, piece], lens[L], starts[L]) ->
+        (arena, x ``[L * piece, d]``, choices ``[expert layers, L * piece,
+        top_k]``), lane after lane: one prefill piece of each of ``L``
+        prompts (any ``L`` up to what ``prefill_piece`` declares), positions
+        ``starts .. starts + lens`` of a lane's prompt (``starts`` a multiple
+        of the piece).  **The piece's frame**, as ``_decode_hidden_fn`` is
+        the wave's (models/decoder.py): a layer gets the leaves of its kind.
+        Whatever is a matrix product over positions sees all lanes'
+        positions as one batch, so a weight, and above all a layer's held
+        experts, is read once a program; a mixer runs a lane at a time, each
+        from its own slot.  A backend supplies a part for each kind it
+        declares, ``_piece_rows_layer``, ``_piece_ring_layer``,
+        ``_piece_state_layer`` ``(lp, *the kind's leaves, ki, rows, starts,
+        lens, x, pos)`` -> (*leaves, o ``[L * piece, *]``), what follows a
+        mixer, ``_after_rows(lp, x, o, live, tile_m)`` -> (x, routing counts,
+        choices: ``()`` or ``(top_i,)``), and a ``"none"`` layer whole,
+        ``_expert_block(lp, x, live, tile_m)`` -> (x, counts, top_i)."""
+        import jax.numpy as jnp
+
+        n = self.piece
+        leaves_of = {"rows": self.cache_leaves, "ring": self.ring_leaves,
+                     "state": self.state_leaves}
+
+        def piece(p, arena, rows, ids, lens, starts):
+            at = jnp.arange(n)
+            live = (at < lens[:, None]).reshape(-1)
+            pos = (starts[:, None] + at).reshape(-1)
+            arena = dict(arena)
+
+            def mixer(kind, ki, lp, x):
+                names = leaves_of[kind]
+                *leaves, o = getattr(self, f"_piece_{kind}_layer")(
+                    lp, *(arena[name] for name in names), ki, rows, starts,
+                    lens, x, pos)
+                arena.update(zip(names, leaves))
+                return o
+
+            x, routes = self._walk_kinds(
+                p, p["embed"][ids.reshape(-1)].astype(jnp.float32), live,
+                self._piece_tile(rows.shape[0] * n), mixer)
+            return arena, x, routes
+
+        return piece
+
     def prefill_fn(self):
         """``PREFILL_ARGS`` -> (arena, tokens[L]): one **piece** of each
         lane's prompt (``piece_hidden_fn``: (arena, x ``[L * piece, d]``,
@@ -422,24 +513,19 @@ class ExpertDecoder(DecoderBackend):
 
             lanes = rows.shape[0]
             arena, x, routes = piece(p, arena, rows, ids, lens, starts)
-            # Each lane's last valid row of x.  (One lane keeps the lines the
-            # recorded programs of the backends that hold one were lowered
-            # from: tests/test_served_programs.py.)
-            at = lens - 1 if lanes == 1 else (
-                lens - 1 + self.piece * np.arange(lanes, dtype=np.int32))
+            # Each lane's last valid row of x.
+            at = lens - 1 + self.piece * np.arange(lanes, dtype=np.int32)
             logits = self._logits(p, x[at])
             arena, tokens = sample_into_slots(
                 arena, rows, logits, seeds, starts + lens, temps, top_ks,
                 top_ps, sample)
             if not self.stream_record:
                 return arena, tokens
-            last = (jnp.arange(self.piece) == lens[0] - 1 if lanes == 1
-                    else jnp.arange(lanes * self.piece)
+            last = (jnp.arange(lanes * self.piece)
                     == jnp.repeat(at, self.piece))
             words, last = self._piece_words(routes), last[:, None]
-            bits = logit_bits(logits, tokens, RECORD_LOGITS)
-            if lanes > 1:
-                bits = jnp.repeat(bits, self.piece, axis=0)
+            bits = jnp.repeat(logit_bits(logits, tokens, RECORD_LOGITS),
+                              self.piece, axis=0)
             rec = jnp.concatenate(words + [jnp.where(last, bits, 0)], axis=1)
             return arena, jnp.concatenate([tokens, rec.reshape(-1)])
 

@@ -5,8 +5,8 @@ one copy.
 and a ring around the same attention) and ``models/nemotron_h.py`` (its
 attention layers) both hold ``n_heads`` query heads over ``n_kv_heads``
 key/value heads of ``head_dim`` in two leaves ``[layers of the kind, R,
-max_seq_len, Hkv*D]`` and prefill by pieces of ``piece`` positions, one prompt
-a call.  Piece i of a prompt has exactly ``i * piece`` rows before it, so a
+max_seq_len, Hkv*D]`` and prefill by pieces of ``piece`` positions, a lane at a
+time.  Piece i of a prompt has exactly ``i * piece`` rows before it, so a
 layer holds one branch a count (``lax.switch``) and computes nothing that is
 masked but inside the causal block: the piece's queries attend to the rows
 before them and, causally, to their own (the flash kernel's grouped-query
@@ -23,7 +23,9 @@ _NEG_INF = -1e30
 
 class GroupedQueryPieces:
     """The shared parts above, for a backend that sets ``n_heads, n_kv_heads,
-    head_dim, piece, max_seq_len`` and ``attention_impl``."""
+    head_dim, piece, max_seq_len`` and ``attention_impl`` and supplies
+    ``_project(lp, x, pos)`` -> q ``[n, H, D]``, k, v ``[n, Hkv, D]`` (of a
+    wave's rows it is the decode step's ``_qkv``)."""
 
     def _attend(self, q, own_k, own_v, before_k, before_v, window,
                 impl=None):
@@ -71,13 +73,70 @@ class GroupedQueryPieces:
             leaf, (ki, row, 0, 0),
             (1, 1, count, self.n_kv_heads * self.head_dim))[0, 0]
 
-    def _piece_rows(self, k_a, v_a, ki, row, start, q, own_k, own_v):
-        """A whole-context layer's part of a piece: q ``[piece, H, D]``
-        against the slot's ``start`` rows before the piece and its own
+    def _qkv(self, lp, x, pos):
+        return self._project(lp, x["h"], pos)
+
+    def _heads(self, lp, h):
+        """Normed rows h ``[n, d]`` -> q ``[n, H, D]``, k, v ``[n, Hkv, D]``
+        float32 (``wq, wk, wv``)."""
+        n, d = h.shape[0], self.head_dim
+        return (self._mm(h, lp["wq"]).reshape(n, self.n_heads, d),
+                self._mm(h, lp["wk"]).reshape(n, self.n_kv_heads, d),
+                self._mm(h, lp["wv"]).reshape(n, self.n_kv_heads, d))
+
+    def _as_cached(self, k, v, dtype):
+        """k, v ``[n, Hkv, D]`` -> the rows ``[n, Hkv*D]`` the cache holds."""
+        hd = self.n_kv_heads * self.head_dim
+        return (t.reshape(-1, hd).astype(dtype) for t in (k, v))
+
+    def _full_layer(self, qkv, window):
+        """A layer's attention over a whole prompt from its projections,
+        nothing cached (models/experts.py ``make_apply_params``): dense
+        scores, in a band of ``window`` keys where one is given."""
+        import jax.numpy as jnp
+
+        q, k, v = qkv
+        own_k, own_v = self._as_cached(k, v, jnp.dtype(self.dtype))
+        return self._attend(q, own_k, own_v, own_k[:0], own_v[:0], window,
+                            impl="einsum")
+
+    def _full_rows_layer(self, lp, x, pos):
+        return self._full_layer(self._project(lp, x, pos), None)
+
+    def _lane_by_lane(self, one, qkv, k_a, v_a, ki, rows, starts, lens):
+        """A layer's part of a piece of ``L`` lanes from its projections over
+        every lane's positions at once (``qkv``: q ``[L * piece, H, D]``, k, v
+        ``[L * piece, Hkv, D]`` float32): the rows as the cache holds them,
+        then a lane at a time ``one(k_a, v_a, ki, row, start, n_valid, q,
+        own_k, own_v)`` -> (K leaf, V leaf, o ``[piece, H * D]``).  -> (K
+        leaf, V leaf, o ``[L * piece, H * D]``)."""
+        import jax.numpy as jnp
+
+        n, (q, k, v) = self.piece, qkv
+        own_k, own_v = self._as_cached(k, v, k_a.dtype)
+        outs = []
+        for i in range(rows.shape[0]):
+            own = slice(i * n, (i + 1) * n)
+            k_a, v_a, o = one(k_a, v_a, ki, rows[i], starts[i], lens[i],
+                              q[own], own_k[own], own_v[own])
+            outs.append(o)
+        return k_a, v_a, jnp.concatenate(outs)
+
+    def _piece_rows_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
+        """A whole-context layer's part of a piece (models/experts.py
+        ``piece_hidden_fn``), by the backend's ``_project(lp, x, pos)``."""
+        return self._lane_by_lane(self._piece_rows, self._project(lp, x, pos),
+                                  k_a, v_a, ki, rows, starts, lens)
+
+    def _piece_rows(self, k_a, v_a, ki, row, start, n_valid, q, own_k, own_v):
+        """A whole-context layer's part of one lane's piece: q ``[piece, H,
+        D]`` against the slot's ``start`` rows before the piece and its own
         ``own_k, own_v [piece, Hkv*D]`` (as the cache holds them), which are
-        written behind them.  -> (K leaf, V leaf, o ``[piece, H * D]``)."""
+        written behind them (those behind the ``n_valid`` are beyond the
+        slot's live rows).  -> (K leaf, V leaf, o ``[piece, H * D]``)."""
         import jax
 
+        del n_valid
         n = self.piece
 
         def attend(pre):

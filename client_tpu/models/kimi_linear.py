@@ -35,15 +35,11 @@ a slot's last stream are masked by ``lens``; a state is not, so a prompt's
 first piece (``starts == 0``) starts from a zero state and a zero tail.
 **Decode** advances a wave's states in place (ops/kda.py ``kda_wave_update``,
 or its oracle where the arena is not the kernels').  **Prefill** is by pieces
-(``prefill_piece``), of one prompt or of two a call, as many as stand in line
-(this backend declares two lanes; ``models/pangu_moe.py`` keeps
-models/latent_moe.py's one): what multiplies positions by a large weight sees
-both prompts' rows as one batch, so a layer's held experts are read once a
-program; the mixers run a lane at a time, each from its own slot: a KDA layer
-runs the chunked form (``kda_chunk_scan``) from the slot's state and tail and
-writes both back (a padded position has ``g = 0, beta = 0``: it moves nothing,
-and the tail is that of the last valid positions); a latent layer holds its
-own ``lax.switch`` over the count of rows before a lane's piece.
+(models/experts.py's frame; this backend declares two lanes,
+``models/pangu_moe.py`` keeps models/latent_moe.py's one): a KDA layer's part
+is models/state_layer.py's around the chunked form (``kda_chunk_scan``; a
+padded position has ``g = 0, beta = 0``: it moves nothing), a latent layer's
+models/latent_moe.py's.
 
 The projection's output is rounded to the model's dtype before the
 convolution, in a wave and in a piece alike: the tail a slot carries is then
@@ -54,8 +50,10 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.latent_moe import (TILE_M_PIECE, LatentMoeDecoder,
-                                          record_width, rms_norm)
+from client_tpu.models.experts import record_width
+from client_tpu.models.latent_moe import LatentMoeDecoder
+from client_tpu.models.layers import rms_norm
+from client_tpu.models.state_layer import StateLayer
 from client_tpu.ops.kda import CHUNK
 
 # The tiny preset's layer pattern, in the published config's form.
@@ -70,7 +68,7 @@ def l2norm(x, eps=1e-6):
         jnp.sum(x * x, axis=-1, keepdims=True) + eps))
 
 
-class KimiLinearBackend(LatentMoeDecoder):
+class KimiLinearBackend(StateLayer, LatentMoeDecoder):
     """The decoder above.  ``linear_attn`` is the published
     ``linear_attn_config`` as it stands (entries past ``n_layers`` name
     layers that lie on further chips); ``dtype="float32"`` makes weights,
@@ -110,6 +108,7 @@ class KimiLinearBackend(LatentMoeDecoder):
         self.kda_heads, self.kda_dim = int(linear["num_heads"]), int(
             linear["head_dim"])
         self.taps = int(linear["short_conv_kernel_size"])
+        self.state_shape = (self.kda_heads, self.kda_dim, self.kda_dim)
         # The two low-rank pairs (decay, output gate): the head size.
         self.low_rank = self.kda_dim
         self.kv_rank = int(kv_rank)
@@ -242,84 +241,33 @@ class KimiLinearBackend(LatentMoeDecoder):
         return (l2norm(q) / math.sqrt(self.kda_dim), l2norm(k), v, g, beta,
                 gate)
 
-    def _kda_output(self, lp, o, gate):
-        """The heads' read-outs ``[..., H, d_v]``, normed a head and gated
-        -> ``[..., H * d_v]``."""
+    # -- the state layer's parts (models/state_layer.py) --------------------------
+
+    def _state_ops(self):
+        from client_tpu.ops.kda import (kda_chunk_scan, kda_recurrence,
+                                        kda_wave_update, reference_kda_update)
+
+        return (kda_wave_update, reference_kda_update, kda_chunk_scan,
+                kda_recurrence)
+
+    def _state_project(self, lp, x, dtype):
+        """``wqkv``'s columns in the cache's dtype, and the normed rows, which
+        the gates read (``_kda_inputs``: its five small matrices are read a
+        lane)."""
+        h = rms_norm(x, lp["ln1"], self.rms_eps)
+        return self._mm(h, lp["wqkv"]).astype(dtype), h, None
+
+    def _state_inputs(self, lp, h, ext):
+        return self._kda_inputs(lp, h, ext)
+
+    def _through_state(self, lp, ins, aside, run, pad):
+        """A padded position has ``g = 0, beta = 0``: it moves nothing.  The
+        heads' read-outs ``[..., H, d_v]`` are normed a head and gated ->
+        ``[..., H * d_v]``."""
+        q, k, v, g, beta, gate = ins
+        o = run(q, k, v, pad(g), pad(beta))
         return rms_norm(o, lp["onorm"], self.rms_eps).reshape(
             gate.shape) * gate
-
-    def _advance(self, lp, x, s_a, conv_a, rows, lens, ki):
-        """A KDA layer's part of a wave: each lane's projection joins its
-        slot's tail, the slot's state moves one position in place
-        (ops/kda.py: the kernel, or its oracle where the arena is not the
-        kernels').
-
-        The tails leave and enter the leaf through a one-hot product
-        (models/decoder.py ``slot_tails``)."""
-        import jax.numpy as jnp
-
-        from client_tpu.engine.backend_init import pallas_interpret
-        from client_tpu.models.decoder import put_slot_tails, slot_tails
-        from client_tpu.ops.kda import kda_wave_update, reference_kda_update
-
-        del lens
-        h = rms_norm(x["h"], lp["ln1"], self.rms_eps)
-        new = self._mm(h, lp["wqkv"]).astype(conv_a.dtype)
-        lanes, width = new.shape
-        pick, slots, tail = slot_tails(conv_a, ki, rows)
-        ext = jnp.concatenate(
-            [tail.reshape(lanes, self.taps - 1, width), new[:, None]], axis=1)
-        q, k, v, g, beta, gate = (
-            t[:, 0] for t in self._kda_inputs(lp, h[:, None], ext))
-        conv_a = put_slot_tails(conv_a, ki, pick, slots, ext)
-        if self._use_kernel():
-            s_a, o = kda_wave_update(s_a, q, k, v, g, beta, rows, layer=ki,
-                                     interpret=pallas_interpret())
-        else:
-            s_a, o = reference_kda_update(s_a, q, k, v, g, beta, rows,
-                                          layer=ki)
-        return s_a, conv_a, self._kda_output(lp, o, gate)
-
-    # -- full-context forward (no cache) ----------------------------------------
-
-    def make_apply_params(self):
-        """Full-context forward in the served precision: no cache, no
-        pieces, nothing absorbed, the state walked position by position.
-        Logits of every position.  Model-level entry for diagnostics;
-        serving goes through pieces and waves."""
-        params = self.place_params(self.load_or_init_params(self._init_params))
-
-        def apply(p, inputs):
-            import jax
-            import jax.numpy as jnp
-
-            from client_tpu.ops.kda import kda_recurrence
-
-            ids = inputs["INPUT_IDS"].astype("int32")
-            n = ids.shape[0]
-            live = jnp.ones(n, bool)
-            x = p["embed"][ids].astype(jnp.float32)
-            cdt = jnp.dtype(self.dtype)
-            for lp, kind in zip(p["layers"], self.layer_kinds):
-                if kind == "state":
-                    h = rms_norm(x, lp["ln1"], self.rms_eps)
-                    new = self._mm(h, lp["wqkv"]).astype(cdt)
-                    ext = jnp.concatenate(
-                        [jnp.zeros((self.taps - 1, new.shape[1]), cdt), new])
-                    q, k, v, g, beta, gate = self._kda_inputs(lp, h, ext)
-                    zero = jnp.zeros((self.kda_heads, self.kda_dim,
-                                      self.kda_dim), jnp.float32)
-                    o, _ = kda_recurrence(q, k, v, g, beta, zero)
-                    o = self._kda_output(lp, o, gate)
-                else:
-                    q_nope, q_r, c, k_r = self._queries_and_rows(lp, x, None)
-                    own = self._cache_rows_of(c, k_r, cdt)
-                    o = self._piece_attention(lp, q_nope, q_r, own, own[:0],
-                                              impl="einsum")
-                x, _, _ = self._after_rows(lp, x, o, live, TILE_M_PIECE)
-            return {"logits": self._logits(p, x)}
-
-        return apply, params
 
     # -- generative interface (used by GenerativeScheduler) -------------------
 
@@ -340,78 +288,3 @@ class KimiLinearBackend(LatentMoeDecoder):
             "conv": jnp.zeros((n_state, r, (self.taps - 1) * 3 * hk * dk),
                               dt),
             "tok": jnp.zeros(r, jnp.int32)}
-
-    def _piece_state_layer(self, lp, s_a, conv_a, ki, rows, starts, lens, x):
-        """A KDA layer's part of a piece of ``L`` lanes, x ``[L * piece, d]``
-        (``rows, starts, lens``: a scalar a lane): ``wqkv`` over every lane's
-        positions at once, then a lane at a time the convolution, the gates
-        (``_kda_inputs``: its five small matrices are read a lane), the
-        chunked form from the slot's state and tail (zeros for a prompt's
-        first piece), both written back, and the heads' norm.  -> (s_a,
-        conv_a, o ``[L * piece, H * d_v]``)."""
-        import jax
-        import jax.numpy as jnp
-
-        from client_tpu.ops.kda import kda_chunk_scan
-
-        n, width = self.piece, conv_a.shape[-1] // (self.taps - 1)
-        h = rms_norm(x, lp["ln1"], self.rms_eps)
-        new, outs = self._mm(h, lp["wqkv"]).astype(conv_a.dtype), []
-        for i, (row, start, n_valid) in enumerate(zip(rows, starts, lens)):
-            own, fresh = slice(i * n, (i + 1) * n), start == 0
-            valid = jnp.arange(n) < n_valid
-            tail = jnp.where(fresh, 0, conv_a[ki, row]).reshape(-1, width)
-            ext = jnp.concatenate([tail, new[own]])
-            q, k, v, g, beta, gate = self._kda_inputs(lp, h[own], ext)
-            o, s = kda_chunk_scan(
-                q, k, v, jnp.where(valid[:, None, None], g, 0.0),
-                jnp.where(valid[:, None], beta, 0.0),
-                jnp.where(fresh, 0.0, s_a[ki, row]), chunk=self.chunk)
-            s_a = jax.lax.dynamic_update_slice(
-                s_a, s.astype(s_a.dtype)[None, None], (ki, row, 0, 0, 0))
-            # The inputs of the last valid positions (with the old tail's,
-            # where the piece holds fewer than a tail).
-            tail = jax.lax.dynamic_slice(ext, (n_valid, 0),
-                                         (self.taps - 1, width))
-            conv_a = jax.lax.dynamic_update_slice(
-                conv_a, tail.reshape(1, 1, -1), (ki, row, 0))
-            outs.append(self._kda_output(lp, o, gate))
-        return s_a, conv_a, jnp.concatenate(outs)
-
-    def piece_hidden_fn(self):
-        """(params, arena, rows[L], ids[L, piece], lens[L], starts[L]) ->
-        (arena, x ``[L * piece, d]``, choices ``[expert layers, L * piece,
-        top_k]``), lane after lane: one prefill piece of each of ``L``
-        prompts, positions ``starts .. starts + lens`` of a lane's prompt
-        (``starts`` a multiple of the piece).  The embedding, ``wqkv``, the
-        latent layers' projections, ``wo`` and the feed-forward see all
-        lanes' positions as one batch: one plan and one pair of grouped
-        matmuls an expert layer, so the held experts are read once a
-        program; the mixers run a lane at a time, each from its own slot."""
-        import jax.numpy as jnp
-
-        n = self.piece
-
-        def piece(p, arena, rows, ids, lens, starts):
-            lanes = rows.shape[0]
-            live = (jnp.arange(n) < lens[:, None]).reshape(-1)
-            tile_m = self._piece_tile(lanes * n)
-            rows, starts, lens = ([t[i] for i in range(lanes)]
-                                  for t in (rows, starts, lens))
-            c_a, s_a, conv_a = arena["c"], arena["s"], arena["conv"]
-            x = p["embed"][ids.reshape(-1)].astype(jnp.float32)
-            routes = []
-            for li, lp in enumerate(p["layers"]):
-                kind, ki = self._layer_kind(li)
-                if kind == "rows":
-                    c_a, o = self._piece_latent_layer(lp, c_a, ki, rows,
-                                                      starts, x, None)
-                else:
-                    s_a, conv_a, o = self._piece_state_layer(
-                        lp, s_a, conv_a, ki, rows, starts, lens, x)
-                x, _, route = self._after_rows(lp, x, o, live, tile_m)
-                routes += route
-            return ({**arena, "c": c_a, "s": s_a, "conv": conv_a}, x,
-                    jnp.stack(routes))
-
-        return piece

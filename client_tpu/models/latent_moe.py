@@ -19,11 +19,10 @@ What does not differ between them lives here, in :class:`LatentMoeDecoder`:
   head q and k ``nope + rope`` wide, padded to whole tiles, v ``v_dim``).
   Piece i of a prompt has exactly ``i * piece`` rows before it, so a latent
   layer holds one branch a count (``lax.switch``) and computes nothing that is
-  masked; the switch is the layer's, not the model's.  A piece program holds
-  one prompt a call (``prefill_piece = (piece, 1)``, what
-  ``models/pangu_moe.py`` keeps) or as many as a model declares
-  (``models/kimi_linear.py``: two): the projections over every lane's rows at
-  once, the switch, the flash call and the rows' write a lane at a time;
+  masked; the switch is the layer's, not the model's.  Of a piece of several
+  lanes (models/experts.py ``piece_hidden_fn``) the projections see every
+  lane's rows at once; the switch, the flash call and the rows' write go a
+  lane at a time;
 - **the expert layer** beside its shared expert (``_ffn``): the router, the
   held experts' grouped matmuls, the lazily made weights, the wave's carry and
   its three counters, the final norm and head, and the words of a stream's
@@ -45,17 +44,15 @@ What does not differ between them lives here, in :class:`LatentMoeDecoder`:
   values instead of on which token won.
 
 A model supplies ``_queries_and_rows`` (its query path and what it does to
-positions), ``_after_rows`` (its norms around ``_ffn``), its weights and its
-piece.
+positions), ``_after_rows`` (its norms around ``_ffn``) and its weights; the
+piece program is models/experts.py's frame around ``_piece_rows_layer``.
 """
 
 from __future__ import annotations
 
 import math
 
-from client_tpu.models.experts import (RECORD_LOGITS, TILE_M_PIECE,  # noqa: F401
-                                       TILE_M_WAVE, ExpertDecoder,
-                                       SeededWeight, record_width, rms_norm)
+from client_tpu.models.experts import RECORD_LOGITS, ExpertDecoder
 
 _NEG_INF = -1e30
 
@@ -168,29 +165,17 @@ class LatentMoeDecoder(ExpertDecoder):
                 jnp.einsum("sr,hrv->shv", c, lp["wvb"],
                            preferred_element_type=jnp.float32))
 
-    def _swiglu(self, h, wgu, wd):
-        import jax
-
-        gu = self._mm(h, wgu)
-        f = gu.shape[-1] // 2
-        return self._mm(jax.nn.silu(gu[..., :f]) * gu[..., f:], wd)
-
     def _ffn(self, lp, f, live, tile_m):
         """The layer's feed-forward for normed rows f ``[n, d]`` -> (y,
         routing counts, choices): of a dense layer 0 and ``()``, of an expert
         layer its counts and ``(choices [n, k],)``."""
         if "wgu" in lp:
-            return self._swiglu(f, lp["wgu"], lp["wd"]), 0, ()
+            return self._dense_expert(f, lp["wgu"], lp["wd"]), 0, ()
         y, counts, top_i = self._experts(lp, f, live, tile_m)
-        return y + self._swiglu(f, lp["sgu"], lp["sd"]), counts, (top_i,)
+        return (y + self._dense_expert(f, lp["sgu"], lp["sd"]), counts,
+                (top_i,))
 
     # -- the decode step's parts (models/decoder.py) ---------------------------
-
-    def _after_attention(self, lp, x, o):
-        h, stats, route = self._after_rows(lp, x["h"], o, x["live"],
-                                           TILE_M_WAVE)
-        return {**x, "h": h, "stats": x["stats"] + stats,
-                "route": x["route"] + route}
 
     def _record(self, x, logits, tokens):
         """A wave's rows of the streams' record ``[B, stream_record]``."""
@@ -252,27 +237,38 @@ class LatentMoeDecoder(ExpertDecoder):
             "hqk,khd->qhd", jax.nn.softmax(s, -1),
             v.astype(jnp.float32)).reshape(n, h * self.v_dim)
 
+    def _full_rows_layer(self, lp, x, pos):
+        """A latent layer over a whole prompt, nothing cached (models/
+        experts.py ``make_apply_params``): dense scores."""
+        import jax.numpy as jnp
+
+        q_nope, q_rope, c, k_r = self._queries_and_rows(lp, x, pos)
+        own = self._cache_rows_of(c, k_r, jnp.dtype(self.dtype))
+        return self._piece_attention(lp, q_nope, q_rope, own, own[:0],
+                                     impl="einsum")
+
     def _piece_words(self, routes):
         """A piece's choices ``[expert layers, n, top_k]`` -> its record's
         words ``[n, expert layers]``: one word a layer."""
         return [self.held_mask(routes).T]
 
-    def _piece_latent_layer(self, lp, c_a, li, rows, starts, x, pos):
+    def _piece_rows_layer(self, lp, c_a, li, rows, starts, lens, x, pos):
         """A latent layer's part of a piece of ``L`` lanes, x ``[L * piece,
-        d]`` (``rows, starts``: a scalar a lane): the projections over every
-        lane's positions at once, then a lane at a time the piece's queries
-        against the slot's ``start`` rows before it and its own (one
+        d]`` (models/experts.py ``piece_hidden_fn``): the projections over
+        every lane's positions at once, then a lane at a time the piece's
+        queries against the slot's ``start`` rows before it and its own (one
         ``lax.switch`` branch a count of earlier rows: ``start`` is a
         multiple of the piece), its rows written behind them.  ``li`` is the
         layer's index into ``c_a``.  -> (c_a, o ``[L * piece, H * v_dim]``)."""
         import jax
         import jax.numpy as jnp
 
+        del lens
         n, w = self.piece, self.row_width
         q_nope, q_rope, c, k_r = self._queries_and_rows(lp, x, pos)
         rows_new, outs = self._cache_rows_of(c, k_r, c_a.dtype), []
-        for i, (row, start) in enumerate(zip(rows, starts)):
-            lane = slice(i * n, (i + 1) * n)
+        for i in range(rows.shape[0]):
+            row, start, lane = rows[i], starts[i], slice(i * n, (i + 1) * n)
             own = rows_new[lane]
 
             def attend(pre):        # (traced at once, by the switch below)

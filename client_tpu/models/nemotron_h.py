@@ -37,15 +37,11 @@ them, in the model's dtype, one row a slot), a \\* layer a ``"rows"`` layer
 leaf, no mixer.  **Decode** advances a wave's states in place
 (``ssd_wave_update``, or its oracle where the arena is not the kernels') and
 reads the lanes' rows with the grouped-query decode kernel.  **Prefill** is by
-pieces (``prefill_piece``), one prompt or two a call: every
-matrix product over positions (the projections, the shared expert, and above
-all the held experts' grouped matmuls) sees all lanes' positions as one batch
-and reads its weights once; the mixers run a lane at a time: an M layer runs
-the chunked form (``ssd_chunk_scan``) from the lane's slot's state and tail
-and writes both back (a padded position has ``dt = 0``: it moves nothing, and
-the tail is that of the last valid positions; a prompt's first piece starts
-from zeros); a \\* layer is models/grouped_query.py's, a lane's own count
-of rows before it.
+pieces (models/experts.py's frame; this backend declares two lanes): an M
+layer's part is models/state_layer.py's around the chunked form
+(``ssd_chunk_scan``; a padded position has ``dt = 0``: it moves nothing), a
+\\* layer's models/grouped_query.py's, an E layer the expert block over all
+lanes' positions at once.
 
 The projection's ``xBC`` is rounded to the model's dtype before the
 convolution, in a wave and in a piece alike: the tail a slot carries is then
@@ -56,15 +52,17 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.experts import (TILE_M_PIECE, TILE_M_WAVE,
-                                       ExpertDecoder, record_width, rms_norm)
+from client_tpu.models.experts import (TILE_M_WAVE, ExpertDecoder,
+                                       record_width)
 from client_tpu.models.grouped_query import GroupedQueryPieces
+from client_tpu.models.layers import rms_norm
+from client_tpu.models.state_layer import StateLayer
 from client_tpu.ops.ssd import CHUNK
 
 _KINDS = {"M": "state", "*": "rows", "E": "none"}
 
 
-class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
+class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
     """The decoder above (``models/decoder.py`` for what it is served
     through).  ``pattern`` is the published ``hybrid_override_pattern`` as it
     stands, of which the first ``n_layers`` letters are served (the rest name
@@ -106,6 +104,8 @@ class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
         self.m_heads, self.m_dim = int(mamba_heads), int(mamba_head_dim)
         self.n_groups, self.state_size = int(n_groups), int(state_size)
         self.taps = int(conv_kernel)
+        # (``S^T [N, P]`` a head: ops/ssd.py.)
+        self.state_shape = (self.m_heads, self.state_size, self.m_dim)
         # The gated norm's groups: those of B and C.
         self.norm_groups = self.n_groups
         self.d_inner = self.m_heads * self.m_dim
@@ -209,31 +209,17 @@ class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
         ``[n, Hkv, D]`` float32; no position enters (``pos`` is there for the
         benchmark's control that serves a rotated reading)."""
         del pos
-        h = rms_norm(x, lp["ln"], self.rms_eps)
-        n = x.shape[0]
-        return (self._mm(h, lp["wq"]).reshape(n, self.n_heads, self.head_dim),
-                self._mm(h, lp["wk"]).reshape(n, self.n_kv_heads,
-                                              self.head_dim),
-                self._mm(h, lp["wv"]).reshape(n, self.n_kv_heads,
-                                              self.head_dim))
+        return self._heads(lp, rms_norm(x, lp["ln"], self.rms_eps))
 
-    def _ssm_project(self, lp, x, dtype):
-        """An M layer's x ``[..., d]`` float32 -> the gate z ``[..., d_inner]``
-        float32, the convolution's new inputs ``[..., conv_dim]`` in the
-        cache's ``dtype`` and ``dt [..., H]`` float32 (after the softplus)."""
-        import jax
-
-        h = rms_norm(x, lp["ln"], self.rms_eps)
-        return (self._mm(h, lp["wz"]), self._mm(h, lp["wxbc"]).astype(dtype),
-                jax.nn.softplus(self._mm(h, lp["wdt"]) + lp["dt_bias"]))
-
-    def _ssm_inputs(self, lp, ext, n):
+    def _state_inputs(self, lp, beside, ext):
         """The convolution's inputs ext ``[..., n + taps - 1, conv_dim]`` (the
         tail, then these rows' projections) -> x ``[..., n, H, P]``, B, C
-        ``[..., n, G, N]`` float32."""
+        ``[..., n, G, N]`` float32 (models/state_layer.py)."""
         import jax
         import jax.numpy as jnp
 
+        del beside
+        n = ext.shape[-2] - self.taps + 1
         ext = ext.astype(jnp.float32)
         taps = lp["conv"].astype(jnp.float32)
         mixed = jax.nn.silu(
@@ -269,13 +255,15 @@ class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
 
     # -- the decode step's parts (models/decoder.py) ---------------------------
 
-    def _qkv(self, lp, x, pos):
-        return self._project(lp, x["h"], pos)
+    def _after_rows(self, lp, h, o, live, tile_m):
+        """A mixer layer's tail: the residual add and nothing else (no
+        routing: the experts are layers of their own)."""
+        return h + self._mm(o, lp["wo"]), 0, ()
 
     def _after_attention(self, lp, x, o):
-        """A mixer layer's tail: the residual add and nothing else."""
-        return {**x, "h": x["h"] + self._mm(o.reshape(o.shape[0], -1),
-                                            lp["wo"])}
+        h, _, _ = self._after_rows(lp, x["h"], o.reshape(o.shape[0], -1),
+                                   x["live"], TILE_M_WAVE)
+        return {**x, "h": h}
 
     def _feed_forward(self, lp, x):
         h, stats, top_i = self._expert_block(lp, x["h"], x["live"],
@@ -283,84 +271,34 @@ class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
         return {**x, "h": h, "stats": x["stats"] + stats,
                 "route": x["route"] + (top_i,)}
 
-    def _advance(self, lp, x, s_a, conv_a, rows, lens, ki):
-        """An M layer's part of a wave: each lane's projection joins its
-        slot's tail (models/decoder.py ``slot_tails``), the slot's state moves
-        one position in place (ops/ssd.py: the kernel, or its oracle where the
-        arena is not the kernels')."""
+    # -- the state layer's parts (models/state_layer.py) --------------------------
+
+    def _state_ops(self):
+        from client_tpu.ops.ssd import (reference_ssd_update, ssd_chunk_scan,
+                                        ssd_recurrence, ssd_wave_update)
+
+        return (ssd_wave_update, reference_ssd_update, ssd_chunk_scan,
+                ssd_recurrence)
+
+    def _state_project(self, lp, x, dtype):
+        """An M layer's x ``[n, d]`` float32 -> the convolution's new inputs
+        ``xBC [n, conv_dim]`` in the cache's ``dtype``, nothing the
+        convolution reads beside them, and what goes round it: the gate z
+        ``[n, d_inner]`` and ``dt [n, H]`` (after the softplus), float32."""
+        import jax
+
+        h = rms_norm(x, lp["ln"], self.rms_eps)
+        z, new = self._mm(h, lp["wz"]), self._mm(h, lp["wxbc"]).astype(dtype)
+        dt = jax.nn.softplus(self._mm(h, lp["wdt"]) + lp["dt_bias"])
+        return new, None, (z, dt)
+
+    def _through_state(self, lp, ins, aside, run, pad):
+        """A padded position has ``dt = 0``: it moves nothing."""
         import jax.numpy as jnp
 
-        from client_tpu.engine.backend_init import pallas_interpret
-        from client_tpu.models.decoder import put_slot_tails, slot_tails
-        from client_tpu.ops.ssd import reference_ssd_update, ssd_wave_update
-
-        del lens
-        z, new, dt = self._ssm_project(lp, x["h"], conv_a.dtype)
-        lanes = new.shape[0]
-        pick, slots, tail = slot_tails(conv_a, ki, rows)
-        ext = jnp.concatenate(
-            [tail.reshape(lanes, self.taps - 1, self.conv_dim),
-             new[:, None]], axis=1)
-        xs, b, c = (t[:, 0] for t in self._ssm_inputs(lp, ext, 1))
-        conv_a = put_slot_tails(conv_a, ki, pick, slots, ext)
-        a = -jnp.exp(lp["a_log"])
-        if self._use_kernel():
-            s_a, y = ssd_wave_update(s_a, xs, dt, a, b, c, rows, layer=ki,
-                                     interpret=pallas_interpret())
-        else:
-            s_a, y = reference_ssd_update(s_a, xs, dt, a, b, c, rows,
-                                          layer=ki)
-        return s_a, conv_a, self._ssm_output(lp, y, xs, z)
-
-    # -- full-context forward (no cache) ----------------------------------------
-
-    def make_apply_params(self):
-        """Full-context forward in the served precision: no cache, no pieces,
-        the state walked position by position.  Logits of every position,
-        and each expert layer's choices ``[expert layers, n, top_k]``.
-        Model-level entry for diagnostics; serving goes through pieces and
-        waves."""
-        params = self.place_params(self.load_or_init_params(self._init_params))
-
-        def apply(p, inputs):
-            import jax.numpy as jnp
-
-            from client_tpu.ops.ssd import ssd_recurrence
-
-            ids = inputs["INPUT_IDS"].astype("int32")
-            n = ids.shape[0]
-            live = jnp.ones(n, bool)
-            cdt = jnp.dtype(self.dtype)
-            hd = self.n_kv_heads * self.head_dim
-            x = p["embed"][ids].astype(jnp.float32)
-            routes = []
-            for lp, kind in zip(p["layers"], self.layer_kinds):
-                if kind == "none":
-                    x, _, top_i = self._expert_block(lp, x, live,
-                                                     TILE_M_PIECE)
-                    routes.append(top_i)
-                    continue
-                if kind == "state":
-                    z, new, dt = self._ssm_project(lp, x, cdt)
-                    ext = jnp.concatenate(
-                        [jnp.zeros((self.taps - 1, self.conv_dim), cdt), new])
-                    xs, b, c = self._ssm_inputs(lp, ext, n)
-                    zero = jnp.zeros((self.m_heads, self.state_size,
-                                      self.m_dim), jnp.float32)
-                    y, _ = ssd_recurrence(xs, dt, -jnp.exp(lp["a_log"]), b,
-                                          c, zero)
-                    o = self._ssm_output(lp, y, xs, z)
-                else:
-                    q, k, v = self._project(lp, x, jnp.arange(n))
-                    own_k, own_v = (t.reshape(n, hd).astype(cdt)
-                                    for t in (k, v))
-                    o = self._attend(q, own_k, own_v, own_k[:0], own_v[:0],
-                                     None, impl="einsum")
-                x = x + self._mm(o, lp["wo"])
-            return {"logits": self._logits(p, x),
-                    "routing": jnp.stack(routes)}
-
-        return apply, params
+        (xs, b, c), (z, dt) = ins, aside
+        y = run(xs, pad(dt), -jnp.exp(lp["a_log"]), b, c)
+        return self._ssm_output(lp, y, xs, z)
 
     # -- generative interface (used by GenerativeScheduler) -------------------
 
@@ -383,89 +321,3 @@ class NemotronHBackend(GroupedQueryPieces, ExpertDecoder):
             "conv": jnp.zeros((n_state, r, (self.taps - 1) * self.conv_dim),
                               dt),
             "tok": jnp.zeros(r, jnp.int32)}
-
-    def _piece_state_layer(self, lp, s_a, conv_a, ki, rows, fresh, lens, x):
-        """An M layer's part of a piece of ``L`` lanes, x ``[L * piece, d]``:
-        the projections over every lane's positions at once, then a lane at a
-        time the convolution, the chunked form from its slot's state and tail
-        (zeros for a prompt's first piece), both written back, and the gated
-        norm.  -> (s_a, conv_a, o ``[L * piece, d_inner]``)."""
-        import jax
-        import jax.numpy as jnp
-
-        from client_tpu.ops.ssd import ssd_chunk_scan
-
-        n = self.piece
-        z, new, dt = self._ssm_project(lp, x, conv_a.dtype)
-        a, outs = -jnp.exp(lp["a_log"]), []
-        for i in range(rows.shape[0]):
-            own = slice(i * n, (i + 1) * n)
-            valid = jnp.arange(n) < lens[i]
-            tail = jnp.where(fresh[i], 0, conv_a[ki, rows[i]]).reshape(
-                -1, self.conv_dim)
-            ext = jnp.concatenate([tail, new[own]])
-            xs, b, c = self._ssm_inputs(lp, ext, n)
-            y, s = ssd_chunk_scan(
-                xs, jnp.where(valid[:, None], dt[own], 0.0), a, b, c,
-                jnp.where(fresh[i], 0.0, s_a[ki, rows[i]]), chunk=self.chunk)
-            s_a = jax.lax.dynamic_update_slice(
-                s_a, s.astype(s_a.dtype)[None, None], (ki, rows[i], 0, 0, 0))
-            # The inputs of the last valid positions (with the old tail's,
-            # where the piece holds fewer than a tail).
-            tail = jax.lax.dynamic_slice(ext, (lens[i], 0),
-                                         (self.taps - 1, self.conv_dim))
-            conv_a = jax.lax.dynamic_update_slice(
-                conv_a, tail.reshape(1, 1, -1), (ki, rows[i], 0))
-            outs.append(self._ssm_output(lp, y, xs, z[own]))
-        return s_a, conv_a, jnp.concatenate(outs)
-
-    def piece_hidden_fn(self):
-        """(params, arena, rows[L], ids[L, piece], lens[L], starts[L]) ->
-        (arena, x ``[L * piece, d]``, choices ``[expert layers, L * piece,
-        top_k]``), lane after lane: one prefill piece of each of ``L``
-        prompts, positions ``starts .. starts + lens`` of a lane's prompt
-        (``starts`` a multiple of the piece).  Whatever is a matrix product
-        over positions sees all lanes' positions as one batch, so a weight,
-        and above all a layer's held experts, is read once a program; the
-        mixers run a lane at a time, each from its own slot."""
-        import jax.numpy as jnp
-
-        n = self.piece
-        hd = self.n_kv_heads * self.head_dim
-
-        def piece(p, arena, rows, ids, lens, starts):
-            lanes = rows.shape[0]
-            at = jnp.arange(n)
-            live = (at < lens[:, None]).reshape(-1)
-            tile_m = self._piece_tile(lanes * n)
-            k_a, v_a = arena["k"], arena["v"]
-            s_a, conv_a = arena["s"], arena["conv"]
-            x = p["embed"][ids.reshape(-1)].astype(jnp.float32)
-            routes = []
-            for li, lp in enumerate(p["layers"]):
-                kind, ki = self._layer_kind(li)
-                if kind == "none":
-                    x, _, top_i = self._expert_block(lp, x, live, tile_m)
-                    routes.append(top_i)
-                    continue
-                if kind == "state":
-                    s_a, conv_a, o = self._piece_state_layer(
-                        lp, s_a, conv_a, ki, rows, starts == 0, lens, x)
-                else:
-                    q, k, v = self._project(
-                        lp, x, (starts[:, None] + at).reshape(-1))
-                    own_k, own_v = (t.reshape(-1, hd).astype(k_a.dtype)
-                                    for t in (k, v))
-                    outs = []
-                    for i in range(lanes):
-                        own = slice(i * n, (i + 1) * n)
-                        k_a, v_a, o = self._piece_rows(
-                            k_a, v_a, ki, rows[i], starts[i], q[own],
-                            own_k[own], own_v[own])
-                        outs.append(o)
-                    o = jnp.concatenate(outs)
-                x = x + self._mm(o, lp["wo"])
-            return ({**arena, "k": k_a, "v": v_a, "s": s_a, "conv": conv_a},
-                    x, jnp.stack(routes))
-
-        return piece

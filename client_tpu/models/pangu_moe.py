@@ -36,13 +36,10 @@ head, scores ``[q_lat | q_rope] . row``, ``o_lat = p c``, ``o_h = o_lat
 W_vb`` (``latent_attention`` of models/decoder.py's contract; the kernel is
 ops/decode_kernel.py ``latent_wave_attention``, which takes the query and
 leaves ``o_lat`` with the heads along the minor axis: the two einsums write
-and read that layout).  **Prefill does not**: a
-piece of ``piece`` positions computes ``k_nope`` and ``v`` of its own rows and,
-from the cache, of the rows before it, and attends with the flash kernel
-(per head q and k ``nope + rope`` wide, padded to whole tiles, v ``v_dim``).
-A prompt is consumed a piece at a time (``prefill_piece``), one lane a call;
-piece i of a prompt has exactly ``i * piece`` rows before it, so the program
-holds one branch a count (``lax.switch``) and computes nothing that is masked.
+and read that layout).  **Prefill does not**: a prompt is consumed a piece of
+``piece`` positions at a time (``prefill_piece``, one lane a call), each by
+the flash kernel against the rows before it and its own: models/latent_moe.py
+``_piece_rows_layer`` inside models/experts.py's piece frame.
 
 What this decoder shares with ``models/kimi_linear.py`` (the cache's rows and
 the absorbed products, the piece's attention, routing and the grouped expert
@@ -62,9 +59,8 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.evabyte import rope
-from client_tpu.models.latent_moe import (_NEG_INF, TILE_M_PIECE,
-                                          LatentMoeDecoder, rms_norm)
+from client_tpu.models.latent_moe import LatentMoeDecoder
+from client_tpu.models.layers import rms_norm, rope
 
 
 class PanguMoeBackend(LatentMoeDecoder):
@@ -180,45 +176,6 @@ class PanguMoeBackend(LatentMoeDecoder):
         return (h + rms_norm(y, lp["ln4"], self.rms_eps),
                 jnp.asarray(counts, jnp.int32), route)
 
-    # -- full-context forward (no cache) ----------------------------------------
-
-    def make_apply_params(self):
-        """Full-context forward in the served precision, no cache, no
-        pieces and nothing absorbed: logits of every position, and each
-        expert layer's choices ``[layers, n, top_k]``.  Model-level entry for
-        warm-up and diagnostics; serving goes through pieces and waves."""
-        params = self.place_params(self.load_or_init_params(self._init_params))
-
-        def apply(p, inputs):
-            import jax
-            import jax.numpy as jnp
-
-            ids = inputs["INPUT_IDS"].astype("int32")
-            n = ids.shape[0]
-            pos = jnp.arange(n)
-            live = jnp.ones(n, bool)
-            causal = pos[None, :] <= pos[:, None]
-            x = p["embed"][ids].astype(jnp.float32)
-            routes = []
-            for lp in p["layers"]:
-                q_nope, q_rope, c, k_r = self._queries_and_rows(lp, x, pos)
-                row = self._cache_rows_of(c, k_r, jnp.dtype(self.dtype))
-                c_c = row[:, :self.kv_rank]
-                k_r = row[:, self.kv_rank:self.kv_rank + self.rope_dim]
-                k_nope, v = self._keys_values(lp, c_c)
-                s = (jnp.einsum("qhd,khd->hqk", q_nope, k_nope)
-                     + jnp.einsum("qhd,kd->hqk", q_rope,
-                                  k_r.astype(jnp.float32))) * self.sm_scale
-                s = jnp.where(causal[None], s, _NEG_INF)
-                o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), v)
-                x, _, route = self._after_rows(lp, x, o.reshape(n, -1), live,
-                                               TILE_M_PIECE)
-                routes += route
-            return {"logits": self._logits(p, x),
-                    "routing": jnp.stack(routes)}
-
-        return apply, params
-
     # -- generative interface (used by GenerativeScheduler) -------------------
 
     def init_arena(self, capacity: int):
@@ -231,29 +188,3 @@ class PanguMoeBackend(LatentMoeDecoder):
                  self.row_width)
         return {"c": jnp.zeros(shape, jnp.dtype(self.dtype)),
                 "tok": jnp.zeros(capacity + 1, jnp.int32)}
-
-    def piece_hidden_fn(self):
-        """(params, arena, rows[1], ids[1, piece], lens[1], starts[1]) ->
-        (arena, x ``[piece, d]``, choices ``[expert layers, piece, top_k]``):
-        one prefill piece, positions ``starts .. starts + lens`` of the
-        lane's prompt (``starts`` a multiple of the piece)."""
-        import jax.numpy as jnp
-
-        n = self.piece
-
-        def piece(p, arena, rows, ids, lens, starts):
-            row, start = rows[0], starts[0]
-            pos = start + jnp.arange(n)
-            live = jnp.arange(n) < lens[0]
-            c_a = arena["c"]
-            x = p["embed"][ids[0]].astype(jnp.float32)
-            routes = []
-            for li, lp in enumerate(p["layers"]):
-                c_a, o = self._piece_latent_layer(lp, c_a, li, [row],
-                                                  [start], x, pos)
-                x, _, route = self._after_rows(lp, x, o, live,
-                                               TILE_M_PIECE)
-                routes += route
-            return {**arena, "c": c_a}, x, jnp.stack(routes)
-
-        return piece

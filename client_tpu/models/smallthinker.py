@@ -66,10 +66,9 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.evabyte import rope
-from client_tpu.models.experts import (TILE_M_PIECE, TILE_M_WAVE,
-                                       ExpertDecoder, record_width, rms_norm)
+from client_tpu.models.experts import ExpertDecoder, record_width
 from client_tpu.models.grouped_query import GroupedQueryPieces
+from client_tpu.models.layers import rms_norm, rope
 
 
 class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
@@ -182,15 +181,12 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
 
     # -- the model's own blocks -------------------------------------------------
 
-    def _project(self, lp, x, pos, rotate: bool):
+    def _project(self, lp, x, pos, kind: str = "rows"):
         """x ``[n, d]`` float32 -> q ``[n, H, D]``, k, v ``[n, Hkv, D]``
-        float32, q and k rotated to ``pos`` where the layer rotates."""
-        h = rms_norm(x, lp["ln1"], self.rms_eps)
-        n = x.shape[0]
-        q = self._mm(h, lp["wq"]).reshape(n, self.n_heads, self.head_dim)
-        k = self._mm(h, lp["wk"]).reshape(n, self.n_kv_heads, self.head_dim)
-        v = self._mm(h, lp["wv"]).reshape(n, self.n_kv_heads, self.head_dim)
-        if rotate:
+        float32, q and k rotated to ``pos`` where the layers of the ``kind``
+        rotate."""
+        q, k, v = self._heads(lp, rms_norm(x, lp["ln1"], self.rms_eps))
+        if self.rotate[kind]:
             q, k = rope(q, pos, self.rope_theta), rope(k, pos,
                                                        self.rope_theta)
         return q, k, v
@@ -205,47 +201,41 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
     def _after_rows(self, lp, x, o, live, tile_m):
         """The block behind its attention, for rows x ``[n, d]`` (the
         layer's input) and their heads' outputs o ``[n, H * D]`` -> (x,
-        routing counts, choices ``[n, k]``).  The router reads what the
+        routing counts, (choices ``[n, k]``,)).  The router reads what the
         attention read."""
         routing = self.route(lp, self._router_input(lp, x))
         x = x + self._mm(o, lp["wo"])
         y, counts, top_i = self._experts(
             lp, rms_norm(x, lp["ln2"], self.rms_eps), live, tile_m,
             routing=routing)
-        return x + y, counts, top_i
+        return x + y, counts, (top_i,)
 
     # -- the decode step's parts (models/decoder.py) ---------------------------
 
-    def _qkv(self, lp, x, pos):
-        return self._project(lp, x["h"], pos, self.rotate["rows"])
-
     def _ring_qkv(self, lp, x, pos):
-        return self._project(lp, x["h"], pos, self.rotate["ring"])
-
-    def _after_attention(self, lp, x, o):
-        h, stats, top_i = self._after_rows(
-            lp, x["h"], o.reshape(o.shape[0], -1), x["live"], TILE_M_WAVE)
-        return {**x, "h": h, "stats": x["stats"] + stats,
-                "route": x["route"] + (top_i,)}
+        return self._project(lp, x["h"], pos, "ring")
 
     # -- a piece's attention (models/grouped_query.py) ----------------------------
 
-    def _piece_layer(self, lp, leaves, kind, ki, row, start, n_valid, x,
-                     pos):
-        """One layer's attention for a piece, its rows written: ``leaves`` the
-        (K, V) leaves of the layer's kind, ``ki`` its index among them.  ->
-        (K leaf, V leaf, o ``[piece, H * D]``)."""
+    def _piece_ring_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
+        """A window layer's part of a piece (models/experts.py
+        ``piece_hidden_fn``)."""
+        return self._lane_by_lane(
+            self._piece_ring, self._project(lp, x, pos, "ring"), k_a, v_a, ki,
+            rows, starts, lens)
+
+    def _full_ring_layer(self, lp, x, pos):
+        return self._full_layer(self._project(lp, x, pos, "ring"), self.window)
+
+    def _piece_ring(self, k_a, v_a, ki, row, start, n_valid, q, own_k, own_v):
+        """A window layer's part of one lane's piece: q ``[piece, H, D]``
+        against the slot's ring and, causally, its own ``own_k, own_v [piece,
+        Hkv*D]`` (as the cache holds them), which are written into the ring.
+        -> (K leaf, V leaf, o ``[piece, H * D]``)."""
         import jax
         import jax.numpy as jnp
 
-        n, ring = self.piece, kind == "ring"
-        hd = self.n_kv_heads * self.head_dim
-        k_a, v_a = leaves
-        q, k, v = self._project(lp, x, pos, self.rotate[kind])
-        own_k, own_v = (t.reshape(n, hd).astype(k_a.dtype) for t in (k, v))
-
-        if not ring:
-            return self._piece_rows(k_a, v_a, ki, row, start, q, own_k, own_v)
+        n, hd = self.piece, self.n_kv_heads * self.head_dim
 
         def attend(pre, rolled=False):
             before = [self._rows_before(leaf, ki, row, pre)
@@ -274,39 +264,6 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
             for leaf, own in ((k_a, own_k), (v_a, own_v)))
         return k_a, v_a, o
 
-    # -- full-context forward (no cache) ----------------------------------------
-
-    def make_apply_params(self):
-        """Full-context forward in the served precision, no cache and no
-        pieces: logits of every position, and each layer's choices
-        ``[layers, n, top_k]``.  Model-level entry for warm-up and
-        diagnostics; serving goes through pieces and waves."""
-        params = self.place_params(self.load_or_init_params(self._init_params))
-
-        def apply(p, inputs):
-            import jax.numpy as jnp
-
-            ids = inputs["INPUT_IDS"].astype("int32")
-            n = ids.shape[0]
-            pos = jnp.arange(n)
-            live = jnp.ones(n, bool)
-            cdt = jnp.dtype(self.dtype)
-            hd = self.n_kv_heads * self.head_dim
-            x = p["embed"][ids].astype(jnp.float32)
-            routes = []
-            for lp, kind in zip(p["layers"], self.layer_kinds):
-                q, k, v = self._project(lp, x, pos, self.rotate[kind])
-                own_k, own_v = (t.reshape(n, hd).astype(cdt) for t in (k, v))
-                o = self._attend(q, own_k, own_v, own_k[:0], own_v[:0],
-                                 self.window if kind == "ring" else None,
-                                 impl="einsum")
-                x, _, top_i = self._after_rows(lp, x, o, live, TILE_M_PIECE)
-                routes.append(top_i)
-            return {"logits": self._logits(p, x),
-                    "routing": jnp.stack(routes)}
-
-        return apply, params
-
     # -- generative interface (used by GenerativeScheduler) -------------------
 
     def init_arena(self, capacity: int):
@@ -324,32 +281,3 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
         return {"kg": jnp.zeros(whole, dt), "vg": jnp.zeros(whole, dt),
                 "kw": jnp.zeros(ring, dt), "vw": jnp.zeros(ring, dt),
                 "tok": jnp.zeros(r, jnp.int32)}
-
-    def piece_hidden_fn(self):
-        """(params, arena, rows[1], ids[1, piece], lens[1], starts[1]) ->
-        (arena, x ``[piece, d]``, choices ``[layers, piece, top_k]``): one
-        prefill piece, positions ``starts .. starts + lens`` of the lane's
-        prompt (``starts`` a multiple of the piece)."""
-        import jax.numpy as jnp
-
-        n = self.piece
-
-        def piece(p, arena, rows, ids, lens, starts):
-            row, start = rows[0], starts[0]
-            pos = start + jnp.arange(n)
-            live = jnp.arange(n) < lens[0]
-            leaves = {"rows": [arena["kg"], arena["vg"]],
-                      "ring": [arena["kw"], arena["vw"]]}
-            x = p["embed"][ids[0]].astype(jnp.float32)
-            routes = []
-            for li, lp in enumerate(p["layers"]):
-                kind, ki = self._layer_kind(li)
-                *leaves[kind], o = self._piece_layer(
-                    lp, leaves[kind], kind, ki, row, start, lens[0], x, pos)
-                x, _, top_i = self._after_rows(lp, x, o, live, TILE_M_PIECE)
-                routes.append(top_i)
-            (kg, vg), (kw, vw) = leaves["rows"], leaves["ring"]
-            return ({**arena, "kg": kg, "vg": vg, "kw": kw, "vw": vw}, x,
-                    jnp.stack(routes))
-
-        return piece

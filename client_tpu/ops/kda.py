@@ -9,10 +9,11 @@ head)::
     S  = S' + k u^T            o = S^T q
 
 - :func:`kda_wave_update`: one position of every lane of a decode wave, on the
-  arena's state leaf ``[L, R, H, d_k, d_v]`` **in place**: a Pallas kernel,
-  one grid over lanes and head blocks; a slot's block of heads is read once,
-  decayed, corrected and read out while it is in VMEM, and written once to
-  where it came from (``input_output_aliases``).  :func:`reference_kda_update`
+  arena's state leaf ``[L, R, H, d_k, d_v]`` **in place**: a Pallas kernel
+  (its grid, index maps and aliasing are ops/state_wave.py's, shared with
+  ops/ssd.py); a slot's block of heads is read once, decayed, corrected and
+  read out while it is in VMEM, and written once to where it came from.
+  :func:`reference_kda_update`
   is its ``jax.numpy`` oracle (gather, the four lines above, scatter).
 - :func:`kda_chunk_scan`: ``n`` positions of one sequence from a start state,
   in chunks of ``C`` (plain ``jax.numpy``; the state alone walks the chunks,
@@ -47,11 +48,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# Heads of one grid step of the wave kernel: 32 heads of 128 x 128 float32 are
-# 2 MB of state in and as much out, double-buffered 8 MB of VMEM (on the v5e a
-# call of 256 lanes read 1.93 ms at 8 heads a step, 1.73 at 16, 1.72 at 32:
-# PERF.md section 6, PR 34).  A model of fewer heads takes them all at once.
-HEAD_BLOCK = 32
+from client_tpu.ops.state_wave import HEAD_BLOCK, state_wave_call
+
 # Positions of a chunk of the chunked form (the pairwise tensor bounds it: see
 # above; on the v5e a layer's piece of 512 read 1.07 ms at 8, 0.88 at 16, 0.93
 # at 32, 1.56 at 64: PERF.md section 6, PR 34).
@@ -123,9 +121,6 @@ def kda_wave_update(s_arena, q, k, v, g, beta, rows, *, layer,
     [B, H, d_v])`` float32: slot ``rows[b]``'s state advanced one position
     and read by ``q``.  Lanes that follow one another on one slot (padded
     lanes on the junk slot) move its block once and leave junk there."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     _, _, n_heads, d_k, d_v = s_arena.shape
     bsz = q.shape[0]
     hb = min(HEAD_BLOCK, n_heads)
@@ -141,36 +136,9 @@ def kda_wave_update(s_arena, q, k, v, g, beta, rows, *, layer,
                     axis=1)
     vec = vec.reshape(bsz, 5, nb, hb, d_k).swapaxes(1, 2).reshape(
         bsz, nb, 5 * hb, d_k)
-    prefetch = (rows.astype(jnp.int32),
-                jnp.asarray(layer, jnp.int32).reshape(1))
-
-    def lane_map(b, ih, rows, layer):
-        return (b, ih, 0, 0)
-
-    def state_map(b, ih, rows, layer):
-        return (layer[0], rows[b], ih, 0, 0)
-
-    state_spec = pl.BlockSpec((None, None, hb, d_k, d_v), state_map)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(bsz, nb),
-        in_specs=[pl.BlockSpec((None, None, 5 * hb, d_k), lane_map),
-                  state_spec],
-        out_specs=[state_spec,
-                   pl.BlockSpec((None, None, hb, d_v), lane_map)],
-    )
-    block_bytes = hb * d_k * d_v * s_arena.dtype.itemsize
-    s_out, o = pl.pallas_call(
-        functools.partial(_wave_kernel, heads=hb),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(s_arena.shape, s_arena.dtype),
-                   jax.ShapeDtypeStruct((bsz, nb, hb, d_v), f32)],
-        input_output_aliases={len(prefetch) + 1: 0},
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=min(100 << 20, 6 * block_bytes + (16 << 20))),
-        interpret=interpret,
-        name="kda_wave_update",
-    )(*prefetch, vec, s_arena)
+    s_out, o = state_wave_call(
+        functools.partial(_wave_kernel, heads=hb), "kda_wave_update",
+        s_arena, (vec,), rows, layer, hb, interpret=interpret)
     return s_out, o.reshape(bsz, n_heads, d_v)
 
 

@@ -22,9 +22,10 @@ x`` and ``y`` are whole rows of lanes, ``B`` and ``C`` columns (one transpose
 a grid step, as ops/kda.py), and the sum runs down the sublanes.
 
 - :func:`ssd_wave_update`: one position of every lane of a decode wave, on the
-  packed leaf **in place**: a Pallas kernel, one grid over lanes and blocks of
-  packed heads; a slot's block is read once, advanced and read out while it is
-  in VMEM, and written once to where it came from (``input_output_aliases``).
+  packed leaf **in place**: a Pallas kernel (its grid over lanes and blocks
+  of packed heads, index maps and aliasing are ops/state_wave.py's, shared
+  with ops/kda.py); a slot's block is read once, advanced and read out while
+  it is in VMEM, and written once to where it came from.
   :func:`reference_ssd_update` is its ``jax.numpy`` oracle.
 - :func:`ssd_chunk_scan`: ``n`` positions of one sequence from a start state,
   in chunks of ``C`` positions (plain ``jax.numpy``; the state alone walks the
@@ -50,11 +51,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# Packed heads of one grid step of the wave kernel: 32 of 128 x 128 float32
-# are 2 MB of state in and as much out, double-buffered 8 MB of VMEM
-# (ops/kda.py's block, which read 1.72 ms a call of 256 lanes there).  A
-# model of fewer takes them all at once.
-HEAD_BLOCK = 32
+from client_tpu.ops.state_wave import HEAD_BLOCK, state_wave_call
+
 # Positions of a chunk of the chunked form: the published ``chunk_size``.
 CHUNK = 128
 
@@ -140,9 +138,6 @@ def ssd_wave_update(s_arena, x, dt, a, b, c, rows, *, layer,
     [B, H, P])`` float32: slot ``rows[b]``'s state advanced one position and
     read by ``c``.  Lanes that follow one another on one slot (padded lanes on
     the junk slot) move its block once and leave junk there."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     _, _, packed, n_state, width = s_arena.shape
     bsz, n_heads, p = x.shape
     groups = b.shape[1]
@@ -166,37 +161,10 @@ def ssd_wave_update(s_arena, x, dt, a, b, c, rows, *, layer,
     bc = jnp.stack([b.astype(f32), c.astype(f32)], axis=1)     # [B, 2, G, N]
     bc = bc.reshape(bsz, 2, nb, gb, n_state).swapaxes(1, 2).reshape(
         bsz, nb, 2 * gb, n_state)
-    prefetch = (rows.astype(jnp.int32),
-                jnp.asarray(layer, jnp.int32).reshape(1))
-
-    def lane_map(b, ih, rows, layer):
-        return (b, ih, 0, 0)
-
-    def state_map(b, ih, rows, layer):
-        return (layer[0], rows[b], ih, 0, 0)
-
-    state_spec = pl.BlockSpec((None, None, hb, n_state, width), state_map)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(bsz, nb),
-        in_specs=[pl.BlockSpec((None, None, 2 * hb, width), lane_map),
-                  pl.BlockSpec((None, None, 2 * gb, n_state), lane_map),
-                  state_spec],
-        out_specs=[state_spec,
-                   pl.BlockSpec((None, None, hb, width), lane_map)],
-    )
-    block_bytes = hb * n_state * width * s_arena.dtype.itemsize
-    s_out, y = pl.pallas_call(
+    s_out, y = state_wave_call(
         functools.partial(_wave_kernel, heads=hb, per_group=per_group),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(s_arena.shape, s_arena.dtype),
-                   jax.ShapeDtypeStruct((bsz, nb, hb, width), f32)],
-        input_output_aliases={len(prefetch) + 2: 0},
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=min(100 << 20, 6 * block_bytes + (16 << 20))),
-        interpret=interpret,
-        name="ssd_wave_update",
-    )(*prefetch, vec, bc, s_arena)
+        "ssd_wave_update", s_arena, (vec, bc), rows, layer, hb,
+        interpret=interpret)
     return s_out, y.reshape(bsz, n_heads, p)
 
 

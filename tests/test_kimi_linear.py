@@ -634,8 +634,8 @@ def test_the_configuration_file_is_the_catalog_row_but_for_its_cuts():
             assert cfg[key] == value, key
     # The harness's name for the experts held, beside the published key.
     assert cfg["n_routed_experts"] == cfg["num_experts"] == 32
-    from client_tpu.models import latent_moe
-    assert cfg["serve"]["expert_tile_rows"] == latent_moe.TILE_M_WAVE
+    from client_tpu.models import experts
+    assert cfg["serve"]["expert_tile_rows"] == experts.TILE_M_WAVE
 
 
 def test_the_backend_built_from_the_file_is_the_issues_arena():

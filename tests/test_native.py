@@ -60,6 +60,14 @@ def native_build():
 def server():
     eng = TpuEngine(build_repository(
         ["simple", "simple_string", "simple_sequence"]))
+    # A sequence may stand idle for a minute here, not the default second:
+    # on a host that other test workers load, a sequence of the load
+    # generator's waits longer than that between two of its requests, is
+    # collected, and its next request is refused ("request without start
+    # flag for an inactive sequence": what
+    # test_perf_analyzer_num_of_sequences_rate_mode met).
+    eng.repository.get("simple_sequence").config.sequence_batching \
+        .max_sequence_idle_microseconds = 60_000_000
     srv = HttpInferenceServer(eng, port=0).start()
     yield srv
     srv.stop()

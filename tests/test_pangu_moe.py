@@ -26,8 +26,8 @@ import family  # noqa: E402
 from client_tpu.engine import TpuEngine  # noqa: E402
 from client_tpu.engine.repository import ModelRepository  # noqa: E402
 from client_tpu.engine.types import InferRequest  # noqa: E402
-from client_tpu.models import latent_moe  # noqa: E402
-from client_tpu.models.latent_moe import SeededWeight  # noqa: E402
+from client_tpu.models import experts  # noqa: E402
+from client_tpu.models.experts import SeededWeight  # noqa: E402
 from client_tpu.models.pangu_moe import PanguMoeBackend  # noqa: E402
 from client_tpu.observability import spans  # noqa: E402
 from client_tpu.ops.decode_kernel import (  # noqa: E402
@@ -187,7 +187,7 @@ def routed_part(be, lp, h):
     """What this share's held experts add, by the program."""
     lp = jax.tree_util.tree_map(jnp.asarray, lp)
     y, counts, _ = be._experts(lp, jnp.asarray(h), jnp.ones(len(h), bool),
-                               latent_moe.TILE_M_WAVE)
+                               experts.TILE_M_WAVE)
     return np.asarray(y), np.asarray(counts)
 
 
@@ -608,7 +608,7 @@ def test_the_configuration_file_is_the_catalog_row_but_for_its_cuts():
     for key in CFG:
         if key not in ("family", "serve"):
             assert cfg[key] == CFG[key], key
-    assert cfg["serve"]["expert_tile_rows"] == latent_moe.TILE_M_WAVE
+    assert cfg["serve"]["expert_tile_rows"] == experts.TILE_M_WAVE
     assert fam.wave_rows(cfg) == capacity_rows(128 * 8, 16, 16) == 1264
 
 

@@ -1,61 +1,16 @@
-"""The programs of the served decoders the benchmark already measures are the
-ones they were: what ``models/decoder.py``, ``ops/decode_kernel.py`` and
-``ops/flash_attention.py`` gain for a new decoder (cache leaves a backend
-names, a latent kernel, values of another width than the keys, a wave's
-counts behind its tokens) changes nothing that ``gpt2_small`` or
-``evabyte_6b5`` runs.
+"""The programs of the served decoders the benchmark measures are the ones
+they were.
 
-For each family's decode wave and prefill (or piece) program at a tiny preset,
-lowered for the TPU with the kernels in: the StableHLO with the kernels' bodies
-taken out (a body carries the source path of the checkout) and, apart, the
-Pallas kernels' own jaxprs, each against the hash recorded at commit 6b8c7c9
-(PR 31); ``pangu``'s (models/pangu_moe.py: the latent kernel, the grouped
-matmuls, prefill by flash pieces) at the tree PR 33 left; ``kimi``'s
-(models/kimi_linear.py: the state kernel beside the latent one, the chunked
-piece) at the tree PR 34 left, which moved what ``pangu`` and ``kimi`` share
-into models/latent_moe.py and left ``pangu``'s two as they were; the kernels
-of ``pangu``'s and ``kimi``'s decode waves at the tree PR 40 left (the latent
-kernel walks a lane's live blocks: its body and its grid changed, the
-programs around it did not); ``evabyte``'s four at the tree PR 42 left (its
-layers walked in a loop over leaves of their own, ``wq``, ``wk`` and ``wv``
-served ``[out, in]``, a piece fenced: the other three families' eight, which
-walk the same loop of ``models/decoder.py`` now, did not move);
-``smallthinker``'s two (models/smallthinker.py: the decode kernel with
-grouped-query rows, over whole-context leaves and over a ring; prefill by
-pieces through the flash kernel's band) at the tree PR 43 left, which gave
-``models/decoder.py`` the ``"ring"`` layer kind and both kernels their static
-switches, and moved the expert layer from models/latent_moe.py to
-models/experts.py (activation and score function its parameters): the other
-four families' sixteen hashes did not move; ``gpt``'s, ``evabyte``'s and
-``smallthinker``'s decode waves at the tree PR 44 left (the decode-wave
-kernel walks a lane's live blocks, the lanes its grid, a lane's last block
-copied by quanta, and takes the layer as an operand, so that a program's
-calls of one shape are one function: both hashes of the three moved, the
-three prefill programs and ``pangu``'s and ``kimi``'s four did not);
-``nemotron``'s two (models/nemotron_h.py: the state-space kernel of
-ops/ssd.py, the decode kernel with grouped-query rows, the transposed grouped
-matmul of an un-gated expert; the chunked piece) at the tree PR 45 left, which
-gave ``models/decoder.py`` the ``"none"`` layer kind and ``models/experts.py``
-the expert's form, and lifted a wave's tails (``slot_tails``), a piece's
-grouped-query attention (models/grouped_query.py) and a stream's record out
-of ``kimi_linear.py`` and ``smallthinker.py``: the other five families'
-twenty hashes did not move; ``nemotron``'s piece programs at the tree PR 47
-left, whose piece holds as many prompts as its backend declares lanes (two:
-``prefill``, the program of both lanes; ``prefill_1``, the one-lane program
-of its ladder): the projections
-and the expert block over all lanes' positions at once, the mixers a lane at
-a time.  ``models/experts.py`` ``prefill_fn`` takes any lane count for it and
-lowers to the recorded programs for the three other families that prefill
-through it with one lane: the other eleven pairs of hashes did not move;
-``kimi``'s piece programs at the tree PR 48 left, whose backend declares two
-lanes as ``nemotron``'s does (``prefill``, ``prefill_1``: ``wqkv``, the latent
-projections and the feed-forward over both lanes' positions, the chunked
-form, the switch and the flash call a lane at a time), with
-models/latent_moe.py's own ``prefill_fn`` gone for models/experts.py's (the
-record's one word a layer through ``_piece_words``): ``pangu``'s two, ``kimi``'s
-decode wave and the ten other pairs did not move.  A
-PR that means to change one of these programs records the new hash here and
-says so; one that does not has a guard.
+**The rule.**  For each family's decode wave and prefill (or piece) program at
+a tiny preset, lowered for the TPU with the kernels in, two hashes stand
+recorded: of the StableHLO with the kernels' bodies taken out (a body carries
+the source path of the checkout) and, apart, of the Pallas kernels' own jaxprs
+with their grids.  A PR that means to change one of these programs records the
+new hash here, says so in CHANGES.md, and shows on the chip that the compiled
+program is the one it was or better; one that does not mean to has a guard.
+``prefill`` is the program of a piece backend's declared lanes, ``prefill_<n>``
+another lane count of its ladder.  Beside each entry: the PR that last recorded
+(StableHLO, kernels).
 
     python - <<'X'          # to record: run from the repo root
     import tests.test_served_programs as t; t.record()
@@ -70,20 +25,26 @@ import jax.numpy as jnp
 import pytest
 
 RECORDED = {
-    ("evabyte", "decode"): ("4b633014727aa219", "54792ebb84a37cd7"),
-    ("evabyte", "prefill"): ("f73e0dc2333af8de", "e59f91f604bb6804"),
-    ("gpt", "decode"): ("c7d0eebb4f86770a", "2445b260a28de378"),
-    ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),
-    ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),
-    ("kimi", "prefill"): ("0719e01ccc5ce5e0", "083a795658c4ced4"),
-    ("kimi", "prefill_1"): ("8e944d9b2ab178e0", "11c370955249a916"),
-    ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),
-    ("nemotron", "prefill"): ("412e3a406d01a832", "036da1223ab7372f"),
-    ("nemotron", "prefill_1"): ("2e42a7ed9a4722d7", "72f715ae5cf8c153"),
-    ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),
-    ("pangu", "prefill"): ("2947c2e62c0d448d", "fa56a8062981bfe9"),
-    ("smallthinker", "decode"): ("44c3bd43dd161186", "93891480d1ba5ee1"),
-    ("smallthinker", "prefill"): ("312ebd7279ae9bc1", "ffec3fbbb48d7b3e"),
+    ("evabyte", "decode"): ("4b633014727aa219", "54792ebb84a37cd7"),  # 44, 44
+    ("evabyte", "prefill"): ("f73e0dc2333af8de", "e59f91f604bb6804"),  # 42, 42
+    ("gpt", "decode"): ("c7d0eebb4f86770a", "2445b260a28de378"),      # 44, 44
+    ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),     # 31, 31
+    ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),     # 34, 40
+    ("kimi", "prefill"): ("52d1572f07436271", "083a795658c4ced4"),    # 49, 48
+    ("kimi", "prefill_1"): ("0c6ea0bf4bd5ed1c", "11c370955249a916"),  # 49, 48
+    ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),  # 45, 45
+    ("nemotron", "prefill"): ("8ee5817dbe0c06e1", "036da1223ab7372f"),  # 49, 47
+    ("nemotron", "prefill_1"): ("22e712c920141233", "72f715ae5cf8c153"),  # 49, 47
+    ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),    # 33, 40
+    # (the piece programs of PR 49: one frame for the four piece backends,
+    # models/experts.py ``piece_hidden_fn``; ``pangu``'s and
+    # ``smallthinker``'s kernels by the tile of the sorted layout alone,
+    # which ``_piece_tile`` now chooses for them too: 32 rows for 64 at
+    # these presets' shares, and the hashes PR 33 and PR 43 recorded with
+    # the tile held at 64)
+    ("pangu", "prefill"): ("16efbfb833a536c1", "f9c5bf8655c5c096"),   # 49, 49
+    ("smallthinker", "decode"): ("44c3bd43dd161186", "93891480d1ba5ee1"),  # 44, 44
+    ("smallthinker", "prefill"): ("62c9d3c11db270e5", "1cfe8973c0312174"),  # 49, 49
 }
 
 
@@ -221,6 +182,41 @@ def test_the_program_is_the_one_it_was(on_the_chips_branches, family, which,
     got = hashes(family, which)[part == "kernels"]
     assert got == RECORDED[family, which][part == "kernels"], (
         f"{family}'s {which} {part} changed: if that is meant, record {got}")
+
+
+# The four cells' routers, and what ``_piece_tile`` gives a piece call of
+# ``lanes`` prompts of 512 positions there (the tiles PR 47 and PR 48 measured
+# fastest: models/experts.py ``_piece_tile``).
+PIECE_TILES = [
+    ("pangu", dict(n_experts=256, top_k=8), 1, 32),
+    ("kimi", dict(n_experts=256, top_k=8), 1, 32),
+    ("kimi", dict(n_experts=256, top_k=8), 2, 32),
+    ("smallthinker", dict(n_experts=64, top_k=6), 1, 64),
+    ("nemotron", dict(n_experts=128, top_k=6), 1, 32),
+    ("nemotron", dict(n_experts=128, top_k=6), 2, 64),
+]
+
+
+@pytest.mark.parametrize("family,router,lanes,tile", PIECE_TILES)
+def test_a_piece_backend_runs_the_one_frame(family, router, lanes, tile):
+    """No piece backend writes its own piece program, and the sorted layout's
+    tile is one rule's for all four."""
+    import importlib
+
+    from client_tpu.models.experts import ExpertDecoder
+
+    module, name = {"pangu": ("pangu_moe", "PanguMoeBackend"),
+                    "kimi": ("kimi_linear", "KimiLinearBackend"),
+                    "smallthinker": ("smallthinker", "SmallThinkerBackend"),
+                    "nemotron": ("nemotron_h", "NemotronHBackend")}[family]
+    cls = getattr(importlib.import_module(f"client_tpu.models.{module}"),
+                  name)
+    assert cls.piece_hidden_fn is ExpertDecoder.piece_hidden_fn
+    assert cls.prefill_fn is ExpertDecoder.prefill_fn
+    assert cls._piece_tile is ExpertDecoder._piece_tile
+    backend = cls(**router)
+    assert lanes <= backend.prefill_piece[1]
+    assert backend._piece_tile(lanes * 512) == tile
 
 
 def record():
