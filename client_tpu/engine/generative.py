@@ -41,6 +41,12 @@ generation streams. Design, TPU-first:
   ``wave_stats``, names of ``spans.GEN_COUNTERS``): the decode program returns
   that many int32 behind a wave's tokens (what a sparse expert layer routed),
   one fetch brings both, and the counters move when the tokens arrive.
+- **Passes** (a backend that declares ``passes`` > 1: its layer stack runs
+  that many times a step over one set of weights, each pass on a cache of
+  its own): the programs are the backend's and look like any other's; the
+  worker adds ``passes`` a fetched wave to the counter ``fetched_passes``
+  and the rows of every pass reach ``fetched_rows_global`` through
+  ``cache_rows_by_kind``.
 - **A stream's record** (a backend that declares ``stream_record``, int32 a
   position, and a request whose parameters say ``record``): the programs
   return every position's row behind their tokens (pieces and waves alike);
@@ -343,6 +349,7 @@ class GenerativeScheduler(Scheduler):
         self._piece_len, self._piece_lanes = backend.prefill_piece or (0, 0)
         self._cache_rows = backend.cache_rows
         self._rows_by_kind = backend.cache_rows_by_kind
+        self._passes = int(backend.passes)
         # Counters only the device can fill (``wave_stats``): that many
         # int32 ride behind a decode wave's tokens.
         self._wave_stats = [_sp.GEN_COUNTERS.index(name)
@@ -1088,6 +1095,7 @@ class GenerativeScheduler(Scheduler):
                 c[_sp.C_FETCHED_ROWS_WINDOW] += head.by_kind[0]
                 c[_sp.C_FETCHED_ROWS_GLOBAL] += head.by_kind[1]
                 c[_sp.C_FETCHED_LANES_PAST_WINDOW] += head.by_kind[2]
+                c[_sp.C_FETCHED_PASSES] += head.waves * self._passes
                 profiler().record_wave(
                     self.model.config.name, self.model.config.version,
                     bucket=head.bucket, chunk=head.waves,

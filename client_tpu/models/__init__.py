@@ -78,6 +78,16 @@ def _nemotron_h() -> ModelBackend:
     return NemotronHBackend()
 
 
+@register_model("ouro", default=False)
+def _ouro() -> ModelBackend:
+    """The dense decoder whose layer stack runs four passes over one set of
+    weights (a key/value cache for every pass in one arena), at its tiny
+    preset.  Opt-in, and imported when it is built, as ``pangu_moe``."""
+    from client_tpu.models.ouro import OuroBackend
+
+    return OuroBackend()
+
+
 def model_names() -> list[str]:
     _import_all()
     return sorted(_REGISTRY)

@@ -4,8 +4,9 @@ one decode step every such model runs.
 ``engine/generative.py`` knows no model.  It builds its programs from a
 :class:`DecoderBackend` and reads the contract below, every member of which has
 a documented default here; a model file supplies its parts once and the rest
-(the kernel-or-oracle choice, the decode-step frame, the chunked step, the
-sampling tail, the decoupled ``ModelConfig``) is written in this module.
+(the kernel-or-oracle choice, the decode-step frame, the piece frame, the pass
+axis of both, the chunked step, the sampling tail, the decoupled
+``ModelConfig``) is written in this module.
 
 **The contract the scheduler reads**
 
@@ -38,6 +39,16 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
   keys as the ring has rows), or fewer.  The order of a ring's rows means
   nothing to the softmax: a model rotates a key before it is written
   (``_ring_qkv``).
+- **Passes.**  ``passes``: ``1``, or how many times a step runs the layer
+  stack over the **one** list of weights ``p["layers"]`` (models/ouro.py: a
+  looped model), with ``_between_passes(p, x)`` -> x between two passes
+  (default: nothing).  Every pass keeps a cache of its own: a leaf's leading
+  axis counts ``passes x (layers of its kind)``, pass ``t``'s entries behind
+  those of the passes before it, and ``_layer_kind`` gives the frames a
+  layer's index at pass ``t`` as ``t x (layers of the kind) + its index among
+  them``; ``init_arena`` sizes the leaves so, the weights stay one set.  The
+  scheduler reads ``passes`` for its counter ``fetched_passes`` and learns
+  nothing else of it.
 - ``wave_stats``: ``()``, or names of ``spans.GEN_COUNTERS`` that only the
   device can count (what a wave's tokens were routed to): the decode program
   then returns that many int32 behind its ``B`` tokens (``_wave_stats(x)``),
@@ -55,7 +66,12 @@ sampling tail, the decoupled ``ModelConfig``) is written in this module.
 - ``prefill_piece``: ``None`` (a whole prompt a lane, one program a prompt
   bucket) or ``(positions, lanes)`` (a prompt is consumed ``positions`` a
   piece; ``prefill_fn()`` is called with every power of two of lanes up to
-  ``lanes``, a program each: the one that holds the prompts in line).
+  ``lanes``, a program each: the one that holds the prompts in line).  The
+  piece program is written here too (``piece_hidden_fn``, ``prefill_fn``:
+  **the piece's frame**, beside the wave's): a backend supplies a mixer a
+  kind, ``_piece_rows_layer`` | ``_piece_ring_layer`` |
+  ``_piece_state_layer``, and nothing else where its piece carries
+  activations alone.
 - ``cache_rows``: ``None`` (a step reads one row a position) or ``(n) ->
   (summary rows, exact rows)`` a step at context length ``n`` reads.
 - ``cache_rows_by_kind``: ``None``, or ``(n) -> (ring rows, other rows, past)``:
@@ -101,8 +117,9 @@ where they are not ``[B, vocab]``, ``_served(logits)`` picking those tokens
 are sampled from; ``_live_rows(lens)``, the rows of each slot a step at
 context length ``lens`` may read (default: ``lens``).  The layers are walked
 by ``_walk_layers(p, body, carry)``, written here: ``body(carry, lp, li)``
-over ``p["layers"]``, a list of one tree of leaves a layer, in a Python loop
-(``li`` a Python int).  A weight is then a parameter of the program, read by
+over ``passes`` x ``p["layers"]``, a list of one tree of leaves a layer, in a
+Python loop (``li`` a Python int, the body's place in the walk: ``pass x
+layers + the layer's number``).  A weight is then a parameter of the program, read by
 its product where it lies; a ``lax.scan`` over stacked leaves made the
 compiler write out and re-lay a layer's slice every iteration (PERF.md
 section 6, PR 42), and no served model overrides the loop.
@@ -111,6 +128,8 @@ section 6, PR 42), and no served model overrides the loop.
 from __future__ import annotations
 
 import functools
+
+import numpy as np
 
 from client_tpu.engine.config import ModelConfig, TensorConfig
 from client_tpu.engine.model import ModelBackend
@@ -210,6 +229,16 @@ def put_slot_tails(leaf, ki, pick, slots, ext):
     return jax.lax.dynamic_update_slice(leaf, slots[None], (ki, 0, 0))
 
 
+# Logits of a row's first ids in a stream's record, beside its token's.
+RECORD_LOGITS = 8
+
+
+def record_width(words: int = 0) -> int:
+    """int32 a position of a stream's record: a model's own ``words`` (an
+    expert model's routing), then the ``1 + RECORD_LOGITS`` logits."""
+    return words + 1 + RECORD_LOGITS
+
+
 def logit_bits(logits, tokens, samples: int):
     """``[B, 1 + samples]`` int32: the float32 bits of each lane's logit of
     its token and of the row's first ``samples`` ids (a fixed sample of the
@@ -232,6 +261,7 @@ class DecoderBackend(ModelBackend):
     generative = True
 
     prefill_piece: tuple[int, int] | None = None
+    passes = 1
     cache_leaves: tuple[str, ...] = ("k", "v")
     layer_kinds: tuple[str, ...] | None = None
     state_leaves: tuple[str, ...] = ()
@@ -347,9 +377,21 @@ class DecoderBackend(ModelBackend):
     def _served(self, logits):
         return logits
 
+    def _between_passes(self, p, x):
+        return x
+
     def _walk_layers(self, p, body, carry):
-        for li, lp in enumerate(p["layers"]):
-            carry = body(carry, lp, li)
+        """``body(carry, lp, li)`` over ``passes`` x ``p["layers"]``, ``li``
+        the body's place in the walk (``pass x layers + the layer's
+        number``: ``_layer_kind`` tells the pass from it).  Between two
+        passes the head of a carry ``(x, ...)`` goes through
+        ``_between_passes``."""
+        layers = p["layers"]
+        for t in range(self.passes):
+            if t:
+                carry = (self._between_passes(p, carry[0]), *carry[1:])
+            for li, lp in enumerate(layers):
+                carry = body(carry, lp, t * len(layers) + li)
         return carry
 
     # -- kernel or oracle -----------------------------------------------------
@@ -478,11 +520,16 @@ class DecoderBackend(ModelBackend):
     # -- the decode step ------------------------------------------------------
 
     def _layer_kind(self, li):
-        """(kind, index among the layers of that kind) of layer ``li``."""
+        """(kind, index into the kind's leaves) of the walk's body ``li``
+        (``pass x layers + the layer's number``): pass ``t``'s entries lie
+        behind those of the passes before it, ``t x (layers of the kind) +
+        the layer's index among them``."""
         if self.layer_kinds is None:
             return "rows", li
+        t, li = divmod(li, len(self.layer_kinds))
         kind = self.layer_kinds[li]
-        return kind, self.layer_kinds[:li].count(kind)
+        return kind, (t * self.layer_kinds.count(kind)
+                      + self.layer_kinds[:li].count(kind))
 
     def _attention_output(self, lp, o):
         return o
@@ -497,7 +544,7 @@ class DecoderBackend(ModelBackend):
         raise NotImplementedError
 
     def _record(self, x, logits, tokens):
-        raise NotImplementedError
+        return logit_bits(logits, tokens, RECORD_LOGITS)
 
     def _decode_hidden_fn(self):
         """(params, arena, rows[B], lens[B]) -> (arena, x after the last
@@ -589,6 +636,123 @@ class DecoderBackend(ModelBackend):
             return arena, tokens
 
         return decode
+
+    # -- the prefill piece ----------------------------------------------------
+
+    def _piece_start(self, p, ids, pos, live):
+        return self._embed(p, ids, pos), None
+
+    def _piece_after(self, lp, x, o, trail):
+        return self._after_attention(lp, x, o), trail
+
+    def _piece_block(self, lp, x, trail):
+        return self._feed_forward(lp, x), trail
+
+    def _piece_end(self, trail):
+        return trail
+
+    def _piece_words(self, trail):
+        return []
+
+    def _walk_kinds(self, p, x, trail, mixer):
+        """x ``[n, d]`` through ``passes`` x the layers by their kinds:
+        ``mixer(kind, ki, lp, x)`` -> o for a layer that has one, then
+        ``_piece_after``; a ``"none"`` layer is ``_piece_block``.  -> (x,
+        what ``_piece_end`` makes of the trail)."""
+        def layer(carry, lp, li):
+            x, trail = carry
+            kind, ki = self._layer_kind(li)
+            if kind == "none":
+                return self._piece_block(lp, x, trail)
+            return self._piece_after(lp, x, mixer(kind, ki, lp, x), trail)
+
+        x, trail = self._walk_layers(p, layer, (x, trail))
+        return x, self._piece_end(trail)
+
+    def piece_hidden_fn(self):
+        """(params, arena, rows[L], ids[L, piece], lens[L], starts[L]) ->
+        (arena, x ``[L * piece, d]``, the piece's trail), lane after lane:
+        one prefill piece of each of ``L`` prompts (any ``L`` up to what
+        ``prefill_piece`` declares), positions ``starts .. starts + lens`` of
+        a lane's prompt (``starts`` a multiple of the piece).  **The piece's
+        frame**, as ``_decode_hidden_fn`` is the wave's: ``passes`` x the
+        layers, a layer gets the leaves of its kind and its index into them
+        (``_layer_kind``: pass ``t``'s piece reads and writes pass ``t``'s
+        rows).  Whatever is a matrix product over positions sees all lanes'
+        positions as one batch, so a weight is read once a program and pass;
+        a mixer runs a lane at a time, each from its own slot.  A backend
+        supplies a part for each kind it declares, ``_piece_rows_layer``,
+        ``_piece_ring_layer``, ``_piece_state_layer`` ``(lp, *the kind's
+        leaves, ki, rows, starts, lens, x, pos)`` -> (*leaves, o ``[L * piece,
+        *]``).  What follows a mixer is the wave's ``_after_attention`` on
+        the piece's rows, a ``"none"`` layer its ``_feed_forward``, the
+        first x its ``_embed``; a backend whose piece carries more than
+        activations (models/experts.py: which lanes are live, the routing's
+        choices) says so through ``_piece_start(p, ids, pos, live)`` -> (x,
+        trail), ``_piece_after(lp, x, o, trail)`` and ``_piece_block(lp, x,
+        trail)`` -> (x, trail), ``_piece_end(trail)`` -> what the program
+        hands on, and ``_piece_words(that)`` -> the record's leading
+        columns."""
+        import jax.numpy as jnp
+
+        n = self.prefill_piece[0]
+        leaves_of = {"rows": self.cache_leaves, "ring": self.ring_leaves,
+                     "state": self.state_leaves}
+
+        def piece(p, arena, rows, ids, lens, starts):
+            at = jnp.arange(n)
+            live = (at < lens[:, None]).reshape(-1)
+            pos = (starts[:, None] + at).reshape(-1)
+            arena = dict(arena)
+
+            def mixer(kind, ki, lp, x):
+                names = leaves_of[kind]
+                *leaves, o = getattr(self, f"_piece_{kind}_layer")(
+                    lp, *(arena[name] for name in names), ki, rows, starts,
+                    lens, x, pos)
+                arena.update(zip(names, leaves))
+                return o
+
+            x, trail = self._walk_kinds(
+                p, *self._piece_start(p, ids.reshape(-1), pos, live), mixer)
+            return arena, x, trail
+
+        return piece
+
+    def prefill_fn(self):
+        """``PREFILL_ARGS`` -> (arena, tokens[L]): one **piece** of each
+        lane's prompt (``piece_hidden_fn``); the token sampled after a lane's
+        last valid position lands in its slot's device-side token, and means
+        something for a prompt's last piece only.  With ``stream_record`` the
+        pieces' rows of the record follow the tokens, ``[L + L x piece x
+        stream_record]``: a model's own words (``_piece_words``), then the
+        logits' bits in the row of a lane's last valid position.  (A backend
+        that declares no ``prefill_piece`` writes its own ``prefill_fn``.)"""
+        piece = self.piece_hidden_fn()
+        n = self.prefill_piece[0]
+
+        def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
+                    sample, starts):
+            import jax.numpy as jnp
+
+            lanes = rows.shape[0]
+            arena, x, trail = piece(p, arena, rows, ids, lens, starts)
+            # Each lane's last valid row of x.
+            at = lens - 1 + n * np.arange(lanes, dtype=np.int32)
+            logits = self._served(self._logits(p, x[at]))
+            arena, tokens = sample_into_slots(
+                arena, rows, logits, seeds, starts + lens, temps, top_ks,
+                top_ps, sample)
+            if not self.stream_record:
+                return arena, tokens
+            last = (jnp.arange(lanes * n) == jnp.repeat(at, n))
+            words, last = self._piece_words(trail), last[:, None]
+            bits = jnp.repeat(logit_bits(logits, tokens, RECORD_LOGITS), n,
+                              axis=0)
+            rec = jnp.concatenate(words + [jnp.where(last, bits, 0)], axis=1)
+            return arena, jnp.concatenate([tokens, rec.reshape(-1)])
+
+        return prefill
 
     def decode_chunk_fn(self):
         """``DECODE_CHUNK_ARGS`` -> (arena, tokens[k, B]).

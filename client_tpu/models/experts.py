@@ -12,8 +12,8 @@ state-space and attention layers; sigmoid scores with a selection bias,
 form).  What does not differ between them lives here, in
 :class:`ExpertDecoder`:
 
-- weights made when asked for (:class:`SeededWeight`) and put on the device a
-  leaf at a time;
+- weights made when asked for and put on the device a leaf at a time
+  (models/seeded.py, which a dense decoder shares);
 - **the router** (``route``): float32 at full precision whatever the matmuls',
   the score function a parameter (``router_score``: ``"sigmoid"`` |
   ``"softmax"``);
@@ -38,14 +38,18 @@ form).  What does not differ between them lives here, in
 - **a stream's record** of its routing (``held_mask``): one bit a held expert
   in int32 words, 32 experts a word, the one thing about a routing that a
   share's output depends on discontinuously; ``_words``, ``_record`` and
-  ``prefill_fn`` leave ``held_words`` words an expert layer and a few logits
+  ``_piece_words`` leave ``held_words`` words an expert layer and a few logits
   behind a program's tokens (``stream_record`` of the decoder's contract),
   for a model whose ``piece_hidden_fn`` returns a piece's choices
   (models/latent_moe.py keeps its one-word form: its own ``_record`` and
   ``_piece_words``);
-- **the piece program** (``piece_hidden_fn``) and the full-context forward
-  (``make_apply_params``): one walk over the layers by their kinds
-  (``_walk_kinds``) around the parts a backend supplies a kind.
+- **what a piece carries beside its activations** (the parts of
+  models/decoder.py's piece frame, ``piece_hidden_fn``: ``_piece_start``,
+  ``_piece_after``, ``_piece_block``, ``_piece_end``): which positions are
+  live, the sorted layout's tile (``_piece_tile``) and the expert layers'
+  choices, around ``_after_rows`` and ``_expert_block``, which a backend
+  supplies; and the full-context forward (``make_apply_params``) over the
+  same walk (``_walk_kinds``).
 
 A model sets ``d_model, d_expert, n_experts, experts_held, first_expert,
 top_k, routed_scale, dtype, rms_eps, _seed`` and, where they differ from the
@@ -54,103 +58,16 @@ defaults, ``router_score``, ``expert_form`` and ``expert_act``.
 
 from __future__ import annotations
 
-import concurrent.futures
-import math
-import os
-
-import numpy as np
-
-from client_tpu.models.decoder import (DecoderBackend, logit_bits,
-                                       sample_into_slots)
+from client_tpu.models.decoder import RECORD_LOGITS, logit_bits
 from client_tpu.models.layers import rms_norm
+from client_tpu.models.seeded import SeededDecoder
 
-_CHUNK = 1 << 24          # elements of a weight made by one task
-_BLOCK = 1 << 17          # elements made at a time (cache-sized)
 # Rows of a grouped matmul's tile: a wave's groups are a few rows (16 is
 # bfloat16's sublane tile), a prefill piece's some dozens.
 TILE_M_WAVE, TILE_M_PIECE = 16, 64
 # Tokens whose pairs come back from the sorted layout in one gather.
 BACK_ROWS = 512
-# Logits of a row's first ids in a stream's record, beside its token's.
-RECORD_LOGITS = 8
-
-
-def record_width(expert_layers: int) -> int:
-    """int32 a position of a stream's record."""
-    return expert_layers + 1 + RECORD_LOGITS
-
-
-class SeededWeight:
-    """A weight that is made when it is asked for: ``offset + scale * N(0,
-    1)`` from its own seed, **rounded to bfloat16** whatever dtype it is asked
-    in, so a reference that asks for float32 (``np.asarray(w, np.float32)``)
-    holds exactly what the chip holds and never a second copy.  Chunks of
-    ``_CHUNK`` elements have seeds of their own and are filled by as many
-    threads as the process may use (numpy's generators release the
-    interpreter lock): the values do not depend on the thread count.  With
-    ``first`` given, entry i of the leading axis is made from ``first + i``
-    alone: the experts a share holds are the model's, whichever share holds
-    them."""
-
-    def __init__(self, seed, shape, scale, offset=0.0, dtype="bfloat16",
-                 first=None):
-        self.seed, self.shape = tuple(int(s) for s in seed), tuple(shape)
-        self.scale, self.offset = float(scale), float(offset)
-        self.dtype = str(dtype)          # "bfloat16" | "float32"
-        self.first = first
-
-    def _spans(self):
-        """(lo, hi, seed) of every chunk of the flattened weight."""
-        n = int(np.prod(self.shape))
-        unit = n if self.first is None else n // self.shape[0]
-        return [(u + lo, u + min(lo + _CHUNK, unit),
-                 [*self.seed, lo // _CHUNK] + (
-                     [] if self.first is None else [self.first + u // unit]))
-                for u in range(0, n, unit) for lo in range(0, unit, _CHUNK)]
-
-    def _fill(self, out, lo, hi, seed):
-        """Chunk ``[lo, hi)`` of the flattened weight into ``out`` (float32,
-        or uint16 holding bfloat16's bits), a block at a time and in place:
-        whole-chunk temporaries would be mapped and unmapped by every thread
-        at once, which the kernel serializes."""
-        rng = np.random.default_rng(seed)
-        wide = out.dtype == np.float32
-        scratch = None if wide else np.empty(_BLOCK, np.float32)
-        carry = np.empty(_BLOCK, np.uint32)
-        for a in range(lo, hi, _BLOCK):
-            b = min(a + _BLOCK, hi)
-            part = out[a:b] if wide else scratch[:b - a]
-            rng.standard_normal(b - a, dtype=np.float32, out=part)
-            part *= np.float32(self.scale)
-            if self.offset:
-                part += np.float32(self.offset)
-            bits, t = part.view(np.uint32), carry[:b - a]
-            np.right_shift(bits, 16, out=t)      # round to nearest even
-            t &= np.uint32(1)
-            t += np.uint32(0x7FFF)
-            bits += t
-            if wide:
-                bits &= np.uint32(0xFFFF0000)
-            else:
-                np.right_shift(bits, 16, out=t)
-                out[a:b] = t
-
-    def __array__(self, dtype=None, copy=None):
-        import ml_dtypes
-
-        wide = self.dtype == "float32" or (
-            dtype is not None and np.dtype(dtype) == np.float32)
-        out = np.empty(int(np.prod(self.shape)),
-                       np.float32 if wide else np.uint16)
-        spans = self._spans()
-        workers = max(1, min(len(spans), len(os.sched_getaffinity(0))))
-        with concurrent.futures.ThreadPoolExecutor(workers) as pool:
-            list(pool.map(lambda s: self._fill(out, *s), spans))
-        out = out.reshape(self.shape)
-        return out if wide else out.view(ml_dtypes.bfloat16)
-
-
-class ExpertDecoder(DecoderBackend):
+class ExpertDecoder(SeededDecoder):
     """The shared parts above."""
 
     wave_stats = ("expert_pairs_local", "expert_pairs_busiest",
@@ -172,43 +89,7 @@ class ExpertDecoder(DecoderBackend):
         if self.expert_form not in ("gated", "plain"):
             raise ValueError(f"expert_form {self.expert_form!r}")
 
-    # -- params --------------------------------------------------------------
-
-    def _weight_makers(self):
-        """``w(*shape, scale, ...)``, ``mat(rows, cols)`` and ``gain(n)``:
-        ``SeededWeight`` leaves numbered in the order they are asked for.  A
-        float32 model's weights are still rounded to bfloat16 values: the
-        same numbers in both forms of the program."""
-        count = iter(range(1 << 20))
-
-        def w(*shape, scale, offset=0.0, dtype=None, first=None):
-            return SeededWeight((self._seed, next(count)), shape, scale,
-                                offset, dtype or self.dtype, first)
-
-        def mat(rows, cols):
-            return w(rows, cols, scale=1.0 / math.sqrt(rows))
-
-        def gain(n):
-            return w(n, scale=0.1, offset=1.0)
-
-        return w, mat, gain
-
-    def place_params(self, params):
-        """Leaf by leaf: a weight is made, put on the device and let go, so
-        the host never holds the model."""
-        import jax
-
-        return jax.tree_util.tree_map(
-            lambda leaf: jax.device_put(np.asarray(leaf)), params)
-
     # -- shared blocks --------------------------------------------------------
-
-    def _mm(self, x, w):
-        """Operands in the weights' dtype, float32 result."""
-        import jax.numpy as jnp
-
-        return jnp.matmul(x.astype(w.dtype), w,
-                          preferred_element_type=jnp.float32)
 
     def route(self, lp, h):
         """The router: h ``[n, d]`` float32 (normed) -> (experts ``[n, k]``,
@@ -407,24 +288,31 @@ class ExpertDecoder(DecoderBackend):
         return {**x, "h": h, "stats": x["stats"] + stats,
                 "route": x["route"] + route}
 
-    def _walk_kinds(self, p, x, live, tile_m, mixer):
-        """x ``[n, d]`` through the layers by their kinds: ``mixer(kind, ki,
-        lp, x)`` -> o for a layer that has one, then ``_after_rows``; a
-        ``"none"`` layer is ``_expert_block``.  -> (x, choices ``[expert
-        layers, n, top_k]``)."""
+    # -- the piece's parts (models/decoder.py ``piece_hidden_fn``) ---------------
+
+    def _piece_start(self, p, ids, pos, live):
+        """A piece's first x and its trail: which positions hold a token,
+        the sorted layout's tile for this many positions, and the expert
+        layers' choices so far."""
         import jax.numpy as jnp
 
-        routes = []
-        for li, lp in enumerate(p["layers"]):
-            kind, ki = self._layer_kind(li)
-            if kind == "none":
-                x, _, top_i = self._expert_block(lp, x, live, tile_m)
-                routes.append(top_i)
-                continue
-            x, _, route = self._after_rows(lp, x, mixer(kind, ki, lp, x),
-                                           live, tile_m)
-            routes += route
-        return x, jnp.stack(routes)
+        return (p["embed"][ids].astype(jnp.float32),
+                {"live": live, "tile": self._piece_tile(ids.shape[0]),
+                 "routes": ()})
+
+    def _piece_after(self, lp, x, o, trail):
+        x, _, route = self._after_rows(lp, x, o, trail["live"], trail["tile"])
+        return x, {**trail, "routes": trail["routes"] + route}
+
+    def _piece_block(self, lp, x, trail):
+        x, _, top_i = self._expert_block(lp, x, trail["live"], trail["tile"])
+        return x, {**trail, "routes": trail["routes"] + (top_i,)}
+
+    def _piece_end(self, trail):
+        """The choices ``[expert layers, n, top_k]``."""
+        import jax.numpy as jnp
+
+        return jnp.stack(trail["routes"])
 
     def make_apply_params(self):
         """Full-context forward in the served precision: no cache, no pieces,
@@ -444,89 +332,11 @@ class ExpertDecoder(DecoderBackend):
             n = ids.shape[0]
             pos = jnp.arange(n)
             x, routes = self._walk_kinds(
-                p, p["embed"][ids].astype(jnp.float32), jnp.ones(n, bool),
-                TILE_M_PIECE,
+                p, p["embed"][ids].astype(jnp.float32),
+                {"live": jnp.ones(n, bool), "tile": TILE_M_PIECE,
+                 "routes": ()},
                 lambda kind, ki, lp, x: getattr(self, f"_full_{kind}_layer")(
                     lp, x, pos))
             return {"logits": self._logits(p, x), "routing": routes}
 
         return apply, params
-
-    def piece_hidden_fn(self):
-        """(params, arena, rows[L], ids[L, piece], lens[L], starts[L]) ->
-        (arena, x ``[L * piece, d]``, choices ``[expert layers, L * piece,
-        top_k]``), lane after lane: one prefill piece of each of ``L``
-        prompts (any ``L`` up to what ``prefill_piece`` declares), positions
-        ``starts .. starts + lens`` of a lane's prompt (``starts`` a multiple
-        of the piece).  **The piece's frame**, as ``_decode_hidden_fn`` is
-        the wave's (models/decoder.py): a layer gets the leaves of its kind.
-        Whatever is a matrix product over positions sees all lanes'
-        positions as one batch, so a weight, and above all a layer's held
-        experts, is read once a program; a mixer runs a lane at a time, each
-        from its own slot.  A backend supplies a part for each kind it
-        declares, ``_piece_rows_layer``, ``_piece_ring_layer``,
-        ``_piece_state_layer`` ``(lp, *the kind's leaves, ki, rows, starts,
-        lens, x, pos)`` -> (*leaves, o ``[L * piece, *]``), what follows a
-        mixer, ``_after_rows(lp, x, o, live, tile_m)`` -> (x, routing counts,
-        choices: ``()`` or ``(top_i,)``), and a ``"none"`` layer whole,
-        ``_expert_block(lp, x, live, tile_m)`` -> (x, counts, top_i)."""
-        import jax.numpy as jnp
-
-        n = self.piece
-        leaves_of = {"rows": self.cache_leaves, "ring": self.ring_leaves,
-                     "state": self.state_leaves}
-
-        def piece(p, arena, rows, ids, lens, starts):
-            at = jnp.arange(n)
-            live = (at < lens[:, None]).reshape(-1)
-            pos = (starts[:, None] + at).reshape(-1)
-            arena = dict(arena)
-
-            def mixer(kind, ki, lp, x):
-                names = leaves_of[kind]
-                *leaves, o = getattr(self, f"_piece_{kind}_layer")(
-                    lp, *(arena[name] for name in names), ki, rows, starts,
-                    lens, x, pos)
-                arena.update(zip(names, leaves))
-                return o
-
-            x, routes = self._walk_kinds(
-                p, p["embed"][ids.reshape(-1)].astype(jnp.float32), live,
-                self._piece_tile(rows.shape[0] * n), mixer)
-            return arena, x, routes
-
-        return piece
-
-    def prefill_fn(self):
-        """``PREFILL_ARGS`` -> (arena, tokens[L]): one **piece** of each
-        lane's prompt (``piece_hidden_fn``: (arena, x ``[L * piece, d]``,
-        choices ``[expert layers, L * piece, top_k]``, lane after lane)); the
-        token sampled after a lane's last valid position lands in its slot's
-        device-side token, and means something for a prompt's last piece
-        only.  With ``stream_record`` the pieces' rows of the record follow
-        the tokens, ``[L + L x piece x stream_record]``."""
-        piece = self.piece_hidden_fn()
-
-        def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
-                    sample, starts):
-            import jax.numpy as jnp
-
-            lanes = rows.shape[0]
-            arena, x, routes = piece(p, arena, rows, ids, lens, starts)
-            # Each lane's last valid row of x.
-            at = lens - 1 + self.piece * np.arange(lanes, dtype=np.int32)
-            logits = self._logits(p, x[at])
-            arena, tokens = sample_into_slots(
-                arena, rows, logits, seeds, starts + lens, temps, top_ks,
-                top_ps, sample)
-            if not self.stream_record:
-                return arena, tokens
-            last = (jnp.arange(lanes * self.piece)
-                    == jnp.repeat(at, self.piece))
-            words, last = self._piece_words(routes), last[:, None]
-            bits = jnp.repeat(logit_bits(logits, tokens, RECORD_LOGITS),
-                              self.piece, axis=0)
-            rec = jnp.concatenate(words + [jnp.where(last, bits, 0)], axis=1)
-            return arena, jnp.concatenate([tokens, rec.reshape(-1)])
-
-        return prefill

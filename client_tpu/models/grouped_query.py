@@ -123,7 +123,7 @@ class GroupedQueryPieces:
         return k_a, v_a, jnp.concatenate(outs)
 
     def _piece_rows_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
-        """A whole-context layer's part of a piece (models/experts.py
+        """A whole-context layer's part of a piece (models/decoder.py
         ``piece_hidden_fn``), by the backend's ``_project(lp, x, pos)``."""
         return self._lane_by_lane(self._piece_rows, self._project(lp, x, pos),
                                   k_a, v_a, ki, rows, starts, lens)

@@ -50,7 +50,7 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.experts import record_width
+from client_tpu.models.decoder import record_width
 from client_tpu.models.latent_moe import LatentMoeDecoder
 from client_tpu.models.layers import rms_norm
 from client_tpu.models.state_layer import StateLayer

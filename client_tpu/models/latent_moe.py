@@ -20,7 +20,7 @@ What does not differ between them lives here, in :class:`LatentMoeDecoder`:
   Piece i of a prompt has exactly ``i * piece`` rows before it, so a latent
   layer holds one branch a count (``lax.switch``) and computes nothing that is
   masked; the switch is the layer's, not the model's.  Of a piece of several
-  lanes (models/experts.py ``piece_hidden_fn``) the projections see every
+  lanes (models/decoder.py ``piece_hidden_fn``) the projections see every
   lane's rows at once; the switch, the flash call and the rows' write go a
   lane at a time;
 - **the expert layer** beside its shared expert (``_ffn``): the router, the
@@ -52,7 +52,8 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.experts import RECORD_LOGITS, ExpertDecoder
+from client_tpu.models.decoder import RECORD_LOGITS, logit_bits
+from client_tpu.models.experts import ExpertDecoder
 
 _NEG_INF = -1e30
 
@@ -181,8 +182,6 @@ class LatentMoeDecoder(ExpertDecoder):
         """A wave's rows of the streams' record ``[B, stream_record]``."""
         import jax.numpy as jnp
 
-        from client_tpu.models.decoder import logit_bits
-
         return jnp.concatenate(
             [jnp.stack([self.held_mask(r) for r in x["route"]], axis=1),
              logit_bits(logits, tokens, RECORD_LOGITS)], axis=1)
@@ -254,7 +253,7 @@ class LatentMoeDecoder(ExpertDecoder):
 
     def _piece_rows_layer(self, lp, c_a, li, rows, starts, lens, x, pos):
         """A latent layer's part of a piece of ``L`` lanes, x ``[L * piece,
-        d]`` (models/experts.py ``piece_hidden_fn``): the projections over
+        d]`` (models/decoder.py ``piece_hidden_fn``): the projections over
         every lane's positions at once, then a lane at a time the piece's
         queries against the slot's ``start`` rows before it and its own (one
         ``lax.switch`` branch a count of earlier rows: ``start`` is a

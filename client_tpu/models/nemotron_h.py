@@ -37,7 +37,7 @@ them, in the model's dtype, one row a slot), a \\* layer a ``"rows"`` layer
 leaf, no mixer.  **Decode** advances a wave's states in place
 (``ssd_wave_update``, or its oracle where the arena is not the kernels') and
 reads the lanes' rows with the grouped-query decode kernel.  **Prefill** is by
-pieces (models/experts.py's frame; this backend declares two lanes): an M
+pieces (models/decoder.py's frame; this backend declares two lanes): an M
 layer's part is models/state_layer.py's around the chunked form
 (``ssd_chunk_scan``; a padded position has ``dt = 0``: it moves nothing), a
 \\* layer's models/grouped_query.py's, an E layer the expert block over all
@@ -52,8 +52,8 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.experts import (TILE_M_WAVE, ExpertDecoder,
-                                       record_width)
+from client_tpu.models.decoder import record_width
+from client_tpu.models.experts import TILE_M_WAVE, ExpertDecoder
 from client_tpu.models.grouped_query import GroupedQueryPieces
 from client_tpu.models.layers import rms_norm
 from client_tpu.models.state_layer import StateLayer

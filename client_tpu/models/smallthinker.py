@@ -66,7 +66,8 @@ from __future__ import annotations
 
 import math
 
-from client_tpu.models.experts import ExpertDecoder, record_width
+from client_tpu.models.decoder import record_width
+from client_tpu.models.experts import ExpertDecoder
 from client_tpu.models.grouped_query import GroupedQueryPieces
 from client_tpu.models.layers import rms_norm, rope
 
@@ -218,7 +219,7 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
     # -- a piece's attention (models/grouped_query.py) ----------------------------
 
     def _piece_ring_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
-        """A window layer's part of a piece (models/experts.py
+        """A window layer's part of a piece (models/decoder.py
         ``piece_hidden_fn``)."""
         return self._lane_by_lane(
             self._piece_ring, self._project(lp, x, pos, "ring"), k_a, v_a, ki,

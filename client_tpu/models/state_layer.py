@@ -87,7 +87,7 @@ class StateLayer:
     def _piece_state_layer(self, lp, s_a, conv_a, ki, rows, starts, lens, x,
                            pos):
         """-> (s_a, conv_a, o ``[L * piece, *]``), lane after lane
-        (models/experts.py ``piece_hidden_fn``)."""
+        (models/decoder.py ``piece_hidden_fn``)."""
         import jax
         import jax.numpy as jnp
 

@@ -128,6 +128,11 @@ GEN_COUNTERS = (
     # leaves, each summed over the layers of the kind, and the live lanes
     # whose context has outgrown the ring (those the ring saves reads for).
     "fetched_rows_window", "fetched_rows_global", "fetched_lanes_past_window",
+    # per decode fetch: the passes over its layers that the wave's program
+    # ran, by what the backend declares (``passes`` of models/decoder.py's
+    # contract: 1 a wave for every backend but one whose layer stack runs
+    # several times over one set of weights).
+    "fetched_passes",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
@@ -140,7 +145,7 @@ GEN_COUNTERS = (
  C_PROMPTS_STARTED, C_ADMIT_WAIT_NS, C_PREFILL_LINE_WAIT_NS, C_GAP_LANES,
  C_GAP_LANE_NS, C_GAP_LANES_BEHIND_PREFILL,
  C_GAP_LANE_BEHIND_PREFILL_NS, C_FETCHED_ROWS_WINDOW, C_FETCHED_ROWS_GLOBAL,
- C_FETCHED_LANES_PAST_WINDOW) = range(len(GEN_COUNTERS))
+ C_FETCHED_LANES_PAST_WINDOW, C_FETCHED_PASSES) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

@@ -366,9 +366,10 @@ class TestCacheReaders:
         for name in CACHE_METRICS:
             # (The decode kernel's share is read on the other cells that
             # run the kernel as well: a global layer's calls, PR 43, and an
-            # attention layer's between state-space layers, PR 45.)
+            # attention layer's between state-space layers, PR 45, and a
+            # looped stack's 48 calls a wave, PR 50.)
             others = (["smallthinker_21b.mixed",
-                       "nemotron3_nano_30b.assistant"]
+                       "nemotron3_nano_30b.assistant", "ouro_2b6.fewshot"]
                       if name == "decode_attn_roofline.itl" else [])
             assert by[name]["workloads"] == [CELL] + others
             assert by[name]["moves"] == "itl_mean_ms"
@@ -583,9 +584,12 @@ class TestLanesPerCall:
             self):
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
-        last = manifest["per_layer"][-1]
+        # (Last of the accepted metrics until PR 50 put its two behind it.)
+        last = manifest["per_layer"][-3]
         stage = next(m for m in manifest["per_layer"]
                      if m["name"] == "prefill_stage_ms_mean.itl")
+        assert [m["name"] for m in manifest["per_layer"][-2:]] == [
+            "loop_dense_roofline.itl", "passes_per_wave.obs"]
         assert last == {"name": LANES, "unit": "lanes", "better": "higher",
                         "source": "program_counter",
                         "layer": "generative scheduler",

@@ -1,0 +1,25 @@
+"""The cell ``ouro_2b6.fewshot`` end to end on the CPU at the configuration's
+``rehearse_cpu`` sizes, through ``benchmark/run.py`` (server, load generator,
+reference, readers): ``benchmark/testdata/check_ouro.py --rehearse``.  A
+rehearsal proves nothing about the chip; it holds the control flow, the final
+line's keys and that every listed counter reader prints a number.  Marked
+``slow`` (a server, a reference and a load generator for most of a minute,
+beside tier-1's timing-sensitive tests): ``python -m pytest
+tests/test_ouro_rehearsal.py`` runs it.  The readers' and the arithmetic's
+checks of that file are quick and run with tier-1 (tests/test_ouro.py)."""
+
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+sys.path.insert(0, os.path.join(ROOT, "benchmark", "testdata"))
+
+
+@pytest.mark.slow
+def test_the_new_cell_rehearses_on_the_cpu():
+    import check_ouro
+
+    assert check_ouro.rehearse() == 0

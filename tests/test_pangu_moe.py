@@ -27,7 +27,7 @@ from client_tpu.engine import TpuEngine  # noqa: E402
 from client_tpu.engine.repository import ModelRepository  # noqa: E402
 from client_tpu.engine.types import InferRequest  # noqa: E402
 from client_tpu.models import experts  # noqa: E402
-from client_tpu.models.experts import SeededWeight  # noqa: E402
+from client_tpu.models.seeded import SeededWeight  # noqa: E402
 from client_tpu.models.pangu_moe import PanguMoeBackend  # noqa: E402
 from client_tpu.observability import spans  # noqa: E402
 from client_tpu.ops.decode_kernel import (  # noqa: E402

@@ -35,6 +35,10 @@ RECORDED = {
     ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),  # 45, 45
     ("nemotron", "prefill"): ("8ee5817dbe0c06e1", "036da1223ab7372f"),  # 49, 47
     ("nemotron", "prefill_1"): ("22e712c920141233", "72f715ae5cf8c153"),  # 49, 47
+    # (three passes over two layers: the pass axis of both frames)
+    ("ouro", "decode"): ("a3cdb3b4609e3d6a", "0a13edafef621d9d"),     # 50, 50
+    ("ouro", "prefill"): ("fcb9f0bebf61b0d9", "472369f043e003f9"),    # 50, 50
+    ("ouro", "prefill_1"): ("cfa36356b529678e", "54b16b6182cc8ce7"),  # 50, 50
     ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),    # 33, 40
     # (the piece programs of PR 49: one frame for the four piece backends,
     # models/experts.py ``piece_hidden_fn``; ``pangu``'s and
@@ -94,6 +98,15 @@ def _backend(family):
                                 max_seq_len=32, piece=16, chunk=8,
                                 attention_impl="flash", attn_impl="fused",
                                 record=True)
+    if family == "ouro":
+        from client_tpu.models.ouro import OuroBackend
+
+        # Heads of whole 128-lane tiles, as the flash pieces need them; two
+        # layers, three passes.
+        return OuroBackend(seed=3, n_layers=2, passes=3, n_heads=2,
+                           n_kv_heads=2, head_dim=128, max_seq_len=32,
+                           piece=16, attention_impl="flash",
+                           attn_impl="fused", record=True)
     from client_tpu.models.generate import TinyGptBackend
 
     return TinyGptBackend(attention_impl="flash", attn_impl="fused")
@@ -104,7 +117,7 @@ def _program(family, which):
     ``which``: ``decode``, ``prefill`` (a piece backend's declared lanes) or
     ``prefill_<lanes>``."""
     be = _backend(family)
-    if family in ("pangu", "kimi", "smallthinker", "nemotron"):
+    if family in ("pangu", "kimi", "smallthinker", "nemotron", "ouro"):
         # (made when asked for)
         params = jax.tree_util.tree_map(
             lambda w: jax.ShapeDtypeStruct(w.shape, jnp.dtype(w.dtype)),
@@ -182,6 +195,20 @@ def test_the_program_is_the_one_it_was(on_the_chips_branches, family, which,
     got = hashes(family, which)[part == "kernels"]
     assert got == RECORDED[family, which][part == "kernels"], (
         f"{family}'s {which} {part} changed: if that is meant, record {got}")
+
+
+def test_the_piece_frame_is_the_decoders():
+    """One piece frame, the decoder's: the expert backends and the dense one
+    run it, and none writes a piece program of its own."""
+    from client_tpu.models.decoder import DecoderBackend
+    from client_tpu.models.experts import ExpertDecoder
+    from client_tpu.models.ouro import OuroBackend
+
+    for cls in (ExpertDecoder, OuroBackend):
+        assert cls.piece_hidden_fn is DecoderBackend.piece_hidden_fn
+        assert cls.prefill_fn is DecoderBackend.prefill_fn
+        assert cls._walk_kinds is DecoderBackend._walk_kinds
+        assert cls._walk_layers is DecoderBackend._walk_layers
 
 
 # The four cells' routers, and what ``_piece_tile`` gives a piece call of

@@ -729,18 +729,18 @@ NEMOTRON = dict(
     record=True)
 
 
-def _nemotron_program(one_chip, monkeypatch, which, lanes=1):
-    """``nemotron3_nano_30b``'s ``jit_decode`` (a full wave of 256) or
-    ``jit_prefill`` (a piece of 512 of each of ``lanes`` prompts) for one v5e
-    chip from shapes alone (13.3 GB of weights and cache that nothing
-    allocates).  Returns (optimised text, arena shapes, memory, backend)."""
+def _piece_backend_program(one_chip, monkeypatch, backend, which, lanes=1):
+    """A piece backend's ``jit_decode`` (a full wave of its ``max_streams``)
+    or ``jit_prefill`` (a piece of each of ``lanes`` prompts) for one v5e chip
+    from shapes alone (weights and cache that nothing allocates).  Returns
+    (optimised text, arena shapes, memory, seconds the compiler took)."""
+    import time
+
     from client_tpu.engine import backend_init
-    from client_tpu.models.nemotron_h import NemotronHBackend
     from client_tpu.observability import spans
 
     monkeypatch.setattr(backend_init, "pallas_interpret", lambda: False)
     place = _on(one_chip)
-    backend = NemotronHBackend(name="n", **NEMOTRON)
     params = jax.tree_util.tree_map(
         lambda leaf: place(leaf.shape, jnp.dtype(leaf.dtype)),
         backend._init_params())
@@ -748,8 +748,9 @@ def _nemotron_program(one_chip, monkeypatch, which, lanes=1):
         lambda a: place(a.shape, a.dtype),
         jax.eval_shape(lambda: backend.init_arena(backend.max_streams)))
     if which == "decode":
-        lanes_i, lanes_f = place((256,), jnp.int32), place((256,),
-                                                           jnp.float32)
+        wave = backend.max_streams
+        lanes_i, lanes_f = place((wave,), jnp.int32), place((wave,),
+                                                            jnp.float32)
         step = jax.jit(
             spans.named_step(backend.decode_fn(), spans.STEP_DECODE),
             donate_argnums=backend.donate_argnums,
@@ -757,7 +758,7 @@ def _nemotron_program(one_chip, monkeypatch, which, lanes=1):
         lowered = step.lower(params, arena, lanes_i, lanes_i, lanes_i,
                              lanes_f, lanes_i, lanes_f, False)
     else:
-        assert backend.prefill_piece == (512, 2)
+        piece = backend.prefill_piece[0]
         lane_i = place((lanes,), jnp.int32)
         lane_f = place((lanes,), jnp.float32)
         step = jax.jit(
@@ -765,10 +766,26 @@ def _nemotron_program(one_chip, monkeypatch, which, lanes=1):
             donate_argnums=backend.donate_argnums,
             static_argnums=backend.prefill_static_argnums)
         lowered = step.lower(params, arena, lane_i,
-                             place((lanes, 512), jnp.int32), lane_i, lane_i,
-                             lane_f, lane_i, lane_f, False, lane_i)
+                             place((lanes, piece), jnp.int32), lane_i,
+                             lane_i, lane_f, lane_i, lane_f, False, lane_i)
+    t0 = time.monotonic()
     compiled = lowered.compile()
-    return compiled.as_text(), arena, compiled.memory_analysis(), backend
+    return (compiled.as_text(), arena, compiled.memory_analysis(),
+            time.monotonic() - t0)
+
+
+def _nemotron_program(one_chip, monkeypatch, which, lanes=1):
+    """``nemotron3_nano_30b``'s ``jit_decode`` (a full wave of 256) or
+    ``jit_prefill`` (a piece of 512 of each of ``lanes`` prompts): 13.3 GB of
+    weights and cache.  Returns (optimised text, arena shapes, memory,
+    backend)."""
+    from client_tpu.models.nemotron_h import NemotronHBackend
+
+    backend = NemotronHBackend(name="n", **NEMOTRON)
+    assert backend.prefill_piece == (512, 2)
+    text, arena, memory, _ = _piece_backend_program(
+        one_chip, monkeypatch, backend, which, lanes)
+    return text, arena, memory, backend
 
 
 @pytest.mark.parametrize("which,lanes", [("decode", 1), ("prefill", 1),
@@ -825,6 +842,54 @@ def test_state_attention_and_expert_blocks_compile_at_published_widths(
     assert memory.temp_size_in_bytes < (0.3e9 if lanes == 2 else 0.2e9), \
         memory
     assert 13.2e9 < memory.argument_size_in_bytes < 13.4e9
+
+
+# -- a layer stack that runs four passes over one set of weights (PR 50) --------
+
+OURO = dict(n_layers=12, passes=4, d_model=2048, n_heads=16, n_kv_heads=16,
+            head_dim=128, d_ff=5632, vocab=49152, max_seq_len=1536, piece=512,
+            max_streams=18, attention_impl="flash", record=True)
+
+
+@pytest.mark.parametrize("which,lanes", [("decode", 1), ("prefill", 1),
+                                         ("prefill", 2)])
+def test_four_passes_over_one_set_of_weights_compile_at_published_widths(
+        one_chip, monkeypatch, which, lanes):
+    """At the cell's widths (2048; 16 query heads over 16 key heads of 128;
+    SwiGLU 5632; 49152 ids; 4 passes over 12 layers; 18 + 1 slots of 1536): a
+    wave is 48 grouped-query decode calls over **12** layers' weights, a piece
+    a flash call for every count of rows before it (3) in each of its 48
+    layer bodies and lanes.  The parameters are one set of layers (1.64 GB)
+    and ``passes x layers`` cache leaves (11.5 GB); no program copies a
+    weight or writes a cache leaf out again, whichever pass reads it."""
+    from client_tpu.models.ouro import OuroBackend
+
+    backend = OuroBackend(name="o", **OURO)
+    assert backend.prefill_piece == (512, 2)
+    text, arena, memory, seconds = _piece_backend_program(
+        one_chip, monkeypatch, backend, which, lanes)
+    print(f"ouro_2b6 {which} x{lanes}: compiled in {seconds:.1f} s; {memory}")
+    calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
+    assert arena["k"].shape == (48, 19, 1536, 2048)
+    if which == "decode":
+        assert calls.count("decode_wave_attention") == 48
+        # 18 tokens and a record row a lane.
+        assert f"s32[{18 + 18 * backend.stream_record}]" in text
+    else:
+        assert calls.count("flash_attention") == lanes * 48 * 3
+        # A token and 512 record rows a lane.
+        assert f"s32[{lanes * (1 + 512 * backend.stream_record)}]" in text
+    weights = r"2048,2048|2048,11264|5632,2048|49152,2048|2048,49152"
+    moved = _written_out_again(text, weights + r"|48,19,1536,2048")
+    assert not moved, moved
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    cache = 2 * math.prod(arena["k"].shape) * 2
+    assert memory.alias_size_in_bytes >= cache
+    # One set of weights: 12 x 51.39M + 201.3M parameters in bfloat16 beside
+    # the cache, not four.
+    assert 13.0e9 < memory.argument_size_in_bytes < 13.25e9
+    assert memory.temp_size_in_bytes < 0.3e9, memory
 
 
 # The three served configurations' cache leaves: slots, rows a slot, lanes a
