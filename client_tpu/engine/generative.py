@@ -29,6 +29,13 @@ generation streams. Design, TPU-first:
   ``lanes``, all warmed with the model), whatever the prompt's length; a
   piece runs with the smallest count that holds the prompts standing in
   line, and a lone prompt's piece goes at once, in the one-lane program.
+  The worker knows, lane by lane, whether a piece is its prompt's last, and
+  says so to a backend that declares ``piece_ends`` (the decoder's own piece
+  frame) in one more operand behind ``starts``, ``ends [lanes]`` (0 on padded
+  lanes): such a program computes its head, the vocabulary's matrix read for
+  one row a lane, only where some lane ends, so a prompt of a dozen pieces
+  pays for one head and not for twelve (counter ``prefill_heads``: the piece
+  programs in which some lane ended).
 - **Transitions** (a backend that declares ``transition_due(n)`` and
   ``transition_fn()``): a stream whose dispatch-side length ``n`` is due has
   the jitted transition queued before its next wave (span
@@ -347,6 +354,7 @@ class GenerativeScheduler(Scheduler):
         # pieces, the rows a step reads, and a transition ordered between
         # two waves.
         self._piece_len, self._piece_lanes = backend.prefill_piece or (0, 0)
+        self._piece_ends = bool(self._piece_len and backend.piece_ends)
         self._cache_rows = backend.cache_rows
         self._rows_by_kind = backend.cache_rows_by_kind
         self._passes = int(backend.passes)
@@ -490,12 +498,16 @@ class GenerativeScheduler(Scheduler):
                 f"warmup: prefill prompt bucket={bucket} lanes={lane}",
                 _sp.STEP_PREFILL, bucket)
             rows, *sampling = self._stage_lanes([], lane)
+            # A piece's `starts`, the argument only prefill by pieces has,
+            # and behind it the `ends` of a backend that takes them (no lane
+            # ends here: the executable is the one of a piece where one does).
+            zeros = np.zeros(lane, np.int32)
             self._arena, _ = self._prefill(
                 self.model._params, self._arena, rows,
                 np.zeros((lane, bucket), np.int32), np.ones(lane, np.int32),
                 *sampling, sample,
-                # A piece's `starts`: the argument only prefill by pieces has.
-                *((np.zeros(lane, np.int32),) if self._piece_len else ()))
+                *((zeros, zeros) if self._piece_ends
+                  else (zeros,) if self._piece_len else ()))
         self._ladders_warm.add((bucket, sample))
 
     def _precompile(self) -> None:
@@ -871,10 +883,12 @@ class GenerativeScheduler(Scheduler):
         ids_mat = np.zeros((lane, width), np.int32)
         lens = np.ones(lane, np.int32)
         starts = np.zeros(lane, np.int32)
+        ends = np.zeros(lane, np.int32)
         for i, s in enumerate(todo):
             part = s.ids[s.consumed:s.consumed + width]
             ids_mat[i, :len(part)] = part
             lens[i], starts[i] = len(part), s.consumed
+            ends[i] = s.consumed + len(part) >= len(s.ids)
         rows, seeds, temps, top_ks, top_ps = self._stage_lanes(todo, lane)
         sample = bool((temps > 0.0).any())
         self._warm_ladder(width, sample, but=lane)
@@ -886,12 +900,14 @@ class GenerativeScheduler(Scheduler):
             with self._rec.span[_sp.S_PREFILL_DISPATCH]:
                 self._arena, tokens = self._prefill(
                     self.model._params, self._arena, rows, ids_mat, lens,
-                    seeds, temps, top_ks, top_ps, sample, starts)
+                    seeds, temps, top_ks, top_ps, sample, starts,
+                    *((ends,) if self._piece_ends else ()))
             tokens.copy_to_host_async()
         finally:
             self.model._clear_state()
         now = time.monotonic_ns()
         self._rec.c[_sp.C_PREFILL_PIECES] += len(todo)
+        self._rec.c[_sp.C_PREFILL_HEADS] += int(ends.any())
         held = int(lens[:len(todo)].sum())
         self._rec.c[_sp.C_PREFILL_POSITIONS_VALID] += held
         self._rec.c[_sp.C_PREFILL_POSITIONS_PADDED] += \
@@ -902,7 +918,7 @@ class GenerativeScheduler(Scheduler):
             if not s.consumed:
                 self._count_started(s.req, now)
             s.consumed += int(lens[i])
-            if s.consumed >= len(s.ids):
+            if ends[i]:
                 s.ids = None          # prefilled: live from the next wave
             done.append(_NO_STREAM if s.ids is not None else s)
         # The fetch queue keeps dispatch order and the pipeline's depth; a

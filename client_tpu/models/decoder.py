@@ -72,6 +72,11 @@ axis of both, the chunked step, the sampling tail, the decoupled
   kind, ``_piece_rows_layer`` | ``_piece_ring_layer`` |
   ``_piece_state_layer``, and nothing else where its piece carries
   activations alone.
+- ``piece_ends``: ``True`` (the piece program is the frame's: it takes the
+  trailing ``ends`` and computes its head only where a lane ends its prompt)
+  or ``False`` (a backend that writes a piece program of its own and runs
+  its head in every piece: models/evabyte.py).  Read with ``prefill_piece``
+  alone.
 - ``cache_rows``: ``None`` (a step reads one row a position) or ``(n) ->
   (summary rows, exact rows)`` a step at context length ``n`` reads.
 - ``cache_rows_by_kind``: ``None``, or ``(n) -> (ring rows, other rows, past)``:
@@ -85,7 +90,11 @@ axis of both, the chunked step, the sampling tail, the decoupled
   wave of a stream that decoded its way to a due length.
 - The programs, by their positional arguments (``PREFILL_ARGS``,
   ``DECODE_ARGS``, ``DECODE_CHUNK_ARGS``): ``prefill_fn()`` -> (arena,
-  tokens[B]) takes the trailing ``starts`` only with ``prefill_piece``;
+  tokens[B]) takes the trailing ``starts`` only with ``prefill_piece``, and
+  behind it ``ends`` (``[L]`` int32, traced: 1 where the lane's piece is its
+  prompt's last, 0 elsewhere and on padded lanes; the scheduler knows it and
+  the program cannot, since a last piece may be full) only with
+  ``piece_ends`` too;
   ``decode_fn()`` -> (arena, tokens[B]); ``decode_chunk_fn()`` -> (arena,
   tokens[k, B]).  ``sample`` (and the chunk's ``k``) are static, the arena is
   donated: ``*_static_argnums`` and ``donate_argnums`` say so by position.
@@ -135,7 +144,7 @@ from client_tpu.engine.config import ModelConfig, TensorConfig
 from client_tpu.engine.model import ModelBackend
 
 PREFILL_ARGS = ("params", "arena", "rows", "ids", "lens", "seeds", "temps",
-                "top_ks", "top_ps", "sample", "starts")
+                "top_ks", "top_ps", "sample", "starts", "ends")
 DECODE_ARGS = ("params", "arena", "rows", "lens", "seeds", "temps", "top_ks",
                "top_ps", "sample")
 DECODE_CHUNK_ARGS = DECODE_ARGS + ("k",)
@@ -173,23 +182,34 @@ def _sample_token(logits, seed, ctx_len, temp, top_k, top_p):
     return jnp.where(temp <= 0.0, greedy, sampled)
 
 
-def sample_into_slots(arena, rows, logits, seeds, ctx, temps, top_ks, top_ps,
-                      sample):
-    """The tail of every program that emits a token: (arena, tokens[B]) with
-    each lane's token chosen from ``logits[b]`` at context length ``ctx[b]``
-    and left in the slot's device-side token, where the next wave finds it
-    without the host.  ``sample`` is STATIC: an all-greedy program compiles
+def choose_tokens(logits, seeds, ctx, temps, top_ks, top_ps, sample):
+    """tokens[B]: each lane's token chosen from ``logits[b]`` at context
+    length ``ctx[b]``.  ``sample`` is STATIC: an all-greedy program compiles
     without the sort/cumsum/PRNG pipeline (``jnp.where`` alone would keep
     both)."""
     import jax
     import jax.numpy as jnp
 
     if sample:
-        tokens = jax.vmap(_sample_token)(logits, seeds, ctx, temps, top_ks,
-                                         top_ps)
-    else:
-        tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return {**arena, "tok": arena["tok"].at[rows].set(tokens)}, tokens
+        return jax.vmap(_sample_token)(logits, seeds, ctx, temps, top_ks,
+                                       top_ps)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def tokens_into_slots(arena, rows, tokens):
+    """The arena with each lane's token left in its slot's device-side
+    token, where the next wave finds it without the host."""
+    return {**arena, "tok": arena["tok"].at[rows].set(tokens)}
+
+
+def sample_into_slots(arena, rows, logits, seeds, ctx, temps, top_ks, top_ps,
+                      sample):
+    """The tail of every program that emits a token: (arena, tokens[B]),
+    ``choose_tokens`` and ``tokens_into_slots`` as one (the piece's frame
+    calls the pair apart: its choice stands under a conditional, the write
+    does not)."""
+    tokens = choose_tokens(logits, seeds, ctx, temps, top_ks, top_ps, sample)
+    return tokens_into_slots(arena, rows, tokens), tokens
 
 
 def slot_tails(leaf, ki, rows):
@@ -261,6 +281,7 @@ class DecoderBackend(ModelBackend):
     generative = True
 
     prefill_piece: tuple[int, int] | None = None
+    piece_ends = True
     passes = 1
     cache_leaves: tuple[str, ...] = ("k", "v")
     layer_kinds: tuple[str, ...] | None = None
@@ -723,32 +744,65 @@ class DecoderBackend(ModelBackend):
         """``PREFILL_ARGS`` -> (arena, tokens[L]): one **piece** of each
         lane's prompt (``piece_hidden_fn``); the token sampled after a lane's
         last valid position lands in its slot's device-side token, and means
-        something for a prompt's last piece only.  With ``stream_record`` the
+        something for a prompt's last piece only.  So **the head runs only
+        where a prompt ends**: ``_logits``, ``_served``, the token choice and
+        the record's logit bits stand under one ``lax.cond`` on "some lane's
+        ``ends`` is set", and a program in which no lane ends reads no row of
+        the vocabulary's matrix and leaves zeros for tokens and bits (which
+        nothing reads: such a lane's fetch carries no stream).  A lane that
+        goes on beside one that ends gets a token as it always did.  The
+        arena does not pass through the conditional: the write of the tokens
+        into the slots stands behind it (a donated leaf carried through a
+        branch is a leaf the compiler may copy).  With ``stream_record`` the
         pieces' rows of the record follow the tokens, ``[L + L x piece x
-        stream_record]``: a model's own words (``_piece_words``), then the
-        logits' bits in the row of a lane's last valid position.  (A backend
-        that declares no ``prefill_piece`` writes its own ``prefill_fn``.)"""
+        stream_record]``: a model's own words (``_piece_words``) in every
+        piece, then the logits' bits in the row of a lane's last valid
+        position.  (A backend that declares no ``prefill_piece`` writes its
+        own ``prefill_fn``.)"""
         piece = self.piece_hidden_fn()
         n = self.prefill_piece[0]
+        record = bool(self.stream_record)
 
         def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
-                    sample, starts):
+                    sample, starts, ends):
+            import jax
             import jax.numpy as jnp
 
             lanes = rows.shape[0]
             arena, x, trail = piece(p, arena, rows, ids, lens, starts)
             # Each lane's last valid row of x.
             at = lens - 1 + n * np.arange(lanes, dtype=np.int32)
-            logits = self._served(self._logits(p, x[at]))
-            arena, tokens = sample_into_slots(
-                arena, rows, logits, seeds, starts + lens, temps, top_ks,
-                top_ps, sample)
-            if not self.stream_record:
+
+            def head(x_at):
+                logits = self._served(self._logits(p, x_at))
+                tokens = choose_tokens(logits, seeds, starts + lens, temps,
+                                       top_ks, top_ps, sample)
+                if not record:
+                    return (tokens,)
+                return tokens, logit_bits(logits, tokens, RECORD_LOGITS)
+
+            def skip(x_at):
+                tokens = jnp.zeros(lanes, jnp.int32)
+                if not record:
+                    return (tokens,)
+                return tokens, jnp.zeros((lanes, 1 + RECORD_LOGITS),
+                                         jnp.int32)
+
+            # (The rows behind a barrier: the compiler otherwise sinks what
+            # of the last layer only they read, an expert layer's gather
+            # back from the sorted layout, into the branch, and every
+            # operand of that then lives until the conditional: 48 MB more
+            # temporaries at smallthinker_21b's widths, compiled for the
+            # v5e, tests/test_tpu_compile.py.)
+            tokens, *bits = jax.lax.cond(
+                jnp.any(ends != 0), head, skip,
+                jax.lax.optimization_barrier(x[at]))
+            arena = tokens_into_slots(arena, rows, tokens)
+            if not record:
                 return arena, tokens
             last = (jnp.arange(lanes * n) == jnp.repeat(at, n))
             words, last = self._piece_words(trail), last[:, None]
-            bits = jnp.repeat(logit_bits(logits, tokens, RECORD_LOGITS), n,
-                              axis=0)
+            bits = jnp.repeat(bits[0], n, axis=0)
             rec = jnp.concatenate(words + [jnp.where(last, bits, 0)], axis=1)
             return arena, jnp.concatenate([tokens, rec.reshape(-1)])
 

@@ -136,8 +136,10 @@ class EvaByteBackend(DecoderBackend):
         self.slot_rows = -(-used // step) * step
         # What the scheduler asks (engine/generative.py): a prompt is
         # consumed ``window`` positions a piece, ``prefill_lanes`` prompts
-        # a call.
+        # a call.  The piece program is this file's own and runs its head
+        # (320 ids) in every piece: it takes no ``ends``.
         self.prefill_piece = (self.window, int(prefill_lanes))
+        self.piece_ends = False
 
     # -- what the scheduler asks of a cache that is not slot-per-position --
 

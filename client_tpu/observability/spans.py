@@ -133,6 +133,11 @@ GEN_COUNTERS = (
     # contract: 1 a wave for every backend but one whose layer stack runs
     # several times over one set of weights).
     "fetched_passes",
+    # per dispatched piece program in which some lane's piece was its
+    # prompt's last (a program each, as ``gen.prefill_dispatch`` counts; the
+    # worker knows it when it stages the piece).  A backend that declares
+    # ``piece_ends`` computes the head of exactly these programs.
+    "prefill_heads",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
@@ -145,7 +150,8 @@ GEN_COUNTERS = (
  C_PROMPTS_STARTED, C_ADMIT_WAIT_NS, C_PREFILL_LINE_WAIT_NS, C_GAP_LANES,
  C_GAP_LANE_NS, C_GAP_LANES_BEHIND_PREFILL,
  C_GAP_LANE_BEHIND_PREFILL_NS, C_FETCHED_ROWS_WINDOW, C_FETCHED_ROWS_GLOBAL,
- C_FETCHED_LANES_PAST_WINDOW, C_FETCHED_PASSES) = range(len(GEN_COUNTERS))
+ C_FETCHED_LANES_PAST_WINDOW, C_FETCHED_PASSES,
+ C_PREFILL_HEADS) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

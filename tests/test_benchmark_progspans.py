@@ -550,6 +550,7 @@ class TestGapReaders:
 # -- PR 47's reader: the prompts a piece program held ---------------------------
 
 LANES = "prefill_lanes_per_call.obs"
+HEADS = "prefill_head_share.itl"
 # (pieces, programs) at the window's two ends -> the value by hand.
 LANE_WINDOWS = {
     "every_piece_alone": ((40, 40), (1040, 1040), 1.0),
@@ -584,14 +585,77 @@ class TestLanesPerCall:
             self):
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
-        # (Last of the accepted metrics until PR 50 put its two behind it.)
-        last = manifest["per_layer"][-3]
+        # (Last of the accepted metrics until PR 50 put its two behind it,
+        # and PR 51 its one.)
+        last = manifest["per_layer"][-4]
         stage = next(m for m in manifest["per_layer"]
                      if m["name"] == "prefill_stage_ms_mean.itl")
-        assert [m["name"] for m in manifest["per_layer"][-2:]] == [
-            "loop_dense_roofline.itl", "passes_per_wave.obs"]
+        assert [m["name"] for m in manifest["per_layer"][-3:]] == [
+            "loop_dense_roofline.itl", "passes_per_wave.obs", HEADS]
         assert last == {"name": LANES, "unit": "lanes", "better": "higher",
                         "source": "program_counter",
                         "layer": "generative scheduler",
                         "moves": "itl_mean_ms",
                         "workloads": stage["workloads"]}
+
+
+# -- PR 51's reader: the piece programs that computed a head --------------------
+
+# (pieces, programs, heads) at the window's two ends -> the value by hand;
+# heads None: a program that has no such counter.
+HEAD_WINDOWS = {
+    "a_prompt_of_twelve_pieces": ((40, 40, 10), (1240, 1240, 110), 100 / 12),
+    "every_piece_ends_its_prompt": ((40, 40, 40), (140, 140, 140), 100.0),
+    "pairs_of_which_one_in_four_ends": ((40, 40, 10), (840, 440, 110), 25.0),
+    "no_prompt_ended_in_the_window": ((40, 40, 10), (60, 60, 10), 0.0),
+    "a_one_shot_prefill_counts_no_piece": ((0, 40, 0), (0, 1040, 0), None),
+    "no_piece_in_the_window": ((40, 40, 10), (40, 40, 10), None),
+    "the_parent_has_no_such_counter": ((40, 40, None), (1240, 1240, None),
+                                       None),
+}
+
+
+class TestHeadShare:
+    @pytest.mark.parametrize("window", sorted(HEAD_WINDOWS))
+    def test_heads_over_programs(self, window):
+        """``prefill_heads`` counts a piece program in which some lane ended
+        its prompt, the span ``gen.prefill_dispatch`` a program each: their
+        ratio in percent, 0 where the window's pieces ended no prompt, and
+        nothing where no piece was dispatched or the program counts no
+        heads (which is not a share of 0)."""
+        def side(pieces, calls, heads):
+            counters = {"prefill_pieces": pieces}
+            if heads is not None:
+                counters["prefill_heads"] = heads
+            return snap({"gen.prefill_dispatch": (calls, 9)}, counters)
+
+        a, b, want = HEAD_WINDOWS[window]
+        got = run_reader(HEADS)({"snap_before": side(*a),
+                                 "snap_after": side(*b)})
+        assert got is None if want is None else got == pytest.approx(want)
+
+    def test_nothing_from_a_program_without_the_profile(self):
+        read = run_reader(HEADS)
+        assert read({"snap_before": None, "snap_after": None}) is None
+        bare = {"t": 0.0, "stats": {}, "profile": {"models": {"gpt:1": {
+            "decode_waves": []}}}}
+        assert read({"snap_before": bare, "snap_after": bare}) is None
+
+    def test_the_manifest_holds_it_last_for_the_cells_of_the_piece_frame(
+            self):
+        """Appended behind every accepted metric, for the five cells whose
+        backend runs the decoder's piece frame (``evabyte_6b5.longdoc``
+        prefills by pieces through a program of its own, which takes no
+        ``ends``)."""
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+        lanes = next(m for m in manifest["per_layer"] if m["name"] == LANES)
+        assert manifest["per_layer"][-1] == {
+            "name": HEADS, "unit": "%", "better": "lower",
+            "source": "program_counter", "layer": "generative scheduler",
+            "moves": "itl_mean_ms",
+            "workloads": [c for c in lanes["workloads"]
+                          if c != "evabyte_6b5.longdoc"]}
+        from client_tpu.observability import spans
+
+        assert spans.GEN_COUNTERS[-1] == "prefill_heads"

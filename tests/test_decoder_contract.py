@@ -31,8 +31,16 @@ READ_AT_CONSTRUCTION = ["max_streams", "max_seq_len", "arena_rows",
 @pytest.fixture(scope="module")
 def engines():
     built = {}
-    yield lambda name: built.setdefault(
-        name, TpuEngine(build_repository([name])))
+
+    def engine(name):
+        # (Built once a name: ``setdefault`` would build, and leak, an engine
+        # a call, and a live ``tiny_gpt`` arena is what tests/test_costs.py
+        # reconciles the census against in the same worker.)
+        if name not in built:
+            built[name] = TpuEngine(build_repository([name]))
+        return built[name]
+
+    yield engine
     for eng in built.values():
         eng.shutdown()
 
@@ -173,3 +181,127 @@ def test_the_chunked_step_is_k_single_steps(attn_impl, sample):
     for leaf in ("k", "v", "tok"):
         np.testing.assert_allclose(np.asarray(got_arena[leaf]),
                                    np.asarray(want_arena[leaf]), atol=1e-6)
+
+
+# -- the piece's head runs only where a prompt ends (PR 51) ---------------------
+
+# The backends that run the decoder's piece frame, at their tiny presets with
+# a stream's record where they keep one, and what they declare for lanes.
+FRAME_BACKENDS = {
+    "pangu_moe": ("pangu_moe", "PanguMoeBackend", {}, 1),
+    "kimi_linear": ("kimi_linear", "KimiLinearBackend", {}, 2),
+    "smallthinker": ("smallthinker", "SmallThinkerBackend",
+                     {"record": True}, 1),
+    "nemotron_h": ("nemotron_h", "NemotronHBackend", {"record": True}, 2),
+    "ouro": ("ouro", "OuroBackend", {"record": True}, 2),
+}
+
+
+def _frame_backend(name):
+    import importlib
+
+    module, cls, options, lanes = FRAME_BACKENDS[name]
+    backend = getattr(importlib.import_module(f"client_tpu.models.{module}"),
+                      cls)(**options)
+    assert backend.prefill_piece[1] == lanes
+    assert type(backend).prefill_fn is DecoderBackend.prefill_fn
+    assert backend.piece_ends
+    return backend
+
+
+@pytest.mark.parametrize("sample", [False, True])
+@pytest.mark.parametrize("name", sorted(FRAME_BACKENDS))
+def test_a_piece_computes_its_head_only_where_a_lane_ends(name, sample):
+    """One piece program, run on the same operands with no lane's ``ends``
+    set, with every lane's and with one lane's: the cache, ring and state
+    leaves and the record's words (the routing of **every** piece) are
+    bit-equal whatever ``ends`` says; where a lane ends the tokens, the
+    slots' tokens and the logit bits are the head's as it stood in the open
+    (``_logits`` of each lane's last valid row, ``_served``, the token
+    choice, ``logit_bits``), for the lane that goes on beside it too; where
+    none does they are zeros.  Lane 0 holds a full piece (what the program
+    cannot tell from a prompt that goes on)."""
+    import jax
+    import jax.numpy as jnp
+
+    from client_tpu.models.decoder import (RECORD_LOGITS, choose_tokens,
+                                           logit_bits)
+
+    be = _frame_backend(name)
+    piece, lanes = be.prefill_piece
+    width, logits_at = be.stream_record, be.stream_record - 1 - RECORD_LOGITS
+    params = be.place_params(be._init_params())
+    rng = np.random.default_rng(5)
+    rows = np.asarray([2, 0][:lanes], np.int32)
+    lens = np.asarray([piece, 5][:lanes], np.int32)
+    starts = np.zeros(lanes, np.int32)
+    ids = rng.integers(1, be.vocab, (lanes, piece)).astype(np.int32)
+    ids *= np.arange(piece) < lens[:, None]
+    sampling = (np.asarray([7, 11][:lanes], np.int32),
+                np.full(lanes, 0.8 if sample else 0.0, np.float32),
+                np.full(lanes, 8, np.int32), np.full(lanes, 0.9, np.float32))
+    step = jax.jit(be.prefill_fn(), static_argnums=be.prefill_static_argnums)
+
+    def run(ends):
+        arena, out = step(params, be.init_arena(3), rows, ids, lens,
+                          *sampling, sample, starts,
+                          np.asarray(ends[:lanes], np.int32))
+        out = np.asarray(out)
+        rec = out[lanes:].reshape(lanes, piece, width) if width else None
+        return {k: np.asarray(v) for k, v in arena.items()}, out[:lanes], rec
+
+    def open_head(p, arena, rows, ids, lens, starts):
+        _, x, _ = be.piece_hidden_fn()(p, arena, rows, ids, lens, starts)
+        at = lens - 1 + piece * jnp.arange(lanes)
+        logits = be._served(be._logits(p, x[at]))
+        tokens = choose_tokens(logits, *sampling[:1], starts + lens,
+                               *sampling[1:], sample)
+        return tokens, logit_bits(logits, tokens, RECORD_LOGITS)
+
+    want_tokens, want_bits = (np.asarray(a) for a in jax.jit(open_head)(
+        params, be.init_arena(3), rows, ids, lens, starts))
+    none, every, one = run([0, 0]), run([1, 1]), run([0, 1][-lanes:])
+    for (arena, tokens, rec), ended in ((none, False), (every, True),
+                                        (one, True)):
+        for leaf in arena:
+            if leaf != "tok":
+                assert np.array_equal(arena[leaf], every[0][leaf]), leaf
+        assert np.array_equal(tokens, want_tokens * ended)
+        assert np.array_equal(arena["tok"][rows], want_tokens * ended)
+        if rec is None:
+            continue
+        assert np.array_equal(rec[..., :logits_at], every[2][..., :logits_at])
+        bits = rec[..., logits_at:]
+        for lane in range(lanes):
+            last = np.arange(piece) == lens[lane] - 1
+            assert np.array_equal(bits[lane][last][0],
+                                  want_bits[lane] * ended)
+            assert not bits[lane][~last].any()
+    # (The routing's words say something in every row that held a token.)
+    if every[2] is not None and logits_at:
+        assert none[2][0, :, :logits_at].any()
+
+
+def test_a_backend_with_its_own_piece_program_takes_no_ends(engines):
+    """``piece_ends`` goes with the frame: the one backend that writes its own
+    piece program (its head is 320 ids) says so, and the scheduler hands it
+    ``starts`` alone."""
+    import inspect
+
+    from client_tpu.models.evabyte import EvaByteBackend
+
+    sched = engines("evabyte")._schedulers["evabyte"]
+    backend = sched.model.backend
+    assert type(backend).prefill_fn is EvaByteBackend.prefill_fn \
+        is not DecoderBackend.prefill_fn
+    assert backend.prefill_piece and not backend.piece_ends
+    assert not sched._piece_ends
+    assert list(inspect.signature(backend.prefill_fn()).parameters)[-1] \
+        == PREFILL_ARGS[-2] == "starts"
+    frame = engines("kimi_linear")._schedulers["kimi_linear"]
+    assert frame._piece_ends and PREFILL_ARGS[-1] == "ends"
+    assert list(inspect.signature(
+        frame.model.backend.prefill_fn()).parameters)[-1] == "ends"
+    # A one-shot backend's program takes neither.
+    shot = engines("tiny_gpt")._schedulers["tiny_gpt"]
+    assert not shot._piece_len and not shot._piece_ends

@@ -189,6 +189,14 @@ class TestLaneCounters:
         assert d["first_tokens"] + d["fetched_lanes_live"] \
             == sum(len(t) for _, _, t in streams)
 
+    def test_a_one_shot_prefill_counts_no_piece_and_no_head(self, ran):
+        """``prefill_heads`` is the piece programs': a backend that prefills
+        a prompt in one program has none."""
+        before, after, streams = ran
+        d = _delta(before, after)
+        assert d["first_tokens"] == len(streams)
+        assert d["prefill_pieces"] == 0 and d["prefill_heads"] == 0
+
     def test_positions_valid_from_the_known_prompts(self, ran):
         before, after, streams = ran
         d = _delta(before, after)
@@ -604,6 +612,17 @@ LINES = {
 }
 
 
+# The same lines (and a prompt of exactly one full piece) -> the piece
+# programs in which some lane ended its prompt.
+HEADS = {
+    "three_pieces": ([80], 1),
+    "exactly_one_full_piece": ([32], 1),
+    "a_pair_that_ends_together": ([80, 80], 1),
+    "a_pair_with_one_ending_lane": ([20, 80], 2),
+    "three_prompts_end_in_three_programs": ([20, 80, 30], 3),
+}
+
+
 class TestPieceLanes:
     @pytest.mark.parametrize("line", sorted(LINES))
     def test_a_piece_program_holds_the_prompts_that_wait(self, lane_engine,
@@ -632,6 +651,24 @@ class TestPieceLanes:
         assert [j() for j in together] == alone
         assert eng.profile_snapshot(model=model)["compiles"]["count"] \
             == compiles
+
+    @pytest.mark.parametrize("line", sorted(HEADS))
+    def test_prefill_heads_counts_the_programs_in_which_a_lane_ends(
+            self, lane_engine, line):
+        """``prefill_heads`` moves once a piece program in which some lane's
+        piece is its prompt's last, however many lanes end in it: what the
+        worker tells a backend of the decoder's piece frame in ``ends``, and
+        so the programs whose head ran (PR 51).  Counted on the host for
+        every backend that prefills by pieces."""
+        eng, _, model = lane_engine
+        lengths, want = HEADS[line]
+        prompts = [list(range(1 + i, 1 + i + n))
+                   for i, n in enumerate(lengths)]
+        before = _gen(eng, model)
+        _held_batch(eng, model, prompts, 2)
+        d = _delta(before, _gen(eng, model))
+        assert d["prefill_heads"] == want
+        assert d["first_tokens"] == len(prompts)
 
     def test_the_ladder_is_the_powers_of_two_up_to_the_backends_lanes(
             self, lane_engine, piece_engine):

@@ -410,7 +410,7 @@ def test_two_lanes_tokens_and_record_rows_are_the_lanes_alone(sample):
             np.asarray(slots, np.int32) + 5,          # a seed a slot
             np.full(n, 0.9 if sample else 0.0, np.float32),
             np.full(n, 8, np.int32), np.full(n, 0.95, np.float32), sample,
-            starts)
+            starts, np.ones(n, np.int32))
         out = np.asarray(out)
         assert out.shape == (n * (1 + width),)
         return (np.asarray(arena["tok"]), out[:n],
@@ -768,7 +768,7 @@ def test_the_programs_leave_the_record_of_what_they_computed():
     buf[0, :5] = ids[16:]
     piece = jax.jit(be.prefill_fn(), static_argnums=be.prefill_static_argnums)
     _, out = piece(srv.params, dict(kept), rows, buf, lens, *zeros, False,
-                   np.asarray([16], np.int32))
+                   np.asarray([16], np.int32), np.ones(1, np.int32))
     rec = np.asarray(out[1:]).reshape(PIECE, width)
     srv.arena = dict(kept)
     logits, routes = srv.prefill(ids, slot=1)[0][16:], None

@@ -372,7 +372,7 @@ def test_two_lanes_tokens_and_record_rows_are_the_lanes_alone(sample):
             np.asarray(slots, np.int32) + 5,          # a seed a slot
             np.full(n, 0.9 if sample else 0.0, np.float32),
             np.full(n, 8, np.int32), np.full(n, 0.95, np.float32), sample,
-            starts)
+            starts, np.ones(n, np.int32))
         out = np.asarray(out)
         assert out.shape == (n * (1 + width),)
         return (np.asarray(arena["tok"]), out[:n],

@@ -108,7 +108,7 @@ def test_a_prompt_is_prefilled_alike_in_every_program_of_the_ladder(
         tok_full.tolist()
 
 
-def admit_together(sched, eng, batch, max_tokens=2):
+def admit_together(sched, eng, batch, max_tokens=2, model=MODEL):
     """Submit ``batch`` while the worker is held, so that one admit holds
     them all; returns their token lists."""
     hold, held = threading.Event(), threading.Event()
@@ -137,7 +137,7 @@ def admit_together(sched, eng, batch, max_tokens=2):
 
     for i, p in enumerate(batch):
         eng.async_infer(InferRequest(
-            model_name=MODEL, inputs={"INPUT_IDS": p},
+            model_name=model, inputs={"INPUT_IDS": p},
             parameters={"max_tokens": max_tokens}), callback(i))
     hold.set()
     warm.join(60)
@@ -205,3 +205,86 @@ def test_a_bucket_first_used_under_load_warms_its_whole_ladder():
         assert admit_together(sched, eng, prompts(1)) == first
     finally:
         eng.shutdown()
+
+
+# -- a piece's head runs only where a prompt ends (PR 51) -----------------------
+
+PIECES = "ladder_ouro"      # pieces of 16 positions, two prompts a program
+
+
+@pytest.fixture(scope="module")
+def piece_served():
+    """A backend that runs the decoder's piece frame (float32, so that a
+    token is the plain reference's to the bit of an argmax), and the plain
+    reference's greedy continuation of a prompt: no cache, no piece, no
+    conditional (``benchmark/models/ouro.py``)."""
+    import os
+    import sys
+
+    import jax
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    import family
+
+    from client_tpu.models.ouro import OuroBackend
+
+    fam = family.load("ouro")
+    be = OuroBackend(name=PIECES, seed=5, max_seq_len=64, piece=16,
+                     dtype="float32", max_streams=2)
+    params = jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32),
+                                    be._init_params())
+
+    def one_shot(prompt, n):
+        seq = [int(t) for t in prompt]
+        for _ in range(n):
+            logits, _ = fam.backend_forward(params, be,
+                                            np.asarray(seq, np.int32), 1)
+            seq.append(int(np.asarray(logits)[-1].argmax()))
+        return seq[len(prompt):]
+
+    repo = ModelRepository()
+    repo.register_backend(be)
+    eng = TpuEngine(repo)
+    sched = eng._schedulers[PIECES]
+    sched.warmup()
+    yield eng, sched, one_shot
+    eng.shutdown()
+
+
+# The prompts of a line (their lengths) -> (piece programs, those of them in
+# which some lane ended).  40 positions are three pieces, 16 exactly one full
+# piece: nothing in the program says that it is the prompt's last.
+PIECE_LINES = {
+    "three_pieces_alone": ([40], (3, 1)),
+    "exactly_one_full_piece_alone": ([16], (1, 1)),
+    "one_lane_ends_beside_one_that_goes_on": ([40, 16], (3, 2)),
+    "both_lanes_end_in_a_full_piece": ([16, 16], (1, 1)),
+}
+
+
+@pytest.mark.parametrize("line", sorted(PIECE_LINES))
+def test_a_piece_without_a_head_loses_no_token(piece_served, line):
+    """Served through the scheduler, a prompt emits the tokens of the
+    one-shot reference whether its pieces ran alone or beside another
+    prompt's, in a program whose other lane ended or went on: the head ran
+    exactly where the worker said a lane ends."""
+    eng, sched, one_shot = piece_served
+    lengths, (programs, heads) = PIECE_LINES[line]
+    rng = np.random.default_rng(11)
+    batch = [rng.integers(1, 96, n).astype(np.int32) for n in lengths]
+
+    def profile():
+        g = eng.profile_snapshot(model=PIECES)["models"][f"{PIECES}:1"][
+            "generative"]
+        return (g["spans"][spans.GEN_PREFILL_DISPATCH]["count"],
+                g["counters"]["prefill_heads"],
+                g["counters"]["prefill_pieces"])
+
+    before = profile()
+    got = admit_together(sched, eng, batch, max_tokens=3, model=PIECES)
+    assert got == [one_shot(p, 3) for p in batch]
+    after = profile()
+    assert tuple(a - b for a, b in zip(after, before)) == (
+        programs, heads, sum(-(-n // 16) for n in lengths))

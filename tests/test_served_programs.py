@@ -30,25 +30,26 @@ RECORDED = {
     ("gpt", "decode"): ("c7d0eebb4f86770a", "2445b260a28de378"),      # 44, 44
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),     # 31, 31
     ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),     # 34, 40
-    ("kimi", "prefill"): ("52d1572f07436271", "083a795658c4ced4"),    # 49, 48
-    ("kimi", "prefill_1"): ("0c6ea0bf4bd5ed1c", "11c370955249a916"),  # 49, 48
+    ("kimi", "prefill"): ("37d5b524b0b73f60", "083a795658c4ced4"),    # 51, 48
+    ("kimi", "prefill_1"): ("c1a4f0a1a32b0105", "11c370955249a916"),  # 51, 48
     ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),  # 45, 45
-    ("nemotron", "prefill"): ("8ee5817dbe0c06e1", "036da1223ab7372f"),  # 49, 47
-    ("nemotron", "prefill_1"): ("22e712c920141233", "72f715ae5cf8c153"),  # 49, 47
+    ("nemotron", "prefill"): ("d59199529e2364d4", "036da1223ab7372f"),  # 51, 47
+    ("nemotron", "prefill_1"): ("50e1142bcff1a77f", "72f715ae5cf8c153"),  # 51, 47
     # (three passes over two layers: the pass axis of both frames)
     ("ouro", "decode"): ("a3cdb3b4609e3d6a", "0a13edafef621d9d"),     # 50, 50
-    ("ouro", "prefill"): ("fcb9f0bebf61b0d9", "472369f043e003f9"),    # 50, 50
-    ("ouro", "prefill_1"): ("cfa36356b529678e", "54b16b6182cc8ce7"),  # 50, 50
+    ("ouro", "prefill"): ("f92e3ffa529763cb", "472369f043e003f9"),    # 51, 50
+    ("ouro", "prefill_1"): ("6418e3116c027a48", "54b16b6182cc8ce7"),  # 51, 50
     ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),    # 33, 40
     # (the piece programs of PR 49: one frame for the four piece backends,
     # models/experts.py ``piece_hidden_fn``; ``pangu``'s and
     # ``smallthinker``'s kernels by the tile of the sorted layout alone,
     # which ``_piece_tile`` now chooses for them too: 32 rows for 64 at
     # these presets' shares, and the hashes PR 33 and PR 43 recorded with
-    # the tile held at 64)
-    ("pangu", "prefill"): ("16efbfb833a536c1", "f9c5bf8655c5c096"),   # 49, 49
+    # the tile held at 64; the programs of PR 51: the frame's head under one
+    # conditional on the trailing ``ends``, the kernels as they were)
+    ("pangu", "prefill"): ("9f81a0e83b3aaa34", "f9c5bf8655c5c096"),   # 51, 49
     ("smallthinker", "decode"): ("44c3bd43dd161186", "93891480d1ba5ee1"),  # 44, 44
-    ("smallthinker", "prefill"): ("62c9d3c11db270e5", "1cfe8973c0312174"),  # 49, 49
+    ("smallthinker", "prefill"): ("19097d0c8dec2ec6", "1cfe8973c0312174"),  # 51, 49
 }
 
 
@@ -145,9 +146,10 @@ def _program(family, which):
         lanes = int(which.split("_")[1])
     args = (params, arena, i32(lanes), i32(lanes, width), i32(lanes),
             i32(lanes), f32(lanes), i32(lanes), f32(lanes), False)
+    # A piece's ``starts`` and, for the decoder's own piece frame, ``ends``.
     return be.prefill_fn(), (be.prefill_static_argnums,
                              be.donate_argnums), args + (
-        (i32(lanes),) if piece else ())
+        (i32(lanes),) * (1 + be.piece_ends) if piece else ())
 
 
 def _kernel_bodies(jaxpr, out):

@@ -467,7 +467,8 @@ def test_state_and_latent_piece_programs_compile_at_published_widths(
                    static_argnums=backend.prefill_static_argnums)
     compiled = step.lower(params, arena, lane_i,
                           place((lanes, 512), jnp.int32), lane_i, lane_i,
-                          lane_f, lane_i, lane_f, False, lane_i).compile()
+                          lane_f, lane_i, lane_f, False, lane_i,
+                          lane_i).compile()
     text = compiled.as_text()
     calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
     assert calls.count("grouped_matmul") == 14
@@ -612,7 +613,23 @@ SMALLTHINKER = dict(n_layers=8, d_model=2560, n_heads=28, n_kv_heads=4,
                     max_streams=48, attention_impl="flash", record=True)
 
 
+# A program compiled once a module: (family, which, lanes) -> what its
+# builder returned (two tests read the same piece programs).
+_COMPILED = {}
+
+
+def _once(key, build):
+    if key not in _COMPILED:
+        _COMPILED[key] = build()
+    return _COMPILED[key]
+
+
 def _smallthinker_program(one_chip, monkeypatch, which):
+    return _once(("smallthinker", which, 1), lambda: _compile_smallthinker(
+        one_chip, monkeypatch, which))
+
+
+def _compile_smallthinker(one_chip, monkeypatch, which):
     """``smallthinker_21b``'s ``jit_decode`` (a full wave of 48) or
     ``jit_prefill`` (one piece of 512) for one v5e chip from shapes alone
     (13.7 GB of weights and cache that nothing allocates).  Returns
@@ -646,7 +663,7 @@ def _smallthinker_program(one_chip, monkeypatch, which):
             static_argnums=backend.prefill_static_argnums)
         lowered = step.lower(params, arena, lane_i,
                              place((1, 512), jnp.int32), lane_i, lane_i,
-                             lane_f, lane_i, lane_f, False, lane_i)
+                             lane_f, lane_i, lane_f, False, lane_i, lane_i)
     compiled = lowered.compile()
     return compiled.as_text(), arena, compiled.memory_analysis(), backend
 
@@ -767,7 +784,8 @@ def _piece_backend_program(one_chip, monkeypatch, backend, which, lanes=1):
             static_argnums=backend.prefill_static_argnums)
         lowered = step.lower(params, arena, lane_i,
                              place((lanes, piece), jnp.int32), lane_i,
-                             lane_i, lane_f, lane_i, lane_f, False, lane_i)
+                             lane_i, lane_f, lane_i, lane_f, False, lane_i,
+                             lane_i)
     t0 = time.monotonic()
     compiled = lowered.compile()
     return (compiled.as_text(), arena, compiled.memory_analysis(),
@@ -775,6 +793,11 @@ def _piece_backend_program(one_chip, monkeypatch, backend, which, lanes=1):
 
 
 def _nemotron_program(one_chip, monkeypatch, which, lanes=1):
+    return _once(("nemotron", which, lanes), lambda: _compile_nemotron(
+        one_chip, monkeypatch, which, lanes))
+
+
+def _compile_nemotron(one_chip, monkeypatch, which, lanes):
     """``nemotron3_nano_30b``'s ``jit_decode`` (a full wave of 256) or
     ``jit_prefill`` (a piece of 512 of each of ``lanes`` prompts): 13.3 GB of
     weights and cache.  Returns (optimised text, arena shapes, memory,
@@ -842,6 +865,105 @@ def test_state_attention_and_expert_blocks_compile_at_published_widths(
     assert memory.temp_size_in_bytes < (0.3e9 if lanes == 2 else 0.2e9), \
         memory
     assert 13.2e9 < memory.argument_size_in_bytes < 13.4e9
+
+
+# -- the piece's head under one conditional (PR 51) -----------------------------
+
+# A piece program's temporaries at the parent of PR 51 (bytes, this compiler),
+# where the head stood in the open: (family, lanes) -> (temporaries, the
+# vocabulary, the model's width).
+_PIECE_BEFORE_THE_CONDITIONAL = {
+    ("smallthinker", 1): (56700416, 151936, 2560),
+    ("nemotron", 1): (33867776, 65536, 2688),
+    ("nemotron", 2): (168177664, 65536, 2688),
+}
+
+
+def _computations(text):
+    """name -> text of every computation of an optimised module."""
+    out = {}
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", comp)
+        if head:
+            out[head.group(1)] = comp
+    return out
+
+
+def _reached_from(comps, name):
+    """The computations ``name`` calls, itself among them."""
+    seen, todo = set(), [name]
+    while todo:
+        at = todo.pop()
+        if at in seen or at not in comps:
+            continue
+        seen.add(at)
+        todo += re.findall(r"%([\w.\-]+)", " ".join(re.findall(
+            r"(?:calls|to_apply|branch_computations|true_computation"
+            r"|false_computation|body|condition)=\{?([^}\n]*?)[},\n]",
+            comps[at])))
+    return seen
+
+
+@pytest.mark.parametrize("family,lanes", sorted(
+    _PIECE_BEFORE_THE_CONDITIONAL))
+def test_a_piece_computes_its_head_under_one_conditional(
+        one_chip, monkeypatch, family, lanes):
+    """The compiled piece program of ``smallthinker_21b``'s and
+    ``nemotron3_nano_30b``'s widths holds **one** conditional of two branches
+    beside its flash calls' switches (a branch a count of rows): the head's.
+    Whatever has the vocabulary's dimension (the product with the head's
+    matrix, the logits, the token choice) stands in the branch that a lane's
+    end takes, the other holds no product at all, and the donated arena does
+    not pass through either: the program's temporaries are the parent's and
+    at most a row of logits a lane more."""
+    if family == "smallthinker":
+        text, arena, memory, _ = _smallthinker_program(one_chip, monkeypatch,
+                                                       "prefill")
+    else:
+        text, arena, memory, _ = _nemotron_program(one_chip, monkeypatch,
+                                                   "prefill", lanes)
+    before, vocab, width = _PIECE_BEFORE_THE_CONDITIONAL[family, lanes]
+    comps = _computations(text)
+    two_way = [re.findall(r"%([\w.\-]+)", branches) for branches in
+               re.findall(r" conditional\([^\n]*?branch_computations="
+                          r"\{([^}]*)\}", text)
+               if branches.count("%") == 2]
+    assert len(two_way) == 1, two_way
+    skip, head = two_way[0]          # (pred is false, pred is true)
+    under = _reached_from(comps, head)
+    fused = set(re.findall(r" fusion\([^\n]*?calls=%([\w.\-]+)", text))
+    reads_head = {name for name, comp in comps.items()
+                  if re.search(rf"\(param[^\n]*?bf16\[{width},{vocab}\]",
+                               comp.split("\n", 1)[0])}
+    assert reads_head and reads_head <= under, reads_head - under
+    passes_on = ("parameter", "get-tuple-element", "tuple", "conditional")
+    for name, comp in comps.items():
+        if name in under or name in fused:
+            continue
+        shaped = re.findall(
+            rf"^\s*(?:ROOT )?%[\w.\-]+ = [^=\n]*?\b{vocab}\b[^=\n]*? "
+            rf"([\w\-]+)\(", comp, re.M)
+        assert set(shaped) <= set(passes_on), (name, shaped)
+    for name in _reached_from(comps, skip):
+        assert not re.search(r" (?:dot|convolution|custom-call|reduce)\(",
+                             comps[name]), name
+    # No leaf of the arena is an operand of the conditional.
+    (operands,) = re.findall(
+        r" conditional\(([^\n]*?)\), branch_computations=\{%"
+        + re.escape(skip), text)
+    leaves = {math.prod(leaf.shape) for leaf in arena.values()
+              if leaf.ndim > 1}
+    for operand in re.findall(r"%([\w.\-]+)", operands):
+        made = re.search(rf"%{re.escape(operand)} = (\(?[^=\n]*?) [\w\-]+\(",
+                         text).group(1)
+        for dims in re.findall(r"\w+\[([\d,]+)\]", made):
+            assert math.prod(int(d) for d in dims.split(",")) not in leaves, \
+                (operand, made)
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    print(f"{family} x{lanes}: temporaries {memory.temp_size_in_bytes} "
+          f"({before} before)")
+    assert memory.temp_size_in_bytes <= before + lanes * vocab * 4, memory
 
 
 # -- a layer stack that runs four passes over one set of weights (PR 50) --------
