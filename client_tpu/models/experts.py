@@ -141,18 +141,21 @@ class ExpertDecoder(SeededDecoder):
 
     def _piece_tile(self, tokens: int) -> int:
         """The sorted layout's tile for a piece call of ``tokens`` positions:
-        half a piece's where a held expert's mean share of the call's (token,
-        expert) pairs fits in half; a tile over the share only lengthens the
-        layout that every operation around the products walks.  (On the v5e
-        at the cells' widths, ms a program by tile, PERF.md section 6: 64 of
-        128 experts of 1856 held, 6 a token, PR 47: a share of 24 rows 15.20
-        | 13.70 | 13.96 in tiles of 16 | 32 | 64, of 48 rows 22.56 | 22.49 |
+        the smallest of 32, 64 and 128 rows (the MXU's) that holds a held
+        expert's mean share of the call's (token, expert) pairs.  A tile over
+        the share only lengthens the layout that every operation around the
+        products walks; a tile under it, while the MXU has rows to spare,
+        leaves the products short of the matrices' read.  (On the v5e at the
+        cells' widths, ms a program by tile, PERF.md section 6: 64 of 128
+        experts of 1856 held, 6 a token, PR 47: a share of 24 rows 15.20 |
+        13.70 | 13.96 in tiles of 16 | 32 | 64, of 48 rows 22.56 | 22.49 |
         24.4 in 32 | 64 | 128; 32 of 256 experts of 1024 held, 8 a token, PR
         48: a share of 16 rows 18.06 | 17.36 | 17.47 in 16 | 32 | 64, of 32
-        rows 32.19 | 33.51 in 32 | 64.)"""
+        rows 32.19 | 33.51 in 32 | 64; 64 experts of 768, all held, 6 a
+        token, PR 52: a share of 96 rows 28.41 | 27.07 in 64 | 128.)"""
         share = tokens * self.top_k / self.n_experts
-        half = TILE_M_PIECE // 2
-        return half if share <= half else TILE_M_PIECE
+        return next((tile for tile in (TILE_M_PIECE // 2, TILE_M_PIECE)
+                     if share <= tile), 2 * TILE_M_PIECE)
 
     def _experts(self, lp, h, live, tile_m, routing=None):
         """The held experts' part of the layer for tokens h ``[n, d]``:

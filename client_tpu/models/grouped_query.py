@@ -2,14 +2,17 @@
 one copy.
 
 ``models/smallthinker.py`` (its global layers; its window layers put a band
-and a ring around the same attention) and ``models/nemotron_h.py`` (its
-attention layers) both hold ``n_heads`` query heads over ``n_kv_heads``
-key/value heads of ``head_dim`` in two leaves ``[layers of the kind, R,
-max_seq_len, Hkv*D]`` and prefill by pieces of ``piece`` positions, a lane at a
-time.  Piece i of a prompt has exactly ``i * piece`` rows before it, so a
-layer holds one branch a count (``lax.switch``) and computes nothing that is
-masked but inside the causal block: the piece's queries attend to the rows
-before them and, causally, to their own (the flash kernel's grouped-query
+and a ring around the same attention), ``models/nemotron_h.py`` (its
+attention layers) and ``models/ouro.py`` hold ``n_heads`` query heads over
+``n_kv_heads`` key/value heads of ``head_dim`` in two leaves ``[layers of the
+kind, R, max_seq_len, Hkv*D]`` and prefill by pieces of ``piece`` positions,
+one prompt's or two's a program: a layer projects every lane's positions at
+once and then walks the lanes one after the other (``_lane_by_lane``), a
+lane's read of its slot, then its write, then the next lane's.  Piece i of a
+prompt has exactly ``i * piece`` rows before it, so a
+layer holds one branch a count and lane (``lax.switch``) and computes nothing
+that is masked but inside the causal block: the piece's queries attend to the
+rows before them and, causally, to their own (the flash kernel's grouped-query
 heads, ``ops/flash_attention.py``, or dense scores, by ``attention_impl``),
 and the piece's rows are written behind them.
 """
@@ -103,40 +106,55 @@ class GroupedQueryPieces:
     def _full_rows_layer(self, lp, x, pos):
         return self._full_layer(self._project(lp, x, pos), None)
 
-    def _lane_by_lane(self, one, qkv, k_a, v_a, ki, rows, starts, lens):
+    def _lane_by_lane(self, read, write, qkv, k_a, v_a, ki, rows, starts,
+                      lens):
         """A layer's part of a piece of ``L`` lanes from its projections over
         every lane's positions at once (``qkv``: q ``[L * piece, H, D]``, k, v
         ``[L * piece, Hkv, D]`` float32): the rows as the cache holds them,
-        then a lane at a time ``one(k_a, v_a, ki, row, start, n_valid, q,
-        own_k, own_v)`` -> (K leaf, V leaf, o ``[piece, H * D]``).  -> (K
-        leaf, V leaf, o ``[L * piece, H * D]``)."""
+        then lane after lane ``read(k_a, v_a, ki, row, start, q, own_k,
+        own_v)`` -> o ``[piece, H * D]`` and ``write(k_a, v_a, ki, row,
+        start, n_valid, own_k, own_v)`` -> (K leaf, V leaf).  **A lane's rows
+        go into the leaf behind its own reads and before the next lane's**:
+        where another lane follows, the rows pass a barrier beside the
+        lane's output, so the order is the data's and not the compiler's to
+        choose (left to it, the two-lane program of ``smallthinker_21b``'s
+        widths copied a 1.5 GB leaf to keep one lane's reads apart from
+        the other's writes; tests/test_tpu_compile.py).  -> (K leaf, V leaf,
+        o ``[L * piece, H * D]``)."""
+        import jax
         import jax.numpy as jnp
 
         n, (q, k, v) = self.piece, qkv
         own_k, own_v = self._as_cached(k, v, k_a.dtype)
-        outs = []
-        for i in range(rows.shape[0]):
+        lanes, outs = rows.shape[0], []
+        for i in range(lanes):
             own = slice(i * n, (i + 1) * n)
-            k_a, v_a, o = one(k_a, v_a, ki, rows[i], starts[i], lens[i],
-                              q[own], own_k[own], own_v[own])
+            # (The lane's operands in the order the recorded one-lane
+            # programs take them: tests/test_served_programs.py.)
+            at, n_valid = (ki, rows[i], starts[i]), lens[i]
+            new_k, new_v = own_k[own], own_v[own]
+            o = read(k_a, v_a, *at, q[own], new_k, new_v)
+            if i + 1 < lanes:
+                new_k, new_v, o = jax.lax.optimization_barrier(
+                    (new_k, new_v, o))
+            k_a, v_a = write(k_a, v_a, *at, n_valid, new_k, new_v)
             outs.append(o)
         return k_a, v_a, jnp.concatenate(outs)
 
     def _piece_rows_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
         """A whole-context layer's part of a piece (models/decoder.py
         ``piece_hidden_fn``), by the backend's ``_project(lp, x, pos)``."""
-        return self._lane_by_lane(self._piece_rows, self._project(lp, x, pos),
-                                  k_a, v_a, ki, rows, starts, lens)
+        return self._lane_by_lane(
+            self._read_rows, self._write_rows, self._project(lp, x, pos),
+            k_a, v_a, ki, rows, starts, lens)
 
-    def _piece_rows(self, k_a, v_a, ki, row, start, n_valid, q, own_k, own_v):
+    def _read_rows(self, k_a, v_a, ki, row, start, q, own_k, own_v):
         """A whole-context layer's part of one lane's piece: q ``[piece, H,
         D]`` against the slot's ``start`` rows before the piece and its own
-        ``own_k, own_v [piece, Hkv*D]`` (as the cache holds them), which are
-        written behind them (those behind the ``n_valid`` are beyond the
-        slot's live rows).  -> (K leaf, V leaf, o ``[piece, H * D]``)."""
+        ``own_k, own_v [piece, Hkv*D]`` (as the cache holds them).  -> o
+        ``[piece, H * D]``."""
         import jax
 
-        del n_valid
         n = self.piece
 
         def attend(pre):
@@ -144,10 +162,17 @@ class GroupedQueryPieces:
                       for leaf in (k_a, v_a)]
             return self._attend(q, own_k, own_v, *before, None)
 
-        o = jax.lax.switch(
+        return jax.lax.switch(
             start // n, [lambda pre=i * n: attend(pre)
                          for i in range(self.max_seq_len // n)])
-        k_a, v_a = (jax.lax.dynamic_update_slice(
+
+    def _write_rows(self, k_a, v_a, ki, row, start, n_valid, own_k, own_v):
+        """A lane's piece written behind the slot's ``start`` rows (those
+        behind the ``n_valid`` are beyond the slot's live rows).  -> (K
+        leaf, V leaf)."""
+        import jax
+
+        del n_valid
+        return tuple(jax.lax.dynamic_update_slice(
             leaf, own[None, None], (ki, row, start, 0))
             for leaf, own in ((k_a, own_k), (v_a, own_v)))
-        return k_a, v_a, o

@@ -35,15 +35,21 @@ global layers' is ``decode_wave_attention``, both with grouped-query rows).
 to the softmax.  A slot is ``(3 window + max_seq_len) / (4 max_seq_len)`` of
 what it would be with every layer global.
 
-**Prefill goes by pieces** of ``piece`` positions (``prefill_piece``), one
-prompt a call.  Piece i of a prompt has ``i * piece`` rows before it in a
+**Prefill goes by pieces** of ``piece`` positions (``prefill_piece``), the
+next piece of one prompt or of two a call (the two oldest that wait: a
+layer's 64 experts are then read once for both; the projections and the
+expert layer see both lanes' positions as one batch, the attention walks the
+lanes one after the other, each from its own slot).  Piece i of a prompt has
+``i * piece`` rows before it in a
 global layer and ``min(i * piece, window)`` in a window layer, so a layer
-holds one branch a count (``lax.switch``: nothing masked is computed but
+holds one branch a count and lane (``lax.switch`` on the lane's own
+``start``: nothing masked is computed but
 inside the band's two edge blocks): the piece's queries attend to the rows
 before them and, causally, to their own, with the flash kernel's band and
-grouped-query heads (``ops/flash_attention.py``; the attention and a global
-layer's part of a piece are ``models/grouped_query.py``'s, shared with
-``models/nemotron_h.py``).  A ring that is full is read
+grouped-query heads (``ops/flash_attention.py``; the attention, the walk over
+the lanes and a global layer's part of a piece are
+``models/grouped_query.py``'s, shared with ``models/nemotron_h.py`` and
+``models/ouro.py``).  A ring that is full is read
 whole, oldest position first, **before** the piece's rows overwrite its oldest
 block (a ring holds whole pieces: a piece never wraps); a
 prompt's last piece writes its valid rows only, the rows behind them being
@@ -136,7 +142,9 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
             raise ValueError("max_seq_len divides into prefill pieces, and "
                              "a window's ring fits a slot")
         self._check_experts()
-        self.prefill_piece = (self.piece, 1)
+        # Two prompts a piece program at most (what was measured: PERF.md
+        # section 6, PR 52).
+        self.prefill_piece = (self.piece, 2)
         self.stream_record = record_width(
             self.n_layers * self.held_words) if record else 0
 
@@ -222,21 +230,21 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
         """A window layer's part of a piece (models/decoder.py
         ``piece_hidden_fn``)."""
         return self._lane_by_lane(
-            self._piece_ring, self._project(lp, x, pos, "ring"), k_a, v_a, ki,
-            rows, starts, lens)
+            self._read_ring, self._write_ring,
+            self._project(lp, x, pos, "ring"), k_a, v_a, ki, rows, starts,
+            lens)
 
     def _full_ring_layer(self, lp, x, pos):
         return self._full_layer(self._project(lp, x, pos, "ring"), self.window)
 
-    def _piece_ring(self, k_a, v_a, ki, row, start, n_valid, q, own_k, own_v):
+    def _read_ring(self, k_a, v_a, ki, row, start, q, own_k, own_v):
         """A window layer's part of one lane's piece: q ``[piece, H, D]``
         against the slot's ring and, causally, its own ``own_k, own_v [piece,
-        Hkv*D]`` (as the cache holds them), which are written into the ring.
-        -> (K leaf, V leaf, o ``[piece, H * D]``)."""
+        Hkv*D]`` (as the cache holds them).  -> o ``[piece, H * D]``."""
         import jax
         import jax.numpy as jnp
 
-        n, hd = self.piece, self.n_kv_heads * self.head_dim
+        n = self.piece
 
         def attend(pre, rolled=False):
             before = [self._rows_before(leaf, ki, row, pre)
@@ -251,7 +259,15 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
         full = self.ring_rows // n
         branches = [lambda pre=i * n: attend(pre) for i in range(full)]
         branches.append(lambda: attend(self.ring_rows, rolled=True))
-        o = jax.lax.switch(jnp.minimum(start // n, full), branches)
+        return jax.lax.switch(jnp.minimum(start // n, full), branches)
+
+    def _write_ring(self, k_a, v_a, ki, row, start, n_valid, own_k, own_v):
+        """A lane's piece written into the slot's ring, over its oldest
+        block.  -> (K leaf, V leaf)."""
+        import jax
+        import jax.numpy as jnp
+
+        n, hd = self.piece, self.n_kv_heads * self.head_dim
         at = start % self.ring_rows
         # A prompt's last piece: the rows behind its valid ones hold
         # positions a later step still reads.
@@ -260,10 +276,9 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
             jnp.where(valid, own, jax.lax.dynamic_slice(
                 leaf, (ki, row, at, 0), (1, 1, n, hd))[0, 0])
             for own, leaf in ((own_k, k_a), (own_v, v_a)))
-        k_a, v_a = (jax.lax.dynamic_update_slice(
+        return tuple(jax.lax.dynamic_update_slice(
             leaf, own[None, None], (ki, row, at, 0))
             for leaf, own in ((k_a, own_k), (v_a, own_v)))
-        return k_a, v_a, o
 
     # -- generative interface (used by GenerativeScheduler) -------------------
 

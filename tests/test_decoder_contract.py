@@ -191,7 +191,7 @@ FRAME_BACKENDS = {
     "pangu_moe": ("pangu_moe", "PanguMoeBackend", {}, 1),
     "kimi_linear": ("kimi_linear", "KimiLinearBackend", {}, 2),
     "smallthinker": ("smallthinker", "SmallThinkerBackend",
-                     {"record": True}, 1),
+                     {"record": True}, 2),
     "nemotron_h": ("nemotron_h", "NemotronHBackend", {"record": True}, 2),
     "ouro": ("ouro", "OuroBackend", {"record": True}, 2),
 }

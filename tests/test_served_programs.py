@@ -33,11 +33,14 @@ RECORDED = {
     ("kimi", "prefill"): ("37d5b524b0b73f60", "083a795658c4ced4"),    # 51, 48
     ("kimi", "prefill_1"): ("c1a4f0a1a32b0105", "11c370955249a916"),  # 51, 48
     ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),  # 45, 45
-    ("nemotron", "prefill"): ("d59199529e2364d4", "036da1223ab7372f"),  # 51, 47
+    # (the two-lane programs of PR 52: a lane's rows pass a barrier beside
+    # its output where another lane follows, models/grouped_query.py
+    # ``_lane_by_lane``; the kernels and every one-lane program as they were)
+    ("nemotron", "prefill"): ("d02cab991c063cc5", "036da1223ab7372f"),  # 52, 47
     ("nemotron", "prefill_1"): ("50e1142bcff1a77f", "72f715ae5cf8c153"),  # 51, 47
     # (three passes over two layers: the pass axis of both frames)
     ("ouro", "decode"): ("a3cdb3b4609e3d6a", "0a13edafef621d9d"),     # 50, 50
-    ("ouro", "prefill"): ("f92e3ffa529763cb", "472369f043e003f9"),    # 51, 50
+    ("ouro", "prefill"): ("70bea758f74145ba", "472369f043e003f9"),    # 52, 50
     ("ouro", "prefill_1"): ("6418e3116c027a48", "54b16b6182cc8ce7"),  # 51, 50
     ("pangu", "decode"): ("b73c536a3102de37", "231955794429a2a6"),    # 33, 40
     # (the piece programs of PR 49: one frame for the four piece backends,
@@ -49,7 +52,9 @@ RECORDED = {
     # conditional on the trailing ``ends``, the kernels as they were)
     ("pangu", "prefill"): ("9f81a0e83b3aaa34", "f9c5bf8655c5c096"),   # 51, 49
     ("smallthinker", "decode"): ("44c3bd43dd161186", "93891480d1ba5ee1"),  # 44, 44
-    ("smallthinker", "prefill"): ("19097d0c8dec2ec6", "1cfe8973c0312174"),  # 51, 49
+    # (``prefill_1`` is what ``prefill`` was until PR 52 declared two lanes)
+    ("smallthinker", "prefill"): ("1fe2b7a99c70a34e", "65c5487282cc111d"),  # 52, 52
+    ("smallthinker", "prefill_1"): ("19097d0c8dec2ec6", "1cfe8973c0312174"),  # 51, 49
 }
 
 
@@ -214,13 +219,14 @@ def test_the_piece_frame_is_the_decoders():
 
 
 # The four cells' routers, and what ``_piece_tile`` gives a piece call of
-# ``lanes`` prompts of 512 positions there (the tiles PR 47 and PR 48 measured
-# fastest: models/experts.py ``_piece_tile``).
+# ``lanes`` prompts of 512 positions there (the tiles PR 47, PR 48 and PR 52
+# measured fastest: models/experts.py ``_piece_tile``).
 PIECE_TILES = [
     ("pangu", dict(n_experts=256, top_k=8), 1, 32),
     ("kimi", dict(n_experts=256, top_k=8), 1, 32),
     ("kimi", dict(n_experts=256, top_k=8), 2, 32),
     ("smallthinker", dict(n_experts=64, top_k=6), 1, 64),
+    ("smallthinker", dict(n_experts=64, top_k=6), 2, 128),
     ("nemotron", dict(n_experts=128, top_k=6), 1, 32),
     ("nemotron", dict(n_experts=128, top_k=6), 2, 64),
 ]

@@ -252,6 +252,241 @@ def test_a_prompts_last_piece_keeps_the_ring_rows_behind_it():
     assert [np.nonzero(c)[0].tolist() for c in changed] == [[24 % WINDOW]] * 3
 
 
+# -- two prompts' pieces in one program -------------------------------------------
+
+# Two lanes of one piece call: (whole pieces prefilled before the call,
+# positions the call holds) a lane, each lane's prompt its own.  A ring is two
+# pieces in both geometries below, so a lane with three or more pieces before
+# it reads its ring rolled, and one that holds fewer positions than a piece is
+# at its prompt's last piece: the rows behind its valid ones stay.
+TWO_LANES = {
+    "a_first_piece_beside_one_past_the_window": [(0, 4), (3, 4)],
+    "a_last_piece_beside_one_that_goes_on": [(5, 1), (1, 4)],
+    "both_past_the_window_at_different_starts": [(3, 4), (5, 3)],
+    "a_ring_just_filled_beside_a_first_piece_cut_short": [(2, 4), (0, 2)],
+}
+# The tiny preset (dense scores, either decode path's expert products) and
+# heads of a whole lane tile through the flash kernel, interpreted.
+GEOMETRIES = {
+    "reference": dict(attn_impl="reference"),
+    "fused": dict(attn_impl="fused"),
+    "flash": dict(attention_impl="flash", head_dim=128, n_heads=2,
+                  n_kv_heads=1, piece=8, window=16, n_layers=2,
+                  window_layout=(0, 1)),
+}
+LEAVES = ("kw", "vw", "kg", "vg")
+
+
+def _piece_args(lanes, slots, piece):
+    """A piece call's (rows, ids, lens, starts) for ``lanes`` [(prompt,
+    positions before, positions held)]."""
+    buf = np.zeros((len(lanes), piece), np.int32)
+    for i, (ids, before, held) in enumerate(lanes):
+        buf[i, :held] = ids[before:before + held]
+    return (np.asarray(slots, np.int32), buf,
+            np.asarray([held for _, _, held in lanes], np.int32),
+            np.asarray([before for _, before, _ in lanes], np.int32))
+
+
+def _pair_and_solo(be, lanes):
+    """``lanes`` [(prompt, positions before, positions held)] through one
+    two-lane call (``pair``) and through two one-lane calls (``solo``), both
+    after the same one-lane pieces before.  -> (pair, solo, per lane (x,
+    routes) of each)."""
+    pair, solo = Served(be), Served(be)
+    for srv in (pair, solo):
+        for slot, (ids, before, _) in enumerate(lanes):
+            if before:
+                srv.prefill(ids[:before], slot=slot)
+    pair.arena, x, routes = pair.piece(
+        pair.params, pair.arena, *_piece_args(lanes, [0, 1], be.piece))
+    x, routes = np.asarray(x), np.asarray(routes)
+    got = [(x[i * be.piece:(i + 1) * be.piece],
+            routes[:, i * be.piece:(i + 1) * be.piece]) for i in range(2)]
+    want = []
+    for slot, lane in enumerate(lanes):
+        solo.arena, x, routes = solo.piece(
+            solo.params, solo.arena, *_piece_args([lane], [slot], be.piece))
+        want.append((np.asarray(x), np.asarray(routes)))
+    return pair, solo, got, want
+
+
+def _two_lanes(dtype, geometry, case):
+    """A case of ``TWO_LANES`` in a geometry.  -> (``_pair_and_solo``'s
+    four, the lanes)."""
+    be = backend(dtype=dtype, **GEOMETRIES[geometry])
+    lanes = [(ids_of(SEQ, seed=30 + i), pieces * be.piece, held)
+             for i, (pieces, held) in enumerate(TWO_LANES[case])]
+    return (*_pair_and_solo(be, lanes), lanes)
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+@pytest.mark.parametrize("case", sorted(TWO_LANES))
+def test_two_lanes_of_a_piece_are_the_lanes_alone_bit_for_bit(geometry, case):
+    """float32: two prompts' pieces in one program (the projections and the
+    expert layer over both lanes' positions at once, the attention a lane at
+    a time, each from its own slot and by its own ``start``'s branch, in
+    window and global layers alike) leave every slot's rings and rows, and
+    give every valid position's activations and choices, exactly as the same
+    two pieces do one lane at a time."""
+    pair, solo, got, want, lanes = _two_lanes("float32", geometry, case)
+    for leaf in LEAVES:
+        assert np.array_equal(np.asarray(pair.arena[leaf][:, :3]),
+                              np.asarray(solo.arena[leaf][:, :3])), leaf
+    for (x, routes), (x1, routes1), (_, _, held) in zip(got, want, lanes):
+        assert np.array_equal(x[:held], x1[:held])
+        assert np.array_equal(routes[:, :held], routes1[:, :held])
+
+
+@pytest.mark.parametrize("attn_impl", ["reference", "fused"])
+def test_two_lanes_in_the_wider_tile_are_the_lanes_alone(attn_impl):
+    """Two lanes whose pairs give an expert a mean share of 96 rows are sorted
+    in tiles of 128 where one lane's 48 go in tiles of 64 (``_piece_tile``,
+    the cell's shares at 512 positions a lane): the same choices, and the
+    same rings, rows and activations to the order of float32's sums (a
+    product over 96 rows is blocked otherwise than one over 48), one lane
+    past the window, the other at a first piece cut short."""
+    be = backend(dtype="float32", attn_impl=attn_impl, piece=48, window=96,
+                 max_seq_len=288, n_experts=2, top_k=2)
+    assert (be._piece_tile(48), be._piece_tile(96)) == (64, 128)
+    lanes = [(ids_of(288, seed=60), 144, 48), (ids_of(288, seed=61), 0, 20)]
+    pair, solo, got, want = _pair_and_solo(be, lanes)
+    for (x, routes), (x1, routes1), (_, _, held) in zip(got, want, lanes):
+        assert np.abs(x[:held] - x1[:held]).max() < TOL_F32
+        assert np.array_equal(routes[:, :held], routes1[:, :held])
+    for leaf in LEAVES:
+        assert np.abs(np.asarray(pair.arena[leaf][:, :3])
+                      - np.asarray(solo.arena[leaf][:, :3])).max() < TOL_F32
+
+
+@pytest.mark.parametrize("geometry", ["fused", "flash"])
+@pytest.mark.parametrize("case", sorted(TWO_LANES))
+def test_two_bfloat16_lanes_are_the_lanes_alone_within_the_files_limits(
+        geometry, case):
+    """bfloat16: a matmul over twice the rows may round an activation the
+    other way, so the two forms agree to the file's limit against the
+    reference, on the logits of every valid position and on the rows they
+    leave, and not to the bit."""
+    pair, solo, got, want, lanes = _two_lanes("bfloat16", geometry, case)
+    for leaf in LEAVES:
+        a, b = (np.asarray(srv.arena[leaf][:, :3], np.float32)
+                for srv in (pair, solo))
+        assert np.abs(a - b).max() < TOL_BF16, leaf
+    for (x, _), (x1, _), (_, _, held) in zip(got, want, lanes):
+        logits, logits1 = (np.asarray(pair.be._logits(pair.params, t[:held]))
+                           for t in (x, x1))
+        assert np.abs(logits - logits1).max() < TOL_BF16
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_a_last_piece_in_a_pair_keeps_the_ring_rows_behind_it(geometry):
+    """The pair's lane at its prompt's last piece, one valid position past
+    the window, writes one row of each ring; the lane beside it writes its
+    whole piece into its own slot and nothing into the first's."""
+    pair, _, _, _, lanes = _two_lanes(
+        "float32", geometry, "a_last_piece_beside_one_that_goes_on")
+    be = pair.be
+    alone = Served(be)
+    alone.prefill(lanes[0][0][:lanes[0][1]], slot=0)
+    changed = (np.asarray(alone.arena["kw"][:, 0])
+               != np.asarray(pair.arena["kw"][:, 0])).any(-1)
+    assert [np.nonzero(c)[0].tolist() for c in changed] == [
+        [lanes[0][1] % be.ring_rows]] * be.layer_kinds.count("ring")
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_a_padded_second_lane_leaves_every_live_slot_untouched(geometry):
+    """A two-lane call whose second lane holds no prompt (the junk slot, one
+    position, as a scheduler would pad it): slots 0 and 2 hold live streams
+    and stand bit for bit, and slot 1's piece, past the window, is the
+    one-lane program's."""
+    be = backend(dtype="float32", **GEOMETRIES[geometry])
+    pair, solo = Served(be), Served(be)
+    ids, before = ids_of(SEQ, seed=41), 3 * be.piece
+    for srv in (pair, solo):
+        srv.walk(ids_of(30, seed=42), 20, slot=0)
+        srv.walk(ids_of(25, seed=43), 18, slot=2)
+        srv.prefill(ids[:before], slot=1)
+    rows, buf, lens, starts = _piece_args([(ids, before, be.piece)], [1],
+                                          be.piece)
+    pair.arena, x, _ = pair.piece(
+        pair.params, pair.arena, np.asarray([1, 3], np.int32),
+        np.concatenate([buf, np.zeros_like(buf)]),
+        np.asarray([be.piece, 1], np.int32),
+        np.asarray([before, 0], np.int32))
+    solo.arena, x1, _ = solo.piece(solo.params, solo.arena, rows, buf, lens,
+                                   starts)
+    assert np.array_equal(np.asarray(x)[:be.piece], np.asarray(x1))
+    for leaf in LEAVES:
+        assert np.array_equal(np.asarray(pair.arena[leaf][:, :3]),
+                              np.asarray(solo.arena[leaf][:, :3])), leaf
+
+
+# The whole prefill program's two lanes: (whole pieces before, positions
+# held, the lane's piece is its prompt's last) a lane.
+TWO_ENDS = {
+    "both_end": [(0, 3, 1), (3, 4, 1)],
+    "one_ends_past_the_window_beside_one_that_goes_on": [(4, 2, 1),
+                                                         (0, 4, 0)],
+    "neither_ends": [(1, 4, 0), (3, 4, 0)],
+}
+
+
+@pytest.mark.parametrize("sample", [False, True])
+@pytest.mark.parametrize("case", sorted(TWO_ENDS))
+def test_two_lanes_tokens_and_record_rows_are_the_lanes_alone(case, sample):
+    """The whole prefill program: a token a lane from its own last valid
+    position into its own slot, and the record laid ``[L | L x piece x
+    stream_record]`` as the scheduler cuts it, lane after lane: each lane's
+    words (64 experts' bits in two words a layer) and, where a lane ends,
+    its logits' bits in its last valid row and nowhere else."""
+    be = backend(dtype="float32", record=True)
+    step = jax.jit(be.prefill_fn(), static_argnums=be.prefill_static_argnums)
+    params = be.place_params(be._init_params())
+    lanes = [(ids_of(SEQ, seed=50 + i), pieces * PIECE, held)
+             for i, (pieces, held, _) in enumerate(TWO_ENDS[case])]
+    ends = [end for _, _, end in TWO_ENDS[case]]
+    width = PIECE * be.stream_record
+
+    def run(arena, which, slots, ends):
+        rows, buf, lens, starts = _piece_args(which, slots, PIECE)
+        n = len(which)
+        arena, out = step(
+            params, arena, rows, buf, lens,
+            np.asarray(slots, np.int32) + 5,          # a seed a slot
+            np.full(n, 0.9 if sample else 0.0, np.float32),
+            np.full(n, 8, np.int32), np.full(n, 0.95, np.float32), sample,
+            starts, np.asarray(ends, np.int32))
+        out = np.asarray(out)
+        assert out.shape == (n * (1 + width),)
+        return (arena, out[:n],
+                out[n:].reshape(n, PIECE, be.stream_record))
+
+    def before(slots):
+        arena = be.init_arena(3)
+        for (ids, upto, _), slot in zip(lanes, slots):
+            for st in range(0, upto, PIECE):
+                arena, _, _ = run(arena, [(ids, st, PIECE)], [slot], [0])
+        return arena
+
+    slots = [2, 0]
+    arena, tokens, record = run(before(slots), lanes, slots, ends)
+    arena1 = before(slots)
+    for i, (lane, slot) in enumerate(zip(lanes, slots)):
+        # Alone a lane runs its head where the pair ran it for the lane.
+        arena1, tokens1, record1 = run(arena1, [lane], [slot],
+                                       [int(any(ends))])
+        held = lane[2]
+        assert tokens[i] == tokens1[0]
+        assert np.array_equal(record[i, :held], record1[0, :held])
+        assert (record[i, :held - 1, -9:] == 0).all()
+        assert record[i, held - 1, -9:].any() == any(ends)
+    assert np.array_equal(np.asarray(arena["tok"]), np.asarray(arena1["tok"]))
+    for leaf in LEAVES:
+        assert np.array_equal(np.asarray(arena[leaf][:, :3]),
+                              np.asarray(arena1[leaf][:, :3])), leaf
+
+
 @pytest.mark.parametrize("piece", [4, 8, 24])
 def test_a_prompt_cut_into_pieces_of_any_size_gives_one_cache(piece):
     ids = ids_of(24)
@@ -500,6 +735,69 @@ class TestScheduler:
             * be.n_layers * be.top_k
         assert c["fetched_rows_exact"] == c["fetched_rows_summary"] == 0
 
+    @pytest.mark.parametrize("attn_impl", ["reference", "fused"])
+    def test_a_piece_program_holds_the_prompts_that_wait(self, attn_impl):
+        """``prefill_pieces`` counts a lane's piece each and the span
+        ``gen.prefill_dispatch`` a program each: a prompt alone in line goes
+        through the one-lane program piece by piece, two that wait together
+        go two to a program while both have pieces left (the longer one's
+        last pieces past the window, alone), every prompt gets the tokens it
+        gets alone, and nothing compiles after the warm-up, which ran both
+        lane counts."""
+        from client_tpu.engine.generative import _WarmupReq
+
+        name = f"st_lanes_{attn_impl}"
+        repo = ModelRepository()
+        repo.register_backend(backend(name=name, attn_impl=attn_impl,
+                                      dtype="float32", max_streams=2))
+        engine = TpuEngine(repo)
+        sched = engine._schedulers[name]
+        sched.warmup()
+
+        def snap():
+            c = counters(engine, name)
+            g = engine.profile_snapshot(model=name)
+            return (c["prefill_pieces"],
+                    g["models"][f"{name}:1"]["generative"]["spans"][
+                        spans.GEN_PREFILL_DISPATCH]["count"],
+                    g["compiles"]["count"])
+
+        def held(prompts):
+            """One admit takes them all: the worker is held inside a
+            warm-up sentinel while they queue."""
+            counters(engine, name)            # parked in its blocking wait
+            gate, real = threading.Event(), sched._precompile
+            sched._precompile = lambda: gate.wait(60)
+            hold = _WarmupReq()
+            try:
+                sched.queue.put(hold)
+                deadline = time.monotonic() + 10
+                while sched._rec.open is sched._rec.span[spans.S_IDLE] \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.001)
+                joins = [stream(engine, p, 4, name) for p in prompts]
+            finally:
+                gate.set()
+                sched._precompile = real
+            assert hold.done.wait(60)
+            return [j() for j in joins]
+
+        try:
+            prompts = [ids_of(n, seed=70 + i).tolist()
+                       for i, n in enumerate((18, 6))]    # 5 pieces and 2
+            before = snap()
+            alone = [stream(engine, p, 4, name)() for p in prompts]
+            single = snap()
+            assert (single[0] - before[0], single[1] - before[1]) == (7, 7)
+            together = held(prompts)
+            pair = snap()
+            # Programs of 2, 2, 1, 1, 1 lanes.
+            assert (pair[0] - single[0], pair[1] - single[1]) == (7, 5)
+            assert together == alone
+            assert pair[2] == before[2]
+        finally:
+            engine.shutdown()
+
     def test_a_backend_without_a_ring_counts_none(self):
         from client_tpu.models.generate import TinyGptBackend
 
@@ -547,7 +845,7 @@ def test_the_backend_built_from_the_file_is_the_issues_arena():
     assert arena["kw"].shape == arena["vw"].shape == (6, 49, 4096, 512)
     assert arena["kg"].shape == arena["vg"].shape == (2, 49, 16384, 512)
     assert all(arena[k].dtype == jnp.bfloat16 for k in "kw vw kg vg".split())
-    assert be.prefill_piece == (512, 1) and be.ring_window is None
+    assert be.prefill_piece == (512, 2) and be.ring_window is None
     assert (be.router_score, be.expert_act) == ("softmax", "relu")
     total = sum(int(np.prod(w.shape))
                 for w in jax.tree_util.tree_leaves(be._init_params()))
