@@ -35,7 +35,10 @@ generation streams. Design, TPU-first:
   lanes): such a program computes its head, the vocabulary's matrix read for
   one row a lane, only where some lane ends, so a prompt of a dozen pieces
   pays for one head and not for twelve (counter ``prefill_heads``: the piece
-  programs in which some lane ended).
+  programs in which some lane ended).  A backend that declares
+  ``piece_pairs_by_kind`` has the (query, key) pairs of every dispatched
+  lane's piece added to ``prefill_pairs_window`` and ``prefill_pairs_global``,
+  as ``cache_rows_by_kind`` feeds a wave's rows by kind.
 - **Transitions** (a backend that declares ``transition_due(n)`` and
   ``transition_fn()``): a stream whose dispatch-side length ``n`` is due has
   the jitted transition queued before its next wave (span
@@ -357,6 +360,7 @@ class GenerativeScheduler(Scheduler):
         self._piece_ends = bool(self._piece_len and backend.piece_ends)
         self._cache_rows = backend.cache_rows
         self._rows_by_kind = backend.cache_rows_by_kind
+        self._pairs_by_kind = backend.piece_pairs_by_kind
         self._passes = int(backend.passes)
         # Counters only the device can fill (``wave_stats``): that many
         # int32 ride behind a decode wave's tokens.
@@ -912,6 +916,12 @@ class GenerativeScheduler(Scheduler):
         self._rec.c[_sp.C_PREFILL_POSITIONS_VALID] += held
         self._rec.c[_sp.C_PREFILL_POSITIONS_PADDED] += \
             len(todo) * width - held
+        if self._pairs_by_kind is not None:
+            for i in range(len(todo)):
+                ring, whole = self._pairs_by_kind(int(starts[i]),
+                                                  int(lens[i]))
+                self._rec.c[_sp.C_PREFILL_PAIRS_WINDOW] += ring
+                self._rec.c[_sp.C_PREFILL_PAIRS_GLOBAL] += whole
         self.stats.record_execution(len(todo))
         done = []                     # by lane: the stream, if it ended
         for i, s in enumerate(todo):

@@ -88,6 +88,17 @@ def _ouro() -> ModelBackend:
     return OuroBackend()
 
 
+@register_model("cohere_moe", default=False)
+def _cohere_moe() -> ModelBackend:
+    """The parallel-block decoder (window and full layers, one LayerNorm that
+    attention, routed and averaged shared experts all read, a tied head), at
+    its tiny preset.  Opt-in, and imported when it is built, as
+    ``pangu_moe``."""
+    from client_tpu.models.cohere_moe import CohereMoeBackend
+
+    return CohereMoeBackend()
+
+
 def model_names() -> list[str]:
     _import_all()
     return sorted(_REGISTRY)

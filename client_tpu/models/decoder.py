@@ -85,6 +85,12 @@ axis of both, the chunked step, the sampling tail, the decoupled
   the context has outgrown the ring (1 | 0): the scheduler's counters
   ``fetched_rows_window``, ``fetched_rows_global``,
   ``fetched_lanes_past_window``.
+- ``piece_pairs_by_kind``: ``None``, or ``(start, valid) -> (ring pairs, other
+  pairs)``: the (query, key) pairs that a lane's piece of ``valid`` positions
+  from ``start`` scores in its ``"ring"`` layers and in its ``"rows"`` layers,
+  each summed over the layers of the kind: the scheduler's counters
+  ``prefill_pairs_window``, ``prefill_pairs_global`` (what a piece's attention
+  costs; read with ``prefill_piece`` alone).
 - ``transition_due`` / ``transition_fn``: ``None``, or ``(n) -> bool`` and the
   builder of ``(params, arena, rows[T], lens[T]) -> arena``, ordered before the
   wave of a stream that decoded its way to a due length.
@@ -293,6 +299,7 @@ class DecoderBackend(ModelBackend):
     stream_record = 0
     cache_rows = None
     cache_rows_by_kind = None
+    piece_pairs_by_kind = None
     transition_due = None
     transition_fn = None
     donate_argnums = (PREFILL_ARGS.index("arena"),)

@@ -15,6 +15,17 @@ that is masked but inside the causal block: the piece's queries attend to the
 rows before them and, causally, to their own (the flash kernel's grouped-query
 heads, ``ops/flash_attention.py``, or dense scores, by ``attention_impl``),
 and the piece's rows are written behind them.
+
+**A ring beside whole-context rows** (:class:`RingPieces`): the backends whose
+layers are window and global layers mixed (``models/smallthinker.py``,
+``models/cohere_moe.py``) keep two row shapes in one arena, ``kg, vg [global
+layers, R, max_seq_len, Hkv*D]`` and the rings ``kw, vw [window layers, R,
+ring rows, Hkv*D]``, position n at row ``n mod ring rows``.  What a piece does
+to a ring (read it whole, oldest position first, before the piece's rows
+overwrite its oldest block; a prompt's last piece writes its valid rows
+alone), the arena of two row shapes, the ring's size and what the scheduler
+counts of both kinds live here once, by ``layer_kinds`` and ``rotate`` alone:
+which layers slide, and whether the layers of a kind take positions.
 """
 
 from __future__ import annotations
@@ -176,3 +187,125 @@ class GroupedQueryPieces:
         return tuple(jax.lax.dynamic_update_slice(
             leaf, own[None, None], (ki, row, start, 0))
             for leaf, own in ((k_a, own_k), (v_a, own_v)))
+
+
+class RingPieces(GroupedQueryPieces):
+    """Window layers (a ring a slot) beside global layers (a row a position)
+    in one arena, for a backend that supplies ``_project(lp, x, pos, kind)``
+    and sets, beside :class:`GroupedQueryPieces`'s sizes, ``n_layers``,
+    ``window`` and ``dtype`` and calls ``_ring_setup``."""
+
+    cache_leaves = ("kg", "vg")
+    ring_leaves = ("kw", "vw")
+
+    def _ring_setup(self, slides, rotates):
+        """``layer_kinds`` (``"ring"`` where a layer slides, ``"rows"`` where
+        it sees every earlier position), ``rotate`` (kind -> whether its
+        layers take positions: the frame hands a layer over by its kind, so
+        the layers of a kind rotate alike), and the ring's rows: the window's
+        keys rounded up to whole pieces (a piece never wraps)."""
+        self.layer_kinds = tuple("ring" if s else "rows" for s in slides)
+        self.rotate = {kind: rotates[self.layer_kinds.index(kind)]
+                       for kind in set(self.layer_kinds)}
+        if any(r != self.rotate[k]
+               for r, k in zip(rotates, self.layer_kinds)):
+            raise ValueError("the layers of a kind (window | global) rotate "
+                             "alike or not at all")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{self.n_heads} query heads over "
+                             f"{self.n_kv_heads} key/value heads")
+        self.ring_rows = -(-self.window // self.piece) * self.piece
+        if self.ring_rows != self.window:
+            self.ring_window = self.window
+        if self.max_seq_len % self.piece or self.ring_rows > self.max_seq_len:
+            raise ValueError("max_seq_len divides into prefill pieces, and "
+                             "a window's ring fits a slot")
+
+    # -- what the scheduler counts (models/decoder.py) ---------------------------
+
+    def cache_rows_by_kind(self, n: int) -> tuple[int, int, int]:
+        """(ring rows, whole-context rows, past the ring) of a decode step at
+        context length ``n``: a window layer reads its live rows but the one
+        it overwrites, a global layer every position's."""
+        rings = self.layer_kinds.count("ring")
+        return (rings * min(n, self.window - 1),
+                (self.n_layers - rings) * n, int(n > self.window))
+
+    # -- the decode step's parts (models/decoder.py) ---------------------------
+
+    def _ring_qkv(self, lp, x, pos):
+        return self._project(lp, x["h"], pos, "ring")
+
+    # -- a piece's attention over a ring ------------------------------------------
+
+    def _piece_ring_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
+        """A window layer's part of a piece (models/decoder.py
+        ``piece_hidden_fn``)."""
+        return self._lane_by_lane(
+            self._read_ring, self._write_ring,
+            self._project(lp, x, pos, "ring"), k_a, v_a, ki, rows, starts,
+            lens)
+
+    def _full_ring_layer(self, lp, x, pos):
+        return self._full_layer(self._project(lp, x, pos, "ring"), self.window)
+
+    def _read_ring(self, k_a, v_a, ki, row, start, q, own_k, own_v):
+        """A window layer's part of one lane's piece: q ``[piece, H, D]``
+        against the slot's ring and, causally, its own ``own_k, own_v [piece,
+        Hkv*D]`` (as the cache holds them).  -> o ``[piece, H * D]``."""
+        import jax
+        import jax.numpy as jnp
+
+        n = self.piece
+
+        def attend(pre, rolled=False):
+            before = [self._rows_before(leaf, ki, row, pre)
+                      for leaf in (k_a, v_a)]
+            if rolled:
+                # A full ring, oldest position first: row (start mod ring)
+                # holds position start - ring.
+                before = [jnp.roll(b, -(start % self.ring_rows), axis=0)
+                          for b in before]
+            return self._attend(q, own_k, own_v, *before, self.window)
+
+        full = self.ring_rows // n
+        branches = [lambda pre=i * n: attend(pre) for i in range(full)]
+        branches.append(lambda: attend(self.ring_rows, rolled=True))
+        return jax.lax.switch(jnp.minimum(start // n, full), branches)
+
+    def _write_ring(self, k_a, v_a, ki, row, start, n_valid, own_k, own_v):
+        """A lane's piece written into the slot's ring, over its oldest
+        block.  -> (K leaf, V leaf)."""
+        import jax
+        import jax.numpy as jnp
+
+        n, hd = self.piece, self.n_kv_heads * self.head_dim
+        at = start % self.ring_rows
+        # A prompt's last piece: the rows behind its valid ones hold
+        # positions a later step still reads.
+        valid = (jnp.arange(n) < n_valid)[:, None]
+        own_k, own_v = (
+            jnp.where(valid, own, jax.lax.dynamic_slice(
+                leaf, (ki, row, at, 0), (1, 1, n, hd))[0, 0])
+            for own, leaf in ((own_k, k_a), (own_v, v_a)))
+        return tuple(jax.lax.dynamic_update_slice(
+            leaf, own[None, None], (ki, row, at, 0))
+            for leaf, own in ((k_a, own_k), (v_a, own_v)))
+
+    # -- generative interface (used by GenerativeScheduler) -------------------
+
+    def init_arena(self, capacity: int):
+        """``kg, vg [global layers, R, max_seq_len, Hkv*D]`` and ``kw, vw
+        [window layers, R, ring rows, Hkv*D]`` in the model's dtype (``R =
+        capacity + 1``: the last slot absorbs padded lanes) and ``tok [R]``,
+        each slot's latest token on the device."""
+        import jax.numpy as jnp
+
+        r, dt = capacity + 1, jnp.dtype(self.dtype)
+        hd = self.n_kv_heads * self.head_dim
+        rings = self.layer_kinds.count("ring")
+        whole = (self.n_layers - rings, r, self.max_seq_len, hd)
+        ring = (rings, r, self.ring_rows, hd)
+        return {"kg": jnp.zeros(whole, dt), "vg": jnp.zeros(whole, dt),
+                "kw": jnp.zeros(ring, dt), "vw": jnp.zeros(ring, dt),
+                "tok": jnp.zeros(r, jnp.int32)}

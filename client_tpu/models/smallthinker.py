@@ -49,7 +49,11 @@ before them and, causally, to their own, with the flash kernel's band and
 grouped-query heads (``ops/flash_attention.py``; the attention, the walk over
 the lanes and a global layer's part of a piece are
 ``models/grouped_query.py``'s, shared with ``models/nemotron_h.py`` and
-``models/ouro.py``).  A ring that is full is read
+``models/ouro.py``; **the ring's parts** (the arena of two row shapes, the
+ring's size, a piece's read and write of a ring, what the scheduler counts of
+both kinds) **are that module's ``RingPieces``**, shared with
+``models/cohere_moe.py``: this file supplies ``_project(lp, x, pos, kind)``
+and which layers slide and rotate).  A ring that is full is read
 whole, oldest position first, **before** the piece's rows overwrite its oldest
 block (a ring holds whole pieces: a piece never wraps); a
 prompt's last piece writes its valid rows only, the rows behind them being
@@ -74,17 +78,15 @@ import math
 
 from client_tpu.models.decoder import record_width
 from client_tpu.models.experts import ExpertDecoder
-from client_tpu.models.grouped_query import GroupedQueryPieces
+from client_tpu.models.grouped_query import RingPieces
 from client_tpu.models.layers import rms_norm, rope
 
 
-class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
+class SmallThinkerBackend(RingPieces, ExpertDecoder):
     """The decoder above (``models/decoder.py`` for what it is served
     through).  ``dtype="float32"`` makes weights, cache and matmuls float32
     (the tests' exact comparison); the served form is bfloat16."""
 
-    cache_leaves = ("kg", "vg")
-    ring_leaves = ("kw", "vw")
     router_score = "softmax"
     expert_act = "relu"
 
@@ -115,48 +117,20 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
         self.dtype = str(dtype)
         self._seed = seed
         # Which layers slide and which rotate, a layer each (the config's
-        # two lists, a period or the whole depth).  The frame hands a layer
-        # over by its kind, so the layers of a kind rotate alike.
+        # two lists, a period or the whole depth).
         def per_layer(layout):
             return [bool(layout[i % len(layout)])
                     for i in range(self.n_layers)]
 
         slides = per_layer(window_layout)
-        rotates = slides if rope_layout is None else per_layer(rope_layout)
-        self.layer_kinds = tuple("ring" if s else "rows" for s in slides)
-        self.rotate = {kind: rotates[self.layer_kinds.index(kind)]
-                       for kind in set(self.layer_kinds)}
-        if any(r != self.rotate[k]
-               for r, k in zip(rotates, self.layer_kinds)):
-            raise ValueError("the layers of a kind (window | global) rotate "
-                             "alike or not at all")
-        if self.n_heads % self.n_kv_heads:
-            raise ValueError(f"{n_heads} query heads over {n_kv_heads} "
-                             "key/value heads")
-        # A ring holds whole pieces (a piece never wraps): the window's keys
-        # rounded up to them.
-        self.ring_rows = -(-self.window // self.piece) * self.piece
-        if self.ring_rows != self.window:
-            self.ring_window = self.window
-        if self.max_seq_len % self.piece or self.ring_rows > self.max_seq_len:
-            raise ValueError("max_seq_len divides into prefill pieces, and "
-                             "a window's ring fits a slot")
+        self._ring_setup(slides, slides if rope_layout is None
+                         else per_layer(rope_layout))
         self._check_experts()
         # Two prompts a piece program at most (what was measured: PERF.md
         # section 6, PR 52).
         self.prefill_piece = (self.piece, 2)
         self.stream_record = record_width(
             self.n_layers * self.held_words) if record else 0
-
-    # -- what the scheduler counts (models/decoder.py) ---------------------------
-
-    def cache_rows_by_kind(self, n: int) -> tuple[int, int, int]:
-        """(ring rows, whole-context rows, past the ring) of a decode step at
-        context length ``n``: a window layer reads its live rows but the one
-        it overwrites, a global layer every position's."""
-        rings = self.layer_kinds.count("ring")
-        return (rings * min(n, self.window - 1),
-                (self.n_layers - rings) * n, int(n > self.window))
 
     # -- params --------------------------------------------------------------
 
@@ -218,82 +192,3 @@ class SmallThinkerBackend(GroupedQueryPieces, ExpertDecoder):
             lp, rms_norm(x, lp["ln2"], self.rms_eps), live, tile_m,
             routing=routing)
         return x + y, counts, (top_i,)
-
-    # -- the decode step's parts (models/decoder.py) ---------------------------
-
-    def _ring_qkv(self, lp, x, pos):
-        return self._project(lp, x["h"], pos, "ring")
-
-    # -- a piece's attention (models/grouped_query.py) ----------------------------
-
-    def _piece_ring_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
-        """A window layer's part of a piece (models/decoder.py
-        ``piece_hidden_fn``)."""
-        return self._lane_by_lane(
-            self._read_ring, self._write_ring,
-            self._project(lp, x, pos, "ring"), k_a, v_a, ki, rows, starts,
-            lens)
-
-    def _full_ring_layer(self, lp, x, pos):
-        return self._full_layer(self._project(lp, x, pos, "ring"), self.window)
-
-    def _read_ring(self, k_a, v_a, ki, row, start, q, own_k, own_v):
-        """A window layer's part of one lane's piece: q ``[piece, H, D]``
-        against the slot's ring and, causally, its own ``own_k, own_v [piece,
-        Hkv*D]`` (as the cache holds them).  -> o ``[piece, H * D]``."""
-        import jax
-        import jax.numpy as jnp
-
-        n = self.piece
-
-        def attend(pre, rolled=False):
-            before = [self._rows_before(leaf, ki, row, pre)
-                      for leaf in (k_a, v_a)]
-            if rolled:
-                # A full ring, oldest position first: row (start mod ring)
-                # holds position start - ring.
-                before = [jnp.roll(b, -(start % self.ring_rows), axis=0)
-                          for b in before]
-            return self._attend(q, own_k, own_v, *before, self.window)
-
-        full = self.ring_rows // n
-        branches = [lambda pre=i * n: attend(pre) for i in range(full)]
-        branches.append(lambda: attend(self.ring_rows, rolled=True))
-        return jax.lax.switch(jnp.minimum(start // n, full), branches)
-
-    def _write_ring(self, k_a, v_a, ki, row, start, n_valid, own_k, own_v):
-        """A lane's piece written into the slot's ring, over its oldest
-        block.  -> (K leaf, V leaf)."""
-        import jax
-        import jax.numpy as jnp
-
-        n, hd = self.piece, self.n_kv_heads * self.head_dim
-        at = start % self.ring_rows
-        # A prompt's last piece: the rows behind its valid ones hold
-        # positions a later step still reads.
-        valid = (jnp.arange(n) < n_valid)[:, None]
-        own_k, own_v = (
-            jnp.where(valid, own, jax.lax.dynamic_slice(
-                leaf, (ki, row, at, 0), (1, 1, n, hd))[0, 0])
-            for own, leaf in ((own_k, k_a), (own_v, v_a)))
-        return tuple(jax.lax.dynamic_update_slice(
-            leaf, own[None, None], (ki, row, at, 0))
-            for leaf, own in ((k_a, own_k), (v_a, own_v)))
-
-    # -- generative interface (used by GenerativeScheduler) -------------------
-
-    def init_arena(self, capacity: int):
-        """``kg, vg [global layers, R, max_seq_len, Hkv*D]`` and ``kw, vw
-        [window layers, R, window, Hkv*D]`` in the model's dtype (``R =
-        capacity + 1``: the last slot absorbs padded lanes) and ``tok [R]``,
-        each slot's latest token on the device."""
-        import jax.numpy as jnp
-
-        r, dt = capacity + 1, jnp.dtype(self.dtype)
-        hd = self.n_kv_heads * self.head_dim
-        rings = self.layer_kinds.count("ring")
-        whole = (self.n_layers - rings, r, self.max_seq_len, hd)
-        ring = (rings, r, self.ring_rows, hd)
-        return {"kg": jnp.zeros(whole, dt), "vg": jnp.zeros(whole, dt),
-                "kw": jnp.zeros(ring, dt), "vw": jnp.zeros(ring, dt),
-                "tok": jnp.zeros(r, jnp.int32)}

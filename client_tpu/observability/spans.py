@@ -138,6 +138,13 @@ GEN_COUNTERS = (
     # worker knows it when it stages the piece).  A backend that declares
     # ``piece_ends`` computes the head of exactly these programs.
     "prefill_heads",
+    # per dispatched prefill piece, for a backend that declares
+    # ``piece_pairs_by_kind`` (0 for every other): the (query, key) pairs the
+    # live lanes' pieces score in their ring (sliding-window) layers and in
+    # their whole-context layers, each summed over the layers of the kind:
+    # what a piece's attention costs, as the positions say what its products
+    # cost.
+    "prefill_pairs_window", "prefill_pairs_global",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
@@ -151,7 +158,8 @@ GEN_COUNTERS = (
  C_GAP_LANE_NS, C_GAP_LANES_BEHIND_PREFILL,
  C_GAP_LANE_BEHIND_PREFILL_NS, C_FETCHED_ROWS_WINDOW, C_FETCHED_ROWS_GLOBAL,
  C_FETCHED_LANES_PAST_WINDOW, C_FETCHED_PASSES,
- C_PREFILL_HEADS) = range(len(GEN_COUNTERS))
+ C_PREFILL_HEADS, C_PREFILL_PAIRS_WINDOW,
+ C_PREFILL_PAIRS_GLOBAL) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

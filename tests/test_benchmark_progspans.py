@@ -367,9 +367,11 @@ class TestCacheReaders:
             # (The decode kernel's share is read on the other cells that
             # run the kernel as well: a global layer's calls, PR 43, and an
             # attention layer's between state-space layers, PR 45, and a
-            # looped stack's 48 calls a wave, PR 50.)
+            # looped stack's 48 calls a wave, PR 50, and a full layer's call
+            # at a query group of 16, PR 53.)
             others = (["smallthinker_21b.mixed",
-                       "nemotron3_nano_30b.assistant", "ouro_2b6.fewshot"]
+                       "nemotron3_nano_30b.assistant", "ouro_2b6.fewshot",
+                       "command_a_plus.rag"]
                       if name == "decode_attn_roofline.itl" else [])
             assert by[name]["workloads"] == [CELL] + others
             assert by[name]["moves"] == "itl_mean_ms"
@@ -586,12 +588,13 @@ class TestLanesPerCall:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         # (Last of the accepted metrics until PR 50 put its two behind it,
-        # and PR 51 its one.)
-        last = manifest["per_layer"][-4]
+        # PR 51 its one and PR 53 its two.)
+        last = manifest["per_layer"][-6]
         stage = next(m for m in manifest["per_layer"]
                      if m["name"] == "prefill_stage_ms_mean.itl")
-        assert [m["name"] for m in manifest["per_layer"][-3:]] == [
-            "loop_dense_roofline.itl", "passes_per_wave.obs", HEADS]
+        assert [m["name"] for m in manifest["per_layer"][-5:]] == [
+            "loop_dense_roofline.itl", "passes_per_wave.obs", HEADS,
+            "piece_roofline.itl", "dense_branch_roofline.itl"]
         assert last == {"name": LANES, "unit": "lanes", "better": "higher",
                         "source": "program_counter",
                         "layer": "generative scheduler",
@@ -643,14 +646,14 @@ class TestHeadShare:
 
     def test_the_manifest_holds_it_last_for_the_cells_of_the_piece_frame(
             self):
-        """Appended behind every accepted metric, for the five cells whose
-        backend runs the decoder's piece frame (``evabyte_6b5.longdoc``
-        prefills by pieces through a program of its own, which takes no
-        ``ends``)."""
+        """Appended behind every accepted metric (PR 53's two stand behind
+        it since), for the cells whose backend runs the decoder's piece frame
+        (``evabyte_6b5.longdoc`` prefills by pieces through a program of its
+        own, which takes no ``ends``)."""
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         lanes = next(m for m in manifest["per_layer"] if m["name"] == LANES)
-        assert manifest["per_layer"][-1] == {
+        assert manifest["per_layer"][-3] == {
             "name": HEADS, "unit": "%", "better": "lower",
             "source": "program_counter", "layer": "generative scheduler",
             "moves": "itl_mean_ms",
@@ -658,4 +661,5 @@ class TestHeadShare:
                           if c != "evabyte_6b5.longdoc"]}
         from client_tpu.observability import spans
 
-        assert spans.GEN_COUNTERS[-1] == "prefill_heads"
+        assert spans.GEN_COUNTERS[-3:] == (
+            "prefill_heads", "prefill_pairs_window", "prefill_pairs_global")

@@ -1,0 +1,388 @@
+"""``cohere_moe`` (models/cohere_moe.py) where it meets the rest: the shares
+of an expert-parallel group against the uncut layer; the scheduler's two
+piece counters and a stream's record through the engine; the benchmark
+family's arithmetic, readers, controls and configuration file."""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+sys.path.insert(0, BENCH)
+sys.path.insert(0, os.path.join(BENCH, "testdata"))
+
+import family  # noqa: E402
+
+from client_tpu.engine import TpuEngine  # noqa: E402
+from client_tpu.engine.repository import ModelRepository  # noqa: E402
+from client_tpu.models.cohere_moe import CohereMoeBackend  # noqa: E402
+from test_cohere_moe import (PIECE, SEQ, WINDOW, Served, backend,  # noqa
+                             f32_params, ids_of, words_of)
+from test_smallthinker import counters, stream  # noqa: E402
+
+fam = family.load("cohere_moe")
+kimi = family.load("kimi_linear")
+
+
+# -- the share (guide section 4) ---------------------------------------------------
+
+def test_the_four_shares_add_up_to_the_uncut_references_layer():
+    """Four shares of 2 of 8 experts: what each share's block adds to x,
+    with the attention and the shared experts (which every chip computes
+    alike) counted once, add up to what the uncut reference's layer adds."""
+    kw = dict(dtype="float32")
+    whole = backend(**kw)
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(rng.standard_normal((9, 64)), jnp.float32)
+    o = jnp.asarray(rng.standard_normal((9, 8 * 16)), jnp.float32)
+    live = jnp.ones(9, bool)
+
+    def layer_of(be):
+        return jax.tree_util.tree_map(
+            lambda a: jnp.asarray(np.asarray(a, np.float32)),
+            be._init_params()["layers"][1])
+
+    def added(be):
+        return np.asarray(be._after_rows(layer_of(be), x, o, live, 16)[0] - x)
+
+    lp = layer_of(whole)
+    alike = np.asarray(whole._mm(o, lp["wo"]) + whole._shared(
+        lp, whole._norm(x, lp["ln"])))
+    shares = [added(backend(experts_held=2, first_expert=f, **kw)) - alike
+              for f in (0, 2, 4, 6)]
+    assert all(np.abs(s).max() > 1e-3 for s in shares)
+    # The uncut reference: the router, every expert, four shared experts
+    # computed apart and averaged.
+    ref = fam.prepare({"embed": np.zeros(1), "lnf": np.zeros(1), "layers": [
+        {k: np.asarray(v, np.float32) for k, v in
+         whole._init_params()["layers"][1].items()}]})["layers"][0]
+    assert len(ref["experts"]) == 8 and len(ref["shared"]) == 4
+    with jax.default_matmul_precision("highest"):
+        h = fam.layer_norm(x, ref["ln"], whole.norm_eps)
+        chosen, weights, _ = fam.route(ref, h, top_k=2, first=0)
+        want = np.asarray(o @ ref["wo"] + fam.experts(
+            ref, h, chosen, weights, first=0))
+    assert np.abs(sum(shares) + alike - want).max() < 1e-4
+    assert np.abs(added(whole) - want).max() < 1e-4
+
+
+def test_the_shared_pair_is_four_experts_averaged():
+    be = backend(dtype="float32")
+    lp = {k: jnp.asarray(np.asarray(v, np.float32))
+          for k, v in be._init_params()["layers"][0].items()}
+    h = jnp.asarray(np.random.default_rng(3).standard_normal((5, 64)),
+                    jnp.float32)
+    f = be.d_expert
+    apart = [(jax.nn.silu(h @ lp["sgu"][:, i * f:(i + 1) * f])
+              * (h @ lp["sgu"][:, (4 + i) * f:(5 + i) * f]))
+             @ lp["sd"][i * f:(i + 1) * f] for i in range(4)]
+    ref = fam.prepare({"embed": np.zeros(1), "lnf": np.zeros(1), "layers": [
+        {k: np.asarray(v, np.float32) for k, v in
+         be._init_params()["layers"][0].items()}]})["layers"][0]
+    for one, want in zip(ref["shared"], apart):
+        assert np.abs(np.asarray(fam.swiglu(h, *one))
+                      - np.asarray(want)).max() < 1e-5
+    assert np.abs(np.asarray(be._shared(lp, h))
+                  - np.asarray(sum(apart)) / 4).max() < 1e-5
+
+
+# -- through the scheduler -------------------------------------------------------
+
+# (prompt length, tokens): inside a piece, past the window inside prefill, on
+# a piece's edge, past two rings; slots are reused.
+PLAN = [(3, 6), (21, 5), (16, 4), (37, 4)]
+
+
+@pytest.fixture(scope="module")
+def served():
+    name = "cm_fused"
+    be = backend(name=name, attn_impl="fused", max_streams=2, record=True)
+    repo = ModelRepository()
+    repo.register_backend(be)
+    engine = TpuEngine(repo)
+    engine._schedulers[name].warmup()
+    before = counters(engine, name)
+    prompts = [ids_of(n, seed=10 + i).tolist()
+               for i, (n, _) in enumerate(PLAN)]
+    joins = [stream(engine, p, m, name, record=True)
+             for p, (_, m) in zip(prompts, PLAN)]
+    together = [j() for j in joins]
+    after = counters(engine, name)
+    alone = [stream(engine, p, m, name, record=True)()
+             for p, (_, m) in zip(prompts, PLAN)]
+    yield be, prompts, together, alone, before, after
+    engine.shutdown()
+
+
+def test_together_equals_alone_token_for_token(served):
+    _, _, together, alone, _, _ = served
+    assert [t for t, _ in together] == [t for t, _ in alone]
+    assert [len(t) for t, _ in together] == [m for _, m in PLAN]
+
+
+def test_the_scheduler_counts_a_pieces_pairs_by_what_the_backend_declares(
+        served):
+    be, prompts, _, _, before, after = served
+    ring = whole = valid = pieces = 0
+    for p in prompts:
+        for st in range(0, len(p), PIECE):
+            n = min(PIECE, len(p) - st)
+            a, b = be.piece_pairs_by_kind(st, n)
+            ring, whole, valid, pieces = ring + a, whole + b, valid + n, \
+                pieces + 1
+
+    def moved(name):
+        return after[name] - before[name]
+
+    assert moved("prefill_pairs_window") == ring
+    assert moved("prefill_pairs_global") == whole
+    assert moved("prefill_positions_valid") == valid
+    assert moved("prefill_pieces") == pieces
+    assert moved("prefill_heads") == len(prompts)
+    assert moved("fetched_rows_window") > 0 < moved("fetched_rows_global")
+    assert moved("fetched_lanes_past_window") > 0
+    assert moved("expert_pairs_local") > 0
+
+
+def test_a_backend_that_declares_nothing_counts_nothing():
+    from client_tpu.models.smallthinker import SmallThinkerBackend
+    assert SmallThinkerBackend.piece_pairs_by_kind is None
+    name = "st_nopairs"
+    be = SmallThinkerBackend(name=name, seed=5, max_streams=2)
+    repo = ModelRepository()
+    repo.register_backend(be)
+    engine = TpuEngine(repo)
+    try:
+        stream(engine, ids_of(21).tolist(), 3, name)()
+        c = counters(engine, name)
+        assert c["prefill_pieces"] == 3
+        assert c["prefill_pairs_window"] == c["prefill_pairs_global"] == 0
+    finally:
+        engine.shutdown()
+
+
+def test_the_reference_accepts_every_token_of_the_served_streams(served):
+    be, prompts, together, alone, _, _ = served
+    params = f32_params(be)
+
+    def rows_fn(prompt, emitted, words):
+        seq = np.asarray(prompt + emitted, np.int32)
+        with jax.default_matmul_precision("highest"):
+            logits, _, flips = fam.backend_forward(
+                params, be, seq[:-1], len(emitted),
+                follow=np.asarray(words).reshape(-1, be.n_layers,
+                                                 be.held_words))
+        return logits, flips
+
+    for i, (p, (_, m)) in enumerate(zip(prompts, PLAN)):
+        one = {"prompts": [p], "max_tokens": m,
+               "concurrent": [together[i][0]], "solo": [alone[i][0]],
+               "concurrent_record": [together[i][1]],
+               "solo_record": [alone[i][1]]}
+        verdict = kimi.judge(one, rows_fn, be.n_layers * be.held_words,
+                             margin=1.5, logit_rms_alone=0.5,
+                             logit_rms_together=0.5, logit_max=1.5, tie=0.05)
+        assert verdict["ok"], verdict
+        assert verdict["tokens_checked"] == 2 * m
+
+
+def test_the_cells_comparison_runs_whole_on_the_served_streams(served):
+    """``check`` as the harness calls it (its limits are the published
+    widths'; what it computes is held here): every stream judged, the twins
+    sent alone computed against their prompts' kept keys, the window's edge
+    weighed on the streams that reach it."""
+    be, prompts, together, alone, _, _ = served
+    probe = {"prompts": prompts, "max_tokens": None,
+             "concurrent": [t for t, _ in together],
+             "solo": [t for t, _ in alone],
+             "concurrent_record": [np.asarray(r).tolist()
+                                   for _, r in together],
+             "solo_record": [np.asarray(r).tolist() for _, r in alone]}
+    verdicts = []
+    for i, (_, m) in enumerate(PLAN):
+        one = {k: (v[i:i + 1] if isinstance(v, list) else v)
+               for k, v in probe.items()}
+        one["max_tokens"] = m
+        with jax.default_matmul_precision("highest"):
+            verdicts.append(fam.check(f32_params(be), one, be))
+    assert all(v["streams_short"] == 0 and v["tokens_checked"] > 0
+               for v in verdicts)
+    assert all(v["logit_rms_error_alone"] < 0.5 for v in verdicts)
+    # Those whose context reaches the window lean neither way by much.
+    assert all(abs(v["window_lean_fewer"]) < 2 for v in verdicts)
+
+
+# -- the controls of the comparison -------------------------------------------------
+
+@pytest.mark.parametrize("which", [
+    "sequential_block", "rms_norm", "rotate_half", "rope_on_full",
+    "global_first", "shared_summed", "softmax_router", "window_4095", "e4m3"])
+def test_a_control_is_the_served_backend_with_one_thing_wrong(which):
+    """``benchmark/testdata/cohere_moe_controls.py``: same weights, one thing
+    about the model wrong (or every product's operands rounded further); at
+    the tiny preset in float32, where nothing is left but the fault, the
+    reference, which stays the published model, reads the program far off on
+    logits or at the router's edge."""
+    import cohere_moe_controls as controls
+
+    kw = {"seed": 5, "max_seq_len": SEQ, "window": WINDOW, "piece": PIECE,
+          "dtype": "float32",
+          "layer_types": ("sliding_attention",) * 3 + ("full_attention",)}
+    be, plain = controls.CONTROLS[which](**kw), backend(dtype="float32")
+    for a, b in zip(jax.tree_util.tree_leaves(be._init_params()),
+                    jax.tree_util.tree_leaves(plain._init_params())):
+        assert (a.seed, a.shape, a.dtype) == (b.seed, b.shape, b.dtype)
+    ids = ids_of()
+    got, routes = Served(be).walk(ids, 21)
+    with jax.default_matmul_precision("highest"):
+        want, _, flips = fam.backend_forward(
+            f32_params(be), be, ids, len(ids), follow=words_of(be, routes))
+    assert np.isfinite(got).all()
+    assert np.abs(got - np.asarray(want)).max() > 0.05 or flips.max() > 0.05
+
+
+# -- the benchmark family ----------------------------------------------------------
+
+def _config():
+    from traffic import load_json
+    return load_json(os.path.join(BENCH, "configs", "command_a_plus.json"))
+
+
+def test_the_configuration_file_is_the_catalog_row_but_for_its_cut():
+    cfg = _config()
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["name"] == "command-a-plus-05-2026")
+    assert cfg["source"] == row["source_url"]
+    assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                              "vocab_size"]
+    held = {"num_hidden_layers": 4, "vocab_size": 32768}
+    for key, value in row["config"].items():
+        if key in held:
+            assert cfg["published"][key] == value and cfg[key] == held[key]
+        else:
+            assert cfg[key] == value, key
+    assert cfg["n_routed_experts"] == 16
+    assert cfg["published"]["num_experts"] == cfg["num_experts"] == 128
+    assert cfg["moe_intermediate_size"] == cfg["intermediate_size"] == 4096
+    assert cfg["sliding_window_size"] == cfg["sliding_window"] == 4096
+    assert cfg["sliding_window_layout"] == [
+        int(t == "sliding_attention") for t in cfg["layer_types"][:4]]
+    from client_tpu.models import experts
+    assert cfg["serve"]["expert_tile_rows"] == experts.TILE_M_WAVE
+    for key in ("assumed", "departures", "deployment", "memory"):
+        assert cfg[key]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == "command_a_plus")
+    assert entry["reduced"] == cfg["reduced"]
+    assert entry["source"] == cfg["source"]
+
+
+def test_the_backend_built_from_the_file_is_the_issues_arena():
+    import serve as serve_mod
+    cfg = _config()
+    be = CohereMoeBackend(name="c", **serve_mod.backend_kwargs(
+        cfg, 7, 25600))
+    assert be.layer_kinds == ("ring", "ring", "ring", "rows")
+    assert be.rotate == {"rows": False, "ring": True}
+    arena = jax.eval_shape(lambda: be.init_arena(be.max_streams))
+    assert arena["kw"].shape == arena["vw"].shape == (3, 25, 4096, 1024)
+    assert arena["kg"].shape == arena["vg"].shape == (1, 25, 25600, 1024)
+    assert all(arena[k].dtype == jnp.bfloat16 for k in "kw vw kg vg".split())
+    assert be.prefill_piece[0] == 512 and be.ring_window is None
+    assert (be.n_experts, be.experts_held, be.top_k, be.n_shared) == (
+        128, 16, 8, 4)
+    assert (be.router_score, be.expert_act, be.expert_form) == (
+        "sigmoid", "silu", "gated")
+    params = be._init_params()
+    assert params["layers"][0]["egu"].shape == (16, 4096, 8192)
+    assert params["layers"][0]["sgu"].shape == (4096, 32768)
+    total = sum(int(np.prod(w.shape))
+                for w in jax.tree_util.tree_leaves(params))
+    assert 4.72e9 < total < 4.75e9          # the issue's 9.47 GB
+    cache = sum(int(np.prod(arena[k].shape)) * 2
+                for k in "kw vw kg vg".split())
+    assert 3.87e9 < cache < 3.89e9          # the issue's 3.88 GB
+    assert be.cache_rows_by_kind(9000) == (3 * 4095, 9000, 1)
+
+
+def test_the_traffic_file_is_the_issues_cell():
+    from traffic import load_json
+    t = load_json(os.path.join(BENCH, "traffic", "rag.json"))
+    assert (t["loop"], t["clients"], t["cycle_requests"]) == ("closed", 24, 96)
+    assert (t["stagger_s"], t["preroll_s"], t["trace_seconds"]) == (24, 36, 4)
+    assert t["prompt_len"] == {"dist": "uniform", "min": 2048, "max": 24576}
+    assert t["output_len"] == {"dist": "loguniform", "min": 256, "max": 1024}
+    assert t["max_model_len"] == 25600 and t["server_args"] == ["--warmup"]
+    assert t["probe_prompt_lens"] == [5, 600, 4080, 4700, 9000]
+    assert t["probe_max_tokens"] == 32
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = manifest["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        "command_a_plus.rag", "command_a_plus", "rag", 1)
+    reports = {m["name"] for m in manifest["end_to_end"]
+               + manifest["per_layer"]
+               if "workloads" not in m or cell["name"] in m["workloads"]}
+    assert {"itl_mean_ms", "setup_s", "piece_roofline.itl",
+            "dense_branch_roofline.itl", "step_roofline.itl",
+            "window_attn_roofline.itl", "decode_attn_roofline.itl",
+            "experts_touched_share.itl", "prefill_head_share.itl"} <= reports
+    # (``expert_ffn_roofline.itl`` pairs the window's mean touched experts
+    # with the traced full-bucket calls alone: at 1.1 rows an expert it read
+    # 83.9 and 101.8% in two runs, so the cell does not report it: PERF.md
+    # section 7.)
+    assert not {"arena_live_share.itl", "kv_live_share.itl",
+                "expert_mlp_roofline.itl", "expert_ffn_roofline.itl",
+                "prefill_live_lane_share.itl"} & reports
+
+
+def test_the_new_readers_and_the_family_arithmetic():
+    import check_cohere_moe
+
+    cfg = _config()
+    assert check_cohere_moe.readers(cfg) == 0
+    assert check_cohere_moe.arithmetic(cfg) == 0
+
+
+def test_step_arithmetic_by_hand():
+    cfg = _config()
+    # 24 lanes x 4095 rows x 4 KiB, 128 heads x 128 x 4 operations a row.
+    flops, nbytes = fam.window_attention(cfg, 24, 4095)
+    assert flops == 4 * 24 * 4095 * 128 * 128
+    assert nbytes == 2 * 24 * 4096 * 1024 * 2
+    _, up = fam.expert_ffn(cfg, 192, 12.6, "up")
+    assert up == 12.6 * 2 * 4096 * 4096 * 2 + 192 * (4096 * 2 + 2 * 4096 * 4)
+    flops, nbytes = fam.dense_products(cfg, 24)
+    weights = 4 * (2 * 4096 * 16384 + 2 * 4096 * 1024 + 12 * 4096 * 4096) \
+        + 4096 * 32768
+    assert (flops, nbytes) == (2 * 24 * weights, 2 * weights)
+    assert fam.wave_rows(cfg) == 432
+    # A piece of 512 positions from 13312: 8 / 128 x 16 = 1 held expert a
+    # position.
+    flops, nbytes = fam.piece_step(cfg, 512, 3 * 512 * 4096,
+                                   sum(range(13313, 13825)), 1, 0)
+    per = 4 * (2 * 4096 * 16384 + 2 * 4096 * 1024 + 4096 * 128
+               + 12 * 4096 * 4096 + 3 * 4096 * 4096)
+    assert flops == 2 * 512 * per + 4 * (
+        3 * 512 * 4096 + sum(range(13313, 13825))) * 128 * 128
+    assert 9.1e9 < nbytes < 9.3e9
+
+
+def test_a_launch_of_another_model_imports_none_of_it():
+    code = ("import sys, client_tpu.models as zoo; zoo._import_all(); "
+            "assert 'cohere_moe' in zoo.model_names(); "
+            "hit = [m for m in sys.modules if 'cohere_moe' in m "
+            "or 'models.experts' in m or 'grouped_query' in m]; "
+            "assert not hit, hit")
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=ROOT,
+                   env={**os.environ, "JAX_PLATFORMS": "cpu"}, timeout=300)

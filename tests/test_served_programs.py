@@ -25,6 +25,10 @@ import jax.numpy as jnp
 import pytest
 
 RECORDED = {
+    # (a parallel block over a ring of two pieces and whole-context rows, a
+    # group of four query heads a key head, half the experts held: PR 53)
+    ("cohere_moe", "decode"): ("781b759572c6fa34", "a9b441acdfa65310"),  # 53, 53
+    ("cohere_moe", "prefill"): ("03df8db14a8ae370", "e1195263e49cba3f"),  # 53, 53
     ("evabyte", "decode"): ("4b633014727aa219", "54792ebb84a37cd7"),  # 44, 44
     ("evabyte", "prefill"): ("f73e0dc2333af8de", "e59f91f604bb6804"),  # 42, 42
     ("gpt", "decode"): ("c7d0eebb4f86770a", "2445b260a28de378"),      # 44, 44
@@ -93,6 +97,17 @@ def _backend(family):
                                    max_seq_len=64, window=32, piece=16,
                                    attention_impl="flash", attn_impl="fused",
                                    record=True)
+    if family == "cohere_moe":
+        from client_tpu.models.cohere_moe import CohereMoeBackend
+
+        # Heads of whole 128-lane tiles, as the flash pieces need them; a
+        # group of four query heads a key head, a ring of two pieces, a share
+        # of half the experts.
+        return CohereMoeBackend(seed=3, n_heads=8, n_kv_heads=2,
+                                head_dim=128, experts_held=4,
+                                max_seq_len=64, window=32, piece=16,
+                                attention_impl="flash", attn_impl="fused",
+                                record=True)
     if family == "nemotron":
         from client_tpu.models.nemotron_h import NemotronHBackend
 
@@ -123,7 +138,8 @@ def _program(family, which):
     ``which``: ``decode``, ``prefill`` (a piece backend's declared lanes) or
     ``prefill_<lanes>``."""
     be = _backend(family)
-    if family in ("pangu", "kimi", "smallthinker", "nemotron", "ouro"):
+    if family in ("pangu", "kimi", "smallthinker", "nemotron", "ouro",
+                  "cohere_moe"):
         # (made when asked for)
         params = jax.tree_util.tree_map(
             lambda w: jax.ShapeDtypeStruct(w.shape, jnp.dtype(w.dtype)),

@@ -1014,6 +1014,68 @@ def test_four_passes_over_one_set_of_weights_compile_at_published_widths(
     assert memory.temp_size_in_bytes < 0.3e9, memory
 
 
+# -- a parallel block over window and full layers, 128 heads over 8 (PR 53) -----
+
+COHERE = dict(n_layers=4, d_model=4096, n_heads=128, n_kv_heads=8,
+              head_dim=128, d_expert=4096, n_experts=128, experts_held=16,
+              top_k=8, n_shared=4, window=4096, vocab=32768,
+              max_seq_len=25600, piece=512, max_streams=24,
+              attention_impl="flash", record=True)
+
+
+@pytest.mark.parametrize("which,lanes", [("decode", 1), ("prefill", 1),
+                                         ("prefill", 2)])
+def test_parallel_block_decoder_compiles_at_published_widths(
+        one_chip, monkeypatch, which, lanes):
+    """At the cell's widths (4096; 128 query heads over 8 key heads of 128;
+    16 held of 128 experts of 4096, 8 a token, 4 shared; 32768 ids; 24 + 1
+    slots of 3 x 4096 ring rows and 25600 rows): a wave is three ring calls
+    and one whole-context call with grouped-query rows of 1024 lanes and
+    eight grouped matmuls; a piece holds, a lane, a flash call for every
+    count of rows before it (9 a window layer, 50 the full one).  The tied
+    head is the embedding where it lies: no program writes a weight or a
+    cache leaf out again, and the donated arena's four leaves are updated in
+    place."""
+    from client_tpu.models.cohere_moe import CohereMoeBackend
+
+    backend = CohereMoeBackend(name="c", **COHERE)
+    text, arena, memory, seconds = _piece_backend_program(
+        one_chip, monkeypatch, backend, which, lanes)
+    print(f"command_a_plus {which} x{lanes}: compiled in {seconds:.1f} s; "
+          f"{memory}")
+    calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
+    assert calls.count("grouped_matmul") == 8
+    if which == "decode":
+        assert calls.count("window_wave_attention") == 3
+        assert calls.count("decode_wave_attention") == 1
+        # 24 tokens, a record row a lane and the wave's three counts.
+        assert f"s32[{24 + 24 * backend.stream_record + 3}]" in text
+        # The groups of ``expert_ffn_roofline.itl``: 432 rows of sorted
+        # layout.
+        for width in (8192, 4096):
+            assert re.search(rf"f32\[432,{width}\][^=]*? custom-call\(", text)
+    else:
+        assert calls.count("flash_attention") == lanes * (3 * 9 + 50)
+        assert f"s32[{lanes * (1 + 512 * backend.stream_record)}]" in text
+    weights = (r"4096,16384|16384,4096|4096,1024|16,4096,8192|16,4096,4096"
+               r"|4096,32768|32768,4096")
+    leaves = r"[13],25,(?:4096|25600),1024"
+    moved = _written_out_again(text, weights + "|" + leaves)
+    if which == "prefill":
+        # (Rows before a slot's piece, sliced out of a leaf for a flash call,
+        # can have W_k's shape: 4096 rows of 1024 lanes.)
+        moved = [m for m in moved if m[1:] != ("fusion", "4096,1024")]
+    assert not moved, moved
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    cache = sum(math.prod(arena[k].shape) * 2 for k in ("kg", "vg", "kw",
+                                                         "vw"))
+    assert memory.alias_size_in_bytes >= cache
+    assert 13.3e9 < memory.argument_size_in_bytes < 13.4e9
+    assert memory.temp_size_in_bytes < (1.0e9 if lanes == 2 else 0.6e9), \
+        memory
+
+
 # The three served configurations' cache leaves: slots, rows a slot, lanes a
 # row, dtype; a full wave's lanes, query heads, key heads, the head's width.
 _SERVED_LEAVES = {
@@ -1025,6 +1087,10 @@ _SERVED_LEAVES = {
                                 48, 28, 4, 128),
     "smallthinker_21b.ring": ("window", (6, 49, 4096, 512), jnp.bfloat16, 48,
                               28, 4, 128),
+    "command_a_plus.global": ("decode", (1, 25, 25600, 1024), jnp.bfloat16,
+                              24, 128, 8, 128),
+    "command_a_plus.ring": ("window", (3, 25, 4096, 1024), jnp.bfloat16, 24,
+                            128, 8, 128),
 }
 
 
