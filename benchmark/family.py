@@ -9,8 +9,13 @@ on the architecture lives in ``benchmark/models/<name>.py``, which exports
   judges (after the window, untimed) and returns what the server answered;
 - ``check(params, probe, backend)``: the plain float32 forward pass and
   the comparison that decides ``correct``; returns a verdict with ``ok``;
-- ``step_mix(ctx)``: ``[(count, (flops, bytes)), ...]`` of the jitted steps
-  the window ran, from shapes alone (the roofline's numerator).
+- ``step_mix(ctx)``: ``[(count, (flops, bytes)), ...]`` of the decode waves
+  the window ran, from shapes and the program's counters alone (no trace, no
+  program's name), and ``prefill_work(ctx)``: ``(flops, bytes)`` of all its
+  prefill programs: the numerator of the whole step's share of the roofline
+  (``reduce.step_mfu_roofline``).  A family that prefills by pieces gives
+  ``piece_step(cfg, positions, pairs_window, pairs_global, programs, heads)``
+  and calls ``reduce.pieces_work``.
 
 A configuration of another architecture adds a module there and edits
 nothing.  The plain operations below are shared by the references.

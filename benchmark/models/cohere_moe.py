@@ -669,7 +669,13 @@ def _traced_waves(ctx):
     ordinal k >= 1 of a stream with a prompt of P came from a wave at context
     ``P + k - 1``; the traced seconds are the harness's (``run.py``
     ``trace_window``: ``trace_seconds`` from ``t1 - trace_end_margin_s -
-    trace_seconds``, later by the start call's own time)."""
+    trace_seconds``, later by the start call's own time).
+
+    **None as well where the quotient exceeds the wave's capacity**
+    (``serve.kwargs.max_streams``): a wave holds no more lanes than slots, so
+    more tokens a ``jit_decode`` than slots means waves ran that the trace
+    shows under another name, and a kernel's bytes at such lanes would
+    flatter its share.  The window's counters are used then."""
     import reduce
 
     tr, ev = ctx.get("trace") or {}, None
@@ -685,17 +691,21 @@ def _traced_waves(ctx):
     hit = (t >= lo) & (t < lo + span) & (ordinal > 0)
     if not hit.any():
         return None
+    lanes = float(hit.sum()) / step["count"]
+    if lanes > int(ctx["cfg"]["serve"]["kwargs"]["max_streams"]):
+        return None
     n = ctx["req"]["prompt_len"][slot[hit]] + ordinal[hit] - 1
     ring = np.minimum(n, _dims(ctx["cfg"])["window"] - 1)
-    return (float(hit.sum()) / step["count"], float(ring.mean()),
-            float(n.mean()))
+    return lanes, float(ring.mean()), float(n.mean())
 
 
-def wave_means(ctx):
+def wave_means(ctx, traced_seconds: bool = True):
     """Means over the decode waves, from the program's counters: (live lanes
     a wave, context positions a live lane, pairs a layer, experts touched a
     layer, waves), or None.  In a traced run the lanes and the context are
-    the traced seconds' (``_traced_waves``) and the pairs follow the lanes."""
+    the traced seconds' (``_traced_waves``) and the pairs follow the lanes:
+    what a kernel's share of **the traced calls'** time wants.  With
+    ``traced_seconds`` false, the window's counters alone."""
     c = _counters(ctx)
     if c is None or "expert_pairs_local" not in c:
         return None
@@ -703,7 +713,7 @@ def wave_means(ctx):
     layers = _dims(ctx["cfg"])["layers"]
     pairs = c["expert_pairs_local"] / waves / layers
     touched = c["experts_touched"] / waves / layers
-    traced = _traced_waves(ctx)
+    traced = _traced_waves(ctx) if traced_seconds else None
     if traced is not None:
         return (traced[0], traced[2], pairs * traced[0] * waves / lanes,
                 touched, waves)
@@ -711,15 +721,15 @@ def wave_means(ctx):
             touched, waves)
 
 
-def rows_by_kind(ctx):
+def rows_by_kind(ctx, traced_seconds: bool = True):
     """Mean rows a live lane read in one window layer and in one full layer
     of the decode waves (counters ``fetched_rows_window``,
     ``fetched_rows_global``; in a traced run the traced seconds' waves,
-    ``_traced_waves``), or None."""
+    ``_traced_waves``, unless ``traced_seconds`` is false), or None."""
     c = _counters(ctx)
     if c is None or "fetched_rows_window" not in c:
         return None
-    traced = _traced_waves(ctx)
+    traced = _traced_waves(ctx) if traced_seconds else None
     if traced is not None:
         return traced[1], traced[2]
     m = _dims(ctx["cfg"])
@@ -740,12 +750,26 @@ def rows_per_wave(ctx):
 
 def step_mix(ctx):
     """Decode cells: the window's waves as one mean step (live lanes, not the
-    bucket; touched experts by the counter)."""
-    m, rows = wave_means(ctx), rows_by_kind(ctx)
+    bucket; touched experts by the counter), by the window's counters in a
+    traced run too: the whole step's share is taken over the counters'
+    seconds, not over the traced calls."""
+    m, rows = wave_means(ctx, False), rows_by_kind(ctx, False)
     if m is None or rows is None:
         return None
     return [(float(m[4]), decode_step(ctx["cfg"], m[0], rows[0], rows[1],
                                       m[2], m[3]))]
+
+
+def prefill_work(ctx):
+    """The window's piece programs by its counters, (flops, bytes) of all of
+    them (``piece_step`` through ``reduce.pieces_work``), or None.  The pairs
+    are the program's own counters; the parent of the PR that added them has
+    them from the harness's table of prompts."""
+    import reduce
+
+    m = _dims(ctx["cfg"])
+    return reduce.pieces_work(ctx, piece_step, m["n_window"], m["n_global"],
+                              m["window"])
 
 
 def wave_rows(cfg: dict) -> int:

@@ -243,6 +243,51 @@ def piece_step(cfg: dict, lanes: int, valid: float, n_sum: float,
     return float(flops), float(nbytes)
 
 
+def pieces_useful(cfg: dict, positions: float, pairs: float, pieces: float,
+                  programs: float, weight_bytes: int = 2):
+    """``programs`` piece programs by ``piece_step``'s terms at the prompts'
+    own sizes: ``positions`` valid bytes in ``pieces`` lanes' pieces scoring
+    ``pairs`` (query, key or summary) pairs a layer; a piece's padded
+    positions are not counted.  Every weight read once a program, the head
+    on one position a lane's piece, the embedding rows gathered; cache rows
+    are left out of the bytes.  (flops, bytes)."""
+    d, f, n_layers, v, _, _ = _dims(cfg)
+    flops = (n_layers * (2 * positions * (4 * d * d + 3 * d * f)
+                         + 4 * pairs * d) + 2 * pieces * d * v)
+    nbytes = (programs * _weights(cfg) * weight_bytes
+              + positions * (d * weight_bytes + 4))
+    return float(flops), float(nbytes)
+
+
+def prefill_work(ctx):
+    """The window's piece programs, (flops, bytes) of all of them
+    (``pieces_useful``), or None: programs, lanes' pieces and valid positions
+    by the program's counters (the count of gen.prefill_dispatch,
+    ``prefill_pieces``, ``prefill_positions_valid``); the pairs are the
+    counted positions times the pairs a position of the harness's table of
+    prompts scores (a prompt's piece j: the triangle over its own valid
+    positions and a rectangle over the ``j x window_size / chunk_size``
+    summaries before it), and left out where the context has no table."""
+    import reduce
+
+    n, prompts = reduce.prefill_counts(ctx), reduce.window_prompts(ctx)
+    if n is None or not n["programs"]:
+        return None
+    _, _, _, _, w, c = _dims(ctx["cfg"])
+    a_position = 0.0
+    if prompts is not None:
+        total = 0.0
+        for p in np.asarray(prompts, np.float64):
+            j = np.arange(int(-(-p // w)))
+            valid = np.minimum(w, p - j * w)
+            total += float((valid * (valid + 1) / 2
+                            + valid * j * (w // c)).sum())
+        a_position = total / float(np.sum(prompts))
+    return pieces_useful(ctx["cfg"], n["positions"],
+                         n["positions"] * a_position, n["pieces"],
+                         n["programs"])
+
+
 def piece_attention(cfg: dict, lanes: int, valid: float, n_sum: float):
     """The attention of one layer of one piece alone (the flash kernel):
     the causal triangle over the piece and the rectangle over the

@@ -33,7 +33,6 @@ import threading
 import numpy as np
 
 import family
-import roofline
 
 MARGIN = 0.04
 
@@ -178,23 +177,6 @@ def _dims(cfg: dict):
             cfg["vocab_size"])
 
 
-def prefill_step(cfg: dict, lanes: int, bucket: int, weight_bytes: int = 4):
-    """One batched prefill: ``lanes`` prompts padded to ``bucket`` tokens;
-    the head runs on each lane's last position only.  (flops, bytes)."""
-    d, f, n_layers, v = _dims(cfg)
-    t = lanes * bucket
-    per_layer = (2 * t * (4 * d * d + 2 * d * f)
-                 + 4 * lanes * (bucket * (bucket + 1) // 2) * d)
-    flops = n_layers * per_layer + 2 * lanes * d * v
-    w = n_layers * (4 * d * d + 2 * d * f) + d * v
-    nbytes = (w * weight_bytes
-              + t * d * weight_bytes           # gathered embedding rows
-              + bucket * d * weight_bytes      # positions
-              + n_layers * 2 * t * d * 4       # K and V rows written (f32)
-              + t * 4)
-    return float(flops), float(nbytes)
-
-
 def decode_step(cfg: dict, lanes: int, context: float,
                 weight_bytes: int = 4):
     """One decode wave: ``lanes`` streams advance one token, each reading
@@ -210,26 +192,53 @@ def decode_step(cfg: dict, lanes: int, context: float,
     return float(flops), float(nbytes)
 
 
-def step_mix(ctx):
-    """Prefill cells (``step_module`` jit_prefill): one step per prompt
-    bucket the window's prompts fell into, at the configured lanes.  Decode
-    cells: one step per wave bucket the window ran, at the mean context."""
+def prefill_step(cfg: dict, positions: float, pairs: float, lanes: float,
+                 programs: float, weight_bytes: int = 4):
+    """``programs`` one-shot prefills at the prompts' own sizes:
+    ``positions`` prompt tokens in ``lanes`` live lanes scoring ``pairs``
+    causal (query, key) pairs a layer; the bucket's and the padded lanes'
+    positions are not counted.  Every weight read once a program, the head on
+    each live lane's last position only.  (flops, bytes)."""
+    d, f, n_layers, v = _dims(cfg)
+    flops = (n_layers * (2 * positions * (4 * d * d + 2 * d * f)
+                         + 4 * pairs * d) + 2 * lanes * d * v)
+    w = n_layers * (4 * d * d + 2 * d * f) + d * v
+    nbytes = (programs * w * weight_bytes
+              + positions * d * weight_bytes        # gathered embedding rows
+              + n_layers * 2 * positions * d * 4    # K and V rows written
+              + positions * 4)
+    return float(flops), float(nbytes)
+
+
+def prefill_work(ctx):
+    """The window's one-shot prefills, (flops, bytes) of all of them: the
+    programs and their live lanes by the program's counters (the count of
+    gen.prefill_dispatch, ``prefill_lanes_live``), a live lane's positions
+    and causal pairs by the harness's table of prompt lengths (the program
+    counts no position of a one-shot prefill).  None without the table."""
     import reduce
 
-    cfg, traffic = ctx["cfg"], ctx["traffic"]
-    if traffic["step_module"] == "jit_prefill":
-        if not reduce.prefill_lane_fill(ctx):
-            return None
-        lanes = int(cfg["serve"]["prefill_lanes"])
-        cap = int(traffic["max_model_len"])
-        r = ctx["req"]
-        lens = r["prompt_len"][r["in_window"] & r["ok"]]
-        buckets = np.asarray([roofline.next_bucket(int(n), cap)
-                              for n in lens])
-        return [(float((buckets == b).sum()),
-                 prefill_step(cfg, lanes, int(b)))
-                for b in np.unique(buckets)]
-    w, c = reduce.waves_delta(ctx), reduce.mean_context(ctx)
-    if not w or c is None:
+    n, prompts = reduce.prefill_counts(ctx), reduce.window_prompts(ctx)
+    if n is None or prompts is None or not n["programs"]:
         return None
-    return [(float(n), decode_step(cfg, b, c)) for b, (n, _) in w.items()]
+    return prefill_step(
+        ctx["cfg"], n["lanes"] * float(prompts.mean()),
+        n["lanes"] * float(reduce.causal_pairs(prompts).mean()), n["lanes"],
+        n["programs"])
+
+
+def step_mix(ctx):
+    """The window's decode waves as one mean step, at the mean **live** lanes
+    a wave held and the mean context a live lane read (the program's counters
+    ``fetched_lanes_live``, ``fetched_positions_valid`` over
+    ``fetched_waves``); a bucket's padded lanes read the dummy row and are
+    not counted."""
+    import progspans
+
+    w = progspans.window(ctx)
+    c = w["counters"] if w else {}
+    waves, lanes = c.get("fetched_waves"), c.get("fetched_lanes_live")
+    if not waves or not lanes:
+        return None
+    return [(float(waves), decode_step(
+        ctx["cfg"], lanes / waves, c["fetched_positions_valid"] / lanes))]

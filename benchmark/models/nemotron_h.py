@@ -397,6 +397,51 @@ def decode_step(cfg: dict, lanes: float, context: float, pairs: float,
     return float(flops), float(nbytes)
 
 
+def piece_step(cfg: dict, positions: float, pairs_window: float,
+               pairs_global: float, programs: float, heads: float = 0.0):
+    """``programs`` piece programs that consumed ``positions`` valid prompt
+    positions and scored ``pairs_window + pairs_global`` (query, key) pairs
+    (summed over the attention layers), ``heads`` of them with a head
+    (``prefill_heads``): ``cohere_moe``'s rules.  Useful work only: two
+    operations a weight and valid position for the M layers' projections
+    (**the chunked scan over the state is left out**: a floor), the
+    attention layers' projections, the router, the shared expert and the
+    ``6 / 128 x 64`` held experts (two matrices each) a position chooses;
+    four a pair, head and lane of 128 for the attention; the head's product
+    for one row a program that ran it. Every held weight read once a
+    program, one lane or two (the touched share taken as 1), the head's
+    where it ran; states and cache rows are left out of the bytes.  (flops,
+    bytes)."""
+    m = _dims(cfg)
+    pairs = pairs_window + pairs_global     # no window layers here
+    expert = 2 * m["d"] * cfg["moe_intermediate_size"]
+    chosen_here = (cfg["num_experts_per_tok"]
+                   / int(cfg["serve"]["kwargs"]["n_experts"]) * m["held"])
+    per_position = (m["n_m"] * m["mamba"] + m["n_attn"] * m["attn"]
+                    + m["n_e"] * (m["shared"] + m["router"]
+                                  + chosen_here * expert))
+    flops = (2 * positions * per_position
+             + 4 * pairs * m["heads"] * m["head_dim"]
+             + 2 * heads * m["d"] * m["vocab"])
+    nbytes = (programs * (
+        (m["n_m"] * m["mamba"] + m["n_attn"] * m["attn"]) * 2
+        + m["n_e"] * ((m["shared"] + m["held"] * expert) * 2
+                      + m["router"] * 4))
+        + heads * m["d"] * m["vocab"] * 2)
+    return float(flops), float(nbytes)
+
+
+def prefill_work(ctx):
+    """The window's piece programs by its counters, (flops, bytes) of all of
+    them (``piece_step`` through ``reduce.pieces_work``), or None.  The program
+    counts no attention pairs for this backend: the harness's table of
+    prompts gives them, the triangle in an attention layer."""
+    import reduce
+
+    m = _dims(ctx["cfg"])
+    return reduce.pieces_work(ctx, piece_step, n_global=m["n_attn"])
+
+
 def _counters(ctx):
     import progspans
 

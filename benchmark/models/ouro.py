@@ -283,6 +283,39 @@ def decode_step(cfg: dict, lanes: float, context: float, passes: float):
             float(d_b + calls * a_b + lanes * m["d"] * 2))
 
 
+def piece_step(cfg: dict, positions: float, pairs_window: float,
+               pairs_global: float, programs: float, heads: float = 0.0):
+    """``programs`` piece programs that consumed ``positions`` valid prompt
+    positions and scored ``pairs_window + pairs_global`` (query, key) pairs
+    (summed over the layers of every pass), ``heads`` of them with a head
+    (``prefill_heads``): ``cohere_moe``'s rules.  Useful work only: two
+    operations a weight and valid position for every layer's seven matrices
+    **a pass**; four a pair, head and lane of 128 for the attention; the
+    head's product for one row a program that ran it.  Every layer's weights
+    read once a pass and program (``dense_products``' count of a wave), one
+    lane or two, the head's where it ran; cache rows are left out of the
+    bytes.  (flops, bytes)."""
+    m = _dims(cfg)
+    pairs = pairs_window + pairs_global     # no window layers here
+    weights = m["passes"] * m["layers"] * m["layer"]
+    flops = (2 * positions * weights + 4 * pairs * m["heads"] * m["head_dim"]
+             + 2 * heads * m["d"] * m["vocab"])
+    nbytes = programs * weights * 2 + heads * m["d"] * m["vocab"] * 2
+    return float(flops), float(nbytes)
+
+
+def prefill_work(ctx):
+    """The window's piece programs by its counters, (flops, bytes) of all of
+    them (``piece_step`` through ``reduce.pieces_work``), or None.  The program
+    counts no attention pairs for this backend: the harness's table of
+    prompts gives them, the triangle in every layer of every pass."""
+    import reduce
+
+    m = _dims(ctx["cfg"])
+    return reduce.pieces_work(ctx, piece_step,
+                              n_global=m["passes"] * m["layers"])
+
+
 def _counters(ctx):
     import progspans
 

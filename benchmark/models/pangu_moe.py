@@ -338,6 +338,52 @@ def decode_step(cfg: dict, lanes: float, context: float, pairs: float,
     return float(flops), float(nbytes)
 
 
+def piece_step(cfg: dict, positions: float, pairs_window: float,
+               pairs_global: float, programs: float, heads: float = 0.0):
+    """``programs`` piece programs that consumed ``positions`` valid prompt
+    positions and scored ``pairs_window + pairs_global`` (query, key) pairs
+    (summed over the layers), ``heads`` of them with a head
+    (``prefill_heads``): ``cohere_moe``'s rules.  Useful work only: two
+    operations a weight and valid position for the latent projections, the
+    dense layer, the router, the shared expert and the ``8 / 256 x 16``
+    held experts a position chooses; a pair costs a head its score over
+    ``qk_nope + qk_rope`` features and its value over ``v_head_dim`` (the
+    plain form a piece runs, the cheaper of the two for many queries); the
+    head's product for one row a program that ran it.  Every held weight
+    read once a program (the touched share taken as 1), the head's where it
+    ran; cache rows are left out of the bytes.  (flops, bytes)."""
+    m = _dims(cfg)
+    pairs = pairs_window + pairs_global     # no window layers here
+    layers = m["n_dense"] + m["n_moe"]
+    chosen_here = (cfg["num_experts_per_tok"]
+                   / int(cfg["serve"]["kwargs"]["n_experts"]) * m["held"])
+    per_position = (layers * m["attn"] + m["n_dense"] * m["dense"]
+                    + m["n_moe"] * (m["shared"] + m["router"]
+                                    + chosen_here * m["expert"]))
+    a_pair = m["heads"] * (cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+                           + cfg["v_head_dim"])
+    flops = (2 * positions * per_position + 2 * pairs * a_pair
+             + 2 * heads * m["d"] * m["vocab"])
+    nbytes = (programs * (
+        (layers * m["attn"] + m["n_dense"] * m["dense"]) * 2
+        + m["n_moe"] * ((m["shared"] + m["held"] * m["expert"]) * 2
+                        + m["router"] * 4))
+        + heads * m["d"] * m["vocab"] * 2)
+    return float(flops), float(nbytes)
+
+
+def prefill_work(ctx):
+    """The window's piece programs by its counters, (flops, bytes) of all of
+    them (``piece_step`` through ``reduce.pieces_work``), or None.  The program
+    counts no attention pairs for this backend: the harness's table of
+    prompts gives them, the triangle in every layer."""
+    import reduce
+
+    m = _dims(ctx["cfg"])
+    return reduce.pieces_work(ctx, piece_step,
+                              n_global=m["n_dense"] + m["n_moe"])
+
+
 def wave_means(ctx):
     """Means over the window's decode waves, from the program's counters:
     (live lanes a wave, context rows a live lane, pairs held here an expert

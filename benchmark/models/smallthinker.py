@@ -522,6 +522,44 @@ def decode_step(cfg: dict, lanes: float, rows_window: float,
     return float(flops), float(nbytes)
 
 
+def piece_step(cfg: dict, positions: float, pairs_window: float,
+               pairs_global: float, programs: float, heads: float = 0.0):
+    """``programs`` piece programs that consumed ``positions`` valid prompt
+    positions and scored ``pairs_window`` and ``pairs_global`` (query, key)
+    pairs (each summed over the layers of its kind), ``heads`` of them with a
+    head (``prefill_heads``): ``cohere_moe``'s rules.  Useful work only: two
+    operations a weight and valid position for the projections, the router
+    and the six experts a position chooses; four a pair, head and lane of 128
+    for the attention; the head's product for one row a program that ran it.
+    Every weight read once a program, one lane or two (all 64 experts are
+    held and a piece of some tens of positions touches them all), the head's
+    where it ran; cache rows are left out of the bytes.  (flops, bytes)."""
+    m = _dims(cfg)
+    chosen = cfg["moe_num_active_primary_experts"]
+    per_position = m["layers"] * (m["attn"] + m["router"]
+                                  + chosen * m["expert"])
+    flops = (2 * positions * per_position
+             + 4 * (pairs_window + pairs_global) * m["heads"] * m["head_dim"]
+             + 2 * heads * m["d"] * m["vocab"])
+    nbytes = (programs * m["layers"] * (
+        (m["attn"] + m["held"] * m["expert"]) * 2 + m["router"] * 4)
+        + heads * m["d"] * m["vocab"] * 2)
+    return float(flops), float(nbytes)
+
+
+def prefill_work(ctx):
+    """The window's piece programs by its counters, (flops, bytes) of all of
+    them (``piece_step`` through ``reduce.pieces_work``), or None.  The program
+    counts no attention pairs for this backend: the harness's table of
+    prompts gives them, a band of ``sliding_window_size`` keys in a window
+    layer, the triangle in a global one."""
+    import reduce
+
+    m = _dims(ctx["cfg"])
+    return reduce.pieces_work(ctx, piece_step, m["n_window"], m["n_global"],
+                              m["window"])
+
+
 def _counters(ctx):
     import progspans
 

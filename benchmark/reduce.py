@@ -26,6 +26,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 import family  # noqa: E402
+import progspans  # noqa: E402
 import roofline  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
 
@@ -286,26 +287,128 @@ def step_ms(ctx):
     return m["mean_ms"] if m else None
 
 
-def step_min_seconds(ctx):
-    """Least seconds the chip could take for the cell's mean step, from the
-    mix of shapes the window ran (the model family's ``step_mix``), and the
-    bound that holds."""
-    peaks = roofline.peaks_for(ctx["device"]["kind"])
-    mix = family.load(ctx["cfg"]["family"]).step_mix(ctx)
-    if not mix:
+def counters_seconds(ctx):
+    """Seconds the program's counters were differenced over: the harness's
+    clock at the two snapshots (``snap["t"]``, read before each snapshot's
+    requests).  The second snapshot comes when the load generator has ended,
+    0.1-1.1 s after ``t1``, and the program works on meanwhile, so the
+    counters hold a little more than the window's 50 s of work: over ``t1 -
+    t0`` a rate would read up to 2% high.  ``t1 - t0`` where a snapshot has
+    no clock (a hand-made context)."""
+    a, b = ctx.get("snap_before") or {}, ctx.get("snap_after") or {}
+    if "t" in a and "t" in b:
+        return float(b["t"] - a["t"])
+    if "t0" in ctx and "t1" in ctx:
+        return float(ctx["t1"] - ctx["t0"])
+    return None
+
+
+def prefill_counts(ctx):
+    """What the window's prefill programs did, by the program's counters:
+    ``programs`` (the count of the span gen.prefill_dispatch), ``positions``
+    (``prefill_positions_valid``: a piece's positions that held a prompt
+    token), ``heads`` (``prefill_heads``: the programs whose head ran),
+    ``pairs_window`` / ``pairs_global`` (the (query, key) pairs scored, where
+    the backend declares them), ``pieces`` (``prefill_pieces``: a lane's
+    piece each), ``lanes`` (``prefill_lanes_live``: prompts in one-shot
+    programs).  None where the program has no such spans; a window
+    that held no prefill program gives ``programs`` 0."""
+    w = progspans.window(ctx)
+    if w is None or "gen.prefill_dispatch" not in w["spans"]:
         return None
-    total = sum(n for n, _ in mix)
-    secs = [(n, roofline.min_seconds(f, by, peaks)) for n, (f, by) in mix]
-    t = sum(n * s for n, (s, _) in secs) / total
-    bound = max(secs, key=lambda x: x[0])[1][1]
-    return t, bound
+    c = w["counters"]
+    return {"programs": w["spans"]["gen.prefill_dispatch"]["count"],
+            "positions": c.get("prefill_positions_valid", 0),
+            "heads": c.get("prefill_heads", 0),
+            "pairs_window": c.get("prefill_pairs_window", 0),
+            "pairs_global": c.get("prefill_pairs_global", 0),
+            "pieces": c.get("prefill_pieces", 0),
+            "lanes": c.get("prefill_lanes_live", 0)}
 
 
-def step_roofline(ctx):
-    ms, least = step_ms(ctx), None
-    if ms:
-        least = step_min_seconds(ctx)
-    return 100.0 * least[0] * 1e3 / ms if least else None
+def window_prompts(ctx):
+    """The harness's own table: the lengths of the prompts whose first token
+    arrived inside the window (their prefill ended in it), or None."""
+    r = ctx.get("req")
+    if r is None or "t0" not in ctx:
+        return None
+    hit = (r["first"] >= ctx["t0"]) & (r["first"] < ctx["t1"])
+    return r["prompt_len"][hit] if hit.any() else None
+
+
+def causal_pairs(prompts, band: int | None = None):
+    """(query, key) pairs one layer scores over a whole prompt of P tokens,
+    for each P of ``prompts``: the lower triangle, ``P (P + 1) / 2``; with
+    ``band`` (a query sees its own and the ``band - 1`` keys before it) the
+    triangle up to the band and ``band`` a query after."""
+    p = np.asarray(prompts, np.float64)
+    if band is None:
+        return p * (p + 1) / 2
+    short = np.minimum(p, band)
+    return short * (short + 1) / 2 + (p - short) * band
+
+
+def pairs_a_position(ctx, band: int | None = None):
+    """Mean pairs a prompt position scores in one layer, over the prompts of
+    ``window_prompts``; a family multiplies it by the positions the program
+    counted, so the table gives the shape and the counter the amount.  None
+    where the context has no table."""
+    prompts = window_prompts(ctx)
+    if prompts is None:
+        return None
+    return float(causal_pairs(prompts, band).sum() / prompts.sum())
+
+
+def pieces_work(ctx, piece_step, n_window: int = 0, n_global: int = 0,
+                band: int | None = None):
+    """The window's piece programs by its counters, (flops, bytes) of all of
+    them through a family's ``piece_step(cfg, positions, pairs_window,
+    pairs_global, programs, heads)``, or None where the window held none.
+    The attention pairs are the program's own where it counts them
+    (``prefill_pairs_window``, ``prefill_pairs_global``); else the counted
+    positions times the pairs a position of the harness's table of prompts
+    scores (``pairs_a_position``: the band of ``band`` keys in each of
+    ``n_window`` layers, the triangle in each of ``n_global``), and left out
+    where the context has no table: a floor, never a flattery."""
+    n = prefill_counts(ctx)
+    if n is None or not n["programs"]:
+        return None
+    ring, whole = n["pairs_window"], n["pairs_global"]
+    if not ring and not whole:
+        if n_window:
+            ring = n["positions"] * n_window * (
+                pairs_a_position(ctx, band) or 0.0)
+        whole = n["positions"] * n_global * (pairs_a_position(ctx) or 0.0)
+    return piece_step(ctx["cfg"], n["positions"], ring, whole,
+                      n["programs"], n["heads"])
+
+
+def step_mfu_roofline(ctx):
+    """The share of the counters' seconds that the chip would need at its
+    roofline for the useful work they count, in percent: the family's
+    ``step_mix`` (the decode waves at their mean live lanes, rows and expert
+    pairs: ``[(waves, (flops, bytes) of one)]``) and ``prefill_work`` (the
+    prefill programs: ``(flops, bytes)`` of all of them, every held weight
+    read once a program), each at ``roofline.min_seconds``, over
+    ``counters_seconds``.  Counters and the harness's clock alone: no trace,
+    no program's name, so it reads the same work whatever program did it.  A
+    family without ``prefill_work`` has its prefill left out (a floor)."""
+    seconds = counters_seconds(ctx)
+    if not seconds or not ctx.get("cfg") or not ctx.get("device"):
+        return None
+    if ctx["device"].get("platform") == "cpu":      # a rehearsal: no chip,
+        return None                                 # no share of its peak
+    fam = family.load(ctx["cfg"]["family"])
+    peaks = roofline.peaks_for(ctx["device"]["kind"])
+    waves = fam.step_mix(ctx)
+    if not waves:
+        return None
+    least = sum(n * roofline.min_seconds(f, by, peaks)[0]
+                for n, (f, by) in waves)
+    work = fam.prefill_work(ctx) if hasattr(fam, "prefill_work") else None
+    if work:
+        least += roofline.min_seconds(*work, peaks)[0]
+    return 100.0 * least / seconds
 
 
 def kernel_groups(ctx, match,

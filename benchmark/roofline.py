@@ -3,11 +3,14 @@
 The table of peaks is keyed by ``device_kind`` as JAX reports it; a device
 that is not in the table is an error, never a default.  Each model family
 (``benchmark/models/<family>.py``) computes, from shapes alone, the
-floating-point operations and the bytes the *algorithm* needs for one jitted
-step as the program runs it (its lanes and buckets, padding included,
-because that is the call whose time the trace gives).  ``min_seconds`` is the larger of operations over peak FLOP/s and
-bytes over peak bytes/s; a step's roofline share is that over the step's
-device time from the trace.
+floating-point operations and the bytes the *algorithm* needs for the useful
+work of one decode wave (its **live** lanes at their valid context; a
+bucket's padded lanes are not work) and of the window's prefill programs (the
+prompts' own positions).  ``min_seconds`` is the larger of operations over
+peak FLOP/s and bytes over peak bytes/s.  A kernel's roofline share is that
+over the kernel's device time from the trace; the whole step's
+(``step_mfu_roofline.itl``, ``reduce.step_mfu_roofline``) is the least
+seconds of everything the window's counters hold over the seconds they span.
 
 Counting rules, chosen so that a share can never be flattered:
 

@@ -104,7 +104,7 @@ def readers(cfg) -> int:
                                    "prefill_positions_valid": 5}, {}))
     bare = dict(ctx, snap_before=None, snap_after=None, trace=None)
     other = dict(ctx, cfg=load_json(os.path.join(
-        BENCH, "configs", "ouro_2b6.json")))
+        BENCH, "configs", "gpt2_small.json")))
     nothing = [reader(PIECE)(parent), reader(PIECE)(bare),
                reader(DENSE)(bare), reader(PIECE)(other),
                reader(DENSE)(other)]

@@ -6,7 +6,7 @@
 
 1. The two new readers (``loop_dense_roofline.itl``, ``passes_per_wave.obs``)
    and the accepted ones that take the cell through the family
-   (``decode_attn_roofline.itl``, ``step_roofline.itl``) on a reduced trace of
+   (``decode_attn_roofline.itl``, ``step_mfu_roofline.itl``) on a reduced trace of
    made-up times (``check_readers.py``'s ``kernel_ctx``: 48 attention calls a
    step at twice their least time, the dense products at twice theirs), and
    on a context of a program that counts no passes (the parent of PR 50) or
@@ -39,11 +39,11 @@ from traffic import load_json  # noqa: E402
 
 CELL = "ouro_2b6.fewshot"
 DENSE, PASSES = "loop_dense_roofline.itl", "passes_per_wave.obs"
-ATTENTION, STEP = "decode_attn_roofline.itl", "step_roofline.itl"
+ATTENTION, STEP = "decode_attn_roofline.itl", "step_mfu_roofline.itl"
 KERNEL = "decode_wave_attention_bf16_48_19_1536_2048_"
 # The window's counters over 1000 waves of 17 live lanes at 800 positions:
 # four passes a wave, 48 calls' rows.
-LANES, CONTEXT = 17.0, 800.0
+LANES, CONTEXT, WINDOW_S = 17.0, 800.0, 50.0
 COUNTERS = dict(fetched_lanes_live=17_000,
                 fetched_positions_valid=13_600_000,
                 fetched_rows_global=48 * 13_600_000, fetched_passes=4_000)
@@ -67,6 +67,7 @@ def readers(cfg) -> int:
         ctx["trace"]["modules"]["jit_decode"]["mean_ms"] = 1e3 * (
             2 * 48 * call + scale * 2 * dense)
         ctx["traffic"] = {"step_module": "jit_decode"}
+        ctx.update(t0=0.0, t1=WINDOW_S)
         return ctx
     groups = {KERNEL: [2 * call * events, events]}
     ctx = ctx_of(COUNTERS, groups)
@@ -75,11 +76,14 @@ def readers(cfg) -> int:
     parent = {k: v for k, v in COUNTERS.items() if k != "fetched_passes"}
     other = dict(ctx, cfg=load_json(os.path.join(
         BENCH, "configs", "nemotron3_nano_30b.json")))
-    whole = 100.0 * step / (2 * 48 * call + 2 * dense)
+    # The whole step's share is the counters' 1000 waves over the window's
+    # seconds (no trace in it): 1000 x 13.2 ms of 50 s.
+    whole = 100.0 * 1000 * step / WINDOW_S
     status = check(
         near(got[DENSE], 50.0) and near(faster, 50.0 / 0.7)
         and near(got[PASSES], 4.0) and near(got[ATTENTION], 50.0)
-        and near(got[STEP], whole, 1e-6) and 49.9 < whole <= 50.1,
+        and near(got[STEP], whole, 1e-6) and 25.0 < whole < 27.0
+        and reader(STEP)(dict(ctx, trace=None)) == got[STEP],
         f"a step of 48 calls and the dense products, each at twice its "
         f"least time: {DENSE} {got[DENSE]!r}% (30% shorter products "
         f"{faster!r}%), {ATTENTION} {got[ATTENTION]!r}% from {events} "

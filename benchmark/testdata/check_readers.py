@@ -10,8 +10,9 @@ of the kernel is shorter than ten other operations (PR 39: what a faster
 kernel makes of a trace): each reads its share from every call the table of
 operations holds, 1 / 0.7 times as much with the calls 30% shorter, and
 nothing only where the table holds no event of the kernel.  Then the trace
-reduction's own check (``check_trace.py``), so that the tests that run this
-file hold the reduction too.
+reduction's own check (``check_trace.py``) and the whole step's share of the
+roofline's (``check_step_mfu.py``), so that the tests that run this file hold
+them too.
 """
 
 import os
@@ -219,9 +220,10 @@ def main() -> int:
         and near(share, RECORDED_PREFILL_SHARE, 1e-6),
         f"recorded_v5e.xplane.pb.gz: {len(pre)} prefill programs, "
         f"{len(whole)} whole, {step!r} ms each, {share!r}% of the piece")
+    import check_step_mfu
     import check_trace
 
-    return status | check_trace.main()
+    return status | check_trace.main() | check_step_mfu.main()
 
 
 # From the piece's events, as noted when this check was written (PR 27): the
