@@ -39,6 +39,25 @@ generation streams. Design, TPU-first:
   ``piece_pairs_by_kind`` has the (query, key) pairs of every dispatched
   lane's piece added to ``prefill_pairs_window`` and ``prefill_pairs_global``,
   as ``cache_rows_by_kind`` feeds a wave's rows by kind.
+- **A wave carried in the piece's program** (a backend that declares
+  ``piece_wave``): every piece program of its ladder takes a wave's operands
+  at the top bucket behind the piece's (``wave``, behind ``ends``) and
+  returns the wave's part behind the piece's.  Where an iteration has a
+  piece to dispatch and lanes to decode, the worker stages both and
+  dispatches that one program (one ``gen.prefill_dispatch``); the one fetch
+  is taken apart into the piece's and the wave's, and each is counted,
+  kept and emitted as its own program's fetch is: the decoding lanes' next
+  token comes out of the piece's pass over the weights.  A carried wave is a
+  wave to every counter (``fetched_*``, the gaps', ``dispatches``; its
+  ``wave_stats`` are of its own lanes), and ``fetched_waves_carried`` counts
+  those that rode.  With no lane to decode the same program runs with every
+  wave lane on the dummy row.  Such a backend's waves go one at a time
+  (``CLIENT_TPU_GEN_CHUNK`` is not read, as with transitions), and one that
+  declares transitions too is refused.  **What a carried wave is charged**:
+  the program's interval is one clock's for both, so the wave's part is its
+  rows' share of the frame's (its bucket's lanes over those and the piece's
+  ``lanes x piece`` positions): that is the wave's ``decode_waves`` time and
+  its lanes' bill; a piece's part stays unbilled, as a lone piece's is.
 - **Transitions** (a backend that declares ``transition_due(n)`` and
   ``transition_fn()``): a stream whose dispatch-side length ``n`` is due has
   the jitted transition queued before its next wave (span
@@ -199,11 +218,11 @@ class _Inflight:
 
     __slots__ = ("kind", "streams", "tokens", "waves", "t_disp", "bucket",
                  "depth", "positions", "rows", "pieces", "fresh", "by_kind",
-                 "lanes")
+                 "lanes", "rider")
 
     def __init__(self, kind, streams, tokens, waves=1, t_disp=0, bucket=0,
                  depth=0, positions=0, rows=(0, 0), pieces=(), fresh=(),
-                 by_kind=(0, 0, 0), lanes=0):
+                 by_kind=(0, 0, 0), lanes=0, rider=None):
         self.kind = kind          # 'prefill' | 'piece' | 'wave' | 'chunk'
         self.streams = streams    # lane order, real lanes only
         self.tokens = tokens      # jax.Array future (copy_to_host_async'd)
@@ -218,6 +237,9 @@ class _Inflight:
         self.by_kind = by_kind    # rows it reads: (ring, whole-context), and
         #                           its lanes past the ring
         self.lanes = lanes        # a piece call's compiled lanes
+        # The wave a piece's program carried (an _Inflight of its own kind
+        # with no tokens: its part lies behind the piece's in this fetch).
+        self.rider = rider
 
 
 class _WarmupReq:
@@ -358,6 +380,10 @@ class GenerativeScheduler(Scheduler):
         # two waves.
         self._piece_len, self._piece_lanes = backend.prefill_piece or (0, 0)
         self._piece_ends = bool(self._piece_len and backend.piece_ends)
+        # The lanes of the wave that every piece program carries (module
+        # docstring: the top wave bucket; 0: the programs take no wave).
+        self._piece_wave = self._cap if (
+            self._piece_ends and backend.piece_wave) else 0
         self._cache_rows = backend.cache_rows
         self._rows_by_kind = backend.cache_rows_by_kind
         self._pairs_by_kind = backend.piece_pairs_by_kind
@@ -381,6 +407,15 @@ class GenerativeScheduler(Scheduler):
                 donate_argnums=backend.donate_argnums)
             # A transition may fall between any two steps of a stream, so
             # waves are dispatched one at a time.
+            self._chunk = 1
+            if self._piece_wave:
+                raise ValueError(
+                    f"{model.config.name}: a transition is ordered before "
+                    "a stream's next wave and a piece's program carries "
+                    "that wave; the backend declares both")
+        if self._piece_wave:
+            # A piece's program carries one wave, so waves are dispatched
+            # one at a time.
             self._chunk = 1
         self._decode_chunk = None
         if self._chunk > 1:
@@ -511,7 +546,9 @@ class GenerativeScheduler(Scheduler):
                 np.zeros((lane, bucket), np.int32), np.ones(lane, np.int32),
                 *sampling, sample,
                 *((zeros, zeros) if self._piece_ends
-                  else (zeros,) if self._piece_len else ()))
+                  else (zeros,) if self._piece_len else ()),
+                *((self._stage_wave([], self._piece_wave),)
+                  if self._piece_wave else ()))
         self._ladders_warm.add((bucket, sample))
 
     def _precompile(self) -> None:
@@ -526,8 +563,7 @@ class GenerativeScheduler(Scheduler):
         for wb in self._wave_buckets:
             self.model._set_state(f"warmup: decode wave bucket={wb}",
                                   _sp.STEP_DECODE, wb)
-            rows, *sampling = self._stage_lanes([], wb)
-            lens = np.zeros(wb, np.int32)
+            rows, lens, *sampling = self._stage_wave([], wb)
             self._arena, tokens = self._decode(
                 self.model._params, self._arena, rows, lens, *sampling,
                 False)
@@ -597,11 +633,11 @@ class GenerativeScheduler(Scheduler):
             return True
         with span[_sp.S_SWEEP]:
             live = self._sweep()
-        pieced = False
+        pieced = carried = False
         try:
             if self._piece_len:
-                pieced = self._dispatch_piece()
-            if live:
+                pieced, carried = self._dispatch_piece(live)
+            if live and not carried:
                 if self._transition is not None:
                     self._dispatch_transitions(live)
                 self._dispatch_wave(live)
@@ -820,6 +856,15 @@ class GenerativeScheduler(Scheduler):
                 column([s.top_k for s in lanes], 0, np.int32),
                 column([s.top_p for s in lanes], 1.0, np.float32))
 
+    def _stage_wave(self, live: list, bucket: int):
+        """(rows, lens, seeds, temps, top_ks, top_ps) of a wave of ``live``
+        at ``bucket`` lanes, as a decode program takes them: a padded lane
+        on the dummy row at length 0."""
+        rows, *sampling = self._stage_lanes(live, bucket)
+        lens = np.asarray([s.disp_len for s in live]
+                          + [0] * (bucket - len(live)), np.int32)
+        return (rows, lens, *sampling)
+
     def _prefill_chunk(self, prompt_bucket: int, chunk: list) -> None:
         """One batched prefill dispatch: B admits -> ONE device execution,
         no host sync (the first tokens arrive through the fetch queue)."""
@@ -867,21 +912,23 @@ class GenerativeScheduler(Scheduler):
                                         depth=self._inflight_waves))
         self._inflight_waves += 1
 
-    def _dispatch_piece(self) -> bool:
+    def _dispatch_piece(self, live: list) -> tuple[bool, bool]:
         """The next piece of the oldest prompts still prefilling (up to the
-        backend's lanes), as ONE device execution with no host sync; True
-        if one was dispatched.  A prompt's last piece leaves its first token
-        in the slot's device-side token and in the fetch queue, and the
-        stream joins the next wave."""
+        backend's lanes), as ONE device execution with no host sync.  A
+        prompt's last piece leaves its first token in the slot's device-side
+        token and in the fetch queue, and the stream joins the next wave.
+        Where the piece program carries a wave, the wave of ``live`` goes in
+        it.  -> (a piece was dispatched, it carried the wave)."""
         todo = [s for s in self._streams
                 if s.ids is not None][:self._piece_lanes]
         if not todo:
-            return False
+            return False, False
+        riders = live if self._piece_wave else []
         with self._rec.span[_sp.S_PREFILL_STAGE]:
-            self._stage_and_dispatch_piece(todo)
-        return True
+            self._stage_and_dispatch_piece(todo, riders)
+        return True, bool(riders)
 
-    def _stage_and_dispatch_piece(self, todo: list) -> None:
+    def _stage_and_dispatch_piece(self, todo: list, riders: list) -> None:
         width = self._piece_len
         lane = next(b for b in self._ladders[width] if b >= len(todo))
         ids_mat = np.zeros((lane, width), np.int32)
@@ -895,17 +942,22 @@ class GenerativeScheduler(Scheduler):
             ends[i] = s.consumed + len(part) >= len(s.ids)
         rows, seeds, temps, top_ks, top_ps = self._stage_lanes(todo, lane)
         sample = bool((temps > 0.0).any())
+        wave = ()
+        if self._piece_wave:
+            wave = (self._stage_wave(riders, self._piece_wave),)
+            sample = sample or bool((wave[0][3] > 0.0).any())
         self._warm_ladder(width, sample, but=lane)
         self.model._set_state(
             f"generative prefill piece ({len(todo)} streams, from "
-            f"{[int(x) for x in starts[:len(todo)]]})",
+            f"{[int(x) for x in starts[:len(todo)]]}"
+            + (f", a wave of {len(riders)}" if riders else "") + ")",
             _sp.STEP_PREFILL, width)
         try:
             with self._rec.span[_sp.S_PREFILL_DISPATCH]:
                 self._arena, tokens = self._prefill(
                     self.model._params, self._arena, rows, ids_mat, lens,
                     seeds, temps, top_ks, top_ps, sample, starts,
-                    *((ends,) if self._piece_ends else ()))
+                    *((ends,) if self._piece_ends else ()), *wave)
             tokens.copy_to_host_async()
         finally:
             self.model._clear_state()
@@ -933,11 +985,17 @@ class GenerativeScheduler(Scheduler):
             done.append(_NO_STREAM if s.ids is not None else s)
         # The fetch queue keeps dispatch order and the pipeline's depth; a
         # lane whose prompt goes on carries no stream (its token is junk).
+        depth = self._inflight_waves
+        self._inflight_waves += 1
+        # (A carried wave stands in the queue's depth for the dispatch it
+        # replaces; a program whose wave held no lane leaves a part that
+        # nothing reads.)
+        rider = self._wave_dispatched(
+            riders, self._piece_wave, 1, None, wave[0][1]) if riders else None
         self._inflight.append(_Inflight(
             "prefill" if any(s is not _NO_STREAM for s in done) else "piece",
-            done, tokens, depth=self._inflight_waves, lanes=lane,
+            done, tokens, depth=depth, lanes=lane, rider=rider,
             pieces=list(zip(todo, lens.tolist())) if self._record else ()))
-        self._inflight_waves += 1
 
     def _dispatch_transitions(self, live: list) -> None:
         """Queue the cache transition of every live stream that is due one,
@@ -974,9 +1032,8 @@ class GenerativeScheduler(Scheduler):
     def _stage_and_dispatch(self, live: list) -> None:
         rec = self._rec
         bucket = next(b for b in self._wave_buckets if b >= len(live))
-        rows, seeds, temps, top_ks, top_ps = self._stage_lanes(live, bucket)
-        lens = np.asarray([s.disp_len for s in live]
-                          + [0] * (bucket - len(live)), np.int32)
+        rows, lens, seeds, temps, top_ks, top_ps = self._stage_wave(
+            live, bucket)
         # Chunk only when every live lane has K steps of sequence headroom:
         # a scanned step past max_seq would CLIP its k/v scatter onto the
         # last position (jax .at[] semantics) and corrupt it.  Budget
@@ -1006,6 +1063,33 @@ class GenerativeScheduler(Scheduler):
             nxt.copy_to_host_async()
         finally:
             self.model._clear_state()
+        self._inflight.append(
+            self._wave_dispatched(live, bucket, k, nxt, lens))
+        if (bucket, k) not in self._wave_cost_captured:
+            # Once per wave shape: static roofline numerator for this
+            # decode executable. The jit call above just traced this
+            # exact signature, so .lower() is a cache hit (no compile);
+            # donation is not executed by lowering, and self._arena is
+            # the live post-dispatch arena with identical avals.
+            self._wave_cost_captured.add((bucket, k))
+            from client_tpu.observability import roofline
+
+            args = (self.model._params, self._arena, rows, lens,
+                    seeds, temps, top_ks, top_ps, sample)
+            cost = roofline.capture_cost_model(
+                self._decode_chunk if k > 1 else self._decode,
+                args + ((k,) if k > 1 else ()))
+            profiler().record_wave_cost_model(
+                self.model.config.name, self.model.config.version,
+                bucket, k, cost)
+
+    def _wave_dispatched(self, live: list, bucket: int, k: int, nxt,
+                         lens) -> _Inflight:
+        """A wave of ``live`` is on the device, in a decode program (``nxt``
+        its result) or in a piece's (``None``: its part lies in the piece's
+        fetch): the counters of a dispatch, the streams' dispatch-side
+        lengths, and what its fetch will count."""
+        rec = self._rec
         # How deep the pipeline already was, and the valid context the
         # live lanes read (each its length before this wave, one more for
         # each scanned step): counted when the wave's tokens arrive.
@@ -1034,32 +1118,14 @@ class GenerativeScheduler(Scheduler):
         # One device dispatch = one execution in the public stats, chunked
         # or not — execution_count means device executions, and fewer
         # executions per token IS the chunking win the stat should show.
-        self.stats.record_execution(len(live))
-        self._inflight.append(_Inflight("chunk" if k > 1 else "wave",
-                                        live, nxt, waves=k,
-                                        t_disp=time.monotonic_ns(),
-                                        bucket=bucket,
-                                        positions=positions,
-                                        rows=(n_sum, n_exact), fresh=fresh,
-                                        by_kind=by_kind))
+        # (A carried wave's execution is its piece's.)
+        if nxt is not None:
+            self.stats.record_execution(len(live))
         self._inflight_waves += k
-        if (bucket, k) not in self._wave_cost_captured:
-            # Once per wave shape: static roofline numerator for this
-            # decode executable. The jit call above just traced this
-            # exact signature, so .lower() is a cache hit (no compile);
-            # donation is not executed by lowering, and self._arena is
-            # the live post-dispatch arena with identical avals.
-            self._wave_cost_captured.add((bucket, k))
-            from client_tpu.observability import roofline
-
-            args = (self.model._params, self._arena, rows, lens,
-                    seeds, temps, top_ks, top_ps, sample)
-            cost = roofline.capture_cost_model(
-                self._decode_chunk if k > 1 else self._decode,
-                args + ((k,) if k > 1 else ()))
-            profiler().record_wave_cost_model(
-                self.model.config.name, self.model.config.version,
-                bucket, k, cost)
+        return _Inflight("chunk" if k > 1 else "wave", live, nxt, waves=k,
+                         t_disp=time.monotonic_ns(), bucket=bucket,
+                         positions=positions, rows=(n_sum, n_exact),
+                         fresh=fresh, by_kind=by_kind)
 
     def _drain_fetches(self, force_one: bool = False) -> None:
         """Consume completed token fetches in dispatch order; emission,
@@ -1085,74 +1151,108 @@ class GenerativeScheduler(Scheduler):
                 self._reset_arena(exc)
                 break
             drained = True
-            if self._wave_stats and head.kind in ("wave", "chunk"):
-                n = len(self._wave_stats)
-                stats = toks[..., -n:].reshape(-1, n).sum(axis=0)
-                toks = toks[..., :-n]
-                for i, v in zip(self._wave_stats, stats.tolist()):
-                    c[i] += v
-            if self._record:
-                toks = self._keep_records(head, toks)
-            # Wave timing: the device ran this dispatch from
-            # max(its dispatch, the previous fetch) until now — pipelined
-            # waves complete back to back, so the deltas between
-            # consecutive fetches ARE the per-dispatch device occupancy
-            # (the first fetch after an idle gap also carries host
-            # staging; steady-state waves dominate the histogram).
-            t_done = time.monotonic_ns()
-            if not head.bucket:
-                self._prefill_since_decode = True
-            else:
+            if head.rider is not None:
+                # The piece's program carried a wave: the fetch is the
+                # piece's part and then the wave's, each taken as its own
+                # program's fetch.  No clock splits the program's interval
+                # (from the fetch before it), so the wave's time is its
+                # rows' share of the frame's (module docstring).
+                self._inflight_waves -= head.rider.waves
+                c[_sp.C_FETCHED_WAVES_CARRIED] += 1
+                since, cut = self._last_fetch_ns, self._piece_part(head)
+                wave_rows = head.rider.bucket
+                self._take_fetch(head, toks[:cut], since)
+                self._take_fetch(
+                    head.rider, toks[cut:], since,
+                    wave_rows / (wave_rows + head.lanes * self._piece_len))
                 decode_fetches += 1
-                self._count_gap(head, t_done)
-                busy_ns = max(
-                    0, t_done - max(head.t_disp, self._last_fetch_ns))
-                # The device has run the wave: its lanes, padding and
-                # valid context are what decode_waves and the clients'
-                # token gaps of this moment are about.
-                lanes = len(head.streams) * head.waves
-                c[_sp.C_FETCHED_WAVES] += head.waves
-                c[_sp.C_FETCHED_LANES_LIVE] += lanes
-                c[_sp.C_FETCHED_LANES_PADDED] += \
-                    head.bucket * head.waves - lanes
-                c[_sp.C_FETCHED_POSITIONS_VALID] += head.positions
-                c[_sp.C_FETCHED_ROWS_SUMMARY] += head.rows[0]
-                c[_sp.C_FETCHED_ROWS_EXACT] += head.rows[1]
-                c[_sp.C_FETCHED_ROWS_WINDOW] += head.by_kind[0]
-                c[_sp.C_FETCHED_ROWS_GLOBAL] += head.by_kind[1]
-                c[_sp.C_FETCHED_LANES_PAST_WINDOW] += head.by_kind[2]
-                c[_sp.C_FETCHED_PASSES] += head.waves * self._passes
-                profiler().record_wave(
-                    self.model.config.name, self.model.config.version,
-                    bucket=head.bucket, chunk=head.waves,
-                    duration_ns=busy_ns, waves=head.waves)
-                # Cost ledger: the wave's device occupancy splits evenly
-                # across live lanes (every stream advances one token per
-                # wave regardless of context length); padded lanes charge
-                # the wave's dominant tenant as padding waste. A junk
-                # wave (every lane retired while it was in flight) bills
-                # its dispatch-time streams instead — they caused the
-                # speculative dispatch, and conservation against the
-                # profiler requires every recorded wave to be charged.
-                live = [s for s in head.streams if not s.dead] \
-                    or list(head.streams)
-                if live:
-                    ledger().charge_batch(
-                        self.model.config.name,
-                        str(self.model.config.version),
-                        [(s.req.tenant, 1, None) for s in live],
-                        busy_ns / 1e9,
-                        padded=max(0, head.bucket - len(live)),
-                        component="wave")
-            self._last_fetch_ns = t_done
-            with rec.span[_sp.S_EMIT]:
-                self._emit_fetched(head, toks)
+            else:
+                if self._piece_wave and not head.bucket:
+                    toks = toks[:self._piece_part(head)]  # no lane rode
+                self._take_fetch(head, toks, self._last_fetch_ns)
+                decode_fetches += bool(head.bucket)
         if drained:
             c[_sp.C_DRAINS] += 1
             if decode_fetches >= 2:
                 # Two waves' tokens leave back to back: the pairs a
                 # client sees as one long gap and one of nothing.
                 c[_sp.C_DRAINS_MULTI] += 1
+
+    def _piece_part(self, head: _Inflight) -> int:
+        """How much of a piece program's fetch is the piece's: its lanes'
+        tokens and their positions' rows of the record (the rest is the part
+        of the wave it carried)."""
+        return head.lanes * (1 + self._piece_len * self._record)
+
+    def _take_fetch(self, head: _Inflight, toks, since: int,
+                    share: float = 1.0) -> None:
+        """One program's fetched result (a carrying piece's comes apart into
+        two): what only the device counted, the records, the wave's timing
+        and counters, then emission.  ``since``: the fetch before it;
+        ``share``: how much of the device's interval is this wave's (a
+        carried wave's rows over its program's)."""
+        rec = self._rec
+        c = rec.c
+        if self._wave_stats and head.kind in ("wave", "chunk"):
+            n = len(self._wave_stats)
+            stats = toks[..., -n:].reshape(-1, n).sum(axis=0)
+            toks = toks[..., :-n]
+            for i, v in zip(self._wave_stats, stats.tolist()):
+                c[i] += v
+        if self._record:
+            toks = self._keep_records(head, toks)
+        # Wave timing: the device ran this dispatch from max(its dispatch,
+        # the previous fetch) until now — pipelined waves complete back to
+        # back, so the deltas between consecutive fetches ARE the
+        # per-dispatch device occupancy (the first fetch after an idle gap
+        # also carries host staging; steady-state waves dominate the
+        # histogram).
+        t_done = time.monotonic_ns()
+        if not head.bucket:
+            self._prefill_since_decode = True
+        else:
+            self._count_gap(head, t_done)
+            busy_ns = int(share * max(0, t_done - max(head.t_disp, since)))
+            # The device has run the wave: its lanes, padding and valid
+            # context are what decode_waves and the clients' token gaps of
+            # this moment are about.
+            lanes = len(head.streams) * head.waves
+            c[_sp.C_FETCHED_WAVES] += head.waves
+            c[_sp.C_FETCHED_LANES_LIVE] += lanes
+            c[_sp.C_FETCHED_LANES_PADDED] += \
+                head.bucket * head.waves - lanes
+            c[_sp.C_FETCHED_POSITIONS_VALID] += head.positions
+            c[_sp.C_FETCHED_ROWS_SUMMARY] += head.rows[0]
+            c[_sp.C_FETCHED_ROWS_EXACT] += head.rows[1]
+            c[_sp.C_FETCHED_ROWS_WINDOW] += head.by_kind[0]
+            c[_sp.C_FETCHED_ROWS_GLOBAL] += head.by_kind[1]
+            c[_sp.C_FETCHED_LANES_PAST_WINDOW] += head.by_kind[2]
+            c[_sp.C_FETCHED_PASSES] += head.waves * self._passes
+            profiler().record_wave(
+                self.model.config.name, self.model.config.version,
+                bucket=head.bucket, chunk=head.waves,
+                duration_ns=busy_ns, waves=head.waves)
+            # Cost ledger: the wave's device occupancy splits evenly across
+            # live lanes (every stream advances one token per wave
+            # regardless of context length); padded lanes charge the wave's
+            # dominant tenant as padding waste. A junk wave (every lane
+            # retired while it was in flight) bills its dispatch-time
+            # streams instead — they caused the speculative dispatch, and
+            # conservation against the profiler requires every recorded
+            # wave to be charged.
+            live = [s for s in head.streams if not s.dead] \
+                or list(head.streams)
+            if live:
+                ledger().charge_batch(
+                    self.model.config.name,
+                    str(self.model.config.version),
+                    [(s.req.tenant, 1, None) for s in live],
+                    busy_ns / 1e9,
+                    padded=max(0, head.bucket - len(live)),
+                    component="wave")
+        self._last_fetch_ns = t_done
+        with rec.span[_sp.S_EMIT]:
+            self._emit_fetched(head, toks)
 
     def _count_gap(self, head: _Inflight, t_done: int) -> None:
         """The token gap a decode fetch closes, for each of its lanes: the

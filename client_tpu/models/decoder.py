@@ -77,6 +77,23 @@ axis of both, the chunked step, the sampling tail, the decoupled
   or ``False`` (a backend that writes a piece program of its own and runs
   its head in every piece: models/evabyte.py).  Read with ``prefill_piece``
   alone.
+- ``piece_wave``: ``False``, or ``True`` where **every piece program carries
+  a decode wave** of ``B = max_streams`` lanes (the top wave bucket).  The
+  program then takes one more operand behind ``ends``, ``wave = (rows, lens,
+  seeds, temps, top_ks, top_ps)``, each ``[B]`` as ``DECODE_ARGS`` has them
+  (a lane that holds no stream on the junk slot at length 0; ``sample`` is
+  the one flag of both), and returns ``[the piece's part | the wave's part]``,
+  each as its own program leaves it (``[L | L x piece x stream_record]``,
+  then ``[B | B x stream_record | wave_stats]``): where a token gap holds a
+  piece, the scheduler dispatches this one program and the decoding lanes'
+  next token comes out of the piece's pass over the weights.  The frame is
+  written here (``piece_hidden_fn``, ``prefill_fn``) for the layer kinds
+  ``"rows"``, ``"ring"`` and ``"none"``; a backend that declares it supplies
+  mixers that take the wave's rows behind the piece's
+  (``_piece_rows_layer(..., wave)``, models/grouped_query.py) and a trail
+  that counts the wave's rows apart (``_piece_end``, models/experts.py).
+  Read with ``piece_ends`` alone; a scheduler dispatches such a backend's
+  waves one at a time and refuses one that declares transitions too.
 - ``cache_rows``: ``None`` (a step reads one row a position) or ``(n) ->
   (summary rows, exact rows)`` a step at context length ``n`` reads.
 - ``cache_rows_by_kind``: ``None``, or ``(n) -> (ring rows, other rows, past)``:
@@ -100,7 +117,7 @@ axis of both, the chunked step, the sampling tail, the decoupled
   behind it ``ends`` (``[L]`` int32, traced: 1 where the lane's piece is its
   prompt's last, 0 elsewhere and on padded lanes; the scheduler knows it and
   the program cannot, since a last piece may be full) only with
-  ``piece_ends`` too;
+  ``piece_ends`` too, and behind that ``wave`` only with ``piece_wave``;
   ``decode_fn()`` -> (arena, tokens[B]); ``decode_chunk_fn()`` -> (arena,
   tokens[k, B]).  ``sample`` (and the chunk's ``k``) are static, the arena is
   donated: ``*_static_argnums`` and ``donate_argnums`` say so by position.
@@ -150,7 +167,7 @@ from client_tpu.engine.config import ModelConfig, TensorConfig
 from client_tpu.engine.model import ModelBackend
 
 PREFILL_ARGS = ("params", "arena", "rows", "ids", "lens", "seeds", "temps",
-                "top_ks", "top_ps", "sample", "starts", "ends")
+                "top_ks", "top_ps", "sample", "starts", "ends", "wave")
 DECODE_ARGS = ("params", "arena", "rows", "lens", "seeds", "temps", "top_ks",
                "top_ps", "sample")
 DECODE_CHUNK_ARGS = DECODE_ARGS + ("k",)
@@ -288,6 +305,7 @@ class DecoderBackend(ModelBackend):
 
     prefill_piece: tuple[int, int] | None = None
     piece_ends = True
+    piece_wave = False
     passes = 1
     cache_leaves: tuple[str, ...] = ("k", "v")
     layer_kinds: tuple[str, ...] | None = None
@@ -667,7 +685,7 @@ class DecoderBackend(ModelBackend):
 
     # -- the prefill piece ----------------------------------------------------
 
-    def _piece_start(self, p, ids, pos, live):
+    def _piece_start(self, p, ids, pos, live, riders: int = 0):
         return self._embed(p, ids, pos), None
 
     def _piece_after(self, lp, x, o, trail):
@@ -677,7 +695,9 @@ class DecoderBackend(ModelBackend):
         return self._feed_forward(lp, x), trail
 
     def _piece_end(self, trail):
-        return trail
+        """(what the program hands on, the ``wave_stats`` of the wave that
+        rode or ``None``)."""
+        return trail, None
 
     def _piece_words(self, trail):
         return []
@@ -686,7 +706,7 @@ class DecoderBackend(ModelBackend):
         """x ``[n, d]`` through ``passes`` x the layers by their kinds:
         ``mixer(kind, ki, lp, x)`` -> o for a layer that has one, then
         ``_piece_after``; a ``"none"`` layer is ``_piece_block``.  -> (x,
-        what ``_piece_end`` makes of the trail)."""
+        what ``_piece_end`` makes of the trail, a riding wave's counts)."""
         def layer(carry, lp, li):
             x, trail = carry
             kind, ki = self._layer_kind(li)
@@ -695,7 +715,7 @@ class DecoderBackend(ModelBackend):
             return self._piece_after(lp, x, mixer(kind, ki, lp, x), trail)
 
         x, trail = self._walk_layers(p, layer, (x, trail))
-        return x, self._piece_end(trail)
+        return (x, *self._piece_end(trail))
 
     def piece_hidden_fn(self):
         """(params, arena, rows[L], ids[L, piece], lens[L], starts[L]) ->
@@ -720,30 +740,62 @@ class DecoderBackend(ModelBackend):
         trail), ``_piece_after(lp, x, o, trail)`` and ``_piece_block(lp, x,
         trail)`` -> (x, trail), ``_piece_end(trail)`` -> what the program
         hands on, and ``_piece_words(that)`` -> the record's leading
-        columns."""
+        columns.
+
+        **With a wave** (``piece_wave``; ``wave = (rows[B], lens[B])`` behind
+        ``starts``): the wave's ``B`` rows stand behind the piece's ``L *
+        piece`` in x all the way, each lane's input token gathered from its
+        slot and its position its length, as ``_decode_hidden_fn`` takes
+        them.  A mixer projects all rows as one batch and gets, behind
+        ``pos``, the wave's own step of its kind for the rows behind the
+        piece's (``_decode_attend``, the wave's rows, its live rows or
+        lengths); every other product sees the rows as one batch, and
+        ``_piece_start`` is told how many of its rows are a wave's
+        (``riders``).  The slots of the two are disjoint: a stream prefills
+        or decodes."""
         import jax.numpy as jnp
 
         n = self.prefill_piece[0]
         leaves_of = {"rows": self.cache_leaves, "ring": self.ring_leaves,
                      "state": self.state_leaves}
+        steps = {}
+        if self.piece_wave:
+            if self.latent_attention is not None or "state" in (
+                    self.layer_kinds or ()):
+                raise NotImplementedError(
+                    f"{self.config.name}: a piece carries a wave through "
+                    "the layer kinds rows, ring and none")
+            steps["rows"] = self._decode_attend()
+            if self.ring_leaves:
+                steps["ring"] = self._decode_attend(ring=True)
 
-        def piece(p, arena, rows, ids, lens, starts):
+        def piece(p, arena, rows, ids, lens, starts, wave=None):
             at = jnp.arange(n)
             live = (at < lens[:, None]).reshape(-1)
             pos = (starts[:, None] + at).reshape(-1)
+            ids, riding, start = ids.reshape(-1), {}, ()
+            if wave is not None:
+                w_rows, w_lens = wave
+                ids = jnp.concatenate([ids, arena["tok"][w_rows]])
+                live = jnp.concatenate([live, w_lens > 0])
+                pos = jnp.concatenate([pos, w_lens])
+                riding = {"rows": ((steps["rows"], w_rows,
+                                    self._live_rows(w_lens)),),
+                          "ring": ((steps.get("ring"), w_rows, w_lens),)}
+                start = (w_rows.shape[0],)
             arena = dict(arena)
 
             def mixer(kind, ki, lp, x):
                 names = leaves_of[kind]
                 *leaves, o = getattr(self, f"_piece_{kind}_layer")(
                     lp, *(arena[name] for name in names), ki, rows, starts,
-                    lens, x, pos)
+                    lens, x, pos, *riding.get(kind, ()))
                 arena.update(zip(names, leaves))
                 return o
 
-            x, trail = self._walk_kinds(
-                p, *self._piece_start(p, ids.reshape(-1), pos, live), mixer)
-            return arena, x, trail
+            x, trail, stats = self._walk_kinds(
+                p, *self._piece_start(p, ids, pos, live, *start), mixer)
+            return (arena, x, trail) + (() if wave is None else (stats,))
 
         return piece
 
@@ -765,36 +817,70 @@ class DecoderBackend(ModelBackend):
         stream_record]``: a model's own words (``_piece_words``) in every
         piece, then the logits' bits in the row of a lane's last valid
         position.  (A backend that declares no ``prefill_piece`` writes its
-        own ``prefill_fn``.)"""
+        own ``prefill_fn``.)
+
+        **With a wave** (``piece_wave``; ``wave = (rows, lens, seeds, temps,
+        top_ks, top_ps)``, each ``[B]``): the wave's rows go through the
+        layers behind the piece's (``piece_hidden_fn``) and out through the
+        same head: the rows of x that the one product over the vocabulary's
+        matrix takes are each piece lane's last and the wave's ``B``, under
+        the one conditional, now on "a lane ends or a wave lane is live";
+        a wave lane's token is chosen at context ``lens + 1``, as
+        ``decode_fn`` chooses it.  -> (arena, ``[the piece's part | the
+        wave's part]``), each as its own program leaves it: the wave's is
+        ``[B | B x stream_record | wave_stats]``, the record's rows and the
+        counts (``_piece_end``) of the wave's rows alone."""
         piece = self.piece_hidden_fn()
         n = self.prefill_piece[0]
         record = bool(self.stream_record)
 
         def prefill(p, arena, rows, ids, lens, seeds, temps, top_ks, top_ps,
-                    sample, starts, ends):
+                    sample, starts, ends, wave=None):
             import jax
             import jax.numpy as jnp
 
-            lanes = rows.shape[0]
-            arena, x, trail = piece(p, arena, rows, ids, lens, starts)
+            lanes, counts = rows.shape[0], None
+            if wave is None:
+                arena, x, trail = piece(p, arena, rows, ids, lens, starts)
+            else:
+                w_rows, w_lens, *w_sampling = wave
+                arena, x, trail, counts = piece(
+                    p, arena, rows, ids, lens, starts, (w_rows, w_lens))
             # Each lane's last valid row of x.
-            at = lens - 1 + n * np.arange(lanes, dtype=np.int32)
+            at = last_at = lens - 1 + n * np.arange(lanes, dtype=np.int32)
+            heads = lanes
+            if wave is not None:
+                # The wave's rows behind the lanes' last, its columns behind
+                # the lanes': one head for both.
+                heads = lanes + w_rows.shape[0]
+                at = jnp.concatenate([at, np.arange(lanes * n, x.shape[0],
+                                                    dtype=np.int32)])
+                seeds, temps, top_ks, top_ps = (
+                    jnp.concatenate(pair) for pair in zip(
+                        (seeds, temps, top_ks, top_ps), w_sampling))
+                rows = jnp.concatenate([rows, w_rows])
 
             def head(x_at):
                 logits = self._served(self._logits(p, x_at))
-                tokens = choose_tokens(logits, seeds, starts + lens, temps,
-                                       top_ks, top_ps, sample)
+                ctx = starts + lens
+                if wave is not None:
+                    ctx = jnp.concatenate([ctx, w_lens + 1])
+                tokens = choose_tokens(logits, seeds, ctx, temps, top_ks,
+                                       top_ps, sample)
                 if not record:
                     return (tokens,)
                 return tokens, logit_bits(logits, tokens, RECORD_LOGITS)
 
             def skip(x_at):
-                tokens = jnp.zeros(lanes, jnp.int32)
+                tokens = jnp.zeros(heads, jnp.int32)
                 if not record:
                     return (tokens,)
-                return tokens, jnp.zeros((lanes, 1 + RECORD_LOGITS),
+                return tokens, jnp.zeros((heads, 1 + RECORD_LOGITS),
                                          jnp.int32)
 
+            due = jnp.any(ends != 0)
+            if wave is not None:
+                due = due | jnp.any(w_lens > 0)
             # (The rows behind a barrier: the compiler otherwise sinks what
             # of the last layer only they read, an expert layer's gather
             # back from the sorted layout, into the branch, and every
@@ -802,16 +888,25 @@ class DecoderBackend(ModelBackend):
             # temporaries at smallthinker_21b's widths, compiled for the
             # v5e, tests/test_tpu_compile.py.)
             tokens, *bits = jax.lax.cond(
-                jnp.any(ends != 0), head, skip,
-                jax.lax.optimization_barrier(x[at]))
+                due, head, skip, jax.lax.optimization_barrier(x[at]))
             arena = tokens_into_slots(arena, rows, tokens)
+            stats = [] if counts is None else [counts.astype(tokens.dtype)]
             if not record:
-                return arena, tokens
-            last = (jnp.arange(lanes * n) == jnp.repeat(at, n))
+                return arena, (jnp.concatenate([tokens, *stats]) if stats
+                               else tokens)
+            last = (jnp.arange(lanes * n) == jnp.repeat(last_at, n))
             words, last = self._piece_words(trail), last[:, None]
-            bits = jnp.repeat(bits[0], n, axis=0)
-            rec = jnp.concatenate(words + [jnp.where(last, bits, 0)], axis=1)
-            return arena, jnp.concatenate([tokens, rec.reshape(-1)])
+            if wave is None:
+                bits = jnp.repeat(bits[0], n, axis=0)
+                rec = jnp.concatenate(words + [jnp.where(last, bits, 0)],
+                                      axis=1)
+                return arena, jnp.concatenate([tokens, rec.reshape(-1)])
+            own = jnp.where(last, jnp.repeat(bits[0][:lanes], n, axis=0), 0)
+            rec = jnp.concatenate(
+                words + [jnp.concatenate([own, bits[0][lanes:]])], axis=1)
+            return arena, jnp.concatenate(
+                [tokens[:lanes], rec[:lanes * n].reshape(-1),
+                 tokens[lanes:], rec[lanes * n:].reshape(-1), *stats])
 
         return prefill
 

@@ -152,7 +152,10 @@ class ExpertDecoder(SeededDecoder):
         24.4 in 32 | 64 | 128; 32 of 256 experts of 1024 held, 8 a token, PR
         48: a share of 16 rows 18.06 | 17.36 | 17.47 in 16 | 32 | 64, of 32
         rows 32.19 | 33.51 in 32 | 64; 64 experts of 768, all held, 6 a
-        token, PR 52: a share of 96 rows 28.41 | 27.07 in 64 | 128.)"""
+        token, PR 52: a share of 96 rows 28.41 | 27.07 in 64 | 128; 16 of 128
+        experts of 4096 held, 8 a token, PR 56: a share of 33.5 rows, a
+        piece's 512 and the 24 of the wave it carries, 37.55 | 36.49 in 32 |
+        64.)"""
         share = tokens * self.top_k / self.n_experts
         return next((tile for tile in (TILE_M_PIECE // 2, TILE_M_PIECE)
                      if share <= tile), 2 * TILE_M_PIECE)
@@ -293,29 +296,61 @@ class ExpertDecoder(SeededDecoder):
 
     # -- the piece's parts (models/decoder.py ``piece_hidden_fn``) ---------------
 
-    def _piece_start(self, p, ids, pos, live):
+    def _piece_start(self, p, ids, pos, live, riders: int = 0):
         """A piece's first x and its trail: which positions hold a token,
         the sorted layout's tile for this many positions, and the expert
-        layers' choices so far."""
+        layers' choices so far.  With ``riders`` (the last that many rows
+        are a wave's that rides in the program, models/decoder.py
+        ``piece_wave``): how many, and the wave's counts so far."""
         import jax.numpy as jnp
 
-        return (p["embed"][ids].astype(jnp.float32),
-                {"live": live, "tile": self._piece_tile(ids.shape[0]),
-                 "routes": ()})
+        trail = {"live": live, "tile": self._piece_tile(ids.shape[0]),
+                 "routes": ()}
+        if riders:
+            trail.update(riders=riders, stats=jnp.zeros(3, jnp.int32))
+        return p["embed"][ids].astype(jnp.float32), trail
+
+    def _held_counts(self, top_i, live):
+        """(pairs held here, the busiest held expert's, held experts
+        touched) of the rows whose choices are ``top_i [B, k]`` (``live
+        [B]``: which of them hold a stream): what ``_experts`` counts of a
+        whole call, for the rows of a wave that rides in a piece's."""
+        import jax.numpy as jnp
+
+        held = self.experts_held
+        e = top_i - self.first_expert
+        e = jnp.where((e >= 0) & (e < held) & live[:, None], e, held)
+        sizes = (e.reshape(-1, 1) == jnp.arange(held)).sum(axis=0)
+        return jnp.stack([sizes.sum(), sizes.max(),
+                          (sizes > 0).sum()]).astype(jnp.int32)
+
+    def _trail_on(self, trail, routes):
+        """The trail behind an expert layer that chose ``routes`` (a tuple
+        of ``[n, k]``): the choices kept, and a riding wave's rows counted
+        apart (a piece touches every held expert: a union would say nothing
+        of the wave)."""
+        trail = {**trail, "routes": trail["routes"] + routes}
+        if "riders" in trail:
+            b = trail["riders"]
+            trail["stats"] = trail["stats"] + sum(
+                self._held_counts(r[-b:], trail["live"][-b:])
+                for r in routes)
+        return trail
 
     def _piece_after(self, lp, x, o, trail):
         x, _, route = self._after_rows(lp, x, o, trail["live"], trail["tile"])
-        return x, {**trail, "routes": trail["routes"] + route}
+        return x, self._trail_on(trail, route)
 
     def _piece_block(self, lp, x, trail):
         x, _, top_i = self._expert_block(lp, x, trail["live"], trail["tile"])
-        return x, {**trail, "routes": trail["routes"] + (top_i,)}
+        return x, self._trail_on(trail, (top_i,))
 
     def _piece_end(self, trail):
-        """The choices ``[expert layers, n, top_k]``."""
+        """(the choices ``[expert layers, n, top_k]``, a riding wave's counts
+        or ``None``)."""
         import jax.numpy as jnp
 
-        return jnp.stack(trail["routes"])
+        return jnp.stack(trail["routes"]), trail.get("stats")
 
     def make_apply_params(self):
         """Full-context forward in the served precision: no cache, no pieces,
@@ -334,7 +369,7 @@ class ExpertDecoder(SeededDecoder):
             ids = inputs["INPUT_IDS"].astype("int32")
             n = ids.shape[0]
             pos = jnp.arange(n)
-            x, routes = self._walk_kinds(
+            x, routes, _ = self._walk_kinds(
                 p, p["embed"][ids].astype(jnp.float32),
                 {"live": jnp.ones(n, bool), "tile": TILE_M_PIECE,
                  "routes": ()},

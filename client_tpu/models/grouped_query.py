@@ -118,7 +118,7 @@ class GroupedQueryPieces:
         return self._full_layer(self._project(lp, x, pos), None)
 
     def _lane_by_lane(self, read, write, qkv, k_a, v_a, ki, rows, starts,
-                      lens):
+                      lens, wave=None):
         """A layer's part of a piece of ``L`` lanes from its projections over
         every lane's positions at once (``qkv``: q ``[L * piece, H, D]``, k, v
         ``[L * piece, Hkv, D]`` float32): the rows as the cache holds them,
@@ -131,13 +131,25 @@ class GroupedQueryPieces:
         choose (left to it, the two-lane program of ``smallthinker_21b``'s
         widths copied a 1.5 GB leaf to keep one lane's reads apart from
         the other's writes; tests/test_tpu_compile.py).  -> (K leaf, V leaf,
-        o ``[L * piece, H * D]``)."""
+        o ``[L * piece, H * D]``).
+
+        **With a wave** (models/decoder.py ``piece_wave``; ``wave``: the
+        wave's step of the layer's kind, its rows ``[B]``, its live rows or
+        lengths, and the layer's weights): ``qkv`` hold the wave's ``B`` rows
+        behind the piece's, and behind the last lane's write (that lane's
+        rows pass the barrier too) the wave's step writes each lane's row
+        into its own slot and reads that slot, its output taken through
+        ``_attention_output`` as a decode step's is; o is then ``[L * piece +
+        B, H * D]``."""
         import jax
         import jax.numpy as jnp
 
         n, (q, k, v) = self.piece, qkv
-        own_k, own_v = self._as_cached(k, v, k_a.dtype)
         lanes, outs = rows.shape[0], []
+        if wave is not None:
+            (q, q_w), (k, k_w), (v, v_w) = (
+                (t[:lanes * n], t[lanes * n:]) for t in qkv)
+        own_k, own_v = self._as_cached(k, v, k_a.dtype)
         for i in range(lanes):
             own = slice(i * n, (i + 1) * n)
             # (The lane's operands in the order the recorded one-lane
@@ -145,19 +157,25 @@ class GroupedQueryPieces:
             at, n_valid = (ki, rows[i], starts[i]), lens[i]
             new_k, new_v = own_k[own], own_v[own]
             o = read(k_a, v_a, *at, q[own], new_k, new_v)
-            if i + 1 < lanes:
+            if i + 1 < lanes or wave is not None:
                 new_k, new_v, o = jax.lax.optimization_barrier(
                     (new_k, new_v, o))
             k_a, v_a = write(k_a, v_a, *at, n_valid, new_k, new_v)
             outs.append(o)
+        if wave is not None:
+            step, w_rows, w_live, lp = wave
+            k_a, v_a, o = step(k_a, v_a, q_w, k_w, v_w, w_rows, w_live, ki)
+            o = self._attention_output(lp, o)
+            outs.append(o.reshape(o.shape[0], -1))
         return k_a, v_a, jnp.concatenate(outs)
 
-    def _piece_rows_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
+    def _piece_rows_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos,
+                          wave=None):
         """A whole-context layer's part of a piece (models/decoder.py
         ``piece_hidden_fn``), by the backend's ``_project(lp, x, pos)``."""
         return self._lane_by_lane(
             self._read_rows, self._write_rows, self._project(lp, x, pos),
-            k_a, v_a, ki, rows, starts, lens)
+            k_a, v_a, ki, rows, starts, lens, wave and (*wave, lp))
 
     def _read_rows(self, k_a, v_a, ki, row, start, q, own_k, own_v):
         """A whole-context layer's part of one lane's piece: q ``[piece, H,
@@ -238,13 +256,14 @@ class RingPieces(GroupedQueryPieces):
 
     # -- a piece's attention over a ring ------------------------------------------
 
-    def _piece_ring_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos):
+    def _piece_ring_layer(self, lp, k_a, v_a, ki, rows, starts, lens, x, pos,
+                          wave=None):
         """A window layer's part of a piece (models/decoder.py
         ``piece_hidden_fn``)."""
         return self._lane_by_lane(
             self._read_ring, self._write_ring,
             self._project(lp, x, pos, "ring"), k_a, v_a, ki, rows, starts,
-            lens)
+            lens, wave and (*wave, lp))
 
     def _full_ring_layer(self, lp, x, pos):
         return self._full_layer(self._project(lp, x, pos, "ring"), self.window)

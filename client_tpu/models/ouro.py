@@ -173,7 +173,7 @@ class OuroBackend(GroupedQueryPieces, SeededDecoder):
 
             ids = inputs["INPUT_IDS"].astype("int32")
             pos = jnp.arange(ids.shape[0])
-            x, _ = self._walk_kinds(
+            x, *_ = self._walk_kinds(
                 p, self._embed(p, ids, pos), None,
                 lambda kind, ki, lp, x: self._full_rows_layer(lp, x, pos))
             return {"logits": self._logits(p, x)}

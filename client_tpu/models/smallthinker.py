@@ -89,6 +89,11 @@ class SmallThinkerBackend(RingPieces, ExpertDecoder):
 
     router_score = "softmax"
     expert_act = "relu"
+    # Every piece program carries a wave of the top bucket: where a token
+    # gap holds a piece, the decoding lanes' next token comes out of the
+    # piece's pass over the weights (models/decoder.py ``piece_wave``;
+    # PERF.md section 6, PR 56).
+    piece_wave = True
 
     def __init__(self, name: str = "smallthinker", n_layers: int = 4,
                  d_model: int = 64, n_heads: int = 4, n_kv_heads: int = 2,

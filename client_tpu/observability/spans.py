@@ -145,6 +145,11 @@ GEN_COUNTERS = (
     # what a piece's attention costs, as the positions say what its products
     # cost.
     "prefill_pairs_window", "prefill_pairs_global",
+    # per decode fetch whose tokens came out of a piece's program (a backend
+    # that declares ``piece_wave``: one program for the piece and the wave of
+    # a token gap; 0 for every other).  Such a wave is a wave: every
+    # ``fetched_*`` and ``gap_*`` counter counts it as a lone one.
+    "fetched_waves_carried",
 )
 (C_DISPATCHES, C_INFLIGHT_WAVES, C_FETCHED_WAVES, C_FETCHED_LANES_LIVE,
  C_FETCHED_LANES_PADDED, C_FETCHED_POSITIONS_VALID, C_DRAINS, C_DRAINS_MULTI,
@@ -159,7 +164,8 @@ GEN_COUNTERS = (
  C_GAP_LANE_BEHIND_PREFILL_NS, C_FETCHED_ROWS_WINDOW, C_FETCHED_ROWS_GLOBAL,
  C_FETCHED_LANES_PAST_WINDOW, C_FETCHED_PASSES,
  C_PREFILL_HEADS, C_PREFILL_PAIRS_WINDOW,
- C_PREFILL_PAIRS_GLOBAL) = range(len(GEN_COUNTERS))
+ C_PREFILL_PAIRS_GLOBAL,
+ C_FETCHED_WAVES_CARRIED) = range(len(GEN_COUNTERS))
 
 # -- Model.execute_timed (trace annotations only) --------------------------------
 

@@ -553,6 +553,7 @@ class TestGapReaders:
 
 LANES = "prefill_lanes_per_call.obs"
 HEADS = "prefill_head_share.itl"
+CARRIED = "wave_carried_share.itl"
 # (pieces, programs) at the window's two ends -> the value by hand.
 LANE_WINDOWS = {
     "every_piece_alone": ((40, 40), (1040, 1040), 1.0),
@@ -588,13 +589,15 @@ class TestLanesPerCall:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         # (Last of the accepted metrics until PR 50 put its two behind it,
-        # PR 51 its one and PR 53 its two.)
-        last = manifest["per_layer"][-6]
+        # PR 51 its one, PR 53 its two and PR 56 its four.)
+        last = manifest["per_layer"][-10]
         stage = next(m for m in manifest["per_layer"]
                      if m["name"] == "prefill_stage_ms_mean.itl")
-        assert [m["name"] for m in manifest["per_layer"][-5:]] == [
+        assert [m["name"] for m in manifest["per_layer"][-9:]] == [
             "loop_dense_roofline.itl", "passes_per_wave.obs", HEADS,
-            "piece_roofline.itl", "dense_branch_roofline.itl"]
+            "piece_roofline.itl", "dense_branch_roofline.itl", CARRIED,
+            "decode_attn_all_roofline.itl", "window_attn_all_roofline.itl",
+            "piece_wave_roofline.itl"]
         assert last == {"name": LANES, "unit": "lanes", "better": "higher",
                         "source": "program_counter",
                         "layer": "generative scheduler",
@@ -646,14 +649,15 @@ class TestHeadShare:
 
     def test_the_manifest_holds_it_last_for_the_cells_of_the_piece_frame(
             self):
-        """Appended behind every accepted metric (PR 53's two stand behind
-        it since), for the cells whose backend runs the decoder's piece frame
+        """Appended behind every accepted metric (PR 53's two and PR 56's
+        four stand behind it since), for the cells whose backend runs the
+        decoder's piece frame
         (``evabyte_6b5.longdoc`` prefills by pieces through a program of its
         own, which takes no ``ends``)."""
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         lanes = next(m for m in manifest["per_layer"] if m["name"] == LANES)
-        assert manifest["per_layer"][-3] == {
+        assert manifest["per_layer"][-7] == {
             "name": HEADS, "unit": "%", "better": "lower",
             "source": "program_counter", "layer": "generative scheduler",
             "moves": "itl_mean_ms",
@@ -661,5 +665,57 @@ class TestHeadShare:
                           if c != "evabyte_6b5.longdoc"]}
         from client_tpu.observability import spans
 
-        assert spans.GEN_COUNTERS[-3:] == (
+        assert spans.GEN_COUNTERS[-4:-1] == (
             "prefill_heads", "prefill_pairs_window", "prefill_pairs_global")
+
+
+# -- PR 56's reader: the waves that rode in a piece's program -------------------
+
+# (waves, carried) at the window's two ends -> the value by hand; carried
+# None: a program that has no such counter.
+CARRIED_WINDOWS = {
+    "three_waves_in_four_rode": ((100, 60), (1100, 810), 75.0),
+    "every_wave_rode": ((100, 60), (300, 260), 100.0),
+    "a_backend_that_carries_and_none_rode": ((100, 60), (300, 60), 0.0),
+    "no_wave_in_the_window": ((100, 60), (100, 60), None),
+    "the_parent_has_no_such_counter": ((100, None), (1100, None), None),
+}
+
+
+class TestCarriedShare:
+    @pytest.mark.parametrize("window", sorted(CARRIED_WINDOWS))
+    def test_carried_over_fetched_waves(self, window):
+        """``fetched_waves_carried`` counts a fetched wave whose tokens came
+        out of a piece's program, ``fetched_waves`` every fetched wave: their
+        ratio in percent, and nothing where no wave was fetched or the
+        program has no such counter (which is not a share of 0)."""
+        def side(waves, carried):
+            counters = {"fetched_waves": waves}
+            if carried is not None:
+                counters["fetched_waves_carried"] = carried
+            return snap({"gen.prefill_dispatch": (40, 9)}, counters)
+
+        a, b, want = CARRIED_WINDOWS[window]
+        got = run_reader(CARRIED)({"snap_before": side(*a),
+                                   "snap_after": side(*b)})
+        assert got is None if want is None else got == pytest.approx(want)
+
+    def test_nothing_from_a_program_without_the_profile(self):
+        read = run_reader(CARRIED)
+        assert read({"snap_before": None, "snap_after": None}) is None
+
+    def test_the_manifest_holds_it_last_for_the_two_cells_that_carry(self):
+        """Appended behind every accepted metric (the three shares that
+        read a carried wave's kernels and its program stand behind it,
+        tests/test_wave_kernel_readers.py), for the cells whose backend's
+        piece programs carry a wave (``piece_wave``)."""
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+        assert manifest["per_layer"][-4] == {
+            "name": CARRIED, "unit": "%", "better": "higher",
+            "source": "program_counter", "layer": "generative scheduler",
+            "moves": "itl_mean_ms",
+            "workloads": ["smallthinker_21b.mixed", "command_a_plus.rag"]}
+        from client_tpu.observability import spans
+
+        assert spans.GEN_COUNTERS[-1] == "fetched_waves_carried"

@@ -334,7 +334,7 @@ def test_the_traffic_file_is_the_issues_cell():
                + manifest["per_layer"]
                if "workloads" not in m or cell["name"] in m["workloads"]}
     assert {"itl_mean_ms", "setup_s", "piece_roofline.itl",
-            "dense_branch_roofline.itl", "step_roofline.itl",
+            "dense_branch_roofline.itl", "step_mfu_roofline.itl",
             "window_attn_roofline.itl", "decode_attn_roofline.itl",
             "experts_touched_share.itl", "prefill_head_share.itl"} <= reports
     # (``expert_ffn_roofline.itl`` pairs the window's mean touched experts

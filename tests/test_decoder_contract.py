@@ -297,11 +297,14 @@ def test_a_backend_with_its_own_piece_program_takes_no_ends(engines):
     assert backend.prefill_piece and not backend.piece_ends
     assert not sched._piece_ends
     assert list(inspect.signature(backend.prefill_fn()).parameters)[-1] \
-        == PREFILL_ARGS[-2] == "starts"
+        == PREFILL_ARGS[-3] == "starts"
     frame = engines("kimi_linear")._schedulers["kimi_linear"]
-    assert frame._piece_ends and PREFILL_ARGS[-1] == "ends"
+    assert frame._piece_ends and PREFILL_ARGS[-2:] == ("ends", "wave")
+    # (Behind ``ends``, the wave that a backend's piece programs carry,
+    # ``piece_wave``: this one's carry none.)
     assert list(inspect.signature(
-        frame.model.backend.prefill_fn()).parameters)[-1] == "ends"
+        frame.model.backend.prefill_fn()).parameters)[-2:] == ["ends", "wave"]
+    assert not frame._piece_wave and not frame.model.backend.piece_wave
     # A one-shot backend's program takes neither.
     shot = engines("tiny_gpt")._schedulers["tiny_gpt"]
     assert not shot._piece_len and not shot._piece_ends

@@ -28,7 +28,12 @@ RECORDED = {
     # (a parallel block over a ring of two pieces and whole-context rows, a
     # group of four query heads a key head, half the experts held: PR 53)
     ("cohere_moe", "decode"): ("781b759572c6fa34", "a9b441acdfa65310"),  # 53, 53
-    ("cohere_moe", "prefill"): ("03df8db14a8ae370", "e1195263e49cba3f"),  # 53, 53
+    # (the piece programs of PR 56, ``cohere_moe``'s and ``smallthinker``'s:
+    # each carries a wave behind the piece's rows, models/decoder.py
+    # ``piece_wave``; their wave programs, and every other backend's
+    # programs, as they were: with no ``wave`` operand the frame traces to
+    # the programs PR 53 and PR 52 recorded, ``PLAIN_PIECES`` below)
+    ("cohere_moe", "prefill"): ("98ba8582adfbc1a9", "54ab1ee336ca57f1"),  # 56, 56
     ("evabyte", "decode"): ("4b633014727aa219", "54792ebb84a37cd7"),  # 44, 44
     ("evabyte", "prefill"): ("f73e0dc2333af8de", "e59f91f604bb6804"),  # 42, 42
     ("gpt", "decode"): ("c7d0eebb4f86770a", "2445b260a28de378"),      # 44, 44
@@ -57,6 +62,17 @@ RECORDED = {
     ("pangu", "prefill"): ("9f81a0e83b3aaa34", "f9c5bf8655c5c096"),   # 51, 49
     ("smallthinker", "decode"): ("44c3bd43dd161186", "93891480d1ba5ee1"),  # 44, 44
     # (``prefill_1`` is what ``prefill`` was until PR 52 declared two lanes)
+    ("smallthinker", "prefill"): ("8a616ab553920ba9", "2b386c908a98390c"),  # 56, 56
+    ("smallthinker", "prefill_1"): ("d16dcb5f1e1712d4", "0340085c96da5db9"),  # 56, 56
+}
+
+# What the piece frame traces to with no ``wave`` operand for the backends
+# whose programs carry one: the programs they served until PR 56 (the PR that
+# last recorded them beside each).  The guard that the frame, the lane walk
+# and the expert layer are what they were for the four families whose piece
+# programs take none.
+PLAIN_PIECES = {
+    ("cohere_moe", "prefill"): ("03df8db14a8ae370", "e1195263e49cba3f"),  # 53, 53
     ("smallthinker", "prefill"): ("1fe2b7a99c70a34e", "65c5487282cc111d"),  # 52, 52
     ("smallthinker", "prefill_1"): ("19097d0c8dec2ec6", "1cfe8973c0312174"),  # 51, 49
 }
@@ -133,11 +149,14 @@ def _backend(family):
     return TinyGptBackend(attention_impl="flash", attn_impl="fused")
 
 
-def _program(family, which):
+def _program(family, which, plain=False):
     """(function, static and donated argument numbers, abstract arguments);
     ``which``: ``decode``, ``prefill`` (a piece backend's declared lanes) or
-    ``prefill_<lanes>``."""
+    ``prefill_<lanes>``; ``plain``: the piece program with no wave operand,
+    of a backend whose programs carry one."""
     be = _backend(family)
+    if plain:
+        be.piece_wave = False
     if family in ("pangu", "kimi", "smallthinker", "nemotron", "ouro",
                   "cohere_moe"):
         # (made when asked for)
@@ -167,10 +186,14 @@ def _program(family, which):
         lanes = int(which.split("_")[1])
     args = (params, arena, i32(lanes), i32(lanes, width), i32(lanes),
             i32(lanes), f32(lanes), i32(lanes), f32(lanes), False)
-    # A piece's ``starts`` and, for the decoder's own piece frame, ``ends``.
+    # A piece's ``starts`` and, for the decoder's own piece frame, ``ends``;
+    # behind them the wave that a backend's piece programs carry, at the top
+    # bucket (``piece_wave``).
+    wave = ((i32(4), i32(4), i32(4), f32(4), i32(4), f32(4)),) if (
+        piece and be.piece_wave) else ()
     return be.prefill_fn(), (be.prefill_static_argnums,
                              be.donate_argnums), args + (
-        (i32(lanes),) * (1 + be.piece_ends) if piece else ())
+        (i32(lanes),) * (1 + be.piece_ends) if piece else ()) + wave
 
 
 def _kernel_bodies(jaxpr, out):
@@ -191,8 +214,8 @@ def _digest(text):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-def hashes(family, which):
-    fn, (static, donated), args = _program(family, which)
+def hashes(family, which, plain=False):
+    fn, (static, donated), args = _program(family, which, plain)
     text = jax.jit(fn, static_argnums=static,
                    donate_argnums=donated).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
@@ -218,6 +241,15 @@ def test_the_program_is_the_one_it_was(on_the_chips_branches, family, which,
     got = hashes(family, which)[part == "kernels"]
     assert got == RECORDED[family, which][part == "kernels"], (
         f"{family}'s {which} {part} changed: if that is meant, record {got}")
+
+
+@pytest.mark.parametrize("part", ["program", "kernels"])
+@pytest.mark.parametrize("family,which", sorted(PLAIN_PIECES))
+def test_with_no_wave_the_frame_traces_to_the_program_it_was(
+        on_the_chips_branches, family, which, part):
+    got = hashes(family, which, plain=True)[part == "kernels"]
+    assert got == PLAIN_PIECES[family, which][part == "kernels"], (
+        f"{family}'s {which} {part} with no wave changed: {got}")
 
 
 def test_the_piece_frame_is_the_decoders():

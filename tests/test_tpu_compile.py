@@ -675,34 +675,42 @@ def test_window_and_global_decoder_compiles_at_published_widths(
     before it (9 a window layer, 32 a global one); sixteen grouped matmuls
     every way: **a piece of two prompts reads a layer's 64 experts once**
     (one plan, one gather and one pair of products for both lanes'
-    positions, a sorted layout of 14336 rows for one of 7104).  No program
-    writes a weight or a cache leaf out again: every matrix is read by its
-    product where it lies, and the donated arena's four leaves are updated
-    in place, the second lane's rows behind the first's in the same
-    leaf."""
+    positions).  **A piece program carries a wave** (PR 56,
+    ``piece_wave``): the full wave's 48 rows behind the piece's, so
+    the wave's eight attention calls beside the flash calls, one sorted
+    layout for the rows of both (7424 rows where the piece alone had 7104,
+    14592 for 14336) and the wave's part of the result behind the
+    piece's.  No program writes a weight or a cache leaf out again: every
+    matrix is read by its product where it lies, and the donated arena's
+    four leaves are updated in place, the second lane's rows behind the
+    first's and the wave's rows behind the piece's in the same leaf."""
     text, arena, memory, backend = _smallthinker_program(
         one_chip, monkeypatch, which, lanes)
     calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
     assert calls.count("grouped_matmul") == 16
+    # (A wave of its own, or the one that rides in the piece's program.)
+    assert calls.count("window_wave_attention") == 6
+    assert calls.count("decode_wave_attention") == 2
+    # 48 tokens, a record row a lane and the wave's three counts.
+    wave = 48 + 48 * backend.stream_record + 3
     if which == "decode":
-        assert calls.count("window_wave_attention") == 6
-        assert calls.count("decode_wave_attention") == 2
-        # 48 tokens, a record row a lane and the wave's three counts.
-        assert f"s32[{48 + 48 * backend.stream_record + 3}]" in text
+        assert f"s32[{wave}]" in text
     else:
         assert calls.count("flash_attention") == lanes * (6 * 9 + 2 * 32)
         # The sorted layout in tiles of 64 rows for one lane's pairs, of 128
-        # for two lanes' (an expert's mean share 48 and 96 rows): the names
-        # of the trace's groups, ``grouped_matmul_f32_7104_*`` and
-        # ``_14336_*``, once a layer each.
-        layout = 7104 if lanes == 1 else 14336
+        # for two lanes' (an expert's mean share 52.5 and 100.5 rows with the
+        # wave's 48 among them): the names of the trace's groups,
+        # ``grouped_matmul_f32_7424_*`` and ``_14592_*``, once a layer each.
+        layout = 7424 if lanes == 1 else 14592
         assert f"bf16[{layout},2560]" in text
         for width in (1536, 2560):
             assert len(set(re.findall(
                 rf"%([\w.\-]+) = f32\[{layout},{width}\][^=]*? "
                 r"custom-call\(", text))) == 8
-        # A token and 512 record rows a lane.
-        assert f"s32[{lanes * (1 + 512 * backend.stream_record)}]" in text
+        # A token and 512 record rows a lane, and the wave's part behind
+        # them: one result.
+        piece = lanes * (1 + 512 * backend.stream_record)
+        assert f"s32[{piece + wave}]" in text
     weights = (r"2560,3584|3584,2560|2560,512|64,2560,1536|64,768,2560"
                r"|151936,2560|2560,151936")
     leaves = r"[26],49,(?:4096|16384),512"
@@ -719,10 +727,13 @@ def test_window_and_global_decoder_compiles_at_published_widths(
     cache = sum(math.prod(arena[k].shape) * 2 for k in ("kg", "vg", "kw",
                                                          "vw"))
     assert memory.alias_size_in_bytes >= cache
+    print(f"smallthinker {which} x{lanes}: temporaries "
+          f"{memory.temp_size_in_bytes}")
     # A wave's temporaries are its activations (14 MB); a piece's the rows
     # before it (a global layer's 15872 x 512 of K and of V), its scores'
-    # operands and the sorted layout's 7104 rows (57 MB; 215 MB with two
-    # lanes' 14336 rows, gathers and projections): far under one ring
+    # operands and the sorted layout's 7424 rows (100 MB with the wave it
+    # holds; 203 MB with two lanes' 14592 rows, gathers and projections;
+    # 57 and 215 MB before a wave rode): far under one ring
     # leaf's 1.2 GB or one layer's 0.8 GB of matrices, either of which a
     # lane walk the compiler may reorder costs (models/grouped_query.py
     # ``_lane_by_lane``).
@@ -779,10 +790,16 @@ def _piece_backend_program(one_chip, monkeypatch, backend, which, lanes=1):
             spans.named_step(backend.prefill_fn(), spans.STEP_PREFILL),
             donate_argnums=backend.donate_argnums,
             static_argnums=backend.prefill_static_argnums)
+        wave = ()
+        if backend.piece_wave:
+            # The wave that rides in the program, at the top bucket.
+            wave_i = place((backend.max_streams,), jnp.int32)
+            wave_f = place((backend.max_streams,), jnp.float32)
+            wave = ((wave_i, wave_i, wave_i, wave_f, wave_i, wave_f),)
         lowered = step.lower(params, arena, lane_i,
                              place((lanes, piece), jnp.int32), lane_i,
                              lane_i, lane_f, lane_i, lane_f, False, lane_i,
-                             lane_i)
+                             lane_i, *wave)
     t0 = time.monotonic()
     compiled = lowered.compile()
     return (compiled.as_text(), arena, compiled.memory_analysis(),
@@ -910,11 +927,14 @@ def test_a_piece_computes_its_head_under_one_conditional(
         one_chip, monkeypatch, family, lanes):
     """The compiled piece program of ``smallthinker_21b``'s and
     ``nemotron3_nano_30b``'s widths holds **one** conditional of two branches
-    beside its flash calls' switches (a branch a count of rows): the head's.
-    Whatever has the vocabulary's dimension (the product with the head's
-    matrix, the logits, the token choice) stands in the branch that a lane's
-    end takes, the other holds no product at all, and the donated arena does
-    not pass through either: the program's temporaries are the parent's and
+    beside its flash calls' switches (a branch a count of rows): the head's,
+    which in ``smallthinker_21b``'s is the head of the wave that rides in the
+    program too (PR 56: one product over the vocabulary's matrix for the
+    lanes' last rows and the wave's, under "a lane ends or a wave lane is
+    live").  Whatever has the vocabulary's dimension (the product with the
+    head's matrix, the logits, the token choice) stands in the branch that
+    takes, the other branch holds no product at all, and the donated arena
+    passes through neither: the program's temporaries are the parent's and
     at most a row of logits a lane more."""
     if family == "smallthinker":
         text, arena, memory, _ = _smallthinker_program(one_chip, monkeypatch,
@@ -929,8 +949,8 @@ def test_a_piece_computes_its_head_under_one_conditional(
                           r"\{([^}]*)\}", text)
                if branches.count("%") == 2]
     assert len(two_way) == 1, two_way
-    skip, head = two_way[0]          # (pred is false, pred is true)
-    under = _reached_from(comps, head)
+    # (pred is false, pred is true) of each.
+    under = set().union(*(_reached_from(comps, head) for _, head in two_way))
     fused = set(re.findall(r" fusion\([^\n]*?calls=%([\w.\-]+)", text))
     reads_head = {name for name, comp in comps.items()
                   if re.search(rf"\(param[^\n]*?bf16\[{width},{vocab}\]",
@@ -944,26 +964,36 @@ def test_a_piece_computes_its_head_under_one_conditional(
             rf"^\s*(?:ROOT )?%[\w.\-]+ = [^=\n]*?\b{vocab}\b[^=\n]*? "
             rf"([\w\-]+)\(", comp, re.M)
         assert set(shaped) <= set(passes_on), (name, shaped)
-    for name in _reached_from(comps, skip):
-        assert not re.search(r" (?:dot|convolution|custom-call|reduce)\(",
-                             comps[name]), name
-    # No leaf of the arena is an operand of the conditional.
-    (operands,) = re.findall(
-        r" conditional\(([^\n]*?)\), branch_computations=\{%"
-        + re.escape(skip), text)
     leaves = {math.prod(leaf.shape) for leaf in arena.values()
               if leaf.ndim > 1}
-    for operand in re.findall(r"%([\w.\-]+)", operands):
-        made = re.search(rf"%{re.escape(operand)} = (\(?[^=\n]*?) [\w\-]+\(",
-                         text).group(1)
-        for dims in re.findall(r"\w+\[([\d,]+)\]", made):
-            assert math.prod(int(d) for d in dims.split(",")) not in leaves, \
-                (operand, made)
+    plain = re.sub(r"/\*index=\d+\*/", "", text)   # (a long tuple's marks)
+    for skip, _ in two_way:
+        for name in _reached_from(comps, skip):
+            assert not re.search(
+                r" (?:dot|convolution|custom-call|reduce)\(",
+                comps[name]), name
+        # No leaf of the arena is an operand of the conditional.
+        (operands,) = re.findall(
+            r" conditional\(([^\n]*?)\), branch_computations=\{%"
+            + re.escape(skip), text)
+        for operand in re.findall(r"%([\w.\-]+)", operands):
+            made = re.search(
+                rf"%{re.escape(operand)} = (\(?[^=\n]*?) [\w\-]+\(",
+                plain).group(1)
+            for dims in re.findall(r"\w+\[([\d,]+)\]", made):
+                assert math.prod(int(d) for d in dims.split(",")) \
+                    not in leaves, (operand, made)
     if memory is None:
         pytest.skip("this backend reports no memory analysis")
     print(f"{family} x{lanes}: temporaries {memory.temp_size_in_bytes} "
           f"({before} before)")
-    assert memory.temp_size_in_bytes <= before + lanes * vocab * 4, memory
+    allowed = before + lanes * vocab * 4
+    if family == "smallthinker":
+        # The wave that rides (PR 56): a row of float32 logits a lane of its
+        # 48 more under the one conditional (29.2 MB; the one-lane program
+        # reads 6.0 MB over ``before``, the two-lane one under it).
+        allowed += 48 * vocab * 4
+    assert memory.temp_size_in_bytes <= allowed, memory
 
 
 # -- a layer stack that runs four passes over one set of weights (PR 50) --------
@@ -1032,10 +1062,12 @@ def test_parallel_block_decoder_compiles_at_published_widths(
     slots of 3 x 4096 ring rows and 25600 rows): a wave is three ring calls
     and one whole-context call with grouped-query rows of 1024 lanes and
     eight grouped matmuls; a piece holds, a lane, a flash call for every
-    count of rows before it (9 a window layer, 50 the full one).  The tied
-    head is the embedding where it lies: no program writes a weight or a
-    cache leaf out again, and the donated arena's four leaves are updated in
-    place."""
+    count of rows before it (9 a window layer, 50 the full one) and, since
+    PR 56 (``piece_wave``), the full wave's 24 rows behind its own:
+    the wave's four attention calls and its result beside the piece's, one
+    sorted layout for the rows of both.  The tied head is the embedding
+    where it lies: no program writes a weight or a cache leaf out again,
+    and the donated arena's four leaves are updated in place."""
     from client_tpu.models.cohere_moe import CohereMoeBackend
 
     backend = CohereMoeBackend(name="c", **COHERE)
@@ -1045,18 +1077,22 @@ def test_parallel_block_decoder_compiles_at_published_widths(
           f"{memory}")
     calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
     assert calls.count("grouped_matmul") == 8
+    # (A wave of its own, or the one that rides in the piece's program.)
+    assert calls.count("window_wave_attention") == 3
+    assert calls.count("decode_wave_attention") == 1
+    # 24 tokens, a record row a lane and the wave's three counts.
+    wave = 24 + 24 * backend.stream_record + 3
     if which == "decode":
-        assert calls.count("window_wave_attention") == 3
-        assert calls.count("decode_wave_attention") == 1
-        # 24 tokens, a record row a lane and the wave's three counts.
-        assert f"s32[{24 + 24 * backend.stream_record + 3}]" in text
+        assert f"s32[{wave}]" in text
         # The groups of ``expert_ffn_roofline.itl``: 432 rows of sorted
         # layout.
         for width in (8192, 4096):
             assert re.search(rf"f32\[432,{width}\][^=]*? custom-call\(", text)
     else:
         assert calls.count("flash_attention") == lanes * (3 * 9 + 50)
-        assert f"s32[{lanes * (1 + 512 * backend.stream_record)}]" in text
+        # One result: the piece's part and the wave's behind it.
+        piece = lanes * (1 + 512 * backend.stream_record)
+        assert f"s32[{piece + wave}]" in text
     weights = (r"4096,16384|16384,4096|4096,1024|16,4096,8192|16,4096,4096"
                r"|4096,32768|32768,4096")
     leaves = r"[13],25,(?:4096|25600),1024"
