@@ -139,6 +139,7 @@ def ensure_backend(hard_timeout_s: float = 300.0) -> list:
             install_compile_listener, profiler)
 
         install_compile_listener()
+        profiler().record_process_start(int(t0 * 1e9))
         profiler().record_startup(spans.STARTUP_BACKEND_INIT,
                                   int(t0 * 1e9), time.monotonic_ns())
         log.info("JAX backend ready in %.1fs: platform=%s device_kind=%s "

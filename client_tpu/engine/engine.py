@@ -460,7 +460,8 @@ class TpuEngine:
             for sched in new_scheds:
                 sched.warmup()
             self.profiler.record_startup(spans.STARTUP_WARMUP + name,
-                                         t_loaded, time.monotonic_ns())
+                                         t_loaded, time.monotonic_ns(),
+                                         rest=spans.STARTUP_FIRST_RUN + name)
 
     def unload_model(self, name: str, unload_dependents: bool = False) -> None:
         dependents: list[str] = []

@@ -31,8 +31,13 @@ of a few dict operations per *batch* (not per request):
 - **The generative scheduler's clock** — loop-phase spans and lane counters
   of every ``GenerativeScheduler`` worker (vocabulary and recorder in
   :mod:`client_tpu.observability.spans`), committed per loop iteration and
-  served as each model's ``generative`` object; the launcher's set-up
-  phases as the snapshot's ``startup`` list.
+  served as each model's ``generative`` object.
+- **The set-up timeline** — the snapshot's ``startup`` list: the launcher's
+  phases (``startup.*``: the process's own start, backend init, imports,
+  model load, warm-up and what of it is no compilation, frontends) and the
+  three phases of every compilation (``compile.trace``, ``compile.lower``,
+  ``compile.backend``, from the same ``jax.monitoring`` listener), all on
+  ``time.monotonic_ns()``, which every process of the machine shares.
 - **Device duty-cycle** — a sliding window (default 60 s,
   ``CLIENT_TPU_PROFILE_WINDOW_S``) of executable-busy intervals, sampled
   at scrape time into the ``tpu_device_duty_cycle`` gauge (busy device
@@ -72,6 +77,16 @@ COMPILE_SECONDS_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 # Decode wave steps: ~1-3 ms on TPU, tens of ms on the CPU test backend.
 WAVE_SECONDS_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
                         0.05, 0.1, 0.25, 1.0)
+
+# The set-up timeline's bound.  A launch of a benchmark cell is 46-92 spans
+# (13-18 programs x three compile spans and the first runs between them,
+# half a dozen ``startup.*``); the rest is room for reloads and for compiles
+# under traffic.  What it refuses is counted (``startup_clock.dropped``).
+_STARTUP_SPANS_MAX = 1024
+# A stretch of a warm-up between two compile spans shorter than this is the
+# interpreter between two phases of one compilation, not a program's first
+# run: it gets no span of its own.
+_FIRST_RUN_MIN_NS = 1_000_000
 
 # EWMA smoothing for per-call device/host time (~last 10 calls dominate).
 _EWMA_ALPHA = 0.2
@@ -302,11 +317,18 @@ class EfficiencyProfiler:
         # Every backend compilation the jax.monitoring listener heard.
         self._compile_count = 0
         self._compile_s = 0.0
+        self._trace_s = 0.0
+        self._lower_s = 0.0
+        self._cache_hits = 0
         self._cache_misses = 0
-        self._compile_scopes: dict[str, list] = {}  # scope -> [n, seconds]
-        # Set-up phases: (name, start mono ns, end mono ns), oldest first;
+        # scope -> [backend compiles, their seconds, trace s, lower s, hits]
+        self._compile_scopes: dict[str, list] = {}
+        # The set-up timeline: (name, start mono ns, end mono ns, attrs) in
+        # the order recorded; ``attrs`` is None for a ``startup.*`` phase and
+        # a compile span's cause, scope and program otherwise.  Served
         # relative to the launcher's entry once that is marked.
-        self._startup: list[tuple[str, int, int]] = []
+        self._startup: list[tuple[str, int, int, dict | None]] = []
+        self._startup_dropped = 0
         self._startup_t0: int | None = None
 
     # -- metric binding ------------------------------------------------------
@@ -419,39 +441,79 @@ class EfficiencyProfiler:
                        bucket=key[2], compile_s=round(compile_ns / 1e9, 3))
 
     def record_backend_compile(self, seconds: float,
-                               scope: tuple | None = None) -> None:
+                               scope: tuple | None = None,
+                               hit: bool = False) -> None:
         """One XLA backend compilation, as ``jax.monitoring`` reported it
         (:func:`install_compile_listener`); ``scope`` is the compiling
         thread's ``(model, version, step, bucket)`` or None outside any jit
-        call site the program brackets."""
-        model, version, step, bucket = scope or ("", "", "", "")
-        key = f"{model}:{version}:{step}:{bucket}" if scope else ""
+        call site the program brackets; ``hit`` where the persistent cache
+        held the program and the seconds were its load."""
+        model, version, _, bucket = scope or ("", "", "", "")
         with self._lock:
             self._compile_count += 1
             self._compile_s += seconds
-            row = self._compile_scopes.setdefault(key, [0, 0.0])
+            self._cache_hits += hit
+            row = self._scope_row(scope)
             row[0] += 1
             row[1] += seconds
+            row[4] += hit
         for b in self._bindings():
             b.compilations.inc(model=str(model), version=str(version),
                                bucket=str(bucket))
             b.compile_seconds.observe(seconds, model=str(model),
                                       version=str(version))
 
+    def _scope_row(self, scope: tuple | None) -> list:
+        return self._compile_scopes.setdefault(
+            _scope_key(scope), [0, 0.0, 0.0, 0.0, 0])
+
+    def record_compile_span(self, name: str, start_ns: int, end_ns: int,
+                            scope: tuple | None = None, fun_name: str = "",
+                            hit: bool = False,
+                            retrieval_s: float | None = None) -> None:
+        """One phase of one compilation (``spans.COMPILE_*``), monotonic ns,
+        onto the set-up timeline and into the ``compiles`` sums.  ``hit`` and
+        ``retrieval_s`` (the seconds of the span that read the persistent
+        cache's entry) belong to a ``compile.backend`` span."""
+        seconds = (end_ns - start_ns) / 1e9
+        attrs = {"cause": None, "scope": _scope_key(scope),
+                 "fun_name": fun_name}
+        if name == _spans.COMPILE_BACKEND:
+            attrs["cache"] = "hit" if hit else "miss"
+            if retrieval_s is not None:
+                attrs["retrieval_s"] = retrieval_s
+            self.record_backend_compile(seconds, scope, hit)
+        with self._lock:
+            if name == _spans.COMPILE_TRACE:
+                self._trace_s += seconds
+                self._scope_row(scope)[2] += seconds
+            elif name == _spans.COMPILE_LOWER:
+                self._lower_s += seconds
+                self._scope_row(scope)[3] += seconds
+            self._append_span(name, start_ns, end_ns, attrs)
+
     def record_cache_miss(self) -> None:
         with self._lock:
             self._cache_misses += 1
 
     def compile_totals(self) -> dict:
-        """The snapshot's ``compiles`` object: every backend compilation
-        since process start, and by the scope it happened in."""
+        """The snapshot's ``compiles`` object: every compilation since
+        process start (``count``, ``seconds``: the backend's, a cache load
+        included), the tracing and lowering before it, and all of it by the
+        scope it happened in."""
         with self._lock:
             return {
                 "count": self._compile_count,
                 "seconds": self._compile_s,
+                "trace_seconds": self._trace_s,
+                "lower_seconds": self._lower_s,
+                "cache_hits": self._cache_hits,
                 "cache_misses": self._cache_misses,
-                "by_scope": {k: {"count": n, "seconds": sec} for k, (n, sec)
-                             in sorted(self._compile_scopes.items())},
+                "by_scope": {
+                    k: {"count": n, "seconds": sec, "trace_s": trace_s,
+                        "lower_s": lower_s, "hits": hits}
+                    for k, (n, sec, trace_s, lower_s, hits)
+                    in sorted(self._compile_scopes.items())},
             }
 
     def commit_generative(self, model: str, version, rec) -> None:
@@ -466,14 +528,65 @@ class EfficiencyProfiler:
             tot.add(rec)
 
     def startup_entry(self) -> None:
-        """The launcher's entry: set-up spans are reported relative to it."""
+        """The launcher's entry: set-up spans are reported relative to it,
+        and where nothing was recorded before it (no wrapper initialised the
+        backend first) ``startup.process`` ends here."""
         self._startup_t0 = self._now()
+        self.record_process_start(self._startup_t0)
 
-    def record_startup(self, name: str, start_ns: int, end_ns: int) -> None:
-        """One set-up phase (``spans.STARTUP_*``), monotonic ns."""
+    def record_process_start(self, until_ns: int) -> None:
+        """``startup.process``: from the operating system's start of this
+        process to ``until_ns``, where the first set-up phase starts: the
+        interpreter and the imports before it.  Recorded once, by whichever
+        comes first of the launcher's entry and ``ensure_backend``."""
+        born = process_start_ns()
+        if born is None or born > until_ns:
+            return
         with self._lock:
-            if len(self._startup) < 256:  # reloads must not grow it forever
-                self._startup.append((name, int(start_ns), int(end_ns)))
+            if all(attrs is not None for *_, attrs in self._startup):
+                self._append_span(_spans.STARTUP_PROCESS, born, int(until_ns))
+
+    def record_startup(self, name: str, start_ns: int, end_ns: int,
+                       rest: str | None = None) -> None:
+        """One set-up phase (``spans.STARTUP_*``), monotonic ns.  The compile
+        spans inside it that no phase has claimed yet take it as their cause.
+        Where ``rest`` names a span, every stretch of the phase under no
+        compile span is recorded under that name (``startup.first_run:`` of a
+        warm-up: a program's first execution and the staging for it), so the
+        children partition the parent."""
+        start_ns, end_ns = int(start_ns), int(end_ns)
+        with self._lock:
+            inside = []
+            for _, a, b, attrs in self._startup:
+                if attrs is not None and start_ns <= a and b <= end_ns:
+                    inside.append((a, b))
+                    if attrs["cause"] is None:
+                        attrs["cause"] = name
+            if rest:
+                for a, b in _uncovered(start_ns, end_ns, inside):
+                    if b - a >= _FIRST_RUN_MIN_NS:
+                        self._append_span(rest, a, b)
+            self._append_span(name, start_ns, end_ns)
+
+    def record_startup_since_last(self, name: str) -> None:
+        """A set-up phase that runs from the end of the latest ``startup.*``
+        phase to now: what the launcher did between two recorded phases
+        (``startup.imports``: the zoo's import, the arguments, the
+        repository's build, and whatever a wrapper did before the entry)."""
+        now = self._now()
+        with self._lock:
+            last = max((b for *_, b, attrs in self._startup
+                        if attrs is None), default=None)
+        if last is not None and last <= now:
+            self.record_startup(name, last, now)
+
+    def _append_span(self, name: str, start_ns: int, end_ns: int,
+                     attrs: dict | None = None) -> None:
+        # The caller holds the lock.
+        if len(self._startup) < _STARTUP_SPANS_MAX:
+            self._startup.append((name, start_ns, end_ns, attrs))
+        else:
+            self._startup_dropped += 1
 
     def record_cost_model(self, model: str, version, bucket: int | None,
                           cost: dict | None, axis: str = "rows") -> None:
@@ -659,7 +772,9 @@ class EfficiencyProfiler:
                      sorted(w.recent), w.dispatches, w.cost_model))
                 for k, w in self._waves.items())
             gen_items = sorted((k, g.as_dict()) for k, g in self._gen.items())
-            startup = list(self._startup)
+            startup = [(name, a, b, attrs and dict(attrs))
+                       for name, a, b, attrs in self._startup]
+            dropped = self._startup_dropped
         models: dict[str, dict] = {}
         # Per-model roofline accumulators: [flops, bytes, wasted_flops,
         # covered_device_s] summed over buckets+waves with cost models.
@@ -779,7 +894,7 @@ class EfficiencyProfiler:
             "duty_cycle": round(self.duty_cycle(), 6),
             "roofline": ctx,
             "compiles": self.compile_totals(),
-            "startup": _startup_spans(startup, self._startup_t0),
+            **_startup_timeline(startup, self._startup_t0, dropped),
             "models": models,
         }
 
@@ -830,15 +945,57 @@ def _model_roofline(agg: list[float], device_s: float, peaks) -> dict:
     return out
 
 
-def _startup_spans(spans: list, t0: int | None) -> list[dict]:
-    """``(name, start_s, end_s)`` relative to the launcher's entry (to the
-    first span's start where no launcher marked one: an embedded engine).
-    A phase that ran before the entry (a wrapper that initialised the
-    backend first) starts below zero."""
+def _startup_timeline(spans: list, t0: int | None, dropped: int) -> dict:
+    """The snapshot's ``startup`` list, ``(name, start_s, end_s)`` and a
+    compile span's attributes, relative to the launcher's entry (to the
+    earliest start where no launcher marked one: an embedded engine), and
+    ``startup_clock``: that entry as an absolute ``time.monotonic()``, which
+    is one clock for every process of the machine, and the spans the list's
+    bound refused.  A phase that ran before the entry (the process's own
+    start; a wrapper that initialised the backend first) starts below
+    zero."""
     if t0 is None:
-        t0 = min((s for _, s, _ in spans), default=0)
-    return [{"name": name, "start_s": (s - t0) / 1e9, "end_s": (e - t0) / 1e9}
-            for name, s, e in spans]
+        t0 = min((a for _, a, _, _ in spans), default=0)
+    return {
+        "startup": [{"name": name, "start_s": (a - t0) / 1e9,
+                     "end_s": (b - t0) / 1e9, **(attrs or {})}
+                    for name, a, b, attrs in spans],
+        "startup_clock": {"entry_monotonic_s": t0 / 1e9, "dropped": dropped},
+    }
+
+
+def _uncovered(start: int, end: int, intervals: list) -> list[tuple]:
+    """The stretches of ``[start, end]`` that none of ``intervals`` covers."""
+    out, at = [], start
+    for a, b in sorted(intervals):
+        if a > at:
+            out.append((at, a))
+        at = max(at, b)
+    if end > at:
+        out.append((at, end))
+    return out
+
+
+def _scope_key(scope: tuple | None) -> str:
+    """``by_scope``'s key: ``model:version:step:bucket``, "" outside any."""
+    return ":".join(map(str, scope)) if scope else ""
+
+
+def process_start_ns() -> int | None:
+    """When the operating system started this process, on
+    ``time.monotonic_ns()``'s clock: the start time of ``/proc/self/stat``
+    (clock ticks after boot) against the boot clock now.  None where the
+    system does not say."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            stat = f.read()
+        # Field 22, counted behind the command's closing parenthesis.
+        ticks = int(stat[stat.rindex(b")") + 2:].split()[19])
+        age_ns = (time.clock_gettime_ns(time.CLOCK_BOOTTIME)
+                  - ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.monotonic_ns() - age_ns
 
 
 def _suggest_bucket_tweak(buckets: list[dict]) -> dict | None:
@@ -941,9 +1098,20 @@ def reset_profiler() -> None:
 
 # -- the compile listener -------------------------------------------------------
 
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_COMPILE_SPAN_OF = {TRACE_EVENT: _spans.COMPILE_TRACE,
+                    LOWER_EVENT: _spans.COMPILE_LOWER,
+                    BACKEND_COMPILE_EVENT: _spans.COMPILE_BACKEND}
 
+# The compiling thread's own: ``value`` (the scope), and between JAX's events
+# of one compilation ``open`` (how many compile phases the thread is inside),
+# ``hit`` and ``retrieval_s`` (what the persistent cache said inside the
+# backend span).
 _scope = threading.local()
 _listener_installed = False
 
@@ -959,14 +1127,47 @@ def clear_compile_scope() -> None:
     _scope.value = None
 
 
+def _on_compile_start(event: str, value: float, **_) -> None:
+    # JAX reports a phase's start as a scalar.  A jitted function traced
+    # inside another's trace, or inside a lowering (a Pallas kernel's body
+    # traces a jnp function an operator, hundreds a program), reports a trace
+    # span of its own inside the outer phase: the count of open phases tells
+    # the outermost, the one the timeline keeps.
+    if event in _COMPILE_SPAN_OF:
+        _scope.open = getattr(_scope, "open", 0) + 1
+
+
+def _on_compile_span(event: str, start_time: float, end_time: float,
+                     fun_name: str = "", **_) -> None:
+    name = _COMPILE_SPAN_OF.get(event)
+    if name is None:
+        return
+    _scope.open = max(0, getattr(_scope, "open", 0) - 1)
+    if name == _spans.COMPILE_TRACE and _scope.open:
+        return
+    hit, retrieval_s = False, None
+    if name == _spans.COMPILE_BACKEND:
+        hit = getattr(_scope, "hit", False)
+        retrieval_s = getattr(_scope, "retrieval_s", None)
+        _scope.hit, _scope.retrieval_s = False, None
+    # JAX stamps time.time(); the timeline is on time.monotonic_ns().
+    # tpulint: allow[wall-clock] the offset that moves JAX's wall stamps onto the monotonic clock
+    to_monotonic = time.monotonic_ns() - time.time_ns()
+    profiler().record_compile_span(
+        name, int(start_time * 1e9) + to_monotonic,
+        int(end_time * 1e9) + to_monotonic,
+        getattr(_scope, "value", None), str(fun_name), hit, retrieval_s)
+
+
 def _on_compile_duration(event: str, duration_secs: float, **_) -> None:
-    if event == BACKEND_COMPILE_EVENT:
-        profiler().record_backend_compile(
-            duration_secs, getattr(_scope, "value", None))
+    if event == CACHE_RETRIEVAL_EVENT:
+        _scope.retrieval_s = duration_secs
 
 
 def _on_compile_event(event: str, **_) -> None:
-    if event == CACHE_MISS_EVENT:
+    if event == CACHE_HIT_EVENT:
+        _scope.hit = True
+    elif event == CACHE_MISS_EVENT:
         profiler().record_cache_miss()
 
 
@@ -981,5 +1182,7 @@ def install_compile_listener() -> None:
         _listener_installed = True
     import jax.monitoring as monitoring
 
+    monitoring.register_scalar_listener(_on_compile_start)
+    monitoring.register_event_time_span_listener(_on_compile_span)
     monitoring.register_event_duration_secs_listener(_on_compile_duration)
     monitoring.register_event_listener(_on_compile_event)

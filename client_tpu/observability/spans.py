@@ -1,7 +1,7 @@
 """The program's own span vocabulary, and the recorder a worker thread
 writes it with.
 
-Three families of names, constants here and nowhere else:
+Four families of names, constants here and nowhere else:
 
 - ``gen.*`` — the phases of one ``GenerativeScheduler._worker_loop``
   iteration.  Always on: every span is (count, total ns, max ns) per model,
@@ -18,7 +18,11 @@ Three families of names, constants here and nowhere else:
 - ``exec.*`` — the batcher's three phases inside ``Model.execute_timed``.
   Their aggregate times already live in the profiler's bucket table
   (``host_s``/``device_s``); the names exist for the device trace.
-- ``startup.*`` — the launcher's set-up phases (``/v2/profile`` ``startup``).
+- ``startup.*`` — the launcher's set-up phases (``/v2/profile`` ``startup``),
+  from the operating system's start of the process to "serving".
+- ``compile.*`` — the three phases of one compilation (trace, lower, backend),
+  one span each on the same list whenever JAX reports one, with the compiling
+  thread's scope and the program's name.
 
 While a device trace is active (``TraceManager`` flips :func:`set_trace_active`)
 each ``gen.*``/``exec.*`` span is also a ``jax.profiler.TraceAnnotation`` of
@@ -175,10 +179,19 @@ EXEC_FETCH = "exec.fetch"
 
 # -- set-up phases ------------------------------------------------------------
 
+STARTUP_PROCESS = "startup.process"          # the OS's start to the first
 STARTUP_BACKEND_INIT = "startup.backend_init"
+STARTUP_IMPORTS = "startup.imports"          # the last span's end to the engine
 STARTUP_MODEL_LOAD = "startup.model_load:"   # + model name
 STARTUP_WARMUP = "startup.warmup:"           # + model name
+STARTUP_FIRST_RUN = "startup.first_run:"     # + model name: warm-up less compiles
 STARTUP_FRONTENDS = "startup.frontends"
+
+# One compilation's phases as JAX reports them (``jax.monitoring`` time
+# spans), on the set-up timeline whenever they happen.
+COMPILE_TRACE = "compile.trace"      # Python function -> jaxpr
+COMPILE_LOWER = "compile.lower"      # jaxpr -> MLIR module
+COMPILE_BACKEND = "compile.backend"  # XLA, or the persistent cache's load
 
 # -- jitted steps ---------------------------------------------------------------
 

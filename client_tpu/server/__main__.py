@@ -41,8 +41,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
 
-    # Set-up phases (backend init, model load, warm-up, frontends) are
-    # spans of /v2/profile "startup", relative to this moment.
+    # Set-up phases (the process's start, backend init, imports, model
+    # load, warm-up, frontends) are spans of /v2/profile "startup", relative
+    # to this moment.
     from client_tpu.observability import spans
     from client_tpu.observability.profiler import profiler
 
@@ -78,6 +79,7 @@ def main(argv: list[str] | None = None) -> int:
     else:
         repo = build_repository(zoo_names, jit=jit)
 
+    profiler().record_startup_since_last(spans.STARTUP_IMPORTS)
     engine = TpuEngine(repo, jit=jit, warmup=args.warmup)
     for entry in engine.repository_index():
         line = f"model {entry['name']}: {entry['state']}"

@@ -589,11 +589,12 @@ class TestLanesPerCall:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         # (Last of the accepted metrics until PR 50 put its two behind it,
-        # PR 51 its one, PR 53 its two and PR 56 its four.)
-        last = manifest["per_layer"][-10]
+        # PR 51 its one, PR 53 its two, PR 56 its four and PR 57 the seven
+        # of the set-up timeline.)
+        last = manifest["per_layer"][-17]
         stage = next(m for m in manifest["per_layer"]
                      if m["name"] == "prefill_stage_ms_mean.itl")
-        assert [m["name"] for m in manifest["per_layer"][-9:]] == [
+        assert [m["name"] for m in manifest["per_layer"][-16:-7]] == [
             "loop_dense_roofline.itl", "passes_per_wave.obs", HEADS,
             "piece_roofline.itl", "dense_branch_roofline.itl", CARRIED,
             "decode_attn_all_roofline.itl", "window_attn_all_roofline.itl",
@@ -649,15 +650,16 @@ class TestHeadShare:
 
     def test_the_manifest_holds_it_last_for_the_cells_of_the_piece_frame(
             self):
-        """Appended behind every accepted metric (PR 53's two and PR 56's
-        four stand behind it since), for the cells whose backend runs the
+        """Appended behind every accepted metric (PR 53's two, PR 56's
+        four and PR 57's seven stand behind it since), for the cells whose
+        backend runs the
         decoder's piece frame
         (``evabyte_6b5.longdoc`` prefills by pieces through a program of its
         own, which takes no ``ends``)."""
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         lanes = next(m for m in manifest["per_layer"] if m["name"] == LANES)
-        assert manifest["per_layer"][-7] == {
+        assert manifest["per_layer"][-14] == {
             "name": HEADS, "unit": "%", "better": "lower",
             "source": "program_counter", "layer": "generative scheduler",
             "moves": "itl_mean_ms",
@@ -707,11 +709,12 @@ class TestCarriedShare:
     def test_the_manifest_holds_it_last_for_the_two_cells_that_carry(self):
         """Appended behind every accepted metric (the three shares that
         read a carried wave's kernels and its program stand behind it,
-        tests/test_wave_kernel_readers.py), for the cells whose backend's
+        tests/test_wave_kernel_readers.py, and PR 57's seven of the set-up
+        timeline behind those), for the cells whose backend's
         piece programs carry a wave (``piece_wave``)."""
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
-        assert manifest["per_layer"][-4] == {
+        assert manifest["per_layer"][-11] == {
             "name": CARRIED, "unit": "%", "better": "higher",
             "source": "program_counter", "layer": "generative scheduler",
             "moves": "itl_mean_ms",

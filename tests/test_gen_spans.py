@@ -898,8 +898,10 @@ class TestCompileCounter:
         c = p.snapshot()["compiles"]
         assert c["count"] == 2 and c["seconds"] == pytest.approx(0.75)
         assert c["by_scope"] == {
-            "": {"count": 1, "seconds": 0.25},
-            "m:1:apply:8": {"count": 1, "seconds": 0.5}}
+            "": {"count": 1, "seconds": 0.25, "trace_s": 0.0,
+                 "lower_s": 0.0, "hits": 0},
+            "m:1:apply:8": {"count": 1, "seconds": 0.5, "trace_s": 0.0,
+                            "lower_s": 0.0, "hits": 0}}
         text = reg.render()
         assert 'tpu_xla_compilations_total{model="m",version="1",' \
                'bucket="8"} 1' in text
@@ -973,12 +975,17 @@ class TestStartupSpans:
         finally:
             proc.send_signal(signal.SIGTERM)
             proc.wait(timeout=60)
-        by = {s["name"]: s for s in snap["startup"]}
-        assert list(by) == ["startup.backend_init",
+        phases = [s for s in snap["startup"]
+                  if s["name"].startswith("startup.")
+                  and not s["name"].startswith(spans.STARTUP_FIRST_RUN)]
+        by = {s["name"]: s for s in phases}
+        assert list(by) == ["startup.process", "startup.imports",
+                            "startup.backend_init",
                             "startup.model_load:simple",
                             "startup.warmup:simple", "startup.frontends"]
+        assert by["startup.process"]["end_s"] == 0  # it ends at the entry
         assert by["startup.backend_init"]["start_s"] >= 0  # after the entry
-        ends = [s["end_s"] for s in snap["startup"]]
+        ends = [s["end_s"] for s in phases]
         assert ends == sorted(ends)
         # --warmup compiled every bucket of `simple`, and the program's
         # counter saw it without a request having been served.

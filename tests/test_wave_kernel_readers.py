@@ -196,7 +196,8 @@ def test_the_manifest_holds_the_three_last_for_the_two_cells():
         manifest = json.load(f)
     layers = {GLOBAL: "kernels", WINDOW: "kernels",
               PROGRAM: "model execution"}
-    assert manifest["per_layer"][-3:] == [
+    # (Last until PR 57 put the seven of the set-up timeline behind them.)
+    assert manifest["per_layer"][-10:-7] == [
         {"name": name, "unit": "%", "better": "higher",
          "source": "device_trace", "layer": layers[name],
          "moves": "itl_mean_ms",

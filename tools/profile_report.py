@@ -34,6 +34,14 @@ and the compute/bandwidth bound classification.
 
     python tools/profile_report.py http://127.0.0.1:8000 --roofline
 
+``--setup`` renders the set-up timeline ("why was this launch slow",
+docs/OBSERVABILITY.md): the launcher's phases from the operating system's
+start of the process to "serving", every compilation's trace / lower /
+backend spans under the phase that caused them, and ``compiles.by_scope``
+sorted by seconds with the persistent cache's hits and misses.
+
+    python tools/profile_report.py http://127.0.0.1:8000 --setup
+
 ``--loops`` renders the self-drive closed-loop state (docs/SELFDRIVING.md):
 the dispatch tuner's per-model phase and recent decisions, the admission
 loop's tightened rate ratios, or — against a router status body — the
@@ -337,6 +345,60 @@ def render_memory(report: dict, out=None) -> None:
           f"(threshold {pressure['threshold'] * 100:.0f}%){mark}\n")
 
 
+def render_setup(snap: dict, out=None) -> None:
+    """The set-up section: the ``startup`` timeline in the order things
+    started (a compile span indented under the phase that caused it, with
+    the program's name, its scope and whether the persistent cache held it),
+    then every scope's compile cost, dearest first."""
+    w = (out or sys.stdout).write
+    spans = snap.get("startup") or []
+    clock = snap.get("startup_clock") or {}
+    w(f"set-up timeline: {len(spans)} span(s) relative to the launcher's "
+      f"entry (monotonic {clock.get('entry_monotonic_s')}), "
+      f"{clock.get('dropped', 0)} dropped\n")
+    phases = [s for s in spans if "scope" not in s]
+    for s in sorted(spans, key=lambda s: (s["start_s"], -s["end_s"])):
+        compile_span = "scope" in s
+        inside = any(p is not s and p["start_s"] <= s["start_s"]
+                     and s["end_s"] <= p["end_s"] for p in phases)
+        line = (f"{s['start_s']:10.3f} {s['end_s']:10.3f} "
+                f"{s['end_s'] - s['start_s']:9.3f}  "
+                f"{'  ' if inside else ''}{s['name']}")
+        if compile_span:
+            line += f" {s['fun_name']} [{s['scope'] or '-'}]"
+            if "cache" in s:
+                line += f" cache {s['cache']}"
+            if "retrieval_s" in s:
+                line += f" (read in {s['retrieval_s']:.3f}s)"
+            if not s.get("cause"):
+                line += " (outside any phase)"
+        w(line + "\n")
+    c = snap.get("compiles") or {}
+    w(f"\ncompilations: {c.get('count', 0)} in {c.get('seconds', 0.0):.3f}s "
+      f"of backend ({c.get('cache_hits', 0)} persistent-cache hit(s), "
+      f"{c.get('cache_misses', 0)} miss(es) written), tracing "
+      f"{c.get('trace_seconds', 0.0):.3f}s, lowering "
+      f"{c.get('lower_seconds', 0.0):.3f}s\n")
+    cols = ("scope", "count", "hits", "trace_s", "lower_s", "backend_s",
+            "total_s")
+    rows = [cols]
+    by_scope = c.get("by_scope", {})
+    for key in sorted(by_scope, key=lambda k: -(
+            by_scope[k]["seconds"] + by_scope[k].get("trace_s", 0.0)
+            + by_scope[k].get("lower_s", 0.0))):
+        r = by_scope[key]
+        trace_s, lower_s = r.get("trace_s", 0.0), r.get("lower_s", 0.0)
+        rows.append((key or "(outside any scope)", r["count"],
+                     r.get("hits", 0), f"{trace_s:.3f}", f"{lower_s:.3f}",
+                     f"{r['seconds']:.3f}",
+                     f"{trace_s + lower_s + r['seconds']:.3f}"))
+    widths = [max(len(str(r[i])) for r in rows) for i in range(len(cols))]
+    for r in rows:
+        w("  " + "  ".join(
+            str(v).ljust(widths[i]) if i == 0 else str(v).rjust(widths[i])
+            for i, v in enumerate(r)) + "\n")
+
+
 def render_loops(snap: dict, out=None) -> None:
     """The self-drive loop view: which closed loops are actuated right
     now and what they decided recently. Accepts an engine ``/v2/profile``
@@ -402,6 +464,11 @@ def main(argv=None) -> int:
                    help="render the roofline attribution of /v2/profile: "
                         "achieved vs peak FLOP/s and bytes/s per bucket "
                         "with the compute/bandwidth bound classification")
+    p.add_argument("--setup", action="store_true",
+                   help="render the set-up timeline of /v2/profile: the "
+                        "launcher's phases, every compilation's trace / "
+                        "lower / backend spans, and the compile cost by "
+                        "scope with persistent-cache hits")
     p.add_argument("--loops", action="store_true",
                    help="render the self-drive closed-loop state "
                         "(the 'selfdrive' section of /v2/profile, or "
@@ -422,6 +489,8 @@ def main(argv=None) -> int:
     if args.json:
         json.dump(snap, sys.stdout, indent=2)
         sys.stdout.write("\n")
+    elif args.setup:
+        render_setup(snap)
     elif args.loops:
         render_loops(snap)
     elif args.timeseries:
