@@ -40,12 +40,15 @@ generation streams. Design, TPU-first:
   lane's piece added to ``prefill_pairs_window`` and ``prefill_pairs_global``,
   as ``cache_rows_by_kind`` feeds a wave's rows by kind.
 - **A wave carried in the piece's program** (a backend that declares
-  ``piece_wave``): every piece program of its ladder takes a wave's operands
-  at the top bucket behind the piece's (``wave``, behind ``ends``) and
-  returns the wave's part behind the piece's.  Where an iteration has a
-  piece to dispatch and lanes to decode, the worker stages both and
-  dispatches that one program (one ``gen.prefill_dispatch``); the one fetch
-  is taken apart into the piece's and the wave's, and each is counted,
+  ``piece_wave``: the ring-and-expert decoders ``cohere_moe`` and
+  ``smallthinker`` and, through its state layers, ``nemotron_h``; the
+  scheduler reads the declaration and no name): every piece program of its
+  ladder takes a wave's operands at the top bucket behind the piece's
+  (``wave``, behind ``ends``) and returns the wave's part behind the
+  piece's.  Where an iteration has a piece to dispatch and lanes to decode,
+  the worker stages both and dispatches that one program (one
+  ``gen.prefill_dispatch``); the one fetch is taken apart into the piece's
+  and the wave's, and each is counted,
   kept and emitted as its own program's fetch is: the decoding lanes' next
   token comes out of the piece's pass over the weights.  A carried wave is a
   wave to every counter (``fetched_*``, the gaps', ``dispatches``; its

@@ -88,10 +88,12 @@ axis of both, the chunked step, the sampling tail, the decoupled
   piece, the scheduler dispatches this one program and the decoding lanes'
   next token comes out of the piece's pass over the weights.  The frame is
   written here (``piece_hidden_fn``, ``prefill_fn``) for the layer kinds
-  ``"rows"``, ``"ring"`` and ``"none"``; a backend that declares it supplies
-  mixers that take the wave's rows behind the piece's
-  (``_piece_rows_layer(..., wave)``, models/grouped_query.py) and a trail
-  that counts the wave's rows apart (``_piece_end``, models/experts.py).
+  ``"rows"``, ``"ring"``, ``"state"`` and ``"none"`` (a latent cache is
+  refused); a backend that declares it supplies mixers that take the wave's
+  rows behind the piece's (``_piece_rows_layer(..., wave)``,
+  models/grouped_query.py; ``_piece_state_layer(..., wave)``,
+  models/state_layer.py) and a trail that counts the wave's rows apart
+  (``_piece_end``, models/experts.py).
   Read with ``piece_ends`` alone; a scheduler dispatches such a backend's
   waves one at a time and refuses one that declares transitions too.
 - ``cache_rows``: ``None`` (a step reads one row a position) or ``(n) ->
@@ -749,7 +751,9 @@ class DecoderBackend(ModelBackend):
         them.  A mixer projects all rows as one batch and gets, behind
         ``pos``, the wave's own step of its kind for the rows behind the
         piece's (``_decode_attend``, the wave's rows, its live rows or
-        lengths); every other product sees the rows as one batch, and
+        lengths; a ``"state"`` layer the wave's rows and lengths, its step
+        being its own, models/state_layer.py ``_step_slots``); every other
+        product sees the rows as one batch, and
         ``_piece_start`` is told how many of its rows are a wave's
         (``riders``).  The slots of the two are disjoint: a stream prefills
         or decodes."""
@@ -760,11 +764,11 @@ class DecoderBackend(ModelBackend):
                      "state": self.state_leaves}
         steps = {}
         if self.piece_wave:
-            if self.latent_attention is not None or "state" in (
-                    self.layer_kinds or ()):
+            if self.latent_attention is not None:
                 raise NotImplementedError(
                     f"{self.config.name}: a piece carries a wave through "
-                    "the layer kinds rows, ring and none")
+                    "the layer kinds rows, ring, state and none (not "
+                    "through a latent cache)")
             steps["rows"] = self._decode_attend()
             if self.ring_leaves:
                 steps["ring"] = self._decode_attend(ring=True)
@@ -781,7 +785,8 @@ class DecoderBackend(ModelBackend):
                 pos = jnp.concatenate([pos, w_lens])
                 riding = {"rows": ((steps["rows"], w_rows,
                                     self._live_rows(w_lens)),),
-                          "ring": ((steps.get("ring"), w_rows, w_lens),)}
+                          "ring": ((steps.get("ring"), w_rows, w_lens),),
+                          "state": ((w_rows, w_lens),)}
                 start = (w_rows.shape[0],)
             arena = dict(arena)
 

@@ -41,7 +41,13 @@ pieces (models/decoder.py's frame; this backend declares two lanes): an M
 layer's part is models/state_layer.py's around the chunked form
 (``ssd_chunk_scan``; a padded position has ``dt = 0``: it moves nothing), a
 \\* layer's models/grouped_query.py's, an E layer the expert block over all
-lanes' positions at once.
+lanes' positions at once.  **Every piece program carries a wave**
+(``piece_wave``, models/decoder.py): the decoding lanes' rows stand behind
+the piece's, an M layer steps their states with the wave's own kernel behind
+the piece lanes' chunked form (models/state_layer.py ``_step_slots``), a \\*
+layer reads their rows with the decode kernel, and an E layer's held experts
+are read once for the rows of both, so where a token gap holds a piece the
+lanes' next token comes out of the piece's pass over the weights.
 
 The projection's ``xBC`` is rounded to the model's dtype before the
 convolution, in a wave and in a piece alike: the tail a slot carries is then
@@ -72,6 +78,12 @@ class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
     state_leaves = ("s", "conv")
     expert_form = "plain"
     expert_act = "relu2"
+    # Every piece program carries a wave of the top bucket: where a token
+    # gap holds a piece, the decoding lanes' next token comes out of the
+    # piece's pass over the weights (models/decoder.py ``piece_wave``;
+    # the wave's rows advance their slots' states by the wave's own step,
+    # models/state_layer.py; PERF.md section 6, PR 58).
+    piece_wave = True
 
     def __init__(self, name: str = "nemotron_h", pattern: str = "MEM*EME",
                  n_layers: int | None = None, d_model: int = 64,

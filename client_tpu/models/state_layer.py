@@ -10,14 +10,28 @@ kind, R, (taps - 1) * width]``, as the projection leaves them, in the model's
 dtype.  What a layer does with them is one frame around different
 projections:
 
-- **a wave** (``_advance``): each lane's new input joins its slot's tail
-  (``ext [B, taps, width]``), all but the oldest go back, and the slot's state
-  moves one position in place: the wave kernel, or its oracle where the arena
-  is not the kernels' (``_use_kernel()``, the one place that chooses);
+- **a wave** (``_advance``: the projection, then ``_step_slots``): each
+  lane's new input joins its slot's tail (``ext [B, taps, width]``), all but
+  the oldest go back, and the slot's state moves one position in place: the
+  wave kernel, or its oracle where the arena is not the kernels'
+  (``_use_kernel()``, the one place that chooses);
 - **a piece** of ``L`` lanes (``_piece_state_layer``): the projection over
   every lane's positions at once, then a lane at a time the chunked form from
   the slot's state and tail (zeros for a prompt's first piece), the state and
-  the tail of the last valid positions written back.
+  the tail of the last valid positions written back;
+- **a wave that rides in the piece's program** (models/decoder.py
+  ``piece_wave``; ``_piece_state_layer(..., wave)``): the wave's ``B`` rows
+  stand behind the piece's through the one projection (its matrices are read
+  once a program) and then take ``_step_slots``, the wave's own step and the
+  one copy of it, on their own slots behind the last piece lane's writes.  The
+  slots of the two are disjoint (a stream prefills or decodes), and the
+  state's leaf goes from a lane's ``dynamic_update_slice`` into the kernel's
+  aliased operand with no branch between: it is updated in place all the
+  way (tests/test_tpu_compile.py holds the compiled programs to it).  A
+  backend whose other kinds carry too declares ``piece_wave``
+  (``models/nemotron_h.py``); ``models/kimi_linear.py`` does not, its
+  attention being a latent cache, and with no wave the piece traces to the
+  program it was.
 
 A model sets ``taps, piece, chunk, state_shape`` (a slot's state as the
 recurrence walks it) and supplies ``_state_ops()`` -> (wave kernel, its
@@ -40,14 +54,24 @@ class StateLayer:
     """The shared parts above."""
 
     def _advance(self, lp, x, s_a, conv_a, rows, lens, ki):
+        del lens
+        return self._step_slots(
+            lp, self._state_project(lp, x["h"], conv_a.dtype), s_a, conv_a,
+            rows, ki)
+
+    def _step_slots(self, lp, projected, s_a, conv_a, rows, ki):
+        """A wave's step behind its projection (``projected``: what
+        ``_state_project`` made of the lanes' rows): -> (s_a, conv_a, o ``[B,
+        *]``).  The one copy of the step: a wave of its own runs it
+        (``_advance``), and so does the wave that rides in a piece's program
+        (``_piece_state_layer``)."""
         import jax
         import jax.numpy as jnp
 
         from client_tpu.engine.backend_init import pallas_interpret
         from client_tpu.models.decoder import put_slot_tails, slot_tails
 
-        del lens
-        new, beside, aside = self._state_project(lp, x["h"], conv_a.dtype)
+        new, beside, aside = projected
         lanes, width = new.shape
         pick, slots, tail = slot_tails(conv_a, ki, rows)
         ext = jnp.concatenate(
@@ -85,9 +109,16 @@ class StateLayer:
             lambda *operands: recurrence(*operands, zero)[0], lambda t: t)
 
     def _piece_state_layer(self, lp, s_a, conv_a, ki, rows, starts, lens, x,
-                           pos):
+                           pos, wave=None):
         """-> (s_a, conv_a, o ``[L * piece, *]``), lane after lane
-        (models/decoder.py ``piece_hidden_fn``)."""
+        (models/decoder.py ``piece_hidden_fn``).
+
+        **With a wave** (models/decoder.py ``piece_wave``; ``wave``: the
+        wave's rows ``[B]`` and lengths): x holds the wave's ``B`` rows behind
+        the piece's, the projection runs once over all of them (its matrices
+        are read once a program), and behind the last lane the wave's rows go
+        through the wave's own step on their slots (``_step_slots``); o is
+        then ``[L * piece + B, *]``."""
         import jax
         import jax.numpy as jnp
 
@@ -128,4 +159,11 @@ class StateLayer:
                                          (self.taps - 1, width))
             conv_a = jax.lax.dynamic_update_slice(
                 conv_a, tail.reshape(1, 1, -1), (ki, row, 0))
+        if wave is not None:
+            w_rows, _ = wave
+            s_a, conv_a, o = self._step_slots(
+                lp, jax.tree_util.tree_map(
+                    lambda t: t[rows.shape[0] * n:], (new, beside, aside)),
+                s_a, conv_a, w_rows, ki)
+            outs.append(o)
         return s_a, conv_a, jnp.concatenate(outs)

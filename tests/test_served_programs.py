@@ -42,11 +42,13 @@ RECORDED = {
     ("kimi", "prefill"): ("37d5b524b0b73f60", "083a795658c4ced4"),    # 51, 48
     ("kimi", "prefill_1"): ("c1a4f0a1a32b0105", "11c370955249a916"),  # 51, 48
     ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),  # 45, 45
-    # (the two-lane programs of PR 52: a lane's rows pass a barrier beside
-    # its output where another lane follows, models/grouped_query.py
-    # ``_lane_by_lane``; the kernels and every one-lane program as they were)
-    ("nemotron", "prefill"): ("d02cab991c063cc5", "036da1223ab7372f"),  # 52, 47
-    ("nemotron", "prefill_1"): ("50e1142bcff1a77f", "72f715ae5cf8c153"),  # 51, 47
+    # (the piece programs of PR 58: each carries a wave behind the piece's
+    # rows through the ``"state"`` kind too, models/state_layer.py
+    # ``_step_slots``; the wave program as it was, and with no ``wave``
+    # operand the frame traces to the programs PR 52 and PR 51 recorded,
+    # ``PLAIN_PIECES`` below)
+    ("nemotron", "prefill"): ("e092785a1c460693", "6e989d52d0c1992c"),  # 58, 58
+    ("nemotron", "prefill_1"): ("ccedbbbfc4760089", "b9d9b336795b3b24"),  # 58, 58
     # (three passes over two layers: the pass axis of both frames)
     ("ouro", "decode"): ("a3cdb3b4609e3d6a", "0a13edafef621d9d"),     # 50, 50
     ("ouro", "prefill"): ("70bea758f74145ba", "472369f043e003f9"),    # 52, 50
@@ -67,12 +69,16 @@ RECORDED = {
 }
 
 # What the piece frame traces to with no ``wave`` operand for the backends
-# whose programs carry one: the programs they served until PR 56 (the PR that
-# last recorded them beside each).  The guard that the frame, the lane walk
-# and the expert layer are what they were for the four families whose piece
-# programs take none.
+# whose programs carry one: the programs they served until PR 56 (``nemotron``
+# until PR 58; the PR that last recorded them beside each; ``nemotron``'s
+# two-lane program is PR 52's, a lane's rows behind a barrier where another
+# lane follows, models/grouped_query.py ``_lane_by_lane``).  The guard that
+# the frame, the lane walk, the state layer and the expert layer are what
+# they were for the three families whose piece programs take none.
 PLAIN_PIECES = {
     ("cohere_moe", "prefill"): ("03df8db14a8ae370", "e1195263e49cba3f"),  # 53, 53
+    ("nemotron", "prefill"): ("d02cab991c063cc5", "036da1223ab7372f"),  # 52, 47
+    ("nemotron", "prefill_1"): ("50e1142bcff1a77f", "72f715ae5cf8c153"),  # 51, 47
     ("smallthinker", "prefill"): ("1fe2b7a99c70a34e", "65c5487282cc111d"),  # 52, 52
     ("smallthinker", "prefill_1"): ("19097d0c8dec2ec6", "1cfe8973c0312174"),  # 51, 49
 }

@@ -838,47 +838,67 @@ def test_state_attention_and_expert_blocks_compile_at_published_widths(
     form in plain XLA; **a piece of two prompts is ten grouped matmuls too**
     (one plan, one gather and one pair of products a layer for both lanes'
     positions: the 64 held experts of a layer are read once a program) and a
-    lane's own flash calls.  No program copies a weight (the experts' ``[64,
-    1856, 2688]`` leaves, ``W_in``'s three blocks, the head) or writes a
-    state, tail or row leaf out again: the donated arena's four leaves are
-    updated in place, each lane's slot of state among them."""
+    lane's own flash calls.  **A piece program carries a wave** (PR 58,
+    ``piece_wave`` through the ``"state"`` kind): the full wave's 256 rows
+    behind the piece's, so the wave's six state calls and two decode calls
+    beside the flash calls and the chunked form, still ten grouped matmuls
+    (one sorted layout for the rows of both: 8640 rows where the piece alone
+    had 5056, 11712 for 10176) and the wave's part of the result behind the
+    piece's.  No program copies a weight (the experts' ``[64, 1856, 2688]``
+    leaves, ``W_in``'s three blocks, the head) or writes a state, tail or row
+    leaf out again: the donated arena's four leaves are updated in place,
+    each piece lane's slot of state and then the wave's slots, through the
+    kernel's aliased operand, in the one 3.2 GB leaf."""
     text, arena, memory, backend = _nemotron_program(one_chip, monkeypatch,
                                                      which, lanes)
     calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
     assert calls.count("grouped_matmul") == 10
+    # (A wave of its own, or the one that rides in the piece's program.)
+    assert calls.count("ssd_wave_update") == 6
+    assert calls.count("decode_wave_attention") == 2
+    # 256 tokens, a record row a lane and the wave's three counts.
+    wave = 256 + 256 * backend.stream_record + 3
     if which == "decode":
-        assert calls.count("ssd_wave_update") == 6
-        assert calls.count("decode_wave_attention") == 2
-        # 256 tokens, a record row a lane and the wave's three counts.
-        assert f"s32[{256 + 256 * backend.stream_record + 3}]" in text
+        assert f"s32[{wave}]" in text
     else:
         assert calls.count("flash_attention") == lanes * 8 * 2
-        assert "ssd_wave_update" not in calls
-        # The sorted layout in tiles of 32 rows for one lane's pairs, of 64
-        # for two lanes' (an expert's mean share 24 and 48 rows).
-        assert f"bf16[{5056 if lanes == 1 else 10176},2688]" in text
-        # A token and 512 record rows a lane.
-        assert f"s32[{lanes * (1 + 512 * backend.stream_record)}]" in text
+        # The sorted layout in tiles of 64 rows either way (an expert's mean
+        # share 36 and 60 rows with the wave's 256 rows among the piece's:
+        # 24 and 48, in tiles of 32 and 64, before a wave rode).
+        assert f"bf16[{8640 if lanes == 1 else 11712},2688]" in text
+        # A token and 512 record rows a lane, and the wave's part behind
+        # them: one result.
+        piece = lanes * (1 + 512 * backend.stream_record)
+        assert f"s32[{piece + wave}]" in text
     weights = (r"64,1856,2688|2688,4096|2688,6144|4096,2688|2688,3712"
                r"|3712,2688|65536,2688|2688,65536")
     leaves = r"6,257,32,128,128|6,257,18432|2,257,4096,256"
     moved = _written_out_again(text, weights + "|" + leaves)
     assert not moved, moved
+    # The state's leaf above all: 3.2 GB that fit the chip once.
+    assert not re.findall(r"= f32\[6,257,32,128,128\][^=\n]*? copy\(", text)
     if memory is None:
         pytest.skip("this backend reports no memory analysis")
     cache = sum(math.prod(arena[k].shape) * arena[k].dtype.itemsize
                 for k in ("s", "conv", "k", "v"))
     assert memory.alias_size_in_bytes >= cache
+    print(f"nemotron {which} x{lanes}: temporaries "
+          f"{memory.temp_size_in_bytes}")
     # A wave's temporaries are its activations (16 MB), a piece's the sorted
-    # layout's 7104 rows and the chunks' pairwise decays (73 MB): far under
-    # the 3.2 GB state leaf or one expert leaf's 0.64 GB, which this program
-    # copied whole before its leaves lay as they do (PERF.md section 6, PR
-    # 45).
-    # Two lanes double a piece's (the sorted layout, the gather and the
-    # pairwise decays of both).
+    # layout's rows, the chunks' pairwise decays and the carried wave's 256
+    # rows of logits (147 MB; 34 MB before a wave rode): far under the 3.2 GB
+    # state leaf or one expert leaf's 0.64 GB, which this program copied
+    # whole before its leaves lay as they do (PERF.md section 6, PR 45).
+    # Two lanes double a piece's own (211 MB; 168 MB before a wave rode).
     assert memory.temp_size_in_bytes < (0.3e9 if lanes == 2 else 0.2e9), \
         memory
     assert 13.2e9 < memory.argument_size_in_bytes < 13.4e9
+    # The program's peak with the cell's arena: arguments (the arena among
+    # them, aliased to the result), temporaries and what of the result is
+    # not the arena, under the chip's 16 GB (13.45 and 13.51 GB).
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < 13.7e9, memory
 
 
 # -- the piece's head under one conditional (PR 51) -----------------------------
@@ -928,10 +948,10 @@ def test_a_piece_computes_its_head_under_one_conditional(
     """The compiled piece program of ``smallthinker_21b``'s and
     ``nemotron3_nano_30b``'s widths holds **one** conditional of two branches
     beside its flash calls' switches (a branch a count of rows): the head's,
-    which in ``smallthinker_21b``'s is the head of the wave that rides in the
-    program too (PR 56: one product over the vocabulary's matrix for the
-    lanes' last rows and the wave's, under "a lane ends or a wave lane is
-    live").  Whatever has the vocabulary's dimension (the product with the
+    which is the head of the wave that rides in the program too (PR 56, and
+    PR 58 for ``nemotron3_nano_30b``: one product over the vocabulary's
+    matrix for the lanes' last rows and the wave's, under "a lane ends or a
+    wave lane is live").  Whatever has the vocabulary's dimension (the product with the
     head's matrix, the logits, the token choice) stands in the branch that
     takes, the other branch holds no product at all, and the donated arena
     passes through neither: the program's temporaries are the parent's and
@@ -993,6 +1013,16 @@ def test_a_piece_computes_its_head_under_one_conditional(
         # 48 more under the one conditional (29.2 MB; the one-lane program
         # reads 6.0 MB over ``before``, the two-lane one under it).
         allowed += 48 * vocab * 4
+    else:
+        # The wave that rides (PR 58): a row of float32 logits a lane of its
+        # 256 more under the one conditional (67.1 MB), and the sorted
+        # layout's rows more with the wave's among them (8640 for 5056, 11712
+        # for 10176), a row of it in bfloat16 before the first product and in
+        # float32 behind each of the two.  The one-lane program reads 146.9
+        # MB (113.0 MB over ``before``), the two-lane one 211.2 MB (43.1 MB
+        # over).
+        more = (8640 - 5056) if lanes == 1 else (11712 - 10176)
+        allowed += 256 * vocab * 4 + more * (width * 2 + 1856 * 4 + width * 4)
     assert memory.temp_size_in_bytes <= allowed, memory
 
 

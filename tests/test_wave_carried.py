@@ -1,8 +1,10 @@
 """A wave carried in the piece's program (models/decoder.py ``piece_wave``:
-``models/cohere_moe.py`` and ``models/smallthinker.py``) at the tiny presets
-on the CPU: the one program against the piece program and then the wave
-program on the same arena (the arena's leaves, both programs' tokens, the
-records' rows, the wave's counts, which are the wave's rows' alone), exact in
+``models/cohere_moe.py``, ``models/smallthinker.py`` and, through the
+``"state"`` kind of models/state_layer.py, ``models/nemotron_h.py``) at the
+tiny presets on the CPU: the one program against the piece program and then
+the wave program on the same arena (the arena's leaves, a slot's state and
+convolution tail among them, both programs' tokens, the records' rows, the
+wave's counts, which are the wave's rows' alone), exact in
 float32 with the reference products and within the tie in bfloat16 with the
 kernels; with every wave lane padded it is the piece program; and through the
 scheduler, a backend that declares it against a subclass that does not: one
@@ -18,20 +20,38 @@ import pytest
 from client_tpu.engine import TpuEngine
 from client_tpu.engine.repository import ModelRepository
 from client_tpu.models.cohere_moe import CohereMoeBackend
+from client_tpu.models.nemotron_h import NemotronHBackend
 from client_tpu.models.smallthinker import SmallThinkerBackend
 from client_tpu.observability import spans
 from test_smallthinker import counters, stream
 
 PIECE, CAP = 8, 4
-TINY = dict(seed=5, max_seq_len=64, window=16, piece=PIECE, dtype="float32",
+TINY = dict(seed=5, max_seq_len=64, piece=PIECE, dtype="float32",
             record=True, max_streams=CAP)
-FAMILIES = {"cohere_moe": CohereMoeBackend, "smallthinker": SmallThinkerBackend}
+FAMILIES = {"cohere_moe": CohereMoeBackend, "nemotron_h": NemotronHBackend,
+            "smallthinker": SmallThinkerBackend}
+# What a family's preset takes beside ``TINY``: a window for the two that
+# keep rings; ``nemotron_h``'s own pattern holds all three of its kinds
+# (``MEM*EME``: three state layers, an attention layer, three expert layers).
+OWN = {"cohere_moe": {"window": 16}, "nemotron_h": {},
+       "smallthinker": {"window": 16}}
 # (family, prompts a piece program)
-CASES = [("cohere_moe", 1), ("smallthinker", 1), ("smallthinker", 2)]
+CASES = [("cohere_moe", 1), ("smallthinker", 1), ("smallthinker", 2),
+         ("nemotron_h", 1), ("nemotron_h", 2)]
 # The logits' bits behind a record's words.
 BITS = 9
-# The tiny presets' choices a token and layers (all experts held).
-TOP_K, LAYERS = 2, 4
+# The tiny presets' choices a token (all experts held).
+TOP_K = 2
+
+
+def tiny(family, **how):
+    return {**TINY, **OWN[family], **how}
+
+
+def expert_layers(be):
+    """The layers that route: blocks of their own where a backend has such
+    (the ``"none"`` kind), else every layer."""
+    return be.layer_kinds.count("none") or be.n_layers
 
 
 def apart(cls):
@@ -51,7 +71,7 @@ class Programs:
 
     def __init__(self, family, lanes, **how):
         cls = FAMILIES[family]
-        kwargs = {**TINY, **how}
+        kwargs = tiny(family, **how)
         self.be, plain = cls(**kwargs), apart(cls)(**kwargs)
         assert self.be.piece_wave and not plain.piece_wave
         self.params = self.be.place_params(self.be._init_params())
@@ -194,10 +214,9 @@ def test_with_the_kernels_it_is_the_two_programs_within_the_tie(
     for name in a2:
         one = np.asarray(a1[name]).astype(np.float32)
         two = np.asarray(a2[name]).astype(np.float32)
-        if name == "tok":
-            one, two = one[:2], two[:2]
-        assert np.allclose(one[..., :CAP, :, :] if one.ndim == 4 else one,
-                           two[..., :CAP, :, :] if two.ndim == 4 else two,
+        # (The junk slot apart: the padded lanes' rows, tails and states.)
+        keep, axis = (range(2), 0) if name == "tok" else (range(CAP), 1)
+        assert np.allclose(np.take(one, keep, axis), np.take(two, keep, axis),
                            atol=0.05), name
 
 
@@ -208,8 +227,9 @@ def test_the_waves_counts_are_of_its_own_rows(family, lanes):
     p = programs(family, lanes)
     _, (_, _, wave) = p.step(PIECE)
     pairs, busiest, touched = wave[-3:]
-    assert pairs == 2 * p.be.top_k * p.be.n_layers
-    assert p.be.n_layers <= busiest <= 2 * p.be.n_layers
+    layers = expert_layers(p.be)
+    assert pairs == 2 * p.be.top_k * layers
+    assert layers <= busiest <= 2 * layers
     assert pairs // 2 <= touched <= pairs
 
 
@@ -237,29 +257,36 @@ def test_with_every_wave_lane_padded_it_is_the_piece_program(family, lanes,
         assert not wave1[CAP:-3].reshape(CAP, width)[:, -BITS:].any()
 
 
-def test_the_two_ring_and_expert_backends_declare_it_and_no_other():
-    """The latent, the state and the looped families keep their programs
-    (the frame carries a wave through the kinds rows, ring and none)."""
+def test_the_ring_and_the_state_backends_declare_it_and_no_other():
+    """Three declare it; the two latent caches and the looped family keep
+    their programs (the frame carries a wave through the kinds rows, ring,
+    state and none, and a state layer beside a latent cache carries none)."""
     from client_tpu.models.decoder import DecoderBackend
     from client_tpu.models.kimi_linear import KimiLinearBackend
-    from client_tpu.models.nemotron_h import NemotronHBackend
     from client_tpu.models.ouro import OuroBackend
     from client_tpu.models.pangu_moe import PanguMoeBackend
 
     assert DecoderBackend.piece_wave is False
-    for cls in (KimiLinearBackend, NemotronHBackend, OuroBackend,
-                PanguMoeBackend):
+    for cls in (KimiLinearBackend, OuroBackend, PanguMoeBackend):
         assert cls.piece_wave is False, cls
+    assert sorted(cls.__name__ for cls in FAMILIES.values()) == [
+        "CohereMoeBackend", "NemotronHBackend", "SmallThinkerBackend"]
     for cls in FAMILIES.values():
         assert cls.piece_wave is True
 
 
-def test_a_state_layer_carries_no_wave():
-    from client_tpu.models.nemotron_h import NemotronHBackend
+@pytest.mark.parametrize("family", ["kimi_linear", "pangu_moe"])
+def test_a_latent_cache_carries_no_wave(family):
+    """The frame's refusal, which names the kinds that carry: the state
+    layers of ``kimi_linear`` would, its latent cache does not."""
+    from client_tpu.models.kimi_linear import KimiLinearBackend
+    from client_tpu.models.pangu_moe import PanguMoeBackend
 
-    be = NemotronHBackend()
+    be = {"kimi_linear": KimiLinearBackend, "pangu_moe": PanguMoeBackend}[
+        family]()
     be.piece_wave = True
-    with pytest.raises(NotImplementedError, match="rows, ring and none"):
+    with pytest.raises(NotImplementedError,
+                       match="rows, ring, state and none"):
         be.prefill_fn()
 
 
@@ -280,10 +307,12 @@ def test_a_carrying_backends_waves_go_one_at_a_time(monkeypatch):
     read for such a backend (as for one with transitions), so every piece
     that has lanes beside it carries them."""
     monkeypatch.setenv("CLIENT_TPU_GEN_CHUNK", "4")
-    sched = scheduler_of(CohereMoeBackend(name="one_at_a_time", **TINY))
+    sched = scheduler_of(CohereMoeBackend(name="one_at_a_time",
+                                          **tiny("cohere_moe")))
     assert sched._piece_wave == CAP and sched._chunk == 1
     assert sched._decode_chunk is None
-    plain = scheduler_of(apart(CohereMoeBackend)(name="chunked", **TINY))
+    plain = scheduler_of(apart(CohereMoeBackend)(name="chunked",
+                                                 **tiny("cohere_moe")))
     assert not plain._piece_wave and plain._chunk == 4
 
 
@@ -294,7 +323,8 @@ def test_a_carrying_backend_with_transitions_is_refused():
 
     from client_tpu.engine.generative import GenerativeScheduler
 
-    sched = scheduler_of(SmallThinkerBackend(name="both", **TINY))
+    sched = scheduler_of(SmallThinkerBackend(name="both",
+                                             **tiny("smallthinker")))
     model = copy.copy(sched.model)
     model.backend = copy.copy(sched.model.backend)
     model.backend.transition_due = lambda n: False
@@ -318,13 +348,13 @@ def span_counts(engine, model):
 def served(request):
     """family -> per backend (the one that declares, the subclass that does
     not): (the streams' tokens and records, the counters' moves, the spans'
-    counts)."""
-    out = {}
-    cls = FAMILIES[request.param]
+    counts); and the family's ``expert_layers``."""
+    cls, out = FAMILIES[request.param], {}
     for which, kind in (("carries", cls), ("apart", apart(cls))):
         name = f"{request.param}_{which}"
-        be = kind(name=name, **{**TINY, "dtype": "bfloat16",
-                                "max_streams": 2, "attn_impl": "fused"})
+        be = kind(name=name, **tiny(request.param, dtype="bfloat16",
+                                    max_streams=2, attn_impl="fused"))
+        out["expert_layers"] = expert_layers(be)
         repo = ModelRepository()
         repo.register_backend(be)
         engine = TpuEngine(repo)
@@ -397,7 +427,7 @@ def test_the_carried_waves_stats_count_its_lanes_alone(served):
     for which in ("carries", "apart"):
         _, moved, _ = served[which]
         assert moved["expert_pairs_local"] == \
-            moved["fetched_lanes_live"] * TOP_K * LAYERS
+            moved["fetched_lanes_live"] * TOP_K * served["expert_layers"]
 
 
 def test_a_gap_behind_a_carried_wave_is_behind_a_piece(served):
@@ -449,8 +479,8 @@ def test_a_carried_wave_is_charged_its_rows_share(family, monkeypatch):
                         waves[n:]))
     monkeypatch.setattr(gen.GenerativeScheduler, "_take_fetch", noted)
     name = f"{family}_billed"
-    be = FAMILIES[family](name=name, **{**TINY, "dtype": "bfloat16",
-                                         "max_streams": 2})
+    be = FAMILIES[family](name=name, **tiny(family, dtype="bfloat16",
+                                            max_streams=2))
     repo = ModelRepository()
     repo.register_backend(be)
     engine = TpuEngine(repo)
