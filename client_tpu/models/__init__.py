@@ -78,6 +78,17 @@ def _nemotron_h() -> ModelBackend:
     return NemotronHBackend()
 
 
+@register_model("granite_hybrid", default=False)
+def _granite_hybrid() -> ModelBackend:
+    """The dense decoder with a recurrent state (nine Mamba-2 layers to one
+    attention layer, a SwiGLU inside every layer, scalar multipliers on the
+    embedding, the residual branches, the scores and a tied head), at its tiny
+    preset.  Opt-in, and imported when it is built, as ``pangu_moe``."""
+    from client_tpu.models.granite_hybrid import GraniteHybridBackend
+
+    return GraniteHybridBackend()
+
+
 @register_model("ouro", default=False)
 def _ouro() -> ModelBackend:
     """The dense decoder whose layer stack runs four passes over one set of

@@ -96,6 +96,12 @@ axis of both, the chunked step, the sampling tail, the decoupled
   (``_piece_end``, models/experts.py).
   Read with ``piece_ends`` alone; a scheduler dispatches such a backend's
   waves one at a time and refuses one that declares transitions too.
+- ``attn_scale``: ``None`` (a ``"rows"`` layer's scores are scaled by ``1 /
+  sqrt(head_dim)``) or the number a model scales them by (a published
+  ``attention_multiplier``): the wave's kernel and its oracle take it
+  (ops/decode_kernel.py ``sm_scale``), and so does a piece's attention
+  (models/grouped_query.py).  One chip's whole-context rows alone: with a
+  ring, a latent cache or ``kv_shards > 1`` the step is refused at build.
 - ``cache_rows``: ``None`` (a step reads one row a position) or ``(n) ->
   (summary rows, exact rows)`` a step at context length ``n`` reads.
 - ``cache_rows_by_kind``: ``None``, or ``(n) -> (ring rows, other rows, past)``:
@@ -315,6 +321,7 @@ class DecoderBackend(ModelBackend):
     ring_leaves: tuple[str, ...] = ()
     ring_window: int | None = None
     latent_attention: int | None = None
+    attn_scale: float | None = None
     wave_stats: tuple[str, ...] = ()
     stream_record = 0
     cache_rows = None
@@ -476,6 +483,12 @@ class DecoderBackend(ModelBackend):
                                                   window_wave_attention)
 
         interpret, block_s = pallas_interpret(), self.decode_block_s
+        if self.attn_scale is not None and (
+                ring or self.latent_attention is not None
+                or self.kv_shards > 1):
+            raise NotImplementedError(
+                "attn_scale is taken by one chip's whole-context rows (not "
+                "by a ring, a latent cache or kv_shards > 1)")
         if ring:
             if self.kv_shards > 1:
                 raise NotImplementedError(
@@ -510,9 +523,12 @@ class DecoderBackend(ModelBackend):
                     layer_index=None if static else layer,
                     block_s=block_s, interpret=interpret)
         elif not self._use_kernel():
+            scale = self.attn_scale
+
             def attend(k_a, v_a, q, k, v, rows, live, layer):
                 return reference_decode_attention(
-                    k_a, v_a, q, k, v, rows, live, layer=layer)
+                    k_a, v_a, q, k, v, rows, live, layer=layer,
+                    sm_scale=scale)
         elif self.kv_shards > 1:
             from client_tpu.parallel.kv_shard import \
                 sharded_decode_attention
@@ -524,13 +540,15 @@ class DecoderBackend(ModelBackend):
                     mesh, k_a, v_a, q, k, v, rows, live, layer=layer,
                     block_s=block_s, interpret=interpret, combine=combine)
         else:
+            scale = self.attn_scale
+
             def attend(k_a, v_a, q, k, v, rows, live, layer):
                 static = isinstance(layer, int)
                 return decode_wave_attention(
                     k_a, v_a, q, k, v, rows, live,
                     layer=layer if static else None,
                     layer_index=None if static else layer,
-                    block_s=block_s, interpret=interpret)
+                    block_s=block_s, interpret=interpret, sm_scale=scale)
 
         return attend
 

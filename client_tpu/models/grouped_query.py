@@ -47,7 +47,9 @@ class GroupedQueryPieces:
         values of the rows before it ``[P, Hkv*D]`` and, causally, of its own
         ``[n, Hkv*D]`` (both as the cache holds them), in a band of ``window``
         keys where one is given, by ``impl`` (the backend's
-        ``attention_impl`` unless given).  -> ``[n, H * D]`` float32."""
+        ``attention_impl`` unless given), the scores scaled by ``1 /
+        sqrt(D)`` or by the backend's ``attn_scale``.  -> ``[n, H * D]``
+        float32."""
         import jax
         import jax.numpy as jnp
 
@@ -59,17 +61,37 @@ class GroupedQueryPieces:
             from client_tpu.engine.backend_init import pallas_interpret
             from client_tpu.ops.flash_attention import flash_attention
 
+            if hk != h and d % 128:
+                # The kernel's grouped-query heads fill whole 128-lane
+                # tiles; narrower ones (models/granite_hybrid.py: 32 over 8
+                # of 64) go in repeated to the query heads, two a tile.  By
+                # a one-hot product, exact (a value times 1): a reshape to
+                # heads of 64 lanes is a layout of its own, which the
+                # compiler carried back to the cache's leaf and copied the
+                # leaf to (tests/test_tpu_compile.py).
+                lane = jnp.arange(h * d)
+                pick = (jnp.arange(hk * d)[:, None]
+                        == (lane // d // (h // hk) * d + lane % d)[None, :]
+                        ).astype(k_all.dtype)
+                k_all, v_all = (
+                    jnp.matmul(t, pick, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32
+                               ).astype(t.dtype) for t in (k_all, v_all))
+                hk = h
             return flash_attention(
                 q.reshape(1, n, h * d).astype(k_all.dtype), k_all[None],
                 v_all[None], causal=True, prefix=pre, window=window,
                 n_heads=h, n_kv_heads=hk, block_q=n, block_k=n,
+                sm_scale=self.attn_scale,
                 interpret=pallas_interpret())[0].astype(jnp.float32)
         group = h // hk
         k_f = jnp.repeat(k_all.astype(jnp.float32).reshape(pre + n, hk, d),
                          group, axis=1)
         v_f = jnp.repeat(v_all.astype(jnp.float32).reshape(pre + n, hk, d),
                          group, axis=1)
-        s = jnp.einsum("qhd,khd->hqk", q, k_f) / math.sqrt(d)
+        s = jnp.einsum("qhd,khd->hqk", q, k_f)
+        s = (s / math.sqrt(d) if self.attn_scale is None
+             else s * self.attn_scale)
         ago = (pre + jnp.arange(n)[:, None]) - jnp.arange(pre + n)[None, :]
         seen = ago >= 0
         if window is not None:

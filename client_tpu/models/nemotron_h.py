@@ -38,7 +38,8 @@ leaf, no mixer.  **Decode** advances a wave's states in place
 (``ssd_wave_update``, or its oracle where the arena is not the kernels') and
 reads the lanes' rows with the grouped-query decode kernel.  **Prefill** is by
 pieces (models/decoder.py's frame; this backend declares two lanes): an M
-layer's part is models/state_layer.py's around the chunked form
+layer's part is models/state_layer.py's around models/mamba2.py's mixer and
+the chunked form
 (``ssd_chunk_scan``; a padded position has ``dt = 0``: it moves nothing), a
 \\* layer's models/grouped_query.py's, an E layer the expert block over all
 lanes' positions at once.  **Every piece program carries a wave**
@@ -62,20 +63,19 @@ from client_tpu.models.decoder import record_width
 from client_tpu.models.experts import TILE_M_WAVE, ExpertDecoder
 from client_tpu.models.grouped_query import GroupedQueryPieces
 from client_tpu.models.layers import rms_norm
-from client_tpu.models.state_layer import StateLayer
+from client_tpu.models.mamba2 import Mamba2Layer
 from client_tpu.ops.ssd import CHUNK
 
 _KINDS = {"M": "state", "*": "rows", "E": "none"}
 
 
-class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
+class NemotronHBackend(Mamba2Layer, GroupedQueryPieces, ExpertDecoder):
     """The decoder above (``models/decoder.py`` for what it is served
     through).  ``pattern`` is the published ``hybrid_override_pattern`` as it
     stands, of which the first ``n_layers`` letters are served (the rest name
     layers on further chips); ``dtype="float32"`` makes weights, caches and
     matmuls float32 (the tests' exact comparison)."""
 
-    state_leaves = ("s", "conv")
     expert_form = "plain"
     expert_act = "relu2"
     # Every piece program carries a wave of the top bucket: where a token
@@ -113,24 +113,11 @@ class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
         self.n_layers, self.d_model = len(served), int(d_model)
         self.n_heads, self.n_kv_heads = int(n_heads), int(n_kv_heads)
         self.head_dim = int(head_dim)
-        self.m_heads, self.m_dim = int(mamba_heads), int(mamba_head_dim)
-        self.n_groups, self.state_size = int(n_groups), int(state_size)
-        self.taps = int(conv_kernel)
-        # (``S^T [N, P]`` a head: ops/ssd.py.)
-        self.state_shape = (self.m_heads, self.state_size, self.m_dim)
-        # The gated norm's groups: those of B and C.
-        self.norm_groups = self.n_groups
-        self.d_inner = self.m_heads * self.m_dim
-        # What the convolution mixes: x | B | C.
-        self.conv_dim = self.d_inner + 2 * self.n_groups * self.state_size
-        if self.n_heads % self.n_kv_heads or self.m_heads % self.n_groups:
-            raise ValueError(
-                f"{n_heads} query heads over {n_kv_heads} key/value heads, "
-                f"{mamba_heads} state heads in {n_groups} groups")
-        # Heads side by side in the state's leaf (ops/ssd.py): as many of one
-        # group as fill a row of 128 lanes.
-        self.pack = math.gcd(self.m_heads // self.n_groups,
-                             max(1, 128 // self.m_dim))
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{n_heads} query heads over {n_kv_heads} "
+                             "key/value heads")
+        self._mamba_setup(mamba_heads, mamba_head_dim, n_groups, state_size,
+                          conv_kernel)
         self.d_expert, self.d_shared = int(d_expert), int(d_shared)
         self.n_experts, self.first_expert = int(n_experts), int(first_expert)
         self.experts_held = int(n_experts if experts_held is None
@@ -166,15 +153,13 @@ class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
     def _init_params(self):
         """Seeded weights as ``SeededWeight`` leaves (made, and rounded to
         bfloat16, when asked for).  Every layer its norm ``ln``; an M layer
-        ``W_in`` by its columns (``wz, wxbc, wdt``: leaves of whole lanes,
-        which no slice of a product has to cut), ``conv [taps, x | B | C]`` and its bias, the
-        heads' ``dt_bias, a_log, skip`` (float32: ``softplus(dt_bias)`` about
-        0.001-0.1, ``exp(a_log)`` about 1-16), the group norm's ``gnorm`` and
+        models/mamba2.py's leaves (``_mamba_weights``: ``W_in`` by its columns
+        ``wz, wxbc, wdt``, the convolution, the heads' scalars, ``gnorm`` and
         ``wo``; a \\* layer ``wq, wk, wv, wo``; an E layer the router and its
         selection bias (float32), the shared expert's ``su, sd`` and the held
         experts' stacked ``eu`` (``W_u^T``) and ``ed``, both ``[E, f, d]``: a
         width that is no multiple of 128 lanes is never the minor axis."""
-        d, hm = self.d_model, self.m_heads
+        d = self.d_model
         f, fs, e = self.d_expert, self.d_shared, self.experts_held
         hd = self.head_dim
         w, mat, gain = self._weight_makers()
@@ -182,16 +167,7 @@ class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
         def layer(kind: str):
             lp = {"ln": gain(d)}
             if kind == "state":
-                lp.update(
-                    wz=mat(d, self.d_inner), wxbc=mat(d, self.conv_dim),
-                    wdt=mat(d, hm),
-                    conv=w(self.taps, self.conv_dim,
-                           scale=1.0 / math.sqrt(self.taps)),
-                    conv_b=w(self.conv_dim, scale=0.1),
-                    dt_bias=w(hm, scale=0.8, offset=-4.6, dtype="float32"),
-                    a_log=w(hm, scale=0.7, offset=1.4, dtype="float32"),
-                    skip=w(hm, scale=0.1, offset=1.0, dtype="float32"),
-                    gnorm=gain(self.d_inner), wo=mat(self.d_inner, d))
+                lp.update(self._mamba_weights(w, mat, gain))
             elif kind == "rows":
                 lp.update(wq=mat(d, self.n_heads * hd),
                           wk=mat(d, self.n_kv_heads * hd),
@@ -223,40 +199,6 @@ class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
         del pos
         return self._heads(lp, rms_norm(x, lp["ln"], self.rms_eps))
 
-    def _state_inputs(self, lp, beside, ext):
-        """The convolution's inputs ext ``[..., n + taps - 1, conv_dim]`` (the
-        tail, then these rows' projections) -> x ``[..., n, H, P]``, B, C
-        ``[..., n, G, N]`` float32 (models/state_layer.py)."""
-        import jax
-        import jax.numpy as jnp
-
-        del beside
-        n = ext.shape[-2] - self.taps + 1
-        ext = ext.astype(jnp.float32)
-        taps = lp["conv"].astype(jnp.float32)
-        mixed = jax.nn.silu(
-            sum(taps[j] * ext[..., j:j + n, :] for j in range(self.taps))
-            + lp["conv_b"].astype(jnp.float32))
-        lead, gn = mixed.shape[:-1], self.n_groups * self.state_size
-        return (mixed[..., :self.d_inner].reshape(*lead, self.m_heads,
-                                                  self.m_dim),
-                mixed[..., self.d_inner:self.d_inner + gn].reshape(
-                    *lead, self.n_groups, self.state_size),
-                mixed[..., self.d_inner + gn:].reshape(
-                    *lead, self.n_groups, self.state_size))
-
-    def _ssm_output(self, lp, y, x, z):
-        """The state's read-outs y ``[..., H, P]`` with the skip term, gated
-        by z ``[..., d_inner]`` and normed a group -> ``[..., d_inner]``."""
-        import jax
-        import jax.numpy as jnp
-
-        y = (y + lp["skip"][:, None] * x).reshape(z.shape) * jax.nn.silu(z)
-        y = y.reshape(*z.shape[:-1], self.norm_groups, -1)
-        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                              + self.rms_eps)
-        return y.reshape(z.shape) * lp["gnorm"].astype(jnp.float32)
-
     def _expert_block(self, lp, x, live, tile_m):
         """An E layer for rows x ``[n, d]`` -> (x, routing counts, choices
         ``[n, k]``)."""
@@ -283,35 +225,6 @@ class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
         return {**x, "h": h, "stats": x["stats"] + stats,
                 "route": x["route"] + (top_i,)}
 
-    # -- the state layer's parts (models/state_layer.py) --------------------------
-
-    def _state_ops(self):
-        from client_tpu.ops.ssd import (reference_ssd_update, ssd_chunk_scan,
-                                        ssd_recurrence, ssd_wave_update)
-
-        return (ssd_wave_update, reference_ssd_update, ssd_chunk_scan,
-                ssd_recurrence)
-
-    def _state_project(self, lp, x, dtype):
-        """An M layer's x ``[n, d]`` float32 -> the convolution's new inputs
-        ``xBC [n, conv_dim]`` in the cache's ``dtype``, nothing the
-        convolution reads beside them, and what goes round it: the gate z
-        ``[n, d_inner]`` and ``dt [n, H]`` (after the softplus), float32."""
-        import jax
-
-        h = rms_norm(x, lp["ln"], self.rms_eps)
-        z, new = self._mm(h, lp["wz"]), self._mm(h, lp["wxbc"]).astype(dtype)
-        dt = jax.nn.softplus(self._mm(h, lp["wdt"]) + lp["dt_bias"])
-        return new, None, (z, dt)
-
-    def _through_state(self, lp, ins, aside, run, pad):
-        """A padded position has ``dt = 0``: it moves nothing."""
-        import jax.numpy as jnp
-
-        (xs, b, c), (z, dt) = ins, aside
-        y = run(xs, pad(dt), -jnp.exp(lp["a_log"]), b, c)
-        return self._ssm_output(lp, y, xs, z)
-
     # -- generative interface (used by GenerativeScheduler) -------------------
 
     def init_arena(self, capacity: int):
@@ -322,14 +235,7 @@ class NemotronHBackend(StateLayer, GroupedQueryPieces, ExpertDecoder):
         import jax.numpy as jnp
 
         r, dt = capacity + 1, jnp.dtype(self.dtype)
-        n_state = self.layer_kinds.count("state")
         rows = (self.layer_kinds.count("rows"), r, self.max_seq_len,
                 self.n_kv_heads * self.head_dim)
-        return {
-            "k": jnp.zeros(rows, dt), "v": jnp.zeros(rows, dt),
-            "s": jnp.zeros((n_state, r, self.m_heads // self.pack,
-                            self.state_size, self.pack * self.m_dim),
-                           jnp.float32),
-            "conv": jnp.zeros((n_state, r, (self.taps - 1) * self.conv_dim),
-                              dt),
-            "tok": jnp.zeros(r, jnp.int32)}
+        return {"k": jnp.zeros(rows, dt), "v": jnp.zeros(rows, dt),
+                **self._state_arena(r, dt), "tok": jnp.zeros(r, jnp.int32)}

@@ -445,9 +445,11 @@ def _layer_operand(layer, layer_index):
     return jnp.asarray(layer_index if layer is None else layer, jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret", "ring"))
+@functools.partial(jax.jit, static_argnames=("block_s", "interpret", "ring",
+                                             "sm_scale"))
 def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, layer, *,
-                    block_s, interpret, ring: int):
+                    block_s, interpret, ring: int,
+                    sm_scale: float | None = None):
     """``decode_wave_attention`` and, with ``ring``,
     ``window_wave_attention``: one kernel, static switches.  ``layer`` is an
     operand (scalar prefetch) whether the caller's is a Python int or traced:
@@ -511,7 +513,8 @@ def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, layer, *,
     )
     kernel = functools.partial(_decode_kernel, block_s=block_s,
                                quantum=quantum, head_dim=d,
-                               sm_scale=1.0 / np.sqrt(d), ring=ring,
+                               sm_scale=(1.0 / np.sqrt(d) if sm_scale is None
+                                         else sm_scale), ring=ring,
                                ring_rows=s, q_group=q_group)
     block_bytes = block_s * hd * dtype.itemsize
     if q_group:
@@ -545,7 +548,8 @@ def _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, layer, *,
 
 def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
                           layer: int | None, block_s: int | None = None,
-                          interpret: bool = False, layer_index=None):
+                          interpret: bool = False, layer_index=None,
+                          sm_scale: float | None = None):
     """One layer's decode wave over the KV arena.
 
     k_arena/v_arena: ``[L, R, S, H*D]``, float32 or bfloat16;
@@ -556,6 +560,8 @@ def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
     and ``o: [B, H, D]`` the attention read over rows ``0 .. lens[b]``
     inclusive.  ``layer`` is a Python int or, with ``layer=None``, the
     traced ``layer_index``; the kernel takes either as an operand.
+    ``sm_scale`` replaces ``1 / sqrt(D)`` where a model scales its scores by
+    a number of its own.
 
     **Grouped-query rows.**  Where a row holds fewer key heads than q has
     heads (``[L, R, S, Hkv*D]``, k_new/v_new ``[B, Hkv, D]``, ``H`` a multiple
@@ -566,7 +572,8 @@ def decode_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
     """
     return _wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens,
                            _layer_operand(layer, layer_index),
-                           block_s=block_s, interpret=interpret, ring=0)
+                           block_s=block_s, interpret=interpret, ring=0,
+                           sm_scale=sm_scale)
 
 
 def window_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
@@ -595,7 +602,8 @@ def window_wave_attention(k_arena, v_arena, q, k_new, v_new, rows, lens, *,
 
 def reference_decode_attention(k_arena, v_arena, q, k_new, v_new, rows,
                                lens, *, layer: int, ring: bool = False,
-                               window: int | None = None):
+                               window: int | None = None,
+                               sm_scale: float | None = None):
     """XLA oracle with the reference path's exact semantics (scatter the
     new K/V, gather the rows, dense masked softmax over ``pos <= len``) —
     the parity target for the kernel, kept next to it like
@@ -618,7 +626,8 @@ def reference_decode_attention(k_arena, v_arena, q, k_new, v_new, rows,
     cv = v_arena[layer, rows].reshape(bsz, s, hd // d, d).astype(jnp.float32)
     if h * d != hd:
         ck, cv = (jnp.repeat(c, h * d // hd, axis=2) for c in (ck, cv))
-    scores = jnp.einsum("bhd,bshd->bhs", q, ck) / np.sqrt(d)
+    scores = jnp.einsum("bhd,bshd->bhs", q, ck)
+    scores = scores / np.sqrt(d) if sm_scale is None else scores * sm_scale
     mask = jnp.arange(s)[None, :] <= lens[:, None]
     if window is not None:
         # Row r holds the position ``(at - r) mod S`` steps back.

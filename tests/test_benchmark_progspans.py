@@ -369,9 +369,11 @@ class TestCacheReaders:
             # attention layer's between state-space layers, PR 45, and a
             # looped stack's 48 calls a wave, PR 50, and a full layer's call
             # at a query group of 16, PR 53.)
+            # (and a whole model's four calls at heads of 64 in groups of
+            # four, PR 59.)
             others = (["smallthinker_21b.mixed",
                        "nemotron3_nano_30b.assistant", "ouro_2b6.fewshot",
-                       "command_a_plus.rag"]
+                       "command_a_plus.rag", "granite4_h_micro.helpdesk"]
                       if name == "decode_attn_roofline.itl" else [])
             assert by[name]["workloads"] == [CELL] + others
             assert by[name]["moves"] == "itl_mean_ms"
@@ -589,12 +591,12 @@ class TestLanesPerCall:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         # (Last of the accepted metrics until PR 50 put its two behind it,
-        # PR 51 its one, PR 53 its two, PR 56 its four and PR 57 the seven
-        # of the set-up timeline.)
-        last = manifest["per_layer"][-17]
+        # PR 51 its one, PR 53 its two, PR 56 its four, PR 57 the seven of
+        # the set-up timeline and PR 59 its one.)
+        last = manifest["per_layer"][-18]
         stage = next(m for m in manifest["per_layer"]
                      if m["name"] == "prefill_stage_ms_mean.itl")
-        assert [m["name"] for m in manifest["per_layer"][-16:-7]] == [
+        assert [m["name"] for m in manifest["per_layer"][-17:-8]] == [
             "loop_dense_roofline.itl", "passes_per_wave.obs", HEADS,
             "piece_roofline.itl", "dense_branch_roofline.itl", CARRIED,
             "decode_attn_all_roofline.itl", "window_attn_all_roofline.itl",
@@ -651,7 +653,8 @@ class TestHeadShare:
     def test_the_manifest_holds_it_last_for_the_cells_of_the_piece_frame(
             self):
         """Appended behind every accepted metric (PR 53's two, PR 56's
-        four and PR 57's seven stand behind it since), for the cells whose
+        four, PR 57's seven and PR 59's one stand behind it since), for the
+        cells whose
         backend runs the
         decoder's piece frame
         (``evabyte_6b5.longdoc`` prefills by pieces through a program of its
@@ -659,7 +662,7 @@ class TestHeadShare:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         lanes = next(m for m in manifest["per_layer"] if m["name"] == LANES)
-        assert manifest["per_layer"][-14] == {
+        assert manifest["per_layer"][-15] == {
             "name": HEADS, "unit": "%", "better": "lower",
             "source": "program_counter", "layer": "generative scheduler",
             "moves": "itl_mean_ms",
@@ -709,12 +712,13 @@ class TestCarriedShare:
     def test_the_manifest_holds_it_last_for_the_two_cells_that_carry(self):
         """Appended behind every accepted metric (the three shares that
         read a carried wave's kernels and its program stand behind it,
-        tests/test_wave_kernel_readers.py, and PR 57's seven of the set-up
-        timeline behind those), for the cells whose backend's
+        tests/test_wave_kernel_readers.py, PR 57's seven of the set-up
+        timeline behind those and PR 59's one last), for the cells whose
+        backend's
         piece programs carry a wave (``piece_wave``)."""
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
-        assert manifest["per_layer"][-11] == {
+        assert manifest["per_layer"][-12] == {
             "name": CARRIED, "unit": "%", "better": "higher",
             "source": "program_counter", "layer": "generative scheduler",
             "moves": "itl_mean_ms",

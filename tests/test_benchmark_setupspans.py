@@ -21,7 +21,7 @@ NEW = ["startup_process_s.setup", "startup_trace_s.setup",
        "setup_unspanned_s.setup"]
 WARM_UP_CELLS = ["kimi_linear.longgen", "smallthinker_21b.mixed",
                  "nemotron3_nano_30b.assistant", "ouro_2b6.fewshot",
-                 "command_a_plus.rag"]
+                 "command_a_plus.rag", "granite4_h_micro.helpdesk"]
 
 
 def reader(name):
@@ -184,7 +184,8 @@ class TestManifest:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             manifest = json.load(f)
         cells = [w["name"] for w in manifest["workloads"]]
-        last = manifest["per_layer"][-7:]
+        # (Last until PR 59 put its one behind them.)
+        last = manifest["per_layer"][-8:-1]
         assert [m["name"] for m in last] == NEW
         for m in last:
             assert (m["unit"], m["better"], m["moves"], m["source"]) == (

@@ -839,23 +839,42 @@ class TestGroupedQueryRowsAndRing:
     against ``reference_decode_attention`` and against a lane worked out by
     positions."""
 
+    # Three query heads a key head of 16 at the kernel's own scale, and
+    # ``granite4_h_micro``'s heads: four a key head of 64 (two key heads a
+    # 128-lane tile), the scores under a multiplier of the model's (1/64).
+    @pytest.mark.parametrize("heads,sm_scale", [((6, 2, 16), None),
+                                                ((32, 8, 64), 0.015625)],
+                             ids=["6_over_2_of_16", "32_over_8_of_64"])
     @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                            (jnp.bfloat16, 2e-2)])
     @pytest.mark.parametrize("block_s,lens", [
         (8, [7, 31, 16, 0]), (32, [7, 31, 16, 0]),
         (16, [15, 16, 17, 0]), (32, [0, 8, 9, 1])])
     def test_grouped_query_rows_match_the_oracle(self, block_s, lens, dtype,
-                                                 tol):
-        k_a, v_a, q, kn, vn, rows = _grouped_case(dtype=dtype)
+                                                 tol, heads, sm_scale):
+        h, hkv, d = heads
+        k_a, v_a, q, kn, vn, rows = _grouped_case(h=h, hkv=hkv, d=d,
+                                                  dtype=dtype)
         lens = jnp.asarray(lens, jnp.int32)
         fk, fv, fo = decode_wave_attention(
             k_a, v_a, q, kn, vn, rows, lens, layer=1, block_s=block_s,
-            interpret=True)
+            interpret=True, sm_scale=sm_scale)
         rk, rv, ro = reference_decode_attention(
-            k_a, v_a, q, kn, vn, rows, lens, layer=1)
+            k_a, v_a, q, kn, vn, rows, lens, layer=1, sm_scale=sm_scale)
         assert float(jnp.max(jnp.abs(fo[:3] - ro[:3]))) < tol
         np.testing.assert_array_equal(np.asarray(fk), np.asarray(rk))
         np.testing.assert_array_equal(np.asarray(fv), np.asarray(rv))
+
+    def test_a_scale_of_the_models_is_the_query_scaled_by_hand(self):
+        """1/64 is ``1 / sqrt(64)`` of a query that is an eighth of itself:
+        exact in float32, so the oracle's two forms agree to the bit."""
+        k_a, v_a, q, kn, vn, rows = _grouped_case(h=32, hkv=8, d=64)
+        lens = jnp.asarray([7, 31, 16, 0], jnp.int32)
+        _, _, scaled = reference_decode_attention(
+            k_a, v_a, q, kn, vn, rows, lens, layer=1, sm_scale=0.015625)
+        _, _, by_hand = reference_decode_attention(
+            k_a, v_a, q / 8, kn, vn, rows, lens, layer=1)
+        assert float(jnp.max(jnp.abs(scaled - by_hand))) < 1e-6
 
     # Lengths under, at and over the ring (32 rows): the first overwrite is
     # at 32, the ring has wrapped twice at 77.
@@ -1012,23 +1031,34 @@ def _ssd_operands(n, heads=4, p=8, groups=2, state=16, strong=False, seed=0):
 
 
 class TestSsd:
-    @pytest.mark.parametrize("chunk", [1, 4, 16, 32])
+    # (positions, chunk, groups of B and C, limits as a share of the largest
+    # value): two groups as ``nemotron3_nano_30b``'s eight, one for all the
+    # heads and the published chunk of 256 as ``granite4_h_micro``'s.  The
+    # cases of 32 positions keep their absolute limits; 512 strong steps
+    # leave states of 30 and more, and float32 carries seven digits of them,
+    # so that case alone is held to the limits times the largest value.
+    @pytest.mark.parametrize("n,chunk,groups,relative", [
+        (32, 1, 2, False), (32, 4, 2, False), (32, 16, 2, False),
+        (32, 32, 2, False), (32, 4, 1, False), (32, 32, 1, False),
+        (512, 256, 1, True)])
     @pytest.mark.parametrize("strong", [False, True])
     @pytest.mark.parametrize("pack", [1, 2])
-    def test_the_chunked_form_is_the_recurrence_from_a_state(self, chunk,
-                                                             strong, pack):
+    def test_the_chunked_form_is_the_recurrence_from_a_state(
+            self, n, chunk, groups, relative, strong, pack):
         """From a non-zero start state, at any chunk, packed as the arena
         packs it or a state a head; under a decay of ``exp(-128)`` a step
         nothing overflows (no ``1 / exp(G)`` is formed)."""
-        x, dt, a, b, c, s0 = _ssd_operands(32, strong=strong)
+        x, dt, a, b, c, s0 = _ssd_operands(n, groups=groups, strong=strong)
         want_y, want_s = ssd.ssd_recurrence(x, dt, a, b, c, s0)
         got_y, got_s = ssd.ssd_chunk_scan(x, dt, a, b, c,
                                           ssd.pack_state(s0, pack),
                                           chunk=chunk)
         assert np.isfinite(np.asarray(got_y)).all()
-        assert np.abs(np.asarray(got_y - want_y)).max() < 2e-4
+        size_y = max(1.0, float(jnp.abs(want_y).max())) if relative else 1.0
+        size_s = max(1.0, float(jnp.abs(want_s).max())) if relative else 1.0
+        assert np.abs(np.asarray(got_y - want_y)).max() < 2e-4 * size_y
         assert np.abs(np.asarray(ssd.unpack_state(got_s, pack) - want_s)
-                      ).max() < 2e-5
+                      ).max() < 2e-5 * size_s
 
     def test_the_recurrence_is_the_published_line(self):
         """``S = exp(dt A) S + (dt x) B^T``, ``y = S C``, head h on group ``h
@@ -1063,7 +1093,8 @@ class TestSsd:
 
     @pytest.mark.parametrize("layer", [1, "traced"])
     @pytest.mark.parametrize("heads,groups,pack,block", [
-        (4, 2, 2, 32), (8, 2, 2, 2), (8, 8, 1, 4), (64, 8, 2, 32)])
+        (4, 2, 2, 32), (8, 2, 2, 2), (8, 8, 1, 4), (64, 8, 2, 32),
+        (4, 1, 2, 32), (64, 1, 2, 32)])
     def test_wave_kernel_parity_in_place(self, heads, groups, pack, block,
                                          layer, monkeypatch):
         """The kernel (interpreted) against its oracle: the lanes' slots

@@ -36,6 +36,11 @@ RECORDED = {
     ("cohere_moe", "prefill"): ("98ba8582adfbc1a9", "54ab1ee336ca57f1"),  # 56, 56
     ("evabyte", "decode"): ("4b633014727aa219", "54792ebb84a37cd7"),  # 44, 44
     ("evabyte", "prefill"): ("f73e0dc2333af8de", "e59f91f604bb6804"),  # 42, 42
+    # (one period of nine state layers round an attention layer, one group of
+    # B and C, heads of 64 in groups of two: PR 59)
+    ("granite", "decode"): ("88e556bdc27a1f6b", "176c43380e722e34"),  # 59, 59
+    ("granite", "prefill"): ("a237a4c9cfd525f5", "ced90e03e3dd34c2"),  # 59, 59
+    ("granite", "prefill_1"): ("b3e8671185e51c68", "1e77ce44a37939cf"),  # 59, 59
     ("gpt", "decode"): ("c7d0eebb4f86770a", "2445b260a28de378"),      # 44, 44
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),     # 31, 31
     ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),     # 34, 40
@@ -141,6 +146,19 @@ def _backend(family):
                                 max_seq_len=32, piece=16, chunk=8,
                                 attention_impl="flash", attn_impl="fused",
                                 record=True)
+    if family == "granite":
+        from client_tpu.models.granite_hybrid import GraniteHybridBackend
+
+        # Heads of 64, two a 128-lane tile (the flash pieces repeat the key
+        # heads to the query heads); two state heads of 64 side by side in a
+        # row of the state's leaf, one group of B and C for both pairs.
+        return GraniteHybridBackend(seed=3, d_model=256, n_heads=4,
+                                    n_kv_heads=2, mamba_heads=4,
+                                    mamba_head_dim=64, state_size=128,
+                                    attention_multiplier=1 / 64,
+                                    max_seq_len=32, piece=16, chunk=8,
+                                    attention_impl="flash",
+                                    attn_impl="fused", record=True)
     if family == "ouro":
         from client_tpu.models.ouro import OuroBackend
 
@@ -164,7 +182,7 @@ def _program(family, which, plain=False):
     if plain:
         be.piece_wave = False
     if family in ("pangu", "kimi", "smallthinker", "nemotron", "ouro",
-                  "cohere_moe"):
+                  "cohere_moe", "granite"):
         # (made when asked for)
         params = jax.tree_util.tree_map(
             lambda w: jax.ShapeDtypeStruct(w.shape, jnp.dtype(w.dtype)),
@@ -263,9 +281,10 @@ def test_the_piece_frame_is_the_decoders():
     run it, and none writes a piece program of its own."""
     from client_tpu.models.decoder import DecoderBackend
     from client_tpu.models.experts import ExpertDecoder
+    from client_tpu.models.granite_hybrid import GraniteHybridBackend
     from client_tpu.models.ouro import OuroBackend
 
-    for cls in (ExpertDecoder, OuroBackend):
+    for cls in (ExpertDecoder, OuroBackend, GraniteHybridBackend):
         assert cls.piece_hidden_fn is DecoderBackend.piece_hidden_fn
         assert cls.prefill_fn is DecoderBackend.prefill_fn
         assert cls._walk_kinds is DecoderBackend._walk_kinds

@@ -1074,6 +1074,78 @@ def test_four_passes_over_one_set_of_weights_compile_at_published_widths(
     assert memory.temp_size_in_bytes < 0.3e9, memory
 
 
+# -- a dense decoder with a recurrent state, the model whole (PR 59) ------------
+
+GRANITE = dict(
+    layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4,
+    d_model=2048, n_heads=32, n_kv_heads=8, d_ff=8192, mamba_heads=64,
+    mamba_head_dim=64, n_groups=1, state_size=128, conv_kernel=4, chunk=256,
+    embedding_multiplier=12, residual_multiplier=0.22,
+    attention_multiplier=0.015625, logits_scaling=8, vocab=100352,
+    max_seq_len=2048, piece=512, max_streams=80, attention_impl="flash",
+    record=True)
+
+
+@pytest.mark.parametrize("which,lanes", [("decode", 1), ("prefill", 1),
+                                         ("prefill", 2)])
+def test_forty_layers_of_state_and_rows_compile_at_published_widths(
+        one_chip, monkeypatch, which, lanes):
+    """``granite4_h_micro`` whole (2048; 36 layers of 64 state heads of 64 x
+    128 in **one** group, packed two a row; 4 of 32 query heads over 8 key
+    heads of **64**; a SwiGLU of 8192 in every layer; 100352 ids and a tied
+    head; 80 + 1 slots of 2048): a wave is 36 state calls and 4 grouped-query
+    decode calls, a piece a flash call for every count of rows before it (4
+    an attention layer and lane) and the chunked form at the published chunk
+    of 256 in plain XLA; no piece carries a wave.  No program copies a weight
+    (the embedding that is the head above all: one leaf, contracted along its
+    minor axis) or writes a state, tail or row leaf out again: the state's
+    leaf is 6.1 GB and fits the chip once.  Arguments and temporaries stay
+    under the chip's ``bytes_limit`` (16 909 336 064 B, as the v5e reports
+    it: PERF.md section 4)."""
+    from client_tpu.models.granite_hybrid import GraniteHybridBackend
+
+    backend = GraniteHybridBackend(name="g", **GRANITE)
+    assert backend.prefill_piece == (512, 2) and not backend.piece_wave
+    assert (backend.pack, backend.chunk, backend.head_dim) == (2, 256, 64)
+    text, arena, memory, seconds = _piece_backend_program(
+        one_chip, monkeypatch, backend, which, lanes)
+    print(f"granite4_h_micro {which} x{lanes}: compiled in {seconds:.1f} s; "
+          f"{memory}")
+    assert arena["s"].shape == (36, 81, 32, 128, 128)
+    assert arena["conv"].shape == (36, 81, 3 * 4352)
+    assert arena["k"].shape == arena["v"].shape == (4, 81, 2048, 512)
+    calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
+    if which == "decode":
+        assert calls.count("ssd_wave_update") == 36
+        assert calls.count("decode_wave_attention") == 4
+        # 80 tokens and a record row a lane.
+        assert f"s32[{80 + 80 * backend.stream_record}]" in text
+    else:
+        assert calls.count("ssd_wave_update") == 0
+        assert calls.count("flash_attention") == lanes * 4 * 4
+        # A token and 512 record rows a lane.
+        assert f"s32[{lanes * (1 + 512 * backend.stream_record)}]" in text
+    weights = (r"2048,4096|2048,4352|4096,2048|2048,16384|8192,2048"
+               r"|100352,2048|2048,100352")
+    leaves = r"36,81,32,128,128|36,81,13056|4,81,2048,512"
+    moved = _written_out_again(text, weights + "|" + leaves)
+    assert not moved, moved
+    assert not re.findall(r"= f32\[36,81,32,128,128\][^=\n]*? copy\(", text)
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    cache = sum(math.prod(arena[k].shape) * arena[k].dtype.itemsize
+                for k in ("s", "conv", "k", "v"))
+    assert 7.54e9 < cache < 7.56e9
+    assert memory.alias_size_in_bytes >= cache
+    # 6.38 GB of weights beside the arena.
+    assert 13.9e9 < memory.argument_size_in_bytes < 13.97e9
+    assert memory.temp_size_in_bytes < (0.1e9 if which == "decode"
+                                        else 0.6e9), memory
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < 14.6e9 < 16_909_336_064, memory
+
+
 # -- a parallel block over window and full layers, 128 heads over 8 (PR 53) -----
 
 COHERE = dict(n_layers=4, d_model=4096, n_heads=128, n_kv_heads=8,

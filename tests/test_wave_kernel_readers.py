@@ -196,8 +196,9 @@ def test_the_manifest_holds_the_three_last_for_the_two_cells():
         manifest = json.load(f)
     layers = {GLOBAL: "kernels", WINDOW: "kernels",
               PROGRAM: "model execution"}
-    # (Last until PR 57 put the seven of the set-up timeline behind them.)
-    assert manifest["per_layer"][-10:-7] == [
+    # (Last until PR 57 put the seven of the set-up timeline behind them,
+    # and PR 59 its one behind those.)
+    assert manifest["per_layer"][-11:-8] == [
         {"name": name, "unit": "%", "better": "higher",
          "source": "device_trace", "layer": layers[name],
          "moves": "itl_mean_ms",
