@@ -88,10 +88,12 @@ axis of both, the chunked step, the sampling tail, the decoupled
   piece, the scheduler dispatches this one program and the decoding lanes'
   next token comes out of the piece's pass over the weights.  The frame is
   written here (``piece_hidden_fn``, ``prefill_fn``) for the layer kinds
-  ``"rows"``, ``"ring"``, ``"state"`` and ``"none"`` (a latent cache is
-  refused); a backend that declares it supplies mixers that take the wave's
-  rows behind the piece's (``_piece_rows_layer(..., wave)``,
-  models/grouped_query.py; ``_piece_state_layer(..., wave)``,
+  ``"rows"`` (grouped-query rows in two leaves, or a latent cache's one:
+  the wave's step is ``_decode_attend``'s either way), ``"ring"``,
+  ``"state"`` and ``"none"``; a backend that declares it supplies mixers
+  that take the wave's rows behind the piece's (``_piece_rows_layer(...,
+  wave)``, models/grouped_query.py and, for a latent cache,
+  models/latent_moe.py; ``_piece_state_layer(..., wave)``,
   models/state_layer.py) and a trail that counts the wave's rows apart
   (``_piece_end``, models/experts.py).
   Read with ``piece_ends`` alone; a scheduler dispatches such a backend's
@@ -768,7 +770,8 @@ class DecoderBackend(ModelBackend):
         slot and its position its length, as ``_decode_hidden_fn`` takes
         them.  A mixer projects all rows as one batch and gets, behind
         ``pos``, the wave's own step of its kind for the rows behind the
-        piece's (``_decode_attend``, the wave's rows, its live rows or
+        piece's (``_decode_attend``, over two leaves or a latent cache's
+        one, the wave's rows, its live rows or
         lengths; a ``"state"`` layer the wave's rows and lengths, its step
         being its own, models/state_layer.py ``_step_slots``); every other
         product sees the rows as one batch, and
@@ -782,11 +785,6 @@ class DecoderBackend(ModelBackend):
                      "state": self.state_leaves}
         steps = {}
         if self.piece_wave:
-            if self.latent_attention is not None:
-                raise NotImplementedError(
-                    f"{self.config.name}: a piece carries a wave through "
-                    "the layer kinds rows, ring, state and none (not "
-                    "through a latent cache)")
             steps["rows"] = self._decode_attend()
             if self.ring_leaves:
                 steps["ring"] = self._decode_attend(ring=True)

@@ -39,7 +39,13 @@ or its oracle where the arena is not the kernels').  **Prefill** is by pieces
 ``models/pangu_moe.py`` keeps models/latent_moe.py's one): a KDA layer's part
 is models/state_layer.py's around the chunked form (``kda_chunk_scan``; a
 padded position has ``g = 0, beta = 0``: it moves nothing), a latent layer's
-models/latent_moe.py's.
+models/latent_moe.py's.  **A piece carries a wave** (``piece_wave``): the
+decoding lanes' rows ride behind the piece's through every product, step
+their slots' states by the wave's own kernel behind the piece lanes' chunked
+form (models/state_layer.py ``_step_slots``) and read their slots' latent rows
+by the wave's own kernel behind the piece lanes' flash calls
+(models/latent_moe.py ``_piece_rows_layer``); the lanes' next token comes out
+of the piece's program (PERF.md section 6, PR 60).
 
 The projection's output is rounded to the model's dtype before the
 convolution, in a wave and in a piece alike: the tail a slot carries is then
@@ -75,6 +81,15 @@ class KimiLinearBackend(StateLayer, LatentMoeDecoder):
     caches and matmuls float32 (the tests' exact comparison)."""
 
     state_leaves = ("s", "conv")
+
+    # Every piece program carries a wave of the top bucket: where a token
+    # gap holds a piece, the decoding lanes' next token comes out of the
+    # piece's pass over the weights (models/decoder.py ``piece_wave``; the
+    # wave's rows step their slots' states, models/state_layer.py
+    # ``_step_slots``, and take the decode step's path through the latent
+    # cache, models/latent_moe.py ``_piece_rows_layer``; PERF.md section 6,
+    # PR 60).
+    piece_wave = True
 
     def __init__(self, name: str = "kimi_linear", n_layers: int = 4,
                  n_dense: int = 1, d_model: int = 64, n_heads: int = 4,

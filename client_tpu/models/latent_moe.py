@@ -22,7 +22,13 @@ What does not differ between them lives here, in :class:`LatentMoeDecoder`:
   masked; the switch is the layer's, not the model's.  Of a piece of several
   lanes (models/decoder.py ``piece_hidden_fn``) the projections see every
   lane's rows at once; the switch, the flash call and the rows' write go a
-  lane at a time;
+  lane at a time.  **A piece carries a wave** (models/decoder.py
+  ``piece_wave``, which both models declare): the decoding lanes' rows stand
+  behind the piece's through the projections, and behind the last lane's
+  write they take the decode step's path, absorbed, on their own slots
+  (``_piece_rows_layer(..., wave)``; ``_absorbed`` is the one copy of what a
+  wave's query and new row are, for a wave of its own and for one that
+  rides);
 - **the expert layer** beside its shared expert (``_ffn``): the router, the
   held experts' grouped matmuls, the lazily made weights, the wave's carry and
   its three counters, the final norm and head, and the words of a stream's
@@ -130,15 +136,16 @@ class LatentMoeDecoder(ExpertDecoder):
             [c, k_r, jnp.zeros((*c.shape[:-1], pad), c.dtype)],
             axis=-1).astype(dtype)
 
-    def _qkv(self, lp, x, pos):
-        """A wave's absorbed query and new row: ``q [B, W, H]``, column h
-        ``[q_nope W_kb^T | q_rope | 0] * sm_scale`` (scaled in float32, then
-        rounded to the cache's dtype: what the kernel multiplies), and the
-        row ``[B, W]``."""
+    def _absorbed(self, lp, q_nope, q_rope, c, k_r):
+        """What ``_queries_and_rows`` made of a wave's ``B`` rows -> the
+        absorbed query and the new row as the kernel takes them: ``q [B, W,
+        H]``, column h ``[q_nope W_kb^T | q_rope | 0] * sm_scale`` (scaled in
+        float32, then rounded to the cache's dtype: what the kernel
+        multiplies), and the row ``[B, W]``.  The one copy: a wave of its own
+        runs it (``_qkv``), and so does the wave that rides in a piece's
+        program (``_piece_rows_layer``)."""
         import jax.numpy as jnp
 
-        # The wave's lanes stand where a sequence's positions would.
-        q_nope, q_rope, c, k_r = self._queries_and_rows(lp, x["h"], pos)
         q_lat = self._heads_mm("bhn,hnr->brh", q_nope, lp["wkb"])
         pad = self.row_width - self.kv_rank - self.rope_dim
         q = jnp.concatenate(
@@ -147,6 +154,11 @@ class LatentMoeDecoder(ExpertDecoder):
             axis=1)
         return ((q * self.sm_scale).astype(jnp.dtype(self.dtype)),
                 self._cache_rows_of(c, k_r, jnp.float32))
+
+    def _qkv(self, lp, x, pos):
+        """A wave's absorbed query and new row (``_absorbed``); the wave's
+        lanes stand where a sequence's positions would."""
+        return self._absorbed(lp, *self._queries_and_rows(lp, x["h"], pos))
 
     def _attention_output(self, lp, o):
         """``o_lat [B, kv_rank, H]`` -> ``concat_h(o_lat W_vb) [B, H *
@@ -251,22 +263,40 @@ class LatentMoeDecoder(ExpertDecoder):
         words ``[n, expert layers]``: one word a layer."""
         return [self.held_mask(routes).T]
 
-    def _piece_rows_layer(self, lp, c_a, li, rows, starts, lens, x, pos):
+    def _piece_rows_layer(self, lp, c_a, li, rows, starts, lens, x, pos,
+                          wave=None):
         """A latent layer's part of a piece of ``L`` lanes, x ``[L * piece,
         d]`` (models/decoder.py ``piece_hidden_fn``): the projections over
         every lane's positions at once, then a lane at a time the piece's
         queries against the slot's ``start`` rows before it and its own (one
         ``lax.switch`` branch a count of earlier rows: ``start`` is a
         multiple of the piece), its rows written behind them.  ``li`` is the
-        layer's index into ``c_a``.  -> (c_a, o ``[L * piece, H * v_dim]``)."""
+        layer's index into ``c_a``.  -> (c_a, o ``[L * piece, H * v_dim]``).
+
+        **With a wave** (models/decoder.py ``piece_wave``; ``wave``: the
+        wave's step of the kind, ``_decode_attend``'s one-leaf form, its rows
+        ``[B]`` and their live rows): x holds the wave's ``B`` rows behind the
+        piece's and the projections run once over all of them (``wqa``,
+        ``wqn``, ``wqr``, ``wkva`` are read once a program).  Behind the last
+        lane's write the wave's rows take the decode step's path: the
+        absorbed query and the new row (``_absorbed``), the step on their own
+        slots, ``_attention_output``; o is then ``[L * piece + B, H *
+        v_dim]``.  The last lane's rows and output pass a barrier before its
+        write, so the order of that lane's reads and the wave's writes in the
+        one leaf is the data's and not the compiler's to choose (as
+        models/grouped_query.py ``_lane_by_lane`` says it)."""
         import jax
         import jax.numpy as jnp
 
         del lens
-        n, w = self.piece, self.row_width
-        q_nope, q_rope, c, k_r = self._queries_and_rows(lp, x, pos)
+        n, w, lanes = self.piece, self.row_width, rows.shape[0]
+        parts = self._queries_and_rows(lp, x, pos)
+        if wave is not None:
+            parts, riding = zip(*((t[:lanes * n], t[lanes * n:])
+                                  for t in parts))
+        q_nope, q_rope, c, k_r = parts
         rows_new, outs = self._cache_rows_of(c, k_r, c_a.dtype), []
-        for i in range(rows.shape[0]):
+        for i in range(lanes):
             row, start, lane = rows[i], starts[i], slice(i * n, (i + 1) * n)
             own = rows_new[lane]
 
@@ -276,10 +306,18 @@ class LatentMoeDecoder(ExpertDecoder):
                 return self._piece_attention(lp, q_nope[lane], q_rope[lane],
                                              own, before)
 
-            outs.append(jax.lax.switch(
+            o = jax.lax.switch(
                 start // n,
                 [lambda pre=j * n: attend(pre)
-                 for j in range(self.max_seq_len // n)]))
+                 for j in range(self.max_seq_len // n)])
+            if wave is not None and i + 1 == lanes:
+                own, o = jax.lax.optimization_barrier((own, o))
+            outs.append(o)
             c_a = jax.lax.dynamic_update_slice(
                 c_a, own[None, None], (li, row, start, 0))
+        if wave is not None:
+            step, w_rows, w_live = wave
+            c_a, o = step(c_a, *self._absorbed(lp, *riding), w_rows, w_live,
+                          li)
+            outs.append(self._attention_output(lp, o))
         return c_a, jnp.concatenate(outs)

@@ -39,7 +39,13 @@ leaves ``o_lat`` with the heads along the minor axis: the two einsums write
 and read that layout).  **Prefill does not**: a prompt is consumed a piece of
 ``piece`` positions at a time (``prefill_piece``, one lane a call), each by
 the flash kernel against the rows before it and its own: models/latent_moe.py
-``_piece_rows_layer`` inside models/experts.py's piece frame.
+``_piece_rows_layer`` inside models/decoder.py's piece frame.  **A piece
+carries a wave** (``piece_wave``): where a token gap holds a piece, the
+decoding lanes' rows ride behind the piece's 512 through every product (the
+16 held experts' matrices, the projections and the dense layer are read once
+for both), through a latent layer by the wave's own kernel on their own
+slots, and out through the one head; the lanes' next token comes out of the
+piece's program (PERF.md section 6, PR 60).
 
 What this decoder shares with ``models/kimi_linear.py`` (the cache's rows and
 the absorbed products, the piece's attention, routing and the grouped expert
@@ -67,6 +73,13 @@ class PanguMoeBackend(LatentMoeDecoder):
     """The decoder above (``models/decoder.py`` for what it is served
     through).  ``dtype="float32"`` makes weights, cache and matmuls float32
     (the tests' exact-routing comparison); the served form is bfloat16."""
+
+    # Every piece program carries a wave of the top bucket: where a token
+    # gap holds a piece, the decoding lanes' next token comes out of the
+    # piece's pass over the weights (models/decoder.py ``piece_wave``; the
+    # wave's rows take the decode step's path through the latent cache,
+    # models/latent_moe.py ``_piece_rows_layer``; PERF.md section 6, PR 60).
+    piece_wave = True
 
     def __init__(self, name: str = "pangu_moe", n_layers: int = 3,
                  n_dense: int = 1, d_model: int = 64, n_heads: int = 4,

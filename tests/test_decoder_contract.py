@@ -301,10 +301,11 @@ def test_a_backend_with_its_own_piece_program_takes_no_ends(engines):
     frame = engines("kimi_linear")._schedulers["kimi_linear"]
     assert frame._piece_ends and PREFILL_ARGS[-2:] == ("ends", "wave")
     # (Behind ``ends``, the wave that a backend's piece programs carry,
-    # ``piece_wave``: this one's carry none.)
+    # ``piece_wave``: this one's carry the top bucket's, since PR 60.)
     assert list(inspect.signature(
         frame.model.backend.prefill_fn()).parameters)[-2:] == ["ends", "wave"]
-    assert not frame._piece_wave and not frame.model.backend.piece_wave
+    assert frame.model.backend.piece_wave
+    assert frame._piece_wave == frame.model.backend.max_streams
     # A one-shot backend's program takes neither.
     shot = engines("tiny_gpt")._schedulers["tiny_gpt"]
     assert not shot._piece_len and not shot._piece_ends

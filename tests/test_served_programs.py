@@ -44,8 +44,14 @@ RECORDED = {
     ("gpt", "decode"): ("c7d0eebb4f86770a", "2445b260a28de378"),      # 44, 44
     ("gpt", "prefill"): ("9f74b6f52137fcbf", "813762073b8c861a"),     # 31, 31
     ("kimi", "decode"): ("3c7634b1c3eb0637", "f12c2095739d8cfd"),     # 34, 40
-    ("kimi", "prefill"): ("37d5b524b0b73f60", "083a795658c4ced4"),    # 51, 48
-    ("kimi", "prefill_1"): ("c1a4f0a1a32b0105", "11c370955249a916"),  # 51, 48
+    # (the piece programs of PR 60, ``kimi``'s and ``pangu``'s: each carries a
+    # wave behind the piece's rows through the latent cache,
+    # models/latent_moe.py ``_piece_rows_layer``, ``kimi``'s through its state
+    # layers too; their wave programs as they were, and with no ``wave``
+    # operand the frame traces to the programs PR 51 recorded, ``PLAIN_PIECES``
+    # below)
+    ("kimi", "prefill"): ("02355e9f9b032c8b", "76860703c0fa674b"),    # 60, 60
+    ("kimi", "prefill_1"): ("2420b6afc1e0138e", "42a21884ef2c6f7d"),  # 60, 60
     ("nemotron", "decode"): ("1389f36bf7b1b00e", "a626f88ba8d2d94e"),  # 45, 45
     # (the piece programs of PR 58: each carries a wave behind the piece's
     # rows through the ``"state"`` kind too, models/state_layer.py
@@ -66,7 +72,8 @@ RECORDED = {
     # these presets' shares, and the hashes PR 33 and PR 43 recorded with
     # the tile held at 64; the programs of PR 51: the frame's head under one
     # conditional on the trailing ``ends``, the kernels as they were)
-    ("pangu", "prefill"): ("9f81a0e83b3aaa34", "f9c5bf8655c5c096"),   # 51, 49
+    # (and PR 60's: it carries a wave, above)
+    ("pangu", "prefill"): ("6780a70c2c70bfb4", "f68b86dd9a430b02"),   # 60, 60
     ("smallthinker", "decode"): ("44c3bd43dd161186", "93891480d1ba5ee1"),  # 44, 44
     # (``prefill_1`` is what ``prefill`` was until PR 52 declared two lanes)
     ("smallthinker", "prefill"): ("8a616ab553920ba9", "2b386c908a98390c"),  # 56, 56
@@ -75,15 +82,20 @@ RECORDED = {
 
 # What the piece frame traces to with no ``wave`` operand for the backends
 # whose programs carry one: the programs they served until PR 56 (``nemotron``
-# until PR 58; the PR that last recorded them beside each; ``nemotron``'s
+# until PR 58, ``kimi`` and ``pangu`` until PR 60; the PR that last recorded
+# them beside each; ``nemotron``'s
 # two-lane program is PR 52's, a lane's rows behind a barrier where another
 # lane follows, models/grouped_query.py ``_lane_by_lane``).  The guard that
-# the frame, the lane walk, the state layer and the expert layer are what
-# they were for the three families whose piece programs take none.
+# the frame, the lane walk, the state layer, the latent layer and the expert
+# layer are what they were for the two families whose piece programs take
+# none.
 PLAIN_PIECES = {
     ("cohere_moe", "prefill"): ("03df8db14a8ae370", "e1195263e49cba3f"),  # 53, 53
+    ("kimi", "prefill"): ("37d5b524b0b73f60", "083a795658c4ced4"),    # 51, 48
+    ("kimi", "prefill_1"): ("c1a4f0a1a32b0105", "11c370955249a916"),  # 51, 48
     ("nemotron", "prefill"): ("d02cab991c063cc5", "036da1223ab7372f"),  # 52, 47
     ("nemotron", "prefill_1"): ("50e1142bcff1a77f", "72f715ae5cf8c153"),  # 51, 47
+    ("pangu", "prefill"): ("9f81a0e83b3aaa34", "f9c5bf8655c5c096"),   # 51, 49
     ("smallthinker", "prefill"): ("1fe2b7a99c70a34e", "65c5487282cc111d"),  # 52, 52
     ("smallthinker", "prefill_1"): ("19097d0c8dec2ec6", "1cfe8973c0312174"),  # 51, 49
 }

@@ -383,6 +383,61 @@ def test_latent_expert_decode_step_compiles_at_published_widths(
                 if d == "f32" and "latent_wave_attention" in src]
 
 
+def test_latent_expert_piece_program_carries_a_wave_at_published_widths(
+        one_chip, monkeypatch):
+    """``pangu_ultra_moe``'s one piece program (a piece of 512 of one prompt)
+    with **the wave it carries** (PR 60, ``piece_wave`` through the latent
+    cache: models/latent_moe.py ``_piece_rows_layer``): the full wave's 128
+    rows behind the piece's through every product, five
+    ``latent_wave_attention`` (a Mosaic body a static layer) behind the five
+    layers' flash calls (a branch a count of rows before the piece), eight
+    grouped matmuls over one sorted layout of 5632 rows (4608 for the piece
+    alone; tiles of 32 either way) and the wave's part (128 tokens, three
+    counts) behind the piece's token.  The 3.4 GB latent leaf is updated in
+    place, a lane's rows and then the wave's through the kernel's aliased
+    operand, and no weight is written out again."""
+    from client_tpu.models.pangu_moe import PanguMoeBackend
+
+    backend = PanguMoeBackend(name="p", **PANGU)
+    assert backend.prefill_piece == (512, 1) and backend.piece_wave
+    text, arena, memory, _ = _piece_backend_program(
+        one_chip, monkeypatch, backend, "prefill", 1)
+    calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
+    assert calls.count("latent_wave_attention") == 5
+    assert calls.count("grouped_matmul") == 8
+    assert calls.count("flash_attention") == 5 * (4096 // 512)
+    assert "bf16[5632,7680]" in text
+    for width in (4096, 7680):
+        assert len(set(re.findall(
+            rf"%([\w.\-]+) = f32\[5632,{width}\][^=]*? custom-call\(",
+            text))) == 4
+    assert f"s32[{1 + 128 + 3}]" in text
+    # (``wqn``'s ``[1536, 16384]`` is left out: the values of a branch of
+    # 1536 rows have its shape.)
+    weights = (r"16,7680,4096|16,2048,7680|7680,1536|1536,8192|7680,576"
+               r"|16384,7680|7680,36864|18432,7680|7680,4096|2048,7680"
+               r"|7680,19200|19200,7680")
+    leaf = r"5,129,4096,640"
+    moved = _written_out_again(text, weights + "|" + leaf)
+    assert not moved, moved
+    assert not re.findall(r"= \w+\[" + leaf + r"\][^=\n]*? copy\(", text)
+    if memory is None:
+        pytest.skip("this backend reports no memory analysis")
+    assert memory.alias_size_in_bytes >= math.prod(arena["c"].shape) * 2
+    print(f"pangu prefill x1: temporaries {memory.temp_size_in_bytes}")
+    # A piece's temporaries are a lane's keys and values of up to 4096 rows
+    # in 128 heads and the sorted layout's rows (671 MB; 750 MB before a
+    # wave rode): under one expert layer's 1.5 GB of matrices, a fifth of
+    # the latent leaf.
+    assert memory.temp_size_in_bytes < 0.8e9, memory
+    assert 13.1e9 < memory.argument_size_in_bytes < 13.4e9
+    # The program's peak with the cell's arena (13.91 GB of the chip's
+    # 16.9; ``hbm_peak_bytes.itl`` counts live buffers, not temporaries).
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < 14.1e9, memory
+
+
 # -- the hybrid decoder at its published widths (PR 34) ----------------------------
 
 KIMI = dict(n_layers=8, n_dense=1, d_model=2304, n_heads=32,
@@ -454,49 +509,69 @@ def test_state_and_latent_piece_programs_compile_at_published_widths(
     pair of products an expert layer for every lane's positions: the 32 held
     experts of a layer are read once a program), a lane's own flash calls (a
     branch a count of rows before it, in both latent layers) and the chunked
-    form in plain XLA.  No program copies a weight or writes a state, tail or
-    latent leaf out again: the donated arena's three leaves (8.7 GB) are
-    updated in place, each lane's slot among them."""
-    from client_tpu.observability import spans
+    form in plain XLA.  **A piece program carries a wave** (PR 60,
+    ``piece_wave`` through the latent cache, models/latent_moe.py
+    ``_piece_rows_layer``, beside the ``"state"`` kind): the full wave's 256
+    rows behind the piece's, so the wave's six state calls and its two latent
+    calls beside the flash calls and the chunked form, still fourteen grouped
+    matmuls (one sorted layout for the rows of both: 7136 rows where the
+    piece alone had 5088, 12288 in tiles of 64 for 9184 in tiles of 32) and
+    the wave's part of the result behind the piece's.  No program copies a
+    weight or writes a state, tail or latent leaf out again: the donated
+    arena's three leaves (8.7 GB) are updated in place, each lane's slot
+    among them and then the wave's slots through the kernels' aliased
+    operands."""
+    from client_tpu.models.kimi_linear import KimiLinearBackend
 
-    place, backend, params, arena = _kimi_shapes(one_chip, monkeypatch)
-    assert backend.prefill_piece == (512, 2)
-    lane_i, lane_f = place((lanes,), jnp.int32), place((lanes,), jnp.float32)
-    step = jax.jit(spans.named_step(backend.prefill_fn(), spans.STEP_PREFILL),
-                   donate_argnums=backend.donate_argnums,
-                   static_argnums=backend.prefill_static_argnums)
-    compiled = step.lower(params, arena, lane_i,
-                          place((lanes, 512), jnp.int32), lane_i, lane_i,
-                          lane_f, lane_i, lane_f, False, lane_i,
-                          lane_i).compile()
-    text = compiled.as_text()
+    backend = KimiLinearBackend(name="k", **KIMI)
+    assert backend.prefill_piece == (512, 2) and backend.piece_wave
+    text, arena, memory, _ = _piece_backend_program(
+        one_chip, monkeypatch, backend, "prefill", lanes)
     calls = re.findall(r"%(\w+?)\.?\d* = [^=]*? custom-call\(", text)
     assert calls.count("grouped_matmul") == 14
     assert calls.count("flash_attention") == lanes * 2 * (8192 // 512)
-    assert "kda_wave_update" not in calls
-    # The sorted layout in tiles of 32 rows for either count (a held
-    # expert's mean share of the call's pairs is 16 rows a lane; the layout
-    # holds any routing of the call's 4096 pairs a lane).
-    assert f"bf16[{5088 if lanes == 1 else 9184},2304]" in text
-    # A token and 512 record rows a lane.
-    assert f"s32[{lanes * (1 + 512 * backend.stream_record)}]" in text
+    # The wave that rides in the program.
+    assert calls.count("kda_wave_update") == 6
+    assert calls.count("latent_wave_attention") == 2
+    # The sorted layout in tiles of 32 rows for one lane's positions and the
+    # wave's 256 rows (a held expert's mean share of the call's pairs is 24
+    # rows), of 64 for two lanes' (a share of 40).
+    assert f"bf16[{7136 if lanes == 1 else 12288},2304]" in text
+    # A token and 512 record rows a lane, and the wave's part behind them
+    # (256 tokens, a record row a lane, three counts): one result.
+    wave = 256 + 256 * backend.stream_record + 3
+    piece = lanes * (1 + 512 * backend.stream_record)
+    assert f"s32[{piece + wave}]" in text
     weights = (r"32,2304,2048|32,1024,2304|2304,12288|4096,2304|2304,18432"
                r"|9216,2304|2304,2048|1024,2304|20480,2304|2304,20480")
     leaves = r"2,257,8192,640|6,257,32,128,128|6,257,36864"
     moved = _written_out_again(text, weights + "|" + leaves)
     assert not moved, moved
-    memory = compiled.memory_analysis()
+    assert not re.findall(
+        r"= \w+\[(?:" + leaves + r")\][^=\n]*? copy\(", text)
+    # The head of the piece's lanes and of the wave under the one
+    # conditional of two branches (the flash calls' switches hold more).
+    assert len([b for b in re.findall(
+        r" conditional\([^\n]*?branch_computations=\{([^}]*)\}", text)
+        if b.count("%") == 2]) == 1
     if memory is None:
         pytest.skip("this backend reports no memory analysis")
     cache = sum(math.prod(arena[k].shape) * arena[k].dtype.itemsize
                 for k in ("c", "s", "conv"))
     assert memory.alias_size_in_bytes >= cache
+    print(f"kimi prefill x{lanes}: temporaries {memory.temp_size_in_bytes}")
     # A piece's temporaries are the sorted layout, the chunks' pairwise
-    # decays and a lane's keys and values of up to 8192 rows: far under the
-    # 3.2 GB state leaf or the 5.4 GB of latent rows (the arena and weights
-    # are 13.1 GB of the chip's 16.9).
-    assert memory.temp_size_in_bytes < 0.4e9, memory
+    # decays, a lane's keys and values of up to 8192 rows and the carried
+    # wave's 256 rows of logits (404 | 461 MB; 320 | 397 MB before a wave
+    # rode): far under the 3.2 GB state leaf or the 5.4 GB of latent rows
+    # (the arena and weights are 13.1 GB of the chip's 16.9).
+    assert memory.temp_size_in_bytes < (0.52e9 if lanes == 2 else 0.45e9), \
+        memory
     assert 12.9e9 < memory.argument_size_in_bytes < 13.2e9
+    # The program's peak with the cell's arena (13.34 | 13.39 GB).
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < 13.6e9, memory
 
 
 # -- the byte-level decoder at its published widths (PR 42) ------------------

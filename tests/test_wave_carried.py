@@ -1,6 +1,8 @@
 """A wave carried in the piece's program (models/decoder.py ``piece_wave``:
-``models/cohere_moe.py``, ``models/smallthinker.py`` and, through the
-``"state"`` kind of models/state_layer.py, ``models/nemotron_h.py``) at the
+``models/cohere_moe.py``, ``models/smallthinker.py``, through the
+``"state"`` kind of models/state_layer.py ``models/nemotron_h.py``, and
+through a latent cache, models/latent_moe.py, ``models/pangu_moe.py`` alone and
+``models/kimi_linear.py`` beside state layers) at the
 tiny presets on the CPU: the one program against the piece program and then
 the wave program on the same arena (the arena's leaves, a slot's state and
 convolution tail among them, both programs' tokens, the records' rows, the
@@ -20,7 +22,10 @@ import pytest
 from client_tpu.engine import TpuEngine
 from client_tpu.engine.repository import ModelRepository
 from client_tpu.models.cohere_moe import CohereMoeBackend
+from client_tpu.models.decoder import record_width
+from client_tpu.models.kimi_linear import KimiLinearBackend
 from client_tpu.models.nemotron_h import NemotronHBackend
+from client_tpu.models.pangu_moe import PanguMoeBackend
 from client_tpu.models.smallthinker import SmallThinkerBackend
 from client_tpu.observability import spans
 from test_smallthinker import counters, stream
@@ -28,16 +33,42 @@ from test_smallthinker import counters, stream
 PIECE, CAP = 8, 4
 TINY = dict(seed=5, max_seq_len=64, piece=PIECE, dtype="float32",
             record=True, max_streams=CAP)
-FAMILIES = {"cohere_moe": CohereMoeBackend, "nemotron_h": NemotronHBackend,
+
+
+def recorded(cls):
+    """A latent family under the tests' one set of arguments: ``record``
+    asks for the record that models/latent_moe.py keeps for a model that
+    declares its width (``kimi_linear`` always does, ``pangu_moe`` here)."""
+    def init(self, record=False, **kwargs):
+        cls.__init__(self, **kwargs)
+        if record:
+            self.stream_record = record_width(self.n_layers - self.n_dense)
+
+    return type(cls.__name__, (cls,), {"__init__": init})
+
+
+FAMILIES = {"cohere_moe": CohereMoeBackend,
+            "kimi_linear": recorded(KimiLinearBackend),
+            "nemotron_h": NemotronHBackend,
+            "pangu_moe": recorded(PanguMoeBackend),
             "smallthinker": SmallThinkerBackend}
 # What a family's preset takes beside ``TINY``: a window for the two that
 # keep rings; ``nemotron_h``'s own pattern holds all three of its kinds
-# (``MEM*EME``: three state layers, an attention layer, three expert layers).
-OWN = {"cohere_moe": {"window": 16}, "nemotron_h": {},
+# (``MEM*EME``: three state layers, an attention layer, three expert layers);
+# the two latent caches (``pangu_moe``: a dense and an expert layer, both
+# latent; ``kimi_linear``: two state layers and a latent one) hold every
+# expert, two a token, as the other presets do.
+_ALL_HELD = {"n_experts": 8, "experts_held": 8, "top_k": 2}
+OWN = {"cohere_moe": {"window": 16},
+       "kimi_linear": {**_ALL_HELD, "n_layers": 3, "linear_attn": {
+           "kda_layers": [1, 2], "full_attn_layers": [3], "num_heads": 4,
+           "head_dim": 16, "short_conv_kernel_size": 4}},
+       "nemotron_h": {}, "pangu_moe": {**_ALL_HELD, "n_layers": 2},
        "smallthinker": {"window": 16}}
 # (family, prompts a piece program)
 CASES = [("cohere_moe", 1), ("smallthinker", 1), ("smallthinker", 2),
-         ("nemotron_h", 1), ("nemotron_h", 2)]
+         ("nemotron_h", 1), ("nemotron_h", 2), ("pangu_moe", 1),
+         ("kimi_linear", 1), ("kimi_linear", 2)]
 # The logits' bits behind a record's words.
 BITS = 9
 # The tiny presets' choices a token (all experts held).
@@ -50,8 +81,9 @@ def tiny(family, **how):
 
 def expert_layers(be):
     """The layers that route: blocks of their own where a backend has such
-    (the ``"none"`` kind), else every layer."""
-    return be.layer_kinds.count("none") or be.n_layers
+    (the ``"none"`` kind), else every layer behind the leading dense ones."""
+    return (be.layer_kinds or ()).count("none") or (
+        be.n_layers - getattr(be, "n_dense", 0))
 
 
 def apart(cls):
@@ -258,36 +290,20 @@ def test_with_every_wave_lane_padded_it_is_the_piece_program(family, lanes,
 
 
 def test_the_ring_and_the_state_backends_declare_it_and_no_other():
-    """Three declare it; the two latent caches and the looped family keep
-    their programs (the frame carries a wave through the kinds rows, ring,
-    state and none, and a state layer beside a latent cache carries none)."""
+    """Five declare it, the two latent caches among them; the looped family
+    and the dense hybrid keep their programs."""
     from client_tpu.models.decoder import DecoderBackend
-    from client_tpu.models.kimi_linear import KimiLinearBackend
+    from client_tpu.models.granite_hybrid import GraniteHybridBackend
     from client_tpu.models.ouro import OuroBackend
-    from client_tpu.models.pangu_moe import PanguMoeBackend
 
     assert DecoderBackend.piece_wave is False
-    for cls in (KimiLinearBackend, OuroBackend, PanguMoeBackend):
+    for cls in (GraniteHybridBackend, OuroBackend):
         assert cls.piece_wave is False, cls
     assert sorted(cls.__name__ for cls in FAMILIES.values()) == [
-        "CohereMoeBackend", "NemotronHBackend", "SmallThinkerBackend"]
-    for cls in FAMILIES.values():
+        "CohereMoeBackend", "KimiLinearBackend", "NemotronHBackend",
+        "PanguMoeBackend", "SmallThinkerBackend"]
+    for cls in (*FAMILIES.values(), KimiLinearBackend, PanguMoeBackend):
         assert cls.piece_wave is True
-
-
-@pytest.mark.parametrize("family", ["kimi_linear", "pangu_moe"])
-def test_a_latent_cache_carries_no_wave(family):
-    """The frame's refusal, which names the kinds that carry: the state
-    layers of ``kimi_linear`` would, its latent cache does not."""
-    from client_tpu.models.kimi_linear import KimiLinearBackend
-    from client_tpu.models.pangu_moe import PanguMoeBackend
-
-    be = {"kimi_linear": KimiLinearBackend, "pangu_moe": PanguMoeBackend}[
-        family]()
-    be.piece_wave = True
-    with pytest.raises(NotImplementedError,
-                       match="rows, ring, state and none"):
-        be.prefill_fn()
 
 
 # -- through the scheduler -------------------------------------------------------
